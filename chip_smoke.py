@@ -1,0 +1,800 @@
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+One process drives both halves of the framework through the entry points
+a user calls, each at the full width of a model the repo ships, and
+checks what comes out by the repo's own means:
+
+* kernels  every Pallas entry point (flash attention fwd/bwd, paged
+           decode attention bf16 and int8-KV, the int8 and packed-int4
+           mixed GEMMs) once at GPT-2-small / llama3-8b widths against
+           the XLA formulation it competes with, to bf16 tolerance;
+* train    ``ds.initialize(model=build_model("gpt2"), ...)`` ->
+           ``engine.train_batch`` on the whole GPT-2-small preset at
+           seq 1024, bf16, ZeRO-1: loss finite on every step and falling
+           on a repeated batch;
+* serve    ``InferenceEngine`` on the llama3-8b preset at its published
+           widths (depth cut, printed) with ``attn_impl="auto"`` so the
+           engine's own kernel race runs: ``generate`` in process, then
+           the same engine behind an in-process ``Gateway`` answering
+           ``POST /v1/completions`` over loopback.  First-token logits
+           agree with a plain non-paged ``model.apply`` of the same
+           weights; HTTP tokens equal the in-process ones;
+* serve-int8  the same widths with int8 weights, so the mixed-GEMM race
+           runs too.
+
+``--chips 4`` runs ONLY the sharded paths and what they are compared
+with: ZeRO-3 over ``fsdp=4`` against a one-device topology (loss
+parity), tensor-parallel serving over ``tensor=4`` against one chip
+(token parity), and the proof that parameters, KV cache and memory are
+really spread over four devices with collectives in the programs.
+
+Without ``--rehearse`` any platform other than ``tpu`` is refused: the
+script never sets ``JAX_PLATFORMS`` and never falls back.  ``--rehearse``
+runs the same code at tiny sizes on whatever platform JAX reports
+(kernels in interpret mode off-TPU); the last line then names that
+platform, so a rehearsal cannot pass for a chip run.
+
+Weights are random, from ``--seed``.  Every time printed here is an
+observation on the device the last line names, not a benchmark result.
+The last stdout line is ``{"ok": true, "device": {...}}``; any failed
+phase exits non-zero without it.
+"""
+# tpulint: disable-file=print — the smoke's stdout IS its deliverable
+# tpulint: disable-file=retrace-hazard — one jit per kernel case and
+# implementation, built once and reused for that case's timed calls
+
+import argparse
+import collections
+import contextlib
+import gc
+import json
+import sys
+import tempfile
+import time
+import traceback
+
+import numpy as np
+
+BF16_REL = 2e-2         # kernel vs XLA, relative to the reference's max
+LOGIT_REL = 5e-2        # whole-model logits (8+ layers of bf16 rounding)
+INT8_LOGIT_REL = 0.15   # int8 row-wise weights vs the dense forward
+LOSS_REL = 2e-2         # ZeRO-3 on four devices vs one
+
+REAL = dict(
+    train=dict(overrides=dict(max_seq_len=1024, remat=False, scan_unroll=12,
+                              attention_impl="xla_flash"),
+               seq=1024, batch=32, steps=5),
+    serve=dict(overrides=dict(max_seq_len=512), layers=8, int8_layers=2,
+               token_budget=1024, max_seqs=8, block=64, blocks=128,
+               prompt_lens=(311, 257, 203), new_tokens=16),
+    flash={"gpt2": (4, 1024, 12, 12, 64), "llama3-8b": (1, 2048, 32, 8, 128)},
+    paged=dict(T=1024, H=32, Hkv=8, D=128, block=64, blocks=128, nb=16),
+    gemm=dict(K=4096, N=14336, Ms=(8, 1024)),
+    barrier=dict(n=8192, iters=64),
+)
+TINY = dict(
+    train=dict(overrides=dict(max_seq_len=64, num_layers=2, d_model=64,
+                              num_heads=4, vocab_size=512),
+               seq=64, batch=4, steps=4),
+    serve=dict(overrides=dict(max_seq_len=128, d_model=64, num_heads=4,
+                              num_kv_heads=4, d_ff=128, vocab_size=512),
+               layers=2, int8_layers=2, token_budget=64, max_seqs=4,
+               block=8, blocks=64, prompt_lens=(21, 17, 9), new_tokens=6),
+    flash={"gpt2": (1, 128, 4, 4, 32), "llama3-8b": (1, 128, 4, 2, 32)},
+    paged=dict(T=16, H=4, Hkv=2, D=32, block=8, blocks=16, nb=4),
+    gemm=dict(K=256, N=512, Ms=(8, 32)),
+    barrier=dict(n=256, iters=8),
+)
+
+
+class SmokeFailure(AssertionError):
+    pass
+
+
+def check(cond, msg):
+    if not cond:
+        raise SmokeFailure(msg)
+
+
+def close(name, got, ref, rel):
+    """max|got - ref| <= rel * max|ref|, everything finite; prints the
+    measured ratio either way."""
+    got = np.asarray(got, np.float32)
+    ref = np.asarray(ref, np.float32)
+    check(np.isfinite(got).all(), f"{name}: non-finite values")
+    check(got.shape == ref.shape, f"{name}: shape {got.shape} vs {ref.shape}")
+    err = float(np.abs(got - ref).max())
+    scale = float(np.abs(ref).max())
+    ok = err <= rel * scale
+    print(f"    {name}: max|d|={err:.4g} of max|ref|={scale:.4g} "
+          f"(ratio {err / max(scale, 1e-30):.3g}, bound {rel}) "
+          f"{'ok' if ok else 'FAIL'}")
+    check(ok, f"{name}: differs from its reference beyond {rel}")
+
+
+# --------------------------------------------------------------------------
+# compile accounting (jax.monitoring) and phases
+# --------------------------------------------------------------------------
+
+COMPILE = collections.Counter()
+
+
+def _watch_compiles():
+    import jax
+
+    def on_duration(event, secs, **_):
+        if event.endswith("backend_compile_duration"):
+            COMPILE["compile_s"] += secs
+        elif event.endswith("cache_retrieval_time_sec"):
+            COMPILE["cache_read_s"] += secs
+
+    def on_event(event, **_):
+        if event.endswith("cache_hits"):
+            COMPILE["cache_hits"] += 1
+        elif event.endswith("cache_misses"):
+            COMPILE["cache_misses"] += 1
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    jax.monitoring.register_event_listener(on_event)
+
+
+def peak_bytes():
+    """``memory_stats()["peak_bytes_in_use"]`` of the first device (0
+    where the backend reports none), through the platform layer."""
+    from deepspeed_tpu.platform import get_platform
+    return get_platform().max_memory_allocated()
+
+
+FAILED = []
+
+
+@contextlib.contextmanager
+def phase(name):
+    """One named phase: prints its wall and compile seconds and the
+    device's peak bytes; a failure is recorded and the run goes on, so
+    one chip call reports every phase."""
+    print(f"[{name}]", flush=True)
+    t0, c0 = time.perf_counter(), COMPILE["compile_s"]
+    try:
+        yield
+    except Exception:
+        traceback.print_exc()
+        FAILED.append(name)
+        print(f"[{name}] FAILED", flush=True)
+    gc.collect()
+    print(f"[{name}] {time.perf_counter() - t0:.1f} s "
+          f"(compile {COMPILE['compile_s'] - c0:.1f} s), "
+          f"peak_bytes_in_use={peak_bytes()}", flush=True)
+
+
+def timed(fn, *args, reps=5):
+    """(result, seconds per call) after one untimed compile+settle call."""
+    import jax
+    out = jax.block_until_ready(fn(*args))
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        out = jax.block_until_ready(fn(*args))
+    return out, (time.perf_counter() - t0) / reps
+
+
+# --------------------------------------------------------------------------
+# device behaviour the measurements of later PRs rest on
+# --------------------------------------------------------------------------
+
+def barrier_phase(sz):
+    """Is ``block_until_ready`` a completion barrier here?  A matmul chain
+    long enough to time: if the barrier is real, the wait takes the
+    compute time and the value fetch after it takes next to nothing."""
+    import jax
+    import jax.numpy as jnp
+
+    n, iters = sz["barrier"]["n"], sz["barrier"]["iters"]
+    x = jnp.full((n, n), 1.0 / n, jnp.bfloat16)
+
+    @jax.jit
+    def chain(x):
+        return jax.lax.fori_loop(0, iters, lambda _, c: c @ x, x)
+
+    float(chain(x)[0, 0])                       # compile + settle
+    t0 = time.perf_counter()
+    float(chain(x)[0, 0])
+    fetch_only = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    y = chain(x)
+    t1 = time.perf_counter()
+    y.block_until_ready()
+    t2 = time.perf_counter()
+    float(y[0, 0])
+    t3 = time.perf_counter()
+    real = (t3 - t2) < 0.1 * (t2 - t0) and (t2 - t0) > 0.5 * fetch_only
+    print(f"    dispatch returned after {1e3 * (t1 - t0):.2f} ms, "
+          f"block_until_ready after {1e3 * (t2 - t0):.2f} ms, value fetch "
+          f"took a further {1e3 * (t3 - t2):.2f} ms; dispatch+fetch alone "
+          f"{1e3 * fetch_only:.2f} ms -> block_until_ready is "
+          f"{'a real barrier' if real else 'NOT a barrier'}")
+
+
+def profiler_phase(sz):
+    """A short profiler window: do device events carry the kernel and
+    ``jax.named_scope`` names every later per-layer metric is read by?"""
+    import glob
+
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.ops.mixed_gemm import mixed_matmul
+    from deepspeed_tpu.ops.quant import quantize_rowwise
+    from tools.tracemerge import load_device_events
+
+    K, N = sz["gemm"]["K"], sz["gemm"]["N"]
+    w = quantize_rowwise(jax.random.normal(jax.random.PRNGKey(0), (K, N)))
+    x = jnp.ones((8, K), jnp.bfloat16)
+
+    @jax.jit
+    def step(x, w):
+        with jax.named_scope("smoke_scope_gemm"):
+            return mixed_matmul(x, w)
+
+    jax.block_until_ready(step(x, w))
+    # the named scope around the call, and the kernel's jitted entry
+    # point (the name its custom call carries in the HLO text)
+    scopes = ("smoke_scope_gemm", "mixed_matmul_2d")
+    with tempfile.TemporaryDirectory(prefix="chip_smoke_trace_") as out:
+        with jax.profiler.trace(out):
+            for _ in range(3):
+                jax.block_until_ready(step(x, w))
+        pb = glob.glob(f"{out}/**/*.xplane.pb", recursive=True)
+        check(pb, "the profiler wrote no xplane")
+        planes = list(jax.profiler.ProfileData.from_file(pb[-1]).planes)
+        dev = [p for p in planes if p.name.startswith("/device:")]
+        names = collections.Counter(
+            ev.name for p in dev for ln in p.lines for ev in ln.events)
+        by_name = sum(c for n, c in names.items()
+                      if any(s in n for s in scopes))
+        by_op = sum(
+            1 for e in load_device_events(out, 0)
+            if isinstance(e, dict) and e.get("ph") == "X"
+            and any(s in str((e.get("args") or {}).get("op_name", ""))
+                    for s in scopes))
+        print(f"    planes={[p.name for p in planes]}")
+        print(f"    device events={sum(names.values())}, carrying "
+              f"{scopes}: by event name {by_name}, by args.op_name after "
+              f"tools/tracemerge {by_op}; most common device event names "
+              f"{[n for n, _ in names.most_common(6)]}")
+        if jax.devices()[0].platform == "tpu":
+            check(names, "no operation showed on a device plane")
+
+
+# --------------------------------------------------------------------------
+# kernels vs the XLA formulations they compete with
+# --------------------------------------------------------------------------
+
+def kernels_phase(sz, seed):
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.inference.model import (_paged_attention,
+                                               _paged_attention_pallas,
+                                               _quantize_kv)
+    from deepspeed_tpu.inference.ragged.state import RaggedBatch
+    from deepspeed_tpu.models.layers import causal_attention
+    from deepspeed_tpu.ops.flash_attention import flash_attention
+    from deepspeed_tpu.ops.mixed_gemm import (dequant_matmul_reference,
+                                              mixed_matmul)
+    from deepspeed_tpu.ops.quant import quantize_rowwise, quantize_rowwise4
+
+    k_flash, k_paged, k_gemm = jax.random.split(jax.random.PRNGKey(seed), 3)
+    ms = lambda s: f"{1e3 * s:.3f} ms"    # noqa: E731
+
+    # --- flash attention, forward and backward
+    for name, (B, S, H, Hkv, D) in sz["flash"].items():
+        kq, kk, kv, kw = jax.random.split(jax.random.fold_in(k_flash, S), 4)
+        q = jax.random.normal(kq, (B, S, H, D), jnp.bfloat16)
+        k = jax.random.normal(kk, (B, S, Hkv, D), jnp.bfloat16)
+        v = jax.random.normal(kv, (B, S, Hkv, D), jnp.bfloat16)
+        w = jax.random.normal(kw, (B, S, H, D), jnp.float32)
+        print(f"  flash attention {name} B{B} S{S} H{H}/{Hkv} D{D}")
+        outs, grads = {}, {}
+        for impl, fn in (("pallas", flash_attention),
+                         ("xla", causal_attention)):
+            # w is an ARGUMENT: closed over, it is baked into the
+            # executable as a constant (a 114 MB compile-cache entry)
+            def loss(q, k, v, w, _fn=fn):
+                return (_fn(q, k, v).astype(jnp.float32) * w).sum()
+            outs[impl], t_f = timed(jax.jit(fn), q, k, v)
+            grads[impl], t_b = timed(
+                jax.jit(jax.grad(loss, argnums=(0, 1, 2))), q, k, v, w)
+            print(f"    {impl}: fwd {ms(t_f)}, fwd+bwd {ms(t_b)}")
+        close("fwd", outs["pallas"], outs["xla"], BF16_REL)
+        for n, a, b in zip("qkv", grads["pallas"], grads["xla"]):
+            close(f"d{n}", a, b, BF16_REL)
+
+    # --- paged decode attention over a full block table
+    c = sz["paged"]
+    T, H, Hkv, D, bs, nb = (c[k] for k in ("T", "H", "Hkv", "D", "block",
+                                            "nb"))
+    S = 8
+    kq, kc = jax.random.split(k_paged)
+    q = jax.random.normal(kq, (T, H, D), jnp.bfloat16)
+    cache = jax.random.normal(kc, (c["blocks"] + 1, bs, 2, Hkv, D),
+                              jnp.bfloat16)
+    r = np.random.RandomState(seed)
+    tables = np.stack([r.permutation(c["blocks"])[:nb] for _ in range(S)])
+    batch = RaggedBatch(
+        token_ids=jnp.zeros(T, jnp.int32),
+        positions=jnp.asarray(r.randint(0, nb * bs, T), jnp.int32),
+        seq_slot=jnp.arange(T, dtype=jnp.int32) % S,
+        token_valid=jnp.ones(T, bool),
+        block_tables=jnp.asarray(tables, jnp.int32),
+        context_lens=jnp.zeros(S, jnp.int32),
+        logits_idx=jnp.full(S, -1, jnp.int32), n_tokens=T, n_seqs=S)
+    codes, scales = _quantize_kv(cache, jnp.int8)
+    for name, kvl in (("bf16", cache), ("int8-KV", (codes, scales))):
+        print(f"  paged attention {name} T{T} H{H}/{Hkv} D{D} "
+              f"block {bs} x{nb}")
+        got = {}
+        for impl, fn in (("pallas", _paged_attention_pallas),
+                         ("xla", _paged_attention)):
+            got[impl], t = timed(
+                jax.jit(lambda kvl, q, _fn=fn: _fn(kvl, q, batch, bs, nb,
+                                                   D ** -0.5)), kvl, q)
+            print(f"    {impl}: {ms(t)}")
+        close("out", got["pallas"], got["xla"], BF16_REL)
+
+    # --- mixed-input GEMMs
+    K, N = sz["gemm"]["K"], sz["gemm"]["N"]
+    kw, kx = jax.random.split(k_gemm)
+    wd = jax.random.normal(kw, (K, N), jnp.float32) / np.sqrt(K)
+    for bits, qt in ((8, quantize_rowwise(wd)), (4, quantize_rowwise4(wd))):
+        for M in sz["gemm"]["Ms"]:
+            x = jax.random.normal(jax.random.fold_in(kx, M), (M, K),
+                                  jnp.bfloat16)
+            print(f"  mixed GEMM int{bits} {M}x{K}x{N}")
+            y, t = timed(jax.jit(mixed_matmul), x, qt)
+            ref, t_ref = timed(jax.jit(dequant_matmul_reference), x, qt)
+            print(f"    pallas: {ms(t)}\n    xla dequant+matmul: {ms(t_ref)}")
+            close("out", y, ref, BF16_REL)
+
+
+# --------------------------------------------------------------------------
+# trainer
+# --------------------------------------------------------------------------
+
+def train_run(sz, seed, devices, mesh, stage, label):
+    """A few steps of GPT-2-small on one repeated batch; returns
+    (losses, engine) with the engine still holding its state."""
+    import deepspeed_tpu as ds
+    from deepspeed_tpu.comm import MeshTopology
+    from deepspeed_tpu.config import MeshConfig
+    from deepspeed_tpu.models import build_model
+    from deepspeed_tpu.runtime.dataloader import synthetic_lm_data
+
+    t = sz["train"]
+    model = build_model("gpt2", seed=seed, **t["overrides"])
+    topo = MeshTopology.build(MeshConfig(**mesh), devices=devices)
+    engine = ds.initialize(model=model, topology=topo, config={
+        "train_micro_batch_size_per_device": t["batch"] // len(devices),
+        "optimizer": {"type": "adamw", "params": {"lr": 3e-4}},
+        "bf16": {"enabled": True},
+        "zero_optimization": {"stage": stage},
+        "gradient_clipping": 1.0,
+        "steps_per_print": 10_000,
+        "seed": seed,
+    })
+    check(engine.train_batch_size == t["batch"],
+          f"global batch {engine.train_batch_size} != {t['batch']}")
+    batch = synthetic_lm_data(model.config.vocab_size, t["batch"], t["seq"],
+                              seed=seed)
+    losses, times = [], []
+    for _ in range(t["steps"]):
+        t0 = time.perf_counter()
+        losses.append(float(engine.train_batch(batch)["loss"]))
+        times.append(time.perf_counter() - t0)
+    cfg = model.config
+    print(f"  {label}: gpt2 L{cfg.num_layers} d{cfg.d_model} "
+          f"vocab {cfg.vocab_size} seq {t['seq']} batch {t['batch']} bf16 "
+          f"ZeRO-{stage} mesh {mesh}")
+    print(f"    losses {[round(x, 4) for x in losses]}")
+    print(f"    step seconds {[round(x, 3) for x in times]} "
+          "(the first includes the compile)")
+    check(np.isfinite(losses).all(), f"{label}: non-finite loss {losses}")
+    check(losses[-1] < losses[0],
+          f"{label}: loss did not fall on a repeated batch: {losses}")
+    return losses, engine, batch
+
+
+# --------------------------------------------------------------------------
+# server
+# --------------------------------------------------------------------------
+
+def random_model(cfg, seed):
+    """The preset's own initializer, cast to bf16 inside one jit so the
+    fp32 tensors never all exist at once (a dense fp32 init of these
+    widths does not fit beside its bf16 copy)."""
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.models.transformer import Model, init_params
+
+    axes = {}
+
+    def init(key):
+        params, axes["axes"] = init_params(cfg, key)
+        return jax.tree.map(lambda x: x.astype(jnp.bfloat16), params)
+
+    params = jax.jit(init)(jax.random.PRNGKey(seed))
+    return Model.from_params(cfg, params, param_axes=axes["axes"])
+
+
+def serve_config(sz, **kw):
+    from deepspeed_tpu.inference import InferenceConfig
+
+    s = sz["serve"]
+    return InferenceConfig(token_budget=s["token_budget"],
+                           max_seqs=s["max_seqs"], kv_block_size=s["block"],
+                           num_kv_blocks=s["blocks"], attn_impl="auto",
+                           mixed_gemm="auto", **kw)
+
+
+def make_prompts(sz, vocab, seed):
+    r = np.random.RandomState(seed)
+    return {uid: [int(t) for t in r.randint(0, vocab, n)]
+            for uid, n in enumerate(sz["serve"]["prompt_lens"])}
+
+
+def prefill_logits(eng, step, prompts):
+    """Last-token logits of each prompt through the engine's paged
+    prefill — the logits-returning sibling of the serving step
+    (``InferenceEngine._build_step``), the idiom of
+    tests/test_inference_tp.py.  Leaves the engine as it found it."""
+    eng.state.reset_prefix_cache()      # a full prefill, not cache hits
+    for uid, toks in prompts.items():
+        eng.put(uid, toks)
+    sched = eng._schedule()
+    check(sorted(u for u, _ in sched) == sorted(prompts),
+          "the prompts did not fit one prefill step")
+    batch = eng._stage(eng.state.build_batch(sched, eng.icfg.token_budget))
+    logits, eng.state.kv = step(eng.params, eng._quant, eng.state.kv, batch)
+    rows = {uid: np.asarray(logits[eng.state.slot(uid)], np.float32)
+            for uid in prompts}
+    for uid in prompts:
+        eng.flush(uid)
+    return rows
+
+
+def reference_logits(model, prompts):
+    """Plain non-paged forward of the same parameters: prompts right-
+    padded to one length (causal, so the padding changes nothing before
+    it), logits read at each prompt's last token."""
+    import jax
+    import jax.numpy as jnp
+
+    width = max(len(p) for p in prompts.values())
+    ids = np.zeros((len(prompts), width), np.int32)
+    for i, p in enumerate(prompts.values()):
+        ids[i, :len(p)] = p
+    last = jnp.asarray([len(p) - 1 for p in prompts.values()])
+
+    @jax.jit
+    def fwd(params, ids):
+        logits = model.apply(params, ids)
+        return logits[jnp.arange(len(prompts)), last].astype(jnp.float32)
+
+    out = np.asarray(fwd(model.params, jnp.asarray(ids)))
+    return dict(zip(prompts, out))
+
+
+def report_probe(eng):
+    for what, res in eng.probe_times.items():
+        print(f"    engine race [{what}]: chose {min(res, key=res.get)} of "
+              f"{ {k: round(1e3 * v, 2) for k, v in res.items()} } "
+              "(ms per 3 steps)")
+
+
+def check_first_tokens(label, tokens, logits, ref, rel):
+    """Logits to tolerance; tokens wherever the reference's top-2 margin
+    is wider than the measured logit difference could bridge."""
+    for uid in tokens:
+        close(f"{label} prompt {uid} first-token logits", logits[uid],
+              ref[uid], rel)
+        check(tokens[uid][0] == int(np.argmax(logits[uid])),
+              f"{label}: prompt {uid}'s first token is not the argmax of "
+              "the engine's own prefill logits")
+        top2 = np.sort(ref[uid])[-2:]
+        margin = float(top2[1] - top2[0])
+        reach = 2 * float(np.abs(logits[uid] - ref[uid]).max())
+        if margin > reach:
+            check(tokens[uid][0] == int(np.argmax(ref[uid])),
+                  f"{label}: prompt {uid}'s first token differs from the "
+                  f"reference argmax at margin {margin:.4g} > {reach:.4g}")
+            print(f"    prompt {uid}: first token {tokens[uid][0]} is the "
+                  f"reference's argmax (top-2 margin {margin:.4g})")
+        else:
+            print(f"    prompt {uid}: top-2 margin {margin:.4g} is within "
+                  f"twice the logit difference; token not compared")
+
+
+def serve_phase(sz, seed):
+    from deepspeed_tpu.gateway import GatewayConfig, spawn_gateway
+    from deepspeed_tpu.inference import InferenceEngine, SamplingParams
+    from deepspeed_tpu.models.presets import build_config
+    from tools.loadgen import http_completion
+
+    s = sz["serve"]
+    cfg = build_config("llama3-8b", num_layers=s["layers"], **s["overrides"])
+    t0 = time.perf_counter()
+    model = random_model(cfg, seed)
+    print(f"  llama3-8b widths d{cfg.d_model} H{cfg.num_heads}/"
+          f"{cfg.num_kv_heads}x{cfg.head_dim} ff{cfg.d_ff} "
+          f"vocab {cfg.vocab_size}, DEPTH {cfg.num_layers} of 32, bf16; "
+          f"weights made in {time.perf_counter() - t0:.1f} s")
+    eng = InferenceEngine(model, serve_config(sz))
+    prompts = make_prompts(sz, cfg.vocab_size, seed)
+    n = s["new_tokens"]
+    greedy = SamplingParams(temperature=0.0, max_new_tokens=n)
+
+    t0 = time.perf_counter()
+    local = eng.generate({u: list(p) for u, p in prompts.items()}, greedy)
+    t_cold = time.perf_counter() - t0
+    report_probe(eng)
+    check(all(len(v) == n and all(0 <= t < cfg.vocab_size for t in v)
+              for v in local.values()), f"bad in-process tokens {local}")
+    print(f"    in-process generate: {len(prompts)} prompts of "
+          f"{[len(p) for p in prompts.values()]} tokens -> {n} new each, "
+          f"{t_cold:.1f} s with the race and compiles")
+
+    logits = prefill_logits(eng, eng._build_step(), prompts)
+    check_first_tokens("bf16", local, logits, reference_logits(model, prompts),
+                       LOGIT_REL)
+
+    # the same engine behind the gateway, over loopback (last: stopping
+    # the gateway drains the engine for good)
+    h = spawn_gateway(eng, GatewayConfig(
+        sampling=SamplingParams(temperature=0.0, max_new_tokens=1 << 30)))
+    try:
+        t0 = time.perf_counter()
+        wire = {u: http_completion(h.host, h.port,
+                                   {"uid": 1000 + u, "prompt": p,
+                                    "max_tokens": n, "stream": True},
+                                   timeout=600.0)
+                for u, p in prompts.items()}
+        t_http = time.perf_counter() - t0
+    finally:
+        h.stop()
+    for u, res in wire.items():
+        check(res["code"] == 200, f"HTTP {res['code']} for prompt {u}")
+        check(res["tokens"] == local[u],
+              f"prompt {u}: HTTP tokens {res['tokens']} != in-process "
+              f"{local[u]}")
+    print(f"    POST /v1/completions x{len(wire)} over loopback: tokens "
+          f"equal the in-process ones, {t_http:.2f} s, wire TTFT ms "
+          f"{[round(r['ttft_ms'], 1) for r in wire.values()]}")
+
+
+
+def serve_int8_phase(sz, seed):
+    """int8 weights at the same widths, so the engine's mixed-GEMM race
+    (Pallas VMEM-dequant kernel vs XLA's fused dequant) runs as well."""
+    from deepspeed_tpu.inference import InferenceEngine, SamplingParams
+    from deepspeed_tpu.models.presets import build_config
+
+    s = sz["serve"]
+    cfg = build_config("llama3-8b", num_layers=s["int8_layers"],
+                       **s["overrides"])
+    model = random_model(cfg, seed + 1)
+    eng = InferenceEngine(model, serve_config(sz, weight_quant="int8"))
+    prompts = make_prompts(sz, cfg.vocab_size, seed + 1)
+    print(f"  llama3-8b widths, DEPTH {cfg.num_layers} of 32, int8 weights")
+    out = eng.generate({u: list(p) for u, p in prompts.items()},
+                       SamplingParams(temperature=0.0,
+                                      max_new_tokens=s["new_tokens"]))
+    report_probe(eng)
+    check(all(len(v) == s["new_tokens"] for v in out.values()),
+          f"bad tokens {out}")
+    logits = prefill_logits(eng, eng._build_step(), prompts)
+    check_first_tokens("int8", out, logits, reference_logits(model, prompts),
+                       INT8_LOGIT_REL)
+
+
+# --------------------------------------------------------------------------
+# four chips: the sharded paths and what they are compared with
+# --------------------------------------------------------------------------
+
+def spread(name, tree, n):
+    """Every sharded array of ``tree`` has shards on ``n`` distinct
+    devices at 1/n of its size; returns the sharded share of the bytes."""
+    import jax
+
+    total = sharded = 0
+    for x in jax.tree.leaves(tree):
+        if not isinstance(x, jax.Array):
+            continue
+        total += x.nbytes
+        if x.sharding.is_fully_replicated:
+            continue
+        shards = x.addressable_shards
+        check(len({s.device for s in shards}) == n,
+              f"{name}: {x.shape} has shards on "
+              f"{len({s.device for s in shards})} devices")
+        check(all(s.data.size * n == x.size for s in shards),
+              f"{name}: {x.shape} shard {shards[0].data.shape} is not 1/{n}")
+        sharded += x.nbytes
+    print(f"    {name}: {sharded / max(total, 1):.1%} of {total / 1e6:.1f} MB "
+          f"is sharded over {n} devices, each shard 1/{n} of its array")
+    return sharded / max(total, 1)
+
+
+def all_hold_bytes(devs):
+    """``bytes_in_use`` is non-zero on every device (where the backend
+    reports memory at all: the CPU rehearsal's does not)."""
+    used = [(d.memory_stats() or {}).get("bytes_in_use") for d in devs]
+    print(f"    bytes_in_use per device: {used}")
+    if devs[0].platform == "tpu":
+        check(all(used), "a device holds nothing")
+
+
+def collectives_in(compiled_text):
+    ops = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
+           "collective-permute")
+    return {op: compiled_text.count(f" {op}(") + compiled_text.count(
+        f" {op}-start(") for op in ops if f" {op}" in compiled_text}
+
+
+def four_chip_phases(sz, seed):
+    import jax
+
+    from deepspeed_tpu.comm import MeshTopology
+    from deepspeed_tpu.config import MeshConfig
+    from deepspeed_tpu.inference import InferenceEngine, SamplingParams
+    from deepspeed_tpu.models.presets import build_config
+
+    devs = jax.devices()[:4]
+    with phase("train ZeRO-3 fsdp=4 vs one device"):
+        sharded, eng4, batch = train_run(sz, seed, devs, {"fsdp": 4}, 3,
+                                         "fsdp=4")
+        share = spread("ZeRO-3 train state", eng4.state, 4)
+        check(share > 0.9, f"only {share:.1%} of the train state is sharded")
+        step = eng4._pick_train_step()
+        rng = jax.random.PRNGKey(0)
+        batch = eng4.shard_batch(batch)
+        coll = collectives_in(
+            step.lower(eng4.state, batch, rng).compile().as_text())
+        print(f"    collectives in the compiled ZeRO-3 step: {coll}")
+        check(coll, "the ZeRO-3 step compiled without a collective")
+        all_hold_bytes(devs)
+        del eng4, step, batch
+        gc.collect()
+        single, eng1, _ = train_run(sz, seed, devs[:1], {"data": 1}, 3,
+                                    "one device")
+        del eng1
+        for i, (a, b) in enumerate(zip(sharded, single)):
+            check(abs(a - b) <= LOSS_REL * abs(b),
+                  f"step {i}: fsdp=4 loss {a} vs one-device {b}")
+        print(f"    losses agree within {LOSS_REL}: max relative difference "
+              f"{max(abs(a - b) / abs(b) for a, b in zip(sharded, single)):.3g}")
+
+    with phase("serve tensor=4 vs one chip"):
+        s = sz["serve"]
+        cfg = build_config("llama3-8b", num_layers=s["layers"],
+                           **s["overrides"])
+        model = random_model(cfg, seed)
+        prompts = make_prompts(sz, cfg.vocab_size, seed)
+        greedy = SamplingParams(temperature=0.0,
+                                max_new_tokens=s["new_tokens"])
+        print(f"  llama3-8b widths, DEPTH {cfg.num_layers} of 32, bf16")
+        one = InferenceEngine(model, serve_config(sz))
+        ref = one.generate({u: list(p) for u, p in prompts.items()}, greedy)
+        report_probe(one)
+        topo = MeshTopology.build(MeshConfig(tensor=4), devices=devs)
+        tp = InferenceEngine(model, serve_config(sz), topology=topo)
+        out = tp.generate({u: list(p) for u, p in prompts.items()}, greedy)
+        report_probe(tp)
+        share = spread("TP weights", tp.params, 4)
+        check(share > 0.9, f"only {share:.1%} of the weights is sharded")
+        check(spread("TP KV cache", tp.state.kv, 4) == 1.0,
+              "the KV cache is not sharded")
+        all_hold_bytes(devs)
+        step1, step4 = one._build_step(), tp._build_step()
+        lg4 = prefill_logits(tp, step4, prompts)
+        lg1 = prefill_logits(one, step1, prompts)
+        for u in prompts:
+            close(f"prompt {u} first-token logits tp4 vs one chip",
+                  lg4[u], lg1[u], LOGIT_REL)
+        tp.put(0, prompts[0])
+        b = tp._stage(tp.state.build_batch(tp._schedule(),
+                                           tp.icfg.token_budget))
+        coll = collectives_in(step4.lower(tp.params, tp._quant, tp.state.kv,
+                                          b).compile().as_text())
+        tp.flush(0)
+        print(f"    collectives in the compiled TP step: {coll}")
+        check(coll, "the TP step compiled without a collective")
+        for u in prompts:
+            if out[u] == ref[u]:
+                print(f"    prompt {u}: {len(out[u])} greedy tokens equal")
+                continue
+            i = next(i for i, (a, b) in enumerate(zip(out[u], ref[u]))
+                     if a != b)
+            prefix = {u: prompts[u] + ref[u][:i]}
+            a = prefill_logits(tp, step4, prefix)[u]
+            b = prefill_logits(one, step1, prefix)[u]
+            print(f"    prompt {u}: parts at token {i} "
+                  f"({out[u][i]} vs {ref[u][i]})")
+            close(f"prompt {u} logits at the divergence", a, b, LOGIT_REL)
+            check(abs(float(b[out[u][i]] - b[ref[u][i]]))
+                  <= 2 * float(np.abs(a - b).max()),
+                  f"prompt {u}: the two tokens are further apart than the "
+                  "logit difference could bridge")
+
+
+# --------------------------------------------------------------------------
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
+                    help="4: run only the sharded paths (ZeRO-3 over fsdp, "
+                    "tensor-parallel serving) and what they are compared "
+                    "with, in one process driving four chips")
+    ap.add_argument("--rehearse", action="store_true",
+                    help="tiny sizes, any platform JAX reports (kernels in "
+                    "interpret mode off-TPU); the last line names the "
+                    "platform it really ran on")
+    ap.add_argument("--seed", type=int, default=0)
+    args = ap.parse_args(argv)
+
+    t_start = time.perf_counter()
+    import jax
+
+    from deepspeed_tpu.platform.compile_cache import enable_compile_cache
+
+    devices = jax.devices()         # raises when the backend cannot start
+    dev = devices[0]
+    if dev.platform != "tpu" and not args.rehearse:
+        print(f"chip_smoke: needs a TPU, JAX reports {dev.platform!r} "
+              "(--rehearse runs the tiny-size rehearsal)", file=sys.stderr)
+        return 2
+    if len(devices) < args.chips:
+        print(f"chip_smoke: --chips {args.chips} needs {args.chips} devices, "
+              f"JAX reports {len(devices)}", file=sys.stderr)
+        return 2
+    # XLA:CPU executables are tied to the host's CPU features and cost
+    # little to rebuild: the rehearsal leaves the cache alone off-TPU
+    cache_dir = enable_compile_cache() if dev.platform == "tpu" else None
+    _watch_compiles()
+    sz = TINY if args.rehearse else REAL
+    print(f"chip_smoke: {'REHEARSAL (tiny sizes) ' if args.rehearse else ''}"
+          f"on {dev.platform} / {dev.device_kind} x{len(devices)}, "
+          f"jax {jax.__version__}, seed {args.seed}, compile cache "
+          f"{cache_dir}", flush=True)
+
+    if args.chips == 4:
+        four_chip_phases(sz, args.seed)
+    else:
+        with phase("block_until_ready"):
+            barrier_phase(sz)
+        with phase("profiler window"):
+            profiler_phase(sz)
+        with phase("kernels vs XLA"):
+            kernels_phase(sz, args.seed)
+        with phase("train"):
+            train_run(sz, args.seed, devices[:1], {"data": 1}, 1, "trainer")
+        with phase("serve"):
+            serve_phase(sz, args.seed)
+        with phase("serve-int8"):
+            serve_int8_phase(sz, args.seed)
+
+    print(f"total {time.perf_counter() - t_start:.1f} s; compile "
+          f"{COMPILE['compile_s']:.1f} s over {COMPILE['cache_misses']} "
+          f"cache misses, {COMPILE['cache_hits']} cache hits read in "
+          f"{COMPILE['cache_read_s']:.1f} s; "
+          f"peak_bytes_in_use={peak_bytes()}", flush=True)
+    if FAILED:
+        print(f"chip_smoke: FAILED phases: {FAILED}", file=sys.stderr)
+        return 1
+    print(json.dumps({"ok": True, "device": {
+        "platform": dev.platform, "kind": dev.device_kind,
+        "count": len(devices) if args.chips == 1 else args.chips}}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -27,8 +27,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from ..compat import shard_map
 
 from .comms_logging import comms_logger
 from .mesh import MeshTopology

@@ -44,9 +44,10 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
+from jax.lax import axis_size
 from jax.sharding import PartitionSpec as P
 
-from ..compat import axis_size, shard_map
 
 STRATEGIES = ("psum", "ring")
 

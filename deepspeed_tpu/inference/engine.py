@@ -441,6 +441,9 @@ class InferenceEngine:
         self._cow_fn = None           # lazy jitted prefix-cache block copy
         self._restage_fn = None       # lazy jitted tier->HBM block upload
         self._pstep_fns: Dict[tuple, object] = {}  # (bucket, sampler_key)
+        # probe label -> {variant: seconds per 3 steps}, for every race
+        # this engine ran itself (a memoized verdict leaves no entry)
+        self.probe_times: Dict[str, Dict[str, float]] = {}
         self._burst_fns: Dict[tuple, object] = {}
         # serving programs that have COMPLETED at least one call: only
         # these run under the dispatch watchdog — a first call may
@@ -1439,21 +1442,14 @@ class InferenceEngine:
         cfg = self.cfg
         bs = self.icfg.kv_block_size
         fw, mbs = self._resolve_fw(mbs)
-        repl = self._repl
 
         def sample_fn(logits, keys):
-            if repl is not None:
-                # pin the logits replicated BEFORE the categorical: on
-                # legacy jax the threefry bits behind temperature
-                # sampling are sharding-dependent, so a vocab-sharded
-                # logits tensor (GSPMD's natural layout for the serial
-                # unembed) and a replicated one (the shard_map overlap
-                # path's output) would sample DIFFERENT tokens from
-                # bitwise-identical logits — this constraint makes
-                # seeded streams invariant to the comm plan (the gather
-                # it forces happens either way for the replicated
-                # token output)
-                logits = jax.lax.with_sharding_constraint(logits, repl)
+            # no sharding constraint needed for seeded streams to be
+            # invariant to the comm plan: jax's partitionable threefry
+            # (the default) draws the same bits whether the logits
+            # arrive vocab-sharded (serial unembed) or replicated (the
+            # shard_map overlap path) — tests/test_comm_overlap.py
+            # holds on-vs-off seeded tokens equal
             return sample_rows(logits, sampling, keys)
 
         def pstep(params, quant, kv, batch: RaggedBatch, prev_toks, rng):
@@ -1476,7 +1472,8 @@ class InferenceEngine:
     def _probe_variants(self, label: str, variants):
         """Race full ragged steps, one per variant (name -> extra
         ragged_forward kwargs), on the real compiled shapes; returns
-        {name: seconds-per-3-steps} for whatever survived."""
+        {name: seconds-per-3-steps}.  On a TPU backend a variant that
+        fails to compile or run raises; elsewhere it is dropped."""
         import time
 
         cfg, bs, mbs = self.cfg, self.icfg.kv_block_size, \
@@ -1549,7 +1546,15 @@ class InferenceEngine:
                     logits, kv = f(self.params, self._quant, kv, batch)
                 float(jnp.sum(logits))      # completion barrier
                 results[name] = time.perf_counter() - t0
-            except Exception as e:          # Mosaic unavailable/failed
+            except Exception as e:
+                if jax.default_backend() == "tpu":
+                    # on the chip every variant must compile and run:
+                    # losing the race on time is a probe outcome, a
+                    # kernel the chip refuses is a bug — never a silent
+                    # switch to the other path
+                    raise
+                # off-TPU the Pallas variants run in interpret mode,
+                # which may not support everything the kernel does
                 logger.warning(f"{label} probe: {name} failed "
                                f"({type(e).__name__}); skipping")
         # restore a pristine zero cache (the probe wrote its fake token)
@@ -1560,19 +1565,21 @@ class InferenceEngine:
         if getattr(self, "_kv_on_host", False):
             self.state.kv = jax.device_put(self.state.kv,
                                            jax.memory.Space.Host)
-        if results:
-            logger.info(
-                f"{label} probe: {min(results, key=results.get)} "
-                f"({ {k: round(v * 1e3, 1) for k, v in results.items()} }"
-                " ms/3 steps)")
+        if not results:
+            raise RuntimeError(f"{label} probe: every variant failed")
+        self.probe_times[label] = results
+        logger.info(
+            f"{label} probe: {min(results, key=results.get)} "
+            f"({ {k: round(v * 1e3, 1) for k, v in results.items()} }"
+            " ms/3 steps)")
         return results
 
     def _probe_attn_impl(self) -> str:
         """Time one ragged forward per implementation on the real compiled
-        shapes and keep the winner (the Pallas streaming kernel wins on
-        bare-metal TPUs; the XLA gather path wins on CPU meshes and some
-        virtualized/tunneled chips where Mosaic underperforms).  Results
-        are memoized per (backend, shape signature) for the process."""
+        shapes and keep the winner (which one wins depends on backend
+        and shapes — on CPU meshes the interpret-mode kernel always
+        loses).  Results are memoized per (backend, shape signature)
+        for the process."""
         key = self._probe_key("attn")
         cached = _PROBE_CACHE.get(key)
         if cached is not None:
@@ -1580,7 +1587,7 @@ class InferenceEngine:
         results = self._probe_variants(
             "paged-attention",
             {"xla": {"attn_impl": "xla"}, "pallas": {"attn_impl": "pallas"}})
-        best = min(results, key=results.get) if results else "xla"
+        best = min(results, key=results.get)
         _PROBE_CACHE[key] = best
         return best
 
@@ -1637,8 +1644,7 @@ class InferenceEngine:
                 "mixed-gemm",
                 {"dequant": {"attn_impl": attn_impl, "mixed_gemm": False},
                  "mixed": {"attn_impl": attn_impl, "mixed_gemm": True}})
-            cached = (min(results, key=results.get) == "mixed"
-                      if results else False)
+            cached = min(results, key=results.get) == "mixed"
             _PROBE_CACHE[key] = cached
         return cached
 
@@ -1843,7 +1849,8 @@ class InferenceEngine:
         }
 
     # ------------------------------------------------------------------
-    def _schedule(self) -> List[tuple]:  # tpulint: serving-loop
+    def _schedule(self, room: Optional[Dict[int, int]] = None
+                  ) -> List[tuple]:  # tpulint: serving-loop
         """Dynamic SplitFuse + overload policy: pack the fixed token
         budget — decode tokens first (latency), then prompt chunks
         (throughput) — while *reserving* KV blocks and slots as requests
@@ -1866,7 +1873,13 @@ class InferenceEngine:
         or slot table starves a candidate, a strictly-lower-priority
         running victim is preempted-by-eviction (``_preempt``) to make
         room.  With the default config every knob is inert and this is
-        exactly the legacy FIFO SplitFuse packer."""
+        exactly the legacy FIFO SplitFuse packer.
+
+        ``room``: uid -> tokens its driver still wants (generate()'s
+        count-based stop).  A verify window emits up to 1 + len(draft)
+        tokens, so drafts are capped to fit: the engine never emits —
+        or counts — a token its driver would discard (bursts are capped
+        by the same room in ``_generate_sync``)."""
         budget = self.icfg.token_budget
         bs = self.icfg.kv_block_size
         ocfg = self.ocfg
@@ -1955,6 +1968,8 @@ class InferenceEngine:
                 # spec_max_draft — drafts compete with prefill chunks
                 # for the same fixed SplitFuse budget
                 limit = min(self._n_verify - 1, budget - 1, ctx_rem - 1)
+                if room is not None and uid in room:
+                    limit = min(limit, room[uid] - 1)
                 if limit > 0:
                     draft = self._spec.propose(uid, toks[0], limit)
             n = min(len(toks), budget, ctx_rem)
@@ -2921,17 +2936,18 @@ class InferenceEngine:
             return None
         return lambda: rng
 
-    def _dispatch(self, sampling: SamplingParams,
-                  rng=None) -> Optional[_InFlight]:  # tpulint: serving-loop
+    def _dispatch(self, sampling: SamplingParams, rng=None,
+                  room: Optional[Dict[int, int]] = None
+                  ) -> Optional[_InFlight]:  # tpulint: serving-loop
         """Schedule, stage, and launch one serving step WITHOUT reading
         the sampled tokens back; returns the in-flight record (tokens
         still on device) or None when nothing is schedulable.  ``rng``:
         an explicit PRNG key, a zero-arg callable invoked only once a
         step is known to launch, or None (engine-internal key stream
-        when the sampler needs one)."""
+        when the sampler needs one).  ``room``: see ``_schedule``."""
         self._ensure_alive()
         t0 = time.perf_counter()
-        sched = self._schedule()
+        sched = self._schedule(room)
         self._close_ctx_exhausted()
         if not sched:
             # an idle round still moves tier work: evictions queued by
@@ -3624,6 +3640,16 @@ class InferenceEngine:
             return self._generate_pipelined(done, active, sampling, rng)
         return self._generate_sync(done, active, sampling, rng)
 
+    def _draft_room(self, done: Dict[int, List[int]], active: set,
+                    sampling: SamplingParams) -> Optional[Dict[int, int]]:
+        """uid -> tokens still wanted, for ``_schedule``'s draft cap
+        (None on a non-speculative engine: nothing to cap).  Exact at
+        schedule time: a row only drafts from a concrete fed token,
+        which its collect put AFTER extending ``done``."""
+        if self._spec is None:
+            return None
+        return {u: sampling.max_new_tokens - len(done[u]) for u in active}
+
     def _generate_sync(self, done: Dict[int, List[int]], active: set,
                        sampling: SamplingParams,
                        rng: Optional[jax.Array]
@@ -3658,7 +3684,8 @@ class InferenceEngine:
             else:
                 # dispatch + collect directly: a verify window's step
                 # emits a LIST per uid and every token must reach done
-                st = self._dispatch(sampling, draw)
+                st = self._dispatch(sampling, draw,
+                                    self._draft_room(done, active, sampling))
                 outs = self._collect(st) if st is not None else {}
             # sequences that hit the context limit end their generation
             for uid in list(self._ctx_exhausted):
@@ -3728,7 +3755,8 @@ class InferenceEngine:
                 finishing -= reaped
             # fill the pipeline while there is schedulable work
             while len(inflight) < depth and any(self._pending.values()):
-                st = self._dispatch(sampling, draw)
+                st = self._dispatch(sampling, draw,
+                                    self._draft_room(done, active, sampling))
                 # sequences that hit the context limit stop being
                 # scheduled; finish them once their last sampled token
                 # (possibly still in flight) has been emitted

@@ -24,8 +24,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 
-from ..compat import shard_map
 from ..comm.overlap import (ServingComm, shard_matmul_allgather,
                             shard_matmul_allreduce)
 from ..models import layers as L
@@ -138,7 +138,7 @@ def _paged_attention_pallas(kv_layer, q, batch: RaggedBatch,
 # one-shot gather cap: [T, C, 2, Hkv, D] materializes T*C*2*Hkv*D
 # elements; past this many BYTES the chunked online-softmax path runs
 # instead (bench shapes at GPT-2s blew HBM: 3.2 GB gather -> 18.5 G
-# peak on a 15.75 G v5e — BENCH_r02's probe JaxRuntimeError)
+# peak on a 15.75 G v5e)
 _ONE_SHOT_GATHER_BYTES = 512 * 1024 * 1024
 
 

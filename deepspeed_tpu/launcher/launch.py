@@ -6,7 +6,8 @@ On TPU there is exactly one process per host: this module reads the
 coordinator env set by the runner, initializes ``jax.distributed``, and
 execs the user script in-process.  Signal handling forwards
 SIGTERM/SIGINT to the child process group when the script is run as a
-subprocess (``--as_subprocess``).
+subprocess (``--as_subprocess``) — that parent never touches JAX, so
+the child is still the only process asking for the host's chips.
 """
 
 from __future__ import annotations

@@ -8,6 +8,9 @@ node-local ``launcher/launch.py:133``).  The structural difference
 ``jax.distributed.initialize`` — there is no per-device process spawn, so
 the node-local launcher sets coordinator env vars and execs the script
 once, and "slots" count hosts' local devices only for bookkeeping.
+A chip belongs to one process: the runner and the node-local launcher
+never initialize a JAX backend themselves (they only spawn), so the one
+worker they start per host is the only process that asks for its chips.
 
 CLI::
 

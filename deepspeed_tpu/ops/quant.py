@@ -25,8 +25,7 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from ..compat import axis_size
+from jax.lax import axis_size
 
 
 @jax.tree_util.register_pytree_node_class
@@ -98,11 +97,12 @@ def unpack_nibbles(p: jax.Array):
     """(lo, hi) int8 nibbles of a packed byte array, sign-extended from
     4-bit two's complement.  Pure jnp — shared by the grouped unpack,
     the rowwise4 dequant, and the Pallas mixed-GEMM kernel."""
-    u = p.astype(jnp.uint8)
-    lo = (u & 0x0F).astype(jnp.int8)
-    hi = ((u >> 4) & 0x0F).astype(jnp.int8)
-    lo = jnp.where(lo > 7, lo - 16, lo)
-    hi = jnp.where(hi > 7, hi - 16, hi)
+    # widened to int32 first: the TPU's kernel compiler has no 8-bit
+    # shifts.  On the sign-extended byte, an arithmetic >> 4 IS the
+    # sign-extended high nibble, and << 28 >> 28 the low one.
+    w = p.astype(jnp.int32)
+    lo = ((w << 28) >> 28).astype(jnp.int8)
+    hi = (w >> 4).astype(jnp.int8)
     return lo, hi
 
 

@@ -50,7 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from ..compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..comm.mesh import BATCH_AXES, MeshTopology, PIPE_AXIS, SEQ_AXIS

@@ -27,7 +27,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
-from ..compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..comm.mesh import BATCH_AXES, MeshTopology, SEQ_AXIS, TENSOR_AXIS

@@ -42,9 +42,10 @@ from ..utils.logging import logger
 from .metrics import MetricsRegistry
 
 # bf16 peak FLOP/s and HBM bandwidth (bytes/s) per chip generation —
-# the same table bench.py uses for its one-shot MFU, here feeding the
-# live gauges.  Matched by substring against device_kind (lowercased);
-# unknown kinds (CPU fallback included) yield None -> absent gauges.
+# THE one table: bench.py reads it for its one-shot MFU (and raises on
+# an unknown kind), the live gauges read it here.  Matched by substring
+# against device_kind (lowercased); unknown kinds (the CPU included)
+# yield None -> absent gauges.
 PEAK_FLOPS = {"v4": 275e12, "v5 lite": 197e12, "v5e": 197e12,
               "v5p": 459e12, "v5": 459e12, "v6e": 918e12, "v6": 918e12}
 PEAK_HBM_BW = {"v4": 1.2e12, "v5 lite": 0.82e12, "v5e": 0.82e12,

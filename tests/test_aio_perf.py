@@ -1,4 +1,4 @@
-"""NVMe/aio throughput microbenchmark (VERDICT r2 item 9).
+"""NVMe/aio throughput microbenchmark.
 
 The reference claims ~10 GB/s for DeepNVMe on real NVMe arrays
 (blogs/deepspeed-gds/README.md:50); that number is hardware-bound, so

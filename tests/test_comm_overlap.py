@@ -28,9 +28,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from deepspeed_tpu.compat import shard_map
 from deepspeed_tpu.comm import overlap as ov
 from deepspeed_tpu.ops.quant import (quantized_all_reduce,
                                      quantized_psum_scatter)

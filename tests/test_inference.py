@@ -440,7 +440,7 @@ class TestChunkedPagedAttention:
     def test_chunked_matches_one_shot(self, monkeypatch):
         """Past the gather-bytes cap the XLA path streams one KV block at
         a time (online softmax); greedy decode must match the one-shot
-        gather exactly (fix for the BENCH_r02 HBM OOM at bench shapes)."""
+        gather exactly (fix for an HBM OOM at the bench's GPT-2 shapes)."""
         from deepspeed_tpu.inference import model as im
 
         m = tiny_model()

@@ -6,7 +6,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from deepspeed_tpu import compat as _compat
 import deepspeed_tpu as ds
 from deepspeed_tpu.runtime.onebit import (onebit_adam, onebit_lamb,
                                           zero_one_adam)
@@ -67,12 +66,6 @@ class TestOnebitOptimizers:
         mags = np.unique(np.round(np.abs(np.asarray(state.m["x"])), 6))
         assert len(mags) == 1
 
-    @pytest.mark.skipif(
-        not _compat._MODERN,
-        reason="knife-edge compressed-training trajectory: the 1-bit wire "
-        "matches its numpy reference exactly, but this lr-1e-2 6-step run "
-        "diverges under jaxlib 0.4.x float scheduling (converges on "
-        "modern jax, and at lr 5e-3 or freeze_step 4 here)")
     def test_engine_integration(self):
         p, ax, loss_fn = make_mlp()
         eng = ds.initialize(loss_fn=loss_fn, params=p, param_axes=ax, config={

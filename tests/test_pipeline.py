@@ -5,7 +5,6 @@ import jax
 import numpy as np
 import pytest
 
-from deepspeed_tpu import compat as _compat
 import deepspeed_tpu as ds
 from deepspeed_tpu.models import build_model
 from deepspeed_tpu.runtime import partition_balanced
@@ -120,9 +119,6 @@ class Test1F1B:
                            d_model=64, num_heads=4, max_seq_len=32,
                            seed=seed)
 
-    @pytest.mark.skipif(
-        not _compat._MODERN,
-        reason="jaxlib 0.4.x shard_map partial-eval mishandles scalar residuals when differentiating the pipeline loss (_SpecError on a rank-0 residual); needs modern jax")
     def test_grads_match_gpipe(self):
         m = self._model()
         ids = np.random.RandomState(0).randint(0, 128, (16, 32))
@@ -153,9 +149,6 @@ class Test1F1B:
             losses.append(float(eng.train_batch({"input_ids": ids})["loss"]))
         assert losses[-1] < losses[0]
 
-    @pytest.mark.skipif(
-        not _compat._MODERN,
-        reason="jaxlib 0.4.x shard_map partial-eval mishandles scalar residuals when differentiating the pipeline loss (_SpecError on a rank-0 residual); needs modern jax")
     def test_1f1b_bounds_activation_memory(self):
         """With M >> S, 1f1b's compiled temp memory stays well below
         gpipe's (ring of min(M, 2S-1) stashes vs M live boundaries)."""
@@ -197,10 +190,6 @@ class Test1F1B:
         b = float(eng_dp.eval_batch({"input_ids": ids}))
         assert a == pytest.approx(b, rel=1e-3)
 
-    @pytest.mark.skipif(
-        not _compat._MODERN,
-        reason="seed-locked losses[-1]<losses[0] short-run assert flips "
-        "under legacy XLA float scheduling (0.01 loss delta)")
     def test_pipe_seq_1f1b_trains(self):
         m = self._model(layers=2)
         eng = ds.initialize(model=m, config=base_cfg(
@@ -241,9 +230,6 @@ class TestPipelineMoE:
         b = float(eng_ep.eval_batch({"input_ids": ids}))
         assert a == pytest.approx(b, rel=1e-3)
 
-    @pytest.mark.skipif(
-        not _compat._MODERN,
-        reason="jaxlib 0.4.x shard_map partial-eval mishandles scalar residuals when differentiating the pipeline loss (_SpecError on a rank-0 residual); needs modern jax")
     def test_trains(self):
         m = self._model()
         eng = ds.initialize(model=m, config=base_cfg(
@@ -257,9 +243,6 @@ class TestPipelineMoE:
                   for _ in range(6)]
         assert losses[-1] < losses[0]
 
-    @pytest.mark.skipif(
-        not _compat._MODERN,
-        reason="jaxlib 0.4.x shard_map partial-eval mishandles scalar residuals when differentiating the pipeline loss (_SpecError on a rank-0 residual); needs modern jax")
     def test_1f1b_moe_matches_gpipe(self):
         """1F1B's eager VJP carries the aux cotangent too: loss and
         grad norm match gpipe+MoE."""

@@ -5,21 +5,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from deepspeed_tpu import compat as _compat
 import deepspeed_tpu as ds
-from deepspeed_tpu.compat import shard_map
 from deepspeed_tpu.ops.quant import (QuantizedTensor, dequantize, quantize,
                                      quantized_all_gather,
                                      quantized_psum_scatter,
                                      quantized_reduction)
 from tests.simple_model import make_batch, make_mlp
-
-# jaxlib 0.4.x CHECK-crashes (process abort, not a catchable error) in
-# backend_compile on the stage-3 qgZ partial-manual shard_map program;
-# modern jax compiles it fine
-_LEGACY_JAX = not _compat._MODERN
 
 
 class TestQuantize:
@@ -122,13 +116,8 @@ class TestZeroPP:
     @pytest.mark.parametrize("stage,mesh", [
         (1, {"fsdp": 8}),
         (2, {"data": 2, "fsdp": 4}),
-        pytest.param(3, {"data": 2, "fsdp": 4}, marks=pytest.mark.skipif(
-            _LEGACY_JAX, reason="XLA CHECK-crash compiling stage-3 qgZ "
-            "on jaxlib 0.4.x")),
-        pytest.param(2, {"data": 2, "fsdp": 2, "tensor": 2},  # TP auto-sharded
-                     marks=pytest.mark.skipif(
-            _LEGACY_JAX, reason="XLA CHECK-crash compiling qgZ with a "
-            "tensor-parallel auto axis on jaxlib 0.4.x")),
+        (3, {"data": 2, "fsdp": 4}),
+        (2, {"data": 2, "fsdp": 2, "tensor": 2}),   # TP auto-sharded
     ])
     def test_qgz_trains_close_to_exact(self, stage, mesh):
         """qgZ: the gradient reduction runs through the int8 reduce-scatter

@@ -5,10 +5,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 import deepspeed_tpu as ds
-from deepspeed_tpu.compat import shard_map
 from deepspeed_tpu.runtime.sparse_grads import (default_capacity,
                                                 is_sparse_leaf, sparse_psum)
 

@@ -1,0 +1,150 @@
+"""The main path's Pallas kernels compile for a TPU v5e — no chip needed.
+
+The TPU compiler ships with the installed libtpu and compiles for a chip
+that is *described* (``v5e:2x2``), not attached, so every kernel the
+serving and training hot paths launch is lowered through Mosaic here at
+its real widths: a kernel the chip's compiler refuses (an op it cannot
+legalize, a mis-tiled slice, too much VMEM) fails in tier-1 instead of
+on the first chip run.  Nothing executes — this says nothing about
+results or speed; ``chip_smoke.py`` checks those on the chip.
+
+The topology is described only inside the module-scoped fixture below
+(one process at a time may hold libtpu, and every xdist worker imports
+every test file — see the on-chip-measurement guide), all such tests
+live in this one file and compile in the test's own process.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from deepspeed_tpu.models.presets import PRESETS
+from deepspeed_tpu.ops.flash_attention import flash_attention
+from deepspeed_tpu.ops.mixed_gemm import mixed_matmul
+from deepspeed_tpu.ops.paged_attention import paged_attention
+from deepspeed_tpu.ops.quant import QuantizedTensor
+
+LLAMA = PRESETS["llama3-8b"]
+GPT2 = PRESETS["gpt2"]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # tpulint: disable=silent-except — capability probe
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def on_chip(monkeypatch):
+    """Steer the kernels' interpret-mode choice (they ask
+    ``jax.default_backend()``, which says ``cpu`` here) to the compiled
+    path, with the persistent compile cache off: an executable compiled
+    for a described chip cannot be read back without one."""
+    from jax.experimental.compilation_cache import compilation_cache
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *shapes):
+    text = jax.jit(fn).lower(*shapes).compile().as_text()
+    return text.count("tpu_custom_call")
+
+
+# ---------------------------------------------------------------- paged
+# decode attention over the paged cache, one layer: the serving step's
+# token budget T, max_seqs 8, blocks of 64
+_LLAMA_PAGED = dict(T=1024, H=LLAMA["num_heads"], Hkv=LLAMA["num_kv_heads"],
+                    D=128, blocks=128, nb=16)
+PAGED = {
+    "llama3-8b-bf16": dict(_LLAMA_PAGED, kv_quant=False),
+    "llama3-8b-int8kv": dict(_LLAMA_PAGED, kv_quant=True),
+    "gpt2-bf16": dict(T=256, H=GPT2["num_heads"], Hkv=GPT2["num_heads"],
+                      D=GPT2["d_model"] // GPT2["num_heads"], blocks=256,
+                      nb=16, kv_quant=False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PAGED))
+def test_paged_attention_compiles(one_chip, on_chip, case):
+    c = PAGED[case]
+    T, H, Hkv, D, bs = c["T"], c["H"], c["Hkv"], c["D"], 64
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    kv_shape = (c["blocks"] + 1, bs, 2, Hkv, D)
+    kv = S(kv_shape, jnp.int8 if c["kv_quant"] else jnp.bfloat16)
+    if c["kv_quant"]:
+        kv = (kv, S(kv_shape[:-1], jnp.float32))
+    q = S((T, H, D), jnp.bfloat16)
+    idx = S((T,), jnp.int32)
+    tables = S((8, c["nb"]), jnp.int32)
+
+    def fn(kv, q, slot, pos, tables):
+        return paged_attention(kv, q, slot, pos, tables, bs, c["nb"],
+                               D ** -0.5)
+
+    assert _compile(fn, kv, q, idx, idx, tables) == 1
+
+
+# ---------------------------------------------------------------- flash
+FLASH = {
+    "gpt2-B4-S1024-H12-D64": (4, 1024, 12, 12, 64),
+    "llama3-8b-B1-S2048-H32kv8-D128": (1, 2048, 32, 8, 128),
+}
+
+
+def _flash_shapes(one_chip, case):
+    B, S, H, Hkv, D = FLASH[case]
+    return (jax.ShapeDtypeStruct((B, S, H, D), jnp.bfloat16,
+                                 sharding=one_chip),
+            jax.ShapeDtypeStruct((B, S, Hkv, D), jnp.bfloat16,
+                                 sharding=one_chip))
+
+
+@pytest.mark.parametrize("case", sorted(FLASH))
+def test_flash_forward_compiles(one_chip, on_chip, case):
+    q, kv = _flash_shapes(one_chip, case)
+    assert _compile(flash_attention, q, kv, kv) == 1
+
+
+@pytest.mark.parametrize("case", sorted(FLASH))
+def test_flash_backward_compiles(one_chip, on_chip, case):
+    q, kv = _flash_shapes(one_chip, case)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    # forward (for the residuals) + the dq kernel + the dk/dv kernel
+    assert _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv) == 3
+
+
+# ----------------------------------------------------------- mixed GEMM
+# llama3-8b MLP up-projection [d_model, d_ff]: decode (M=8 sequences)
+# and prefill (M=1024-token budget)
+@pytest.mark.parametrize("M", [8, 1024])
+@pytest.mark.parametrize("bits", [8, 4])
+def test_mixed_gemm_compiles(one_chip, on_chip, bits, M):
+    K, N = LLAMA["d_model"], LLAMA["d_ff"]
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    data = S((K, N) if bits == 8 else (K // 2, N), jnp.int8)
+    qt = QuantizedTensor(data, S((K, 1), jnp.float32), None, bits, (K, N),
+                         jnp.bfloat16,
+                         "rowwise" if bits == 8 else "rowwise4")
+    assert _compile(mixed_matmul, S((M, K), jnp.bfloat16), qt) == 1

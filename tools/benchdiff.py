@@ -1,8 +1,8 @@
 """BENCH JSON regression sentinel (docs/OBSERVABILITY.md "Device &
 compiler telemetry" — the benchdiff workflow).
 
-The bench trajectory (BENCH_r01.json, r02, ...) has so far been guarded
-by eyeballs: a PR that quietly cost 20% of decode throughput would land
+The bench trajectory (one ``bench.py`` JSON capture per round) had been
+guarded by eyeballs: a PR that quietly cost 20% of decode throughput would land
 green.  ``benchdiff`` compares two BENCH captures **fingerprint-aware**
 (the ``bench_fingerprint()`` PR 8 put in every capture):
 

@@ -1,7 +1,6 @@
-"""Per-fusion profile of the llama3-8b int8 DECODE burst (VERDICT r3
-item 2: decode got a 'weight-traffic-bound' claim with no committed
-profile; training got an hlo_stats budget in round 3 — this does the
-same for decode).
+"""Per-fusion profile of the llama3-8b int8 DECODE burst (the decode
+leg's 'weight-traffic-bound' claim needs a committed profile, as the
+train step has).
 
 Builds the exact bench engine (bench.py llama8b_serving_bench shapes)
 WITH device telemetry on, runs warm decode bursts under the jax
@@ -12,8 +11,9 @@ COMPUTED from the burst program's own ``cost_analysis`` bytes via the
 engine's device telemetry (telemetry/device.py), not hand-written
 constants.
 
-Run on the real chip:  python tools/profile_decode8b.py
-Artifacts: /tmp/decode8b_trace (xplane), /tmp/decode8b_hlo_stats.tsv
+Run on the chip (one process owns it):  python tools/profile_decode8b.py
+Artifacts: chiprun_out/decode8b_trace (xplane),
+chiprun_out/decode8b_hlo_stats.tsv
 """
 # tpulint: disable-file=print — profiling CLI: the fusion table and
 # step accounting ARE the tool's stdout deliverable
@@ -30,10 +30,17 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 import numpy as np
 
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+OUT = os.path.join(REPO, "chiprun_out")
+
+
 def main():
     import jax
 
     from bench import _synthetic_int8_llama
+    from deepspeed_tpu.platform.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from deepspeed_tpu.inference import (InferenceConfig, InferenceEngine,
                                          SamplingParams)
     from deepspeed_tpu.models.presets import PRESETS
@@ -80,7 +87,7 @@ def main():
     # loud absent-profiler degradation; each burst counts as one window
     # step, so `rounds` bursts complete it.  The same seam serves the
     # serving loop's anomaly-armed captures and bench --profile.
-    trace_dir = "/tmp/decode8b_trace"
+    trace_dir = os.path.join(OUT, "decode8b_trace")
     eng.capture(steps=3, reason="decode8b", out_dir=trace_dir)
     t0 = time.perf_counter()
     rounds = 3
@@ -106,8 +113,8 @@ def main():
     ds = eng.device_snapshot()
     burst_cost = next((c for k, c in ds["programs"].items()
                        if k.startswith("('b'")), {})
-    bw = ds["peak_hbm_bw"] or 0.7e12      # fallback: measured ~700GB/s
-    floor_ms = burst_cost.get("bytes_accessed", 0) / bw * 1e3
+    bw = ds["peak_hbm_bw"]     # None for a device_kind not in the table
+    floor_ms = burst_cost.get("bytes_accessed", 0) / bw * 1e3 if bw else 0
     print(json.dumps({
         "ms_per_burst": round(dt / rounds * 1e3, 1),
         "tokens_per_burst": toks // rounds,
@@ -143,7 +150,7 @@ def main():
     data, _ = rtd.xspace_to_tool_data([paths[-1]], "hlo_stats", {})
     if isinstance(data, bytes):
         data = data.decode()
-    with open("/tmp/decode8b_hlo_stats.tsv", "w") as out:
+    with open(os.path.join(OUT, "decode8b_hlo_stats.tsv"), "w") as out:
         out.write(data)
     # the tool emits json-ish rows; print the top self-time entries
     import csv

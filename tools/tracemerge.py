@@ -307,8 +307,8 @@ def load_device_events(device_dir: str,
     back to decoding ``xplane.pb`` directly.  Either way, events whose
     instruction appears in the xplane's HLO metadata gain an
     ``args.op_name`` with the full ``jax.named_scope`` path — the T3
-    tile-comm scopes are only visible through it on backends (XLA:CPU)
-    whose timeline names events by bare instruction."""
+    tile-comm scopes are only visible through it where the timeline
+    names events by bare instruction."""
     pbs = sorted(glob.glob(os.path.join(device_dir, "**", "*.xplane.pb"),
                            recursive=True))
     gz = sorted(glob.glob(os.path.join(device_dir, "**",
@@ -321,18 +321,12 @@ def load_device_events(device_dir: str,
     elif pbs:
         events = xplane_chrome_events(pbs[-1], t_session_epoch_ns)
     if events and pbs:
-        # TPU-style traces already name events by scoped op path; only
-        # harvest the xplane when the timeline carries bare instruction
-        # names (XLA:CPU) — the protobuf walk is not free
-        def scoped(e):
-            n = e.get("name", "")
-            # "$"-prefixed names are the host Python tracer's
-            # file-path frames, not XLA op paths
-            return "/" in n and not n.startswith("$")
-
-        if not any(isinstance(e, dict) and e.get("ph") == "X"
-                   and scoped(e) for e in events):
-            annotate_op_names(events, hlo_op_name_map(pbs[-1]))
+        # annotate is keyed on the bare instruction name, so it leaves
+        # alone any event a backend already names by its scoped path.
+        # (Guessing "already scoped" from a "/" in some event name
+        # skipped the harvest altogether once XLA:CPU's thread pool
+        # began emitting "Wait: pending_threads=1/2" events.)
+        annotate_op_names(events, hlo_op_name_map(pbs[-1]))
     return events
 
 
