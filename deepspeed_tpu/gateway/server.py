@@ -47,6 +47,7 @@ from functools import partial
 from typing import Callable, Dict, List, Optional, Tuple
 
 from ..inference import EngineDeadError, SamplingParams
+from ..telemetry import SpanTracer
 from ..utils.logging import logger
 from . import protocol
 from .sloclass import (SLO_CLASS_HEADER, SloClass, default_slo_classes,
@@ -212,6 +213,12 @@ class Gateway:
         # (wire_journey*): one lock covers every cross-domain touch
         self._jlock = threading.Lock()
         self._t0 = time.perf_counter()
+        # the driver's spans (ds.gateway.*) go where the backend's own
+        # go, so one capture holds the engine's phases and the loop
+        # around them; a backend without a tracer (the fleet router)
+        # gets one of the gateway's own
+        tracer = getattr(backend, "tracer", None)
+        self.tracer = SpanTracer() if tracer is None else tracer
         self._wake = asyncio.Event()
         self._stopped = asyncio.Event()
         self._draining = False
@@ -423,13 +430,33 @@ class Gateway:
                 for uid in reaped
                 if uid in self._streams or uid in journeyed}
 
-    def _pump(self) -> Tuple[Dict[int, int], Dict[int, str]]:
-        outs = self.backend.step(rng=self._rng, sampling=self._sampling)
-        reaped = self._reaped_statuses()
-        self._g_open.set(len(self._streams))
-        if self.cfg.check_invariants:
-            self._assert_backend_invariants()
-        return outs, reaped
+    @staticmethod
+    def _since_us(t: Optional[float]) -> float:
+        """Microseconds from a ``perf_counter`` stamp taken on the other
+        thread to now (0 without one: a direct call)."""
+        return 0.0 if t is None else \
+            round((time.perf_counter() - t) * 1e6, 1)
+
+    async def _submit(self, fn, *args):
+        """The driver's form of ``_call``: the hand-over's stamp rides
+        with the call, so its span can say how long it sat in the
+        executor's queue."""
+        return await self._call(fn, *args, t_submit=time.perf_counter())
+
+    def _pump(self, t_submit: Optional[float] = None
+              ) -> Tuple[Dict[int, int], Dict[int, str], float]:
+        """One engine step; also returns when it ended on this thread
+        (the loop's ``ds.gateway.route`` reads its wake-up from it)."""
+        with self.tracer.span("ds.gateway.pump", track="gateway",
+                              queued_us=self._since_us(t_submit)) as sp:
+            outs = self.backend.step(rng=self._rng,
+                                     sampling=self._sampling)
+            reaped = self._reaped_statuses()
+            self._g_open.set(len(self._streams))
+            if self.cfg.check_invariants:
+                self._assert_backend_invariants()
+            sp.set_metadata(n_out=len(outs))
+        return outs, reaped, time.perf_counter()
 
     def _assert_backend_invariants(self) -> None:
         """The chaos bar, run after every pump when armed: allocator
@@ -445,23 +472,28 @@ class Gateway:
                     f"gateway: leaked open record for uid {uid}"
 
     def _apply(self, feedbacks: List[Tuple[int, int]],
-               flushes: List[int]) -> None:
-        for uid, tok in feedbacks:
-            s = self._streams.get(uid)
-            if s is None or s.finished or s.disconnected:
-                # STALE feedback: the stream closed (or its client
-                # vanished and a cancel() is queued behind us) between
-                # token routing and this apply.  Feeding the token
-                # would RE-ADMIT the terminally-closed uid as a fresh
-                # one-token prompt — a resurrected request no driver
-                # owns, generating forever.  Ordering matters: the
-                # disconnect path sets ``s.disconnected`` before it
-                # enqueues the cancel, so this check can never skip a
-                # continuation the cancel wouldn't have killed anyway.
-                continue
-            self.backend.put(uid, [tok])
-        for uid in flushes:
-            self.backend.flush(uid)
+               flushes: List[int],
+               t_submit: Optional[float] = None) -> None:
+        with self.tracer.span("ds.gateway.apply", track="gateway",
+                              queued_us=self._since_us(t_submit),
+                              n_put=len(feedbacks), n_flush=len(flushes)):
+            for uid, tok in feedbacks:
+                s = self._streams.get(uid)
+                if s is None or s.finished or s.disconnected:
+                    # STALE feedback: the stream closed (or its client
+                    # vanished and a cancel() is queued behind us)
+                    # between token routing and this apply.  Feeding
+                    # the token would RE-ADMIT the terminally-closed
+                    # uid as a fresh one-token prompt — a resurrected
+                    # request no driver owns, generating forever.
+                    # Ordering matters: the disconnect path sets
+                    # ``s.disconnected`` before it enqueues the cancel,
+                    # so this check can never skip a continuation the
+                    # cancel wouldn't have killed anyway.
+                    continue
+                self.backend.put(uid, [tok])
+            for uid in flushes:
+                self.backend.flush(uid)
 
     # ------------------------------------------------------------------
     # the driver: pumps the engine off the event loop
@@ -469,11 +501,6 @@ class Gateway:
     async def _drive(self) -> None:
         try:
             while not self._stop_driver:
-                fb: List[Tuple[int, int]] = []
-                fl: List[int] = []
-                self._resume_stalled(fb, fl)
-                if fb or fl:
-                    await self._call(self._apply, fb, fl)
                 if not any(not s.finished
                            for s in self._streams.values()):
                     try:
@@ -484,14 +511,27 @@ class Gateway:
                     self._wake.clear()
                     continue
                 try:
-                    outs, reaped = await self._call(self._pump)
+                    outs, reaped, t_pump_end = \
+                        await self._submit(self._pump)
                 except EngineDeadError:
                     self._mark_dead()
                     break
-                fb, fl = [], []
-                self._route_tokens(outs, reaped, fb, fl)
+                fb: List[Tuple[int, int]] = []
+                fl: List[int] = []
+                # the loop's own share of the step, one span per pump
+                # and never across an await (TraceMe nests per thread):
+                # deliver the step's tokens, then release any stalled
+                # stream whose client has drained — a stalled stream is
+                # unfinished, so the pump above runs for it too
+                with self.tracer.span(
+                        "ds.gateway.route", track="gateway",
+                        wake_us=self._since_us(t_pump_end),
+                        n_tokens=len(outs)) as sp:
+                    self._route_tokens(outs, reaped, fb, fl)
+                    self._resume_stalled(fb, fl)
+                    sp.set_metadata(n_closed=len(fl) + len(reaped))
                 if fb or fl:
-                    await self._call(self._apply, fb, fl)
+                    await self._submit(self._apply, fb, fl)
                 if not outs:
                     # idle/backoff round: don't hot-spin the engine
                     await asyncio.sleep(self.cfg.idle_s)

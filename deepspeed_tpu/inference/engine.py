@@ -482,7 +482,8 @@ class InferenceEngine:
         self._setup_telemetry()
         # --- failure-domain state (inference/failures.py) --------------
         self.fcfg = self.icfg.failure or FailureConfig()
-        self.failures = FailurePolicy(self.fcfg, self.timings)
+        self.failures = FailurePolicy(self.fcfg, self.timings,
+                                      flight=self.flight)
         self._strikes: Dict[int, int] = {}   # uid -> failing-batch count
         self._probe_groups: List[List[int]] = []  # bisection quarantine
         self._backoff_rounds = 0             # rounds admitting nothing
@@ -649,6 +650,12 @@ class InferenceEngine:
             "serving_compile_wall_ms_total",
             "cumulative first-call (compile-carrying) dispatch wall ms")
         self.timings = CounterDictView({**ms, **ints})
+        # what the watchdog's two thread hops cost (inference/failures.py
+        # ``Watchdog.hop_us``), summed over every guarded call
+        self._c_guard_hop = reg.counter(
+            "serving_guard_hop_ms_total",
+            "cumulative milliseconds guarded device calls spent in the "
+            "watchdog's hand-off (caller to worker and back)")
         # --- overlapped/quantized collectives (docs/SERVING.md
         # "Overlapped & quantized collectives"): static per-dispatch
         # wire accounting — the shapes of a compiled step fully
@@ -1943,8 +1950,8 @@ class InferenceEngine:
                 # the match may revive cached-free blocks / take a COW
                 # copy ONLY from the headroom not already reserved by
                 # earlier admits this round
-                with self.tracer.span("prefix_match", track="schedule",
-                                      uid=uid):
+                with self.tracer.span("ds.serve.prefix_match",
+                                      track="schedule", uid=uid):
                     cached = self.state.match_prefix(
                         uid, toks,
                         max_pool_take=self.state.allocator.free_blocks
@@ -2946,7 +2953,12 @@ class InferenceEngine:
         step is known to launch, or None (engine-internal key stream
         when the sampler needs one).  ``room``: see ``_schedule``."""
         self._ensure_alive()
-        t0 = time.perf_counter()
+        # the step's phases are live spans (telemetry/tracer.py): each
+        # cut below ends one phase, begins the next, and returns the one
+        # clock reading that engine.timings takes there anyway
+        tr = self.tracer
+        sid = self._dispatch_seq + 1
+        t0 = tr.phase("ds.serve.schedule", track="schedule", sid=sid)
         sched = self._schedule(room)
         self._close_ctx_exhausted()
         if not sched:
@@ -2955,6 +2967,7 @@ class InferenceEngine:
             # (a deferred request is waiting on exactly this)
             self._drain_tier_demote()
             self._drain_tier_restage(dispatching=False)
+            tr.phase_end(n_seqs=0)
             return None
         cap = self._cap
         if cap is not None and cap.armed:
@@ -2962,8 +2975,7 @@ class InferenceEngine:
             # KNOWN to launch (an idle/backoff round must not start a
             # session nothing will count down), before staging — the
             # one profiler seam (tpulint: profiler-capture)
-            cap.begin(sid=self._dispatch_seq + 1,
-                      step=self._steps_done)
+            cap.begin(sid=sid, step=self._steps_done)
         # context bucket: the compiled block bound covers every scheduled
         # sequence's post-step context, rounded to a power of two so a
         # growing context mints O(log) programs, not one per block
@@ -2990,7 +3002,9 @@ class InferenceEngine:
             self._note_compile("p", key)
         self._pstep_fns[key] = step_fn    # reinsert: LRU, not FIFO
         cold = ("p", key) not in self._warm_keys
-        t1 = time.perf_counter()
+        n_tokens = sum(len(t) for _, t in sched)
+        t1 = tr.phase("ds.serve.stage", track="stage", sid=sid,
+                      n_tokens=n_tokens, n_seqs=len(sched), mbs=mbs)
         batch = self._stage(
             self.state.build_batch(
                 sched, self.icfg.token_budget, stager=self._stager,
@@ -3004,7 +3018,13 @@ class InferenceEngine:
         self._drain_tier_demote()
         self._drain_cow()       # COW copies land before the step's write
         self._drain_tier_restage(dispatching=True)
-        t2 = time.perf_counter()
+        # a cold call carries the XLA compile in its dispatch wall time:
+        # the same interval, under the name that says so
+        t2 = tr.phase("ds.serve.compile" if cold else "ds.serve.dispatch",
+                      track="dispatch", sid=sid, n_tokens=n_tokens,
+                      n_seqs=len(sched),
+                      n_decode=sum(1 for _, t in sched if len(t) == 1),
+                      mbs=mbs)
         if callable(rng):
             rng = rng()
         if rng is None and sampling.needs_rng:
@@ -3014,6 +3034,7 @@ class InferenceEngine:
         prev = self._last_toks if self._last_toks is not None \
             else self._zero_toks
         uids = tuple(uid for uid, _ in sched)
+        guard: Dict[str, float] = {}      # the watchdog's hand-off time
         try:
             try:
                 # the one deadline-guarded dispatch seam: the watchdog
@@ -3022,7 +3043,8 @@ class InferenceEngine:
                 toks, self.state.kv = self.failures.run(
                     lambda: step_fn(self.params, self._quant,
                                     self.state.kv, batch, prev, rng),
-                    uids=uids, cold=cold)
+                    uids=uids, cold=cold, site="dispatch", sid=sid,
+                    stamps=guard)
             except jax.errors.JaxRuntimeError:
                 # degrade to an HBM cache ONLY on the first-ever step
                 # (the backend compiled but cannot execute in-program
@@ -3058,11 +3080,14 @@ class InferenceEngine:
             # every failure on the dispatch path funnels through the
             # classifier seam (tpulint's serving-except rule holds the
             # loop to this); the live ledger IS this step's build
+            tr.phase_end(failed=type(e).__name__)
             self._handle_step_failure(
                 e, uids, "dispatch",
                 registered=tuple(self.state.round_registered))
             return None
-        t3 = time.perf_counter()
+        hop_us = guard.get("hop_us", 0.0)
+        t3 = tr.phase_end(hop_us=round(hop_us, 1))
+        self._c_guard_hop.inc(hop_us / 1e3)
         self._warm_keys.add(("p", key))
         self._steps_done += 1
         self._last_toks = toks
@@ -3094,18 +3119,6 @@ class InferenceEngine:
             self._feed_step_signals(t0, t2, t3)
         for uid, _ in sched:
             self.requests.on_prefill_start(uid, t3)
-        tr = self.tracer
-        if tr.enabled:
-            # reuse the phase timestamps already taken for timings — one
-            # track per pipeline stage (docs/OBSERVABILITY.md)
-            sid = self._dispatch_seq + 1
-            tr.record("schedule", t0, t1, track="schedule", sid=sid)
-            tr.record("stage", t1, t2, track="stage", sid=sid)
-            tr.record("dispatch", t2, t3, track="dispatch", sid=sid,
-                      n_tokens=sum(len(t) for _, t in sched))
-            if cold:
-                tr.record("compile", t2, t3, track="dispatch", sid=sid,
-                          key=repr(key))
         emit = tuple((uid, self.state.slot(uid)) for uid, _ in sched
                      if not self._pending.get(uid))
         for uid in uids:
@@ -3182,7 +3195,8 @@ class InferenceEngine:
             # donation/placement policy shared with the step programs
             self._cow_fn = self._serving_jit(copy_block, kv_argnum=0,
                                              kv_only_output=True)
-        with self.tracer.span("cow_drain", track="stage", n=len(copies)):
+        with self.tracer.span("ds.serve.cow_drain", track="stage",
+                              n=len(copies)):
             for src, dst in copies:
                 self.state.kv = self._cow_fn(self.state.kv, np.int32(src),
                                              np.int32(dst))
@@ -3198,7 +3212,8 @@ class InferenceEngine:
         if not q:
             return
         tm = self.timings
-        with self.tracer.span("tier_demote", track="stage", n=len(q)):
+        with self.tracer.span("ds.serve.tier_demote", track="stage",
+                              n=len(q)):
             for parent, digest, tokens, blk in q:
                 payload = jax.tree.map(lambda x: x[:, blk], self.state.kv)
                 ev = self.state.tier.put(parent, digest, tokens,
@@ -3234,7 +3249,8 @@ class InferenceEngine:
                                                  kv_only_output=True)
         tm = self.timings
         treedef = jax.tree.structure(self.state.kv)
-        with self.tracer.span("tier_restage", track="stage", n=len(q)):
+        with self.tracer.span("ds.serve.tier_restage", track="stage",
+                              n=len(q)):
             for ent in q:
                 leaves = self.state.tier.resolve(ent.op)
                 if leaves is None:
@@ -3291,7 +3307,9 @@ class InferenceEngine:
                 self._inflight_sched[uid] = n
             else:
                 self._inflight_sched.pop(uid, None)
-        t0 = time.perf_counter()
+        tr = self.tracer
+        guard: Dict[str, float] = {}      # the watchdog's hand-off time
+        t0 = tr.phase("ds.serve.wait", track="wait", sid=st.sid)
         try:
             # readbacks surface deferred async-execution errors and can
             # hang with the device: same deadline guard + classifier
@@ -3299,10 +3317,16 @@ class InferenceEngine:
             # same try — a device dying between the wait and the copy
             # must degrade like any other failure, not crash the loop
             self.failures.run(lambda: jax.block_until_ready(st.toks),
-                              uids=st.uids, cold=st.cold)
-            t1 = time.perf_counter()
+                              uids=st.uids, cold=st.cold,
+                              site="collect", sid=st.sid,
+                              stamps=guard)
+            hop_us = guard.get("hop_us", 0.0)
+            tr.phase_set(hop_us=round(hop_us, 1))
+            t1 = tr.phase("ds.serve.readback", track="readback",
+                          sid=st.sid)
             toks_np = self._fetch_tokens(st.toks)
         except Exception as e:
+            tr.phase_end(failed=type(e).__name__)
             if st.sid == self._dispatch_seq:
                 # this WAS the latest dispatch: its sample array must
                 # never feed a later step (markers deferring to it are
@@ -3311,15 +3335,12 @@ class InferenceEngine:
             self._handle_step_failure(e, st.uids, "collect",
                                       registered=st.registered)
             return {}
-        t2 = time.perf_counter()
+        t2 = tr.phase_end()
+        self._c_guard_hop.inc(hop_us / 1e3)
         self._note_step_success(st.uids)
         tm = self.timings
         tm["wait_ms"] += (t1 - t0) * 1e3
         tm["readback_ms"] += (t2 - t1) * 1e3
-        tr = self.tracer
-        if tr.enabled:
-            tr.record("wait", t0, t1, track="wait", sid=st.sid)
-            tr.record("readback", t1, t2, track="readback", sid=st.sid)
         if self._anom is not None:
             ev = self._anom.observe("step_wait_ms", (t1 - t0) * 1e3,
                                     self._steps_done)
@@ -3530,7 +3551,10 @@ class InferenceEngine:
         burst_cold = ("b", key) not in self._warm_keys
         if rng is None:
             self._rng, rng = jax.random.split(self._rng)
-        t0 = time.perf_counter()
+        tr = self.tracer
+        guard: Dict[str, float] = {}      # the watchdog's hand-off time
+        t0 = tr.phase("ds.serve.burst", track="dispatch", steps=steps,
+                      n_seqs=len(pending))
         burst_fn = self._burst_fns[key]
         # staging runs INSIDE the guarded call: a device error (or
         # hang) during the host->device transfers must route through
@@ -3550,10 +3574,15 @@ class InferenceEngine:
 
         try:
             toks, self.state.kv = self.failures.run(
-                _staged_burst, uids=tuple(pending), cold=burst_cold)
-            t1 = time.perf_counter()
+                _staged_burst, uids=tuple(pending), cold=burst_cold,
+                site="burst", sid=self._dispatch_seq, stamps=guard)
+            hop_us = guard.get("hop_us", 0.0)
+            tr.phase_set(hop_us=round(hop_us, 1))
+            t1 = tr.phase("ds.serve.burst_readback", track="readback",
+                          steps=steps)
             toks_np = self._fetch_tokens(toks)         # ONE fetch
         except Exception as e:
+            tr.phase_end(failed=type(e).__name__)
             # blocks reserved ahead for the burst release with the
             # re-queue; seen_tokens was not advanced yet, so a
             # resumable chain re-prefills token-identically (the fetch
@@ -3578,13 +3607,8 @@ class InferenceEngine:
         # step — without this a burst-heavy workload would count
         # expiries thousands of clean bursts apart as "consecutive"
         self._note_step_success(tuple(pending))
-        t2 = time.perf_counter()
-        tr = self.tracer
-        if tr.enabled:
-            tr.record("burst", t0, t1, track="dispatch", steps=steps,
-                      n_seqs=len(pending))
-            tr.record("burst_readback", t1, t2, track="readback",
-                      steps=steps)
+        t2 = tr.phase_end()
+        self._c_guard_hop.inc(hop_us / 1e3)
         if capw is not None and capw.active:
             fin = capw.end_step(sid=self._dispatch_seq,
                                 step=self._steps_done)

@@ -227,14 +227,27 @@ class Watchdog:
     ``fatal_timeouts`` / ``max_abandoned_workers`` escalations bound
     how many threads a dying device can strand.  With
     ``timeout_ms=None`` the call runs inline: zero threads, zero hops —
-    the watchdog costs nothing unless a deadline is actually set."""
+    the watchdog costs nothing unless a deadline is actually set.
 
-    def __init__(self):
+    The hand-off is stamped, always: four ``perf_counter`` readings per
+    guarded call — put on the request queue, taken by the worker,
+    ``fn()`` returned, result taken by the caller.  ``hop_us`` (queue
+    to worker plus worker back to caller; handed back through the
+    caller's ``stamps`` dict, absent for an inline call) is what the
+    thread hops cost the step.  A call that lasts over a
+    tenth of its deadline, and an abandoned worker whose call finally
+    returns, each hand ``on_note`` one record with the stamps: a
+    completion the runtime delivered late reads there as a long
+    ``fn()``, a result the hand-off lost as a short ``fn()`` whose
+    result the caller never took."""
+
+    def __init__(self, on_note: Optional[Callable[..., None]] = None):
         self._req: Optional[queue.Queue] = None
         self._res: Optional[queue.Queue] = None
         self._thread: Optional[threading.Thread] = None
         self._token = 0
         self.abandoned = 0          # workers stranded by expiries
+        self.on_note = on_note      # (kind, **stamps) -> None
         # ONE guarded call at a time: the worker handshake is a single
         # (req, res) queue pair, so two concurrent run() calls would
         # interleave tokens on one queue, and a shared expiry could
@@ -247,6 +260,10 @@ class Watchdog:
         # pre-warm the worker directly.
         self._admit = threading.RLock()
 
+    def _note(self, kind: str, **info) -> None:
+        if self.on_note is not None:
+            self.on_note(kind, **info)
+
     def _ensure_worker(self) -> None:
         with self._admit:
             if self._thread is not None and self._thread.is_alive():
@@ -256,34 +273,54 @@ class Watchdog:
 
             def loop(req: queue.Queue, res: queue.Queue) -> None:
                 while True:
-                    token, fn = req.get()
+                    token, fn, call = req.get()
                     if fn is None:    # poison pill: worker was abandoned
                         return
+                    t_taken = time.perf_counter()
                     try:
-                        out = (token, True, fn())
+                        ok, val = True, fn()
                     except BaseException as e:  # tpulint: disable=silent-except — shipped across the queue and re-raised in the caller
-                        out = (token, False, e)
-                    res.put(out)
+                        ok, val = False, e
+                    t_ret = time.perf_counter()
+                    res.put((token, ok, val, t_taken, t_ret))
+                    if call.get("abandoned"):
+                        # nobody will take this result: say when the
+                        # stuck call did come back
+                        self._note(
+                            "guard_late_return", site=call["site"],
+                            sid=call["sid"], ok=ok,
+                            deadline_ms=call["deadline_ms"],
+                            queued_ms=(t_taken - call["t_put"]) * 1e3,
+                            fn_ms=(t_ret - t_taken) * 1e3,
+                            late_ms=(t_ret - call["t_put"]) * 1e3
+                            - call["deadline_ms"])
 
             self._thread = threading.Thread(
                 target=loop, args=(self._req, self._res),
                 name="serving-watchdog", daemon=True)
             self._thread.start()
 
-    def run(self, fn: Callable, timeout_ms: Optional[float]):
-        """Run ``fn()`` under ``timeout_ms``; inline when None."""
+    def run(self, fn: Callable, timeout_ms: Optional[float],
+            site: Optional[str] = None, sid: Optional[int] = None,
+            stamps: Optional[Dict[str, float]] = None):
+        """Run ``fn()`` under ``timeout_ms``; inline when None.
+        ``site``/``sid`` name the call in the slow-call records;
+        ``stamps``, the caller's own dict, receives ``hop_us``."""
         if timeout_ms is None:
             return fn()
         with self._admit:
             self._ensure_worker()
             self._token += 1
             token = self._token
-            self._req.put((token, fn))
-            deadline = time.perf_counter() + timeout_ms / 1e3
+            t_put = time.perf_counter()
+            call = {"site": site, "sid": sid, "t_put": t_put,
+                    "deadline_ms": timeout_ms}
+            self._req.put((token, fn, call))
+            deadline = t_put + timeout_ms / 1e3
             while True:
                 remaining = deadline - time.perf_counter()
                 try:
-                    tok, ok, val = self._res.get(
+                    tok, ok, val, t_taken, t_ret = self._res.get(
                         timeout=max(1e-4, remaining)
                         if remaining > 0 else 1e-4)
                 except queue.Empty:
@@ -295,13 +332,25 @@ class Watchdog:
                     # engine's max_abandoned_workers cap declares the
                     # device dead before that count can grow unboundedly
                     self.abandoned += 1
-                    self._req.put((None, None))
+                    call["abandoned"] = True
+                    self._req.put((None, None, None))
                     self._thread = self._req = self._res = None
                     raise DispatchTimeoutError(
                         f"device dispatch outlived its {timeout_ms:.0f} ms "
                         "deadline") from None
                 if tok != token:    # stale result from an older call
                     continue
+                t_got = time.perf_counter()
+                if stamps is not None:
+                    stamps["hop_us"] = ((t_taken - t_put)
+                                        + (t_got - t_ret)) * 1e6
+                if (t_got - t_put) * 1e3 > timeout_ms / 10.0:
+                    self._note(
+                        "guard_slow_call", site=site, sid=sid, ok=ok,
+                        deadline_ms=timeout_ms, t_put_s=t_put,
+                        queued_ms=(t_taken - t_put) * 1e3,
+                        fn_ms=(t_ret - t_taken) * 1e3,
+                        taken_back_ms=(t_got - t_ret) * 1e3)
                 if ok:
                     return val
                 raise val
@@ -313,16 +362,29 @@ class FailurePolicy:
     bookkeeping (strikes, probe groups, backoff — it owns the state
     those mutate); this object owns what is independent of it."""
 
-    def __init__(self, cfg: FailureConfig, timings):
+    def __init__(self, cfg: FailureConfig, timings, flight=None):
         """``timings``: the engine's counter view — the auto deadline
         reads observed ``device_ms + wait_ms`` per step from it (the
-        PR-5 metrics registry is the measurement substrate)."""
+        PR-5 metrics registry is the measurement substrate).
+        ``flight``: the engine's flight recorder; the watchdog's
+        slow-call and late-return records go there and to the log."""
         self.cfg = cfg
         self._timings = timings
-        self.watchdog = Watchdog()
+        self._flight = flight
+        self.watchdog = Watchdog(on_note=self._guard_note)
         # armed injections, consumed in order by guarded dispatches:
         # (kind, uid filter or None, remaining fire count)
         self._inject: List[Tuple[str, Optional[int], int]] = []
+
+    def _guard_note(self, kind: str, **info) -> None:
+        """One log line and one flight-recorder breadcrumb per slow or
+        late guarded call (``Watchdog``); may run on the abandoned
+        worker's thread."""
+        logger.warning("%s: %s", kind, " ".join(
+            f"{k}={v:.3f}" if isinstance(v, float) else f"{k}={v}"
+            for k, v in info.items()))
+        if self._flight is not None:
+            self._flight.note(kind, **info)
 
     # ---- fault injection (the chaos harness seam) ---------------------
     def inject(self, kind: str, uid: Optional[int] = None,
@@ -350,9 +412,14 @@ class FailurePolicy:
         return None
 
     # ---- the guarded-call entry --------------------------------------
-    def run(self, fn: Callable, uids=(), cold: bool = False):
+    def run(self, fn: Callable, uids=(), cold: bool = False,
+            site: Optional[str] = None, sid: Optional[int] = None,
+            stamps: Optional[Dict[str, float]] = None):
         """Run one guarded device call: consume any armed injection,
-        then execute under the current watchdog deadline.  ``cold``
+        then execute under the current watchdog deadline.  ``site``
+        (``dispatch``/``collect``/``burst``) and ``sid`` name the call
+        in the watchdog's slow-call records; ``stamps`` receives the
+        hand-off's ``hop_us`` (``Watchdog.run``).  ``cold``
         marks a call whose compiled program has never completed before
         (a compile may ride it): it runs UNGUARDED — compiles are slow
         and legitimate, and abandoning a worker mid-XLA-compile leaves
@@ -374,7 +441,8 @@ class FailurePolicy:
             else:
                 raise InjectedFault(kind, uid=None)
         return self.watchdog.run(fn,
-                                 None if cold else self.deadline_ms())
+                                 None if cold else self.deadline_ms(),
+                                 site=site, sid=sid, stamps=stamps)
 
     def deadline_ms(self) -> Optional[float]:
         """The current watchdog deadline: the configured value, or the

@@ -433,28 +433,37 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
             lp = merge_layer(lp, quant["blocks"], li, dt,
                              mixed=mixed_gemm)
         ap = lp["attn"]
-        h = norm(lp["ln1"], x)
-        q, k, v = _qkv_proj(cfg, ap, h, dt, cos, sin, batch.positions)
-        kv_layer = _write_kv(kv_layer, k, v, batch, block_size)
-        if attn_impl == "pallas":
-            o = _paged_attention_pallas(kv_layer, q, batch, block_size,
-                                        max_blocks_per_seq, scale,
-                                        shard_mesh=shard_mesh,
-                                        slopes=slopes)
-        else:
-            o = _paged_attention(kv_layer, q, batch, block_size,
-                                 max_blocks_per_seq, scale, slopes=slopes)
-        o = _mm(o.reshape(o.shape[0], -1), ap["wo"], dt,
-                contract_dims=2)
-        if cfg.attn_out_bias:
-            o = o + ap["bo"].astype(dt)
-        if not cfg.parallel_block:
-            x = x + o
-            h = norm(lp["ln2"], x)
-        elif cfg.parallel_separate_norms:
-            h = norm(lp["ln2"], x)   # gpt-neox: MLP norms the original x
-        # parallel residual (falcon/phi): MLP reads the same ln1 output
-        d = _ffn(cfg, lp, h, dt, act, comm=comm)
+        # named scopes at the block's seams (metadata only): a device
+        # trace's operations carry them in their JAX path, which is how
+        # a reader finds what the cache write or the sampler costs
+        with jax.named_scope("qkv"):
+            h = norm(lp["ln1"], x)
+            q, k, v = _qkv_proj(cfg, ap, h, dt, cos, sin,
+                                batch.positions)
+        with jax.named_scope("kv_write"):
+            kv_layer = _write_kv(kv_layer, k, v, batch, block_size)
+        with jax.named_scope("attn"):
+            if attn_impl == "pallas":
+                o = _paged_attention_pallas(
+                    kv_layer, q, batch, block_size, max_blocks_per_seq,
+                    scale, shard_mesh=shard_mesh, slopes=slopes)
+            else:
+                o = _paged_attention(kv_layer, q, batch, block_size,
+                                     max_blocks_per_seq, scale,
+                                     slopes=slopes)
+        with jax.named_scope("attn_out"):
+            o = _mm(o.reshape(o.shape[0], -1), ap["wo"], dt,
+                    contract_dims=2)
+            if cfg.attn_out_bias:
+                o = o + ap["bo"].astype(dt)
+        with jax.named_scope("ffn"):
+            if not cfg.parallel_block:
+                x = x + o
+                h = norm(lp["ln2"], x)
+            elif cfg.parallel_separate_norms:
+                h = norm(lp["ln2"], x)  # gpt-neox: MLP norms the original x
+            # parallel residual (falcon/phi): MLP reads the same ln1 output
+            d = _ffn(cfg, lp, h, dt, act, comm=comm)
         if kv_host:
             kv_layer = jax.device_put(kv_layer, jax.memory.Space.Host)
         if cfg.parallel_block:
@@ -468,6 +477,14 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
     else:
         x, new_kv = jax.lax.scan(block, x, (kv, layer_ids))
 
+    with jax.named_scope("unembed"):
+        return _unembed(cfg, params, embed_tab, x, batch, norm, dt,
+                        comm), new_kv
+
+
+def _unembed(cfg, params, embed_tab, x, batch, norm, dt, comm):
+    """``ragged_forward``'s tail, under its ``unembed`` scope: final
+    norm and float32 logits."""
     # logits only at each sequence's last scheduled token
     # (reference kernel: gather_for_logits / logits_gather) — or, on a
     # speculative verify batch, at every position of each sequence's
@@ -497,7 +514,7 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
             logits = last @ k.astype(dt)
         if cfg.head_bias:
             logits = logits + params["lm_head"]["bias"].astype(dt)
-    return logits.astype(jnp.float32), new_kv
+    return logits.astype(jnp.float32)
 
 
 def pipelined_ragged_step(cfg: TransformerConfig, params, quant, kv,
@@ -549,12 +566,15 @@ def pipelined_ragged_step(cfg: TransformerConfig, params, quant, kv,
         # positions[vidx]; its sample therefore lands at position + 1 —
         # the same "context length after the token" index row_keys folds
         wpos = batch.positions[vidx] + 1                       # [S, W]
-        keys = window_keys(rng, batch.seq_uids, wpos)
-        flat = sample_fn(logits.reshape(S * W, -1),
-                         keys.reshape((S * W,) + keys.shape[2:]))
+        with jax.named_scope("sample"):
+            keys = window_keys(rng, batch.seq_uids, wpos)
+            flat = sample_fn(logits.reshape(S * W, -1),
+                             keys.reshape((S * W,) + keys.shape[2:]))
         return flat.reshape(S, W), new_kv
-    keys = row_keys(rng, batch.seq_uids, batch.context_lens)
-    return sample_fn(logits, keys), new_kv
+    with jax.named_scope("sample"):
+        keys = row_keys(rng, batch.seq_uids, batch.context_lens)
+        toks = sample_fn(logits, keys)
+    return toks, new_kv
 
 
 # --------------------------------------------------------------------------

@@ -159,6 +159,7 @@ def _fwd(q, k, v, scale: float, causal: bool,
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
         interpret=_use_interpret(),
+        name="flash_attention_fwd",
     )(q, k, v)
     return out[0], out[1]
 
@@ -286,6 +287,7 @@ def _bwd(q, k, v, o, lse, do, scale: float, causal: bool,
         out_shape=[jax.ShapeDtypeStruct((B, H, S, D), q.dtype)],
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=_use_interpret(),
+        name="flash_attention_bwd_dq",
     )(q, k, v, do, lse, delta)[0]
 
     # dkv: kv block owns the scratch; the GQA group's (r, i) q blocks
@@ -317,6 +319,7 @@ def _bwd(q, k, v, o, lse, do, scale: float, causal: bool,
         scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
                         pltpu.VMEM((block_k, D), jnp.float32)],
         interpret=_use_interpret(),
+        name="flash_attention_bwd_dkv",
     )(qg, k, v, dog, lseg, deltag)
     return dq, dk, dv
 

@@ -177,5 +177,6 @@ def paged_attention(kv_layer, q, seq_slot, positions, block_tables,
         ),
         out_shape=jax.ShapeDtypeStruct((T, H, D), q.dtype),
         interpret=_use_interpret(),
+        name="paged_attention",
     )(*operands)
     return out

@@ -1785,7 +1785,12 @@ class Engine:
             # an armed deep-capture window opens at the step boundary
             # (the one profiler seam — tpulint: profiler-capture)
             self._cap.begin(step=self.global_steps)
-        t0 = time.perf_counter()
+        # the step's phases are live spans (telemetry/tracer.py): each
+        # cut ends one phase, begins the next, and returns the one
+        # clock reading _phase_ms takes there anyway
+        tr = self.tracer
+        sid = self.global_steps + 1
+        t0 = tr.phase("ds.train.pre_step", track="pre_step", step=sid)
         if rng is None:
             rng = jax.random.PRNGKey(self.config.seed + self.global_steps)
         if self.curriculum or self.pld or self._ltd_cfg or self.moq:
@@ -1793,11 +1798,12 @@ class Engine:
         if self._nvme is not None:
             # the NVMe-streamed step runs as many per-layer programs; its
             # phases are not the four this instrumentation names
+            tr.phase_end()
             return self._train_batch_nvme(batch, rng)
-        t1 = time.perf_counter()
+        t1 = tr.phase("ds.train.stage", track="stage", step=sid)
         step_fn = self._pick_train_step()
         batch = self.shard_batch(batch)
-        t2 = time.perf_counter()
+        t2 = tr.phase("ds.train.dispatch", track="dispatch", step=sid)
         self.tput.start()
         try:
             self.state, metrics = step_fn(self.state, batch, rng)
@@ -1811,13 +1817,14 @@ class Engine:
             # only the *first* execution may fall back — a later failure is
             # a genuine runtime error, not a backend capability gap
             if not self.offload_active or self._offload_validated:
+                tr.phase_end(failed=type(e).__name__)
                 raise
             self._disable_offload(e)
             self._train_step_fn = self._warmup_step_fn = None
             step_fn = self._pick_train_step()
             self.state, metrics = step_fn(self.state, batch, rng)
         self._offload_validated = True
-        t3 = time.perf_counter()
+        t3 = tr.phase_end()
         if self.devtel is not None:
             # cost probe once per program (post-call: the donated state
             # was rebound to the step's output, same avals), then
@@ -1835,14 +1842,6 @@ class Engine:
         if self._anom is not None:
             # detectors fed from the timestamps above — no added reads
             self._feed_step_signals(t0, t3)
-        tr = self.tracer
-        if tr.enabled:
-            # one track per phase — reuses the timestamps above, so
-            # tracing adds no clock reads to the step path
-            sid = self.global_steps + 1
-            tr.record("pre_step", t0, t1, track="pre_step", step=sid)
-            tr.record("stage", t1, t2, track="stage", step=sid)
-            tr.record("dispatch", t2, t3, track="dispatch", step=sid)
         return self._finish_step(batch, rng, metrics)
 
     def _pick_train_step(self):
@@ -1901,13 +1900,11 @@ class Engine:
                 # monitor makes need_host true per step, so the poll
                 # keeps its own cadence guard like publish below)
                 self.devtel.poll_memory()
-            t_f0 = time.perf_counter()
+            t_f0 = self.tracer.phase("ds.train.fetch", track="fetch",
+                                     step=self.global_steps)
             fetched = jax.device_get(metrics)        # ONE transfer
-            t_f1 = time.perf_counter()
+            t_f1 = self.tracer.phase_end()
             self._phase_ms["fetch"].inc((t_f1 - t_f0) * 1e3)
-            if self.tracer.enabled:
-                self.tracer.record("fetch", t_f0, t_f1, track="fetch",
-                                   step=self.global_steps)
             self._last_metrics_host = fetched
             if self.global_steps % self.config.steps_per_print == 0:
                 log_dist(

@@ -8,19 +8,28 @@ paths (tpulint's ``profiler-capture`` rule bans direct
 serving-loop-marked methods): the engines hold one
 :class:`ProfilerCapture` and call ``begin()`` / ``end_step()`` at their
 existing step boundaries, and everything session-shaped — the device
-trace, the host span window, the clock anchor that lets
-``tools/tracemerge.py`` put both on one Perfetto timeline — happens
-here, once, bounded.
+trace, the host span window, the clock anchors — happens here, once,
+bounded.
+
+The program's spans (``SpanTracer``'s profiler mirror) are INSIDE the
+device trace, on its clock: ``tools/tracemerge.py`` takes them from
+there.  ``host_trace.json`` and the anchors serve a host-only capture;
+the anchor is taken on both sides of ``start_trace`` (which takes
+tenths of a second to seconds), so that fallback states how far off it
+may be.
 
 A capture window produces one directory::
 
     <out_dir>/capture_<seq>_<reason>/
-        meta.json          clock anchor (perf_ns <-> epoch_ns at start),
-                           step/sid range, reason, profiler presence
+        meta.json          clock anchors (perf_ns before and after
+                           start_trace, epoch_ns after), step/sid
+                           range, reason, profiler presence
         host_trace.json    Chrome trace of the window's host spans
-                           (SpanTracer, force-enabled for the window)
+                           (SpanTracer's ring, force-enabled for the
+                           window)
         device/            jax.profiler log dir (plugins/profile/...,
-                           xplane.pb + trace.json.gz) — ABSENT when the
+                           xplane.pb + trace.json.gz, the ds.* host
+                           spans on its /host: plane) — ABSENT when the
                            backend/build has no profiler support
         flight.json        the engine's flight-recorder dump (written
                            by the engine when the window completes)
@@ -135,8 +144,8 @@ class ProfilerCapture:
               step: Optional[int] = None) -> None:
         """Start the armed window: create the capture dir, try to start
         the jax profiler session (loudly absent on failure), force the
-        span tracer on, and record the clock anchor tracemerge aligns
-        with.  Called by the engine at the step boundary BEFORE its
+        span tracer's ring on, and record the clock anchors (both sides
+        of ``start_trace``) that a host-only merge falls back on.  Called by the engine at the step boundary BEFORE its
         schedule/stage work, so the window covers whole steps."""
         a, self._armed = self._armed, None
         if a is None:
@@ -155,6 +164,7 @@ class ProfilerCapture:
             return
         profiling = False
         device_dir = os.path.join(cdir, "device")
+        t_before_ns = time.perf_counter_ns()
         if _TRACE_OWNER:
             if not self._warned_unavailable:
                 self._warned_unavailable = True
@@ -192,6 +202,8 @@ class ProfilerCapture:
             "profiling": profiling,
             "device_dir": device_dir if profiling else None,
             "tracer_was_enabled": tracer_was,
+            # the session's zero lies between the two readings
+            "t_before_start_perf_ns": t_before_ns,
             "t_start_perf_ns": time.perf_counter_ns(),
             "t_start_epoch_ns": time.time_ns(),
             "sid_start": sid,
@@ -252,6 +264,7 @@ class ProfilerCapture:
             "version": 1,
             "reason": a["reason"],
             "steps": a["steps"],
+            "t_before_start_perf_ns": a["t_before_start_perf_ns"],
             "t_start_perf_ns": a["t_start_perf_ns"],
             "t_start_epoch_ns": a["t_start_epoch_ns"],
             "t_stop_perf_ns": t_stop,

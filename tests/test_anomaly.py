@@ -360,6 +360,13 @@ class TestCaptureWindow:
         assert merged["otherData"]["device_absent"] is True
         assert validate_merged_trace(merged, require_device=False) == []
         assert validate_merged_trace(merged)  # device required -> fails
+        # the host-only fallback: spans from the ring, placed by the
+        # anchor, which states its own uncertainty (both sides of
+        # start_trace were stamped)
+        clock = merged["otherData"]["clock"]
+        assert clock["host_spans_from"] == "host_trace"
+        assert clock["offset_measured"] is False
+        assert clock["anchor_uncertainty_us"] >= 0.0
 
     def test_budget_and_one_window_at_a_time(self, tmp_path):
         cap = ProfilerCapture(str(tmp_path), max_captures=1)
@@ -439,7 +446,33 @@ class TestMergedTrace:
                 if e.get("pid") == 1 and e.get("ph") == "X"
                 and isinstance(e.get("args"), dict)
                 and "sid" in e["args"]]
-        assert sids
+        assert sids and all(isinstance(s, int) for s in sids)
+        # ... and they were taken from the device artifact, where the
+        # tracer's profiler mirror wrote them on the device's clock;
+        # the offset to perf_counter is measured from the spans that
+        # are in the ring too, and the old anchor's error is stated
+        clock = merged["otherData"]["clock"]
+        assert clock["host_spans_from"] == "device_artifact"
+        assert clock["offset_measured"] is True
+        assert abs(clock["anchor_error_us"]) < 60e6
+        ring = json.load(open(os.path.join(eng.capture_dirs[0],
+                                           "host_trace.json")))
+        ring_ts = {(e["name"], e["args"]["sid"]): e["ts"]
+                   for e in ring["traceEvents"] if e.get("ph") == "X"
+                   and "sid" in e.get("args", {})}
+        both = [(e["ts"], ring_ts[(e["name"], e["args"]["sid"])])
+                for e in merged["traceEvents"]
+                if e.get("pid") == 1 and e.get("ph") == "X"
+                and (e["name"], e.get("args", {}).get("sid")) in ring_ts]
+        assert both
+        # one clock: once the one offset is applied the matched spans
+        # land on their perf_counter stamps (the typical one within a
+        # millisecond; a preempted thread may stretch a single one)
+        diffs = sorted(abs(a - b) for a, b in both)
+        assert diffs[len(diffs) // 2] < 1000.0 and diffs[-1] < 1e6
+        with open(os.path.join(eng.capture_dirs[0], "meta.json")) as f:
+            meta = json.load(f)
+        assert meta["t_before_start_perf_ns"] <= meta["t_start_perf_ns"]
 
     def test_validator_rejects_junk(self):
         assert validate_merged_trace({}) \
@@ -502,9 +535,16 @@ class TestTrainingEngine:
         with open(out) as f:
             merged = json.load(f)
         assert validate_merged_trace(merged) == []
-        tracks = {e["args"]["name"] for e in merged["traceEvents"]
-                  if e.get("pid") == 1 and e.get("name") == "thread_name"}
-        assert "dispatch" in tracks
+        # the step's phases come from the device artifact itself (the
+        # tracer's profiler mirror), one host track per thread
+        assert merged["otherData"]["clock"]["host_spans_from"] \
+            == "device_artifact"
+        host = [e for e in merged["traceEvents"]
+                if e.get("pid") == 1 and e.get("ph") == "X"]
+        assert {"ds.train.pre_step", "ds.train.stage",
+                "ds.train.dispatch"} <= {e["name"] for e in host}
+        assert {e["args"]["step"] for e in host
+                if e["name"] == "ds.train.dispatch"} == {1, 2}
 
 
 # --------------------------------------------------------------------------
@@ -551,6 +591,54 @@ class TestXplaneDecoder:
         (ev,) = line["events"]
         assert ev == {"metadata_id": 7, "offset_ps": 2_000_000,
                       "duration_ps": 5_000_000}
+
+    def _host_space(self):
+        """A host plane with one ``ds.serve.dispatch`` TraceMe carrying
+        its args as stats (sid: int64, hop_us: double), one foreign
+        event, and a device plane."""
+        import struct
+
+        def dblf(fno, v):
+            return _vint((fno << 3) | 1) + struct.pack("<d", v)
+        stats = (_lenf(4, _intf(1, 1) + _intf(4, 17))
+                 + _lenf(4, _intf(1, 2) + dblf(2, 12.5)))
+        ev = _intf(1, 7) + _intf(2, 2_000_000) + _intf(3, 5_000_000) + stats
+        other = _intf(1, 8) + _intf(2, 0) + _intf(3, 1_000_000)
+        line = (_intf(1, 3) + _lenf(2, b"gateway-engine_0") + _intf(3, 1_000)
+                + _lenf(4, ev) + _lenf(4, other))
+        emeta = [_lenf(4, _intf(1, i) + _lenf(2, _intf(1, i) + _lenf(2, n)))
+                 for i, n in ((7, b"ds.serve.dispatch"), (8, b"not.ours"))]
+        smeta = [_lenf(5, _intf(1, i) + _lenf(2, _intf(1, i) + _lenf(2, n)))
+                 for i, n in ((1, b"sid"), (2, b"hop_us"))]
+        host = (_lenf(2, b"/host:CPU") + _lenf(3, line)
+                + b"".join(emeta) + b"".join(smeta))
+        return _lenf(1, host) + self._space()
+
+    @pytest.mark.parametrize("reader", ["ProfileData", "pure_python"])
+    def test_program_spans_come_from_the_xplane_with_their_stats(
+            self, tmp_path, monkeypatch, reader):
+        """The merge takes the program's ``ds.*`` spans from the xplane
+        itself, args included — through jax's ProfileData, or through
+        the built-in decoder where that is absent."""
+        import jax.profiler
+
+        from tools.tracemerge import program_span_events
+        if reader == "pure_python":
+            monkeypatch.delattr(jax.profiler, "ProfileData")
+        p = tmp_path / "t.xplane.pb"
+        p.write_bytes(self._host_space())
+        evs = program_span_events(str(p))
+        (x,) = [e for e in evs if e["ph"] == "X"]
+        assert (x["name"], x["pid"]) == ("ds.serve.dispatch", 1)
+        assert x["args"] == {"sid": 17, "hop_us": 12.5}
+        assert x["ts"] == pytest.approx(3.0) and x["dur"] == pytest.approx(5.0)
+        (track,) = [e for e in evs if e.get("name") == "thread_name"]
+        assert track["tid"] == x["tid"]
+        assert track["args"]["name"].startswith("gateway-engine_0")
+        # an artifact without a ds.* event has no host tracks to give
+        q = tmp_path / "d.xplane.pb"
+        q.write_bytes(self._space())
+        assert program_span_events(str(q)) == []
 
     def test_chrome_events_from_xplane(self, tmp_path):
         p = tmp_path / "t.xplane.pb"

@@ -161,7 +161,7 @@ class TestCompileObservatory:
         assert tm["compile_retraces"] == 0
         assert tm["compile_ms"] > 0
         names = [e["name"] for e in eng.tracer.events()]
-        assert "compile" in names
+        assert "ds.serve.compile" in names
 
     def test_forced_respecialization_bumps_retrace_exactly_once(
             self, model):

@@ -149,6 +149,52 @@ class TestWatchdog:
         # the abandoned one can never be mistaken for this call's
         assert wd.run(lambda: "alive", 1000.0) == "alive"
 
+    def test_hand_off_is_stamped_in_order(self):
+        """Four stamps per guarded call: put, taken by the worker, fn
+        returned, result taken back.  ``hop_us`` is the two hand-overs,
+        never the call itself; an inline call has no hop."""
+        notes = []
+        wd = Watchdog(on_note=lambda kind, **info: notes.append((kind, info)))
+        stamps = {}
+        assert wd.run(lambda: time.sleep(0.05) or "ok", 400.0,
+                      site="collect", sid=7, stamps=stamps) == "ok"
+        # 50 ms is over a tenth of the 400 ms deadline: one slow-call note
+        (kind, info), = notes
+        assert kind == "guard_slow_call"
+        assert (info["site"], info["sid"], info["ok"]) == ("collect", 7, True)
+        assert info["deadline_ms"] == 400.0 and info["t_put_s"] > 0
+        assert info["queued_ms"] >= 0.0 and info["taken_back_ms"] >= 0.0
+        assert info["fn_ms"] >= 50.0
+        # the hop is the two hand-overs, not the 50 ms of fn()
+        assert (info["queued_ms"] + info["taken_back_ms"]) * 1e3 \
+            == pytest.approx(stamps["hop_us"], abs=1.0)
+        assert info["queued_ms"] + info["fn_ms"] + info["taken_back_ms"] \
+            < 400.0
+        # a fast call under a long deadline says nothing
+        assert wd.run(lambda: 1, 60_000.0, stamps=stamps) == 1
+        assert len(notes) == 1 and stamps["hop_us"] >= 0.0
+        inline = {}
+        assert wd.run(lambda: 2, None, stamps=inline) == 2
+        assert inline == {}
+
+    def test_abandoned_worker_says_when_its_call_came_back(self):
+        """Expiry abandons the worker; when the stuck call finally
+        returns, the worker leaves one record: how long after the
+        deadline, and how long fn() itself took."""
+        notes = []
+        wd = Watchdog(on_note=lambda kind, **info: notes.append((kind, info)))
+        with pytest.raises(DispatchTimeoutError):
+            wd.run(lambda: time.sleep(0.25), 50.0, site="dispatch", sid=3)
+        assert notes == []                    # nothing came back yet
+        deadline = time.time() + 5.0
+        while not notes and time.time() < deadline:
+            time.sleep(0.01)
+        (kind, info), = notes
+        assert kind == "guard_late_return"
+        assert (info["site"], info["sid"]) == ("dispatch", 3)
+        assert info["fn_ms"] >= 240.0 and info["late_ms"] >= 150.0
+        assert info["queued_ms"] >= 0.0
+
     def test_concurrent_guarded_calls_are_serialized(self):
         """Regression (tpulint v3 hardening): two threads sharing one
         watchdog must not interleave tokens on the single (req, res)
@@ -223,6 +269,19 @@ class TestWatchdog:
         assert all(len(v) == 6 for v in done.values())
         assert int(eng.timings["step_retries"]) >= 1
         assert eng.failures.watchdog.abandoned >= 1
+        # the abandoned worker's call came back 4 deadlines later: one
+        # breadcrumb says so, with the call site and the step
+        deadline = time.time() + 5.0
+        late = []
+        while not late and time.time() < deadline:
+            late = [e for e in eng.flight.events()
+                    if e["kind"] == "guard_late_return"]
+            time.sleep(0.01)
+        assert late and late[0]["site"] in ("dispatch", "collect")
+        assert late[0]["late_ms"] > 0 and late[0]["fn_ms"] >= 4 * 150.0
+        assert late[0]["sid"] is not None
+        # and the hops of the guarded calls were counted
+        assert eng.metrics.get("serving_guard_hop_ms_total").value() > 0.0
 
 
 # --------------------------------------------------------------------------

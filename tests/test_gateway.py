@@ -249,6 +249,80 @@ def test_stream_parity_with_inprocess_generate(gw, model):
         assert res[u]["finish_reason"] == "length"
 
 
+def test_driver_spans_pump_route_apply_in_order(gw):
+    """The driver names its own share of a step (ds.gateway.*) on its
+    backend's tracer: per step one pump (engine thread), one route
+    (event loop) and one apply (engine thread), in that order, each
+    carrying how long its hand-over took."""
+    h, eng = gw
+    assert h.gateway.tracer is eng.tracer      # even while it is empty
+    eng.tracer.clear()
+    eng.tracer.enable()
+    try:
+        r = http_completion(h.host, h.port, {"prompt": [3, 4, 5, 6],
+                                             "max_tokens": 4,
+                                             "stream": True})
+    finally:
+        eng.tracer.disable()
+    assert r["code"] == 200 and len(r["tokens"]) == 4
+    evs = [e for e in eng.tracer.events()
+           if e["name"].startswith("ds.gateway.")]
+    order = [e["name"].rsplit(".", 1)[1] for e in evs]
+    # every token-bearing pump is followed by its route, then its apply
+    busy = [i for i, e in enumerate(evs) if e["name"] == "ds.gateway.pump"
+            and e["args"]["n_out"] > 0]
+    assert len(busy) >= 4
+    for i in busy:
+        assert order[i:i + 3] == ["pump", "route", "apply"], order
+        pump, route, apply_ = evs[i:i + 3]
+        assert pump["ts_ns"] + pump["dur_ns"] <= route["ts_ns"]
+        assert route["ts_ns"] + route["dur_ns"] <= apply_["ts_ns"]
+        assert pump["args"]["queued_us"] >= 0.0
+        assert route["args"]["wake_us"] >= 0.0
+        assert route["args"]["n_tokens"] == pump["args"]["n_out"] == 1
+        assert apply_["args"]["queued_us"] >= 0.0
+        assert apply_["args"]["n_put"] + apply_["args"]["n_flush"] == 1
+    # the last token closes the stream: flushed, not fed back
+    last = evs[busy[-1] + 2]["args"]
+    assert (last["n_put"], last["n_flush"]) == (0, 1)
+    assert evs[busy[-1] + 1]["args"]["n_closed"] == 1
+    # the engine's own phases of those steps sit inside the pumps
+    pumps = [(evs[i]["ts_ns"], evs[i]["ts_ns"] + evs[i]["dur_ns"])
+             for i in busy]
+    for e in eng.tracer.events():
+        if e["name"] == "ds.serve.dispatch":
+            assert any(a <= e["ts_ns"] and e["ts_ns"] + e["dur_ns"] <= b
+                       for a, b in pumps)
+
+
+def test_gateway_without_a_backend_tracer_gets_its_own():
+    from deepspeed_tpu.gateway.server import Gateway
+    from deepspeed_tpu.telemetry import MetricsRegistry, SpanTracer
+
+    class Stub:
+        metrics = MetricsRegistry()
+
+        def step(self, rng=None, sampling=None):
+            return {7: 1}
+
+        def _drain_reaped(self):
+            return ()
+
+    g = Gateway(Stub())
+    assert isinstance(g.tracer, SpanTracer)
+    g.tracer.enable()
+    t = time.perf_counter()
+    outs, reaped, t_end = g._pump(t_submit=t)
+    g._apply([], [], t_submit=t_end)
+    g._apply([], [])                          # a direct call: nothing queued
+    assert outs == {7: 1} and reaped == {} and t_end >= t
+    pump, a1, a2 = g.tracer.events()
+    assert pump["name"] == "ds.gateway.pump"
+    assert pump["args"]["n_out"] == 1 and pump["args"]["queued_us"] >= 0.0
+    assert a1["args"]["queued_us"] >= 0.0 and a2["args"]["queued_us"] == 0.0
+    g._exec.shutdown()
+
+
 def test_non_streaming_response(gw):
     h, _ = gw
     r = http_completion(h.host, h.port, {"prompt": [5, 6, 7],

@@ -1097,7 +1097,8 @@ _PROFILER_BARE_NAMES = {"start_trace", "stop_trace"}
       "(telemetry/profiler.py ProfilerCapture arm/begin/end_step): it "
       "owns the session, the clock anchor tracemerge aligns with, the "
       "cooldown/budget rate limit, and the loud absent-profiler "
-      "degradation")
+      "degradation; a span reaches TraceMe through telemetry/tracer.py "
+      "SpanTracer, never a direct TraceAnnotation")
 def check_profiler_capture(ctx: FileContext) -> Iterator[Finding]:
     marked = _serving_marked_lines(ctx)
     if not marked or "profiler" not in ctx.source \

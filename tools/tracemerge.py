@@ -1,21 +1,28 @@
-"""Merge a host SpanTracer Chrome trace with a ``jax.profiler`` device
+"""Merge a capture window's host spans with its ``jax.profiler`` device
 artifact into ONE Perfetto-loadable timeline
 (docs/OBSERVABILITY.md "Anomaly detection & deep capture").
 
-The host trace (telemetry/tracer.py) timestamps spans on
-``time.perf_counter_ns``; the jax profiler's ``*.trace.json.gz``
-timestamps its events relative to the profiling session start, and its
-``*.xplane.pb`` lines carry ns timestamps of their own.  Until now the
-two could only be eyeballed side by side — the depth-2 dispatch-ahead
-overlap (and later the T3 tile-level comm overlap, arxiv 2401.16677)
-was visually verifiable only on the host half.  The capture window
-(telemetry/profiler.py) records a clock anchor — ``perf_counter_ns``
-and ``epoch ns`` at the instant the session started — and this tool
-uses it to shift device events onto the host ``perf_counter``
-timeline, so host stages (schedule / stage / dispatch / wait /
-readback, each span carrying its step ``sid``) and device/XLA activity
-(including the ``jax.named_scope`` labels from ``comm/collectives.py``)
-render as tracks of ONE Perfetto file.
+The program's spans (telemetry/tracer.py, names ``ds.<layer>.<phase>``)
+are mirrored into the profiler session as ``TraceMe`` events, so the
+device artifact already holds them on the clock of its ``XLA Ops``
+lines: host stages (schedule / stage / dispatch / wait / readback,
+each carrying its step ``sid``) and device/XLA activity (including the
+``jax.named_scope`` labels) share a clock by construction.  The merge
+takes the host spans from there and re-homes them onto host tracks
+(pid 1).  To keep every capture on the process's ``perf_counter``
+timeline (a fleet merge aligns N windows on it), the whole artifact is
+shifted by ONE offset, and that offset is measured, not guessed: the
+same span is in the SpanTracer ring (``host_trace.json``,
+``perf_counter_ns``) and in the artifact, so the median difference of
+the matched spans is the clocks' offset.  ``otherData.clock`` says how
+far the capture's anchor (``perf_counter_ns`` when ``start_trace``
+returned) was from it.
+
+Only a capture whose artifact holds no ``ds.*`` event (no profiler, or
+a host-only window) falls back to ``host_trace.json`` plus that anchor;
+``start_trace`` takes tenths of a second to seconds, the capture stamps
+both sides of it, and the fallback reports the distance as its
+uncertainty.
 
 Device-artifact handling, in preference order:
 
@@ -96,8 +103,28 @@ def _decode_event_metadata(buf: bytes) -> Tuple[int, str]:
     return mid, name
 
 
-def _decode_xevent(buf: bytes) -> Dict[str, int]:
-    ev = {"metadata_id": 0, "offset_ps": 0, "duration_ps": 0}
+def _decode_xstat(buf: bytes) -> Tuple[int, Any]:
+    """(stat metadata id, value) of one XStat: double 2 | uint64 3 |
+    int64 4 | str 5."""
+    import struct
+    mid, val = 0, None
+    for fno, wt, v in _fields(buf):
+        if fno == 1:
+            mid = v
+        elif fno == 2 and wt == 1:
+            val = struct.unpack("<d", v.to_bytes(8, "little"))[0]
+        elif fno == 3:
+            val = v
+        elif fno == 4:
+            val = v - (1 << 64) if v >= 1 << 63 else v
+        elif fno == 5:
+            val = v.decode("utf-8", "replace")
+    return mid, val
+
+
+def _decode_xevent(buf: bytes) -> Dict[str, Any]:
+    ev: Dict[str, Any] = {"metadata_id": 0, "offset_ps": 0,
+                          "duration_ps": 0}
     for fno, _, v in _fields(buf):
         if fno == 1:
             ev["metadata_id"] = v
@@ -105,6 +132,9 @@ def _decode_xevent(buf: bytes) -> Dict[str, int]:
             ev["offset_ps"] = v
         elif fno == 3:
             ev["duration_ps"] = v
+        elif fno == 4:
+            # XStat: a TraceMe's args (the ds.* spans' sid, counts)
+            ev.setdefault("stats", []).append(_decode_xstat(v))
     return ev
 
 
@@ -125,7 +155,8 @@ def _decode_xline(buf: bytes) -> Dict[str, Any]:
 
 
 def _decode_xplane(buf: bytes) -> Dict[str, Any]:
-    plane = {"id": 0, "name": "", "lines": [], "event_metadata": {}}
+    plane = {"id": 0, "name": "", "lines": [], "event_metadata": {},
+             "stat_metadata": {}}
     for fno, _, v in _fields(buf):
         if fno == 1:
             plane["id"] = v
@@ -144,6 +175,12 @@ def _decode_xplane(buf: bytes) -> Dict[str, Any]:
             if meta is not None:
                 plane["event_metadata"][k if k is not None
                                         else meta[0]] = meta[1]
+        elif fno == 5:
+            # map<int64, XStatMetadata>: the stats' names
+            for efno, _, ev in _fields(v):
+                if efno == 2:
+                    mid, name = _decode_event_metadata(ev)
+                    plane["stat_metadata"][mid] = name
     return plane
 
 
@@ -180,12 +217,17 @@ def xplane_chrome_events(path: str, t_session_epoch_ns: int,
             for ev in line["events"]:
                 name = plane["event_metadata"].get(
                     ev["metadata_id"], f"event{ev['metadata_id']}")
-                out.append({
+                rec = {
                     "ph": "X", "pid": pid, "tid": tid,
                     "name": name,
                     "ts": (base_ns + ev["offset_ps"] / 1e3) / 1e3,
                     "dur": ev["duration_ps"] / 1e6,
-                })
+                }
+                if ev.get("stats"):
+                    rec["args"] = {
+                        plane["stat_metadata"].get(m, f"stat{m}"): v
+                        for m, v in ev["stats"] if v is not None}
+                out.append(rec)
     return out
 
 
@@ -334,14 +376,118 @@ def load_device_events(device_dir: str,
 # merge
 # --------------------------------------------------------------------------
 
+PROGRAM_SPAN_PREFIX = "ds."
+
+
+def _xplane_program_spans(path: str) -> List[Dict[str, Any]]:
+    """Every ``ds.*`` event on the ``/host:`` planes of one
+    ``*.xplane.pb``: ``{"name", "thread", "thread_name", "ts", "dur"
+    (µs, the artifact's clock), "args"}``.  Read from the xplane, not
+    from the ``trace.json.gz`` beside it: the profiler caps that
+    rendering at a million events, and a window of seconds loses its
+    later steps there.  ``jax.profiler.ProfileData`` reads it where JAX
+    is installed (a second for two million events); the pure-python
+    decoder above is the fallback."""
+    out: List[Dict[str, Any]] = []
+    try:
+        from jax.profiler import ProfileData
+    except ImportError:
+        ProfileData = None
+    if ProfileData is not None:
+        for pi, plane in enumerate(ProfileData.from_file(path).planes):
+            if not plane.name.startswith("/host:"):
+                continue
+            for li, line in enumerate(plane.lines):
+                for ev in line.events:
+                    if ev.name.startswith(PROGRAM_SPAN_PREFIX):
+                        out.append({
+                            "name": ev.name, "thread": f"{pi}.{li}",
+                            "thread_name": line.name,
+                            "ts": ev.start_ns / 1e3,
+                            "dur": ev.duration_ns / 1e3,
+                            "args": dict(ev.stats)})
+        return out
+    with open(path, "rb") as f:
+        planes = decode_xspace(f.read())
+    for pi, plane in enumerate(planes):
+        if not plane["name"].startswith("/host:"):
+            continue
+        for li, line in enumerate(plane["lines"]):
+            for ev in line["events"]:
+                name = plane["event_metadata"].get(ev["metadata_id"], "")
+                if name.startswith(PROGRAM_SPAN_PREFIX):
+                    out.append({
+                        "name": name, "thread": f"{pi}.{li}",
+                        "thread_name": line["name"],
+                        "ts": (line["timestamp_ns"]
+                               + ev["offset_ps"] / 1e3) / 1e3,
+                        "dur": ev["duration_ps"] / 1e6,
+                        "args": {
+                            plane["stat_metadata"].get(m, f"stat{m}"): v
+                            for m, v in ev.get("stats", ())
+                            if v is not None}})
+    return out
+
+
+def program_span_events(xplane_path: str) -> List[Dict[str, Any]]:
+    """The program's ``ds.*`` spans of one device artifact as host
+    tracks of a Chrome trace — pid 1, one tid per source thread — on
+    the artifact's own clock.  ``[]`` when the artifact holds none."""
+    spans = sorted(_xplane_program_spans(xplane_path),
+                   key=lambda sp: sp["ts"])
+    if not spans:
+        return []
+    host: List[Dict[str, Any]] = [{
+        "name": "process_name", "ph": "M", "pid": 1, "tid": 0,
+        "args": {"name": "deepspeed_tpu (spans in the device trace)"}}]
+    tids: Dict[str, int] = {}
+    body: List[Dict[str, Any]] = []
+    for sp in spans:
+        tid = tids.get(sp["thread"])
+        if tid is None:
+            tid = tids[sp["thread"]] = len(tids) + 1
+            host.append({"name": "thread_name", "ph": "M", "pid": 1,
+                         "tid": tid, "args": {
+                             "name": f"{sp['thread_name'] or 'thread'} "
+                                     f"#{tid}"}})
+        rec = {"name": sp["name"], "ph": "X", "pid": 1, "tid": tid,
+               "ts": sp["ts"], "dur": sp["dur"]}
+        if sp["args"]:
+            rec["args"] = sp["args"]
+        body.append(rec)
+    return host + body
+
+
+def measured_offset_us(ring_events: List[Dict[str, Any]],
+                       program_events: List[Dict[str, Any]]
+                       ) -> Optional[float]:
+    """perf_counter minus the artifact's clock, in µs: the median over
+    the spans found in both the SpanTracer ring and the artifact,
+    matched by name and step id (``sid``/``step``).  None without a
+    match."""
+    def key(ev):
+        args = ev.get("args") if isinstance(ev.get("args"), dict) else {}
+        sid = args.get("sid", args.get("step"))
+        return None if sid is None else (ev.get("name"), sid)
+
+    ring = {}
+    for ev in ring_events:
+        if ev.get("ph") == "X" and key(ev) is not None:
+            ring.setdefault(key(ev), ev["ts"])
+    diffs = sorted(ring[key(ev)] - ev["ts"] for ev in program_events
+                   if ev.get("ph") == "X" and key(ev) in ring)
+    return diffs[len(diffs) // 2] if diffs else None
+
+
 def merge_events(host_events: List[Dict[str, Any]],
                  device_events: List[Dict[str, Any]],
                  t_start_perf_ns: int) -> List[Dict[str, Any]]:
     """Put both event streams on the host ``perf_counter`` timeline
-    (microseconds): host events already are; device events are
-    session-relative and get shifted by the capture's anchor.  Device
-    pids are bumped out of the host's pid space so Perfetto renders
-    host stages and device activity as separate process groups."""
+    (microseconds): host events already are; device events are on the
+    artifact's clock and get shifted by ``t_start_perf_ns`` (the
+    measured offset, or the capture's anchor).  Device pids are bumped
+    out of the host's pid space so Perfetto renders host stages and
+    device activity as separate process groups."""
     anchor_us = t_start_perf_ns / 1e3
     out: List[Dict[str, Any]] = list(host_events)
     for ev in device_events:
@@ -359,13 +505,16 @@ def merge_events(host_events: List[Dict[str, Any]],
 def _capture_events(capture_dir: str
                     ) -> Tuple[List[Dict[str, Any]], Dict[str, Any],
                                int, bool]:
-    """One capture window's events, already merged onto the host
-    ``perf_counter`` timeline (µs): host spans as recorded, device
-    events shifted by the capture's OWN clock anchor.  Returns
-    ``(events, meta, n_host_events, device_absent)`` — the shared core
-    of :func:`merge_capture` and :func:`merge_fleet` (each capture is
-    clock-anchored per artifact, so a fleet merge aligns N windows
-    from N replicas on one timeline)."""
+    """One capture window's events on the host ``perf_counter``
+    timeline (µs).  Host spans come from the device artifact when the
+    program's ``ds.*`` events are in it (one clock by construction,
+    shifted as a whole by the measured offset); else from
+    ``host_trace.json``, with the device events shifted by the
+    capture's anchor.  ``meta["clock"]`` says which, and how far the
+    anchor was off or may be off.  Returns ``(events, meta,
+    n_host_events, device_absent)`` — the shared core of
+    :func:`merge_capture` and :func:`merge_fleet` (a fleet merge aligns
+    N windows from N replicas on one timeline)."""
     with open(os.path.join(capture_dir, "meta.json")) as f:
         meta = json.load(f)
     host: Dict[str, Any] = {"traceEvents": []}
@@ -373,6 +522,7 @@ def _capture_events(capture_dir: str
         with open(os.path.join(capture_dir, meta["host_trace"])) as f:
             host = json.load(f)
     device_events: List[Dict[str, Any]] = []
+    program: List[Dict[str, Any]] = []
     device_absent = True
     if meta.get("device_dir"):
         ddir = os.path.join(capture_dir, meta["device_dir"])
@@ -380,9 +530,40 @@ def _capture_events(capture_dir: str
             device_events = load_device_events(
                 ddir, meta.get("t_start_epoch_ns", 0))
             device_absent = not device_events
-    host_events = host.get("traceEvents", [])
+            pbs = sorted(glob.glob(os.path.join(ddir, "**", "*.xplane.pb"),
+                                   recursive=True))
+            if pbs:
+                program = program_span_events(pbs[-1])
+    ring_events = host.get("traceEvents", [])
+    anchor_us = meta["t_start_perf_ns"] / 1e3
+    if program:
+        offset_us = measured_offset_us(ring_events, program)
+        clock = {"host_spans_from": "device_artifact",
+                 "offset_measured": offset_us is not None}
+        if offset_us is None:
+            offset_us = anchor_us
+        else:
+            clock["anchor_error_us"] = anchor_us - offset_us
+        host_events = [dict(ev, ts=ev["ts"] + offset_us)
+                       if "ts" in ev else ev for ev in program]
+        # re-homed above: not a second time among the device's threads
+        device_events = [
+            ev for ev in device_events
+            if not (isinstance(ev, dict) and str(ev.get("name", ""))
+                    .startswith(PROGRAM_SPAN_PREFIX))]
+    else:
+        offset_us = anchor_us
+        host_events = ring_events
+        clock = {"host_spans_from": "host_trace",
+                 "offset_measured": False}
+        if "t_before_start_perf_ns" in meta:
+            clock["anchor_uncertainty_us"] = (
+                meta["t_start_perf_ns"]
+                - meta["t_before_start_perf_ns"]) / 1e3
+    clock["offset_us"] = offset_us
+    meta = {**meta, "clock": clock}
     return (merge_events(host_events, device_events,
-                         meta["t_start_perf_ns"]),
+                         int(offset_us * 1e3)),
             meta, len(host_events), device_absent)
 
 
@@ -403,6 +584,7 @@ def merge_capture(capture_dir: str,
         "otherData": {
             "merged_by": "tools/tracemerge",
             "capture": meta,
+            "clock": meta["clock"],
             "host_events": n_host,
             "device_events": len(events) - n_host,
             "device_absent": device_absent,
