@@ -444,6 +444,13 @@ class InferenceEngine:
         # probe label -> {variant: seconds per 3 steps}, for every race
         # this engine ran itself (a memoized verdict leaves no entry)
         self.probe_times: Dict[str, Dict[str, float]] = {}
+        # (bucket, sampler_key) -> what the compiled step is, noted once
+        # at its first call: "form" says how the layer scan holds the
+        # cache ("carried": in place, as the scan's carry; "streamed":
+        # one layer through HBM at a time, the host-resident cache) and
+        # "temp_bytes" what the program needs beside its arguments — a
+        # carried step's stays far under one layer's share of the pool
+        self.serving_programs: Dict[tuple, Dict] = {}
         self._burst_fns: Dict[tuple, object] = {}
         # serving programs that have COMPLETED at least one call: only
         # these run under the dispatch watchdog — a first call may
@@ -697,6 +704,21 @@ class InferenceEngine:
         reg.gauge_fn("serving_prefix_hit_rate", self._prefix_hit_rate,
                      "cached_tokens / prompt_tokens over the measured "
                      "window (absent before any prompt token)")
+        progs = self.serving_programs
+        reg.gauge_fn("serving_step_temp_bytes",
+                     lambda: max((p["temp_bytes"] for p in progs.values()
+                                  if p["temp_bytes"] is not None),
+                                 default=None),
+                     "largest temporary allocation among the compiled "
+                     "serving steps (what has to fit beside weights and "
+                     "KV pool; absent before the first step)")
+        reg.gauge_fn("serving_step_cache_carried",
+                     lambda: (all(p["form"] == "carried"
+                                  for p in progs.values())
+                              if progs else None),
+                     "1 when every compiled serving step carries the "
+                     "paged cache through its layer scan in place, 0 "
+                     "when one streams it a layer at a time (kv_offload)")
         # tier occupancy: pull-gauges over tier.stats() truth (absent
         # when the tier is off — None suppresses the series, the same
         # contract the devtel gauges use)
@@ -808,6 +830,27 @@ class InferenceEngine:
                 kind, key, int(tm["compile_retraces"]))
         else:
             self._compiled_ever.add((kind, key))
+
+    def _note_program(self, key, step_fn, args) -> None:
+        """Enter one compiled serving step in ``serving_programs``, on
+        the return of its first call.  ``lower(*args).compile()`` of a
+        jit function that has just run these arguments is two cache
+        lookups (jit keeps its lowering and the lowering its
+        executable), so the temporary bytes are read from the program
+        the step already built: nothing compiles twice."""
+        temp = None
+        try:
+            mem = step_fn.lower(*args).compile().memory_analysis()
+            temp = None if mem is None else int(mem.temp_size_in_bytes)
+        except Exception as e:
+            logger.warning("serving step %r: no memory analysis (%s: %s)",
+                           key, type(e).__name__,
+                           str(e).splitlines()[0][:120] if str(e) else "")
+        form = "streamed" if getattr(self, "_kv_on_host", False) \
+            else "carried"
+        self.serving_programs[key] = {"form": form, "temp_bytes": temp}
+        logger.info("serving step %r: cache %s through the layer scan, "
+                    "temporaries %s bytes", key, form, temp)
 
     def reset_timings(self) -> None:
         """Zero the cumulative per-phase breakdown the serving loop
@@ -1039,6 +1082,7 @@ class InferenceEngine:
                 quantize_embeddings=self.icfg.quantize_embeddings)
             # step/burst closures hold the old quant tree
             self._pstep_fns.clear()
+            self.serving_programs.clear()
             self._burst_fns.clear()
             # the rebuilt programs recompile on their next call: they
             # are cold again (warm programs run under the watchdog,
@@ -3061,6 +3105,7 @@ class InferenceEngine:
                 # zeros — recreate it
                 self.state.kv = self.state.cfg.kv_zeros()
                 self._pstep_fns.clear()
+                self.serving_programs.clear()
                 # a backend-capability fallback is a LEGITIMATE rebuild
                 # of every serving program (like refresh_params): the
                 # dropped programs are cold again and their keys leave
@@ -3103,14 +3148,14 @@ class InferenceEngine:
             # time carried the XLA compile (the timestamps are the ones
             # above — the compile span costs no extra clock reads)
             tm["compile_ms"] += (t3 - t2) * 1e3
+            # once per program, on the warm executable — args are the
+            # post-call live buffers (the donated kv was rebound to the
+            # step's output)
+            args = (self.params, self._quant, self.state.kv, batch, prev,
+                    rng)
+            self._note_program(key, step_fn, args)
             if self.devtel is not None:
-                # cost-analysis probe, once per program, on the warm
-                # executable — args are the post-call live buffers
-                # (the donated kv was rebound to the step's output)
-                self.devtel.probe_program(
-                    ("p",) + key, step_fn,
-                    (self.params, self._quant, self.state.kv, batch,
-                     prev, rng))
+                self.devtel.probe_program(("p",) + key, step_fn, args)
         if self.devtel is not None:
             self.devtel.on_dispatch(("p",) + key)
         if self._anom is not None:
@@ -3428,9 +3473,14 @@ class InferenceEngine:
         gather a dense READ-ONLY prefix of every live context, scan
         ``steps`` decode iterations carrying only the tiny in-burst KV
         tail, then scatter the tail into the (donated) paged cache.
-        Carrying the paged cache itself through the scan copies the full
-        pool every iteration (~80 ms/iter for a GPT-2-sized pool on a
-        v5e) — the prefix/tail split removes that entirely."""
+        Carrying the paged cache itself through this scan copied the
+        full pool every iteration on an older rig (~80 ms/iter for a
+        GPT-2-sized pool) and the prefix/tail split removed that.  The
+        step's layer scan has since shown that the TPU compiler keeps a
+        carried pool in place when the body writes it and then reads it
+        once (``ragged_forward``: 39 ms of a 112 ms decode step back on
+        a v5e); the burst keeps its split, which also spares the
+        block-table indirection, and has not been retried carried."""
         from .model import (decode_burst_forward, scatter_tail,
                             snapshot_prefix)
 

@@ -65,20 +65,43 @@ def _dequant_ctx(data, scales, dt):
             * scales[..., None]).astype(dt)
 
 
-def _write_kv(kv_layer, k, v, batch: RaggedBatch, block_size: int):
+def _layer_of(pool, layer):
+    """``(base, rows)``: where in ``pool`` the layer at hand keeps its
+    ``rows`` rows (its blocks and, last, its trash row).  ``None`` is a
+    pool that holds one layer and nothing else; the layer scan passes
+    ``(li * rows, rows)`` for layer ``li`` of the stacked cache viewed
+    as ``[L * rows, ...]``."""
+    return (0, _kv_parts(pool)[0].shape[0]) if layer is None else layer
+
+
+def _layer_tables(tables, layer):
+    """Block ids gathered from ``RaggedBatch.block_tables`` → rows of
+    the pool: the -1 pads go to the layer's trash row, then every id
+    moves by the layer's base.  The cache write and all three attention
+    formulations index the pool by these rows and nothing else, which
+    is how they address ``(layer, block)`` without a slice.  Applied
+    AFTER the gather, so the gather itself reads the same table for
+    every layer."""
+    base, rows = layer
+    return jnp.where(tables < 0, rows - 1, tables) + base
+
+
+def _write_kv(kv_layer, k, v, batch: RaggedBatch, block_size: int,
+              layer=None):
     """Scatter per-token K/V into the paged cache (quantizing on write
     when the cache is a (data, scales) pair).
 
     kv_layer: [blocks, bs, 2, Hkv, D]; k/v: [T, Hkv, D]
     (reference kernel: linear_blocked_kv_rotary / linear_kv_copy).
+    ``layer``: see ``_layer_of``.
     """
     data, scales = _kv_parts(kv_layer)
+    base, rows = _layer_of(kv_layer, layer)
     blk = batch.block_tables[batch.seq_slot,
                              batch.positions // block_size]      # [T]
     # budget-padding tokens write to the trash block (last row) so they
     # can never clobber a live sequence's KV
-    trash = data.shape[0] - 1
-    blk = jnp.where(batch.token_valid, blk, trash)
+    blk = jnp.where(batch.token_valid, blk + base, base + rows - 1)
     off = batch.positions % block_size                           # [T]
     if scales is None:
         data = data.at[blk, off, 0].set(k)
@@ -95,7 +118,8 @@ def _write_kv(kv_layer, k, v, batch: RaggedBatch, block_size: int):
 
 def _paged_attention_pallas(kv_layer, q, batch: RaggedBatch,
                             block_size: int, max_blocks_per_seq: int,
-                            scale: float, shard_mesh=None, slopes=None):
+                            scale: float, shard_mesh=None, slopes=None,
+                            layer=None):
     """Pallas streaming kernel behind the same signature
     (ops/paged_attention.py — reference: blocked_flash).
 
@@ -103,13 +127,16 @@ def _paged_attention_pallas(kv_layer, q, batch: RaggedBatch,
     attention is embarrassingly parallel over heads, so each chip streams
     only its own head group's KV blocks (kv head-split on the ``tensor``
     mesh axis) — the TPU analog of the reference's TP-aware blocked_flash
-    dispatch (inference/v2/model_implementations/sharding/attn.py)."""
+    dispatch (inference/v2/model_implementations/sharding/attn.py).
+    ``layer``: ``(base, rows)`` of the layer inside a stacked pool, see
+    the kernel."""
     from ..ops.paged_attention import paged_attention
 
     if shard_mesh is None:
         return paged_attention(kv_layer, q, batch.seq_slot, batch.positions,
                                batch.block_tables, block_size,
-                               max_blocks_per_seq, scale, slopes=slopes)
+                               max_blocks_per_seq, scale, slopes=slopes,
+                               layer=layer)
     from jax.sharding import PartitionSpec as P
 
     from ..comm.mesh import TENSOR_AXIS
@@ -118,17 +145,18 @@ def _paged_attention_pallas(kv_layer, q, batch: RaggedBatch,
     kv_spec = (data_spec if not isinstance(kv_layer, tuple)
                else (data_spec, P(None, None, None, TENSOR_AXIS)))
     q_spec = P(None, TENSOR_AXIS, None)               # [T, H, D]
-    in_specs = [kv_spec, q_spec, P(), P(), P()]
+    base, rows = _layer_of(kv_layer, layer)
+    in_specs = [kv_spec, q_spec, P(), P(), P(), P()]
     operands = [kv_layer, q, batch.seq_slot, batch.positions,
-                batch.block_tables]
+                batch.block_tables, jnp.asarray(base, jnp.int32)]
     if slopes is not None:
         in_specs.append(P(TENSOR_AXIS, None))   # slopes [Hkv, rep] split
         operands.append(jnp.asarray(slopes, jnp.float32).reshape(
             _kv_parts(kv_layer)[0].shape[3], -1))   # with the kv heads
     f = shard_map(
-        lambda kvl, qq, ss, pos, bt, *sl: paged_attention(
+        lambda kvl, qq, ss, pos, bt, b, *sl: paged_attention(
             kvl, qq, ss, pos, bt, block_size, max_blocks_per_seq, scale,
-            slopes=sl[0] if sl else None),
+            slopes=sl[0] if sl else None, layer=(b, rows)),
         mesh=shard_mesh,
         in_specs=tuple(in_specs),
         out_specs=q_spec, check_vma=False)
@@ -143,7 +171,8 @@ _ONE_SHOT_GATHER_BYTES = 512 * 1024 * 1024
 
 
 def _paged_attention(kv_layer, q, batch: RaggedBatch, block_size: int,
-                     max_blocks_per_seq: int, scale: float, slopes=None):
+                     max_blocks_per_seq: int, scale: float, slopes=None,
+                     layer=None):
     """Per-token attention over the owning sequence's context
     (reference kernel: blocked_flash / flash_attn_by_atoms).
 
@@ -163,10 +192,12 @@ def _paged_attention(kv_layer, q, batch: RaggedBatch, block_size: int,
     if gather_bytes > _ONE_SHOT_GATHER_BYTES:
         return _paged_attention_chunked(kv_layer, q, batch, block_size,
                                         max_blocks_per_seq, scale,
-                                        slopes=slopes)
+                                        slopes=slopes, layer=layer)
     rep = H // Hkv
 
-    tables = batch.block_tables[batch.seq_slot, :max_blocks_per_seq]  # [T, nb]
+    tables = _layer_tables(
+        batch.block_tables[batch.seq_slot, :max_blocks_per_seq],
+        _layer_of(kv_layer, layer))                                # [T, nb]
     ctx = data[tables]                # [T, nb, bs, 2, Hkv, D]
     ctx = ctx.reshape(T, C, 2, Hkv, D)
     k_ctx, v_ctx = ctx[:, :, 0], ctx[:, :, 1]                     # [T, C, Hkv, D]
@@ -190,7 +221,7 @@ def _paged_attention(kv_layer, q, batch: RaggedBatch, block_size: int,
 
 def _paged_attention_chunked(kv_layer, q, batch: RaggedBatch,
                              block_size: int, max_blocks_per_seq: int,
-                             scale: float, slopes=None):
+                             scale: float, slopes=None, layer=None):
     """Streaming XLA paged attention: scan over the block-table columns,
     gathering ONE context block per step ([T, bs, 2, Hkv, D]) and folding
     it into an online-softmax accumulator — same numerics as the
@@ -201,13 +232,15 @@ def _paged_attention_chunked(kv_layer, q, batch: RaggedBatch,
     rep = H // Hkv
     bs = block_size
 
-    tables = batch.block_tables[batch.seq_slot, :max_blocks_per_seq]  # [T, nb]
+    tables = _layer_tables(
+        batch.block_tables[batch.seq_slot, :max_blocks_per_seq],
+        _layer_of(kv_layer, layer))                                # [T, nb]
     qg = q.reshape(T, Hkv, rep, D)
     offs = jnp.arange(bs)
 
     def fold(carry, j):
         m, l, acc = carry
-        blk = tables[:, j]                          # [T] (-1 pad -> trash)
+        blk = tables[:, j]                          # [T]
         ctx = data[blk]                             # [T, bs, 2, Hkv, D]
         k, v = ctx[:, :, 0], ctx[:, :, 1]           # [T, bs, Hkv, D]
         if scales is not None:
@@ -382,9 +415,15 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
     ``quant``: ZeRO-Inference weight-quant tree (inference/quantization
     ``quantize_model_params``) — one layer is dequantized at a time
     inside the scan body, so dense weights never all coexist in HBM.
-    ``kv_host``: the cache lives in host memory; each scan step streams
-    one layer through HBM and writes it back (ZeRO-Inference KV offload)
-    so device memory holds a single layer's KV at a time.
+    The cache in device memory rides the layer scan as a carry that
+    every layer updates in place: the stack is viewed as
+    ``[L * rows, ...]`` and layer ``li`` addresses its rows by block ids
+    moved by ``li * rows`` (``_layer_of``), so no layer is ever
+    sliced out of the stack or written back into it.
+    ``kv_host``: the cache lives in host memory; there the scan takes it
+    as a scanned input and output, and each step streams one layer
+    through HBM and writes it back (ZeRO-Inference KV offload) so device
+    memory holds a single layer's KV at a time.
     ``stream``: an :class:`~.weight_stream.NVMeWeightStore` — the layer
     scan fetches each layer's (possibly quantized) weights from NVMe via
     ``io_callback`` so HBM holds one layer's weights at a time
@@ -421,17 +460,24 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
     else:
         cos, sin = L.rope_freqs(cfg.rotary_dim, cfg.max_seq_len, cfg.rope_theta)
 
-    def block(x, xs):
+    def layer_weights(ws):
+        """One layer's weights from the scanned inputs: ``(lp, li)``,
+        or ``(li,)`` alone when the weights stream from NVMe."""
         if stream is None:
-            lp, kv_layer, li = xs
+            lp, li = ws
         else:
-            kv_layer, li = xs
+            (li,) = ws
             lp = _stream_layer(stream, li, dt, mixed_gemm=mixed_gemm)
-        if kv_host:
-            kv_layer = jax.device_put(kv_layer, jax.memory.Space.Device)
         if quant is not None:
             lp = merge_layer(lp, quant["blocks"], li, dt,
                              mixed=mixed_gemm)
+        return lp, li
+
+    def block(x, lp, pool, layer):
+        """One layer's mathematics.  ``pool`` is the paged cache that
+        holds the layer where ``layer`` says (``_layer_of``): a layer's
+        own slice or the whole stacked cache, the block cannot tell
+        which."""
         ap = lp["attn"]
         # named scopes at the block's seams (metadata only): a device
         # trace's operations carry them in their JAX path, which is how
@@ -441,16 +487,17 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
             q, k, v = _qkv_proj(cfg, ap, h, dt, cos, sin,
                                 batch.positions)
         with jax.named_scope("kv_write"):
-            kv_layer = _write_kv(kv_layer, k, v, batch, block_size)
+            pool = _write_kv(pool, k, v, batch, block_size, layer=layer)
         with jax.named_scope("attn"):
             if attn_impl == "pallas":
                 o = _paged_attention_pallas(
-                    kv_layer, q, batch, block_size, max_blocks_per_seq,
-                    scale, shard_mesh=shard_mesh, slopes=slopes)
+                    pool, q, batch, block_size, max_blocks_per_seq,
+                    scale, shard_mesh=shard_mesh, slopes=slopes,
+                    layer=layer)
             else:
-                o = _paged_attention(kv_layer, q, batch, block_size,
+                o = _paged_attention(pool, q, batch, block_size,
                                      max_blocks_per_seq, scale,
-                                     slopes=slopes)
+                                     slopes=slopes, layer=layer)
         with jax.named_scope("attn_out"):
             o = _mm(o.reshape(o.shape[0], -1), ap["wo"], dt,
                     contract_dims=2)
@@ -464,18 +511,39 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
                 h = norm(lp["ln2"], x)  # gpt-neox: MLP norms the original x
             # parallel residual (falcon/phi): MLP reads the same ln1 output
             d = _ffn(cfg, lp, h, dt, act, comm=comm)
-        if kv_host:
-            kv_layer = jax.device_put(kv_layer, jax.memory.Space.Host)
         if cfg.parallel_block:
-            return x + o + d, kv_layer
-        return x + d, kv_layer
+            return x + o + d, pool
+        return x + d, pool
 
     layer_ids = jnp.arange(cfg.num_layers, dtype=jnp.int32)
-    if stream is None:
-        x, new_kv = jax.lax.scan(block, x,
-                                 (params["blocks"], kv, layer_ids))
+    layers = ((layer_ids,) if stream is not None
+              else (params["blocks"], layer_ids))
+    rows = _kv_parts(kv)[0].shape[1]       # a layer's blocks + trash row
+
+    def streamed(x, xs):
+        # the cache lives in host memory: the scan slices one layer out,
+        # through HBM and back, which here IS the mechanism
+        *ws, kv_layer = xs
+        lp, _ = layer_weights(ws)
+        kv_layer = jax.device_put(kv_layer, jax.memory.Space.Device)
+        x, kv_layer = block(x, lp, kv_layer, None)
+        return x, jax.device_put(kv_layer, jax.memory.Space.Host)
+
+    def carried(carry, ws):
+        # the cache lives in device memory: it rides the scan as a carry
+        # that each layer updates in place, and the layer is an offset
+        # into the stacked pool (no per-layer slice, no second pool)
+        x, pool = carry
+        lp, li = layer_weights(ws)
+        x, pool = block(x, lp, pool, (li * rows, rows))
+        return (x, pool), None
+
+    if kv_host:
+        x, new_kv = jax.lax.scan(streamed, x, (*layers, kv))
     else:
-        x, new_kv = jax.lax.scan(block, x, (kv, layer_ids))
+        pool = jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), kv)
+        (x, pool), _ = jax.lax.scan(carried, (x, pool), layers)
+        new_kv = jax.tree.map(lambda a, o: a.reshape(o.shape), pool, kv)
 
     with jax.named_scope("unembed"):
         return _unembed(cfg, params, embed_tab, x, batch, norm, dt,
@@ -584,8 +652,12 @@ def pipelined_ragged_step(cfg: TransformerConfig, params, quant, kv,
 def snapshot_prefix(kv, block_tables, P: int, block_size: int):
     """Gather each slot's first ``P`` context tokens into a dense
     read-only buffer [L, S, P, 2, Hkv, D] (the burst's attention operand;
-    gathered ONCE per burst, never carried through the scan — carrying
-    the paged cache itself copies it every iteration).  A quantized
+    gathered ONCE per burst, never carried through the burst's scan over
+    decode iterations.  On an older rig that scan copied a carried pool
+    every iteration; ``ragged_forward``'s layer scan now does carry the
+    pool, in place, on a TPU v5e — one write then one read of it per
+    body — but the burst has not been retried in that form: its dense
+    prefix also spares the block-table indirection).  A quantized
     cache snapshots as a (codes, scales [L, S, P, 2, Hkv]) pair — the
     burst dequantizes per layer in its attention, so the snapshot stays
     1 byte/element."""

@@ -44,12 +44,15 @@ def _use_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _kernel(tables_ref, pos_ref, q_ref, kv_ref, *rest,
+def _kernel(tables_ref, pos_ref, *rest,
             block_size: int, scale: float,
             num_kv_heads: int, rep: int, alibi: bool, kv_quant: bool):
     # optional trailing inputs (order: kv scales, alibi slopes) before
     # the output and scratch refs
     rest = list(rest)
+    if kv_quant:
+        rest.pop(0)     # the scales' row offset: the index map's alone
+    q_ref, kv_ref = rest.pop(0), rest.pop(0)
     ks_ref = rest.pop(0) if kv_quant else None
     slopes_ref = rest.pop(0) if alibi else None
     o_ref, acc_ref, m_ref, l_ref = rest
@@ -107,7 +110,7 @@ def _kernel(tables_ref, pos_ref, q_ref, kv_ref, *rest,
 
 def paged_attention(kv_layer, q, seq_slot, positions, block_tables,
                     block_size: int, max_blocks_per_seq: int, scale: float,
-                    slopes=None):
+                    slopes=None, layer=None):
     """kv_layer: [blocks+1, bs, 2, Hkv, D] (last row = trash), or a
     (data, scales) tuple for a quantized cache (scales
     [blocks+1, bs, 2, Hkv] f32; codes dequantized in VMEM so HBM only
@@ -117,7 +120,17 @@ def paged_attention(kv_layer, q, seq_slot, positions, block_tables,
     ``slopes``: optional ALiBi per-head slopes, any shape reshapeable to
     [Hkv, rep] in head order h = hkv*rep + r (reference analog: the alibi
     operand of the inference softmax kernels, csrc/transformer/inference/
-    csrc/softmax.cu)."""
+    csrc/softmax.cu).
+    ``layer``: ``(base, rows)`` when ``kv_layer`` is the stacked cache
+    viewed as ``[L * rows, ...]`` and this call attends layer ``li``,
+    whose ``rows`` rows (its blocks, then its trash row) start at row
+    ``base = li * rows``; ``None`` is a pool of one layer.  The codes
+    need only the offset: the index map picks row ``base + block`` as
+    it picks any row, and the DMA engine reads it where it lies.  The
+    scales are cut to the layer's rows first and indexed without the
+    offset, because Mosaic wants them in a lane-padded layout of its
+    own: a layer's worth is relaid per call as before, never the
+    stack's."""
     kv_scales = None
     if isinstance(kv_layer, tuple):
         kv_layer, kv_scales = kv_layer
@@ -126,35 +139,37 @@ def paged_attention(kv_layer, q, seq_slot, positions, block_tables,
     rep = H // Hkv
     nb = max_blocks_per_seq
 
+    base, rows = (0, nblocks) if layer is None else layer
     tables = block_tables[seq_slot, :nb]                   # [T, nb]
-    tables = jnp.where(tables < 0, nblocks - 1, tables).astype(jnp.int32)
+    tables = (jnp.where(tables < 0, rows - 1, tables)
+              + base).astype(jnp.int32)
     positions = positions.astype(jnp.int32)
 
-    def _kv_index(t, j, tbl, pos):
+    def _kv_index(t, j, tbl, pos, *_):
         # clamp past-position block indices to the last needed block:
         # consecutive grid steps then revisit the same block and Pallas
         # skips the DMA entirely (the kernel skips the compute)
         jj = jnp.minimum(j, pos[t] // bs)
         return (tbl[t, jj], 0, 0, 0, 0)
 
-    def _ks_index(t, j, tbl, pos):
+    def _ks_index(t, j, tbl, pos, base):
         jj = jnp.minimum(j, pos[t] // bs)
-        return (tbl[t, jj], 0, 0, 0)
+        return (tbl[t, jj] - base[0], 0, 0, 0)
 
     alibi = slopes is not None
     kv_quant = kv_scales is not None
+    prefetch = [tables, positions]
     in_specs = [
-        pl.BlockSpec((1, H, D),
-                     lambda t, j, tbl, pos: (t, 0, 0)),
+        pl.BlockSpec((1, H, D), lambda t, j, *_: (t, 0, 0)),
         pl.BlockSpec((1, bs, 2, Hkv, D), _kv_index),
     ]
-    operands = [tables, positions, q, kv_layer]
+    operands = [q, kv_layer]
     if kv_quant:
+        prefetch.append(jnp.reshape(base, (1,)).astype(jnp.int32))
         in_specs.append(pl.BlockSpec((1, bs, 2, Hkv), _ks_index))
-        operands.append(kv_scales)
+        operands.append(jax.lax.dynamic_slice_in_dim(kv_scales, base, rows))
     if alibi:
-        in_specs.append(pl.BlockSpec((Hkv, rep),
-                                     lambda t, j, tbl, pos: (0, 0)))
+        in_specs.append(pl.BlockSpec((Hkv, rep), lambda t, j, *_: (0, 0)))
         operands.append(jnp.asarray(slopes, jnp.float32)
                         .reshape(Hkv, rep))
 
@@ -164,11 +179,10 @@ def paged_attention(kv_layer, q, seq_slot, positions, block_tables,
                           num_kv_heads=Hkv, rep=rep, alibi=alibi,
                           kv_quant=kv_quant),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=len(prefetch),
             grid=grid,
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, H, D),
-                                   lambda t, j, tbl, pos: (t, 0, 0)),
+            out_specs=pl.BlockSpec((1, H, D), lambda t, j, *_: (t, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((H, D), jnp.float32),
                 pltpu.VMEM((H, 1), jnp.float32),
@@ -178,5 +192,5 @@ def paged_attention(kv_layer, q, seq_slot, positions, block_tables,
         out_shape=jax.ShapeDtypeStruct((T, H, D), q.dtype),
         interpret=_use_interpret(),
         name="paged_attention",
-    )(*operands)
+    )(*prefetch, *operands)
     return out

@@ -647,3 +647,55 @@ class TestQuantizedKV:
         q = self._outs(m, kv_quant="int8")
         qp = self._outs(m, kv_quant="int8", attn_impl="pallas")
         assert q == qp == ref
+
+
+class TestServingProgramRecord:
+    """Each compiled serving bucket notes once, at its first call, which
+    form its layer scan took and what the program needs beside its
+    arguments (``engine.serving_programs``, two pull gauges) — read from
+    the executable the call built, so nothing compiles a second time."""
+
+    @pytest.mark.parametrize("over", [
+        {}, {"kv_quant": "int8"}, {"kv_donate": "off"},
+        {"attn_impl": "pallas", "pipeline_depth": 1}],
+        ids=["bf", "int8kv", "no-donation", "pallas-sync"])
+    def test_noted_once_without_a_second_compile(self, over):
+        from jax import monitoring
+        from jax._src.monitoring import unregister_event_duration_listener
+
+        compiles = []
+
+        def on_duration(name, _secs, **_kw):
+            if name.endswith("backend_compile_duration"):
+                compiles.append(name)
+
+        eng = make_fp32_engine(tiny_model(), **over)
+        assert eng.serving_programs == {}
+        assert "serving_step_temp_bytes" not in eng.metrics_snapshot()
+        noted = []
+        note = eng._note_program
+
+        def counted(key, fn, args):
+            before = len(compiles)
+            note(key, fn, args)
+            noted.append((key, len(compiles) - before))
+
+        eng._note_program = counted
+        monitoring.register_event_duration_secs_listener(on_duration)
+        try:
+            sp = SamplingParams(temperature=0.0, max_new_tokens=20)
+            out = eng.generate({0: list(range(1, 30)), 1: [5, 6, 7]}, sp)
+        finally:
+            unregister_event_duration_listener(on_duration)
+        assert len(out[0]) == 20
+        # one note a bucket (16-token blocks: 29 + 20 tokens reach the
+        # 2- and 4-block buckets), none of which compiled anything
+        assert sorted(k for k, _ in noted) == sorted(eng._pstep_fns)
+        assert len(noted) >= 2 and all(n == 0 for _, n in noted)
+        for rec in eng.serving_programs.values():
+            assert rec["form"] == "carried"
+            assert isinstance(rec["temp_bytes"], int)
+        snap = eng.metrics_snapshot()
+        assert snap["serving_step_cache_carried"] == 1.0
+        assert snap["serving_step_temp_bytes"] == max(
+            r["temp_bytes"] for r in eng.serving_programs.values())

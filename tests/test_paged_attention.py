@@ -158,6 +158,139 @@ class TestAliasedBlockTables:
                                    atol=1e-6, rtol=1e-6)
 
 
+class TestCarriedCache:
+    """The layer scan carries the stacked cache ``[L, rows, ...]`` in
+    place and every layer addresses its own rows in it
+    (``model._layer_tables``); a cache in host memory keeps the scanned
+    form of the parent commit, one layer sliced out at a time.  The two
+    forms are one piece of mathematics: same logits, same cache."""
+
+    L, BS, NBLK, T, SEQS = 3, 8, 12, 16, 4
+
+    def _inputs(self, kv_quant, seed=0):
+        import deepspeed_tpu  # noqa: F401  (registers presets)
+        from tests.test_inference import tiny_model
+
+        m = tiny_model(num_layers=self.L)
+        cfg = m.config
+        r = np.random.RandomState(seed)
+        shape = (self.L, self.NBLK + 1, self.BS, 2, cfg.num_kv_heads,
+                 cfg.head_dim)
+        # a cache that is nowhere zero and differs from layer to layer:
+        # a read of another layer's rows cannot pass for the right one
+        if kv_quant:
+            kv = (jnp.asarray(r.randint(-127, 128, shape), jnp.int8),
+                  jnp.asarray(r.uniform(0.01, 0.03, shape[:-1]),
+                              jnp.float32))
+        else:
+            kv = jnp.asarray(r.randn(*shape), jnp.float32)
+        # seq 0 decodes at 19 (blocks 5, 2, 9); seq 1 prefills the chunk
+        # 4..11 of a context whose first 4 tokens are already cached
+        # (blocks 1, 7): a chunk that starts at a non-zero offset, in
+        # the middle of a block; seq 2 decodes at 0 (block 4); the last
+        # six tokens are budget padding.  Every layer shares these ids:
+        # block 5 of layer 0 and block 5 of layer 2 are different rows
+        tables = np.full((self.SEQS, self.NBLK), -1, np.int32)
+        tables[0, :3] = [5, 2, 9]
+        tables[1, :2] = [1, 7]
+        tables[2, :1] = [4]
+        tok_pos = [(0, 19)] + [(1, p) for p in range(4, 12)] + [(2, 0)]
+        n = len(tok_pos)
+        positions = np.zeros(self.T, np.int32)
+        seq_slot = np.zeros(self.T, np.int32)
+        valid = np.zeros(self.T, bool)
+        for i, (s_, p_) in enumerate(tok_pos):
+            seq_slot[i], positions[i], valid[i] = s_, p_, True
+        logits_idx = np.full(self.SEQS, -1, np.int32)
+        logits_idx[:3] = [0, 8, 9]
+        batch = RaggedBatch(
+            token_ids=jnp.asarray(r.randint(1, 128, self.T), jnp.int32),
+            positions=jnp.asarray(positions),
+            seq_slot=jnp.asarray(seq_slot),
+            token_valid=jnp.asarray(valid),
+            block_tables=jnp.asarray(tables),
+            context_lens=jnp.asarray([20, 12, 1, 0], jnp.int32),
+            logits_idx=jnp.asarray(logits_idx), n_tokens=n, n_seqs=3)
+        # (block, offset) that each live token writes
+        written = {(tables[s_, p_ // self.BS], p_ % self.BS)
+                   for s_, p_ in tok_pos}
+        return m, kv, batch, written
+
+    @staticmethod
+    def _forward(m, kv, batch, bs, monkeypatch=None, **kw):
+        from deepspeed_tpu.inference import model as im
+        if kw.get("kv_host"):
+            # this backend cannot run in-program host transfers; the
+            # move between memory spaces is not what is compared
+            monkeypatch.setattr(im.jax, "device_put",
+                                lambda x, *a, **k: x)
+        def f(params, kv):
+            return im.ragged_forward(m.config, params, kv, batch, bs, 4,
+                                     **kw)
+        return f, jax.jit(f)(m.params, kv)
+
+    @pytest.mark.parametrize("attn_impl", ["xla", "pallas"])
+    @pytest.mark.parametrize("kv_quant", [False, True],
+                             ids=["fp", "int8kv"])
+    def test_carried_matches_streamed(self, kv_quant, attn_impl,
+                                      monkeypatch):
+        m, kv, batch, written = self._inputs(kv_quant)
+        f_c, (logits_c, kv_c) = self._forward(m, kv, batch, self.BS,
+                                              attn_impl=attn_impl)
+        f_s, (logits_s, kv_s) = self._forward(
+            m, kv, batch, self.BS, monkeypatch, attn_impl=attn_impl,
+            kv_host=True)
+        # which form each took: the pool is in the scan's carry, or
+        # among its scanned inputs and outputs
+        n_pool = 2 if kv_quant else 1
+        for f, carried in ((f_c, True), (f_s, False)):
+            scan = [e for e in jax.make_jaxpr(f)(m.params, kv).eqns
+                    if e.primitive.name == "scan"][-1]
+            assert scan.params["num_carry"] == 1 + n_pool * carried
+        rows = np.asarray(logits_idx_rows(batch))
+        np.testing.assert_allclose(np.asarray(logits_c)[rows],
+                                   np.asarray(logits_s)[rows],
+                                   rtol=1e-6, atol=1e-6)
+        for new_c, new_s, old in zip(jax.tree.leaves(kv_c),
+                                     jax.tree.leaves(kv_s),
+                                     jax.tree.leaves(kv)):
+            new_c, new_s, old = map(np.asarray, (new_c, new_s, old))
+            assert new_c.shape == old.shape
+            np.testing.assert_array_equal(new_c, new_s)
+            trash = old.shape[1] - 1
+            for li in range(self.L):
+                changed = {(int(b), int(o)) for b, o in zip(*np.nonzero(
+                    (new_c[li] != old[li]).reshape(
+                        old.shape[1], old.shape[2], -1).any(-1)))}
+                # layer li took its tokens in its own rows, its padding
+                # in its own trash row, and nothing anywhere else
+                assert changed - {(trash, 0)} == written, li
+                assert (trash, 0) in changed, li
+
+    def test_layers_do_not_share_rows(self):
+        """Two layers, one block id: a step with layer 1's rows of the
+        cache scrambled beforehand gives the same layer-0 cache and
+        different logits, and a scrambled layer 0 trash row changes
+        nothing (padding reads and writes only trash)."""
+        m, kv, batch, _ = self._inputs(False)
+        f, (logits, new) = self._forward(m, kv, batch, self.BS)
+        other = kv.at[1].set(kv[1][::-1])
+        logits_o, new_o = jax.jit(f)(m.params, other)
+        np.testing.assert_array_equal(np.asarray(new_o[0]),
+                                      np.asarray(new[0]))
+        rows = np.asarray(logits_idx_rows(batch))
+        assert np.abs(np.asarray(logits_o)[rows]
+                      - np.asarray(logits)[rows]).max() > 1e-3
+        trashed = kv.at[0, -1].set(7.0)
+        logits_t, _ = jax.jit(f)(m.params, trashed)
+        np.testing.assert_array_equal(np.asarray(logits_t)[rows],
+                                      np.asarray(logits)[rows])
+
+
+def logits_idx_rows(batch):
+    return np.nonzero(np.asarray(batch.logits_idx) >= 0)[0]
+
+
 def SamplingParams_greedy():
     from deepspeed_tpu.inference import SamplingParams
     return SamplingParams(temperature=0.0, max_new_tokens=6)

@@ -16,6 +16,7 @@ live in this one file and compile in the test's own process.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -148,3 +149,96 @@ def test_mixed_gemm_compiles(one_chip, on_chip, bits, M):
                          jnp.bfloat16,
                          "rowwise" if bits == 8 else "rowwise4")
     assert _compile(mixed_matmul, S((M, K), jnp.bfloat16), qt) == 1
+
+
+# --------------------------------------------------------- serving step
+# the whole pipelined serving step of mistral-7b-d16 (the benchmark's
+# serving configuration: 16 layers, a pool of 1024 blocks of 64 tokens,
+# 512 tokens and 64 sequences a step, the 16-block decode bucket) with
+# the cache donated, as ``InferenceEngine._build_pstep`` jits it
+_ITEM = {"bf16": 2, "f32": 4, "s8": 1, "s32": 4, "u32": 4, "pred": 1}
+_MOVES = ("copy", "dynamic-slice", "dynamic-update-slice")
+
+
+def _moves_of(text: str, floor: int):
+    """Instructions outside fused computations that copy, slice or
+    update-slice (alone or as the fusion XLA names after them) and
+    whose result holds ``floor`` bytes or more."""
+    found, fused = [], False
+    for line in text.splitlines():
+        if line and not line[0].isspace():      # a computation's head
+            fused = line.startswith("%fused_computation")
+        m = re.match(r"\s+(?:ROOT )?%([\w.\-]+) = (.*?) ([\w\-]+)\(", line)
+        if fused or not m:
+            continue
+        name, result, op = m.groups()
+        if not (op.startswith(_MOVES) or (op == "fusion" and any(
+                k in name for k in _MOVES))):
+            continue
+        for dt, dims in re.findall(r"(\w+)\[([\d,]+)\]", result):
+            n = _ITEM.get(dt, 0)
+            for d in dims.split(","):
+                n *= int(d)
+            if n >= floor:
+                found.append(f"{name}: {dt}[{dims}]")
+    return found
+
+
+@pytest.mark.parametrize("kv_quant", [False, True],
+                         ids=["mistral-7b-d16-bf16", "mistral-7b-d16-int8kv"])
+def test_serving_step_keeps_the_pool_in_place(one_chip, on_chip, kv_quant):
+    """The layer scan carries the paged cache and the kernels address
+    ``(layer, block)`` in it: the compiled step holds no temporary of a
+    layer's share of the pool (it held the whole pool, 4.0 GiB, while
+    the cache was a scanned input and output), and neither its entry
+    computation nor its ``while`` body copies, slices or update-slices
+    that much.
+
+    ``kv_host`` is left out on purpose: a cache in host memory keeps the
+    scanned form, where slicing one layer out, through HBM and back is
+    the mechanism and a layer-sized copy is what it is for."""
+    from deepspeed_tpu.inference import SamplingParams
+    from deepspeed_tpu.inference.model import pipelined_ragged_step
+    from deepspeed_tpu.inference.ragged.state import RaggedBatch
+    from deepspeed_tpu.inference.sampler import sample_rows
+    from deepspeed_tpu.models.presets import build_config
+    from deepspeed_tpu.models.transformer import init_params
+
+    cfg = build_config("mistral-7b", num_layers=16)
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    params = jax.tree.map(
+        lambda a: S(a.shape, jnp.bfloat16),
+        jax.eval_shape(lambda k: init_params(cfg, k)[0],
+                       jax.random.PRNGKey(0)))
+    T, seqs, bs, mbs, blocks = 512, 64, 64, 16, 1024
+    pool = (cfg.num_layers, blocks + 1, bs, 2, cfg.num_kv_heads,
+            cfg.head_dim)
+    kv = S(pool, jnp.int8 if kv_quant else jnp.bfloat16)
+    layer_bytes = kv.size * kv.dtype.itemsize // cfg.num_layers
+    if kv_quant:
+        kv = (kv, S(pool[:-1], jnp.float32))
+    tok = S((T,), jnp.int32)
+    batch = RaggedBatch(
+        token_ids=tok, positions=tok, seq_slot=tok,
+        token_valid=S((T,), jnp.bool_),
+        block_tables=S((seqs, 32), jnp.int32),
+        context_lens=S((seqs,), jnp.int32),
+        logits_idx=S((seqs,), jnp.int32), n_tokens=T, n_seqs=seqs,
+        feedback_src=tok, seq_uids=S((seqs,), jnp.uint32))
+    greedy = SamplingParams(temperature=0.0, max_new_tokens=1)
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+
+    def pstep(params, kv, batch, prev, rng):
+        return pipelined_ragged_step(
+            cfg, params, None, kv, batch, prev, rng,
+            lambda logits, keys: sample_rows(logits, greedy, keys),
+            bs, mbs, attn_impl="pallas")
+
+    compiled = jax.jit(pstep, donate_argnums=(1,)).lower(
+        params, kv, batch, S((seqs,), jnp.int32),
+        S(key.shape, key.dtype)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert _moves_of(text, 1), "the reader no longer finds any copy"
+    assert _moves_of(text, layer_bytes) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
