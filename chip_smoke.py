@@ -20,7 +20,12 @@ checks what comes out by the repo's own means:
            agree with a plain non-paged ``model.apply`` of the same
            weights; HTTP tokens equal the in-process ones;
 * serve-int8  the same widths with int8 weights, so the mixed-GEMM race
-           runs too.
+           runs too;
+* serve-moe  ``InferenceEngine.generate`` on the olmoe-1b-7b preset at
+           its published widths (64 experts, top-8, QK-norm, 16/16 heads;
+           depth cut, printed): the grouped expert kernel against
+           ``jax.lax.ragged_dot`` on its shapes, then first-token logits
+           against the dropless ``model.apply`` of the same weights.
 
 ``--chips 4`` runs ONLY the sharded paths and what they are compared
 with: ZeRO-3 over ``fsdp=4`` against a one-device topology (loss
@@ -65,6 +70,7 @@ REAL = dict(
                               attention_impl="xla_flash"),
                seq=1024, batch=32, steps=5),
     serve=dict(overrides=dict(max_seq_len=512), layers=8, int8_layers=2,
+               moe_layers=2, moe_overrides=dict(max_seq_len=512),
                token_budget=1024, max_seqs=8, block=64, blocks=128,
                prompt_lens=(311, 257, 203), new_tokens=16),
     flash={"gpt2": (4, 1024, 12, 12, 64), "llama3-8b": (1, 2048, 32, 8, 128)},
@@ -78,7 +84,11 @@ TINY = dict(
                seq=64, batch=4, steps=4),
     serve=dict(overrides=dict(max_seq_len=128, d_model=64, num_heads=4,
                               num_kv_heads=4, d_ff=128, vocab_size=512),
-               layers=2, int8_layers=2, token_budget=64, max_seqs=4,
+               layers=2, int8_layers=2, moe_layers=2,
+               moe_overrides=dict(max_seq_len=128, d_model=64, num_heads=4,
+                                  num_kv_heads=4, d_ff=32, vocab_size=512,
+                                  num_experts=8, moe_top_k=4),
+               token_budget=64, max_seqs=4,
                block=8, blocks=64, prompt_lens=(21, 17, 9), new_tokens=6),
     flash={"gpt2": (1, 128, 4, 4, 32), "llama3-8b": (1, 128, 4, 2, 32)},
     paged=dict(T=16, H=4, Hkv=2, D=32, block=8, blocks=16, nb=4),
@@ -596,6 +606,61 @@ def serve_int8_phase(sz, seed):
                        INT8_LOGIT_REL)
 
 
+def serve_moe_phase(sz, seed):
+    """Sparse experts served dropless through the grouped kernel, at the
+    widths of olmoe-1b-7b, by the entry point the dense model used."""
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.inference import InferenceEngine, SamplingParams
+    from deepspeed_tpu.models.presets import build_config
+    from deepspeed_tpu.ops.grouped_matmul import grouped_matmul
+
+    s = sz["serve"]
+    # moe_dispatch is the TRAINING forward's: the plain ``model.apply``
+    # below must drop nothing either; serving never reads it
+    cfg = build_config("olmoe-1b-7b", num_layers=s["moe_layers"],
+                       moe_dispatch="ragged", **s["moe_overrides"])
+    E, rows = cfg.num_experts, s["token_budget"] * cfg.moe_top_k
+    kx, kw, kg = jax.random.split(jax.random.PRNGKey(seed + 2), 3)
+    x = jax.random.normal(kx, (rows, cfg.d_model), jnp.bfloat16)
+    w = jax.random.normal(kw, (E, cfg.d_model, cfg.d_ff), jnp.bfloat16) \
+        / cfg.d_model ** 0.5
+    # a decode step's share of the bucket: a quarter of the rows are real
+    sizes = jnp.bincount(jax.random.randint(kg, (rows // 4,), 0, E),
+                         length=E).astype(jnp.int32)
+    outs = {}
+    for impl, fn in (("pallas", grouped_matmul),
+                     ("xla", jax.lax.ragged_dot)):
+        outs[impl], t = timed(jax.jit(fn), x, w, sizes)
+        print(f"    grouped matmul [{rows}, {cfg.d_model}] x [{E}, "
+              f"{cfg.d_model}, {cfg.d_ff}], {rows // 4} rows routed, "
+              f"{impl}: {1e3 * t:.3f} ms")
+    close("grouped matmul pallas vs ragged_dot",
+          outs["pallas"][:rows // 4], outs["xla"][:rows // 4], BF16_REL)
+
+    model = random_model(cfg, seed + 2)
+    print(f"  olmoe-1b-7b widths d{cfg.d_model} H{cfg.num_heads}/"
+          f"{cfg.num_kv_heads}x{cfg.head_dim}, {E} experts of "
+          f"{cfg.d_ff}, top-{cfg.moe_top_k}, QK-norm, DEPTH "
+          f"{cfg.num_layers} of 16, bf16")
+    eng = InferenceEngine(model, serve_config(sz))
+    prompts = make_prompts(sz, cfg.vocab_size, seed + 2)
+    out = eng.generate({u: list(p) for u, p in prompts.items()},
+                       SamplingParams(temperature=0.0,
+                                      max_new_tokens=s["new_tokens"]))
+    report_probe(eng)
+    check(all(len(v) == s["new_tokens"] for v in out.values()),
+          f"bad tokens {out}")
+    snap = eng.metrics_snapshot()
+    print(f"    {snap['serving_moe_assignments_total']:.0f} assignments "
+          "computed, fullest expert over the mean "
+          f"{snap['serving_moe_expert_load_max_over_mean']:.2f}")
+    logits = prefill_logits(eng, eng._build_step(), prompts)
+    check_first_tokens("moe", out, logits, reference_logits(model, prompts),
+                       LOGIT_REL)
+
+
 # --------------------------------------------------------------------------
 # four chips: the sharded paths and what they are compared with
 # --------------------------------------------------------------------------
@@ -781,6 +846,8 @@ def main(argv=None) -> int:
             serve_phase(sz, args.seed)
         with phase("serve-int8"):
             serve_int8_phase(sz, args.seed)
+        with phase("serve-moe"):
+            serve_moe_phase(sz, args.seed)
 
     print(f"total {time.perf_counter() - t_start:.1f} s; compile "
           f"{COMPILE['compile_s']:.1f} s over {COMPILE['cache_misses']} "

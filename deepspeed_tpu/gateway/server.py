@@ -458,6 +458,19 @@ class Gateway:
             sp.set_metadata(n_out=len(outs))
         return outs, reaped, time.perf_counter()
 
+    def _apply_then_pump(self, feedbacks: List[Tuple[int, int]],
+                         flushes: List[int],
+                         t_submit: Optional[float] = None
+                         ) -> Tuple[Dict[int, int], Dict[int, str], float]:
+        """The previous step's continuations and flushes, then the next
+        step, in ONE call on the engine thread: the engine goes on
+        without a round trip through the event loop, which writes the
+        previous step's tokens to their sockets while this one runs."""
+        if feedbacks or flushes:
+            self._apply(feedbacks, flushes, t_submit)
+            t_submit = None
+        return self._pump(t_submit)
+
     def _assert_backend_invariants(self) -> None:
         """The chaos bar, run after every pump when armed: allocator
         partition intact and no lifecycle record leaked, on every live
@@ -499,10 +512,18 @@ class Gateway:
     # the driver: pumps the engine off the event loop
     # ------------------------------------------------------------------
     async def _drive(self) -> None:
+        # what the last routed step left for the engine: it rides with
+        # the next pump (``_apply_then_pump``), or is applied by itself
+        # where no pump follows at once
+        fb: List[Tuple[int, int]] = []
+        fl: List[int] = []
         try:
             while not self._stop_driver:
                 if not any(not s.finished
                            for s in self._streams.values()):
+                    if fb or fl:
+                        await self._submit(self._apply, fb, fl)
+                        fb, fl = [], []
                     try:
                         await asyncio.wait_for(self._wake.wait(),
                                                timeout=0.05)
@@ -512,12 +533,11 @@ class Gateway:
                     continue
                 try:
                     outs, reaped, t_pump_end = \
-                        await self._submit(self._pump)
+                        await self._submit(self._apply_then_pump, fb, fl)
                 except EngineDeadError:
                     self._mark_dead()
-                    break
-                fb: List[Tuple[int, int]] = []
-                fl: List[int] = []
+                    return
+                fb, fl = [], []
                 # the loop's own share of the step, one span per pump
                 # and never across an await (TraceMe nests per thread):
                 # deliver the step's tokens, then release any stalled
@@ -530,11 +550,16 @@ class Gateway:
                     self._route_tokens(outs, reaped, fb, fl)
                     self._resume_stalled(fb, fl)
                     sp.set_metadata(n_closed=len(fl) + len(reaped))
-                if fb or fl:
-                    await self._submit(self._apply, fb, fl)
                 if not outs:
                     # idle/backoff round: don't hot-spin the engine
+                    if fb or fl:
+                        await self._submit(self._apply, fb, fl)
+                        fb, fl = [], []
                     await asyncio.sleep(self.cfg.idle_s)
+            if fb or fl:
+                # stopped between a route and its apply: the engine
+                # still has to hear of the streams that finished
+                await self._submit(self._apply, fb, fl)
         except asyncio.CancelledError:
             raise
         except Exception:

@@ -43,7 +43,8 @@ from .failures import (FATAL_ENGINE, POISON_STEP,
                        DispatchTimeoutError, EngineDeadError,
                        FailureConfig, FailurePolicy, InjectedFault,
                        InjectedTimeout, bisect_groups, classify_failure)
-from .model import pipelined_ragged_step, ragged_forward
+from .model import (MOE_STAT_ROWS, pipelined_ragged_step,
+                    ragged_forward)
 from .overload import (AdmissionVerdict, OverloadConfig, RequestMeta,
                        admission_decision, effective_priority,
                        select_victim)
@@ -469,9 +470,12 @@ class InferenceEngine:
                                    n_verify=self._n_verify)
         # spec engines' steps return [S, W] windows, so the feedback
         # operand (and its step-0 zero fallback) is window-shaped too
+        # a sparse-expert model's steps append their routing statistics
+        rows = self.icfg.max_seqs + (MOE_STAT_ROWS if self.cfg.num_experts
+                                     > 1 else 0)
         self._zero_toks = self._stage(jnp.zeros(
-            (self.icfg.max_seqs,) if self._n_verify == 1
-            else (self.icfg.max_seqs, self._n_verify), jnp.int32))
+            (rows,) if self._n_verify == 1
+            else (rows, self._n_verify), jnp.int32))
         self._last_toks = None
         self._dispatch_seq = 0
         self._fb_step: Dict[int, int] = {}   # uid -> sid its marker defers to
@@ -663,6 +667,22 @@ class InferenceEngine:
             "serving_guard_hop_ms_total",
             "cumulative milliseconds guarded device calls spent in the "
             "watchdog's hand-off (caller to worker and back)")
+        # sparse experts (parallel/moe.py moe_serve): read from the rows
+        # the step appends to its sampled tokens, at their readback;
+        # a dense model has neither
+        self._moe_metrics = None
+        if self.cfg.num_experts > 1:
+            self._moe_metrics = (
+                reg.counter(
+                    "serving_moe_assignments_total",
+                    "(token, expert) assignments the serving steps "
+                    "computed, summed over the layers: every real "
+                    "token's top-k, none dropped (decode bursts are not "
+                    "counted)"),
+                reg.gauge(
+                    "serving_moe_expert_load_max_over_mean",
+                    "rows of the fullest expert over the mean rows an "
+                    "expert, worst layer of the last collected step"))
         # --- overlapped/quantized collectives (docs/SERVING.md
         # "Overlapped & quantized collectives"): static per-dispatch
         # wire accounting — the shapes of a compiled step fully
@@ -3380,7 +3400,15 @@ class InferenceEngine:
             self._handle_step_failure(e, st.uids, "collect",
                                       registered=st.registered)
             return {}
-        t2 = tr.phase_end()
+        moe: Dict[str, float] = {}
+        if self._moe_metrics is not None:
+            # the routing statistics rode the tokens' own readback
+            n, load = toks_np[-MOE_STAT_ROWS:].reshape(
+                MOE_STAT_ROWS, -1)[:, 0]
+            moe = {"moe_assignments": int(n), "moe_load": load / 1e3}
+            self._moe_metrics[0].inc(moe["moe_assignments"])
+            self._moe_metrics[1].set(moe["moe_load"])
+        t2 = tr.phase_end(**moe)
         self._c_guard_hop.inc(hop_us / 1e3)
         self._note_step_success(st.uids)
         tm = self.timings
@@ -3498,7 +3526,8 @@ class InferenceEngine:
             toks, tail = decode_burst_forward(
                 cfg, params, prefix, base_ctx, token0, steps, sample_fn,
                 rng, uids=uids, quant=quant,
-                mixed_gemm=getattr(self, "_mixed_gemm_active", False))
+                mixed_gemm=getattr(self, "_mixed_gemm_active", False),
+                sharded=self._tp_mesh is not None)
             kv = scatter_tail(kv, tail, block_tables, base_ctx, bs)
             return toks, kv
 

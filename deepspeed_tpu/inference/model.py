@@ -34,6 +34,10 @@ from .ragged.state import RaggedBatch
 from .sampler import row_keys, window_keys
 
 
+# rows a sparse-expert model's serving step appends to its sampled tokens
+# (``pipelined_ragged_step``): assignments, 1000 x load max over mean
+MOE_STAT_ROWS = 2
+
 _KV_QMAX = {jnp.dtype(jnp.int8): 127.0,
             jnp.dtype(jnp.float8_e4m3fn): 448.0}
 
@@ -332,6 +336,9 @@ def _qkv_proj(cfg, ap, h, dt, cos, sin, positions):
         q = q + ap["bq"].astype(dt)
         k = k + ap["bk"].astype(dt)
         v = v + ap["bv"].astype(dt)
+    if cfg.qk_norm:
+        q = L.qk_rmsnorm(ap["q_norm"], q, cfg.eps)
+        k = L.qk_rmsnorm(ap["k_norm"], k, cfg.eps)
     if cfg.position == "rope":
         # apply_rope expects [B, S, H, D]; B=1 with per-token positions
         q = L.apply_rope(q[None], cos, sin, positions=positions[None])[0]
@@ -347,8 +354,19 @@ def _dense_weight(w) -> bool:
     return not isinstance(w, QuantizedTensor)
 
 
-def _ffn(cfg, lp, h, dt, act, comm: Optional[ServingComm] = None):
-    """Shared MLP / MoE branch of a serving layer.
+def _ffn(cfg, lp, h, dt, act, comm: Optional[ServingComm] = None,
+         valid=None, sharded: bool = False, experts=None):
+    """Shared MLP / MoE branch of a serving layer → ``(d, moe_stats)``,
+    ``moe_stats`` None for a dense layer.
+
+    Sparse experts are served dropless, by construction and with no
+    option (``parallel/moe.py`` ``moe_serve``): ``valid`` marks the real
+    rows of the step's bucket, the rest are routed nowhere.  The grouped
+    Pallas kernel runs where it can (a TPU, weights on one device;
+    ``sharded`` says they are not), ``jax.lax.ragged_dot`` elsewhere.
+    ``experts``: ``(all layers' stacked expert weights, this layer's
+    index)`` where the layer scan kept them out of its scanned inputs
+    (``ragged_forward``); else the layer's own are ``lp["experts"]``.
 
     With ``comm`` (TP serving, comm_overlap on), the down-projection —
     the layer's one row-parallel GEMM, whose partial-sum all-reduce
@@ -359,16 +377,15 @@ def _ffn(cfg, lp, h, dt, act, comm: Optional[ServingComm] = None):
         from ..models.transformer import _shared_expert
         from ..parallel import moe as M
 
-        d, _ = M.moe_ffn(lp["gate"], lp["experts"], h[None],
-                         top_k=cfg.moe_top_k,
-                         capacity_factor=cfg.eval_capacity_factor,
-                         min_capacity=cfg.min_capacity,
-                         activation=act, gated=cfg.gated_mlp,
-                         norm_topk=cfg.moe_norm_topk)
-        d = d[0]
+        stack, layer = experts or (lp["experts"], None)
+        d, stats = M.moe_serve(
+            lp["gate"], stack, h, valid, top_k=cfg.moe_top_k,
+            activation=act, gated=cfg.gated_mlp,
+            norm_topk=cfg.moe_norm_topk, layer=layer,
+            kernel=jax.default_backend() == "tpu" and not sharded)
         if "shared" in lp:       # qwen2-moe sigmoid-gated shared expert
             d = d + _shared_expert(lp["shared"], h, act, cfg.gated_mlp)
-        return d
+        return d, stats
     mp = lp["mlp"]
     u = _mm(h, mp["wi"], dt)
     if cfg.mlp_bias:
@@ -384,7 +401,7 @@ def _ffn(cfg, lp, h, dt, act, comm: Optional[ServingComm] = None):
         d = _mm(u, wo, dt)
     if cfg.mlp_bias:
         d = d + mp["bo"].astype(dt)
-    return d
+    return d, None
 
 
 def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
@@ -397,8 +414,12 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
                    stream=None,
                    mixed_gemm: bool = False,
                    comm: Optional[ServingComm] = None,
+                   with_moe_stats: bool = False,
                    ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """→ (last_token_logits [max_seqs, vocab], new_kv).
+    """→ (last_token_logits [max_seqs, vocab], new_kv), and with
+    ``with_moe_stats`` (sparse-expert models) a third: ``[2] i32``, the
+    step's expert assignments summed over the layers and 1000 x its
+    worst layer's fullest-expert-over-mean (``moe_serve``).
 
     ``kv``: [L, blocks, bs, 2, Hkv, D].  Rows of the logits output whose
     ``batch.logits_idx`` is -1 are garbage (callers mask by it).
@@ -473,11 +494,22 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
                              mixed=mixed_gemm)
         return lp, li
 
-    def block(x, lp, pool, layer):
+    # Dense expert weights stay OUT of the scanned inputs: the scan
+    # would slice each layer's out of the stack, and what feeds a Pallas
+    # call is then a copy of them all (805 MB a layer at olmoe-1b-7b's
+    # sizes).  The block gets the stack and its layer's index instead,
+    # as it gets the stacked cache and a row offset.
+    blocks = params["blocks"] if stream is None else {}
+    experts = None
+    if "wi" in blocks.get("experts", {}):
+        experts = blocks["experts"]
+        blocks = {k: v for k, v in blocks.items() if k != "experts"}
+
+    def block(x, lp, pool, layer, li):
         """One layer's mathematics.  ``pool`` is the paged cache that
         holds the layer where ``layer`` says (``_layer_of``): a layer's
         own slice or the whole stacked cache, the block cannot tell
-        which."""
+        which.  ``li``: the layer's index, for weights kept stacked."""
         ap = lp["attn"]
         # named scopes at the block's seams (metadata only): a device
         # trace's operations carry them in their JAX path, which is how
@@ -510,24 +542,28 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
             elif cfg.parallel_separate_norms:
                 h = norm(lp["ln2"], x)  # gpt-neox: MLP norms the original x
             # parallel residual (falcon/phi): MLP reads the same ln1 output
-            d = _ffn(cfg, lp, h, dt, act, comm=comm)
+            d, stats = _ffn(cfg, lp, h, dt, act, comm=comm,
+                            valid=batch.token_valid,
+                            sharded=shard_mesh is not None,
+                            experts=None if experts is None
+                            else (experts, li))
         if cfg.parallel_block:
-            return x + o + d, pool
-        return x + d, pool
+            return x + o + d, pool, stats
+        return x + d, pool, stats
 
     layer_ids = jnp.arange(cfg.num_layers, dtype=jnp.int32)
     layers = ((layer_ids,) if stream is not None
-              else (params["blocks"], layer_ids))
+              else (blocks, layer_ids))
     rows = _kv_parts(kv)[0].shape[1]       # a layer's blocks + trash row
 
     def streamed(x, xs):
         # the cache lives in host memory: the scan slices one layer out,
         # through HBM and back, which here IS the mechanism
         *ws, kv_layer = xs
-        lp, _ = layer_weights(ws)
+        lp, li = layer_weights(ws)
         kv_layer = jax.device_put(kv_layer, jax.memory.Space.Device)
-        x, kv_layer = block(x, lp, kv_layer, None)
-        return x, jax.device_put(kv_layer, jax.memory.Space.Host)
+        x, kv_layer, stats = block(x, lp, kv_layer, None, li)
+        return x, (jax.device_put(kv_layer, jax.memory.Space.Host), stats)
 
     def carried(carry, ws):
         # the cache lives in device memory: it rides the scan as a carry
@@ -535,19 +571,22 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
         # into the stacked pool (no per-layer slice, no second pool)
         x, pool = carry
         lp, li = layer_weights(ws)
-        x, pool = block(x, lp, pool, (li * rows, rows))
-        return (x, pool), None
+        x, pool, stats = block(x, lp, pool, (li * rows, rows), li)
+        return (x, pool), stats
 
     if kv_host:
-        x, new_kv = jax.lax.scan(streamed, x, (*layers, kv))
+        x, (new_kv, stats) = jax.lax.scan(streamed, x, (*layers, kv))
     else:
         pool = jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), kv)
-        (x, pool), _ = jax.lax.scan(carried, (x, pool), layers)
+        (x, pool), stats = jax.lax.scan(carried, (x, pool), layers)
         new_kv = jax.tree.map(lambda a, o: a.reshape(o.shape), pool, kv)
 
     with jax.named_scope("unembed"):
-        return _unembed(cfg, params, embed_tab, x, batch, norm, dt,
-                        comm), new_kv
+        logits = _unembed(cfg, params, embed_tab, x, batch, norm, dt, comm)
+    if with_moe_stats:       # per-layer [L, 2] -> the step's [2]
+        return logits, new_kv, jnp.stack([stats[:, 0].sum(),
+                                          stats[:, 1].max()])
+    return logits, new_kv
 
 
 def _unembed(cfg, params, embed_tab, x, batch, norm, dt, comm):
@@ -615,6 +654,8 @@ def pipelined_ragged_step(cfg: TransformerConfig, params, quant, kv,
     a non-drafting row is bit-for-bit the legacy sample and a drafting
     row's columns reproduce the exact non-speculative stream
     (acceptance is a host-side prefix compare at collect).
+    A sparse-expert model's token output carries ``MOE_STAT_ROWS``
+    further rows behind the slots' (``with_stats`` below).
     ``prev_toks`` may then be the previous verify step's [S, W] output;
     feedback reads its column 0 (markers are only ever speculated for
     non-drafting rows, whose sample lives there)."""
@@ -624,9 +665,22 @@ def pipelined_ragged_step(cfg: TransformerConfig, params, quant, kv,
         tok = jnp.where(fb >= 0, prev[jnp.maximum(fb, 0)],
                         batch.token_ids)
         batch = batch._replace(token_ids=tok)
-    logits, new_kv = ragged_forward(cfg, params, kv, batch, block_size,
-                                    max_blocks_per_seq, quant=quant,
-                                    **fw_kwargs)
+    moe = cfg.num_experts > 1
+    logits, new_kv, *stats = ragged_forward(
+        cfg, params, kv, batch, block_size, max_blocks_per_seq,
+        quant=quant, with_moe_stats=moe, **fw_kwargs)
+
+    def with_stats(toks):
+        # a sparse-expert model's routing statistics ride the sampled
+        # tokens' own readback as MOE_STAT_ROWS trailing rows (the
+        # feedback gather never reaches them: slots are < max_seqs)
+        if not moe:
+            return toks
+        rows = stats[0].astype(toks.dtype).reshape(
+            (MOE_STAT_ROWS,) + (1,) * (toks.ndim - 1))
+        return jnp.concatenate([toks, jnp.broadcast_to(
+            rows, (MOE_STAT_ROWS,) + toks.shape[1:])])
+
     if batch.verify_idx is not None:
         S, W = batch.verify_idx.shape
         vidx = jnp.maximum(batch.verify_idx, 0)
@@ -638,11 +692,11 @@ def pipelined_ragged_step(cfg: TransformerConfig, params, quant, kv,
             keys = window_keys(rng, batch.seq_uids, wpos)
             flat = sample_fn(logits.reshape(S * W, -1),
                              keys.reshape((S * W,) + keys.shape[2:]))
-        return flat.reshape(S, W), new_kv
+        return with_stats(flat.reshape(S, W)), new_kv
     with jax.named_scope("sample"):
         keys = row_keys(rng, batch.seq_uids, batch.context_lens)
         toks = sample_fn(logits, keys)
-    return toks, new_kv
+    return with_stats(toks), new_kv
 
 
 # --------------------------------------------------------------------------
@@ -678,7 +732,7 @@ def snapshot_prefix(kv, block_tables, P: int, block_size: int):
 def decode_burst_forward(cfg: TransformerConfig, params, prefix,
                          base_ctx, token0, steps: int, sample_fn,
                          rng, uids=None, quant=None,
-                         mixed_gemm: bool = False):
+                         mixed_gemm: bool = False, sharded: bool = False):
     """Run ``steps`` decode iterations entirely on device.
 
     prefix: [L, S, P, 2, Hkv, D] dense read-only context (closure-sized
@@ -785,7 +839,7 @@ def decode_burst_forward(cfg: TransformerConfig, params, prefix,
             h = norm(lp["ln2"], x)
         elif cfg.parallel_separate_norms:
             h = norm(lp["ln2"], x)   # gpt-neox: MLP norms the original x
-        d = _ffn(cfg, lp, h, dt, act)
+        d, _ = _ffn(cfg, lp, h, dt, act, sharded=sharded)
         y = (x + o + d) if cfg.parallel_block else (x + d)
         return y, tail_l
 

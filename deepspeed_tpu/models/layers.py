@@ -70,6 +70,16 @@ def rmsnorm(p, x, eps: float = 1e-6):
     return (y * p["scale"]).astype(x.dtype)
 
 
+def qk_rmsnorm(scale, x, eps: float):
+    """QK-norm of OLMoE / OLMo-2: ONE RMSNorm over a token's whole q (or
+    k) projection, all heads together, with a learned scale, before the
+    head split's rotary.  x: [..., heads, head_dim]; scale: [heads,
+    head_dim] (the flat published vector, viewed by head)."""
+    x32 = x.astype(jnp.float32)
+    ms = (x32 * x32).mean(axis=(-2, -1), keepdims=True)
+    return (x32 * jax.lax.rsqrt(ms + eps) * scale).astype(x.dtype)
+
+
 # --------------------------------------------------------------------------
 # Rotary position embeddings (reference kernel analog:
 # csrc/transformer/inference apply_rotary_pos_emb, v2 kv_rotary)

@@ -222,6 +222,25 @@ PRESETS: Dict[str, dict] = {
                             mlp_bias=False, eps=1e-6, num_experts=60,
                             moe_top_k=4, moe_shared_ff=5632,
                             moe_norm_topk=False),
+    # --- OLMoE (every layer sparse: 64 small experts, top-8 with the raw
+    # softmax probabilities; one RMSNorm over the whole q and the whole k
+    # projection before rotary; as many kv heads as query heads —
+    # allenai/OLMoE-1B-7B-0125-Instruct config.json + modeling_olmoe.py) --
+    "olmoe-tiny": dict(vocab_size=1024, num_layers=2, d_model=64,
+                       num_heads=4, num_kv_heads=4, d_ff=32,
+                       max_seq_len=256, activation="silu", gated_mlp=True,
+                       norm="rmsnorm", position="rope", rope_theta=10000.0,
+                       tie_embeddings=False, attn_bias=False,
+                       mlp_bias=False, eps=1e-5, qk_norm=True,
+                       num_experts=8, moe_top_k=4, moe_norm_topk=False),
+    "olmoe-1b-7b": dict(vocab_size=50304, num_layers=16, d_model=2048,
+                        num_heads=16, num_kv_heads=16, d_ff=1024,
+                        max_seq_len=4096, activation="silu",
+                        gated_mlp=True, norm="rmsnorm", position="rope",
+                        rope_theta=10000.0, tie_embeddings=False,
+                        attn_bias=False, mlp_bias=False, eps=1e-5,
+                        qk_norm=True, num_experts=64, moe_top_k=8,
+                        moe_norm_topk=False),
     # --- Megatron-GPT (gpt2 architecture, megatron-lm checkpoint naming
     # with per-head-interleaved fused QKV — reference:
     # module_inject/containers/megatron_gpt.py) ---------------------------

@@ -46,6 +46,9 @@ class TransformerConfig:
     position: str = "learned"                 # learned | rope | alibi
     rope_theta: float = 10000.0
     rope_pct: float = 1.0                     # partial rotary (phi: 0.4)
+    # olmoe / olmo-2: RMSNorm with a learned scale over the WHOLE q and
+    # the whole k projection (all heads together), before rotary
+    qk_norm: bool = False
     # bloom: layernorm applied to the word embeddings before the stack
     embed_norm: bool = False
     # parallel residual: x + attn(ln(x)) + mlp(ln(x)), one shared norm
@@ -82,13 +85,14 @@ class TransformerConfig:
     # renormalize kept top-k gate weights to sum 1 (mixtral yes;
     # qwen2-moe norm_topk_prob=False keeps raw softmax probabilities)
     moe_norm_topk: bool = True
+    # training only: serving routes without capacity and drops nothing
     capacity_factor: float = 1.25
-    eval_capacity_factor: float = 2.0         # inference-time capacity
     min_capacity: int = 4
     noise_policy: Optional[str] = None        # None | Jitter | RSample
     aux_loss_coef: float = 0.01
-    # scatter (capacity, EP-shardable) | einsum (GShard dense masks) |
-    # ragged (dropless megablox grouped GEMM via lax.ragged_dot)
+    # training only: scatter (capacity, EP-shardable) | einsum (GShard
+    # dense masks) | ragged (dropless grouped GEMM via lax.ragged_dot).
+    # Serving is dropless whatever this says (parallel/moe.py moe_serve)
     moe_dispatch: str = "scatter"
 
     def __post_init__(self):
@@ -192,6 +196,20 @@ def init_params(cfg: TransformerConfig, key) -> Tuple[Dict, Dict]:
             p["bv"] = jnp.zeros((Hkv, D)); a["bv"] = ("kv_heads", "head_dim")
         if cfg.attn_out_bias:
             p["bo"] = jnp.zeros((dm,)); a["bo"] = ("embed",)
+        if cfg.qk_norm:
+            # seeded scales in (0.5, 1.5), not ones: on random weights a
+            # projection's mean square is already near 1, so a forward
+            # that left the norm out would pass every comparison.  Their
+            # keys are a stream of their own off the layer's key: splitting
+            # ``k`` six ways above would re-seed every model's attention
+            k5, k6 = jax.random.split(jax.random.fold_in(  # tpulint: disable=rng-discipline
+                k, 1))
+            p["q_norm"] = jax.random.uniform(k5, (H, D), minval=0.5,
+                                             maxval=1.5)
+            a["q_norm"] = ("heads", "head_dim")
+            p["k_norm"] = jax.random.uniform(k6, (Hkv, D), minval=0.5,
+                                             maxval=1.5)
+            a["k_norm"] = ("kv_heads", "head_dim")
         return p, a
 
     blk_p["attn"], blk_a["attn"] = stack_init(qkv_init, keys[2])
@@ -311,6 +329,9 @@ def block_apply(cfg: TransformerConfig, lp, x, cos, sin,
         q = q + ap["bq"].astype(dt)
         k = k + ap["bk"].astype(dt)
         v = v + ap["bv"].astype(dt)
+    if cfg.qk_norm:
+        q = L.qk_rmsnorm(ap["q_norm"], q, cfg.eps)
+        k = L.qk_rmsnorm(ap["k_norm"], k, cfg.eps)
     if cfg.position == "rope":
         q = L.apply_rope(q, cos, sin, positions=positions)
         k = L.apply_rope(k, cos, sin, positions=positions)

@@ -273,6 +273,81 @@ def _ragged_moe(expert_p, x, logits, *, top_k: int, activation, gated: bool,
         "moe_dropped": jnp.float32(0.0)}
 
 
+def moe_serve(gate_p, expert_p, h, valid=None, *, top_k: int, activation,
+              gated: bool, norm_topk: bool, kernel: bool = False,
+              layer=None):
+    """The serving expert layer: DROPLESS by construction.  h: [T, d]
+    rows of one serving step (any mix of sequences); ``valid``: [T] bool,
+    False for the rows that pad the step's bucket (None: all real).
+    Returns ``(y [T, d], stats [2] i32)``.
+
+    Every real row's ``top_k`` assignments are computed whatever else is
+    in the step: there is no capacity, so a row's output does not depend
+    on its neighbours.  Padding rows are routed nowhere: they sort behind
+    the last expert, are counted in no group, and come back zero.  The
+    router's softmax and its top-k are float32 (one ``jax.lax.top_k``);
+    the probabilities are used as they are unless ``norm_topk``.
+
+    The three projections are grouped matrix multiplications over the
+    rows sorted by expert: ``ops/grouped_matmul.py`` when ``kernel`` (a
+    TPU, weights on one device), else ``jax.lax.ragged_dot``.
+
+    ``layer``: with it ``expert_p`` holds the experts of ALL layers,
+    ``[L, E, ...]`` as the model stacks them, and this call is layer
+    ``layer``'s (a traced scalar in a layer scan).  The kernel indexes
+    the stack where it lies; a layer sliced out of it first would be
+    copied whole on its way into the custom call.
+
+    ``stats``: (assignments computed, 1000 x the fullest expert's rows
+    over the mean), for the engine's counters."""
+    T, dm = h.shape
+    E = expert_p["wi"].shape[-3]
+    dt = h.dtype
+    if kernel:
+        from ..ops.grouped_matmul import grouped_matmul
+
+        def mm(x, w, sizes):
+            return grouped_matmul(
+                x, w.reshape((-1,) + w.shape[-2:]), sizes,
+                first_group=0 if layer is None else layer * E)
+    else:
+        mm = jax.lax.ragged_dot
+        if layer is not None:
+            expert_p = jax.tree.map(lambda w: w[layer], expert_p)
+    with jax.named_scope("moe_route"):
+        logits = jnp.dot(h, gate_p["kernel"].astype(dt),
+                         preferred_element_type=jnp.float32)
+        vals, ids = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+        if top_k > 1 and norm_topk:
+            vals = vals / jnp.maximum(vals.sum(axis=1, keepdims=True), 1e-9)
+        if valid is not None:
+            ids = jnp.where(valid[:, None], ids, E)     # expert E: nowhere
+        flat = ids.reshape(-1)                                    # [T*K]
+        order = jnp.argsort(flat, stable=True)
+        group_sizes = jnp.zeros((E,), jnp.int32).at[flat].add(
+            1, mode="drop")
+        xs = h[order // top_k]                                # [T*K, d]
+    with jax.named_scope("moe_experts"):
+        u = mm(xs, expert_p["wi"].astype(dt), group_sizes)
+        if gated:
+            u = activation(mm(xs, expert_p["wg"].astype(dt),
+                              group_sizes)) * u
+        else:
+            u = activation(u)
+        out = mm(u, expert_p["wo"].astype(dt), group_sizes)
+    with jax.named_scope("moe_route"):
+        back = jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.shape[0], dtype=order.dtype))
+        picked = out[back].reshape(T, top_k, dm).astype(jnp.float32)
+        y = (picked * vals[..., None]).sum(axis=1).astype(dt)
+        if valid is not None:
+            y = jnp.where(valid[:, None], y, 0)
+        n = group_sizes.sum()
+        stats = jnp.stack([n, (group_sizes.max() * (1000 * E))
+                           // jnp.maximum(n, 1)])
+    return y, stats
+
+
 def moe_ffn(gate_p, expert_p, x, *, top_k: int, capacity_factor: float,
             min_capacity: int = 4, activation=jax.nn.gelu,
             gated: bool = False, rng: Optional[jax.Array] = None,
@@ -299,9 +374,12 @@ def moe_ffn(gate_p, expert_p, x, *, top_k: int, capacity_factor: float,
     (``jax.lax.ragged_dot`` over expert-sorted tokens — no capacity, no
     drops; see :func:`_ragged_moe`).
 
-    Measured (mixtral-ish shapes, E8 d1024 ff3584 T16k): equal step time
-    on a v5e, but the scatter form compiles to 2.4x less temp memory
-    (420 vs 1007 MB on the CPU-mesh compile) — hence the default.
+    Why scatter is the default: at mixtral-ish shapes (E8 d1024 ff3584
+    T16k) it compiled to 2.4x less temp memory than the einsum form (420
+    vs 1007 MB, a CPU-mesh compile; no chip run of either is on record).
+
+    Training only.  Serving does not come here: ``moe_serve`` below is
+    dropless whatever ``dispatch_mode`` a config names.
     """
     B, S, dm = x.shape
     E = expert_p["wi"].shape[0]

@@ -258,15 +258,26 @@ def test_driver_spans_pump_route_apply_in_order(gw):
     assert h.gateway.tracer is eng.tracer      # even while it is empty
     eng.tracer.clear()
     eng.tracer.enable()
+    def gateway_events():
+        return [e for e in eng.tracer.events()
+                if e["name"].startswith("ds.gateway.")]
     try:
         r = http_completion(h.host, h.port, {"prompt": [3, 4, 5, 6],
                                              "max_tokens": 4,
                                              "stream": True})
+        # the client has its last token as soon as the loop has routed
+        # it; the engine thread's apply of that step (the one that
+        # flushes the stream) comes after: wait for it before the tracer
+        # goes off
+        deadline = time.monotonic() + 10.0
+        while time.monotonic() < deadline and not any(
+                e["name"] == "ds.gateway.apply" and e["args"]["n_flush"]
+                for e in gateway_events()):
+            time.sleep(0.01)
     finally:
         eng.tracer.disable()
     assert r["code"] == 200 and len(r["tokens"]) == 4
-    evs = [e for e in eng.tracer.events()
-           if e["name"].startswith("ds.gateway.")]
+    evs = gateway_events()
     order = [e["name"].rsplit(".", 1)[1] for e in evs]
     # every token-bearing pump is followed by its route, then its apply
     busy = [i for i, e in enumerate(evs) if e["name"] == "ds.gateway.pump"
@@ -320,6 +331,55 @@ def test_gateway_without_a_backend_tracer_gets_its_own():
     assert pump["name"] == "ds.gateway.pump"
     assert pump["args"]["n_out"] == 1 and pump["args"]["queued_us"] >= 0.0
     assert a1["args"]["queued_us"] >= 0.0 and a2["args"]["queued_us"] == 0.0
+    g._exec.shutdown()
+
+
+@pytest.mark.parametrize("fb,fl", [([(7, 5)], []), ([], [7]), ([], [])],
+                         ids=["continuation", "flush", "nothing"])
+def test_apply_then_pump_is_one_call_on_the_engine_thread(fb, fl):
+    """The driver hands a routed step's continuations and flushes over
+    WITH the next pump: the backend hears of them before it steps, in
+    the caller's own call (no second hand-over through the loop), as an
+    apply span and then a pump span; with nothing to apply it is a pump."""
+    from deepspeed_tpu.gateway.server import Gateway, _Stream
+    from deepspeed_tpu.telemetry import MetricsRegistry
+
+    calls = []
+
+    class Stub:
+        metrics = MetricsRegistry()
+
+        def put(self, uid, toks):
+            calls.append(("put", uid, list(toks)))
+
+        def flush(self, uid):
+            calls.append(("flush", uid))
+
+        def step(self, rng=None, sampling=None):
+            calls.append(("step",))
+            return {7: 1}
+
+        def _drain_reaped(self):
+            return ()
+
+    g = Gateway(Stub())
+    g._streams[7] = _Stream(uid=7, rid="r", max_tokens=8, want_stream=True,
+                            queue=None)
+    g.tracer.enable()
+    t = time.perf_counter()
+    outs, reaped, t_end = g._apply_then_pump(fb, fl, t_submit=t)
+    assert outs == {7: 1} and reaped == {} and t_end >= t
+    want = [("put", u, [tok]) for u, tok in fb] \
+        + [("flush", u) for u in fl] + [("step",)]
+    assert calls == want
+    names = [e["name"] for e in g.tracer.events()]
+    assert names == (["ds.gateway.apply"] if fb or fl else []) \
+        + ["ds.gateway.pump"]
+    evs = g.tracer.events()
+    # the hand-over's stamp belongs to whichever span began the call
+    assert evs[0]["args"]["queued_us"] > 0.0
+    assert evs[-1]["args"]["queued_us"] == (0.0 if fb or fl else
+                                            evs[0]["args"]["queued_us"])
     g._exec.shutdown()
 
 

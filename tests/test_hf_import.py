@@ -93,7 +93,7 @@ class TestHFParity:
                         d_model=64, num_heads=4, num_kv_heads=2, d_ff=128,
                         num_experts=4, moe_top_k=2, max_seq_len=64,
                         # large capacity: HF routes without dropping
-                        capacity_factor=4.0, eval_capacity_factor=4.0)
+                        capacity_factor=4.0)
         params = load_hf_state_dict(m.config, hf.state_dict(),
                                     family="mixtral",
                                     reference_params=m.params)

@@ -500,8 +500,7 @@ class TestNewFamilyServing:
         ("qwen2-moe-tiny", dict(vocab_size=128, num_layers=2, d_model=64,
                                 num_heads=4, num_kv_heads=2, d_ff=96,
                                 moe_shared_ff=160, num_experts=4,
-                                max_seq_len=64, capacity_factor=4.0,
-                                eval_capacity_factor=4.0)),
+                                max_seq_len=64, capacity_factor=4.0)),
     ])
     def test_greedy_matches_full_forward(self, preset, over):
         m = build_model(preset, **over)

@@ -25,12 +25,14 @@ from jax.sharding import SingleDeviceSharding
 
 from deepspeed_tpu.models.presets import PRESETS
 from deepspeed_tpu.ops.flash_attention import flash_attention
+from deepspeed_tpu.ops.grouped_matmul import grouped_matmul
 from deepspeed_tpu.ops.mixed_gemm import mixed_matmul
 from deepspeed_tpu.ops.paged_attention import paged_attention
 from deepspeed_tpu.ops.quant import QuantizedTensor
 
 LLAMA = PRESETS["llama3-8b"]
 GPT2 = PRESETS["gpt2"]
+OLMOE = PRESETS["olmoe-1b-7b"]
 
 
 @pytest.fixture(scope="module")
@@ -78,6 +80,11 @@ _LLAMA_PAGED = dict(T=1024, H=LLAMA["num_heads"], Hkv=LLAMA["num_kv_heads"],
 PAGED = {
     "llama3-8b-bf16": dict(_LLAMA_PAGED, kv_quant=False),
     "llama3-8b-int8kv": dict(_LLAMA_PAGED, kv_quant=True),
+    # one query head per kv head: the kernel's static loop runs 16 times
+    # over [1, 128] x [128, 64] products
+    "olmoe-1b-7b-bf16": dict(T=512, H=OLMOE["num_heads"],
+                             Hkv=OLMOE["num_kv_heads"], D=128, blocks=768,
+                             nb=16, kv_quant=False),
     "gpt2-bf16": dict(T=256, H=GPT2["num_heads"], Hkv=GPT2["num_heads"],
                       D=GPT2["d_model"] // GPT2["num_heads"], blocks=256,
                       nb=16, kv_quant=False),
@@ -151,6 +158,18 @@ def test_mixed_gemm_compiles(one_chip, on_chip, bits, M):
     assert _compile(mixed_matmul, S((M, K), jnp.bfloat16), qt) == 1
 
 
+# ------------------------------------------------------- grouped matmul
+# olmoe-1b-7b's expert projections: 512 tokens x 8 experts a token are
+# 4096 sorted rows over 64 experts; up/gate [2048, 1024], down [1024, 2048]
+@pytest.mark.parametrize("K,N", [(2048, 1024), (1024, 2048)],
+                         ids=["olmoe-up", "olmoe-down"])
+def test_grouped_matmul_compiles(one_chip, on_chip, K, N):
+    E, rows = OLMOE["num_experts"], 512 * OLMOE["moe_top_k"]
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    assert _compile(grouped_matmul, S((rows, K), jnp.bfloat16),
+                    S((E, K, N), jnp.bfloat16), S((E,), jnp.int32)) == 1
+
+
 # --------------------------------------------------------- serving step
 # the whole pipelined serving step of mistral-7b-d16 (the benchmark's
 # serving configuration: 16 layers, a pool of 1024 blocks of 64 tokens,
@@ -184,33 +203,22 @@ def _moves_of(text: str, floor: int):
     return found
 
 
-@pytest.mark.parametrize("kv_quant", [False, True],
-                         ids=["mistral-7b-d16-bf16", "mistral-7b-d16-int8kv"])
-def test_serving_step_keeps_the_pool_in_place(one_chip, on_chip, kv_quant):
-    """The layer scan carries the paged cache and the kernels address
-    ``(layer, block)`` in it: the compiled step holds no temporary of a
-    layer's share of the pool (it held the whole pool, 4.0 GiB, while
-    the cache was a scanned input and output), and neither its entry
-    computation nor its ``while`` body copies, slices or update-slices
-    that much.
-
-    ``kv_host`` is left out on purpose: a cache in host memory keeps the
-    scanned form, where slicing one layer out, through HBM and back is
-    the mechanism and a layer-sized copy is what it is for."""
+def _pstep_compiled(one_chip, cfg, kv_quant, T, seqs, bs, mbs, blocks):
+    """``InferenceEngine._build_pstep``'s program for ``cfg`` compiled
+    for the described chip, the cache donated → (compiled, bytes of one
+    layer's share of the pool)."""
     from deepspeed_tpu.inference import SamplingParams
-    from deepspeed_tpu.inference.model import pipelined_ragged_step
+    from deepspeed_tpu.inference.model import (MOE_STAT_ROWS,
+                                               pipelined_ragged_step)
     from deepspeed_tpu.inference.ragged.state import RaggedBatch
     from deepspeed_tpu.inference.sampler import sample_rows
-    from deepspeed_tpu.models.presets import build_config
     from deepspeed_tpu.models.transformer import init_params
 
-    cfg = build_config("mistral-7b", num_layers=16)
     S = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
     params = jax.tree.map(
         lambda a: S(a.shape, jnp.bfloat16),
         jax.eval_shape(lambda k: init_params(cfg, k)[0],
                        jax.random.PRNGKey(0)))
-    T, seqs, bs, mbs, blocks = 512, 64, 64, 16, 1024
     pool = (cfg.num_layers, blocks + 1, bs, 2, cfg.num_kv_heads,
             cfg.head_dim)
     kv = S(pool, jnp.int8 if kv_quant else jnp.bfloat16)
@@ -234,9 +242,54 @@ def test_serving_step_keeps_the_pool_in_place(one_chip, on_chip, kv_quant):
             lambda logits, keys: sample_rows(logits, greedy, keys),
             bs, mbs, attn_impl="pallas")
 
-    compiled = jax.jit(pstep, donate_argnums=(1,)).lower(
-        params, kv, batch, S((seqs,), jnp.int32),
-        S(key.shape, key.dtype)).compile()
+    prev = seqs + (MOE_STAT_ROWS if cfg.num_experts > 1 else 0)
+    return jax.jit(pstep, donate_argnums=(1,)).lower(
+        params, kv, batch, S((prev,), jnp.int32),
+        S(key.shape, key.dtype)).compile(), layer_bytes
+
+
+def test_moe_serving_step_compiles_and_fits(one_chip, on_chip):
+    """The whole serving step of ``olmoe-1b-7b-d10`` as the benchmark
+    runs it (10 layers, 768 blocks of 64, 512 tokens and 64 sequences a
+    step): the paged kernel at one query head per kv head and the
+    grouped kernel's three projections are in the layer scan; no layer's
+    experts are sliced out of the stacked weights on their way into the
+    kernel (each of the three leaves was a 268 MB copy a layer while
+    they were scanned inputs); weights, pool and temporaries fit a 16 GB
+    chip."""
+    from deepspeed_tpu.models.presets import build_config
+
+    cfg = build_config("olmoe-1b-7b", num_layers=10, max_seq_len=1024)
+    compiled, layer_bytes = _pstep_compiled(
+        one_chip, cfg, False, T=512, seqs=64, bs=64, mbs=16, blocks=768)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 4
+    leaf_bytes = cfg.num_experts * cfg.d_model * cfg.d_ff * 2
+    assert _moves_of(text, 1), "the reader no longer finds any copy"
+    assert _moves_of(text, leaf_bytes) == []
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < leaf_bytes // 8
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+@pytest.mark.parametrize("kv_quant", [False, True],
+                         ids=["mistral-7b-d16-bf16", "mistral-7b-d16-int8kv"])
+def test_serving_step_keeps_the_pool_in_place(one_chip, on_chip, kv_quant):
+    """The layer scan carries the paged cache and the kernels address
+    ``(layer, block)`` in it: the compiled step holds no temporary of a
+    layer's share of the pool (it held the whole pool, 4.0 GiB, while
+    the cache was a scanned input and output), and neither its entry
+    computation nor its ``while`` body copies, slices or update-slices
+    that much.
+
+    ``kv_host`` is left out on purpose: a cache in host memory keeps the
+    scanned form, where slicing one layer out, through HBM and back is
+    the mechanism and a layer-sized copy is what it is for."""
+    from deepspeed_tpu.models.presets import build_config
+
+    compiled, layer_bytes = _pstep_compiled(
+        one_chip, build_config("mistral-7b", num_layers=16), kv_quant,
+        T=512, seqs=64, bs=64, mbs=16, blocks=1024)
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 1
     assert _moves_of(text, 1), "the reader no longer finds any copy"

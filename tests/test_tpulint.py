@@ -125,9 +125,12 @@ def test_whole_tree_is_clean_fast_and_jax_free():
       are registered and armed;
     * deepspeed_tpu + tests carry zero findings (all 27 rules,
       concurrency and contracts included);
-    * the run stays under 15 s wall — measured ~10 s (per-file rules
-      ~4 s + program passes ~6 s); the assert leaves headroom without
-      letting the analyzer quietly become a multi-minute tax;
+    * the run stays under 15 s of its own CPU time — measured ~8-10 s
+      (per-file rules ~4 s + program passes ~6 s; the analyzer is one
+      thread, so alone its wall time is the same); the assert leaves
+      headroom without letting the analyzer quietly become a
+      multi-minute tax, and does not read the load of the suite's other
+      workers, which a wall clock does (it failed once at 6 workers);
     * the analyzer never imports JAX (pure ast), checked in a fresh
       interpreter where nothing else has imported it.
 
@@ -136,7 +139,7 @@ def test_whole_tree_is_clean_fast_and_jax_free():
     their own fixture-free pass and stay out of this timed run.)
     """
     code = (
-        "import sys, time; t0 = time.perf_counter()\n"
+        "import sys, time; t0 = time.process_time()\n"
         "from tools.tpulint.core import RULES, lint_paths\n"
         "conc = {'shared-state-race', 'lock-order-cycle',\n"
         "        'await-under-lock', 'seam-freeze'}\n"
@@ -146,7 +149,7 @@ def test_whole_tree_is_clean_fast_and_jax_free():
         "             'raise-escape'}\n"
         "assert contracts <= set(RULES), 'contract pass not armed'\n"
         "fs = lint_paths(['deepspeed_tpu', 'tests'])\n"
-        "dt = time.perf_counter() - t0\n"
+        "dt = time.process_time() - t0\n"
         "assert 'jax' not in sys.modules, 'tpulint imported JAX'\n"
         "assert not fs, '\\n'.join(f.human() for f in fs)\n"
         "print(dt)\n"
@@ -155,7 +158,7 @@ def test_whole_tree_is_clean_fast_and_jax_free():
                        capture_output=True, text=True)
     assert r.returncode == 0, r.stderr
     assert float(r.stdout.strip()) < 15.0, \
-        f"tpulint took {r.stdout.strip()}s (budget 15s)"
+        f"tpulint took {r.stdout.strip()}s of CPU (budget 15s)"
 
 
 def test_fixture_corpus_not_swept_into_tree_runs():

@@ -249,8 +249,7 @@ class TestStreamedMoEServing:
         (fp and int8)."""
         m = build_model("mixtral-tiny", vocab_size=128, num_layers=2,
                         d_model=64, num_heads=4, num_kv_heads=2, d_ff=128,
-                        num_experts=4, capacity_factor=4.0,
-                        eval_capacity_factor=4.0)
+                        num_experts=4, capacity_factor=4.0)
         base = dict(token_budget=32, max_seqs=4, kv_block_size=16,
                     num_kv_blocks=64, param_dtype=jnp.float32,
                     kv_dtype=jnp.float32)
@@ -277,7 +276,7 @@ class TestSharedExpertQuantServing:
         return build_model(
             "qwen2-moe-tiny", vocab_size=128, num_layers=2, d_model=64,
             num_heads=4, num_kv_heads=2, d_ff=96, moe_shared_ff=128,
-            max_seq_len=256, capacity_factor=4.0, eval_capacity_factor=4.0)
+            max_seq_len=256, capacity_factor=4.0)
 
     def _kw(self):
         return dict(token_budget=32, max_seqs=4, kv_block_size=16,
