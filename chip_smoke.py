@@ -319,7 +319,11 @@ def kernels_phase(sz, seed):
         for n, a, b in zip("qkv", grads["pallas"], grads["xla"]):
             close(f"d{n}", a, b, BF16_REL)
 
-    # --- paged decode attention over a full block table
+    # --- paged attention over a full block table, on a batch as the
+    # scheduler stages them: two decode tokens and a two-token verify
+    # window (the kernel's short tiles), a chunk of T/4 rows that starts
+    # in the middle of a block and one of T/2 rows that ends at the
+    # context's end (its long tiles), the rest budget padding
     c = sz["paged"]
     T, H, Hkv, D, bs, nb = (c[k] for k in ("T", "H", "Hkv", "D", "block",
                                             "nb"))
@@ -330,14 +334,26 @@ def kernels_phase(sz, seed):
                               jnp.bfloat16)
     r = np.random.RandomState(seed)
     tables = np.stack([r.permutation(c["blocks"])[:nb] for _ in range(S)])
+    ctx = nb * bs
+    runs = [(int(r.randint(0, ctx)), 1), (int(r.randint(0, ctx)), 1),
+            (int(r.randint(0, ctx - 2)), 2),
+            (min(bs // 2 + 5, ctx - T // 4), T // 4),
+            (ctx - T // 2 - 3, T // 2)]
+    n_real = sum(n for _, n in runs)
+    positions, seq_slot = np.zeros(T, np.int32), np.zeros(T, np.int32)
+    row = 0
+    for slot, (start, n) in enumerate(runs):
+        positions[row:row + n] = np.arange(start, start + n)
+        seq_slot[row:row + n] = slot
+        row += n
     batch = RaggedBatch(
         token_ids=jnp.zeros(T, jnp.int32),
-        positions=jnp.asarray(r.randint(0, nb * bs, T), jnp.int32),
-        seq_slot=jnp.arange(T, dtype=jnp.int32) % S,
-        token_valid=jnp.ones(T, bool),
+        positions=jnp.asarray(positions), seq_slot=jnp.asarray(seq_slot),
+        token_valid=jnp.asarray(np.arange(T) < n_real),
         block_tables=jnp.asarray(tables, jnp.int32),
         context_lens=jnp.zeros(S, jnp.int32),
-        logits_idx=jnp.full(S, -1, jnp.int32), n_tokens=T, n_seqs=S)
+        logits_idx=jnp.full(S, -1, jnp.int32), n_tokens=n_real,
+        n_seqs=len(runs))
     codes, scales = _quantize_kv(cache, jnp.int8)
     for name, kvl in (("bf16", cache), ("int8-KV", (codes, scales))):
         print(f"  paged attention {name} T{T} H{H}/{Hkv} D{D} "
@@ -349,7 +365,9 @@ def kernels_phase(sz, seed):
                 jax.jit(lambda kvl, q, _fn=fn: _fn(kvl, q, batch, bs, nb,
                                                    D ** -0.5)), kvl, q)
             print(f"    {impl}: {ms(t)}")
-        close("out", got["pallas"], got["xla"], BF16_REL)
+        close("out", got["pallas"][:n_real], got["xla"][:n_real], BF16_REL)
+        check(not np.asarray(got["pallas"][n_real:], np.float32).any(),
+              "paged attention wrote into budget padding")
 
     # --- mixed-input GEMMs
     K, N = sz["gemm"]["K"], sz["gemm"]["N"]

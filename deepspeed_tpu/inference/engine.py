@@ -33,6 +33,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..comm.mesh import FSDP_AXIS, MeshTopology, TENSOR_AXIS
 from ..models.transformer import Model, TransformerConfig
+from ..ops.paged_attention import LONG, tile_counts
 from ..telemetry import (AnomalyConfig, AnomalyMonitor, CounterDictView,
                          DeviceTelemetry, FlightRecorder, MetricsRegistry,
                          ProfilerCapture, RequestTracker, SloObjective,
@@ -683,6 +684,21 @@ class InferenceEngine:
                     "serving_moe_expert_load_max_over_mean",
                     "rows of the fullest expert over the mean rows an "
                     "expert, worst layer of the last collected step"))
+        # the Pallas paged-attention kernel's grid (ops/paged_attention
+        # ``tile_counts``): how many query tiles the dispatched steps
+        # were cut into, and how full the long ones were.  Counted on
+        # the host from the schedule's run lengths; steps that ran an
+        # XLA formulation count nothing
+        self._c_attn_tiles = reg.counter(
+            "serving_attn_tiles_total",
+            "query tiles of the paged-attention kernel over the "
+            "dispatched steps (height: short|long)", int_valued=True)
+        self._c_attn_long_rows = reg.counter(
+            "serving_attn_long_tile_tokens_total",
+            "real tokens in the long query tiles", int_valued=True)
+        reg.gauge_fn("serving_attn_tile_fill", self._attn_tile_fill,
+                     "real tokens over tile rows of the long query tiles "
+                     "(absent before the first one)")
         # --- overlapped/quantized collectives (docs/SERVING.md
         # "Overlapped & quantized collectives"): static per-dispatch
         # wire accounting — the shapes of a compiled step fully
@@ -828,6 +844,13 @@ class InferenceEngine:
                 self._slo.bind(self._anom,
                                lambda: self._steps_done,
                                self._on_anomaly)
+
+    def _attn_tile_fill(self) -> Optional[float]:
+        """Real tokens over rows of the long query tiles dispatched so
+        far; None before the first one."""
+        tiles = self._c_attn_tiles.value(height="long")
+        return self._c_attn_long_rows.value() / (tiles * LONG) \
+            if tiles else None
 
     def _prefix_hit_rate(self):
         prompt = self.timings["prompt_tokens"]
@@ -1416,9 +1439,7 @@ class InferenceEngine:
         """Resolve the forward-pass knobs shared by every compiled
         serving program (probing attn_impl/mixed_gemm on first use)."""
         mbs = mbs or self.max_blocks_per_seq
-        impl = self.icfg.attn_impl
-        if impl == "auto":
-            impl = self._probe_attn_impl()
+        impl = self._attn_impl()
         mixed = self._resolve_mixed_gemm(impl)
         self._mixed_gemm_active = mixed
         comm = self._serving_comm
@@ -1436,6 +1457,13 @@ class InferenceEngine:
                     kv_host=getattr(self, "_kv_on_host", False),
                     shard_mesh=self._tp_mesh, stream=self._stream,
                     comm=comm), mbs
+
+    def _attn_impl(self) -> str:
+        """The attention implementation the serving programs run:
+        ``icfg.attn_impl``, with ``auto`` settled by the start-up race
+        (once a process and shape signature)."""
+        impl = self.icfg.attn_impl
+        return self._probe_attn_impl() if impl == "auto" else impl
 
     def _donate_kv(self) -> bool:
         """Whether serving programs donate the paged cache.  See
@@ -1553,23 +1581,30 @@ class InferenceEngine:
         nb = self.icfg.num_kv_blocks
         # synthetic batch on the compiled shapes — does NOT touch the
         # state manager (no slot/block allocation).  Representative work:
-        # every slot at FULL context (tables fully populated, positions at
-        # the last context token) — a near-empty batch would let the
+        # every slot at FULL context (tables fully populated, its tokens
+        # the last of the context) — a near-empty batch would let the
         # Pallas kernel skip almost all of its blocks while the XLA
         # gather path pays full cost regardless, biasing the probe.
+        # A batch as ``build_batch`` makes them: the budget split evenly
+        # over the slots, each slot's tokens one run of consecutive
+        # rows and positions (the kernel's tile lists count on a slot
+        # holding one run), what is left over budget padding.
         tables = np.zeros((ms, nb), np.int32)
         tables[:, :mbs] = np.arange(mbs, dtype=np.int32)[None, :] \
             % max(1, nb - 1)
         last_pos = mbs * bs - 1
+        per = min(-(-T // ms), mbs * bs)
+        t = np.arange(T)
         batch = RaggedBatch(
             token_ids=jnp.zeros(T, jnp.int32),
-            positions=jnp.full(T, last_pos, jnp.int32),
-            seq_slot=jnp.arange(T, dtype=jnp.int32) % ms,
-            token_valid=jnp.ones(T, bool),
+            positions=jnp.asarray(last_pos - (per - 1) + t % per,
+                                  jnp.int32),
+            seq_slot=jnp.asarray(np.minimum(t // per, ms - 1), jnp.int32),
+            token_valid=jnp.asarray(t < per * ms),
             block_tables=jnp.asarray(tables),
             context_lens=jnp.full(ms, last_pos + 1, jnp.int32),
-            logits_idx=jnp.full(ms, -1, jnp.int32).at[0].set(0),
-            n_tokens=T, n_seqs=ms)
+            logits_idx=jnp.full(ms, -1, jnp.int32).at[0].set(per - 1),
+            n_tokens=min(T, per * ms), n_seqs=min(ms, -(-T // per)))
         batch = self._stage(batch)
         results = {}
         # probe on the real (pre-serving, all-zeros) cache with donation,
@@ -3042,17 +3077,24 @@ class InferenceEngine:
             cap.begin(sid=sid, step=self._steps_done)
         # context bucket: the compiled block bound covers every scheduled
         # sequence's post-step context, rounded to a power of two so a
-        # growing context mints O(log) programs, not one per block
-        bs_blk = self.icfg.kv_block_size
-        need = 1
-        for uid, toks in sched:
-            seq = self.state.seqs.get(uid)
-            seen = seq.seen_tokens if seq else 0
-            need = max(need, -(-(seen + len(toks)) // bs_blk))
-        mbs = 1
-        while mbs < need:
-            mbs *= 2
-        mbs = min(mbs, self.max_blocks_per_seq)
+        # growing context mints O(log) programs, not one per block.  The
+        # XLA formulations do work proportional to that bound; the
+        # Pallas kernel's grid follows the batch (its tiles, and the
+        # blocks of the deepest one), so there one program, bounded by
+        # the engine's longest context, serves every step
+        pallas = self._attn_impl() == "pallas"
+        mbs = self.max_blocks_per_seq
+        if not pallas:
+            bs_blk = self.icfg.kv_block_size
+            need = 1
+            for uid, toks in sched:
+                seq = self.state.seqs.get(uid)
+                seen = seq.seen_tokens if seq else 0
+                need = max(need, -(-(seen + len(toks)) // bs_blk))
+            mbs = 1
+            while mbs < need:
+                mbs *= 2
+            mbs = min(mbs, self.max_blocks_per_seq)
         key = (mbs, sampling.sampler_key)
         step_fn = self._pstep_fns.pop(key, None)
         if step_fn is None:
@@ -3067,8 +3109,18 @@ class InferenceEngine:
         self._pstep_fns[key] = step_fn    # reinsert: LRU, not FIFO
         cold = ("p", key) not in self._warm_keys
         n_tokens = sum(len(t) for _, t in sched)
+        tiles = {}
+        if pallas:
+            n_short, n_long, rows = tile_counts([len(t) for _, t in sched])
+            self._c_attn_tiles.inc(n_short, height="short")
+            if n_long:
+                self._c_attn_tiles.inc(n_long, height="long")
+                self._c_attn_long_rows.inc(rows)
+            tiles = dict(n_tiles_short=n_short, n_tiles_long=n_long,
+                         tile_fill=rows / (n_long * LONG) if n_long else 0.0)
         t1 = tr.phase("ds.serve.stage", track="stage", sid=sid,
-                      n_tokens=n_tokens, n_seqs=len(sched), mbs=mbs)
+                      n_tokens=n_tokens, n_seqs=len(sched), mbs=mbs,
+                      **tiles)
         batch = self._stage(
             self.state.build_batch(
                 sched, self.icfg.token_budget, stager=self._stager,

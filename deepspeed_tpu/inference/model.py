@@ -120,10 +120,24 @@ def _write_kv(kv_layer, k, v, batch: RaggedBatch, block_size: int,
     return (data, scales)
 
 
+def _query_tiles(kv, batch: RaggedBatch, block_size: int,
+                 max_blocks_per_seq: int):
+    """The step's query tiles for the Pallas kernel
+    (``ops/paged_attention.query_tiles``): built once a step, outside
+    the layer scan, block-table rows gathered per tile.  ``kv``: the
+    cache, stacked ``[L, rows, ...]`` or one layer's ``[rows, ...]``
+    (its last row is the trash row either way)."""
+    from ..ops.paged_attention import query_tiles
+
+    return query_tiles(batch.seq_slot, batch.positions, batch.token_valid,
+                       batch.block_tables, block_size, max_blocks_per_seq,
+                       trash=_kv_parts(kv)[0].shape[-5] - 1)
+
+
 def _paged_attention_pallas(kv_layer, q, batch: RaggedBatch,
                             block_size: int, max_blocks_per_seq: int,
                             scale: float, shard_mesh=None, slopes=None,
-                            layer=None):
+                            layer=None, tiles=None):
     """Pallas streaming kernel behind the same signature
     (ops/paged_attention.py — reference: blocked_flash).
 
@@ -133,13 +147,16 @@ def _paged_attention_pallas(kv_layer, q, batch: RaggedBatch,
     mesh axis) — the TPU analog of the reference's TP-aware blocked_flash
     dispatch (inference/v2/model_implementations/sharding/attn.py).
     ``layer``: ``(base, rows)`` of the layer inside a stacked pool, see
-    the kernel."""
+    the kernel.  ``tiles``: ``_query_tiles`` of the step, which
+    ``ragged_forward`` builds once for all its layers (``None``: built
+    here, for a caller with one layer)."""
     from ..ops.paged_attention import paged_attention
 
+    if tiles is None:
+        tiles = _query_tiles(kv_layer, batch, block_size,
+                             max_blocks_per_seq)
     if shard_mesh is None:
-        return paged_attention(kv_layer, q, batch.seq_slot, batch.positions,
-                               batch.block_tables, block_size,
-                               max_blocks_per_seq, scale, slopes=slopes,
+        return paged_attention(kv_layer, q, tiles, scale, slopes=slopes,
                                layer=layer)
     from jax.sharding import PartitionSpec as P
 
@@ -150,17 +167,17 @@ def _paged_attention_pallas(kv_layer, q, batch: RaggedBatch,
                else (data_spec, P(None, None, None, TENSOR_AXIS)))
     q_spec = P(None, TENSOR_AXIS, None)               # [T, H, D]
     base, rows = _layer_of(kv_layer, layer)
-    in_specs = [kv_spec, q_spec, P(), P(), P(), P()]
-    operands = [kv_layer, q, batch.seq_slot, batch.positions,
-                batch.block_tables, jnp.asarray(base, jnp.int32)]
+    # the tile list is the same on every chip: heads split, rows do not
+    in_specs = [kv_spec, q_spec, jax.tree.map(lambda _: P(), tiles), P()]
+    operands = [kv_layer, q, tiles, jnp.asarray(base, jnp.int32)]
     if slopes is not None:
         in_specs.append(P(TENSOR_AXIS, None))   # slopes [Hkv, rep] split
         operands.append(jnp.asarray(slopes, jnp.float32).reshape(
             _kv_parts(kv_layer)[0].shape[3], -1))   # with the kv heads
     f = shard_map(
-        lambda kvl, qq, ss, pos, bt, b, *sl: paged_attention(
-            kvl, qq, ss, pos, bt, block_size, max_blocks_per_seq, scale,
-            slopes=sl[0] if sl else None, layer=(b, rows)),
+        lambda kvl, qq, tl, b, *sl: paged_attention(
+            kvl, qq, tl, scale, slopes=sl[0] if sl else None,
+            layer=(b, rows)),
         mesh=shard_mesh,
         in_specs=tuple(in_specs),
         out_specs=q_spec, check_vma=False)
@@ -525,7 +542,7 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
                 o = _paged_attention_pallas(
                     pool, q, batch, block_size, max_blocks_per_seq,
                     scale, shard_mesh=shard_mesh, slopes=slopes,
-                    layer=layer)
+                    layer=layer, tiles=tiles)
             else:
                 o = _paged_attention(pool, q, batch, block_size,
                                      max_blocks_per_seq, scale,
@@ -551,6 +568,10 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
             return x + o + d, pool, stats
         return x + d, pool, stats
 
+    # what the Pallas kernel's grid walks is the same for every layer:
+    # cut the batch into query tiles here, once, outside the scan
+    tiles = (_query_tiles(kv, batch, block_size, max_blocks_per_seq)
+             if attn_impl == "pallas" else None)
     layer_ids = jnp.arange(cfg.num_layers, dtype=jnp.int32)
     layers = ((layer_ids,) if stream is not None
               else (blocks, layer_ids))

@@ -1,27 +1,45 @@
-"""Paged (blocked) decode attention — Pallas TPU kernel.
+"""Paged (blocked) attention over query tiles — Pallas TPU kernel.
 
 TPU-native analog of the reference FastGen kernel family
 (``inference/v2/kernels/ragged_ops/blocked_flash`` — flash attention over
 a block table, ``atom_builder`` splitting sequences into fixed KV atoms).
 
-Where the XLA formulation in ``inference/model.py:_paged_attention``
-gathers every scheduled token's *entire* padded context
-(``kv_layer[tables]`` → [T, max_blocks, bs, 2, Hkv, D]) through HBM and
-then re-reads it for the attention einsums, this kernel streams each
-token's KV blocks through VMEM once with an online softmax, keeping the
-(m, l, acc) running state on-chip:
+A serving step's tokens fall into *runs*: consecutive rows of the ragged
+batch that belong to one sequence at consecutive positions (a decode
+token is a run of one, a speculative verify window a run of ``k + 1``, a
+prefill chunk a run of up to the token budget).  ``query_tiles`` cuts
+every run into *tiles* of at most ``height`` rows, once a step and
+outside the layer scan, and gathers each tile's block-table row; budget
+padding belongs to no tile.  The kernel then streams KV blocks through
+VMEM once per tile, not once per token:
 
-* grid (T, num_blocks): one step attends one token (all heads) to one KV
-  block — the block carries every kv head so the trailing block dims are
-  full-size (a Mosaic tiling requirement) and DMA count stays at T×nb;
-* the block table and positions ride scalar prefetch
-  (``PrefetchScalarGridSpec``) so the kv BlockSpec's index_map picks the
-  DMA'd block dynamically — paged indirection happens in the DMA engine,
-  not as a gather;
-* blocks past a token's position are skipped (``pl.when``) — budget
-  padding tokens and table padding (-1 → trash row) contribute nothing;
-* GQA: a static (unrolled) loop over kv heads, one [rep, D]×[D, bs] MXU
-  dot per kv head per block.
+* grid ``(tiles, blocks)``, both traced: a step runs as many grid rows
+  as it has tiles, each as long as the deepest tile's context (the
+  compiled bucket ``max_blocks_per_seq`` only bounds it).  One grid
+  step attends one tile (all heads) to one KV block; the block carries
+  every kv head so the trailing block dims are full-size (a Mosaic
+  tiling requirement);
+* the tiles' tables, first rows, first positions and lengths ride scalar
+  prefetch (``PrefetchScalarGridSpec``): the kv BlockSpec's index map
+  picks the DMA'd block, the query's picks the tile's first row as an
+  element offset into ``[T, H, D]`` — paged indirection and ragged rows
+  both happen in the DMA engine, never as a gather;
+* per kv head the products are ``[height * rep, D] x [D, bs]`` and
+  ``[height * rep, bs] x [bs, D]``: the tile's queries are folded to
+  that shape once, at its first block, and kept in VMEM; the causal mask
+  is ``col <= first_pos + row``; the online softmax keeps (m, l, acc)
+  per row in f32 across the tile's blocks;
+* blocks past the tile's last position are skipped (``pl.when``) and
+  their index maps stay on the last needed block, so nothing is DMA'd
+  for them;
+* the output is written by the kernel's own DMAs, ``length`` rows of it
+  and no more (groups of 8 rows, then single rows: static sizes, a
+  traced count): the row after a tile's last belongs to another run.
+
+``paged_attention`` runs the one kernel body at two heights: runs of at
+most ``SHORT`` rows (decode tokens, verify windows) at ``SHORT``, longer
+ones (prefill chunks) in tiles of ``LONG``.  Which one a run takes is a
+property of the batch, not an option.
 
 CPU tests run the same kernel in interpret mode.  ``InferenceEngine``
 probes this kernel against the XLA formulations at build time and keeps
@@ -31,6 +49,7 @@ whichever is fastest on the running backend at the engine's shapes.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -38,43 +57,144 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# tile heights: [SHORT * rep, D] is what the MXU pads a decode token's
+# [rep, D] to anyway; LONG * rep rows fill it at rep = 4
+SHORT, LONG = 8, 128
 
 
 def _use_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _kernel(tables_ref, pos_ref, *rest,
-            block_size: int, scale: float,
+class TileList(NamedTuple):
+    """The tiles of one height, padded to a static bound."""
+    tables: jnp.ndarray     # [n, nb] i32 rows of a layer's pool (pads → trash)
+    row: jnp.ndarray        # [n] i32 first row in the batch
+    pos: jnp.ndarray        # [n] i32 position of that row in its sequence
+    length: jnp.ndarray     # [n] i32 rows, 1..height
+    count: jnp.ndarray      # [] i32 real tiles; the rest is padding
+    blocks: jnp.ndarray     # [] i32 KV blocks the deepest real tile needs
+
+
+class QueryTiles(NamedTuple):
+    short: TileList
+    long: TileList
+
+
+def tile_counts(run_lengths: Sequence[int]) -> Tuple[int, int, int]:
+    """(short tiles, long tiles, real rows in the long tiles) of a step
+    whose runs have these lengths — the host's count of what
+    ``query_tiles`` builds on the device."""
+    n_short = sum(1 for n in run_lengths if 0 < n <= SHORT)
+    long_runs = [n for n in run_lengths if n > SHORT]
+    return n_short, sum(-(-n // LONG) for n in long_runs), sum(long_runs)
+
+
+def query_tiles(seq_slot, positions, token_valid, block_tables,
+                block_size: int, max_blocks_per_seq: int,
+                trash: int) -> QueryTiles:
+    """Cut a ragged batch into query tiles, on the device, once a step.
+
+    seq_slot/positions: [T] i32, token_valid: [T] bool,
+    block_tables: [max_seqs, max_blocks] i32 (-1 pad → row ``trash`` of
+    a layer's pool).  A run is a maximal stretch of valid rows of one
+    slot at consecutive positions; a slot holds at most one run a step
+    (``StateManager.build_batch`` schedules a sequence once), which
+    bounds the lists: ``max_seqs`` short tiles, ``T // LONG`` full long
+    tiles and one partial one a long run."""
+    T = seq_slot.shape[0]
+    max_seqs = block_tables.shape[0]
+    i = jnp.arange(T, dtype=jnp.int32)
+    slot = seq_slot.astype(jnp.int32)
+    pos = positions.astype(jnp.int32)
+    valid = token_valid
+    follows = (valid[1:] & valid[:-1] & (slot[1:] == slot[:-1])
+               & (pos[1:] == pos[:-1] + 1))
+    first = valid & jnp.concatenate([jnp.ones(1, bool), ~follows])
+    last = valid & jnp.concatenate([~follows, jnp.ones(1, bool)])
+    start = jax.lax.cummax(jnp.where(first, i, -1))          # run's first row
+    end = jax.lax.cummin(jnp.where(last, i, T), reverse=True)
+    run_len = end - start + 1
+    off = i - start
+    is_short = run_len <= SHORT
+
+    def collect(flag, length, bound):
+        rows = jnp.flatnonzero(flag, size=bound, fill_value=0).astype(
+            jnp.int32)
+        count = jnp.minimum(flag.sum(), bound).astype(jnp.int32)
+        real = jnp.arange(bound) < count
+        tables = block_tables[slot[rows], :max_blocks_per_seq]
+        tables = jnp.where(tables < 0, trash, tables).astype(jnp.int32)
+        length = jnp.where(real, length[rows], 0)
+        blocks = jnp.max(jnp.where(
+            real, (pos[rows] + length - 1) // block_size + 1, 1))
+        return TileList(tables, rows, pos[rows], length, count,
+                        jnp.minimum(blocks, max_blocks_per_seq))
+
+    short = collect(first & is_short, run_len, min(T, max_seqs))
+    long = collect(valid & ~is_short & (off % LONG == 0),
+                   jnp.minimum(run_len - off, LONG),
+                   max(1, T // LONG + min(max_seqs, T // (SHORT + 1))))
+    return QueryTiles(short, long)
+
+
+def _each_row_copy(do, src, dst, sem, row, n):
+    """``do`` (start or wait) every DMA that moves ``n`` rows (traced)
+    of ``src`` (VMEM, from its row 0) to ``dst`` (HBM, from ``row``):
+    whole groups of 8 rows, then the rest row by row.  Every size is
+    static and nothing past row ``n`` is touched; all of them count on
+    the one semaphore, so waiting takes the same walk."""
+    g = min(8, src.shape[0], dst.shape[0])
+
+    def copy(at, size):
+        do(pltpu.make_async_copy(src.at[pl.ds(at, size)],
+                                 dst.at[pl.ds(row + at, size)], sem))
+
+    jax.lax.fori_loop(0, n // g, lambda i, _: copy(i * g, g), None)
+    jax.lax.fori_loop(0, n % g, lambda i, _: copy(n // g * g + i, 1), None)
+
+
+def _kernel(tables_ref, row_ref, pos_ref, len_ref, base_ref, *rest,
+            height: int, block_size: int, scale: float,
             num_kv_heads: int, rep: int, alibi: bool, kv_quant: bool):
-    # optional trailing inputs (order: kv scales, alibi slopes) before
-    # the output and scratch refs
+    # optional inputs (order: kv scales, alibi slopes) sit between the
+    # kv block and the aliased output
     rest = list(rest)
-    if kv_quant:
-        rest.pop(0)     # the scales' row offset: the index map's alone
     q_ref, kv_ref = rest.pop(0), rest.pop(0)
     ks_ref = rest.pop(0) if kv_quant else None
     slopes_ref = rest.pop(0) if alibi else None
-    o_ref, acc_ref, m_ref, l_ref = rest
+    _, o_ref, qs_ref, ob_ref, acc_ref, m_ref, l_ref, sem = rest
     t = pl.program_id(0)
     j = pl.program_id(1)
+    nt = pl.num_programs(0)
     nb = pl.num_programs(1)
-    pos = pos_ref[t]
+    R = height * rep
+    pos0 = pos_ref[t]
+    n = len_ref[t]
 
     @pl.when(j == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
+        # fold the tile's queries [height, H, D] to one [height * rep, D]
+        # operand per kv head, once for all its blocks (row = token * rep
+        # + head of the group)
+        for h in range(num_kv_heads):
+            qs_ref[h] = (q_ref[:, h, :] if rep == 1 else
+                         q_ref[:, h * rep:(h + 1) * rep, :].reshape(
+                             R, q_ref.shape[-1]))
 
-    # the whole block is past this token's position → nothing to add
-    @pl.when(j * block_size <= pos)
+    # the whole block is past the tile's last position → nothing to add
+    @pl.when(j * block_size <= pos0 + n - 1)
     def _compute():
         cols = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (rep, block_size), 1)
-        keep = cols <= pos
+            jnp.int32, (R, block_size), 1)
+        # a folded row's position: row // rep tokens after the first
+        keep = cols <= pos0 + jax.lax.broadcasted_iota(
+            jnp.int32, (R, 1), 0) // rep
         for h in range(num_kv_heads):          # static unroll (GQA groups)
-            q = q_ref[0, h * rep:(h + 1) * rep, :]         # [rep, D]
+            q = qs_ref[h]                                  # [R, D]
             k = kv_ref[0, :, 0, h, :]                      # [bs, D]
             v = kv_ref[0, :, 1, h, :]                      # [bs, D]
             if kv_quant:    # in-VMEM dequant: HBM only streamed codes
@@ -84,39 +204,135 @@ def _kernel(tables_ref, pos_ref, *rest,
                      * ks_ref[0, :, 1, h][:, None]).astype(q.dtype)
             s = jax.lax.dot_general(
                 q, k, dimension_numbers=(((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale  # [rep, bs]
+                preferred_element_type=jnp.float32) * scale  # [R, bs]
             if alibi:       # ALiBi: slope_h * absolute key position
-                s = s + (slopes_ref[h, :][:, None]
-                         * cols.astype(jnp.float32))
+                s = s + slopes_ref[h] * cols.astype(jnp.float32)
             s = jnp.where(keep, s, NEG_INF)
-            sl = slice(h * rep, (h + 1) * rep)
-            m_prev, l_prev = m_ref[sl, :], l_ref[sl, :]
+            m_prev, l_prev = m_ref[h], l_ref[h]
             m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
             p = jnp.exp(s - m_new)
             corr = jnp.exp(m_prev - m_new)
-            m_ref[sl, :] = m_new
-            l_ref[sl, :] = l_prev * corr + p.sum(axis=1, keepdims=True)
+            m_ref[h] = m_new
+            l_ref[h] = l_prev * corr + p.sum(axis=1, keepdims=True)
             pv = jax.lax.dot_general(
                 p.astype(v.dtype), v,
                 dimension_numbers=(((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)          # [rep, D]
-            acc_ref[sl, :] = acc_ref[sl, :] * corr + pv
+                preferred_element_type=jnp.float32)          # [R, D]
+            acc_ref[h] = acc_ref[h] * corr + pv
 
     @pl.when(j == nb - 1)
     def _finalize():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        # the output rows leave by the kernel's own DMAs, double
+        # buffered: this tile's start here and are waited for when the
+        # next tile (or the grid) ends, so they overlap its blocks
+        slot = t % 2
+        start, wait = (lambda cp: cp.start()), (lambda cp: cp.wait())
+
+        @pl.when(t > 0)
+        def _():
+            _each_row_copy(wait, ob_ref.at[1 - slot], o_ref,
+                           sem.at[1 - slot], row_ref[t - 1], len_ref[t - 1])
+
+        for h in range(num_kv_heads):
+            # unfolded in f32: Mosaic has no such shape cast for packed
+            # rows narrower than a lane tile (gpt2's D = 64 in bf16)
+            o = (acc_ref[h] / jnp.maximum(l_ref[h], 1e-30)).reshape(
+                height, rep, acc_ref.shape[-1])
+            ob_ref[slot, :, h * rep:(h + 1) * rep, :o.shape[-1]] = o.astype(
+                ob_ref.dtype)
+        mine = (ob_ref.at[slot], o_ref, sem.at[slot], row_ref[t], n)
+        _each_row_copy(start, *mine)
+
+        @pl.when(t == nt - 1)
+        def _():
+            _each_row_copy(wait, *mine)
 
 
-def paged_attention(kv_layer, q, seq_slot, positions, block_tables,
-                    block_size: int, max_blocks_per_seq: int, scale: float,
+def _attend(tiles: TileList, kv_layer, kv_scales, q, out, base, height: int,
+            scale: float, slopes):
+    """One ``pallas_call`` over ``tiles`` → ``out`` with their rows
+    written (``out`` is donated to the call and returned)."""
+    T, H, D = q.shape
+    _, bs, _, Hkv, _ = kv_layer.shape
+    rep = H // Hkv
+    R = height * rep
+    nb = tiles.tables.shape[1]
+
+    def _last_block(t, j, pos, length):
+        # clamp past-position block indices to the last needed block:
+        # consecutive grid steps then revisit the same block and Pallas
+        # skips the DMA entirely (the kernel skips the compute).  (An
+        # empty list's entry 0 has length 0; whatever evaluates this for
+        # it must still get a block of the table.)
+        return jnp.minimum(j, (pos[t] + jnp.maximum(length[t], 1) - 1) // bs)
+
+    def _kv_index(t, j, tbl, row, pos, length, base):
+        return (tbl[t, _last_block(t, j, pos, length)] + base[0], 0, 0, 0, 0)
+
+    def _ks_index(t, j, tbl, row, pos, length, base):
+        return (tbl[t, _last_block(t, j, pos, length)], 0, 0, 0)
+
+    def _q_index(t, j, tbl, row, *_):
+        return (row[t], 0, 0)
+
+    alibi = slopes is not None
+    kv_quant = kv_scales is not None
+    prefetch = [tiles.tables, tiles.row, tiles.pos, tiles.length,
+                jnp.reshape(base, (1,)).astype(jnp.int32)]
+    in_specs = [
+        pl.BlockSpec((pl.Element(height), pl.Element(H), pl.Element(D)),
+                     _q_index),
+        pl.BlockSpec((1, bs, 2, Hkv, D), _kv_index),
+    ]
+    operands = [q, kv_layer]
+    if kv_quant:
+        in_specs.append(pl.BlockSpec((1, bs, 2, Hkv), _ks_index))
+        operands.append(kv_scales)
+    if alibi:
+        # per folded row (token * rep + head of the group)
+        in_specs.append(pl.BlockSpec((Hkv, R, 1), lambda t, j, *_: (0, 0, 0)))
+        operands.append(jnp.tile(
+            jnp.asarray(slopes, jnp.float32).reshape(Hkv, 1, rep),
+            (1, height, 1)).reshape(Hkv, R, 1))
+    in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+    operands.append(out)
+
+    return pl.pallas_call(
+        functools.partial(_kernel, height=height, block_size=bs,
+                          scale=scale, num_kv_heads=Hkv, rep=rep,
+                          alibi=alibi, kv_quant=kv_quant),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(tiles.count, tiles.blocks),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[
+                pltpu.VMEM((Hkv, R, D), q.dtype),            # folded queries
+                pltpu.VMEM((2, height) + out.shape[1:], q.dtype),  # output rows
+                pltpu.VMEM((Hkv, R, D), jnp.float32),
+                pltpu.VMEM((Hkv, R, 1), jnp.float32),
+                pltpu.VMEM((Hkv, R, 1), jnp.float32),
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct(out.shape, out.dtype),
+        input_output_aliases={len(prefetch) + len(operands) - 1: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=_use_interpret(),
+        name=f"paged_attention_h{height}",
+    )(*prefetch, *operands)
+
+
+def paged_attention(kv_layer, q, tiles: QueryTiles, scale: float,
                     slopes=None, layer=None):
     """kv_layer: [blocks+1, bs, 2, Hkv, D] (last row = trash), or a
     (data, scales) tuple for a quantized cache (scales
     [blocks+1, bs, 2, Hkv] f32; codes dequantized in VMEM so HBM only
     streams the 1-byte payloads);
-    q: [T, H, D]; seq_slot/positions: [T] i32;
-    block_tables: [max_seqs, max_blocks] i32 (-1 pad) → out [T, H, D].
+    q: [T, H, D]; ``tiles``: ``query_tiles`` of the step → out [T, H, D],
+    zero in the rows of no tile (budget padding).
     ``slopes``: optional ALiBi per-head slopes, any shape reshapeable to
     [Hkv, rep] in head order h = hkv*rep + r (reference analog: the alibi
     operand of the inference softmax kernels, csrc/transformer/inference/
@@ -134,63 +350,19 @@ def paged_attention(kv_layer, q, seq_slot, positions, block_tables,
     kv_scales = None
     if isinstance(kv_layer, tuple):
         kv_layer, kv_scales = kv_layer
+    base, rows = (0, kv_layer.shape[0]) if layer is None else layer
+    if kv_scales is not None:
+        kv_scales = jax.lax.dynamic_slice_in_dim(kv_scales, base, rows)
+    # the element-offset query window of a tile that starts in the last
+    # rows reads past them: give it rows to read
+    qp = jnp.pad(q, ((0, LONG), (0, 0), (0, 0)))
+    # the kernel's own DMAs move whole (sublane, lane) tiles: heads and
+    # head size that do not fill theirs (gpt2's 12 x 64) get an output
+    # that does, cut back here
     T, H, D = q.shape
-    nblocks, bs, _, Hkv, _ = kv_layer.shape
-    rep = H // Hkv
-    nb = max_blocks_per_seq
-
-    base, rows = (0, nblocks) if layer is None else layer
-    tables = block_tables[seq_slot, :nb]                   # [T, nb]
-    tables = (jnp.where(tables < 0, rows - 1, tables)
-              + base).astype(jnp.int32)
-    positions = positions.astype(jnp.int32)
-
-    def _kv_index(t, j, tbl, pos, *_):
-        # clamp past-position block indices to the last needed block:
-        # consecutive grid steps then revisit the same block and Pallas
-        # skips the DMA entirely (the kernel skips the compute)
-        jj = jnp.minimum(j, pos[t] // bs)
-        return (tbl[t, jj], 0, 0, 0, 0)
-
-    def _ks_index(t, j, tbl, pos, base):
-        jj = jnp.minimum(j, pos[t] // bs)
-        return (tbl[t, jj] - base[0], 0, 0, 0)
-
-    alibi = slopes is not None
-    kv_quant = kv_scales is not None
-    prefetch = [tables, positions]
-    in_specs = [
-        pl.BlockSpec((1, H, D), lambda t, j, *_: (t, 0, 0)),
-        pl.BlockSpec((1, bs, 2, Hkv, D), _kv_index),
-    ]
-    operands = [q, kv_layer]
-    if kv_quant:
-        prefetch.append(jnp.reshape(base, (1,)).astype(jnp.int32))
-        in_specs.append(pl.BlockSpec((1, bs, 2, Hkv), _ks_index))
-        operands.append(jax.lax.dynamic_slice_in_dim(kv_scales, base, rows))
-    if alibi:
-        in_specs.append(pl.BlockSpec((Hkv, rep), lambda t, j, *_: (0, 0)))
-        operands.append(jnp.asarray(slopes, jnp.float32)
-                        .reshape(Hkv, rep))
-
-    grid = (T, nb)
-    out = pl.pallas_call(
-        functools.partial(_kernel, block_size=bs, scale=scale,
-                          num_kv_heads=Hkv, rep=rep, alibi=alibi,
-                          kv_quant=kv_quant),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(prefetch),
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, H, D), lambda t, j, *_: (t, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((H, D), jnp.float32),
-                pltpu.VMEM((H, 1), jnp.float32),
-                pltpu.VMEM((H, 1), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((T, H, D), q.dtype),
-        interpret=_use_interpret(),
-        name="paged_attention",
-    )(*prefetch, *operands)
-    return out
+    Hp = H if H < 8 else -(-H // 8) * 8
+    out = jnp.zeros((T, Hp, -(-D // 128) * 128), q.dtype)
+    for tl, height in ((tiles.long, LONG), (tiles.short, SHORT)):
+        out = _attend(tl, kv_layer, kv_scales, qp, out, base, height,
+                      scale, slopes)
+    return out[:, :H, :D]

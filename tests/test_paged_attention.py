@@ -287,6 +287,253 @@ class TestCarriedCache:
                                       np.asarray(logits)[rows])
 
 
+# --------------------------------------------------------------- tiles
+# The kernel's grid walks query tiles (ops/paged_attention.py): every
+# batch below is built by ``StateManager.build_batch`` itself, so the
+# runs are what the scheduler really stages.
+
+BS_T, NBLK_T, HKV_T, D_T = 8, 96, 2, 16
+
+
+def _built_batch(runs, T, max_seqs=8):
+    """``runs``: [(uid, tokens already in the cache, new tokens)] ->
+    (RaggedBatch of the new tokens, {uid: slot}).  The history goes
+    through ``build_batch`` first, as earlier steps would have put it
+    there (one request at a time, so its rows never count)."""
+    from deepspeed_tpu.inference.ragged.state import (KVCacheConfig,
+                                                      StateManager)
+
+    sm = StateManager(KVCacheConfig(num_layers=1, num_kv_heads=HKV_T,
+                                    head_dim=D_T, block_size=BS_T,
+                                    num_blocks=NBLK_T, dtype=jnp.float32),
+                      max_seqs=max_seqs)
+    for uid, seen, _ in runs:
+        if seen:
+            sm.build_batch([(uid, [1] * seen)], seen)
+    batch = sm.build_batch([(uid, [2] * n) for uid, _, n in runs if n], T)
+    return batch, dict(sm._slots)
+
+
+# name -> (runs, token budget); bs = 8, so 128-row tiles cross 16 blocks
+TILE_BATCHES = {
+    # runs of one at different depths, then budget padding
+    "decode-only": ([(1, 19, 1), (2, 0, 1), (3, 70, 1), (4, 8, 1)], 16),
+    # 150 rows = a whole 128-row tile and 22 of the next; starts at
+    # position 5, in the middle of a block
+    "chunk-unaligned": ([(1, 5, 150)], 160),
+    # a verify window (k + 1 = 4 rows) between two decode tokens
+    "verify-window": ([(1, 30, 1), (2, 19, 4), (3, 3, 1)], 16),
+    # two chunks and three decode tokens in one step
+    "two-chunks": ([(1, 40, 1), (2, 3, 140), (3, 9, 1), (4, 61, 30),
+                    (5, 0, 1)], 192),
+    # a run of exactly the short height and one just over it
+    "eight-and-nine": ([(1, 11, 8), (2, 2, 9)], 32),
+    # all padding but one token
+    "one-token": ([(1, 12, 1)], 64),
+}
+
+
+def _random_pool(seed, layers=None, quant=False):
+    r = np.random.RandomState(seed)
+    shape = (NBLK_T + 1, BS_T, 2, HKV_T, D_T)
+    if layers:
+        shape = (layers,) + shape
+    if quant:
+        return (jnp.asarray(r.randint(-127, 128, shape), jnp.int8),
+                jnp.asarray(r.uniform(0.01, 0.03, shape[:-1]), jnp.float32))
+    return jnp.asarray(r.randn(*shape), jnp.float32)
+
+
+def _check_tiles(kv, batch, H, nb, layer=None, slopes=None, tol=1e-5):
+    T = batch.token_ids.shape[0]
+    q = jnp.asarray(np.random.RandomState(11).randn(T, H, D_T), jnp.float32)
+    scale = 1.0 / np.sqrt(D_T)
+    ref = _paged_attention(kv, q, batch, BS_T, nb, scale, slopes=slopes,
+                           layer=layer)
+    out = _paged_attention_pallas(kv, q, batch, BS_T, nb, scale,
+                                  slopes=slopes, layer=layer)
+    valid = np.asarray(batch.token_valid)
+    out, ref = np.asarray(out), np.asarray(ref)
+    assert valid.any()
+    np.testing.assert_allclose(out[valid], ref[valid], atol=tol, rtol=tol)
+    # budget padding belongs to no tile: nothing is written there
+    assert not out[~valid].any()
+
+
+class TestQueryTiles:
+    @pytest.mark.parametrize("rep", [1, 4])
+    @pytest.mark.parametrize("name", sorted(TILE_BATCHES))
+    def test_matches_xla_on_built_batches(self, name, rep):
+        runs, T = TILE_BATCHES[name]
+        batch, _ = _built_batch(runs, T)
+        _check_tiles(_random_pool(3), batch, HKV_T * rep, nb=32)
+
+    @pytest.mark.parametrize("name", ["two-chunks", "verify-window"])
+    def test_alibi(self, name):
+        from deepspeed_tpu.models import layers as L
+        runs, T = TILE_BATCHES[name]
+        batch, _ = _built_batch(runs, T)
+        _check_tiles(_random_pool(4), batch, 8, nb=32,
+                     slopes=L.alibi_slopes(8))
+
+    @pytest.mark.parametrize("name", ["two-chunks", "decode-only"])
+    def test_int8_kv(self, name):
+        runs, T = TILE_BATCHES[name]
+        batch, _ = _built_batch(runs, T)
+        _check_tiles(_random_pool(5, quant=True), batch, 8, nb=32, tol=1e-4)
+
+    @pytest.mark.parametrize("quant", [False, True], ids=["fp", "int8kv"])
+    @pytest.mark.parametrize("li", [0, 1, 2])
+    def test_layer_of_a_stacked_pool(self, li, quant):
+        """``layer=(base, rows)`` on a three-layer pool viewed
+        ``[L * rows, ...]`` equals the kernel on that layer's own
+        slice, and the XLA formulation there."""
+        runs, T = TILE_BATCHES["two-chunks"]
+        batch, _ = _built_batch(runs, T)
+        kv = _random_pool(6, layers=3, quant=quant)
+        rows = NBLK_T + 1
+        flat = jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), kv)
+        own = jax.tree.map(lambda a: a[li], kv)
+        q = jnp.asarray(np.random.RandomState(12).randn(T, 8, D_T),
+                        jnp.float32)
+        scale = 1.0 / np.sqrt(D_T)
+        stacked = _paged_attention_pallas(flat, q, batch, BS_T, 32, scale,
+                                          layer=(li * rows, rows))
+        alone = _paged_attention_pallas(own, q, batch, BS_T, 32, scale)
+        np.testing.assert_array_equal(np.asarray(stacked),
+                                      np.asarray(alone))
+        _check_tiles(flat, batch, 8, nb=32, layer=(li * rows, rows),
+                     tol=1e-4 if quant else 1e-5)
+
+    def test_row_after_a_tile_is_not_overwritten(self):
+        """Each height alone writes its own tiles' rows and no other:
+        the long call leaves the decode rows that follow a chunk's last
+        row untouched (zero), the short call leaves the chunk's."""
+        from deepspeed_tpu.inference.model import _query_tiles
+        from deepspeed_tpu.ops.paged_attention import paged_attention
+
+        runs, T = TILE_BATCHES["two-chunks"]
+        batch, _ = _built_batch(runs, T)
+        kv = _random_pool(7)
+        q = jnp.asarray(np.random.RandomState(13).randn(T, 8, D_T),
+                        jnp.float32)
+        scale = 1.0 / np.sqrt(D_T)
+        ref = np.asarray(_paged_attention(kv, q, batch, BS_T, 32, scale))
+        tiles = _query_tiles(kv, batch, BS_T, 32)
+        rows = np.arange(T)
+        long_rows = ((rows >= 1) & (rows < 141)) | ((rows >= 142)
+                                                    & (rows < 172))
+        short_rows = np.isin(rows, [0, 141, 172])
+        for off, mine in (("short", long_rows), ("long", short_rows)):
+            only = tiles._replace(**{off: jax.tree.map(
+                jnp.zeros_like, getattr(tiles, off))})
+            out = np.asarray(paged_attention(kv, q, only, scale))
+            np.testing.assert_allclose(out[mine], ref[mine], atol=1e-5,
+                                       rtol=1e-5)
+            assert not out[~mine].any()
+
+    @pytest.mark.parametrize("name", sorted(TILE_BATCHES))
+    def test_tile_lists_and_host_count_agree(self, name):
+        """``query_tiles`` on the device and ``tile_counts`` on the
+        host see the same step; every tile is one slot's consecutive
+        rows and positions."""
+        from deepspeed_tpu.inference.model import _query_tiles
+        from deepspeed_tpu.ops.paged_attention import (LONG, SHORT,
+                                                        tile_counts)
+
+        runs, T = TILE_BATCHES[name]
+        batch, slots = _built_batch(runs, T)
+        tiles = _query_tiles(_random_pool(8), batch, BS_T, 32)
+        n_short, n_long, long_rows = tile_counts([n for _, _, n in runs])
+        assert int(tiles.short.count) == n_short
+        assert int(tiles.long.count) == n_long
+        lengths = np.asarray(tiles.long.length)
+        assert lengths[:n_long].sum() == long_rows
+        assert not lengths[n_long:].any()
+        tables = np.asarray(batch.block_tables)
+        seen = np.zeros(T, int)
+        for tl, height in ((tiles.short, SHORT), (tiles.long, LONG)):
+            for k in range(int(tl.count)):
+                row, pos, n = (int(np.asarray(a)[k])
+                               for a in (tl.row, tl.pos, tl.length))
+                assert 1 <= n <= height
+                sl = slice(row, row + n)
+                slot = np.asarray(batch.seq_slot)[sl]
+                assert (slot == slot[0]).all()
+                np.testing.assert_array_equal(
+                    np.asarray(batch.positions)[sl], np.arange(pos, pos + n))
+                want = tables[slot[0], :32]
+                np.testing.assert_array_equal(
+                    np.asarray(tl.tables)[k],
+                    np.where(want < 0, NBLK_T, want))
+                seen[sl] += 1
+        # every real row in exactly one tile, padding in none
+        np.testing.assert_array_equal(
+            seen, np.asarray(batch.token_valid).astype(int))
+
+
+class TestTileCounter:
+    """The counter the tile grid brings: ``n_tiles_short``,
+    ``n_tiles_long`` and ``tile_fill`` on the ``ds.serve.stage`` span
+    and in ``metrics_snapshot()``, counted on the host from the
+    schedule (``ops/paged_attention.tile_counts``)."""
+
+    @staticmethod
+    def _stage_spans(eng):
+        return [e for e in eng.tracer.events()
+                if e["name"] == "ds.serve.stage"]
+
+    def test_decode_tokens_and_one_chunk(self):
+        import deepspeed_tpu  # noqa: F401  (registers presets)
+        from tests.test_inference import make_fp32_engine, tiny_model
+        from deepspeed_tpu.inference import SamplingParams
+
+        eng = make_fp32_engine(tiny_model(max_seq_len=256),
+                               attn_impl="pallas", token_budget=192,
+                               max_seqs=72, num_kv_blocks=160, trace=True)
+        sp = SamplingParams(temperature=0.0, max_new_tokens=1 << 30)
+        for uid in range(64):
+            eng.put(uid, [3 + uid % 50, 7])
+        first = eng.step(sampling=sp)
+        assert len(first) == 64
+        (span,) = self._stage_spans(eng)
+        # 64 two-token prompts: 64 short tiles, no long one
+        assert (span["args"]["n_tiles_short"], span["args"]["n_tiles_long"],
+                span["args"]["tile_fill"]) == (64, 0, 0.0)
+        assert "serving_attn_tile_fill" not in eng.metrics_snapshot()
+        for uid, tok in first.items():
+            eng.put(uid, [tok])
+        eng.put(100, list(range(1, 105)))
+        assert len(eng.step(sampling=sp)) == 65
+        span = self._stage_spans(eng)[-1]
+        assert span["args"]["n_tokens"] == 64 + 104
+        assert (span["args"]["n_tiles_short"],
+                span["args"]["n_tiles_long"]) == (64, 1)
+        assert span["args"]["tile_fill"] == pytest.approx(104 / 128)
+        snap = eng.metrics_snapshot()
+        tiles = snap["serving_attn_tiles_total"]
+        assert tiles == {'{height="short"}': 128, '{height="long"}': 1}
+        assert snap["serving_attn_tile_fill"] == pytest.approx(104 / 128)
+        # a round with nothing to schedule stages nothing: no span, no
+        # tile
+        assert eng.step(sampling=sp) == {}
+        assert len(self._stage_spans(eng)) == 2
+        assert eng.metrics_snapshot()["serving_attn_tiles_total"] == tiles
+
+    def test_xla_formulation_counts_no_tiles(self):
+        import deepspeed_tpu  # noqa: F401
+        from tests.test_inference import make_fp32_engine, tiny_model
+        from deepspeed_tpu.inference import SamplingParams
+
+        eng = make_fp32_engine(tiny_model(), attn_impl="xla", trace=True)
+        eng.put(0, [5, 6, 7])
+        eng.step(sampling=SamplingParams(temperature=0.0,
+                                         max_new_tokens=4))
+        (span,) = self._stage_spans(eng)
+        assert "n_tiles_short" not in span["args"]
+        assert eng.metrics_snapshot()["serving_attn_tiles_total"] == 0
+
+
 def logits_idx_rows(batch):
     return np.nonzero(np.asarray(batch.logits_idx) >= 0)[0]
 

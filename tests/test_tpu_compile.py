@@ -27,7 +27,7 @@ from deepspeed_tpu.models.presets import PRESETS
 from deepspeed_tpu.ops.flash_attention import flash_attention
 from deepspeed_tpu.ops.grouped_matmul import grouped_matmul
 from deepspeed_tpu.ops.mixed_gemm import mixed_matmul
-from deepspeed_tpu.ops.paged_attention import paged_attention
+from deepspeed_tpu.ops.paged_attention import paged_attention, query_tiles
 from deepspeed_tpu.ops.quant import QuantizedTensor
 
 LLAMA = PRESETS["llama3-8b"]
@@ -104,11 +104,13 @@ def test_paged_attention_compiles(one_chip, on_chip, case):
     idx = S((T,), jnp.int32)
     tables = S((8, c["nb"]), jnp.int32)
 
-    def fn(kv, q, slot, pos, tables):
-        return paged_attention(kv, q, slot, pos, tables, bs, c["nb"],
-                               D ** -0.5)
+    def fn(kv, q, slot, pos, valid, tables):
+        tiles = query_tiles(slot, pos, valid, tables, bs, c["nb"],
+                            trash=c["blocks"])
+        return paged_attention(kv, q, tiles, D ** -0.5)
 
-    assert _compile(fn, kv, q, idx, idx, tables) == 1
+    # the one kernel body at its two heights
+    assert _compile(fn, kv, q, idx, idx, S((T,), jnp.bool_), tables) == 2
 
 
 # ---------------------------------------------------------------- flash
@@ -243,9 +245,44 @@ def _pstep_compiled(one_chip, cfg, kv_quant, T, seqs, bs, mbs, blocks):
             bs, mbs, attn_impl="pallas")
 
     prev = seqs + (MOE_STAT_ROWS if cfg.num_experts > 1 else 0)
-    return jax.jit(pstep, donate_argnums=(1,)).lower(
-        params, kv, batch, S((prev,), jnp.int32),
-        S(key.shape, key.dtype)).compile(), layer_bytes
+    args = (params, kv, batch, S((prev,), jnp.int32),
+            S(key.shape, key.dtype))
+    compiled = jax.jit(pstep, donate_argnums=(1,)).lower(*args).compile()
+    compiled.jaxpr = jax.make_jaxpr(pstep)(*args).jaxpr
+    return compiled, layer_bytes
+
+
+def _eqns(jaxpr, inside_scan=False):
+    """(equation, is it inside a scan) for every equation of ``jaxpr``
+    and of the jaxprs its equations hold."""
+    for e in jaxpr.eqns:
+        yield e, inside_scan
+        for sub in jax.core.jaxprs_in_params(e.params):
+            yield from _eqns(sub, inside_scan or e.primitive.name == "scan")
+
+
+def _tile_grid_conditions(jaxpr, T, seqs, mbs):
+    """The tile grid of ``ops/paged_attention.py`` in the step's jaxpr:
+    two calls a layer (one kernel body, two heights), each over a traced
+    count of tiles whose static bound is at most ``T/128 + seqs`` rows of
+    ``mbs`` blocks, and the block-table rows those tiles carry gathered
+    once a step, outside the layer scan."""
+    calls = [(e, scanned) for e, scanned in _eqns(jaxpr)
+             if e.primitive.name == "pallas_call"
+             and e.params["name"].startswith("paged_attention")]
+    assert len(calls) == 2 and all(scanned for _, scanned in calls)
+    for e, _ in calls:
+        gm = e.params["grid_mapping"]
+        assert gm.num_dynamic_grid_bounds == 2      # (tiles, blocks)
+        tables = e.invars[gm.num_dynamic_grid_bounds].aval
+        assert tables.shape[1] == mbs
+        assert tables.shape[0] * mbs <= (T // 128 + seqs) * mbs
+    table_gathers = [scanned for e, scanned in _eqns(jaxpr)
+                     if e.primitive.name == "gather"
+                     and e.outvars[0].aval.ndim == 2
+                     and e.outvars[0].aval.shape[1] >= mbs
+                     and e.outvars[0].aval.dtype == jnp.int32]
+    assert table_gathers and not any(table_gathers)
 
 
 def test_moe_serving_step_compiles_and_fits(one_chip, on_chip):
@@ -263,7 +300,9 @@ def test_moe_serving_step_compiles_and_fits(one_chip, on_chip):
     compiled, layer_bytes = _pstep_compiled(
         one_chip, cfg, False, T=512, seqs=64, bs=64, mbs=16, blocks=768)
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == 4
+    # the paged kernel at its two heights, the grouped kernel's three
+    assert text.count("tpu_custom_call") == 5
+    _tile_grid_conditions(compiled.jaxpr, T=512, seqs=64, mbs=16)
     leaf_bytes = cfg.num_experts * cfg.d_model * cfg.d_ff * 2
     assert _moves_of(text, 1), "the reader no longer finds any copy"
     assert _moves_of(text, leaf_bytes) == []
@@ -291,7 +330,14 @@ def test_serving_step_keeps_the_pool_in_place(one_chip, on_chip, kv_quant):
         one_chip, build_config("mistral-7b", num_layers=16), kv_quant,
         T=512, seqs=64, bs=64, mbs=16, blocks=1024)
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == 1
+    assert text.count("tpu_custom_call") == 2
     assert _moves_of(text, 1), "the reader no longer finds any copy"
     assert _moves_of(text, layer_bytes) == []
-    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
+    # the queries reach the kernel in batch order and the output leaves
+    # it so: at most a relaid ``q`` and output (4 MB each at these
+    # sizes), never a copy of them padded to tiles (68 tiles of 128 rows
+    # would be 71 MB); the int8 pair still pays its scales' relayout
+    # (76.8 MB a layer, as before the tile grid)
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        100e6 if kv_quant else 16e6)
+    _tile_grid_conditions(compiled.jaxpr, T=512, seqs=64, mbs=16)
