@@ -13,14 +13,15 @@ checks what comes out by the repo's own means:
            seq 1024, bf16, ZeRO-1: loss finite on every step and falling
            on a repeated batch;
 * serve    ``InferenceEngine`` on the llama3-8b preset at its published
-           widths (depth cut, printed) with ``attn_impl="auto"`` so the
-           engine's own kernel race runs: ``generate`` in process, then
-           the same engine behind an in-process ``Gateway`` answering
+           widths (depth cut, printed) with ``attn_impl="auto"``, the
+           engine's own rule (the Pallas kernel on a TPU; its verdict is
+           printed): ``generate`` in process, then the same engine
+           behind an in-process ``Gateway`` answering
            ``POST /v1/completions`` over loopback.  First-token logits
            agree with a plain non-paged ``model.apply`` of the same
            weights; HTTP tokens equal the in-process ones;
-* serve-int8  the same widths with int8 weights, so the mixed-GEMM race
-           runs too;
+* serve-int8  the same widths with int8 weights and ``mixed_gemm="on"``
+           (the kernels phase times that kernel against XLA's dequant);
 * serve-moe  ``InferenceEngine.generate`` on the olmoe-1b-7b preset at
            its published widths (64 experts, top-8, QK-norm, 16/16 heads;
            depth cut, printed): the grouped expert kernel against
@@ -461,7 +462,7 @@ def serve_config(sz, **kw):
     return InferenceConfig(token_budget=s["token_budget"],
                            max_seqs=s["max_seqs"], kv_block_size=s["block"],
                            num_kv_blocks=s["blocks"], attn_impl="auto",
-                           mixed_gemm="auto", **kw)
+                           **kw)
 
 
 def make_prompts(sz, vocab, seed):
@@ -512,11 +513,12 @@ def reference_logits(model, prompts):
     return dict(zip(prompts, out))
 
 
-def report_probe(eng):
-    for what, res in eng.probe_times.items():
-        print(f"    engine race [{what}]: chose {min(res, key=res.get)} of "
-              f"{ {k: round(1e3 * v, 2) for k, v in res.items()} } "
-              "(ms per 3 steps)")
+def report_path(eng):
+    import jax
+
+    print(f"    engine path: attn_impl {eng.icfg.attn_impl!r} -> "
+          f"{eng.attn_impl!r} on backend {jax.default_backend()!r}, "
+          f"mixed_gemm {eng.icfg.mixed_gemm!r}")
 
 
 def check_first_tokens(label, tokens, logits, ref, rel):
@@ -564,12 +566,12 @@ def serve_phase(sz, seed):
     t0 = time.perf_counter()
     local = eng.generate({u: list(p) for u, p in prompts.items()}, greedy)
     t_cold = time.perf_counter() - t0
-    report_probe(eng)
+    report_path(eng)
     check(all(len(v) == n and all(0 <= t < cfg.vocab_size for t in v)
               for v in local.values()), f"bad in-process tokens {local}")
     print(f"    in-process generate: {len(prompts)} prompts of "
           f"{[len(p) for p in prompts.values()]} tokens -> {n} new each, "
-          f"{t_cold:.1f} s with the race and compiles")
+          f"{t_cold:.1f} s with compiles")
 
     logits = prefill_logits(eng, eng._build_step(), prompts)
     check_first_tokens("bf16", local, logits, reference_logits(model, prompts),
@@ -601,8 +603,9 @@ def serve_phase(sz, seed):
 
 
 def serve_int8_phase(sz, seed):
-    """int8 weights at the same widths, so the engine's mixed-GEMM race
-    (Pallas VMEM-dequant kernel vs XLA's fused dequant) runs as well."""
+    """int8 weights at the same widths, the projections through the
+    Pallas VMEM-dequant kernel (``kernels_phase`` times it against XLA's
+    fused dequant)."""
     from deepspeed_tpu.inference import InferenceEngine, SamplingParams
     from deepspeed_tpu.models.presets import build_config
 
@@ -610,13 +613,14 @@ def serve_int8_phase(sz, seed):
     cfg = build_config("llama3-8b", num_layers=s["int8_layers"],
                        **s["overrides"])
     model = random_model(cfg, seed + 1)
-    eng = InferenceEngine(model, serve_config(sz, weight_quant="int8"))
+    eng = InferenceEngine(model, serve_config(sz, weight_quant="int8",
+                                              mixed_gemm="on"))
     prompts = make_prompts(sz, cfg.vocab_size, seed + 1)
     print(f"  llama3-8b widths, DEPTH {cfg.num_layers} of 32, int8 weights")
     out = eng.generate({u: list(p) for u, p in prompts.items()},
                        SamplingParams(temperature=0.0,
                                       max_new_tokens=s["new_tokens"]))
-    report_probe(eng)
+    report_path(eng)
     check(all(len(v) == s["new_tokens"] for v in out.values()),
           f"bad tokens {out}")
     logits = prefill_logits(eng, eng._build_step(), prompts)
@@ -667,7 +671,7 @@ def serve_moe_phase(sz, seed):
     out = eng.generate({u: list(p) for u, p in prompts.items()},
                        SamplingParams(temperature=0.0,
                                       max_new_tokens=s["new_tokens"]))
-    report_probe(eng)
+    report_path(eng)
     check(all(len(v) == s["new_tokens"] for v in out.values()),
           f"bad tokens {out}")
     snap = eng.metrics_snapshot()
@@ -767,11 +771,11 @@ def four_chip_phases(sz, seed):
         print(f"  llama3-8b widths, DEPTH {cfg.num_layers} of 32, bf16")
         one = InferenceEngine(model, serve_config(sz))
         ref = one.generate({u: list(p) for u, p in prompts.items()}, greedy)
-        report_probe(one)
+        report_path(one)
         topo = MeshTopology.build(MeshConfig(tensor=4), devices=devs)
         tp = InferenceEngine(model, serve_config(sz), topology=topo)
         out = tp.generate({u: list(p) for u, p in prompts.items()}, greedy)
-        report_probe(tp)
+        report_path(tp)
         share = spread("TP weights", tp.params, 4)
         check(share > 0.9, f"only {share:.1%} of the weights is sharded")
         check(spread("TP KV cache", tp.state.kv, 4) == 1.0,

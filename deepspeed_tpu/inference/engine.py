@@ -66,9 +66,12 @@ class InferenceConfig:
     max_seq_len: Optional[int] = None   # default: model max
     kv_dtype: object = jnp.bfloat16
     param_dtype: object = jnp.bfloat16
-    # paged attention implementation: "auto" probes the Pallas streaming
-    # kernel against the XLA gather formulation on the first step's real
-    # shapes and keeps the faster one; "xla" / "pallas" force a path
+    # paged attention implementation: "pallas" is the streaming kernel
+    # (ops/paged_attention.py), "xla" the gather formulation it is tested
+    # against.  "auto" is a rule, settled at construction: "pallas" on a
+    # TPU backend (where it wins every shape the chip has timed, and the
+    # XLA path's gather does not fit beside a 7B model), "xla" everywhere
+    # else (there the kernel only runs interpreted)
     attn_impl: str = "auto"
     # "int8" | "fp8": store the paged KV cache quantized (one scale per
     # written token/head vector, per-block layout).  Halves (int8) the
@@ -81,15 +84,13 @@ class InferenceConfig:
     # a time inside the forward (2-4x smaller resident model)
     weight_quant: Optional[str] = None
     # mixed-input GEMM (int8 weight x bf16 act, dequant in VMEM —
-    # ops/mixed_gemm.py; reference: cuda_linear fp6 GEMM): "auto" races
-    # it against the fused-dequant XLA path once post-compile (like
-    # attn_impl); "on"/"off" force.  Engages for the row-wise int8
-    # and packed row-wise int4 layouts.
-    mixed_gemm: str = "auto"
+    # ops/mixed_gemm.py; reference: cuda_linear fp6 GEMM): "on" runs the
+    # projections through it and raises unless the weights are in the
+    # row-wise int8 or packed row-wise int4 layout it consumes; "off"
+    # (the default: the one timing the chip has given chose it)
+    # dequantizes in the fused XLA path
+    mixed_gemm: str = "off"
     quantize_embeddings: bool = False
-    # keep the paged KV cache in host memory, streaming one layer per
-    # scan step through HBM (over-HBM contexts; needs pinned_host)
-    kv_offload: bool = False
     # NVMe per-layer weight streaming (reference:
     # partitioned_param_swapper.py:290 / ZeRO-Inference NVMe): directory
     # to spill the per-layer (quantized, when weight_quant is set)
@@ -301,10 +302,6 @@ class InferenceConfig:
     slo_objectives: Optional[Dict[str, "SloObjective"]] = None
 
 
-# attn-impl probe results, memoized per (backend, shape signature)
-_PROBE_CACHE: Dict[tuple, str] = {}
-
-
 class _InFlight(NamedTuple):
     """One dispatched-but-unread serving step: the on-device [max_seqs]
     sample array, the (uid, slot) emission list frozen at dispatch time
@@ -374,6 +371,21 @@ class InferenceEngine:
                 "demoted blocks by their chain digests, which only the "
                 "prefix-cache index computes (set prefix_cache to "
                 "'auto'/'on' or kv_tier to 'auto'/'off')")
+        if self.icfg.attn_impl not in ("auto", "xla", "pallas"):
+            raise ValueError(f"attn_impl={self.icfg.attn_impl!r}: "
+                             "expected 'auto', 'xla', or 'pallas'")
+        if self.icfg.mixed_gemm not in ("on", "off"):
+            raise ValueError(f"mixed_gemm={self.icfg.mixed_gemm!r}: "
+                             "expected 'on' or 'off'")
+        # the attention formulation every serving program of this engine
+        # runs.  "auto" takes the kernel where it is compiled and the XLA
+        # formulation where it would run interpreted: a rule over what
+        # the process can observe, so two engines of one process always
+        # agree and nothing is compiled or timed to settle it
+        self.attn_impl = self.icfg.attn_impl
+        if self.attn_impl == "auto":
+            self.attn_impl = ("pallas" if jax.default_backend() == "tpu"
+                              else "xla")
         max_len = self.icfg.max_seq_len or self.cfg.max_seq_len
         # a sequence can never hold more blocks than the pool has
         self.max_blocks_per_seq = min(-(-max_len // self.icfg.kv_block_size),
@@ -416,26 +428,29 @@ class InferenceEngine:
         self._stream = None
         if self.icfg.weight_stream:
             self._setup_weight_stream()
-        if self.icfg.mixed_gemm == "on":
+        self._mixed_gemm_active = self.icfg.mixed_gemm == "on"
+        if self._mixed_gemm_active:
             # fail at construction, not at the first compiled step: an
             # explicit force-on with an ineligible layout is a config error
             self._require_mixed_gemm_eligible()
         self._setup_sharding()
         # resolved overlapped/quantized-collective plan (comm/overlap.py)
         # — None when the mesh/shapes give the decomposition nothing to
-        # do; _resolve_fw may still drop the down-projection leg when
-        # the mixed-GEMM probe keeps those weights quantized
+        # do — and the plan the compiled programs run
         self._serving_comm = self._resolve_serving_comm()
-        self._comm_active = self._serving_comm
+        comm = self._serving_comm
+        if comm is not None and self._mixed_gemm_active and comm.downproj:
+            # mixed-GEMM keeps the down-projection weight quantized for
+            # the VMEM-dequant kernel — only the unembed gather can
+            # still decompose; the plan (and its wire accounting)
+            # shrinks to match the compiled program
+            comm = comm._replace(downproj=False, quant_bits=None)
+            if not comm.unembed:
+                comm = None
+        self._comm_active = comm
         self._comm_stats: Optional[Dict[str, float]] = None
         if self.topology is None:
             self._place_default_device()
-        if self.icfg.kv_offload:
-            if self.topology is not None:
-                logger.warning("kv_offload is single-device only; ignored "
-                               "under a multi-device topology")
-            else:
-                self._offload_kv()
         # tpulint: live-set — uid -> unprocessed toks
         self._pending: Dict[int, List[int]] = {}
         self._ctx_exhausted: set = set()
@@ -443,15 +458,15 @@ class InferenceEngine:
         self._cow_fn = None           # lazy jitted prefix-cache block copy
         self._restage_fn = None       # lazy jitted tier->HBM block upload
         self._pstep_fns: Dict[tuple, object] = {}  # (bucket, sampler_key)
-        # probe label -> {variant: seconds per 3 steps}, for every race
-        # this engine ran itself (a memoized verdict leaves no entry)
+        # always empty: the engine races nothing at start-up any more.
+        # Kept because benchmarks/lib/drivers/serve.py iterates it for
+        # its "races" notes and a PR of this kind may not edit that file
+        # (ROADMAP.md debt B1: retire the readers, then this attribute)
         self.probe_times: Dict[str, Dict[str, float]] = {}
         # (bucket, sampler_key) -> what the compiled step is, noted once
-        # at its first call: "form" says how the layer scan holds the
-        # cache ("carried": in place, as the scan's carry; "streamed":
-        # one layer through HBM at a time, the host-resident cache) and
-        # "temp_bytes" what the program needs beside its arguments — a
-        # carried step's stays far under one layer's share of the pool
+        # at its first call: "temp_bytes" is what the program needs
+        # beside its arguments — the layer scan carries the cache in
+        # place, so it stays far under one layer's share of the pool
         self.serving_programs: Dict[tuple, Dict] = {}
         self._burst_fns: Dict[tuple, object] = {}
         # serving programs that have COMPLETED at least one call: only
@@ -748,13 +763,6 @@ class InferenceEngine:
                      "largest temporary allocation among the compiled "
                      "serving steps (what has to fit beside weights and "
                      "KV pool; absent before the first step)")
-        reg.gauge_fn("serving_step_cache_carried",
-                     lambda: (all(p["form"] == "carried"
-                                  for p in progs.values())
-                              if progs else None),
-                     "1 when every compiled serving step carries the "
-                     "paged cache through its layer scan in place, 0 "
-                     "when one streams it a layer at a time (kv_offload)")
         # tier occupancy: pull-gauges over tier.stats() truth (absent
         # when the tier is off — None suppresses the series, the same
         # contract the devtel gauges use)
@@ -889,11 +897,8 @@ class InferenceEngine:
             logger.warning("serving step %r: no memory analysis (%s: %s)",
                            key, type(e).__name__,
                            str(e).splitlines()[0][:120] if str(e) else "")
-        form = "streamed" if getattr(self, "_kv_on_host", False) \
-            else "carried"
-        self.serving_programs[key] = {"form": form, "temp_bytes": temp}
-        logger.info("serving step %r: cache %s through the layer scan, "
-                    "temporaries %s bytes", key, form, temp)
+        self.serving_programs[key] = {"temp_bytes": temp}
+        logger.info("serving step %r: temporaries %s bytes", key, temp)
 
     def reset_timings(self) -> None:
         """Zero the cumulative per-phase breakdown the serving loop
@@ -1412,58 +1417,14 @@ class InferenceEngine:
             kv = jax.device_put(kv, self._kv_nsh)
         return kv
 
-    def _offload_kv(self) -> None:
-        """Move the paged KV cache to host memory (ZeRO-Inference KV
-        offload); best-effort — backends without an addressable host
-        space keep it in HBM with a warning."""
-        try:
-            # probe the whole path: the backend must also EXECUTE
-            # in-program host<->device transfers, not just place arrays
-            # (the CPU backend accepts the placement but has no runtime
-            # implementation for the device_put custom call)
-            def roundtrip(x):
-                h = jax.device_put(x, jax.memory.Space.Host)
-                return jax.device_put(h * 2.0, jax.memory.Space.Device)
-            jax.block_until_ready(jax.jit(roundtrip)(jnp.ones(8)))
-            kv = jax.device_put(self.state.kv, jax.memory.Space.Host)
-            jax.block_until_ready(kv)
-            self.state.kv = kv
-            self._kv_on_host = True
-        except Exception as e:
-            logger.warning(f"kv_offload unavailable on this backend "
-                           f"({type(e).__name__}); KV stays in HBM")
-            self._kv_on_host = False
-
     # ------------------------------------------------------------------
     def _resolve_fw(self, mbs: Optional[int]):
-        """Resolve the forward-pass knobs shared by every compiled
-        serving program (probing attn_impl/mixed_gemm on first use)."""
-        mbs = mbs or self.max_blocks_per_seq
-        impl = self._attn_impl()
-        mixed = self._resolve_mixed_gemm(impl)
-        self._mixed_gemm_active = mixed
-        comm = self._serving_comm
-        if comm is not None and mixed and comm.downproj:
-            # mixed-GEMM keeps the down-projection weight quantized for
-            # the VMEM-dequant kernel — only the unembed gather can
-            # still decompose; the plan (and its wire accounting)
-            # shrinks to match the compiled program
-            comm = comm._replace(downproj=False, quant_bits=None)
-            if not comm.unembed:
-                comm = None
-        self._comm_active = comm
-        self._comm_stats = None        # re-derive from the active plan
-        return dict(attn_impl=impl, mixed_gemm=mixed,
-                    kv_host=getattr(self, "_kv_on_host", False),
+        """The forward-pass knobs shared by every compiled serving
+        program."""
+        return dict(attn_impl=self.attn_impl,
+                    mixed_gemm=self._mixed_gemm_active,
                     shard_mesh=self._tp_mesh, stream=self._stream,
-                    comm=comm), mbs
-
-    def _attn_impl(self) -> str:
-        """The attention implementation the serving programs run:
-        ``icfg.attn_impl``, with ``auto`` settled by the start-up race
-        (once a process and shape signature)."""
-        impl = self.icfg.attn_impl
-        return self._probe_attn_impl() if impl == "auto" else impl
+                    comm=self._comm_active), mbs or self.max_blocks_per_seq
 
     def _donate_kv(self) -> bool:
         """Whether serving programs donate the paged cache.  See
@@ -1488,15 +1449,9 @@ class InferenceEngine:
         ``kv_argnum`` and whose output is (small replicated output,
         new_kv) — or bare new_kv with ``kv_only_output`` (the COW block
         copy) — with the cache donated (see ``_donate_kv``) and its
-        sharding (host placement / head split) pinned.  THE one place
-        the KV donation/placement jit policy lives."""
+        head-split sharding pinned.  THE one place the KV
+        donation/placement jit policy lives."""
         donate = (kv_argnum,) if self._donate_kv() else ()
-        if getattr(self, "_kv_on_host", False):
-            # pin the cache output to host memory so the persistent
-            # state never round-trips through HBM between steps
-            kv_sh = jax.tree.map(lambda x: x.sharding, self.state.kv)
-            out_sh = kv_sh if kv_only_output else (None, kv_sh)
-            return jax.jit(fn, donate_argnums=donate, out_shardings=out_sh)
         if self._kv_nsh is not None:
             # logits/tokens replicated (one small host fetch), cache
             # keeps its head-split sharding across the donation
@@ -1558,145 +1513,6 @@ class InferenceEngine:
 
         return self._serving_jit(pstep)
 
-    def _probe_key(self, what: str):
-        cfg = self.cfg
-        topo_sig = (None if self.topology is None else
-                    tuple(sorted(self.topology.axis_sizes.items())))
-        return (what, jax.default_backend(), cfg.num_layers, cfg.d_model,
-                cfg.num_heads, cfg.num_kv_heads, self.icfg.token_budget,
-                self.icfg.max_seqs, self.icfg.kv_block_size,
-                self.icfg.num_kv_blocks, self.max_blocks_per_seq,
-                self.icfg.kv_quant, topo_sig, self._tp_mesh is not None)
-
-    def _probe_variants(self, label: str, variants):
-        """Race full ragged steps, one per variant (name -> extra
-        ragged_forward kwargs), on the real compiled shapes; returns
-        {name: seconds-per-3-steps}.  On a TPU backend a variant that
-        fails to compile or run raises; elsewhere it is dropped."""
-        import time
-
-        cfg, bs, mbs = self.cfg, self.icfg.kv_block_size, \
-            self.max_blocks_per_seq
-        T, ms = self.icfg.token_budget, self.icfg.max_seqs
-        nb = self.icfg.num_kv_blocks
-        # synthetic batch on the compiled shapes — does NOT touch the
-        # state manager (no slot/block allocation).  Representative work:
-        # every slot at FULL context (tables fully populated, its tokens
-        # the last of the context) — a near-empty batch would let the
-        # Pallas kernel skip almost all of its blocks while the XLA
-        # gather path pays full cost regardless, biasing the probe.
-        # A batch as ``build_batch`` makes them: the budget split evenly
-        # over the slots, each slot's tokens one run of consecutive
-        # rows and positions (the kernel's tile lists count on a slot
-        # holding one run), what is left over budget padding.
-        tables = np.zeros((ms, nb), np.int32)
-        tables[:, :mbs] = np.arange(mbs, dtype=np.int32)[None, :] \
-            % max(1, nb - 1)
-        last_pos = mbs * bs - 1
-        per = min(-(-T // ms), mbs * bs)
-        t = np.arange(T)
-        batch = RaggedBatch(
-            token_ids=jnp.zeros(T, jnp.int32),
-            positions=jnp.asarray(last_pos - (per - 1) + t % per,
-                                  jnp.int32),
-            seq_slot=jnp.asarray(np.minimum(t // per, ms - 1), jnp.int32),
-            token_valid=jnp.asarray(t < per * ms),
-            block_tables=jnp.asarray(tables),
-            context_lens=jnp.full(ms, last_pos + 1, jnp.int32),
-            logits_idx=jnp.full(ms, -1, jnp.int32).at[0].set(per - 1),
-            n_tokens=min(T, per * ms), n_seqs=min(ms, -(-T // per)))
-        batch = self._stage(batch)
-        results = {}
-        # probe on the real (pre-serving, all-zeros) cache with donation,
-        # threading the cache through — never two full KV pools live at
-        # once, matching the real step's memory profile
-        kv = self.state.kv
-        for name, extra in variants.items():
-            try:
-                jit_kw = {}
-                if self._kv_nsh is not None:
-                    jit_kw["out_shardings"] = (self._repl, self._kv_nsh)
-
-                def probe_step(params, quant, pkv, pbatch, _extra=extra):
-                    return ragged_forward(
-                        cfg, params, pkv, pbatch, bs, mbs,
-                        quant=quant,
-                        shard_mesh=self._tp_mesh, stream=self._stream,
-                        kv_host=getattr(self, "_kv_on_host", False),
-                        **_extra)
-
-                # one compile per probed attention variant IS the
-                # autotune measurement; each wrapper is used then dropped
-                f = jax.jit(probe_step, donate_argnums=(2,), **jit_kw)  # tpulint: disable=retrace-hazard
-                logits, kv = f(self.params, self._quant, kv, batch)
-                float(jnp.sum(logits))      # compile + settle, untimed
-                # probe budget from ONE post-compile step: a path an
-                # order of magnitude behind the best-so-far (3-step
-                # totals both sides) loses without the timed loop —
-                # pathological paths (100 s/step seen on the chunked XLA
-                # path at 8B shapes) must not stall start-up for minutes
-                t_w = time.perf_counter()
-                logits, kv = f(self.params, self._quant, kv, batch)
-                float(jnp.sum(logits))
-                warm3 = (time.perf_counter() - t_w) * 3
-                best = min(results.values()) if results else None
-                if warm3 > (180.0 if best is None
-                            else max(30.0, 10 * best)):
-                    logger.info(f"{label} probe: {name} at "
-                                f"{warm3 / 3:.1f}s/step — skipping "
-                                "timed loop")
-                    results[name] = warm3
-                    continue
-                t0 = time.perf_counter()
-                for _ in range(3):
-                    logits, kv = f(self.params, self._quant, kv, batch)
-                float(jnp.sum(logits))      # completion barrier
-                results[name] = time.perf_counter() - t0
-            except Exception as e:
-                if jax.default_backend() == "tpu":
-                    # on the chip every variant must compile and run:
-                    # losing the race on time is a probe outcome, a
-                    # kernel the chip refuses is a bug — never a silent
-                    # switch to the other path
-                    raise
-                # off-TPU the Pallas variants run in interpret mode,
-                # which may not support everything the kernel does
-                logger.warning(f"{label} probe: {name} failed "
-                               f"({type(e).__name__}); skipping")
-        # restore a pristine zero cache (the probe wrote its fake token)
-        # and drop any prefix-cache index entries — zeroed blocks no
-        # longer hold the content their hashes promise
-        self.state.kv = self._kv_zeros()
-        self.state.reset_prefix_cache()
-        if getattr(self, "_kv_on_host", False):
-            self.state.kv = jax.device_put(self.state.kv,
-                                           jax.memory.Space.Host)
-        if not results:
-            raise RuntimeError(f"{label} probe: every variant failed")
-        self.probe_times[label] = results
-        logger.info(
-            f"{label} probe: {min(results, key=results.get)} "
-            f"({ {k: round(v * 1e3, 1) for k, v in results.items()} }"
-            " ms/3 steps)")
-        return results
-
-    def _probe_attn_impl(self) -> str:
-        """Time one ragged forward per implementation on the real compiled
-        shapes and keep the winner (which one wins depends on backend
-        and shapes — on CPU meshes the interpret-mode kernel always
-        loses).  Results are memoized per (backend, shape signature)
-        for the process."""
-        key = self._probe_key("attn")
-        cached = _PROBE_CACHE.get(key)
-        if cached is not None:
-            return cached
-        results = self._probe_variants(
-            "paged-attention",
-            {"xla": {"attn_impl": "xla"}, "pallas": {"attn_impl": "pallas"}})
-        best = min(results, key=results.get)
-        _PROBE_CACHE[key] = best
-        return best
-
     def _quant_is_rowwise(self) -> bool:
         """The mixed-input kernel family consumes the row-wise int8
         (weight-shaped payload) and packed row-wise int4 layouts.
@@ -1717,42 +1533,15 @@ class InferenceEngine:
         return bool(leaves) and all(is_mixed_gemm_layout(q)
                                     for q in leaves)
 
-    def _mixed_gemm_eligible(self) -> bool:
-        return (self._quant_is_rowwise() if self._stream is None
-                else self._stream.mixed_gemm_eligible)
-
     def _require_mixed_gemm_eligible(self) -> None:
-        if not self._mixed_gemm_eligible():
-            what = ("the weight-stream payloads are"
-                    if self._stream is not None
+        streamed = self._stream is not None
+        if not (self._stream.mixed_gemm_eligible if streamed
+                else self._quant_is_rowwise()):
+            what = ("the weight-stream payloads are" if streamed
                     else "the resident quantized weights are")
             raise ValueError(
                 f"mixed_gemm='on': {what} not a row-wise int8/int4 "
-                "layout the kernel family consumes; use 'auto'")
-
-    def _resolve_mixed_gemm(self, attn_impl: str) -> bool:
-        """Resolve the mixed_gemm config to a bool for this build
-        (reference analog: the cuda_linear kernel selection)."""
-        mode = self.icfg.mixed_gemm
-        if mode == "on":
-            self._require_mixed_gemm_eligible()
-            return True
-        if mode == "off" or not self._mixed_gemm_eligible():
-            return False
-        # streamed and resident steps have different cost profiles —
-        # never share a probe verdict between them
-        key = self._probe_key(
-            "mixed_gemm_" + attn_impl
-            + ("_stream" if self._stream is not None else ""))
-        cached = _PROBE_CACHE.get(key)
-        if cached is None:
-            results = self._probe_variants(
-                "mixed-gemm",
-                {"dequant": {"attn_impl": attn_impl, "mixed_gemm": False},
-                 "mixed": {"attn_impl": attn_impl, "mixed_gemm": True}})
-            cached = min(results, key=results.get) == "mixed"
-            _PROBE_CACHE[key] = cached
-        return cached
+                "layout the kernel family consumes; use 'off'")
 
     # ------------------------------------------------------------------
     # request API (reference: engine_v2.put :107)
@@ -2449,10 +2238,7 @@ class InferenceEngine:
             list(registered)
             + list(self.state.round_registered[pre_recovery:]))
         if kv_lost:
-            kv = self._kv_zeros()
-            if getattr(self, "_kv_on_host", False):
-                kv = jax.device_put(kv, jax.memory.Space.Host)
-            self.state.kv = kv
+            self.state.kv = self._kv_zeros()
             self.state.reset_prefix_cache()
             self._last_toks = None
         # a failed probe step retires its group — but NEVER loses it:
@@ -3082,7 +2868,7 @@ class InferenceEngine:
         # Pallas kernel's grid follows the batch (its tiles, and the
         # blocks of the deepest one), so there one program, bounded by
         # the engine's longest context, serves every step
-        pallas = self._attn_impl() == "pallas"
+        pallas = self.attn_impl == "pallas"
         mbs = self.max_blocks_per_seq
         if not pallas:
             bs_blk = self.icfg.kv_block_size
@@ -3152,47 +2938,14 @@ class InferenceEngine:
         uids = tuple(uid for uid, _ in sched)
         guard: Dict[str, float] = {}      # the watchdog's hand-off time
         try:
-            try:
-                # the one deadline-guarded dispatch seam: the watchdog
-                # (and the chaos harness's fault injector) wrap exactly
-                # this call — see inference/failures.py
-                toks, self.state.kv = self.failures.run(
-                    lambda: step_fn(self.params, self._quant,
-                                    self.state.kv, batch, prev, rng),
-                    uids=uids, cold=cold, site="dispatch", sid=sid,
-                    stamps=guard)
-            except jax.errors.JaxRuntimeError:
-                # degrade to an HBM cache ONLY on the first-ever step
-                # (the backend compiled but cannot execute in-program
-                # host transfers); a later-step error must propagate to
-                # the failure classifier below — zeroing a live cache
-                # here would silently corrupt every open sequence
-                if not getattr(self, "_kv_on_host", False) \
-                        or self._steps_done > 0:
-                    raise
-                logger.warning("kv_offload: backend cannot execute host "
-                               "transfers; falling back to HBM KV")
-                self._kv_on_host = False
-                # the failed call donated the cache; at step 0 it is all
-                # zeros — recreate it
-                self.state.kv = self.state.cfg.kv_zeros()
-                self._pstep_fns.clear()
-                self.serving_programs.clear()
-                # a backend-capability fallback is a LEGITIMATE rebuild
-                # of every serving program (like refresh_params): the
-                # dropped programs are cold again and their keys leave
-                # the retrace ledger — this must not count (or warn) as
-                # cache churn
-                self._warm_keys = {k for k in self._warm_keys
-                                   if k[0] != "p"}
-                self._compiled_ever = {k for k in self._compiled_ever
-                                       if k[0] != "p"}
-                step_fn = self._pstep_fns[key] = self._build_pstep(
-                    mbs, sampling)
-                self._note_compile("p", key)
-                toks, self.state.kv = step_fn(
-                    self.params, self._quant, self.state.kv, batch, prev,
-                    rng)
+            # the one deadline-guarded dispatch seam: the watchdog
+            # (and the chaos harness's fault injector) wrap exactly
+            # this call — see inference/failures.py
+            toks, self.state.kv = self.failures.run(
+                lambda: step_fn(self.params, self._quant,
+                                self.state.kv, batch, prev, rng),
+                uids=uids, cold=cold, site="dispatch", sid=sid,
+                stamps=guard)
         except Exception as e:
             # every failure on the dispatch path funnels through the
             # classifier seam (tpulint's serving-except rule holds the
@@ -3578,7 +3331,7 @@ class InferenceEngine:
             toks, tail = decode_burst_forward(
                 cfg, params, prefix, base_ctx, token0, steps, sample_fn,
                 rng, uids=uids, quant=quant,
-                mixed_gemm=getattr(self, "_mixed_gemm_active", False),
+                mixed_gemm=self._mixed_gemm_active,
                 sharded=self._tp_mesh is not None)
             kv = scatter_tail(kv, tail, block_tables, base_ctx, bs)
             return toks, kv
@@ -3610,10 +3363,9 @@ class InferenceEngine:
                              "to be a single-token continuation (with a "
                              "concrete, non-deferred token id); use "
                              "step() for prefill")
-        if getattr(self, "_kv_on_host", False) or self._stream is not None:
-            # bursts need the cache addressable on device and the block
-            # weights resident (streamed layers cannot feed the burst
-            # scan) — degrade to single steps
+        if self._stream is not None:
+            # bursts need the block weights resident (streamed layers
+            # cannot feed the burst scan) — degrade to single steps
             out = self.step(rng=rng, sampling=sampling)
             return {u: [t] for u, t in out.items()}
         # cap the burst by context headroom, then reserve its KV blocks

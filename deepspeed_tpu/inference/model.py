@@ -203,7 +203,8 @@ def _paged_attention(kv_layer, q, batch: RaggedBatch, block_size: int,
     computation streams one KV block at a time with an online-softmax
     accumulator instead (memory ∝ T·block_size, not T·context).  The
     Pallas streaming variant (``_paged_attention_pallas``) drops in
-    behind the same signature; ``InferenceEngine`` probes both.
+    behind the same signature (``InferenceEngine.attn_impl`` says which
+    one an engine runs).
     """
     T, H, D = q.shape
     data, scales = _kv_parts(kv_layer)
@@ -426,7 +427,6 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
                    rng: Optional[jax.Array] = None,
                    attn_impl: str = "xla",
                    quant=None,
-                   kv_host: bool = False,
                    shard_mesh=None,
                    stream=None,
                    mixed_gemm: bool = False,
@@ -453,15 +453,11 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
     ``quant``: ZeRO-Inference weight-quant tree (inference/quantization
     ``quantize_model_params``) — one layer is dequantized at a time
     inside the scan body, so dense weights never all coexist in HBM.
-    The cache in device memory rides the layer scan as a carry that
-    every layer updates in place: the stack is viewed as
-    ``[L * rows, ...]`` and layer ``li`` addresses its rows by block ids
-    moved by ``li * rows`` (``_layer_of``), so no layer is ever
-    sliced out of the stack or written back into it.
-    ``kv_host``: the cache lives in host memory; there the scan takes it
-    as a scanned input and output, and each step streams one layer
-    through HBM and writes it back (ZeRO-Inference KV offload) so device
-    memory holds a single layer's KV at a time.
+    The cache rides the layer scan as a carry that every layer updates
+    in place: the stack is viewed as ``[L * rows, ...]`` and layer
+    ``li`` addresses its rows by block ids moved by ``li * rows``
+    (``_layer_of``), so no layer is ever sliced out of the stack or
+    written back into it.
     ``stream``: an :class:`~.weight_stream.NVMeWeightStore` — the layer
     scan fetches each layer's (possibly quantized) weights from NVMe via
     ``io_callback`` so HBM holds one layer's weights at a time
@@ -523,10 +519,10 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
         blocks = {k: v for k, v in blocks.items() if k != "experts"}
 
     def block(x, lp, pool, layer, li):
-        """One layer's mathematics.  ``pool`` is the paged cache that
-        holds the layer where ``layer`` says (``_layer_of``): a layer's
-        own slice or the whole stacked cache, the block cannot tell
-        which.  ``li``: the layer's index, for weights kept stacked."""
+        """One layer's mathematics.  ``pool`` is the stacked paged
+        cache, which holds the layer where ``layer`` says
+        (``_layer_of``).  ``li``: the layer's index, for weights kept
+        stacked."""
         ap = lp["attn"]
         # named scopes at the block's seams (metadata only): a device
         # trace's operations carry them in their JAX path, which is how
@@ -577,30 +573,18 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
               else (blocks, layer_ids))
     rows = _kv_parts(kv)[0].shape[1]       # a layer's blocks + trash row
 
-    def streamed(x, xs):
-        # the cache lives in host memory: the scan slices one layer out,
-        # through HBM and back, which here IS the mechanism
-        *ws, kv_layer = xs
-        lp, li = layer_weights(ws)
-        kv_layer = jax.device_put(kv_layer, jax.memory.Space.Device)
-        x, kv_layer, stats = block(x, lp, kv_layer, None, li)
-        return x, (jax.device_put(kv_layer, jax.memory.Space.Host), stats)
-
     def carried(carry, ws):
-        # the cache lives in device memory: it rides the scan as a carry
-        # that each layer updates in place, and the layer is an offset
-        # into the stacked pool (no per-layer slice, no second pool)
+        # the cache rides the scan as a carry that each layer updates in
+        # place, and the layer is an offset into the stacked pool (no
+        # per-layer slice, no second pool)
         x, pool = carry
         lp, li = layer_weights(ws)
         x, pool, stats = block(x, lp, pool, (li * rows, rows), li)
         return (x, pool), stats
 
-    if kv_host:
-        x, (new_kv, stats) = jax.lax.scan(streamed, x, (*layers, kv))
-    else:
-        pool = jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), kv)
-        (x, pool), stats = jax.lax.scan(carried, (x, pool), layers)
-        new_kv = jax.tree.map(lambda a, o: a.reshape(o.shape), pool, kv)
+    pool = jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), kv)
+    (x, pool), stats = jax.lax.scan(carried, (x, pool), layers)
+    new_kv = jax.tree.map(lambda a, o: a.reshape(o.shape), pool, kv)
 
     with jax.named_scope("unembed"):
         logits = _unembed(cfg, params, embed_tab, x, batch, norm, dt, comm)

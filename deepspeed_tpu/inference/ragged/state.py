@@ -539,8 +539,8 @@ class StateManager:
 
     def reset_prefix_cache(self) -> None:
         """Drop every index entry; cached-free blocks become plain free.
-        (Used when cache CONTENT is invalidated, e.g. the engine's
-        attn-impl probe rewrites the pool with synthetic tokens.)"""
+        (Used when cache CONTENT is invalidated, e.g. a failed step
+        lost the donated pool and the engine starts from a zero one.)"""
         for b in list(self._block_hash):
             self.allocator.unmark_cached(b)
         self._block_hash.clear()

@@ -15,9 +15,10 @@ Consumes the row-wise serving layout directly
 weight's own shape, fp32 scale per contraction row) — no repacking.
 
 Like the flash kernel (ops/flash_attention.py), this is interpret-tested
-on the CPU, compile-tested for a described v5e (tests/test_tpu_compile.py)
-and raced at runtime: the serving engine times kernel-vs-XLA once
-post-compile and keeps the winner (PERF.md has what the chip said).
+on the CPU and compile-tested for a described v5e
+(tests/test_tpu_compile.py).  The serving engine runs it where
+``InferenceConfig.mixed_gemm="on"`` asks (default "off"; chip_smoke.py
+times it against the XLA path, PERF.md has what the chip said).
 """
 
 from __future__ import annotations
@@ -238,7 +239,7 @@ def mixed_matmul(x: jax.Array, qt, *, contract_dims: int = 1,
 
 
 def dequant_matmul_reference(x: jax.Array, qt, out_dtype=None) -> jax.Array:
-    """The XLA fallback this kernel races in the probe: bf16 fused
+    """The XLA path this kernel is compared with: bf16 fused
     dequantize (ops/quant.dequantize row-wise fast path) then matmul."""
     from .quant import dequantize
     out_dtype = out_dtype or x.dtype

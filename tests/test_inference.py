@@ -3,6 +3,8 @@ tests/unit/inference/v2/ragged/test_blocked_allocator.py,
 test_ragged_wrapper.py; engine-level scheduling tests; decode parity
 with the dense forward)."""
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -27,6 +29,26 @@ def make_engine(m, **over):
               num_kv_blocks=64)
     kw.update(over)
     return InferenceEngine(m, InferenceConfig(**kw))
+
+
+@contextlib.contextmanager
+def backend_compiles():
+    """The XLA backend compiles made inside the block, as a list that
+    fills while it runs (``jax.monitoring``'s compile-duration event)."""
+    from jax import monitoring
+    from jax._src.monitoring import unregister_event_duration_listener
+
+    compiles = []
+
+    def on_duration(name, _secs, **_kw):
+        if name.endswith("backend_compile_duration"):
+            compiles.append(name)
+
+    monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        yield compiles
+    finally:
+        unregister_event_duration_listener(on_duration)
 
 
 def make_fp32_engine(m, **over):
@@ -649,26 +671,17 @@ class TestQuantizedKV:
 
 
 class TestServingProgramRecord:
-    """Each compiled serving bucket notes once, at its first call, which
-    form its layer scan took and what the program needs beside its
-    arguments (``engine.serving_programs``, two pull gauges) — read from
-    the executable the call built, so nothing compiles a second time."""
+    """Each compiled serving bucket notes once, at its first call, what
+    the program needs beside its arguments (``engine.serving_programs``,
+    one pull gauge) — read from the executable the call built, so
+    nothing compiles a second time."""
 
-    @pytest.mark.parametrize("over", [
-        {}, {"kv_quant": "int8"}, {"kv_donate": "off"},
-        {"attn_impl": "pallas", "pipeline_depth": 1}],
+    @pytest.mark.parametrize("attn_impl,over", [
+        ("xla", {}), ("xla", {"kv_quant": "int8"}),
+        ("xla", {"kv_donate": "off"}), ("pallas", {"pipeline_depth": 1})],
         ids=["bf", "int8kv", "no-donation", "pallas-sync"])
-    def test_noted_once_without_a_second_compile(self, over):
-        from jax import monitoring
-        from jax._src.monitoring import unregister_event_duration_listener
-
-        compiles = []
-
-        def on_duration(name, _secs, **_kw):
-            if name.endswith("backend_compile_duration"):
-                compiles.append(name)
-
-        eng = make_fp32_engine(tiny_model(), **over)
+    def test_noted_once_without_a_second_compile(self, attn_impl, over):
+        eng = make_fp32_engine(tiny_model(), attn_impl=attn_impl, **over)
         assert eng.serving_programs == {}
         assert "serving_step_temp_bytes" not in eng.metrics_snapshot()
         noted = []
@@ -680,12 +693,9 @@ class TestServingProgramRecord:
             noted.append((key, len(compiles) - before))
 
         eng._note_program = counted
-        monitoring.register_event_duration_secs_listener(on_duration)
-        try:
+        with backend_compiles() as compiles:
             sp = SamplingParams(temperature=0.0, max_new_tokens=20)
             out = eng.generate({0: list(range(1, 30)), 1: [5, 6, 7]}, sp)
-        finally:
-            unregister_event_duration_listener(on_duration)
         assert len(out[0]) == 20
         # one note a program, none of which compiled anything: under an
         # XLA formulation a program a context bucket (16-token blocks:
@@ -693,15 +703,84 @@ class TestServingProgramRecord:
         # kernel's grid follows the batch, so there one program bounded
         # by the engine's longest context serves every step
         assert sorted(k for k, _ in noted) == sorted(eng._pstep_fns)
-        if eng._attn_impl() == "pallas":
+        if attn_impl == "pallas":
             assert [k[0] for k, _ in noted] == [eng.max_blocks_per_seq]
         else:
             assert len(noted) >= 2
         assert all(n == 0 for _, n in noted)
         for rec in eng.serving_programs.values():
-            assert rec["form"] == "carried"
             assert isinstance(rec["temp_bytes"], int)
         snap = eng.metrics_snapshot()
-        assert snap["serving_step_cache_carried"] == 1.0
         assert snap["serving_step_temp_bytes"] == max(
             r["temp_bytes"] for r in eng.serving_programs.values())
+
+
+class TestServingPathByRule:
+    """Which attention formulation an engine runs, and whether its
+    quantized projections go through the mixed-input kernel, is settled
+    at construction by a rule over what the process can observe: nothing
+    is compiled, run or timed for it, so two engines of one process can
+    never disagree (a start-up race between the formulations once made
+    two replicas of one model answer a bf16 near-tie differently)."""
+
+    def test_auto_is_xla_off_the_chip(self):
+        assert jax.default_backend() != "tpu"
+        assert make_engine(tiny_model()).attn_impl == "xla"
+
+    def test_auto_is_pallas_on_a_tpu_backend(self, monkeypatch):
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert make_engine(tiny_model()).attn_impl == "pallas"
+
+    @pytest.mark.parametrize("impl", ["xla", "pallas"])
+    def test_forced_value_is_kept(self, impl, monkeypatch):
+        # on either side of the rule
+        for backend in ("cpu", "tpu"):
+            monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+            eng = make_engine(tiny_model(), attn_impl=impl)
+            assert eng.attn_impl == impl
+            assert eng._resolve_fw(None)[0]["attn_impl"] == impl
+
+    @pytest.mark.parametrize("over", [
+        {"attn_impl": "flash"}, {"mixed_gemm": "auto"}],
+        ids=["attn_impl", "mixed_gemm-auto"])
+    def test_unknown_value_raises_at_construction(self, over):
+        (name,) = over
+        with pytest.raises(ValueError, match=name):
+            make_engine(tiny_model(), weight_quant="int8", **over)
+
+    def test_resolving_compiles_and_runs_nothing(self):
+        eng = make_engine(tiny_model(), weight_quant="int8",
+                          prefix_cache="on")
+        kv = eng.state.kv
+        eng.state.build_batch([(9, list(range(1, 33)))], 32)
+        index = eng.state.prefix_digests()
+        assert len(index) == 2     # the prompt's two full blocks
+        with backend_compiles() as compiles:
+            fw, mbs = eng._resolve_fw(None)
+            eng._build_pstep(None, SamplingParams(temperature=0.0))
+        assert fw["attn_impl"] == "xla" and fw["mixed_gemm"] is False
+        assert mbs == eng.max_blocks_per_seq
+        assert compiles == []
+        assert eng.metrics_snapshot()["serving_compiles_total"] == 0
+        assert eng.probe_times == {}
+        # the cache and the prefix index are the ones construction and
+        # the scheduler left
+        assert eng.state.kv is kv
+        assert eng.state.prefix_digests() == index
+
+    def test_pool_size_does_not_change_the_answer(self):
+        """tests/test_tier.py's fleet against its reference engine: two
+        default-configured bf16 engines that differ only in
+        ``num_kv_blocks`` (a replica's 16, the reference's 24) answer
+        the trace's filler prompt of uid 3 token for token."""
+        from tools.loadgen import build_engine
+
+        prompt = [int(x) for x in
+                  np.random.RandomState(602).randint(1, 120, 44)]
+        sp = SamplingParams(temperature=0.0, max_new_tokens=4)
+        small, model = build_engine(num_kv_blocks=16, prefix_cache="on")
+        large, _ = build_engine(model=model, prefix_cache="on")
+        assert small.icfg.attn_impl == large.icfg.attn_impl == "auto"
+        assert small.attn_impl == large.attn_impl
+        assert small.generate({3: prompt}, sp) == \
+            large.generate({3: prompt}, sp)

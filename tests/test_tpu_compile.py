@@ -319,11 +319,7 @@ def test_serving_step_keeps_the_pool_in_place(one_chip, on_chip, kv_quant):
     layer's share of the pool (it held the whole pool, 4.0 GiB, while
     the cache was a scanned input and output), and neither its entry
     computation nor its ``while`` body copies, slices or update-slices
-    that much.
-
-    ``kv_host`` is left out on purpose: a cache in host memory keeps the
-    scanned form, where slicing one layer out, through HBM and back is
-    the mechanism and a layer-sized copy is what it is for."""
+    that much."""
     from deepspeed_tpu.models.presets import build_config
 
     compiled, layer_bytes = _pstep_compiled(
