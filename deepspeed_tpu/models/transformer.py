@@ -287,6 +287,25 @@ def init_params(cfg: TransformerConfig, key) -> Tuple[Dict, Dict]:
 # forward
 # --------------------------------------------------------------------------
 
+@dataclasses.dataclass(frozen=True)
+class Placement:
+    """What the caller states about where things live inside the forward.
+    The model knows no mesh: it calls these at the points that matter and
+    the caller (``ZeroPolicy.placement`` under ZeRO stage 3) decides what
+    they constrain.
+
+    ``use(name, subtree, layer_slice=False)``: ``params[name]`` (one
+    layer's slice of it inside the scan) as its uses read it.
+    ``keep(x)``: an activation whose dim 0 is the batch, kept split over it.
+    """
+    use: Callable[..., Any]
+    keep: Callable[[Any], Any]
+
+
+_AS_IS = Placement(use=lambda name, sub, layer_slice=False: sub,
+                   keep=lambda x: x)
+
+
 def _norm(cfg):
     fn = L.layernorm if cfg.norm == "layernorm" else L.rmsnorm
     return partial(fn, eps=cfg.eps)
@@ -380,7 +399,8 @@ def block_apply(cfg: TransformerConfig, lp, x, cos, sin,
 def apply(cfg: TransformerConfig, params, input_ids, mask=None,
           attention_fn: Callable = L.causal_attention,
           dtype=None, rng=None, with_aux: bool = False,
-          pld_theta=None, ltd_keep: Optional[int] = None):
+          pld_theta=None, ltd_keep: Optional[int] = None,
+          placement: Optional[Placement] = None):
     """Forward pass → logits [B, S, vocab] (or (logits, aux) with
     with_aux=True; aux carries MoE load-balancing metrics averaged over
     layers).
@@ -391,14 +411,18 @@ def apply(cfg: TransformerConfig, params, input_ids, mask=None,
     ``ltd_keep``: random-LTD kept-token count (STATIC int — one compiled
     program per value): a sorted random subset of positions runs through
     the layer stack, dropped positions bypass with their embedding
-    (reference: data_routing/basic_layer.py gather/scatter)."""
+    (reference: data_routing/basic_layer.py gather/scatter).
+    ``placement``: see :class:`Placement`; None states nothing."""
+    pl = placement or _AS_IS
+    use, keep = pl.use, pl.keep
     dt = dtype or params["embed"]["table"].dtype
-    x = L.embed(params["embed"], input_ids).astype(dt)
+    x = L.embed(use("embed", params["embed"]), input_ids).astype(dt)
     if cfg.embed_norm:
-        x = _norm(cfg)(params["ln_embed"], x)
+        x = _norm(cfg)(use("ln_embed", params["ln_embed"]), x)
     if cfg.position == "learned":
         S = input_ids.shape[1]
-        x = x + params["pos_embed"]["table"][:S].astype(dt)
+        x = x + use("pos_embed",
+                    params["pos_embed"])["table"][:S].astype(dt)
         cos = sin = None
     elif cfg.position == "alibi":
         cos = sin = None
@@ -431,10 +455,14 @@ def apply(cfg: TransformerConfig, params, input_ids, mask=None,
 
     def body(h, xs):
         lp, r, li = xs
-        y, metrics = block_apply(cfg, lp, h, cos, sin, mask=mask,
+        # inside the (checkpointed) body, so the recomputation and the
+        # backward state the same as the forward
+        y, metrics = block_apply(cfg, use("blocks", lp, layer_slice=True),
+                                 keep(h), cos, sin, mask=mask,
                                  attention_fn=attention_fn,
                                  rng=r if have_rng else None,
                                  positions=positions)
+        y = keep(y)
         if pld_theta is not None:
             # whole-batch per-layer coin; deeper layers drop more
             keep_p = 1.0 - (li.astype(jnp.float32) / cfg.num_layers) \
@@ -449,20 +477,22 @@ def apply(cfg: TransformerConfig, params, input_ids, mask=None,
         body = jax.checkpoint(body, policy=policy() if policy else None)
 
     x, metrics = jax.lax.scan(
-        body, x,
+        body, keep(x),
         (params["blocks"], layer_rngs,
          jnp.arange(cfg.num_layers, dtype=jnp.int32)),
         unroll=min(cfg.scan_unroll, cfg.num_layers))
     if idx is not None:
         # dropped positions bypass the stack with their embedding
         x = random_ltd_scatter(full_x, x, idx)
-    x = _norm(cfg)(params["ln_f"], x)
+    x = _norm(cfg)(use("ln_f", params["ln_f"]), x)
     if cfg.tie_embeddings:
-        logits = x @ params["embed"]["table"].astype(dt).T
+        logits = x @ use("embed", params["embed"])["table"].astype(dt).T
     else:
-        logits = x @ params["lm_head"]["kernel"].astype(dt)
+        head = use("lm_head", params["lm_head"])
+        logits = x @ head["kernel"].astype(dt)
         if cfg.head_bias:
-            logits = logits + params["lm_head"]["bias"].astype(dt)
+            logits = logits + head["bias"].astype(dt)
+    logits = keep(logits)
     if with_aux:
         aux = {k: v.mean() for k, v in metrics.items()} if metrics else {}
         return logits, aux
@@ -483,8 +513,10 @@ def rolled_lm_targets(ids, mask=None):
     return labels, tgt_mask
 
 
-def cross_entropy_loss(logits, labels, mask=None):
-    """Next-token LM loss; logits [B,S,V], labels [B,S].
+def cross_entropy_loss(logits, labels, mask=None,
+                       keep: Callable = lambda x: x):
+    """Next-token LM loss; logits [B,S,V], labels [B,S].  ``keep``:
+    :attr:`Placement.keep` for the per-token terms.
 
     Written as ``lse - target_logit`` with fp32 *reductions* rather than
     ``log_softmax`` so XLA fuses the bf16→fp32 convert into the reduce and
@@ -492,7 +524,7 @@ def cross_entropy_loss(logits, labels, mask=None):
     batch 32·1024 — the difference between fitting in HBM or not)."""
     lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
     tgt = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
-    nll = lse - tgt.astype(jnp.float32)
+    nll = keep(lse - tgt.astype(jnp.float32))
     if mask is not None:
         mask = mask.astype(jnp.float32)
         return (nll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
@@ -501,13 +533,16 @@ def cross_entropy_loss(logits, labels, mask=None):
 
 def lm_loss_fn(cfg: TransformerConfig,
                attention_fn: Callable = L.causal_attention,
-               pld: bool = False, ltd_keep: Optional[int] = None):
+               pld: bool = False, ltd_keep: Optional[int] = None,
+               placement: Optional[Placement] = None):
     """Standard causal-LM loss over a batch {input_ids, [attention_mask]}.
 
     ``pld``: consume the engine-injected per-row ``_pld_theta`` column
     (progressive layer drop).  ``ltd_keep``: bake a static random-LTD
     kept-token count; the engine swaps programs via ``with_ltd`` as the
-    schedule anneals."""
+    schedule anneals.  ``placement``: what the engine states about
+    parameters and activations (:class:`Placement`), handed over through
+    ``with_placement``."""
 
     def loss_fn(params, batch, rng):
         ids = batch["input_ids"]
@@ -516,9 +551,10 @@ def lm_loss_fn(cfg: TransformerConfig,
         logits, aux = apply(cfg, params, ids, mask=mask,
                             attention_fn=attention_fn, rng=rng,
                             with_aux=True, pld_theta=theta,
-                            ltd_keep=ltd_keep)
+                            ltd_keep=ltd_keep, placement=placement)
         labels, tgt_mask = rolled_lm_targets(ids, mask)
-        loss = cross_entropy_loss(logits, labels, tgt_mask)
+        loss = cross_entropy_loss(logits, labels, tgt_mask,
+                                  keep=(placement or _AS_IS).keep)
         if "moe_aux_loss" in aux:
             loss = loss + cfg.aux_loss_coef * aux["moe_aux_loss"]
             return loss, aux
@@ -526,11 +562,14 @@ def lm_loss_fn(cfg: TransformerConfig,
 
     loss_fn.uses_pld = pld
     loss_fn.with_ltd = lambda keep: lm_loss_fn(
-        cfg, attention_fn, pld=pld, ltd_keep=keep)
+        cfg, attention_fn, pld=pld, ltd_keep=keep, placement=placement)
+    loss_fn.with_placement = lambda pl: lm_loss_fn(
+        cfg, attention_fn, pld=pld, ltd_keep=ltd_keep, placement=pl)
     if pld or ltd_keep is not None:
         # evaluation must run the clean forward: no theta column in eval
         # batches, no token dropping skewing eval losses
-        loss_fn.base_eval = lm_loss_fn(cfg, attention_fn)
+        loss_fn.base_eval = lm_loss_fn(cfg, attention_fn,
+                                       placement=placement)
     return loss_fn
 
 

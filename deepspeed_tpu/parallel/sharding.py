@@ -81,7 +81,9 @@ def add_fsdp_to_spec(spec: P, shape: Tuple[int, ...], topology: MeshTopology,
     """Layer ZeRO/FSDP sharding on top of a TP spec: shard the largest
     still-unsharded dim that the fsdp axis size divides (reference analog:
     flat 1-D partitioning in stage_1_and_2.py:646 / stage3 — but on TPU we
-    shard a real tensor dim so XLA can gather lazily per use)."""
+    shard a real tensor dim, so a gather is one all-gather along it).
+    The spec says where the leaf lives, not how it is used: the stage-3
+    step states the per-use gather itself (zero.py ``placement``)."""
     n = topology.axis_sizes.get(axis, 1)
     if n <= 1 or int(np.prod(shape)) < max(min_size, 1):
         return spec

@@ -9,9 +9,13 @@ configuration :1272, ZeRO wiring :1532) for the XLA compilation model:
   (the GAS boundary of engine.py:1960 becomes a scan carry), so a whole
   optimizer step is a single device dispatch.
 * ZeRO stages are sharding specs (see ``parallel/zero.py``); the grad
-  hooks / bucketing / overlap machinery of stage_1_and_2.py &
-  stage3.py is replaced by the XLA SPMD partitioner, which emits the same
-  reduce-scatter / all-gather schedule, overlapped with compute.
+  hooks / bucketing machinery of stage_1_and_2.py & stage3.py is replaced
+  by the XLA SPMD partitioner.  Through stage 2 the specs on state and
+  gradients are enough; at stage 3 the engine also hands the loss a
+  ``Placement`` (gather each parameter per use, keep activations split
+  over the batch), because specs on the parameters alone leave the
+  partitioner free to move activations instead.  When each collective
+  runs relative to the compute around it is XLA's to schedule.
 * fp16 overflow handling (CheckOverflow, dynamic loss scaler) runs inside
   the step with ``jnp.where`` — no host sync, no global state.
 
@@ -193,6 +197,18 @@ class Engine:
             and model is not None and hasattr(model, "config")
             and isinstance(params, dict) and "blocks" in params)
         self._build_shardings(params)
+        # ZeRO-3 over an fsdp axis that exists: the loss is told what stage
+        # 3 means (parallel/zero.py placement) — parameters gathered per
+        # use, activations split over the batch.  A loss that cannot take
+        # it (a user's own, the pipeline's shard_map stages) runs as it
+        # did: specs on the parameters, the rest left to the partitioner.
+        self._zero3_gather = None
+        if self.zero.gathers_per_use and hasattr(loss_fn, "with_placement"):
+            self.loss_fn = loss_fn.with_placement(
+                self.zero.placement(self.param_specs, self.use_specs))
+            self._zero3_gather = self.zero.gather_bytes(
+                self.param_specs, self.use_specs, self.param_shapes,
+                jnp.dtype(self.compute_dtype).itemsize)
         self._qgz_axes = self._qgz_manual_axes()
         self._sparse_axes = self._sparse_manual_axes(params)
         # overlapped / quantized grad-sync collectives (comm/overlap.py;
@@ -489,6 +505,22 @@ class Engine:
                 "#%d) — something invalidated the step executable",
                 key, int(self._c_retraces.value()))
         else:
+            if self._zero3_gather is not None and not self._compiled_ever:
+                # static, from the specs: no device read
+                leaves, nbytes = self._zero3_gather
+                self.metrics.gauge(
+                    "training_zero3_gather_bytes_per_step",
+                    "ZeRO-3: bytes of compute-dtype parameters one chip "
+                    "receives per step for ONE gather of every sharded "
+                    "leaf per micro-batch; forward, backward and (under "
+                    "remat) recomputation each make one").set(
+                        nbytes * self.gas)
+                log_dist(
+                    f"ZeRO-3: {leaves} parameter leaves gathered per use "
+                    f"over fsdp={self.topology.axis_sizes[FSDP_AXIS]}, "
+                    f"{nbytes * self.gas:,} bytes a chip a pass a step "
+                    f"({jnp.dtype(self.compute_dtype).name}); activations "
+                    "stay split over the batch")
             self._compiled_ever.add(key)
 
     def metrics_snapshot(self) -> Dict[str, Any]:
@@ -506,6 +538,7 @@ class Engine:
         self.param_shapes = jax.tree.map(lambda p: tuple(np.shape(p)),
                                          params)
         self.param_specs = zero.tree_param_specs(self.param_axes, params)
+        self.use_specs = zero.tree_use_specs(self.param_axes, params)
         self.master_specs = zero.tree_master_specs(self.param_axes, params)
         self.grad_specs = zero.tree_grad_specs(self.param_axes, params)
         self.param_shardings = zero.tree_named(self.param_specs)
@@ -947,8 +980,9 @@ class Engine:
         if qwz and not getattr(self, "_qwz_applied", False) \
                 and not getattr(self, "_qwz_noop_warned", False):
             # plain stage 3: compute and master layouts coincide, so the
-            # per-use gathers live inside the model's XLA program where
-            # this explicit path can't reach; combine qwZ with hpZ or
+            # only gathers are the per-use ones the loss states through
+            # its Placement (zero.py), in the compute dtype, where this
+            # explicit path does not reach; combine qwZ with hpZ or
             # offload for an actual quantized gather boundary
             self._qwz_noop_warned = True
             logger.warning(
@@ -1016,9 +1050,11 @@ class Engine:
         int8 collectives instead of XLA's implicit fp32 reduce.
 
         data always; fsdp only through stage 2 — at stage 3 the compute
-        params are fsdp-sharded and must stay under XLA auto-sharding for
-        the per-use gathers, so fsdp-axis reductions of the few replicated
-        (persistent) leaves remain full-precision."""
+        params are fsdp-sharded and the per-use gathers and gradient
+        reduce-scatters the loss states (zero.py ``placement``) are
+        sharding constraints over an AUTO fsdp axis, so fsdp-axis
+        reductions, the replicated (persistent) leaves' among them,
+        remain full-precision."""
         if not self.config.zero_optimization.zero_quantized_gradients:
             return ()
         return self._manual_reduce_axes("zero_quantized_gradients")

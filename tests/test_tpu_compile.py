@@ -337,3 +337,129 @@ def test_serving_step_keeps_the_pool_in_place(one_chip, on_chip, kv_quant):
     assert compiled.memory_analysis().temp_size_in_bytes < (
         100e6 if kv_quant else 16e6)
     _tile_grid_conditions(compiled.jaxpr, T=512, seqs=64, mbs=16)
+
+
+# ------------------------------------------------ ZeRO-3 over four chips
+def _zero3_step_compiled(topo, monkeypatch, layers, per_chip, seq):
+    """The train step ``benchmarks/lib/drivers/train.py`` builds for
+    ``train-zero3-4chip`` (pythia-1.4b widths, ``fsdp=4``, stage 3, bf16,
+    each layer recomputed), compiled for the four described chips.  There
+    is no device to hold a train state, so the engine is built over
+    shapes: its state initialiser and the one ``device_put`` of its
+    constructor are replaced here, in the test."""
+    from deepspeed_tpu.comm import MeshTopology
+    from deepspeed_tpu.config import MeshConfig
+    from deepspeed_tpu.config.config import load_config
+    from deepspeed_tpu.models.presets import build_config
+    from deepspeed_tpu.models.transformer import (_resolve_attention,
+                                                  init_params, lm_loss_fn)
+    from deepspeed_tpu.runtime.engine import Engine, TrainState
+
+    cfg = build_config("pythia-1.4b", num_layers=layers, remat=True,
+                       remat_policy="nothing")
+    mesh = MeshTopology.build(MeshConfig(fsdp=4), devices=topo.devices)
+
+    def shaped(tree, shardings):
+        return jax.tree.map(lambda x, s: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=s), tree, shardings)
+
+    def abstract_state(self, params):
+        def init(p):
+            master = jax.tree.map(lambda x: x.astype(jnp.float32), p)
+            return master, self.optimizer.init(master)
+        master, opt = jax.eval_shape(init, params)
+        self.opt_shardings = self._opt_state_shardings(opt, master)
+        scalar = lambda x: jax.ShapeDtypeStruct((), x.dtype,
+                                                sharding=self.repl)
+        return TrainState(
+            step=scalar(jnp.zeros((), jnp.int32)),
+            master=shaped(master, self.master_shardings),
+            opt_state=shaped(opt, self.opt_shardings),
+            loss_scale=jax.tree.map(scalar,
+                                    jax.eval_shape(self.scaler.init)),
+            skipped=scalar(jnp.zeros((), jnp.int32)))
+
+    axes = {}
+
+    def make(key):
+        params, a = init_params(cfg, key)
+        axes.update(a)
+        return params
+
+    params = jax.eval_shape(make, jax.random.PRNGKey(0))
+    with monkeypatch.context() as m:
+        m.setattr(Engine, "_init_state", abstract_state)
+        m.setattr(jax, "device_put", lambda tree, sh: jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh),
+            tree))
+        eng = Engine(
+            loss_fn=lm_loss_fn(cfg, _resolve_attention(cfg)), params=params,
+            param_axes=axes, topology=mesh, config=load_config({
+                "train_micro_batch_size_per_device": per_chip,
+                "optimizer": {"type": "adamw", "params": {"lr": 1e-4}},
+                "bf16": {"enabled": True},
+                "zero_optimization": {"stage": 3},
+                "gradient_clipping": 1.0, "steps_per_print": 1 << 30}))
+    batch = {"input_ids": jax.ShapeDtypeStruct(
+        (per_chip * 4, seq), jnp.int32, sharding=eng.batch_sharding)}
+    rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=eng.repl)
+    return eng, cfg, eng._pick_train_step().lower(
+        eng.state, batch, rng).compile()
+
+
+def test_zero3_step_gathers_parameters_and_fits(topo, on_chip, monkeypatch):
+    """``train-zero3-4chip``'s step at 2 of 24 layers.  What must hold
+    by kind: no all-to-all (q/k/v resharded for the partial rotary, the
+    logits resharded from vocabulary to batch); inside the layer loop no
+    collective carries the batch (every projection all-gathered the whole
+    batch's residual stream, 134 MB, before PR 29); each layer's weights
+    arrive by all-gather in bf16; the weight gradients leave by
+    reduce-scatter, which the TPU's compiler writes as a ring of
+    collective-permutes of a quarter of the weight inside the backward
+    matmul; the program's temporaries stay a third of what they were
+    (6.04 GB at these sizes while each chip ran all 16 sequences)."""
+    per_chip, seq = 4, 2048
+    eng, cfg, compiled = _zero3_step_compiled(topo, monkeypatch, 2,
+                                              per_chip, seq)
+    from tests.test_zero3_placement import collectives_of
+    # one entry a channel: XLA repeats an async collective's text
+    found = {(kind, ch): (shapes, op) for kind, shapes, op, ch
+             in collectives_of(compiled.as_text()) if ch}
+    kinds = [k for k, _ in found]
+    assert "all-to-all" not in kinds
+
+    def carries_batch(shape):
+        return len(shape) >= 3 and shape[0] in (per_chip, per_chip * 4) \
+            and shape[1] == seq
+
+    in_loop = {k: v for k, v in found.items() if "/while/body/" in v[1]}
+    moved = [(k, s, op) for k, (shapes, op) in found.items()
+             for s in shapes if carries_batch(s)]
+    # the one exception lies outside the loop: the gradient of the
+    # vocabulary-sharded embedding table gathers the batch's cotangent
+    # (134 MB) instead of reduce-scattering a table (206 MB)
+    assert all("scatter-add" in op and "/while/body/" not in op
+               for _, _, op in moved), moved
+    assert len(moved) <= 1
+    H, D, dm, ff = cfg.num_heads, cfg.head_dim, cfg.d_model, cfg.d_ff
+    weights = {(dm, H, D), (H, D, dm), (dm, ff), (ff, dm)}
+    # the forward gathers a slice of the stack, [1, ...]; the
+    # recomputation gathers the slice the scan already cut
+    gathered = {s[1:] if s[0] == 1 else s
+                for (k, _), (shapes, _) in in_loop.items()
+                if k == "all-gather" for s in shapes}
+    assert weights <= gathered, gathered
+    scattered = {s for (k, _), (shapes, op) in in_loop.items()
+                 if k in ("reduce-scatter", "collective-permute")
+                 and "transpose(jvp" in op for s in shapes if s}
+    assert scattered, "no weight gradient leaves the loop reduced"
+    quarters = {(dm // 4, H, D), (H, D, dm // 4), (dm, ff // 4),
+                (ff // 4, dm)}
+    assert scattered <= quarters | weights, scattered
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2.6e9
+    # 24 layers of state are 5.7 GB a chip: the whole cell fits 16 GB
+    state = mem.argument_size_in_bytes * 24 / 2
+    assert state + mem.temp_size_in_bytes < 15.75e9
+    leaves, nbytes = eng._zero3_gather
+    assert leaves == 8 and nbytes == 460_062_720
