@@ -154,6 +154,44 @@ def device_block(devs) -> dict:
             "count": len(devs), "memory_peak_bytes": peak}
 
 
+def last_line_faults(result: dict, traced: bool, on_chip: bool,
+                     reduced: dict = None) -> list:
+    """What the driver would refuse in a run's last line, found here so
+    that a builder meets it at their own chip run: the five keys, and in
+    a traced run on the chip ``0 < busy_s <= window_s``, both cut by the
+    one ``bench.trace.window`` span (``trace.reduce``).  A run off the chip
+    (the rehearsal) carries neither number.  Each fault says which of the
+    two is wrong, with the stamps it was cut from."""
+    faults = [f"key {k!r} is missing" for k in
+              ("correct", "attempted", "failed", "metrics", "device")
+              if k not in result]
+    device = result.get("device", {})
+    if not (traced and on_chip):
+        faults += [f"device.{k} stands in a run that is not a traced run on "
+                   "the chip" for k in ("busy_s", "window_s") if k in device]
+        return faults
+    busy, window = device.get("busy_s"), device.get("window_s")
+    red = reduced or {}
+    where = (f"window cut by {red.get('cut_by')} at {red.get('window')}, first "
+             f"operation starts at {red.get('first_op_s')}, last one ends at "
+             f"{red.get('last_op_s')} (seconds on the trace's clock), "
+             f"{red.get('op_events')} operations inside")
+
+    def positive(x):          # a NaN is not above 0 either
+        return isinstance(x, (int, float)) and x > 0
+    if not positive(window):
+        faults.append(f"device.window_s is {window!r}, not a number above 0: "
+                      + (where if reduced else "the trace gave nothing"))
+    elif not positive(busy):
+        faults.append(f"device.busy_s is {busy!r}, not a number above 0: no "
+                      f"operation ran on the device inside the window; {where}")
+    elif busy > window:
+        faults.append(f"device.busy_s {busy!r} is over device.window_s "
+                      f"{window!r}: operations were not clipped to the "
+                      f"window; {where}")
+    return faults
+
+
 def quantile(values, q: float) -> float:
     """Linear-interpolated quantile of a non-empty list."""
     v = sorted(values)
@@ -193,9 +231,26 @@ class Setup:
 def start_trace(trace_dir: str):
     """Start the profiler with the Python tracer off: it stamps every
     Python call, which slows a host-bound loop by a large factor and
-    would be read as device idle.  ``TraceAnnotation`` spans stay."""
+    would be read as device idle.  ``TraceAnnotation`` spans stay.
+
+    Returns the traced window's own span (``bench.trace.window``), opened
+    on the line after the profiler has started: its two stamps lie on the
+    clock of the device's ``XLA Ops`` lines, and ``trace.reduce`` clips
+    every operation to them.  Hand it to ``stop_trace``."""
     import jax
+    from benchmarks.lib.trace import WINDOW_SPAN
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     opts.host_tracer_level = 2
     jax.profiler.start_trace(trace_dir, profiler_options=opts)
+    span = jax.profiler.TraceAnnotation(WINDOW_SPAN)
+    span.__enter__()
+    return span
+
+
+def stop_trace(span):
+    """Close the window's span, then stop the profiler: what the device
+    tracer records while ``stop_trace`` stalls is outside the window."""
+    import jax
+    span.__exit__(None, None, None)
+    jax.profiler.stop_trace()

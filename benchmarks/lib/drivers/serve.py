@@ -14,7 +14,8 @@ import numpy as np
 
 from benchmarks.lib import traffic as T
 from benchmarks.lib.common import (ROOT, CompileWatch, device_block,
-                                   load_module, note, start_trace)
+                                   load_module, note, start_trace,
+                                   stop_trace)
 
 TIMING_KEYS = ("schedule_ms", "stage_ms", "device_ms", "wait_ms",
                "readback_ms", "steps", "prompt_tokens", "cached_tokens",
@@ -280,11 +281,14 @@ def run(ctx):
          compile=watch.snapshot(), cache=ctx["cache_size"]())
     trace_window = (None, None)
     if args.trace:
-        start_trace(ctx["trace_dir"])
+        # the window's span lies on the trace's clock and cuts busy_s and
+        # window_s; the host's two stamps, inside it, only choose the whole
+        # steps of serve_step_roofline
+        span = start_trace(ctx["trace_dir"])
         t_tr0 = time.monotonic()
         closed.wait(timeout=float(mix.get("trace_s", 5.0)))
         t_tr1 = time.monotonic()
-        jax.profiler.stop_trace()
+        stop_trace(span)
         trace_window = (t_tr0, t_tr1)
     closed.wait()
     child.wait()

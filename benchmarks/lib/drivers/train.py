@@ -12,7 +12,8 @@ import numpy as np
 
 from benchmarks.lib import traffic as T
 from benchmarks.lib.common import (ROOT, CompileWatch, device_block,
-                                   load_module, note, start_trace)
+                                   load_module, note, start_trace,
+                                   stop_trace)
 
 
 class InputPipeline:
@@ -163,7 +164,6 @@ def run(ctx):
         return float(c), watch.compiles
 
     # ---- the measured window -------------------------------------------
-    tracing = bool(args.trace)
     trace_dir = ctx["trace_dir"] if args.trace else None
     trace_steps = int(job.get("trace_steps", 3))
     before = compiles()
@@ -171,10 +171,7 @@ def run(ctx):
     note("setup", seconds=setup_s, parts=setup.parts,
          compile=watch.snapshot(), cache=ctx["cache_size"]())
     steps, losses, pending = [], [], None
-    t_trace0 = t_trace1 = None
-    if tracing:
-        start_trace(trace_dir)
-        t_trace0 = time.perf_counter()
+    span = start_trace(trace_dir) if args.trace else None
     t_open = time.perf_counter()
     t_close = t_open + args.seconds
     i = 0
@@ -194,19 +191,19 @@ def run(ctx):
             steps.append((pending[0], time.perf_counter()))
         pending = (now, m)
         i += 1
-        if tracing and t_trace1 is None and len(steps) >= trace_steps:
+        if span is not None and len(steps) >= trace_steps:
             jax.block_until_ready(m["loss"])
             steps.append((now, time.perf_counter()))
             losses.append(float(m["loss"]))
             pending = None
-            t_trace1 = time.perf_counter()
-            jax.profiler.stop_trace()
+            # the device is drained: the span closes on its whole work
+            stop_trace(span)
+            span = None
     if pending is not None:
         losses.append(float(pending[1]["loss"]))
         steps.append((pending[0], time.perf_counter()))
-    if tracing and t_trace1 is None:
-        t_trace1 = time.perf_counter()
-        jax.profiler.stop_trace()
+    if span is not None:
+        stop_trace(span)
     pipe.stop()
     after = compiles()
 
@@ -227,7 +224,7 @@ def run(ctx):
         "seq_len": seq, "chips": len(devs),
         "window_compiles": (after[0] - before[0]) + (after[1] - before[1]),
         "hlo_collectives": collectives,
-        "trace_dir": trace_dir, "trace_window": (t_trace0, t_trace1),
+        "trace_dir": trace_dir,
         "compared": compared,
         "attempted": len(steps),
         "failed": sum(1 for x in losses if not np.isfinite(x)),

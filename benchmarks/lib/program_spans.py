@@ -4,12 +4,15 @@ as ``TraceMe`` events onto the ``/host:`` planes, on the clock of the
 device's ``XLA Ops`` lines) and the ``jax.named_scope`` names in its
 operations' JAX paths.
 
-Two reductions, both of device 0 of the traced window:
+Two reductions, both of device 0 of the traced window (the drivers'
+``bench.trace.window`` span, to which every operation is clipped as in
+``trace.reduce``; first operation to last in a file without one):
 
 * **idle time by what the host was doing.**  Every idle interval of the
-  device (the gaps of ``trace.py``'s busy union, first operation to
-  last) is cut at span edges, and each piece goes to the innermost
-  ``ds.*`` span open at that time: spans of the engine's thread first,
+  device inside the window (the gaps of ``trace.py``'s busy union, the
+  stretches from the window's edges to the first and from the last
+  operation included) is cut at span edges, and each piece goes to the
+  innermost ``ds.*`` span open at that time: the engine's thread first,
   then the event loop's ``ds.gateway.route``.  A piece under no span is
   ``handoff`` when it lies between one ``ds.gateway.pump``'s end and the
   next one's start (the thread hops and the loop's other work), else
@@ -76,13 +79,17 @@ def _innermost(spans, t):
     return best and best[1]
 
 
-def book_idle(threads: dict, ops: list) -> dict:
-    """Device 0's idle seconds by span, cut at span edges.
+def book_idle(threads: dict, ops: list, window=None) -> dict:
+    """Device 0's idle seconds inside ``window`` (``(lo, hi)`` on the
+    trace's clock; first operation to last without one) by span, cut at
+    span edges.
 
     ``{"idle_s", "window": (lo, hi), "by_span": {name | "handoff" |
     "unattributed": s}}``"""
+    if window:
+        ops = trace.clip(ops, window)
     merged = trace._union([(s, e) for s, e, _ in ops])
-    lo, hi = merged[0][0], merged[-1][1]
+    lo, hi = window or (merged[0][0], merged[-1][1])
     engine = [sp for evs in threads.values()
               if any(nm != ROUTE for _, _, nm, _ in evs) for sp in evs]
     loop = [sp for evs in threads.values()
@@ -103,7 +110,8 @@ def book_idle(threads: dict, ops: list) -> dict:
                               for x in (s, e) if lo < x < hi})
     labels = [label((a + b) / 2) for a, b in zip(cuts, cuts[1:])]
     by_span, idle = {}, 0.0
-    for (_, e0), (s1, _) in zip(merged, merged[1:]):
+    edges = [(lo, lo)] + merged + [(hi, hi)]
+    for (_, e0), (s1, _) in zip(edges, edges[1:]):
         if s1 <= e0:
             continue
         idle += s1 - e0
@@ -158,8 +166,9 @@ class Split:
     """The two reductions of one traced serving run, and what the
     per-layer readers take from them."""
 
-    def __init__(self, threads: dict, ops: list, op_names: dict):
-        self.idle = book_idle(threads, ops)
+    def __init__(self, threads: dict, ops: list, op_names: dict,
+                 window=None):
+        self.idle = book_idle(threads, ops, window)
         lo, hi = self.idle["window"]
         spans = [sp for evs in threads.values() for sp in evs]
         inside = [sp for sp in spans if lo <= sp[0] < hi]
@@ -173,7 +182,8 @@ class Split:
         for s, e, nm, _ in inside:
             n, tot = self.spans.get(nm, (0, 0.0))
             self.spans[nm] = (n + 1, tot + e - s)
-        self.scopes = book_scopes(ops, op_names)
+        self.scopes = book_scopes(
+            trace.clip(ops, window) if window else ops, op_names)
 
     def idle_s(self, *names) -> float:
         return sum(self.idle["by_span"].get(n, 0.0) for n in names)
@@ -221,7 +231,8 @@ class Split:
 def of(rec):
     """The ``Split`` of a traced serving run, computed once (and its two
     lines printed once); None where there is no trace, no device
-    operation or no ``ds.*`` event."""
+    operation or no ``ds.*`` event.  The window is the one ``trace.reduce``
+    cut (``rec["trace"]``, which run.py fills before any reader runs)."""
     if "_program_spans" not in rec:
         split = None
         path = trace.find_xplane(rec["trace_dir"]) \
@@ -229,7 +240,8 @@ def of(rec):
         if path:
             threads, ops, op_names = read(path)
             if threads and ops:
-                split = Split(threads, ops, op_names)
+                split = Split(threads, ops, op_names,
+                              (rec.get("trace") or {}).get("window"))
                 split.notes()
         rec["_program_spans"] = split
     return rec["_program_spans"]

@@ -14,6 +14,14 @@ one event per executed HLO operation, named by the instruction's text
 count towards the busy union and not towards any group's sum.
 Host spans come from ``jax.profiler.TraceAnnotation`` in the benchmark's
 own files; their names start with ``bench.``.
+
+The traced window is cut on the trace's own clock: the drivers hold one
+host span, ``bench.trace.window``, open from the line after
+``start_trace`` returns to the line before ``stop_trace`` is called
+(``common.start_trace`` / ``stop_trace``), and every operation is clipped
+to it before anything is summed.  So ``0 < busy_s <= window_s`` holds by
+construction, also for a device that never idles, whatever the device
+tracer recorded while the profiler itself was starting or stopping.
 """
 
 import glob
@@ -24,6 +32,7 @@ COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
                "collective-permute")
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
+WINDOW_SPAN = "bench.trace.window"
 
 
 def hlo_collectives(compiled_text: str) -> dict:
@@ -308,13 +317,32 @@ def read(path: str) -> dict:
     return {"devices": devices, "host": host, "op_names": hlo_op_names(path)}
 
 
-def reduce(trace: dict, window_s: float = None, top: int = 10,
+def window_of(trace: dict):
+    """(start_s, end_s) of the drivers' ``bench.trace.window`` span on the
+    trace's clock, or None for a file that holds none (a recorded fixture,
+    a capture older than the span)."""
+    spans = [(s, e) for s, e, nm in trace.get("host", ()) if nm == WINDOW_SPAN]
+    return max(spans, key=lambda w: w[1] - w[0]) if spans else None
+
+
+def clip(ops: list, window) -> list:
+    """The operations that touch ``window``, each cut at its edges."""
+    lo, hi = window
+    return [(max(s, lo), min(e, hi), nm) for s, e, nm in ops
+            if e > lo and s < hi]
+
+
+def reduce(trace: dict, window=None, top: int = 10,
            aliases: dict = None) -> dict:
     """All the numbers the per-layer readers take from a trace.
 
+    window          (start_s, end_s) on the trace's clock: given, else the
+                    ``bench.trace.window`` span, else first op to last op.
+                    Everything below counts only what lies inside it; an
+                    operation across an edge is cut there
     busy_s          union of op intervals, averaged over the devices
-    window_s        the traced window (given, else first op to last op)
-    idle_share      1 - busy / window
+    window_s        the window's length
+    idle_share      1 - busy / window (never clamped: below 0 is a fault)
     groups_s        seconds per op group, averaged over the devices
     exposed_collective_s   on device 0: time inside collective ops during
                     which no other op runs there
@@ -325,6 +353,12 @@ def reduce(trace: dict, window_s: float = None, top: int = 10,
     devs = trace["devices"]
     if not devs or not any(devs.values()):
         return None
+    window = window or window_of(trace)
+    cut_by = "span" if window else "ops"
+    first_op = min(op[0] for ops in devs.values() for op in ops)
+    last_op = max(op[1] for ops in devs.values() for op in ops)
+    lo, hi = window or (first_op, last_op)
+    devs = {d: clip(ops, (lo, hi)) for d, ops in devs.items()}
     names = trace.get("op_names") or {}
     memo = {}
 
@@ -335,8 +369,6 @@ def reduce(trace: dict, window_s: float = None, top: int = 10,
     n = len(devs)
     busy = 0.0
     groups = {}
-    lo = min(op[0] for ops in devs.values() for op in ops)
-    hi = max(op[1] for ops in devs.values() for op in ops)
     for ops in devs.values():
         busy += _length(_union([(s, e) for s, e, _ in ops]))
         for s, e, name in ops:
@@ -345,7 +377,6 @@ def reduce(trace: dict, window_s: float = None, top: int = 10,
                 groups[g] = groups.get(g, 0.0) + (e - s)
     busy /= n
     groups = {g: v / n for g, v in groups.items()}
-    window = window_s if window_s else hi - lo
     first = devs[min(devs)]
     coll = _union([(s, e) for s, e, nm in first
                    if group_of(nm) == "collectives"])
@@ -353,7 +384,8 @@ def reduce(trace: dict, window_s: float = None, top: int = 10,
                    if group_of(nm) not in ("collectives", "container")])
     merged = _union([(s, e) for s, e, _ in first])
     gaps = {}
-    spans = sorted(trace["host"], key=lambda h: h[1] - h[0])
+    spans = sorted((h for h in trace["host"] if h[2] != WINDOW_SPAN),
+                   key=lambda h: h[1] - h[0])
     edges = [(lo, lo)] + merged + [(hi, hi)]
     for (_, e0), (s1, _) in zip(edges, edges[1:]):
         gap = s1 - e0
@@ -364,8 +396,9 @@ def reduce(trace: dict, window_s: float = None, top: int = 10,
                     "bench.unattributed")
         gaps[name] = gaps.get(name, 0.0) + gap
     rank = lambda d: sorted(d.items(), key=lambda kv: -kv[1])[:top]
-    return {"busy_s": busy, "window_s": window,
-            "idle_share": max(0.0, 1.0 - busy / window) if window else None,
+    return {"busy_s": busy, "window_s": hi - lo, "window": (lo, hi),
+            "cut_by": cut_by, "first_op_s": first_op, "last_op_s": last_op,
+            "idle_share": 1.0 - busy / (hi - lo) if hi > lo else None,
             "groups_s": groups,
             "exposed_collective_s": _subtract(coll, rest),
             "collective_s": _length(coll),
@@ -374,8 +407,8 @@ def reduce(trace: dict, window_s: float = None, top: int = 10,
             "devices": n, "op_events": sum(len(o) for o in devs.values())}
 
 
-def reduce_dir(trace_dir: str, window_s: float = None, aliases: dict = None):
+def reduce_dir(trace_dir: str, aliases: dict = None):
     path = find_xplane(trace_dir) if trace_dir else None
     if not path:
         return None
-    return reduce(read(path), window_s, aliases=aliases)
+    return reduce(read(path), aliases=aliases)

@@ -81,14 +81,13 @@ def main() -> int:
                      else ("end_to_end", "end_to_end"))
     if args.trace:
         from benchmarks.lib import trace as tracelib
-        t0, t1 = rec.get("trace_window") or (None, None)
         rec["trace"] = tracelib.reduce_dir(
-            rec.get("trace_dir"), (t1 - t0) if t0 and t1 else None,
-            aliases=config.get("trace_groups"))
+            rec.get("trace_dir"), aliases=config.get("trace_groups"))
         common.note("trace", found=rec["trace"] is not None,
                     **({k: rec["trace"][k] for k in
                         ("busy_s", "window_s", "idle_share", "devices",
-                         "op_events", "exposed_collective_s")}
+                         "op_events", "exposed_collective_s", "cut_by",
+                         "window", "first_op_s", "last_op_s")}
                        if rec["trace"] else {}))
     if not args.rehearse:
         from benchmarks.lib.peaks import peaks_for
@@ -102,10 +101,7 @@ def main() -> int:
             metrics[m["name"]] = {"value": float(value), "unit": m["unit"]}
     device = rec["device"]
     result = {"correct": bool(rec["correct"]), "attempted": rec["attempted"],
-              "failed": rec["failed"], "metrics": metrics, "device": device,
-              "compared_with_reference": {
-                  "file": config["reference"]["file"],
-                  "checks": rec.get("compared", {})}}
+              "failed": rec["failed"], "metrics": metrics, "device": device}
     if args.trace and rec.get("trace"):
         device["busy_s"] = rec["trace"]["busy_s"]
         device["window_s"] = rec["trace"]["window_s"]
@@ -116,6 +112,24 @@ def main() -> int:
         result["metrics"] = {}
         result["rehearsal"] = True
         common.note("rehearsal_values", values=metrics)
+    # each number compared beside its limit: last in the line, and as the
+    # last lines on standard error
+    checks = rec.get("compared", {})
+    result["compared_with_reference"] = {
+        "file": config["reference"]["file"], "checks": checks}
+    on_chip = not args.rehearse and device["platform"] == "tpu"
+    faults = common.last_line_faults(result, traced=bool(args.trace),
+                                     on_chip=on_chip, reduced=rec.get("trace"))
+    if faults:
+        for f in faults:
+            sys.stderr.write("benchmark: refusing its own last line: "
+                             + f + "\n")
+        return 1
+    for name, c in checks.items():
+        sys.stderr.write(f"compared {name}: rel={c['rel']!r} limit={c['tol']!r} "
+                         f"ok={c['ok']} (system={c['system']!r} "
+                         f"reference={c['reference']!r})\n")
+    sys.stderr.flush()
     print(json.dumps(result))
     sys.stdout.flush()
     return 0 if args.rehearse or device["platform"] == "tpu" else 1

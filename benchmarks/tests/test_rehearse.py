@@ -32,8 +32,24 @@ def test_rehearsal_is_one_well_formed_line_without_metrics(cell, trace):
     assert "busy_s" not in last["device"] and "breakdown" not in last
     assert last["correct"] is True and last["failed"] == 0
     assert last["attempted"] > 0
-    assert all(c["ok"] for c in
-               last["compared_with_reference"]["checks"].values())
+    checks = last["compared_with_reference"]["checks"]
+    assert all(c["ok"] for c in checks.values())
+    assert_cpu_form_of_the_self_check(p, last, trace)
+
+
+def assert_cpu_form_of_the_self_check(p, last, trace):
+    """run.py checked this line before it printed it: off the chip it
+    carries no busy_s; the numbers compared come last in it and are the
+    last lines on standard error."""
+    from benchmarks.lib.common import last_line_faults
+    assert last_line_faults(last, traced=bool(trace), on_chip=False) == []
+    assert list(last)[-1] == "compared_with_reference"
+    checks = last["compared_with_reference"]["checks"]
+    tail = p.stderr.strip().splitlines()[-len(checks):]
+    assert [ln.split(":")[0] for ln in tail] \
+        == ["compared " + name for name in checks]
+    assert all(f"limit={c['tol']!r}" in ln
+               for ln, c in zip(tail, checks.values()))
 
 
 def test_no_cpu_fallback_for_a_measurement():

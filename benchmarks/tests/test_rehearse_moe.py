@@ -34,6 +34,11 @@ def test_moe_cell_rehearses(trace):
     checks = last["compared_with_reference"]["checks"]
     assert set(checks) == {"logits_prefill", "logits_decode"}
     assert all(c["ok"] for c in checks.values())
+    # the line passed run.py's check of itself, in its CPU form (no busy_s)
+    from benchmarks.lib.common import last_line_faults
+    assert last_line_faults(last, traced=bool(trace), on_chip=False) == []
+    assert "busy_s" not in last["device"]
+    assert p.stderr.strip().splitlines()[-1].startswith("compared logits_decode:")
     # the reference says how close the eighth and ninth experts stood
     routers = [n for n in lines if n.get("note") == "reference_router"]
     assert len(routers) == 2 and all(n["layers"] == 2 for n in routers)

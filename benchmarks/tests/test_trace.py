@@ -25,9 +25,116 @@ def test_busy_union_and_idle_share(red):
 
 
 def test_a_given_window_takes_the_place_of_first_to_last_op():
-    r = trace.reduce(trace.read(os.path.join(HERE, "data", "small.xplane.pb")),
-                     window_s=400 * US)
+    """The window is two stamps on the trace's clock, never a length from
+    another clock: what lies outside them counts nowhere."""
+    tr = trace.read(os.path.join(HERE, "data", "small.xplane.pb"))
+    # the file's times lie 5 us after the fixture's round ones: [5,325]
+    r = trace.reduce(tr, window=(-35 * US, 365 * US))
+    assert r["window_s"] == pytest.approx(400 * US)
     assert r["idle_share"] == pytest.approx(1 - 280 / 400)
+    # [110,290]: device 0 keeps [110,165] + [205,265], device 1 all of it
+    r = trace.reduce(tr, window=(110 * US, 290 * US))
+    assert r["busy_s"] == pytest.approx((115 + 180) / 2 * US)
+    assert r["window_s"] == pytest.approx(180 * US)
+    assert r["op_events"] == 4
+    # of the all-gather [105,155] 45 us are left, 10 of them under fusion.2
+    assert r["collective_s"] == pytest.approx(45 * US)
+    assert r["exposed_collective_s"] == pytest.approx(35 * US)
+    g = r["groups_s"]
+    assert g["matmul_fusions"] == pytest.approx(180 / 2 * US)
+    assert g["other_fusions"] == pytest.approx(20 / 2 * US)
+    assert "copy" not in g
+    # idle 165..205 and 265..290: the second is cut at the window's end
+    gaps = dict(r["idle_gaps"])
+    assert gaps["bench.engine.schedule"] == pytest.approx(40 * US)
+    assert gaps["bench.engine.step"] == pytest.approx(25 * US)
+
+
+FUSION = "%fusion.{} = f32[8]{{0}} fusion(%a), kind=kOutput, calls=%f"
+COPY = "%copy.{} = f32[8]{{0}} copy(%a)"
+
+
+def synthetic(ops, span=(10.0, 20.0), host=()):
+    spans = [(span[0], span[1], trace.WINDOW_SPAN)] if span else []
+    return {"devices": {0: ops}, "host": spans + list(host)}
+
+
+def test_operations_outside_the_window_span_count_nowhere():
+    r = trace.reduce(synthetic([(2.0, 9.0, FUSION.format(1)),
+                                (12.0, 15.0, COPY.format(2)),
+                                (20.0, 31.0, FUSION.format(3))]))
+    assert r["cut_by"] == "span" and r["window"] == (10.0, 20.0)
+    assert r["busy_s"] == 3.0 and r["window_s"] == 10.0
+    assert r["idle_share"] == pytest.approx(0.7)
+    assert r["groups_s"] == {"copy": 3.0} and r["op_events"] == 1
+    assert r["device_ops"] == [["copy", 3.0]]
+    # the file's own extent is kept for the self-check's message only
+    assert (r["first_op_s"], r["last_op_s"]) == (2.0, 31.0)
+    # idle from the window's edges to the first and from the last operation
+    assert dict(r["idle_gaps"]) == {"bench.unattributed": 7.0}
+
+
+def test_an_operation_across_an_edge_is_cut_there():
+    coll = "%all-gather.4 = f32[8]{0} all-gather(f32[2] %x)"
+    r = trace.reduce(synthetic(
+        [(8.0, 12.0, FUSION.format(1)), (14.0, 16.0, COPY.format(2)),
+         (18.0, 25.0, coll), (19.0, 26.0, FUSION.format(3))],
+        host=[(0.0, 13.5, "bench.engine.step"),
+              (15.0, 30.0, "bench.gateway.between_steps")]))
+    assert r["busy_s"] == 2.0 + 2.0 + 2.0 and r["window_s"] == 10.0
+    assert r["groups_s"] == {"matmul_fusions": 2.0 + 1.0, "copy": 2.0,
+                             "collectives": 2.0}
+    assert r["collective_s"] == 2.0 and r["exposed_collective_s"] == 1.0
+    gaps = dict(r["idle_gaps"])
+    assert gaps == {"bench.engine.step": 2.0,
+                    "bench.gateway.between_steps": 2.0}
+    assert trace.WINDOW_SPAN not in gaps
+
+
+@pytest.mark.parametrize("ops", [
+    [(3.0, 27.0, FUSION.format(1))],
+    # back to back, as a loop that launches one step ahead leaves them
+    [(9.99, 12.5, FUSION.format(1)), (12.5, 17.0, COPY.format(2)),
+     (17.0, 20.0001, FUSION.format(3))],
+    [(0.0, 40.0, "%while.1 = (s32[]) while(%t), body=%b"),
+     (1.0, 39.0, FUSION.format(1))],
+])
+def test_a_device_busy_past_both_edges_reads_the_window_exactly(ops):
+    r = trace.reduce(synthetic(ops))
+    assert r["busy_s"] == r["window_s"] == 10.0
+    assert r["idle_share"] == 0.0
+    assert r["idle_gaps"] == []
+
+
+def test_a_trace_without_the_span_reads_first_operation_to_last():
+    r = trace.reduce(synthetic([(2.0, 9.0, FUSION.format(1)),
+                                (12.0, 15.0, COPY.format(2))], span=None))
+    assert r["cut_by"] == "ops" and r["window"] == (2.0, 15.0)
+    assert r["busy_s"] == 10.0 and r["window_s"] == 13.0
+    assert trace.window_of({"host": [(0.0, 1.0, "bench.engine.step")]}) is None
+
+
+def test_idle_share_is_not_clamped():
+    """With every operation clipped it cannot be negative; a fault that
+    made it so (here: busy counted on a window handed in too short for the
+    operations, which clip() is bypassed to show) must be seen, not
+    hidden at 0."""
+    tr = synthetic([(10.0, 20.0, FUSION.format(1))])
+    real_clip = trace.clip
+    trace.clip = lambda ops, window: ops
+    try:
+        r = trace.reduce(tr, window=(12.0, 16.0))
+    finally:
+        trace.clip = real_clip
+    assert r["idle_share"] == pytest.approx(1 - 10.0 / 4.0)
+
+
+def test_every_plane_is_clipped_to_the_one_window():
+    tr = synthetic([(5.0, 14.0, FUSION.format(1))])
+    tr["devices"][1] = [(16.0, 50.0, FUSION.format(2))]
+    tr["devices"][2] = [(0.0, 5.0, FUSION.format(3))]      # nothing inside
+    r = trace.reduce(tr)
+    assert r["devices"] == 3 and r["busy_s"] == pytest.approx((4 + 4 + 0) / 3)
 
 
 def test_per_name_sums_are_averaged_over_the_chips(red):
