@@ -31,6 +31,14 @@ Backpressure is a translation, not new policy: a non-admitted
 stream — the driver stops feeding that uid's continuation token back
 to the engine until the client drains its bounded queue, so one slow
 client costs itself, never the batch.
+
+Who continues a stream: over a single engine the ENGINE does
+(``put(max_new_tokens=...)`` at admission), so its ``step()`` launches
+the next step before it reads the last one back and the driver echoes
+no token; the driver only ends a stream (``flush``/``cancel``) and
+pauses a slow reader's (``hold``, then a ``put`` of the held token to
+resume).  Over a fleet the driver feeds every continuation, as the
+router's migration records expect (docs/SERVING.md "The served loop").
 """
 
 from __future__ import annotations
@@ -134,6 +142,7 @@ class _Stream:
     finished: bool = False
     finish_reason: Optional[str] = None
     disconnected: bool = False
+    owned: bool = False              # the engine continues it (no echo)
 
 
 def _query_params(query: str) -> Dict[str, Optional[str]]:
@@ -225,6 +234,7 @@ class Gateway:
         self._dead = False
         self._stop_driver = False
         self._shutting = False
+        self._launched = False       # the last pump left work in flight
         self._server: Optional[asyncio.AbstractServer] = None
         self._driver_task: Optional[asyncio.Task] = None
         self.port: Optional[int] = None
@@ -455,10 +465,14 @@ class Gateway:
             self._g_open.set(len(self._streams))
             if self.cfg.check_invariants:
                 self._assert_backend_invariants()
+            # happens-before: written here on the engine thread, read by
+            # the driver only after it has awaited this very pump
+            self._launched = bool(  # tpulint: disable=shared-state-race
+                getattr(self.backend, "in_flight", False))
             sp.set_metadata(n_out=len(outs))
         return outs, reaped, time.perf_counter()
 
-    def _apply_then_pump(self, feedbacks: List[Tuple[int, int]],
+    def _apply_then_pump(self, feedbacks: List[Tuple[int, Optional[int]]],
                          flushes: List[int],
                          t_submit: Optional[float] = None
                          ) -> Tuple[Dict[int, int], Dict[int, str], float]:
@@ -484,9 +498,12 @@ class Gateway:
                     or uid in eng._meta, \
                     f"gateway: leaked open record for uid {uid}"
 
-    def _apply(self, feedbacks: List[Tuple[int, int]],
+    def _apply(self, feedbacks: List[Tuple[int, Optional[int]]],
                flushes: List[int],
                t_submit: Optional[float] = None) -> None:
+        """``feedbacks``: per uid the token to put (a fleet's every
+        continuation; the held token that resumes a paused stream), or
+        None to pause a stream the engine continues (``hold``)."""
         with self.tracer.span("ds.gateway.apply", track="gateway",
                               queued_us=self._since_us(t_submit),
                               n_put=len(feedbacks), n_flush=len(flushes)):
@@ -504,7 +521,10 @@ class Gateway:
                     # so this check can never skip a continuation the
                     # cancel wouldn't have killed anyway.
                     continue
-                self.backend.put(uid, [tok])
+                if tok is None:
+                    self.backend.hold(uid)
+                else:
+                    self.backend.put(uid, [tok])
             for uid in flushes:
                 self.backend.flush(uid)
 
@@ -515,12 +535,15 @@ class Gateway:
         # what the last routed step left for the engine: it rides with
         # the next pump (``_apply_then_pump``), or is applied by itself
         # where no pump follows at once
-        fb: List[Tuple[int, int]] = []
+        fb: List[Tuple[int, Optional[int]]] = []
         fl: List[int] = []
         try:
             while not self._stop_driver:
-                if not any(not s.finished
-                           for s in self._streams.values()):
+                # a launch the engine left in flight is read back even
+                # when its streams have all ended since (its rows are
+                # thrown away there, and nothing stays uncollected)
+                if not self._launched and not any(
+                        not s.finished for s in self._streams.values()):
                     if fb or fl:
                         await self._submit(self._apply, fb, fl)
                         fb, fl = [], []
@@ -550,8 +573,10 @@ class Gateway:
                     self._route_tokens(outs, reaped, fb, fl)
                     self._resume_stalled(fb, fl)
                     sp.set_metadata(n_closed=len(fl) + len(reaped))
-                if not outs:
-                    # idle/backoff round: don't hot-spin the engine
+                if not outs and not self._launched:
+                    # idle/backoff round: don't hot-spin the engine (a
+                    # pump that launched work and had no token to hand
+                    # over yet is not one: the next pump reads it back)
                     if fb or fl:
                         await self._submit(self._apply, fb, fl)
                         fb, fl = [], []
@@ -567,21 +592,23 @@ class Gateway:
                              "streams and going dead")
             self._mark_dead()
 
-    def _resume_stalled(self, fb: List[Tuple[int, int]],
+    def _resume_stalled(self, fb: List[Tuple[int, Optional[int]]],
                         fl: List[int]) -> None:
         """Backpressure release: a stalled stream whose client drained
         below the queue bound gets its held token delivered and its
-        continuation fed back to the engine."""
+        continuation fed back to the engine (the put that resumes a
+        stream the engine continues, too)."""
         for s in self._streams.values():
             if s.stalled is None or s.finished:
                 continue
             if s.queue.qsize() < self.cfg.stream_queue:
                 tok, s.stalled = s.stalled, None
-                self._deliver(s, tok, fb, fl)
+                self._deliver(s, tok, fb, fl, feed=True)
 
     def _route_tokens(self, outs: Dict[int, int],
                       reaped: Dict[int, str],
-                      fb: List[Tuple[int, int]], fl: List[int]) -> None:
+                      fb: List[Tuple[int, Optional[int]]],
+                      fl: List[int]) -> None:
         for uid, tok in outs.items():
             s = self._streams.get(uid)
             if s is None or s.finished:
@@ -589,8 +616,11 @@ class Gateway:
             if s.queue.qsize() >= self.cfg.stream_queue:
                 # slow reader: hold the token, DON'T feed the engine —
                 # this stream stops consuming step budget until the
-                # client catches up
+                # client catches up (an engine that continues it is
+                # told to pause: the row it launched ahead is dropped)
                 s.stalled = int(tok)
+                if s.owned:
+                    fb.append((uid, None))
                 continue
             self._deliver(s, int(tok), fb, fl)
         for uid, status in reaped.items():
@@ -609,7 +639,11 @@ class Gateway:
                 self._journey(uid, "closed", reason=reason)
 
     def _deliver(self, s: _Stream, tok: int,
-                 fb: List[Tuple[int, int]], fl: List[int]) -> None:
+                 fb: List[Tuple[int, Optional[int]]], fl: List[int],
+                 feed: bool = False) -> None:
+        """Count, stream and close; the continuation is echoed to the
+        backend only for a stream the driver feeds (``feed``: a paused
+        one's resume is such a put, whoever continues it otherwise)."""
         s.emitted += 1
         if s.emitted == 1:
             self._journey(s.uid, "first_token")
@@ -624,7 +658,7 @@ class Gateway:
         if finish is not None:
             self._close_stream(s, finish)
             fl.append(s.uid)
-        else:
+        elif feed or not s.owned:
             fb.append((s.uid, tok))
 
     def _close_stream(self, s: _Stream, reason: str) -> None:  # tpulint: close-out
@@ -994,7 +1028,8 @@ class Gateway:
         # second put would silently append onto the first's prompt)
         s = _Stream(uid=uid, rid=f"cmpl-{uid}",
                     max_tokens=req.max_tokens,
-                    want_stream=req.stream, queue=asyncio.Queue())
+                    want_stream=req.stream, queue=asyncio.Queue(),
+                    owned=not self._is_fleet)
         # happens-before: the event loop is _streams' ONLY writer (this
         # insert + unreserve's del); the engine thread only performs
         # GIL-atomic point lookups (.get/membership/len) and never
@@ -1028,10 +1063,14 @@ class Gateway:
             # it (interactive arrivals land on the prefill pool, batch
             # on decode) and either backend's SLO tracker evaluates
             # the request under it (telemetry/slo.py)
+            # a single engine continues the stream itself, up to its
+            # max_tokens; a fleet's streams are fed by the driver (the
+            # router's migration records carry no such ownership)
+            own = {"max_new_tokens": req.max_tokens} if s.owned else {}
             verdict = await self._call(
                 self.backend.put, uid, req.prompt,
                 priority=priority, deadline_ms=deadline_ms,
-                slo_class=cls)
+                slo_class=cls, **own)
         except Exception:
             unreserve()
             raise
@@ -1076,7 +1115,11 @@ class Gateway:
         s.disconnected = True
         self._journey(s.uid, "disconnect", emitted=s.emitted)
         self._c_disc.inc()
-        await self._call(self.backend.cancel, s.uid)
+        # shielded: the connection's handler cancels its watcher task as
+        # it unwinds, and a cancel still queued behind a running pump
+        # would be withdrawn with it, leaving the request to run on with
+        # nobody to end it
+        await asyncio.shield(self._call(self.backend.cancel, s.uid))
 
     async def _stream_response(self, writer, s: _Stream) -> None:
         self._c_streams.inc()

@@ -495,6 +495,17 @@ class InferenceEngine:
         self._last_toks = None
         self._dispatch_seq = 0
         self._fb_step: Dict[int, int] = {}   # uid -> sid its marker defers to
+        # --- the served step runs one step ahead (step(), docs/SERVING.md
+        # "The served loop"): requests whose continuation the engine owns
+        # (uid -> tokens it may still emit; put(max_new_tokens=...)), the
+        # one launch step() left in flight, the tokens of a launch that
+        # was read back outside step() (snapshot and the like: the next
+        # call hands them over), and rows of the launch in flight whose
+        # result is void (uid -> sid; hold())
+        self._cont: Dict[int, int] = {}
+        self._ahead: Optional[_InFlight] = None
+        self._held: Dict[int, int] = {}
+        self._void: Dict[int, int] = {}
         self._zero_key = jax.random.PRNGKey(0)
         # --- overload policy state (inference/overload.py) -------------
         self.ocfg = self.icfg.overload or OverloadConfig()
@@ -683,6 +694,22 @@ class InferenceEngine:
             "serving_guard_hop_ms_total",
             "cumulative milliseconds guarded device calls spent in the "
             "watchdog's hand-off (caller to worker and back)")
+        # how often the served step runs ahead (step()): launches made
+        # with the previous one unread, launches that were not and why,
+        # and rows launched ahead for a stream that no longer wanted them
+        self._c_ahead = reg.counter(
+            "serving_steps_ahead_total",
+            "served steps launched before the previous one was read back",
+            int_valued=True)
+        self._c_strict = reg.counter(
+            "serving_strict_steps_total",
+            "served steps launched with nothing in flight (reason: "
+            "idle|caller_fed|spec_decode|probe)", int_valued=True)
+        self._c_discarded = reg.counter(
+            "serving_ahead_discarded_rows_total",
+            "sampled rows thrown away at collect because their stream "
+            "had ended or paused (reason: finished|cancelled|stalled|...)",
+            int_valued=True)
         # sparse experts (parallel/moe.py moe_serve): read from the rows
         # the step appends to its sampled tokens, at their readback;
         # a dense model has neither
@@ -993,6 +1020,7 @@ class InferenceEngine:
         not yet constructed; with neither configured nor passed this
         raises — an explicit capture with nowhere to write is a
         caller error (the ANOMALY path degrades instead)."""
+        self._settle()      # the window opens on a step boundary
         cap = self._ensure_capture(out_dir)
         if cap is None:
             raise ValueError(
@@ -1115,6 +1143,7 @@ class InferenceEngine:
         Re-applies the serving cast AND re-quantizes under weight_quant —
         the step closure captures the quantized tree, so merely assigning
         ``self.params`` would keep serving the old quantized weights."""
+        self._settle()      # the launch in flight ran on the old weights
         if self._stream is not None:
             raise NotImplementedError(
                 "refresh_params under weight_stream: re-spill the store "
@@ -1548,7 +1577,8 @@ class InferenceEngine:
     # ------------------------------------------------------------------
     def put(self, uid: int, tokens: Sequence[int], priority: int = 0,
             deadline_ms: Optional[float] = None,
-            slo_class: Optional[str] = None) -> AdmissionVerdict:
+            slo_class: Optional[str] = None,
+            max_new_tokens: Optional[int] = None) -> AdmissionVerdict:
         """Enqueue a new request or continue a known one; returns an
         :class:`AdmissionVerdict` (truthy iff the tokens entered the
         engine) instead of growing the backlog unboundedly.
@@ -1566,7 +1596,16 @@ class InferenceEngine:
         the gateway's job, class->pool the fleet router's).  With the
         default :class:`OverloadConfig` (unbounded queue) the verdict
         is always truthy — legacy callers that ignore the return value
-        see the legacy behavior."""
+        see the legacy behavior.
+
+        ``max_new_tokens`` (first put only) hands the request's
+        continuation to the ENGINE: each token it samples is fed back by
+        the engine itself, on the device where :meth:`step` runs ahead,
+        until that many were emitted.  The caller only reads the tokens
+        ``step()`` returns and ends the request (``flush``/``cancel``),
+        or pauses it (:meth:`hold`); it puts no continuation.  Without
+        it the caller feeds every continuation, and a step that holds
+        such a request's row is launched and read back in one call."""
         now = time.perf_counter()
         toks = [int(t) for t in tokens]
         if uid in self._meta or uid in self.state.seqs \
@@ -1622,6 +1661,8 @@ class InferenceEngine:
                                       degraded=(action == "degrade"))
         if deadline_ms is not None:
             self._deadline_uids.add(uid)
+        if max_new_tokens is not None:
+            self._cont[uid] = int(max_new_tokens)
         self.requests.on_arrival(uid, now, slo_class=slo_class)
         self._pending.setdefault(uid, []).extend(toks)
         if self._spec is not None:
@@ -1636,6 +1677,26 @@ class InferenceEngine:
     def flush(self, uid: int) -> None:
         """(reference: engine_v2.flush :242)."""
         self._finish(uid, "finished")
+
+    def hold(self, uid: int) -> None:
+        """Pause a request whose continuation the engine owns (the
+        gateway's backpressure: its client has not read the last token
+        yet).  The continuation the engine queued for itself is taken
+        back — a row already launched ahead with it is rewound and its
+        result thrown away at collect, at most one token computed in
+        vain — and nothing more is scheduled for ``uid`` until the
+        caller puts the last token it was given, which resumes the
+        request exactly where a caller-fed one would be.  A no-op for
+        any other request."""
+        seq = self.state.seqs.get(uid)
+        if uid not in self._cont or seq is None:
+            return
+        self._pending[uid] = []
+        self._fb_step.pop(uid, None)
+        st = self._ahead
+        if st is not None and uid in st.uids and uid not in self._void:
+            self.state.rewind(uid)
+            self._void[uid] = st.sid
 
     def cancel(self, uid: int) -> None:
         """Client abort: terminally close ``uid`` wherever it is —
@@ -1669,6 +1730,8 @@ class InferenceEngine:
         per-request state here and it is cleaned on every path."""
         self._pending.pop(uid, None)
         self._fb_step.pop(uid, None)
+        self._cont.pop(uid, None)
+        self._void.pop(uid, None)
         self._meta.pop(uid, None)
         self._deadline_uids.discard(uid)
         self._preempt_gen.pop(uid, None)
@@ -2049,6 +2112,10 @@ class InferenceEngine:
             if not m.expired(now):
                 continue
             if self._inflight_sched.get(uid, 0):
+                # closed next round; nothing more is scheduled for it
+                # meanwhile, or a stream that step() keeps one launch
+                # ahead would have a row in flight at every pass
+                self._pending[uid] = []
                 continue
             self._finish(uid, "deadline_exceeded")
             self._reaped.add(uid)
@@ -2492,6 +2559,7 @@ class InferenceEngine:
         decode bursts, an in-flight feedback marker) is recorded
         ``exact: False`` and closed ``failed`` at restore."""
         from .. import __version__
+        self._settle()
         now = time.perf_counter()
         reqs = [self._request_record(uid, now)
                 for uid in self._open_uids()]
@@ -2519,6 +2587,7 @@ class InferenceEngine:
         NOT close the requests — :meth:`migrate_out` is the
         extract-and-close composition."""
         from .. import __version__
+        self._settle()
         now = time.perf_counter()
         known = set(self._open_uids())
         wanted = dict.fromkeys(int(u) for u in uids)   # dedup, ordered
@@ -2543,6 +2612,7 @@ class InferenceEngine:
         non-replayable stream (broken chain — the destination could
         only close it ``failed``, killing a healthy request); both
         stay in place, retry at a later step boundary."""
+        self._settle()
         eligible = [int(u) for u in uids
                     if not self._inflight_sched.get(int(u), 0)]
         part = self.snapshot_requests(eligible)
@@ -2566,6 +2636,7 @@ class InferenceEngine:
         ships the prefilled KV instead of re-prefilling it.  The same
         destroy-avoidance rules apply: dispatched-but-uncollected and
         non-replayable requests stay in place for a later boundary."""
+        self._settle()
         eligible = [int(u) for u in uids
                     if not self._inflight_sched.get(int(u), 0)]
         part = self.snapshot_requests(eligible)
@@ -2622,6 +2693,7 @@ class InferenceEngine:
             raise ValueError(
                 f"snapshot version {snap.get('version')!r}: this engine "
                 f"restores version {self.SNAPSHOT_VERSION}")
+        self._settle()
         open_now = set(self._open_uids()) | set(self.requests.open)
         if not merge and open_now:
             raise ValueError(
@@ -2753,6 +2825,11 @@ class InferenceEngine:
         drain (deadline expiry, context exhaustion, a failure close):
         already settled, so re-placing them would double-run them."""
         self._draining = True
+        # a draining engine continues nothing by itself: what is in
+        # flight is read back, and every request goes on as caller-fed
+        # (one more step for a token already queued, like any other)
+        self._settle()
+        self._cont.clear()
         open_at_start = set(self._open_uids()) | set(self.requests.open)
         if self._health != "dead":
             self._health = "draining"
@@ -2800,8 +2877,25 @@ class InferenceEngine:
              ) -> Dict[int, int]:  # tpulint: serving-loop
         """Run one engine step; returns {uid: next_token} for sequences
         whose last pending token was consumed (i.e. ready to sample).
-        Strict-sync form of the pipeline: dispatch, then read straight
-        back (generate() at ``pipeline_depth>=2`` interleaves these).
+
+        A step whose rows all belong to requests the engine continues
+        itself (``put(max_new_tokens=...)``) runs ONE STEP AHEAD: the
+        call schedules, stages and launches step N+1 — continuing
+        decodes take their token from step N's sample array on the
+        device — and only then reads step N back and returns ITS
+        tokens, so the host's work of a step hides under the device's.
+        With nothing in flight such a step is launched and the call
+        returns ``{}`` at once: a launch left in flight is the only way
+        the loop can start, and a driver that comes straight back
+        (``in_flight``) makes the first token wait one call, not one
+        step.  With nothing schedulable the call reads N back.
+
+        A step that holds a row of a request its CALLER feeds (``put``
+        of concrete tokens between calls) is strict, as ever: launched
+        with nothing in flight and read back in the same call; so are a
+        speculative engine's steps (a draft window's continuation is
+        decided on the host at collect) and bisection probes.  The
+        choice is read from the batch, nothing configures it.
 
         With ``spec_decode`` on, a step may emit SEVERAL tokens for a
         sequence (an accepted verify window); the returned token is the
@@ -2809,10 +2903,86 @@ class InferenceEngine:
         ``put`` — and the full stream accumulates on the sequence
         (``query()["generated"]``).  The generate() drivers consume the
         full per-step lists internally."""
-        st = self._dispatch(sampling, rng)
+        if self._held:
+            # a launch read back outside step() (snapshot, a failed
+            # launch behind it): its tokens are handed over first
+            out, self._held = self._held, {}
+            return out
+        prev = self._ahead
+        if prev is not None and any(
+                t and u not in self._cont
+                for u, t in self._pending.items()):
+            # a caller-fed request waits: read the launch in flight
+            # back now, its step is launched strict by the next call
+            self._ahead = None
+            return self._last(self._collect(prev))
+        st = self._dispatch(sampling, rng, self._cont or None)
+        if prev is not None and self._ahead is None:
+            # the launch failed, and its failure path read ``prev`` back
+            out, self._held = self._held, {}
+            return out
         if st is None:
+            self._ahead = None
+            return self._last(self._collect(prev)) if prev is not None \
+                else {}
+        if prev is None:
+            why = "spec_decode" if self._spec is not None \
+                else "probe" if self._probe_groups \
+                else "caller_fed" if any(u not in self._cont
+                                         for u in st.uids) else None
+            if why is not None:
+                self._c_strict.inc(reason=why)
+                out = self._collect(st)
+                for uid, toks in out.items():
+                    # an engine-continued row of a strict step goes on
+                    # from its concrete token
+                    if self._cont.get(uid, 0) > 0 \
+                            and uid in self.state.seqs \
+                            and not self._pending.get(uid):
+                        self._pending[uid] = [toks[-1]]
+                return self._last(out)
+            self._c_strict.inc(reason="idle")
+        else:
+            self._c_ahead.inc()
+        # continuing decodes of the next launch read this one's samples
+        # on the device; a request's last token is not speculated past
+        unread = {u for u, _ in prev.emit} if prev is not None else ()
+        for uid, _slot in st.emit:
+            if self._cont.get(uid, 0) - 1 - (uid in unread) > 0:
+                self._mark_feedback(uid, st)
+        self._ahead = st
+        if prev is None:
             return {}
-        return {u: ts[-1] for u, ts in self._collect(st).items()}
+        return self._last(self._collect(prev, nxt=st))
+
+    @staticmethod
+    def _last(out: Dict[int, List[int]]) -> Dict[int, int]:
+        return {u: ts[-1] for u, ts in out.items()}
+
+    @property
+    def in_flight(self) -> bool:
+        """:meth:`step` left a launch unread: the next call has tokens
+        to return even if nothing new is schedulable, so a driver must
+        not take an empty return for an idle round."""
+        return self._ahead is not None or bool(self._held)
+
+    def _settle(self) -> None:
+        """Read the launch :meth:`step` left in flight back now, at a
+        boundary that needs every request's stream on the host
+        (snapshot, migration, hand-off, a weight refresh, a capture, a
+        drain's end, another driver taking over).  Its tokens are
+        emitted as ever and handed to the caller by the next
+        :meth:`step`; a dead engine's launch is dropped unread."""
+        st, self._ahead = self._ahead, None
+        if st is None:
+            return
+        if self._health == "dead":
+            self._uncount_inflight(st.uids)
+            return
+        try:
+            self._held.update(self._last(self._collect(st)))
+        except EngineDeadError:
+            pass        # the host's truth stands; the caller reads it
 
     @staticmethod
     def _rng_drawer(rng: Optional[jax.Array]):
@@ -2912,7 +3082,13 @@ class InferenceEngine:
                 sched, self.icfg.token_budget, stager=self._stager,
                 draft_lens={u: len(d)
                             for u, d in self._sched_drafts.items()},
-                n_verify=self._n_verify))
+                n_verify=self._n_verify,
+                # a request the engine continues keeps its chain whole:
+                # the row fed from the device is written in when the
+                # step that sampled it is read back
+                deferred_from={u: self._fb_step[u] for u, t in sched
+                               if t[0] == FEEDBACK_TOKEN
+                               and u in self._cont} or None))
         # device-order bracket: demote reads of just-evicted blocks must
         # enqueue before ANY write that may reuse them (COW copies,
         # restage uploads, the step itself) — stream ordering then makes
@@ -2926,7 +3102,7 @@ class InferenceEngine:
                       track="dispatch", sid=sid, n_tokens=n_tokens,
                       n_seqs=len(sched),
                       n_decode=sum(1 for _, t in sched if len(t) == 1),
-                      mbs=mbs)
+                      mbs=mbs, ahead=int(bool(self._inflight_sched)))
         if callable(rng):
             rng = rng()
         if rng is None and sampling.needs_rng:
@@ -2951,9 +3127,22 @@ class InferenceEngine:
             # classifier seam (tpulint's serving-except rule holds the
             # loop to this); the live ledger IS this step's build
             tr.phase_end(failed=type(e).__name__)
-            self._handle_step_failure(
-                e, uids, "dispatch",
-                registered=tuple(self.state.round_registered))
+            registered = tuple(self.state.round_registered)
+            prev, self._ahead = self._ahead, None
+            if prev is not None:
+                # the launch step() left in flight is read back first:
+                # its tokens are real (step() hands them over), and the
+                # failure path would otherwise close every row of it as
+                # in flight elsewhere.  Should that read fail too, it
+                # takes this step's rows with it (_collect, ``nxt``)
+                retries = self.timings["step_retries"]
+                self._held.update(self._last(self._collect(
+                    prev, nxt=_InFlight(toks=None, emit=(), sid=sid,
+                                        uids=uids, registered=registered))))
+                if self.timings["step_retries"] != retries:
+                    return None
+            self._handle_step_failure(e, uids, "dispatch",
+                                      registered=registered)
             return None
         hop_us = guard.get("hop_us", 0.0)
         t3 = tr.phase_end(hop_us=round(hop_us, 1))
@@ -3142,6 +3331,15 @@ class InferenceEngine:
         self._pending[uid] = [FEEDBACK_TOKEN]
         self._fb_step[uid] = st.sid
 
+    def _uncount_inflight(self, uids) -> None:
+        """One dispatched step of ``uids`` is no longer uncollected."""
+        for uid in uids:
+            n = self._inflight_sched.get(uid, 0) - 1
+            if n > 0:
+                self._inflight_sched[uid] = n
+            else:
+                self._inflight_sched.pop(uid, None)
+
     def _fetch_tokens(self, arr) -> np.ndarray:  # tpulint: serving-loop
         """THE sanctioned serving-loop readback: every device->host token
         fetch (step collect, decode bursts) funnels through here so the
@@ -3149,7 +3347,7 @@ class InferenceEngine:
         critical path."""
         return np.asarray(arr)  # tpulint: disable=serving-sync
 
-    def _collect(self, st: _InFlight
+    def _collect(self, st: _InFlight, nxt: Optional[_InFlight] = None
                  ) -> Dict[int, List[int]]:  # tpulint: serving-loop
         """Read one in-flight step's tokens back and emit them (a LIST
         per uid: one token for a plain decode/prefill row, up to
@@ -3170,13 +3368,15 @@ class InferenceEngine:
         ``resolve_draft`` rewinds the KV write cursor over the rejected
         tail.  A stop token landing inside the window truncates the
         emission exactly where the stepwise engine would have stopped
-        feeding, and the commit rolls back to it."""
-        for uid in st.uids:
-            n = self._inflight_sched.get(uid, 0) - 1
-            if n > 0:
-                self._inflight_sched[uid] = n
-            else:
-                self._inflight_sched.pop(uid, None)
+        feeding, and the commit rolls back to it.
+
+        ``nxt``: the step :meth:`step` launched behind this one, still
+        unread.  It took this step's tokens from the device, so when
+        this read fails its rows are re-queued with this step's; when
+        this read is slow, the slow-call note says whether ``nxt``'s
+        samples were ready by then (``next_ready``: the device had gone
+        on and only the completion came late)."""
+        self._uncount_inflight(st.uids)
         tr = self.tracer
         guard: Dict[str, float] = {}      # the watchdog's hand-off time
         t0 = tr.phase("ds.serve.wait", track="wait", sid=st.sid)
@@ -3186,10 +3386,12 @@ class InferenceEngine:
             # seam as the dispatch.  The host transfer itself rides the
             # same try — a device dying between the wait and the copy
             # must degrade like any other failure, not crash the loop
-            self.failures.run(lambda: jax.block_until_ready(st.toks),
-                              uids=st.uids, cold=st.cold,
-                              site="collect", sid=st.sid,
-                              stamps=guard)
+            self.failures.run(
+                lambda: jax.block_until_ready(st.toks),
+                uids=st.uids, cold=st.cold, site="collect", sid=st.sid,
+                stamps=guard,
+                slow_note=None if nxt is None or nxt.toks is None else
+                lambda: {"next_ready": bool(nxt.toks.is_ready())})
             hop_us = guard.get("hop_us", 0.0)
             tr.phase_set(hop_us=round(hop_us, 1))
             t1 = tr.phase("ds.serve.readback", track="readback",
@@ -3197,13 +3399,29 @@ class InferenceEngine:
             toks_np = self._fetch_tokens(st.toks)
         except Exception as e:
             tr.phase_end(failed=type(e).__name__)
-            if st.sid == self._dispatch_seq:
-                # this WAS the latest dispatch: its sample array must
-                # never feed a later step (markers deferring to it are
-                # cleaned by the re-queue below; zero fallback is safe)
+            uids, registered = st.uids, st.registered
+            if nxt is not None:
+                # the launch behind this one read this step's tokens on
+                # the device: its rows go back to the queue with these
+                # (the rows it fed from the device are rewound first, so
+                # every chain ends at a token the host knows)
+                self._ahead = None
+                self._uncount_inflight(nxt.uids)
+                for uid in nxt.uids:
+                    seq = self.state.seqs.get(uid)
+                    if seq is not None and seq.deferred:
+                        self.state.rewind(uid, len(seq.deferred))
+                    self._void.pop(uid, None)
+                uids = tuple(dict.fromkeys(uids + nxt.uids))
+                registered = registered + nxt.registered
+            if nxt is not None or st.sid == self._dispatch_seq:
+                # this WAS the latest dispatch (or fed it): its sample
+                # array must never feed a later step (markers deferring
+                # to it are cleaned by the re-queue below; zero fallback
+                # is safe)
                 self._last_toks = None
-            self._handle_step_failure(e, st.uids, "collect",
-                                      registered=st.registered)
+            self._handle_step_failure(e, uids, "collect",
+                                      registered=registered)
             return {}
         moe: Dict[str, float] = {}
         if self._moe_metrics is not None:
@@ -3230,7 +3448,20 @@ class InferenceEngine:
         for uid, slot in st.emit:
             row = toks_np[slot]        # [W] on a spec engine, else 0-d
             seq = self.state.seqs.get(uid)
-            live = seq is not None and self.state._slots.get(uid) == slot
+            if self._void.get(uid) == st.sid:
+                # paused after this row was launched (hold()): rewound
+                # there, never emitted; the caller's put computes it anew
+                del self._void[uid]
+                self._c_discarded.inc(reason="stalled")
+                continue
+            if seq is None or self.state._slots.get(uid) != slot:
+                # launched for a stream that has ended since (or was
+                # re-queued): nobody is handed this token
+                status = self.requests.status_of(uid)
+                self._c_discarded.inc(
+                    reason=status if status not in (None, "open")
+                    else "requeued")
+                continue
             d = drafts.get(uid)
             if d:
                 a = 0
@@ -3241,47 +3472,49 @@ class InferenceEngine:
                     # stop inside the window: everything past it was
                     # never fed by a stepwise engine — roll it back too
                     emitted = emitted[:emitted.index(st.stop) + 1]
-                if live:
-                    # commit fed token + the emitted tokens already in
-                    # KV (all but the bonus sample); rewind the rest
-                    self.state.resolve_draft(uid, len(emitted) - 1)
-                    # spec accounting — engine counters and the request
-                    # record move at the same statements so
-                    # sum(per-request) reconciles by construction
-                    tm["spec_windows"] += 1
-                    tm["spec_drafted_tokens"] += len(d)
-                    tm["spec_accepted_tokens"] += len(emitted) - 1
-                    tm["spec_rejected_tokens"] += len(d) - (len(emitted)
-                                                            - 1)
-                    self.requests.on_draft(uid, len(d), len(emitted) - 1)
-                    if self._anom is not None:
-                        evt = self._anom.observe(
-                            "spec_acceptance",
-                            (len(emitted) - 1) / len(d),
-                            self._steps_done)
-                        if evt is not None:
-                            self._on_anomaly(evt)
+                # commit fed token + the emitted tokens already in
+                # KV (all but the bonus sample); rewind the rest
+                self.state.resolve_draft(uid, len(emitted) - 1)
+                # spec accounting — engine counters and the request
+                # record move at the same statements so
+                # sum(per-request) reconciles by construction
+                tm["spec_windows"] += 1
+                tm["spec_drafted_tokens"] += len(d)
+                tm["spec_accepted_tokens"] += len(emitted) - 1
+                tm["spec_rejected_tokens"] += len(d) - (len(emitted)
+                                                        - 1)
+                self.requests.on_draft(uid, len(d), len(emitted) - 1)
+                if self._anom is not None:
+                    evt = self._anom.observe(
+                        "spec_acceptance",
+                        (len(emitted) - 1) / len(d),
+                        self._steps_done)
+                    if evt is not None:
+                        self._on_anomaly(evt)
             else:
                 emitted = [int(row[0] if spec else row)]
-            if live:
-                seq.tokens.extend(emitted)
-                # emitted to a live sequence: the engine generated-token
-                # counter and the request record move together (parity
-                # invariant, tests/test_telemetry.py)
-                tm["generated_tokens"] += len(emitted)
-                self.requests.on_tokens(uid, len(emitted), t2)
-                if self._anom is not None:
-                    rec = self.requests.open.get(uid)
-                    if rec is not None \
-                            and rec.generated_tokens == len(emitted):
-                        # this emission WAS the first token — TTFT is
-                        # known now, not at finish
-                        evt = self._anom.observe(
-                            "ttft_ms", rec.ttft_ms, self._steps_done)
-                        if evt is not None:
-                            self._on_anomaly(evt)
-                if self._spec is not None:
-                    self._spec.observe(uid, emitted)
+            seq.tokens.extend(emitted)
+            if seq.deferred:
+                self.state.resolve_feedback(uid, st.sid, emitted[-1])
+            if uid in self._cont:
+                self._cont[uid] -= len(emitted)
+            # emitted to a live sequence: the engine generated-token
+            # counter and the request record move together (parity
+            # invariant, tests/test_telemetry.py)
+            tm["generated_tokens"] += len(emitted)
+            self.requests.on_tokens(uid, len(emitted), t2)
+            if self._anom is not None:
+                rec = self.requests.open.get(uid)
+                if rec is not None \
+                        and rec.generated_tokens == len(emitted):
+                    # this emission WAS the first token — TTFT is
+                    # known now, not at finish
+                    evt = self._anom.observe(
+                        "ttft_ms", rec.ttft_ms, self._steps_done)
+                    if evt is not None:
+                        self._on_anomaly(evt)
+            if self._spec is not None:
+                self._spec.observe(uid, emitted)
             out[uid] = emitted
             if self._fb_step.get(uid) == st.sid:
                 self._fb_step.pop(uid)
@@ -3353,6 +3586,7 @@ class InferenceEngine:
         of live sequences (pure decode); KV blocks for the whole burst
         are pre-reserved host-side.  Returns {uid: [token, ...]}."""
         self._ensure_alive()
+        self._settle()
         steps = steps or max(1, self.icfg.decode_burst)
         pending = {u: t for u, t in self._pending.items() if t}
         if not pending:
@@ -3535,6 +3769,7 @@ class InferenceEngine:
         default) keeps one step in flight — host scheduling/staging and
         token readback overlap device compute, and the sampled-token
         array feeds the next step on device."""
+        self._settle()      # another driver's launch is read back first
         done: Dict[int, List[int]] = {}
         active = set()
         for uid, p in prompts.items():

@@ -302,10 +302,13 @@ class Watchdog:
 
     def run(self, fn: Callable, timeout_ms: Optional[float],
             site: Optional[str] = None, sid: Optional[int] = None,
-            stamps: Optional[Dict[str, float]] = None):
+            stamps: Optional[Dict[str, float]] = None,
+            slow_note: Optional[Callable[[], Dict]] = None):
         """Run ``fn()`` under ``timeout_ms``; inline when None.
         ``site``/``sid`` name the call in the slow-call records;
-        ``stamps``, the caller's own dict, receives ``hop_us``."""
+        ``stamps``, the caller's own dict, receives ``hop_us``;
+        ``slow_note`` is called only for a ``guard_slow_call`` record,
+        the moment the result is taken back, and adds its keys to it."""
         if timeout_ms is None:
             return fn()
         with self._admit:
@@ -350,7 +353,8 @@ class Watchdog:
                         deadline_ms=timeout_ms, t_put_s=t_put,
                         queued_ms=(t_taken - t_put) * 1e3,
                         fn_ms=(t_ret - t_taken) * 1e3,
-                        taken_back_ms=(t_got - t_ret) * 1e3)
+                        taken_back_ms=(t_got - t_ret) * 1e3,
+                        **(slow_note() if slow_note is not None else {}))
                 if ok:
                     return val
                 raise val
@@ -414,12 +418,14 @@ class FailurePolicy:
     # ---- the guarded-call entry --------------------------------------
     def run(self, fn: Callable, uids=(), cold: bool = False,
             site: Optional[str] = None, sid: Optional[int] = None,
-            stamps: Optional[Dict[str, float]] = None):
+            stamps: Optional[Dict[str, float]] = None,
+            slow_note: Optional[Callable[[], Dict]] = None):
         """Run one guarded device call: consume any armed injection,
         then execute under the current watchdog deadline.  ``site``
         (``dispatch``/``collect``/``burst``) and ``sid`` name the call
         in the watchdog's slow-call records; ``stamps`` receives the
-        hand-off's ``hop_us`` (``Watchdog.run``).  ``cold``
+        hand-off's ``hop_us`` and ``slow_note`` adds to a slow call's
+        record (``Watchdog.run``).  ``cold``
         marks a call whose compiled program has never completed before
         (a compile may ride it): it runs UNGUARDED — compiles are slow
         and legitimate, and abandoning a worker mid-XLA-compile leaves
@@ -442,7 +448,8 @@ class FailurePolicy:
                 raise InjectedFault(kind, uid=None)
         return self.watchdog.run(fn,
                                  None if cold else self.deadline_ms(),
-                                 site=site, sid=sid, stamps=stamps)
+                                 site=site, sid=sid, stamps=stamps,
+                                 slow_note=slow_note)
 
     def deadline_ms(self) -> Optional[float]:
         """The current watchdog deadline: the configured value, or the

@@ -163,6 +163,14 @@ class SequenceDescriptor:
     # :meth:`StateManager.resolve_draft` either commits them or rewinds
     # the write cursor.
     draft_len: int = 0
+    # chain positions whose token is still on the device only, as
+    # ``(sid, index)``: a row fed from step ``sid``'s sample array
+    # (``build_batch(deferred_from=...)``) whose value that step's
+    # collect fills in (:meth:`StateManager.resolve_feedback`).  While
+    # any is open the sequence is not resumable and no block at or past
+    # the first one is content-hashed.
+    deferred: List[Tuple[int, int]] = dataclasses.field(
+        default_factory=list)
 
     def blocks_needed(self, new_tokens: int, block_size: int) -> int:
         total = self.seen_tokens + new_tokens
@@ -180,6 +188,7 @@ class SequenceDescriptor:
         device-side tokens the host never saw (a deferred feedback
         marker or a decode burst) and can only be closed."""
         return (not self.chain_broken and self.draft_len == 0
+                and not self.deferred
                 and len(self.chain) == self.seen_tokens)
 
 
@@ -508,7 +517,9 @@ class StateManager:
         is matchable from the very step that fills it; device ordering
         makes the write land before any aliasing step's read)."""
         bs = self.cfg.block_size
-        while len(seq.hashes) < len(seq.chain) // bs:
+        # a block holding a token the host has not read yet waits
+        known = seq.deferred[0][1] if seq.deferred else len(seq.chain)
+        while len(seq.hashes) < known // bs:
             k = len(seq.hashes)
             parent = seq.hashes[-1] if seq.hashes else _CHAIN_ROOT
             h = chain_hash(parent, seq.chain[k * bs:(k + 1) * bs])
@@ -765,6 +776,29 @@ class StateManager:
             self._register_chain_blocks(seq)
         return rejected
 
+    def resolve_feedback(self, uid: int, sid: int, token: int) -> None:
+        """Step ``sid``'s collect read ``uid``'s sample: write it into
+        the chain position a later batch fed from the device (no-op
+        when none was).  The block it may complete is hashed by the
+        sequence's next ``build_batch``, in that step's ledger."""
+        seq = self.seqs.get(uid)
+        if seq is not None and seq.deferred and seq.deferred[0][0] == sid:
+            seq.chain[seq.deferred.pop(0)[1]] = int(token)
+
+    def rewind(self, uid: int, n_tokens: int = 1) -> None:
+        """Take back ``uid``'s last ``n_tokens`` scheduled rows (a row
+        launched ahead whose result is thrown away, or whose fed token
+        never arrived): the write cursor and the chain move back, the
+        rows' KV is overwritten by whatever is scheduled next, blocks
+        already allocated for them stay with the sequence (as in
+        :meth:`resolve_draft`)."""
+        seq = self.seqs[uid]
+        seq.seen_tokens -= n_tokens
+        if not seq.chain_broken:
+            del seq.chain[-n_tokens:]
+            seq.deferred = [d for d in seq.deferred
+                            if d[1] < len(seq.chain)]
+
     def advance(self, uid: int, n_tokens: int) -> None:
         """Account tokens written device-side (burst iterations past the
         first host-fed token).  Burst-written KV bypasses build_batch, so
@@ -778,7 +812,9 @@ class StateManager:
     def build_batch(self, requests: List[tuple], token_budget: int,
                     stager: Optional[BatchStager] = None,
                     draft_lens: Optional[Dict[int, int]] = None,
-                    n_verify: int = 1) -> RaggedBatch:
+                    n_verify: int = 1,
+                    deferred_from: Optional[Dict[int, int]] = None
+                    ) -> RaggedBatch:
         """requests: [(uid, list_of_new_token_ids)]; allocates KV blocks and
         produces the padded device metadata.  A token id of
         :data:`FEEDBACK_TOKEN` (single-token decode continuations only)
@@ -796,7 +832,13 @@ class StateManager:
         :meth:`resolve_draft` to commit or rewind.  ``n_verify > 1``
         emits ``verify_idx`` ([max_seqs, n_verify]) so the compiled step
         samples every window position (-1 pads; non-drafting rows use
-        column 0 = their last token)."""
+        column 0 = their last token).
+
+        ``deferred_from``: uid -> the step whose collect will read this
+        batch's :data:`FEEDBACK_TOKEN` row for that uid.  Such a row
+        keeps its place in the chain (``SequenceDescriptor.deferred``)
+        instead of breaking it: the host learns the token one step
+        late, in order."""
         max_blocks = self.cfg.num_blocks
         T = token_budget
         # fresh registration ledger for this round (see round_registered)
@@ -870,9 +912,14 @@ class StateManager:
                 # step's on-device sample at this sequence's slot
                 token_ids[cursor] = 0
                 feedback_src[cursor] = s
-                # the host never learns this KV row's token id in order,
-                # so content hashing stops here for this sequence
-                seq.chain_broken = True
+                src = deferred_from.get(uid) if deferred_from else None
+                if src is not None and not seq.chain_broken:
+                    seq.deferred.append((src, len(seq.chain)))
+                    seq.chain.append(FEEDBACK_TOKEN)
+                else:
+                    # the host never learns this KV row's token id in
+                    # order, so content hashing stops here
+                    seq.chain_broken = True
             else:
                 token_ids[cursor:cursor + n] = new_tokens
                 if not seq.chain_broken:
