@@ -54,6 +54,7 @@ import collections
 import contextlib
 import gc
 import json
+import os
 import sys
 import tempfile
 import time
@@ -683,6 +684,98 @@ def serve_moe_phase(sz, seed):
                        LOGIT_REL)
 
 
+def serve_trinity_phase(sz, seed):
+    """The cell serve-window-longgen's model (benchmarks/configs/
+    trinity-mini-d5.json, at its published widths) through the engine's
+    paged path against the benchmark's plain reference, by the cell's own
+    comparison (benchmarks/lib/drivers/serve_routed.py: the reference
+    follows the experts the engine took) and under the file's own limits:
+    the cell's three sample sequences, and a prompt of a window and a
+    half prefilled in the engine's ordinary chunks, then 8 fed tokens.
+    Then the same logits against every wrong forward the reference knows:
+    each has to FAIL the limit the true forward passes (the missing
+    window past the window only)."""
+    import jax.numpy as jnp
+
+    from benchmarks.lib import common
+    from benchmarks.lib.drivers import serve_routed as R
+    from benchmarks.lib.weights import make_model
+    from deepspeed_tpu.inference import InferenceConfig, InferenceEngine
+
+    _, _, config, mix = common.load_cell("serve-window-longgen")
+    if sz is TINY:
+        common.apply_rehearsal(config, mix)
+    cfg = R.preset_config(config)
+    model = make_model(cfg, seed + 3, dtype=jnp.bfloat16)
+    ref = common.load_module(
+        os.path.join(common.ROOT, config["reference"]["file"]), "trinity_ref")
+    tol = config["reference"]["tolerance"]
+    limit, short_limit = tol["followed_rel"], tol["routing_short"]
+    sample = config["reference"]["sample"]
+    k_dec = int(sample["decode_tokens"])
+    print(f"  {config['name']}: d{cfg.d_model} H{cfg.num_heads}/"
+          f"{cfg.num_kv_heads}x{cfg.head_dim}, layers {cfg.layer_kinds}, "
+          f"window {cfg.attn_window}, {cfg.num_experts} experts of "
+          f"{cfg.moe_d_ff} top-{cfg.moe_top_k}, bf16, limit {limit} with "
+          f"the routing followed, {short_limit} on a taken expert's score")
+    rng = np.random.RandomState(seed + 3)
+    seqs = {f"sample{i}": rng.randint(0, cfg.vocab_size, n + k_dec).tolist()
+            for i, n in enumerate(sample["prompt_lens"])}
+    seqs["past_window"], n_long = R.past_window(config, cfg, seed + 3)
+    n_prompt = {u: len(s) - k_dec for u, s in seqs.items()}
+    block = int(mix["engine"]["kv_block_size"])
+    eng = InferenceEngine(model, InferenceConfig(
+        token_budget=int(mix["engine"]["token_budget"]),
+        max_seqs=int(mix["engine"]["max_seqs"]), kv_block_size=block,
+        num_kv_blocks=2 * (-(-(n_long + k_dec) // block)) + 16,
+        max_seq_len=int(mix["engine"]["max_seq_len"]),
+        **config.get("engine_options", {})))
+    report_path(eng)
+    system = R.system_side(eng, seqs, n_prompt)
+    steps = system["past_window"][2]
+    check(steps >= -(-n_long // eng.icfg.token_budget) + k_dec,
+          f"the long prompt took {steps} steps, not its chunks")
+    print(f"    long prompt: {n_long} tokens in chunks of "
+          f"{eng.icfg.token_budget}, then {k_dec} fed: {steps} steps")
+
+    def readings(wrong):
+        """(sample prefill, sample decode, past-window prefill,
+        past-window decode, the routing's shortfall)."""
+        read = R.follow(ref, model.params, config, seqs, system, wrong=wrong)
+        far = read.pop("past_window")
+        return (max(r["prefill"] for r in read.values()),
+                max(r["decode"] for r in read.values()),
+                far["prefill"], far["decode"],
+                max([far["short"]] + [r["short"] for r in read.values()]))
+
+    def line(got):
+        return ", ".join(f"{n} {v:.4g}" for n, v in zip(
+            ("sample prefill", "sample decode", "past-window prefill",
+             "past-window decode", "routing short"), got))
+
+    true = readings(None)
+    print("    true forward: " + line(true))
+    check(max(true[:4]) <= limit, f"the engine differs from the reference "
+          f"that follows its routing beyond {limit}: {true}")
+    check(true[4] <= short_limit, f"the engine took an expert whose score "
+          f"the reference has {true[4]} under its eighth")
+    for wrong in ref.WRONG:
+        got = readings(wrong)
+        fails = max(got[:4]) > limit
+        print(f"    reference with {wrong}: " + line(got)
+              + ("  (fails)" if fails else "  (PASSES)"))
+        if wrong == "no_window":
+            check(max(got[2:4]) > limit, "a reference without the window "
+                  "agrees with the system past the window")
+            check(max(got[:2]) <= limit or sz is TINY, "the sample's "
+                  "contexts reach the window after all")
+        elif sz is not TINY and wrong != "bias_in_weights":
+            # the bias moves a weight by 3%: under bfloat16's own
+            # reading whatever follows the routing (PERF.md, PR 38);
+            # float32 tells it (tests/test_trinity.py)
+            check(fails, f"a reference with {wrong} agrees with the system")
+
+
 # --------------------------------------------------------------------------
 # four chips: the sharded paths and what they are compared with
 # --------------------------------------------------------------------------
@@ -826,6 +919,9 @@ def main(argv=None) -> int:
                     "interpret mode off-TPU); the last line names the "
                     "platform it really ran on")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--only", default=None,
+                    help="run this one-chip phase alone (its name as the "
+                    "log prints it, e.g. serve-trinity)")
     args = ap.parse_args(argv)
 
     t_start = time.perf_counter()
@@ -856,20 +952,23 @@ def main(argv=None) -> int:
     if args.chips == 4:
         four_chip_phases(sz, args.seed)
     else:
-        with phase("block_until_ready"):
-            barrier_phase(sz)
-        with phase("profiler window"):
-            profiler_phase(sz)
-        with phase("kernels vs XLA"):
-            kernels_phase(sz, args.seed)
-        with phase("train"):
-            train_run(sz, args.seed, devices[:1], {"data": 1}, 1, "trainer")
-        with phase("serve"):
-            serve_phase(sz, args.seed)
-        with phase("serve-int8"):
-            serve_int8_phase(sz, args.seed)
-        with phase("serve-moe"):
-            serve_moe_phase(sz, args.seed)
+        one_chip = (
+            ("block_until_ready", lambda: barrier_phase(sz)),
+            ("profiler window", lambda: profiler_phase(sz)),
+            ("kernels vs XLA", lambda: kernels_phase(sz, args.seed)),
+            ("train", lambda: train_run(sz, args.seed, devices[:1],
+                                        {"data": 1}, 1, "trainer")),
+            ("serve", lambda: serve_phase(sz, args.seed)),
+            ("serve-int8", lambda: serve_int8_phase(sz, args.seed)),
+            ("serve-moe", lambda: serve_moe_phase(sz, args.seed)),
+            ("serve-trinity", lambda: serve_trinity_phase(sz, args.seed)))
+        if args.only and args.only not in dict(one_chip):
+            ap.error(f"--only {args.only!r}: no such phase; have "
+                     f"{[n for n, _ in one_chip]}")
+        for name, run in one_chip:
+            if args.only in (None, name):
+                with phase(name):
+                    run()
 
     print(f"total {time.perf_counter() - t_start:.1f} s; compile "
           f"{COMPILE['compile_s']:.1f} s over {COMPILE['cache_misses']} "

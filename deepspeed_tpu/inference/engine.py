@@ -741,6 +741,23 @@ class InferenceEngine:
         reg.gauge_fn("serving_attn_tile_fill", self._attn_tile_fill,
                      "real tokens over tile rows of the long query tiles "
                      "(absent before the first one)")
+        # keys and values one attention layer of each kind has to read
+        # for the dispatched steps, from the schedule (host arithmetic,
+        # nothing read from the device).  A model without window layers
+        # counts the full kind only
+        self._window = (self.cfg.attn_window
+                        if "window" in self.cfg.layer_pattern else None)
+        self._c_attn_kv = reg.counter(
+            "serving_attn_kv_tokens_total",
+            "cached tokens one attention layer reads for the dispatched "
+            "steps (kind: full = every token of the step's sequences | "
+            "window = those inside its queries' windows)", int_valued=True)
+        if self._window:
+            reg.gauge_fn(
+                "serving_kv_tokens_behind_window", self._behind_window,
+                "pooled tokens of the live sequences that no window layer "
+                "will read again: what a pool per layer kind would free "
+                "in each window layer")
         # --- overlapped/quantized collectives (docs/SERVING.md
         # "Overlapped & quantized collectives"): static per-dispatch
         # wire accounting — the shapes of a compiled step fully
@@ -879,6 +896,34 @@ class InferenceEngine:
                 self._slo.bind(self._anom,
                                lambda: self._steps_done,
                                self._on_anomaly)
+
+    def _count_attn_kv(self, sched) -> Dict[str, int]:
+        """The cached tokens an attention layer of each kind reads for
+        the step ``sched``, counted and returned as the stage span's
+        arguments: ``kv_tokens_full``, the sum of ``seen + n`` over its
+        sequences, and with window layers ``kv_tokens_window``, the sum
+        of ``min(seen + n, window + n - 1)``."""
+        w = self._window
+        full = window = 0
+        for uid, toks in sched:
+            seq = self.state.seqs.get(uid)
+            ctx = (seq.seen_tokens if seq else 0) + len(toks)
+            full += ctx
+            if w:
+                window += min(ctx, w + len(toks) - 1)
+        self._c_attn_kv.inc(full, kind="full")
+        if not w:
+            return {"kv_tokens_full": full}
+        self._c_attn_kv.inc(window, kind="window")
+        return {"kv_tokens_full": full, "kv_tokens_window": window}
+
+    def _behind_window(self) -> int:
+        """Tokens the live sequences hold that lie behind the window of
+        their next query (``seen - window + 1`` of each, where positive):
+        every layer keeps every block, so a window layer's pool holds
+        them to no purpose."""
+        return sum(max(0, q.seen_tokens - self._window + 1)
+                   for q in list(self.state.seqs.values()))
 
     def _attn_tile_fill(self) -> Optional[float]:
         """Real tokens over rows of the long query tiles dispatched so
@@ -1489,11 +1534,14 @@ class InferenceEngine:
             return jax.jit(fn, donate_argnums=donate, out_shardings=out_sh)
         return jax.jit(fn, donate_argnums=donate)
 
-    def _build_step(self, mbs: Optional[int] = None):
+    def _build_step(self, mbs: Optional[int] = None,
+                    with_routing: bool = False):
         """Compile one SplitFuse step bounded to ``mbs`` context blocks —
         the logits-returning sibling of :meth:`_build_pstep` (the serving
         loop runs pstep; this entry serves logits-level consumers:
-        quant/TP parity tests and offline scoring).
+        quant/TP parity tests and offline scoring).  ``with_routing``
+        (sparse-expert models): a third output, the experts each row
+        took in each expert layer (``ragged_forward``).
 
         Steps are compiled per power-of-two context bucket (like the
         decode-burst prefix buckets): the XLA attention paths do work
@@ -1503,6 +1551,8 @@ class InferenceEngine:
         cfg = self.cfg
         bs = self.icfg.kv_block_size
         fw, mbs = self._resolve_fw(mbs)
+        if with_routing and cfg.num_experts <= 1:
+            raise ValueError("with_routing: the model routes no token")
 
         # NOTE: the quant tree is a jit ARGUMENT, never a closure —
         # closed-over trees bake into the HLO as constants (7.5 GB of
@@ -1510,7 +1560,8 @@ class InferenceEngine:
         # compile); as an argument it is device buffers, like params
         def step(params, quant, kv, batch: RaggedBatch):
             return ragged_forward(cfg, params, kv, batch, bs, mbs,
-                                  quant=quant, **fw)
+                                  quant=quant, with_routing=with_routing,
+                                  **fw)
 
         return self._serving_jit(step)
 
@@ -3076,7 +3127,7 @@ class InferenceEngine:
                          tile_fill=rows / (n_long * LONG) if n_long else 0.0)
         t1 = tr.phase("ds.serve.stage", track="stage", sid=sid,
                       n_tokens=n_tokens, n_seqs=len(sched), mbs=mbs,
-                      **tiles)
+                      **tiles, **self._count_attn_kv(sched))
         batch = self._stage(
             self.state.build_batch(
                 sched, self.icfg.token_budget, stager=self._stager,
@@ -3426,9 +3477,10 @@ class InferenceEngine:
         moe: Dict[str, float] = {}
         if self._moe_metrics is not None:
             # the routing statistics rode the tokens' own readback
-            n, load = toks_np[-MOE_STAT_ROWS:].reshape(
+            n, load, touched = toks_np[-MOE_STAT_ROWS:].reshape(
                 MOE_STAT_ROWS, -1)[:, 0]
-            moe = {"moe_assignments": int(n), "moe_load": load / 1e3}
+            moe = {"moe_assignments": int(n), "moe_load": load / 1e3,
+                   "moe_experts_touched": int(touched)}
             self._moe_metrics[0].inc(moe["moe_assignments"])
             self._moe_metrics[1].set(moe["moe_load"])
         t2 = tr.phase_end(**moe)

@@ -18,6 +18,7 @@ XLA-friendly):
 
 from __future__ import annotations
 
+import contextlib
 from functools import partial
 from typing import Optional, Tuple
 
@@ -29,14 +30,15 @@ from jax import shard_map
 from ..comm.overlap import (ServingComm, shard_matmul_allgather,
                             shard_matmul_allreduce)
 from ..models import layers as L
-from ..models.transformer import TransformerConfig, _norm
+from ..models.transformer import TransformerConfig, _norm, _qk_norm
 from .ragged.state import RaggedBatch
 from .sampler import row_keys, window_keys
 
 
 # rows a sparse-expert model's serving step appends to its sampled tokens
-# (``pipelined_ragged_step``): assignments, 1000 x load max over mean
-MOE_STAT_ROWS = 2
+# (``pipelined_ragged_step``): assignments, 1000 x load max over mean,
+# experts that took a row
+MOE_STAT_ROWS = 3
 
 _KV_QMAX = {jnp.dtype(jnp.int8): 127.0,
             jnp.dtype(jnp.float8_e4m3fn): 448.0}
@@ -121,23 +123,24 @@ def _write_kv(kv_layer, k, v, batch: RaggedBatch, block_size: int,
 
 
 def _query_tiles(kv, batch: RaggedBatch, block_size: int,
-                 max_blocks_per_seq: int):
+                 max_blocks_per_seq: int, window=None):
     """The step's query tiles for the Pallas kernel
     (``ops/paged_attention.query_tiles``): built once a step, outside
     the layer scan, block-table rows gathered per tile.  ``kv``: the
     cache, stacked ``[L, rows, ...]`` or one layer's ``[rows, ...]``
-    (its last row is the trash row either way)."""
+    (its last row is the trash row either way).  ``window``: the
+    model's attention window, where it has window layers."""
     from ..ops.paged_attention import query_tiles
 
     return query_tiles(batch.seq_slot, batch.positions, batch.token_valid,
                        batch.block_tables, block_size, max_blocks_per_seq,
-                       trash=_kv_parts(kv)[0].shape[-5] - 1)
+                       trash=_kv_parts(kv)[0].shape[-5] - 1, window=window)
 
 
 def _paged_attention_pallas(kv_layer, q, batch: RaggedBatch,
                             block_size: int, max_blocks_per_seq: int,
                             scale: float, shard_mesh=None, slopes=None,
-                            layer=None, tiles=None):
+                            layer=None, tiles=None, window=None):
     """Pallas streaming kernel behind the same signature
     (ops/paged_attention.py — reference: blocked_flash).
 
@@ -149,15 +152,16 @@ def _paged_attention_pallas(kv_layer, q, batch: RaggedBatch,
     ``layer``: ``(base, rows)`` of the layer inside a stacked pool, see
     the kernel.  ``tiles``: ``_query_tiles`` of the step, which
     ``ragged_forward`` builds once for all its layers (``None``: built
-    here, for a caller with one layer)."""
+    here, for a caller with one layer).  ``window``: a window layer's
+    window (the kernel skips what lies behind it)."""
     from ..ops.paged_attention import paged_attention
 
     if tiles is None:
         tiles = _query_tiles(kv_layer, batch, block_size,
-                             max_blocks_per_seq)
+                             max_blocks_per_seq, window)
     if shard_mesh is None:
         return paged_attention(kv_layer, q, tiles, scale, slopes=slopes,
-                               layer=layer)
+                               layer=layer, window=window)
     from jax.sharding import PartitionSpec as P
 
     from ..comm.mesh import TENSOR_AXIS
@@ -177,7 +181,7 @@ def _paged_attention_pallas(kv_layer, q, batch: RaggedBatch,
     f = shard_map(
         lambda kvl, qq, tl, b, *sl: paged_attention(
             kvl, qq, tl, scale, slopes=sl[0] if sl else None,
-            layer=(b, rows)),
+            layer=(b, rows), window=window),
         mesh=shard_mesh,
         in_specs=tuple(in_specs),
         out_specs=q_spec, check_vma=False)
@@ -193,7 +197,7 @@ _ONE_SHOT_GATHER_BYTES = 512 * 1024 * 1024
 
 def _paged_attention(kv_layer, q, batch: RaggedBatch, block_size: int,
                      max_blocks_per_seq: int, scale: float, slopes=None,
-                     layer=None):
+                     layer=None, window=None):
     """Per-token attention over the owning sequence's context
     (reference kernel: blocked_flash / flash_attn_by_atoms).
 
@@ -204,7 +208,8 @@ def _paged_attention(kv_layer, q, batch: RaggedBatch, block_size: int,
     accumulator instead (memory ∝ T·block_size, not T·context).  The
     Pallas streaming variant (``_paged_attention_pallas``) drops in
     behind the same signature (``InferenceEngine.attn_impl`` says which
-    one an engine runs).
+    one an engine runs).  ``window``: a window layer's window; both XLA
+    formulations mask what lies behind it (the kernel skips it).
     """
     T, H, D = q.shape
     data, scales = _kv_parts(kv_layer)
@@ -214,7 +219,8 @@ def _paged_attention(kv_layer, q, batch: RaggedBatch, block_size: int,
     if gather_bytes > _ONE_SHOT_GATHER_BYTES:
         return _paged_attention_chunked(kv_layer, q, batch, block_size,
                                         max_blocks_per_seq, scale,
-                                        slopes=slopes, layer=layer)
+                                        slopes=slopes, layer=layer,
+                                        window=window)
     rep = H // Hkv
 
     tables = _layer_tables(
@@ -235,6 +241,8 @@ def _paged_attention(kv_layer, q, batch: RaggedBatch, block_size: int,
         s = s + (slopes.reshape(Hkv, rep)[None, :, :, None]
                  * cols[:, None, None, :].astype(jnp.float32))
     valid = cols <= batch.positions[:, None]                       # [T, C]
+    if window is not None:
+        valid &= cols > batch.positions[:, None] - window
     s = jnp.where(valid[:, None, None, :], s, -1e30)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     o = jnp.einsum("thrc,tchd->thrd", p, v_ctx)
@@ -243,7 +251,8 @@ def _paged_attention(kv_layer, q, batch: RaggedBatch, block_size: int,
 
 def _paged_attention_chunked(kv_layer, q, batch: RaggedBatch,
                              block_size: int, max_blocks_per_seq: int,
-                             scale: float, slopes=None, layer=None):
+                             scale: float, slopes=None, layer=None,
+                             window=None):
     """Streaming XLA paged attention: scan over the block-table columns,
     gathering ONE context block per step ([T, bs, 2, Hkv, D]) and folding
     it into an online-softmax accumulator — same numerics as the
@@ -275,6 +284,8 @@ def _paged_attention_chunked(kv_layer, q, batch: RaggedBatch,
             s = s + (slopes.reshape(Hkv, rep)[None, :, :, None]
                      * cols[:, None, None, :].astype(jnp.float32))
         valid = cols <= batch.positions[:, None]    # [T, bs]
+        if window is not None:
+            valid &= cols > batch.positions[:, None] - window
         s = jnp.where(valid[:, None, None, :], s, -1e30)
         m_new = jnp.maximum(m, s.max(axis=-1))
         p = jnp.exp(s - m_new[..., None])
@@ -344,9 +355,10 @@ def _mm(x, w, dt, contract_dims: int = 1):
     return y.reshape(*x.shape[:-1], *wshape[contract_dims:])
 
 
-def _qkv_proj(cfg, ap, h, dt, cos, sin, positions):
+def _qkv_proj(cfg, ap, h, dt, cos, sin, positions, kind: str = "full"):
     """Shared qkv projection + biases + rotary for the serving forwards
-    (ragged step and decode burst)."""
+    (ragged step and decode burst).  ``kind``: the layer's attention
+    kind, which says whether it takes the rotary embedding."""
     q = _mm(h, ap["wq"], dt)
     k = _mm(h, ap["wk"], dt)
     v = _mm(h, ap["wv"], dt)
@@ -355,9 +367,9 @@ def _qkv_proj(cfg, ap, h, dt, cos, sin, positions):
         k = k + ap["bk"].astype(dt)
         v = v + ap["bv"].astype(dt)
     if cfg.qk_norm:
-        q = L.qk_rmsnorm(ap["q_norm"], q, cfg.eps)
-        k = L.qk_rmsnorm(ap["k_norm"], k, cfg.eps)
-    if cfg.position == "rope":
+        q = _qk_norm(cfg, ap["q_norm"], q)
+        k = _qk_norm(cfg, ap["k_norm"], k)
+    if cfg.rope_on(kind):
         # apply_rope expects [B, S, H, D]; B=1 with per-token positions
         q = L.apply_rope(q[None], cos, sin, positions=positions[None])[0]
         k = L.apply_rope(k[None], cos, sin, positions=positions[None])[0]
@@ -373,9 +385,13 @@ def _dense_weight(w) -> bool:
 
 
 def _ffn(cfg, lp, h, dt, act, comm: Optional[ServingComm] = None,
-         valid=None, sharded: bool = False, experts=None):
+         valid=None, sharded: bool = False, experts=None,
+         routing: bool = False):
     """Shared MLP / MoE branch of a serving layer → ``(d, moe_stats)``,
-    ``moe_stats`` None for a dense layer.
+    ``moe_stats`` None for a dense layer (a dense model's, or a leading
+    dense layer of a sparse one: the layer that has ``lp["mlp"]``).
+    With ``routing`` an expert layer's is ``(moe_stats, the experts each
+    row took [T, top_k])``.
 
     Sparse experts are served dropless, by construction and with no
     option (``parallel/moe.py`` ``moe_serve``): ``valid`` marks the real
@@ -391,18 +407,23 @@ def _ffn(cfg, lp, h, dt, act, comm: Optional[ServingComm] = None,
     GSPMD would otherwise run serially after it — goes through the
     T3-style tile-decomposed matmul+allreduce instead
     (comm/overlap.py; bitwise-identical on the default exact rung)."""
-    if cfg.num_experts > 1:
+    if "mlp" not in lp:
         from ..models.transformer import _shared_expert
         from ..parallel import moe as M
 
         stack, layer = experts or (lp["experts"], None)
-        d, stats = M.moe_serve(
+        d, *stats = M.moe_serve(
             lp["gate"], stack, h, valid, top_k=cfg.moe_top_k,
             activation=act, gated=cfg.gated_mlp,
             norm_topk=cfg.moe_norm_topk, layer=layer,
-            kernel=jax.default_backend() == "tpu" and not sharded)
-        if "shared" in lp:       # qwen2-moe sigmoid-gated shared expert
-            d = d + _shared_expert(lp["shared"], h, act, cfg.gated_mlp)
+            kernel=jax.default_backend() == "tpu" and not sharded,
+            score=cfg.moe_score, route_scale=cfg.moe_route_scale,
+            with_ids=routing)
+        stats = tuple(stats) if routing else stats[0]
+        if "shared" in lp:       # the dense expert every token takes
+            with jax.named_scope("moe_shared"):
+                d = d + _shared_expert(lp["shared"], h, act,
+                                       cfg.gated_mlp)
         return d, stats
     mp = lp["mlp"]
     u = _mm(h, mp["wi"], dt)
@@ -432,14 +453,27 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
                    mixed_gemm: bool = False,
                    comm: Optional[ServingComm] = None,
                    with_moe_stats: bool = False,
+                   with_routing: bool = False,
                    ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """→ (last_token_logits [max_seqs, vocab], new_kv), and with
-    ``with_moe_stats`` (sparse-expert models) a third: ``[2] i32``, the
-    step's expert assignments summed over the layers and 1000 x its
-    worst layer's fullest-expert-over-mean (``moe_serve``).
+    ``with_moe_stats`` (sparse-expert models) a third: ``[3] i32``, the
+    step's expert assignments summed over the layers, 1000 x its worst
+    layer's fullest-expert-over-mean and the experts that took a row,
+    summed over the layers (``moe_serve``).  With ``with_routing``
+    (sparse-expert models) a last: ``[expert layers, T, top_k] i32``,
+    the experts each row of the step took (a row that pads the step's
+    bucket: ``num_experts``), for a consumer that has to know the
+    router's choice (a comparison that follows it, a router's analysis).
 
     ``kv``: [L, blocks, bs, 2, Hkv, D].  Rows of the logits output whose
     ``batch.logits_idx`` is -1 are garbage (callers mask by it).
+
+    The layers run as ``cfg.layer_plan`` says (``models/transformer.py``
+    ``apply`` reads the same): the leading dense layers one by one, ONE
+    scan over the whole periods of ``cfg.layer_pattern`` whose body
+    holds a period's layers, each of a static kind (a window layer and
+    a full layer call different kernels), then a last period cut short.
+    A model of one block type is a period of one layer.
 
     Every path is position-absolute: a batch whose tokens START at a
     nonzero context offset (chunked SplitFuse prefill — and, same
@@ -468,6 +502,10 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
     of as GSPMD's serial collectives (docs/SERVING.md "Overlapped &
     quantized collectives").
     """
+    if (quant is not None or stream is not None) and not cfg.plain_stack:
+        raise NotImplementedError(
+            "weight quantization and the NVMe weight stream serve a model "
+            "of one block type (TransformerConfig.plain_stack)")
     if quant is not None:
         from .quantization import merge_layer
         from ..ops.quant import dequantize_any
@@ -483,6 +521,8 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
              else 1.0 / (cfg.head_dim ** 0.5))
 
     x = L.embed(embed_tab, batch.token_ids).astype(dt)             # [T, dm]
+    if cfg.embed_scale is not None:
+        x = x * jnp.asarray(cfg.embed_scale, dt)
     if cfg.embed_norm:                  # bloom word_embeddings_layernorm
         x = norm(params["ln_embed"], x)
     slopes = None
@@ -518,36 +558,51 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
         experts = blocks["experts"]
         blocks = {k: v for k, v in blocks.items() if k != "experts"}
 
-    def block(x, lp, pool, layer, li):
+    def block(x, lp, pool, layer, li, kind):
         """One layer's mathematics.  ``pool`` is the stacked paged
         cache, which holds the layer where ``layer`` says
-        (``_layer_of``).  ``li``: the layer's index, for weights kept
-        stacked."""
+        (``_layer_of``).  ``li``: the layer's index in ``blocks``, for
+        weights kept stacked.  ``kind``: its attention kind, static."""
         ap = lp["attn"]
+        window = cfg.attn_window if kind == "window" else None
         # named scopes at the block's seams (metadata only): a device
         # trace's operations carry them in their JAX path, which is how
         # a reader finds what the cache write or the sampler costs
         with jax.named_scope("qkv"):
             h = norm(lp["ln1"], x)
             q, k, v = _qkv_proj(cfg, ap, h, dt, cos, sin,
-                                batch.positions)
+                                batch.positions, kind)
+            if cfg.attn_gate:
+                with jax.named_scope("attn_gate"):
+                    g = _mm(h, ap["wg"], dt)
         with jax.named_scope("kv_write"):
             pool = _write_kv(pool, k, v, batch, block_size, layer=layer)
-        with jax.named_scope("attn"):
+        # a window layer's calls under a scope of their own (inside
+        # ``attn``): a trace tells its kernel from the full layers'
+        with jax.named_scope("attn"), (
+                jax.named_scope("attn_window") if window
+                else contextlib.nullcontext()):
             if attn_impl == "pallas":
                 o = _paged_attention_pallas(
                     pool, q, batch, block_size, max_blocks_per_seq,
                     scale, shard_mesh=shard_mesh, slopes=slopes,
-                    layer=layer, tiles=tiles)
+                    layer=layer, tiles=tiles, window=window)
             else:
                 o = _paged_attention(pool, q, batch, block_size,
                                      max_blocks_per_seq, scale,
-                                     slopes=slopes, layer=layer)
+                                     slopes=slopes, layer=layer,
+                                     window=window)
         with jax.named_scope("attn_out"):
+            if cfg.attn_gate:
+                with jax.named_scope("attn_gate"):
+                    o = o * jax.nn.sigmoid(
+                        g.astype(jnp.float32)).astype(o.dtype)
             o = _mm(o.reshape(o.shape[0], -1), ap["wo"], dt,
                     contract_dims=2)
             if cfg.attn_out_bias:
                 o = o + ap["bo"].astype(dt)
+            if cfg.sandwich_norm:
+                o = norm(lp["ln1_post"], o)
         with jax.named_scope("ffn"):
             if not cfg.parallel_block:
                 x = x + o
@@ -559,39 +614,101 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
                             valid=batch.token_valid,
                             sharded=shard_mesh is not None,
                             experts=None if experts is None
-                            else (experts, li))
+                            else (experts, li), routing=with_routing)
+            if cfg.sandwich_norm:
+                d = norm(lp["ln2_post"], d)
         if cfg.parallel_block:
             return x + o + d, pool, stats
         return x + d, pool, stats
 
-    # what the Pallas kernel's grid walks is the same for every layer:
-    # cut the batch into query tiles here, once, outside the scan
-    tiles = (_query_tiles(kv, batch, block_size, max_blocks_per_seq)
+    pattern = cfg.layer_pattern
+    P = len(pattern)
+    lead, periods, tail = cfg.layer_plan
+    # what the Pallas kernel's grid walks is the same for every layer of
+    # a kind: cut the batch into query tiles here, once, outside the scan
+    tiles = (_query_tiles(kv, batch, block_size, max_blocks_per_seq,
+                          cfg.attn_window if "window" in pattern else None)
              if attn_impl == "pallas" else None)
-    layer_ids = jnp.arange(cfg.num_layers, dtype=jnp.int32)
-    layers = ((layer_ids,) if stream is not None
-              else (blocks, layer_ids))
     rows = _kv_parts(kv)[0].shape[1]       # a layer's blocks + trash row
+
+    def at(li):
+        """Where layer ``li`` of ``blocks`` keeps its rows in the pool
+        (behind the leading dense layers')."""
+        return ((li + lead if lead else li) * rows, rows)
+
+    def outside(x, pool, stack, first, n, layer0):
+        """``n`` layers of ``stack`` from its row ``first``, one by one
+        (the leading dense layers, a last period cut short).
+        ``layer0``: the first one's index in the model."""
+        stats = []
+        for i in range(n):
+            x, pool, st = block(
+                x, jax.tree.map(lambda a: a[first + i], stack), pool,
+                ((layer0 + i) * rows, rows), first + i,
+                cfg.layer_kinds[layer0 + i])
+            stats.append(st)
+        return x, pool, stats
 
     def carried(carry, ws):
         # the cache rides the scan as a carry that each layer updates in
         # place, and the layer is an offset into the stacked pool (no
-        # per-layer slice, no second pool)
+        # per-layer slice, no second pool).  The body holds one period
+        # of the layer pattern, each layer of a static kind
         x, pool = carry
-        lp, li = layer_weights(ws)
-        x, pool, stats = block(x, lp, pool, (li * rows, rows), li)
-        return (x, pool), stats
+        if P == 1:
+            # a period of one layer is that layer: the general path
+            # below gives the same numbers, but another compiled program
+            # for every model the system served before it had a pattern
+            lp, li = layer_weights(ws)
+            x, pool, stats = block(x, lp, pool, at(li), li, pattern[0])
+            return (x, pool), stats
+        stats = []
+        for j, kind in enumerate(pattern):
+            lp, li = layer_weights(jax.tree.map(lambda a: a[j], ws))
+            x, pool, st = block(x, lp, pool, at(li), li, kind)
+            stats.append(st)
+        return (x, pool), (None if stats[0] is None else jax.tree.map(
+            lambda *v: jnp.stack(v), *stats))
 
+    def periods_of(a):
+        """``a``'s rows of the whole periods: a layer a row, or with a
+        longer period a period a row."""
+        a = a if not tail else a[:periods * P]
+        return a if P == 1 else a.reshape((periods, P) + a.shape[1:])
+
+    layer_ids = periods_of(jnp.arange(cfg.num_layers - lead,
+                                      dtype=jnp.int32))
+    layers = ((layer_ids,) if stream is not None
+              else (jax.tree.map(periods_of, blocks), layer_ids))
     pool = jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), kv)
+    outside_stats = []
+    if lead:
+        x, pool, _ = outside(x, pool, params["dense_blocks"], 0, lead, 0)
     (x, pool), stats = jax.lax.scan(carried, (x, pool), layers)
+    if tail:
+        x, pool, outside_stats = outside(x, pool, blocks, periods * P,
+                                         tail, lead + periods * P)
     new_kv = jax.tree.map(lambda a, o: a.reshape(o.shape), pool, kv)
 
     with jax.named_scope("unembed"):
         logits = _unembed(cfg, params, embed_tab, x, batch, norm, dt, comm)
-    if with_moe_stats:       # per-layer [L, 2] -> the step's [2]
-        return logits, new_kv, jnp.stack([stats[:, 0].sum(),
-                                          stats[:, 1].max()])
-    return logits, new_kv
+    out = (logits, new_kv)
+    if with_moe_stats or with_routing:
+        if P > 1:
+            # the scan's [periods, P, ...] and the tail's -> [layers, ...]
+            stats = jax.tree.map(
+                lambda a, *tail: jnp.concatenate(
+                    [a.reshape((-1,) + a.shape[2:])]
+                    + [t[None] for t in tail]),
+                stats, *outside_stats)
+        if with_routing:
+            stats, ids = stats
+        if with_moe_stats:       # per-layer [L, 3] -> the step's [3]
+            out += (jnp.stack([stats[:, 0].sum(), stats[:, 1].max(),
+                               stats[:, 2].sum()]),)
+        if with_routing:
+            out += (ids,)
+    return out
 
 
 def _unembed(cfg, params, embed_tab, x, batch, norm, dt, comm):
@@ -753,6 +870,10 @@ def decode_burst_forward(cfg: TransformerConfig, params, prefix,
     over the prefix (masked by base_ctx) and (b) attention over the
     in-burst tail (masked by iteration) — no concatenation, the prefix
     is never copied."""
+    if not cfg.plain_stack:
+        raise NotImplementedError(
+            "decode bursts serve a model of one block type "
+            "(TransformerConfig.plain_stack)")
     pdata, pscales = _kv_parts(prefix)
     nL = pdata.shape[0]
     S, P = pdata.shape[1], pdata.shape[2]

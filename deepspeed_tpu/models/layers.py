@@ -157,12 +157,16 @@ def apply_rope(x, cos, sin, positions=None):
 
 def causal_attention(q, k, v, mask: Optional[jnp.ndarray] = None,
                      scale: Optional[float] = None, causal: bool = True,
-                     bias: Optional[jnp.ndarray] = None):
+                     bias: Optional[jnp.ndarray] = None,
+                     window: Optional[int] = None):
     """q: [B, S, H, D]; k/v: [B, Sk, Hkv, D].  GQA via grouped einsum — KV
     are never materialized at full head count, preserving the memory GQA
     exists to save.  Softmax in fp32 for stability; XLA fuses the block
     onto the MXU.  ``causal=False`` gives bidirectional attention.
-    ``bias``: additive attention bias [H, S|1, Sk] (ALiBi et al.)."""
+    ``bias``: additive attention bias [H, S|1, Sk] (ALiBi et al.).
+    ``window``: a query sees only its last ``window`` keys, its own
+    among them (a mask over the full score matrix; serving skips the
+    blocks, ops/paged_attention.py)."""
     B, S, H, D = q.shape
     Sk, Hkv = k.shape[1], k.shape[2]
     rep = H // Hkv
@@ -175,6 +179,8 @@ def causal_attention(q, k, v, mask: Optional[jnp.ndarray] = None,
             Hkv, rep, bias.shape[-2], Sk)[None]
     if causal:
         keep = jnp.tril(jnp.ones((S, Sk), bool), k=Sk - S)
+        if window is not None:
+            keep &= ~jnp.tril(jnp.ones((S, Sk), bool), k=Sk - S - window)
         logits = jnp.where(keep[None, None, None], logits, -1e30)
     if mask is not None:                        # [B, Sk] padding mask
         logits = jnp.where(mask[:, None, None, None, :].astype(bool),

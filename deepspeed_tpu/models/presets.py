@@ -241,6 +241,53 @@ PRESETS: Dict[str, dict] = {
                         attn_bias=False, mlp_bias=False, eps=1e-5,
                         qk_norm=True, num_experts=64, moe_top_k=8,
                         moe_norm_topk=False),
+    # --- Trinity (afmoe: three window layers of 2048 then one full layer
+    # without positions; 32 query heads of 128 over 4 KV heads on a
+    # hidden size of 2048; an RMSNorm per head on q and k; a sigmoid
+    # output gate; four norms a layer; 128 sigmoid-scored experts, top-8
+    # chosen with a bias that the weights leave out, renormalised and
+    # scaled by 2.826, beside an ungated shared expert; two leading dense
+    # layers of width 6144; embeddings scaled by sqrt(d) —
+    # arcee-ai/Trinity-Mini config.json + modeling_afmoe.py).  The
+    # published ``layer_types`` repeat (window, window, window, full)
+    # from layer 0 and the two dense layers are its first two; the
+    # pattern here starts BEHIND the dense layers (which are of its
+    # first kind), so at the published depth it reads (window, full,
+    # window, window): seven periods and two layers of an eighth ------
+    "trinity-tiny": dict(vocab_size=1024, num_layers=9, d_model=64,
+                         num_heads=4, num_kv_heads=2, head_dim=32,
+                         d_ff=96, moe_d_ff=32, max_seq_len=256,
+                         activation="silu", gated_mlp=True, norm="rmsnorm",
+                         position="rope", rope_theta=10000.0,
+                         rope_kinds=("window",), tie_embeddings=False,
+                         attn_bias=False, mlp_bias=False, eps=1e-5,
+                         qk_norm=True, qk_norm_form="head", attn_gate=True,
+                         sandwich_norm=True, embed_scale=8.0,
+                         layer_pattern=("window", "window", "window",
+                                        "full"),
+                         attn_window=16, num_dense_layers=1,
+                         num_experts=8, moe_top_k=2, moe_shared_ff=32,
+                         moe_shared_gate=False, moe_score="sigmoid",
+                         moe_select_bias=True, moe_norm_topk=True,
+                         moe_route_scale=2.826, moe_dispatch="ragged",
+                         attention_impl="xla"),
+    "trinity-mini": dict(vocab_size=200192, num_layers=32, d_model=2048,
+                         num_heads=32, num_kv_heads=4, head_dim=128,
+                         d_ff=6144, moe_d_ff=1024, max_seq_len=131072,
+                         activation="silu", gated_mlp=True, norm="rmsnorm",
+                         position="rope", rope_theta=10000.0,
+                         rope_kinds=("window",), tie_embeddings=False,
+                         attn_bias=False, mlp_bias=False, eps=1e-5,
+                         qk_norm=True, qk_norm_form="head", attn_gate=True,
+                         sandwich_norm=True, embed_scale=2048 ** 0.5,
+                         layer_pattern=("window", "full", "window",
+                                        "window"),
+                         attn_window=2048, num_dense_layers=2,
+                         num_experts=128, moe_top_k=8, moe_shared_ff=1024,
+                         moe_shared_gate=False, moe_score="sigmoid",
+                         moe_select_bias=True, moe_norm_topk=True,
+                         moe_route_scale=2.826, moe_dispatch="ragged",
+                         attention_impl="xla"),
     # --- Megatron-GPT (gpt2 architecture, megatron-lm checkpoint naming
     # with per-head-interleaved fused QKV — reference:
     # module_inject/containers/megatron_gpt.py) ---------------------------

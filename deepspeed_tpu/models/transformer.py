@@ -38,6 +38,9 @@ class TransformerConfig:
     d_model: int = 768
     num_heads: int = 12
     num_kv_heads: Optional[int] = None        # None => MHA
+    # a head's size; None => d_model // num_heads (trinity: 32 heads of
+    # 128 over d_model 2048, so H * D is 4096 and not d_model)
+    head_dim: Optional[int] = None
     d_ff: Optional[int] = None                # None => 4*d_model (or 8/3 gated)
     max_seq_len: int = 1024
     activation: str = "gelu"
@@ -49,6 +52,31 @@ class TransformerConfig:
     # olmoe / olmo-2: RMSNorm with a learned scale over the WHOLE q and
     # the whole k projection (all heads together), before rotary
     qk_norm: bool = False
+    # "projection": that whole-projection norm.  "head" (trinity/afmoe):
+    # an RMSNorm per head over head_dim, ONE learned [head_dim] scale for
+    # all the query heads and one for the key heads
+    qk_norm_form: str = "projection"
+    # --- the layer pattern ------------------------------------------------
+    # the attention kind of each layer of one period: "full" (every key
+    # up to the query) | "window" (the last ``attn_window`` keys, the
+    # query's own among them).  The layers behind the leading dense ones
+    # repeat it; a last period may be cut short.  The leading dense
+    # layers are of the period's first kind
+    layer_pattern: Tuple[str, ...] = ("full",)
+    attn_window: Optional[int] = None
+    # sparse-expert models: this many FIRST layers keep a dense MLP of
+    # width d_ff (their weights: params["dense_blocks"])
+    num_dense_layers: int = 0
+    # the layer kinds whose q and k get the rotary embedding; None: all
+    # (trinity: window layers only, full layers carry no positions)
+    rope_kinds: Optional[Tuple[str, ...]] = None
+    # sigmoid(h Wg) [H * D] multiplied into the attention output before
+    # the output projection
+    attn_gate: bool = False
+    # four norms a layer: x + N(attn(N(x))), then x + N(ffn(N(x)))
+    sandwich_norm: bool = False
+    # the embedding's output is multiplied by this (trinity: sqrt(d_model))
+    embed_scale: Optional[float] = None
     # bloom: layernorm applied to the word embeddings before the stack
     embed_norm: bool = False
     # parallel residual: x + attn(ln(x)) + mlp(ln(x)), one shared norm
@@ -82,6 +110,19 @@ class TransformerConfig:
     # qwen2-moe: a dense "shared expert" MLP of this width runs on every
     # token, sigmoid-gated, added to the routed output; None disables
     moe_shared_ff: Optional[int] = None
+    # False: the shared expert's output is added as it is (trinity)
+    moe_shared_gate: bool = True
+    # an expert's width; None => d_ff (a model with leading dense layers
+    # has both: d_ff is the dense MLP's)
+    moe_d_ff: Optional[int] = None
+    # the router's scores: softmax over the experts | sigmoid of each
+    moe_score: str = "softmax"
+    # a learned per-expert bias added to the scores for the CHOICE of the
+    # top-k only; the weights are the unbiased scores (the bias update
+    # that balances load in training is not implemented)
+    moe_select_bias: bool = False
+    # the chosen weights are multiplied by this after renormalisation
+    moe_route_scale: float = 1.0
     # renormalize kept top-k gate weights to sum 1 (mixtral yes;
     # qwen2-moe norm_topk_prob=False keeps raw softmax probabilities)
     moe_norm_topk: bool = True
@@ -107,12 +148,51 @@ class TransformerConfig:
                 self.d_ff = 256 * ((raw + 255) // 256)
             else:
                 self.d_ff = 4 * self.d_model
-        assert self.d_model % self.num_heads == 0
+        if self.head_dim is None:
+            assert self.d_model % self.num_heads == 0
+            self.head_dim = self.d_model // self.num_heads
         assert self.num_heads % self.num_kv_heads == 0
+        self.layer_pattern = tuple(self.layer_pattern)
+        assert set(self.layer_pattern) <= {"full", "window"}
+        assert "window" not in self.layer_pattern or self.attn_window
+        assert self.qk_norm_form in ("projection", "head")
+        assert self.moe_score in ("softmax", "sigmoid")
+        assert 0 <= self.num_dense_layers <= self.num_layers
+        assert self.num_dense_layers == 0 or self.num_experts > 1
+        if self.moe_d_ff is None:
+            self.moe_d_ff = self.d_ff
 
     @property
-    def head_dim(self) -> int:
-        return self.d_model // self.num_heads
+    def layer_kinds(self) -> Tuple[str, ...]:
+        """The attention kind of every layer, first to last."""
+        p = self.layer_pattern
+        return (p[0],) * self.num_dense_layers + tuple(
+            p[i % len(p)] for i in range(self.num_layers
+                                         - self.num_dense_layers))
+
+    @property
+    def layer_plan(self) -> Tuple[int, int, int]:
+        """``(lead, periods, tail)``: the leading dense layers, the whole
+        periods of ``layer_pattern`` behind them (the layer scan's trip
+        count) and the layers of a last period cut short.  Both forwards
+        read their structure from this."""
+        rest = self.num_layers - self.num_dense_layers
+        p = len(self.layer_pattern)
+        return self.num_dense_layers, rest // p, rest % p
+
+    @property
+    def plain_stack(self) -> bool:
+        """One block type and none of the per-layer mechanisms: what the
+        decode burst, the NVMe weight stream, ZeRO-Inference's weight
+        quantization and the pipeline stages were written for."""
+        return (self.layer_pattern == ("full",) and not self.num_dense_layers
+                and not self.attn_gate and not self.sandwich_norm
+                and self.embed_scale is None)
+
+    def rope_on(self, kind: str) -> bool:
+        """Whether a layer of attention kind ``kind`` rotates q and k."""
+        return self.position == "rope" and (
+            self.rope_kinds is None or kind in self.rope_kinds)
 
     @property
     def rotary_dim(self) -> int:
@@ -149,12 +229,13 @@ def init_params(cfg: TransformerConfig, key) -> Tuple[Dict, Dict]:
     keys = jax.random.split(key, 9)
     H, D, Hkv = cfg.num_heads, cfg.head_dim, cfg.num_kv_heads
     dm, dff, nl = cfg.d_model, cfg.d_ff, cfg.num_layers
+    lead = cfg.num_dense_layers
     out_scale = 1.0 / math.sqrt(dm) / math.sqrt(2.0 * nl)   # GPT-2 depth scaling
 
-    def stack_init(fn, key, *args, **kw):
-        """Init one layer's worth with per-layer keys, stacked on dim 0."""
-        ks = jax.random.split(key, nl)
-        outs = [fn(k, *args, **kw) for k in ks]
+    def stack_init(fn, key, n=nl - lead):
+        """Init ``n`` layers' worth with per-layer keys, stacked on dim 0."""
+        ks = jax.random.split(key, n)
+        outs = [fn(k) for k in ks]
         p0, a0 = outs[0]
         stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *[o[0] for o in outs])
         axes = jax.tree.map(lambda ax: ("layers",) + ax, a0,
@@ -174,9 +255,6 @@ def init_params(cfg: TransformerConfig, key) -> Tuple[Dict, Dict]:
         _ninit = (L.layernorm_init if cfg.norm == "layernorm"
                   else L.rmsnorm_init)
         params["ln_embed"], axes["ln_embed"] = _ninit(dm)
-
-    blk_p: Dict[str, Any] = {}
-    blk_a: Dict[str, Any] = {}
 
     # attention — fused qkv as separate heads-aware tensors
     def qkv_init(k):
@@ -204,44 +282,20 @@ def init_params(cfg: TransformerConfig, key) -> Tuple[Dict, Dict]:
             # ``k`` six ways above would re-seed every model's attention
             k5, k6 = jax.random.split(jax.random.fold_in(  # tpulint: disable=rng-discipline
                 k, 1))
-            p["q_norm"] = jax.random.uniform(k5, (H, D), minval=0.5,
-                                             maxval=1.5)
-            a["q_norm"] = ("heads", "head_dim")
-            p["k_norm"] = jax.random.uniform(k6, (Hkv, D), minval=0.5,
-                                             maxval=1.5)
-            a["k_norm"] = ("kv_heads", "head_dim")
+            per_head = cfg.qk_norm_form == "head"
+            p["q_norm"] = jax.random.uniform(
+                k5, (D,) if per_head else (H, D), minval=0.5, maxval=1.5)
+            a["q_norm"] = ("head_dim",) if per_head else ("heads", "head_dim")
+            p["k_norm"] = jax.random.uniform(
+                k6, (D,) if per_head else (Hkv, D), minval=0.5, maxval=1.5)
+            a["k_norm"] = ("head_dim",) if per_head \
+                else ("kv_heads", "head_dim")
+        if cfg.attn_gate:
+            p["wg"] = jax.random.normal(
+                jax.random.fold_in(k, 2),  # tpulint: disable=rng-discipline
+                (dm, H, D)) / math.sqrt(dm)
+            a["wg"] = ("embed", "heads", "head_dim")
         return p, a
-
-    blk_p["attn"], blk_a["attn"] = stack_init(qkv_init, keys[2])
-
-    if cfg.num_experts > 1:
-        from ..parallel import moe as M
-
-        blk_p["gate"], blk_a["gate"] = stack_init(
-            lambda k: M.gate_init(k, dm, cfg.num_experts), keys[7])
-        blk_p["experts"], blk_a["experts"] = stack_init(
-            lambda k: M.experts_init(k, cfg.num_experts, dm, dff,
-                                     gated=cfg.gated_mlp,
-                                     out_scale=out_scale), keys[3])
-        if cfg.moe_shared_ff:        # qwen2-moe dense shared expert
-            sff = cfg.moe_shared_ff
-
-            def shared_init(k):
-                k1, k2, k3, k4 = jax.random.split(k, 4)
-                p = {"wi": jax.random.normal(k1, (dm, sff))
-                     / math.sqrt(dm),
-                     "wo": jax.random.normal(k2, (sff, dm)) * out_scale}
-                a = {"wi": ("embed", "mlp"), "wo": ("mlp", "embed")}
-                if cfg.gated_mlp:
-                    p["wg"] = jax.random.normal(k3, (dm, sff)) \
-                        / math.sqrt(dm)
-                    a["wg"] = ("embed", "mlp")
-                p["gate"] = jax.random.normal(k4, (dm, 1)) / math.sqrt(dm)
-                a["gate"] = ("embed", None)
-                return p, a
-
-            blk_p["shared"], blk_a["shared"] = stack_init(
-                shared_init, keys[8])
 
     def mlp_init(k):
         k1, k2, k3 = jax.random.split(k, 3)
@@ -258,18 +312,94 @@ def init_params(cfg: TransformerConfig, key) -> Tuple[Dict, Dict]:
             p["bo"] = jnp.zeros((dm,)); a["bo"] = ("embed",)
         return p, a
 
-    if cfg.num_experts <= 1:
+    norm_init = L.layernorm_init if cfg.norm == "layernorm" else L.rmsnorm_init
+
+    def norms_init(n):
+        """``n`` layers' norms, stacked: scales at one (the key only
+        shapes the stack)."""
+        names = ["ln1"]
+        if not cfg.parallel_block or cfg.parallel_separate_norms:
+            names.append("ln2")
+        if cfg.sandwich_norm:
+            names += ["ln1_post", "ln2_post"]
+        made = {name: stack_init(lambda k: norm_init(dm), keys[4], n)
+                for name in names}
+        return ({k: v[0] for k, v in made.items()},
+                {k: v[1] for k, v in made.items()})
+
+    blk_p: Dict[str, Any] = {}
+    blk_a: Dict[str, Any] = {}
+    blk_p["attn"], blk_a["attn"] = stack_init(qkv_init, keys[2])
+
+    if cfg.num_experts > 1:
+        from ..parallel import moe as M
+
+        def gate_init(k):
+            p, a = M.gate_init(k, dm, cfg.num_experts)
+            if cfg.moe_select_bias:
+                # seeded away from zero, as a trained router's is: a
+                # forward that added it to the weights, or left it out
+                # of the choice, would otherwise agree with one that
+                # uses it as published.  Small beside the scores' own
+                # spread (0.1 at these logits): at +-0.1 it, not the
+                # token, chose the experts, the fullest took 7.6 times
+                # the mean and a third of them no token at all
+                p["bias"] = jax.random.uniform(
+                    jax.random.fold_in(k, 1),  # tpulint: disable=rng-discipline
+                    (cfg.num_experts,), minval=-0.02, maxval=0.02)
+                a["bias"] = (None,)
+            return p, a
+
+        blk_p["gate"], blk_a["gate"] = stack_init(gate_init, keys[7])
+        blk_p["experts"], blk_a["experts"] = stack_init(
+            lambda k: M.experts_init(k, cfg.num_experts, dm, cfg.moe_d_ff,
+                                     gated=cfg.gated_mlp,
+                                     out_scale=out_scale), keys[3])
+        if cfg.moe_shared_ff:        # a dense expert every token takes
+            sff = cfg.moe_shared_ff
+
+            def shared_init(k):
+                k1, k2, k3, k4 = jax.random.split(k, 4)
+                p = {"wi": jax.random.normal(k1, (dm, sff))
+                     / math.sqrt(dm),
+                     "wo": jax.random.normal(k2, (sff, dm)) * out_scale}
+                a = {"wi": ("embed", "mlp"), "wo": ("mlp", "embed")}
+                if cfg.gated_mlp:
+                    p["wg"] = jax.random.normal(k3, (dm, sff)) \
+                        / math.sqrt(dm)
+                    a["wg"] = ("embed", "mlp")
+                if cfg.moe_shared_gate:     # qwen2-moe's sigmoid gate
+                    p["gate"] = jax.random.normal(k4, (dm, 1)) \
+                        / math.sqrt(dm)
+                    a["gate"] = ("embed", None)
+                return p, a
+
+            blk_p["shared"], blk_a["shared"] = stack_init(
+                shared_init, keys[8])
+    else:
         blk_p["mlp"], blk_a["mlp"] = stack_init(mlp_init, keys[3])
 
-    norm_init = L.layernorm_init if cfg.norm == "layernorm" else L.rmsnorm_init
-    blk_p["ln1"], blk_a["ln1"] = stack_init(
-        lambda k: norm_init(dm), keys[4])
-    if not cfg.parallel_block or cfg.parallel_separate_norms:
-        blk_p["ln2"], blk_a["ln2"] = stack_init(
-            lambda k: norm_init(dm), keys[5])
-
+    for tree, part in zip((blk_p, blk_a), norms_init(nl - lead)):
+        tree.update(part)
     params["blocks"] = blk_p
     axes["blocks"] = blk_a
+
+    if lead:
+        # the leading dense layers: a stack of their own, so that every
+        # leaf of "blocks" keeps one leading size.  Their keys are
+        # streams off the stacks' keys: a model without such layers is
+        # seeded as before
+        def lead_key(i):
+            return jax.random.fold_in(keys[i], 7)  # tpulint: disable=rng-discipline
+
+        dp: Dict[str, Any] = {}
+        da: Dict[str, Any] = {}
+        dp["attn"], da["attn"] = stack_init(qkv_init, lead_key(2), lead)
+        dp["mlp"], da["mlp"] = stack_init(mlp_init, lead_key(3), lead)
+        for tree, part in zip((dp, da), norms_init(lead)):
+            tree.update(part)
+        params["dense_blocks"] = dp
+        axes["dense_blocks"] = da
 
     params["ln_f"], axes["ln_f"] = norm_init(dm)
     if not cfg.tie_embeddings:
@@ -312,24 +442,38 @@ def _norm(cfg):
 
 
 def _shared_expert(sp, h, act, gated: bool):
-    """qwen2-moe dense shared expert: a full MLP on every token, scaled
-    by a per-token sigmoid gate (reference analog: the qwen_v2_moe v2
-    model implementation's shared_expert path)."""
+    """The dense shared expert: a full MLP on every token, added to the
+    routed output.  qwen2-moe scales it by a per-token sigmoid gate
+    (reference analog: the qwen_v2_moe v2 model implementation's
+    shared_expert path); a model without that gate (trinity) has no
+    ``gate`` weight and adds it as it is."""
     dt = h.dtype
     u = h @ sp["wi"].astype(dt)
     u = act(h @ sp["wg"].astype(dt)) * u if "wg" in sp else act(u)
     d = u @ sp["wo"].astype(dt)
+    if "gate" not in sp:
+        return d
     g = jax.nn.sigmoid((h @ sp["gate"].astype(dt)).astype(jnp.float32))
     return d * g.astype(dt)
 
 
+def _qk_norm(cfg, scale, x):
+    """The config's QK-norm of q or k ``[..., heads, head_dim]``."""
+    if cfg.qk_norm_form == "head":
+        return L.rmsnorm({"scale": scale}, x, cfg.eps)
+    return L.qk_rmsnorm(scale, x, cfg.eps)
+
+
 def block_apply(cfg: TransformerConfig, lp, x, cos, sin,
                 mask=None, attention_fn: Callable = L.causal_attention,
-                rng=None, positions=None):
+                rng=None, positions=None, kind: str = "full",
+                dense: bool = False):
     """One decoder layer. lp: this layer's (unstacked) params.
     x: [B, S, dm].  ``positions``: optional [B, S] original token
     positions (random-LTD gathered subsequences keep their rotary
-    phases).  Returns (x, metrics) — metrics non-empty for MoE."""
+    phases).  ``kind``: the layer's attention kind (``layer_kinds``);
+    ``dense``: a leading dense layer of a sparse-expert model.  Both are
+    static.  Returns (x, metrics) — metrics non-empty for MoE."""
     norm = _norm(cfg)
     act = L.ACTIVATIONS[cfg.activation]
     ap = lp["attn"]
@@ -349,15 +493,24 @@ def block_apply(cfg: TransformerConfig, lp, x, cos, sin,
         k = k + ap["bk"].astype(dt)
         v = v + ap["bv"].astype(dt)
     if cfg.qk_norm:
-        q = L.qk_rmsnorm(ap["q_norm"], q, cfg.eps)
-        k = L.qk_rmsnorm(ap["k_norm"], k, cfg.eps)
-    if cfg.position == "rope":
+        q = _qk_norm(cfg, ap["q_norm"], q)
+        k = _qk_norm(cfg, ap["k_norm"], k)
+    if cfg.rope_on(kind):
         q = L.apply_rope(q, cos, sin, positions=positions)
         k = L.apply_rope(k, cos, sin, positions=positions)
-    o = attention_fn(q, k, v, mask=mask)
+    if kind == "window":
+        # only the eager attention takes a window (_resolve_attention)
+        o = attention_fn(q, k, v, mask=mask, window=cfg.attn_window)
+    else:
+        o = attention_fn(q, k, v, mask=mask)
+    if cfg.attn_gate:
+        g = jnp.einsum("bsd,dhk->bshk", h, ap["wg"].astype(dt))
+        o = o * jax.nn.sigmoid(g.astype(jnp.float32)).astype(dt)
     o = jnp.einsum("bshk,hkd->bsd", o, ap["wo"].astype(dt))
     if cfg.attn_out_bias:
         o = o + ap["bo"].astype(dt)
+    if cfg.sandwich_norm:
+        o = norm(lp["ln1_post"], o)
 
     if not cfg.parallel_block:
         x = x + o
@@ -367,7 +520,7 @@ def block_apply(cfg: TransformerConfig, lp, x, cos, sin,
         h = norm(lp["ln2"], x)
     # parallel residual (falcon/phi): the MLP reads the same ln1 output
     metrics: Dict[str, Any] = {}
-    if cfg.num_experts > 1:
+    if cfg.num_experts > 1 and not dense:
         from ..parallel import moe as M
 
         d, metrics = M.moe_ffn(
@@ -376,8 +529,9 @@ def block_apply(cfg: TransformerConfig, lp, x, cos, sin,
             min_capacity=cfg.min_capacity, activation=act,
             gated=cfg.gated_mlp, rng=rng, noise_policy=cfg.noise_policy,
             dispatch_mode=cfg.moe_dispatch,
-            norm_topk=cfg.moe_norm_topk)
-        if "shared" in lp:       # qwen2-moe sigmoid-gated shared expert
+            norm_topk=cfg.moe_norm_topk, score=cfg.moe_score,
+            route_scale=cfg.moe_route_scale)
+        if "shared" in lp:       # the dense expert every token takes
             d = d + _shared_expert(lp["shared"], h, act, cfg.gated_mlp)
     else:
         mp = lp["mlp"]
@@ -391,6 +545,8 @@ def block_apply(cfg: TransformerConfig, lp, x, cos, sin,
         d = u @ mp["wo"].astype(dt)
         if cfg.mlp_bias:
             d = d + mp["bo"].astype(dt)
+    if cfg.sandwich_norm:
+        d = norm(lp["ln2_post"], d)
     if cfg.parallel_block:
         return x + o + d, metrics
     return x + d, metrics
@@ -405,6 +561,12 @@ def apply(cfg: TransformerConfig, params, input_ids, mask=None,
     with_aux=True; aux carries MoE load-balancing metrics averaged over
     layers).
 
+    The layers run as ``cfg.layer_plan`` says: the leading dense layers
+    one by one, then ONE scan over the whole periods of
+    ``cfg.layer_pattern`` whose body holds a period's layers, each of a
+    static kind, then the layers of a last period cut short.  A model of
+    one block type is a period of one layer.
+
     ``pld_theta``: progressive-layer-drop theta (traced scalar; layer i
     is dropped whole-batch with prob (i/L)(1-theta) — reference:
     progressive_layer_drop.py consumed by the BERT forward).
@@ -417,6 +579,8 @@ def apply(cfg: TransformerConfig, params, input_ids, mask=None,
     use, keep = pl.use, pl.keep
     dt = dtype or params["embed"]["table"].dtype
     x = L.embed(use("embed", params["embed"]), input_ids).astype(dt)
+    if cfg.embed_scale is not None:
+        x = x * jnp.asarray(cfg.embed_scale, dt)
     if cfg.embed_norm:
         x = _norm(cfg)(use("ln_embed", params["ln_embed"]), x)
     if cfg.position == "learned":
@@ -452,16 +616,20 @@ def apply(cfg: TransformerConfig, params, input_ids, mask=None,
 
     layer_rngs = (jax.random.split(rng, cfg.num_layers) if have_rng
                   else jnp.zeros((cfg.num_layers, 2), jnp.uint32))
+    layer_ids = jnp.arange(cfg.num_layers, dtype=jnp.int32)
+    pattern = cfg.layer_pattern
+    P = len(pattern)
+    lead, periods, tail = cfg.layer_plan
 
-    def body(h, xs):
-        lp, r, li = xs
+    def layer(h, lp, r, li, name="blocks", kind=pattern[0], dense=False):
         # inside the (checkpointed) body, so the recomputation and the
         # backward state the same as the forward
-        y, metrics = block_apply(cfg, use("blocks", lp, layer_slice=True),
+        y, metrics = block_apply(cfg, use(name, lp, layer_slice=True),
                                  keep(h), cos, sin, mask=mask,
                                  attention_fn=attention_fn,
                                  rng=r if have_rng else None,
-                                 positions=positions)
+                                 positions=positions, kind=kind,
+                                 dense=dense)
         y = keep(y)
         if pld_theta is not None:
             # whole-batch per-layer coin; deeper layers drop more
@@ -472,15 +640,60 @@ def apply(cfg: TransformerConfig, params, input_ids, mask=None,
             y = jnp.where(drop, h, y)
         return y, metrics
 
-    if cfg.remat:
-        policy = REMAT_POLICIES[cfg.remat_policy]
-        body = jax.checkpoint(body, policy=policy() if policy else None)
+    def body(h, xs):
+        lp, r, li = xs
+        if P == 1:
+            # a period of one layer is that layer: the general path
+            # below gives the same numbers, but another compiled program
+            # for every model trained before there was a pattern
+            return layer(h, lp, r, li)
+        ms = []
+        for j, kind in enumerate(pattern):
+            h, m = layer(h, jax.tree.map(lambda a: a[j], lp), r[j], li[j],
+                         kind=kind)
+            ms.append(m)
+        return h, jax.tree.map(lambda *v: jnp.stack(v), *ms)
 
+    def remat(fn):
+        if not cfg.remat:
+            return fn
+        policy = REMAT_POLICIES[cfg.remat_policy]
+        return jax.checkpoint(fn, policy=policy() if policy else None)
+
+    def rows(a, first, n):
+        return a if first == 0 and n == a.shape[0] else a[first:first + n]
+
+    def periods_of(a, first):
+        """``a``'s rows of the whole periods, from row ``first``: a layer
+        a row, or with a longer period a period a row."""
+        a = rows(a, first, periods * P)
+        return a if P == 1 else a.reshape((periods, P) + a.shape[1:])
+
+    outside_ms = []
+
+    def outside(x, stack, first, n, li, **static):
+        """``n`` layers of ``stack`` from its ``first``, one by one;
+        ``li``: the first one's index in the model."""
+        for i in range(n):
+            one = partial(layer, **static, kind=cfg.layer_kinds[li + i])
+            x, m = remat(one)(
+                x, jax.tree.map(lambda a: a[first + i], stack),
+                layer_rngs[li + i], layer_ids[li + i])
+            outside_ms.append(m)
+        return x
+
+    x = keep(x)
+    if lead:
+        x = outside(x, params["dense_blocks"], 0, lead, 0,
+                    name="dense_blocks", dense=True)
     x, metrics = jax.lax.scan(
-        body, keep(x),
-        (params["blocks"], layer_rngs,
-         jnp.arange(cfg.num_layers, dtype=jnp.int32)),
-        unroll=min(cfg.scan_unroll, cfg.num_layers))
+        remat(body), x,
+        (jax.tree.map(lambda a: periods_of(a, 0), params["blocks"]),
+         periods_of(layer_rngs, lead), periods_of(layer_ids, lead)),
+        unroll=max(1, min(cfg.scan_unroll, periods)))
+    if tail:
+        x = outside(x, params["blocks"], periods * P, tail,
+                    lead + periods * P)
     if idx is not None:
         # dropped positions bypass the stack with their embedding
         x = random_ltd_scatter(full_x, x, idx)
@@ -495,6 +708,10 @@ def apply(cfg: TransformerConfig, params, input_ids, mask=None,
     logits = keep(logits)
     if with_aux:
         aux = {k: v.mean() for k, v in metrics.items()} if metrics else {}
+        tail_ms = [m for m in outside_ms if m]
+        if tail_ms:     # expert layers outside the scan count as its do
+            aux = {k: (v.sum() + sum(m[k] for m in tail_ms))
+                   / (v.size + len(tail_ms)) for k, v in metrics.items()}
         return logits, aux
     return logits
 
@@ -581,6 +798,11 @@ def _resolve_attention(cfg: TransformerConfig) -> Callable:
         raise ValueError(
             "attn_scale needs the eager attention (attention_impl="
             "'xla'): the flash kernels bake in 1/sqrt(d)")
+    if "window" in cfg.layer_pattern and cfg.attention_impl in (
+            "flash", "xla_flash"):
+        raise ValueError(
+            "window layers need the eager attention (attention_impl="
+            "'xla'): the flash kernels take no window")
     if cfg.position == "alibi":
         if cfg.attention_impl in ("flash", "xla_flash"):
             raise ValueError(

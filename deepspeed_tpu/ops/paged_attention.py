@@ -41,6 +41,15 @@ most ``SHORT`` rows (decode tokens, verify windows) at ``SHORT``, longer
 ones (prefill chunks) in tiles of ``LONG``.  Which one a run takes is a
 property of the batch, not an option.
 
+A *window* layer (``window=W``: a query sees its last ``W`` keys, its
+own among them) SKIPS what lies behind the window and does not only mask
+it: a tile's grid row starts at the block that holds position ``first
+query - (W - 1)`` and ends at the block of its last query, at most
+``ceil((W + height) / block_size) + 1`` blocks whatever the context, and
+the mask cuts inside the first of them.  Those calls are named
+``paged_attention_w_h<height>``, so that a trace tells them from the
+full layers'.
+
 CPU tests run the same kernel in interpret mode.  ``InferenceEngine``
 probes this kernel against the XLA formulations at build time and keeps
 whichever is fastest on the running backend at the engine's shapes.
@@ -74,6 +83,8 @@ class TileList(NamedTuple):
     length: jnp.ndarray     # [n] i32 rows, 1..height
     count: jnp.ndarray      # [] i32 real tiles; the rest is padding
     blocks: jnp.ndarray     # [] i32 KV blocks the deepest real tile needs
+    wblocks: jnp.ndarray    # [] i32 the same in a window layer (the most
+                            # blocks one tile's window touches)
 
 
 class QueryTiles(NamedTuple):
@@ -90,9 +101,16 @@ def tile_counts(run_lengths: Sequence[int]) -> Tuple[int, int, int]:
     return n_short, sum(-(-n // LONG) for n in long_runs), sum(long_runs)
 
 
+def window_blocks(pos, length, window: int, block_size: int):
+    """(first, last) KV block that the window layer's tile starting at
+    position ``pos`` with ``length`` rows has to read."""
+    first = jnp.maximum(pos - (window - 1), 0) // block_size
+    return first, (pos + jnp.maximum(length, 1) - 1) // block_size
+
+
 def query_tiles(seq_slot, positions, token_valid, block_tables,
                 block_size: int, max_blocks_per_seq: int,
-                trash: int) -> QueryTiles:
+                trash: int, window: int = None) -> QueryTiles:
     """Cut a ragged batch into query tiles, on the device, once a step.
 
     seq_slot/positions: [T] i32, token_valid: [T] bool,
@@ -101,7 +119,8 @@ def query_tiles(seq_slot, positions, token_valid, block_tables,
     slot at consecutive positions; a slot holds at most one run a step
     (``StateManager.build_batch`` schedules a sequence once), which
     bounds the lists: ``max_seqs`` short tiles, ``T // LONG`` full long
-    tiles and one partial one a long run."""
+    tiles and one partial one a long run.  ``window``: the model's
+    attention window where it has window layers (``wblocks``)."""
     T = seq_slot.shape[0]
     max_seqs = block_tables.shape[0]
     i = jnp.arange(T, dtype=jnp.int32)
@@ -126,10 +145,17 @@ def query_tiles(seq_slot, positions, token_valid, block_tables,
         tables = block_tables[slot[rows], :max_blocks_per_seq]
         tables = jnp.where(tables < 0, trash, tables).astype(jnp.int32)
         length = jnp.where(real, length[rows], 0)
-        blocks = jnp.max(jnp.where(
-            real, (pos[rows] + length - 1) // block_size + 1, 1))
-        return TileList(tables, rows, pos[rows], length, count,
-                        jnp.minimum(blocks, max_blocks_per_seq))
+        blocks = jnp.minimum(jnp.max(jnp.where(
+            real, (pos[rows] + length - 1) // block_size + 1, 1)),
+            max_blocks_per_seq)
+        wblocks = blocks
+        if window is not None:
+            first, last = window_blocks(pos[rows], length, window,
+                                        block_size)
+            wblocks = jnp.minimum(
+                jnp.max(jnp.where(real, last - first + 1, 1)), blocks)
+        return TileList(tables, rows, pos[rows], length, count, blocks,
+                        wblocks)
 
     short = collect(first & is_short, run_len, min(T, max_seqs))
     long = collect(valid & ~is_short & (off % LONG == 0),
@@ -156,7 +182,8 @@ def _each_row_copy(do, src, dst, sem, row, n):
 
 def _kernel(tables_ref, row_ref, pos_ref, len_ref, base_ref, *rest,
             height: int, block_size: int, scale: float,
-            num_kv_heads: int, rep: int, alibi: bool, kv_quant: bool):
+            num_kv_heads: int, rep: int, alibi: bool, kv_quant: bool,
+            window):
     # optional inputs (order: kv scales, alibi slopes) sit between the
     # kv block and the aliased output
     rest = list(rest)
@@ -171,6 +198,10 @@ def _kernel(tables_ref, row_ref, pos_ref, len_ref, base_ref, *rest,
     R = height * rep
     pos0 = pos_ref[t]
     n = len_ref[t]
+    # the KV block this grid step attends: a window layer's row starts
+    # at the first block its first query's window touches
+    blk = j if window is None else \
+        j + window_blocks(pos0, n, window, block_size)[0]
 
     @pl.when(j == 0)
     def _init():
@@ -186,13 +217,18 @@ def _kernel(tables_ref, row_ref, pos_ref, len_ref, base_ref, *rest,
                              R, q_ref.shape[-1]))
 
     # the whole block is past the tile's last position → nothing to add
-    @pl.when(j * block_size <= pos0 + n - 1)
+    @pl.when(blk * block_size <= pos0 + n - 1)
     def _compute():
-        cols = j * block_size + jax.lax.broadcasted_iota(
+        cols = blk * block_size + jax.lax.broadcasted_iota(
             jnp.int32, (R, block_size), 1)
         # a folded row's position: row // rep tokens after the first
-        keep = cols <= pos0 + jax.lax.broadcasted_iota(
-            jnp.int32, (R, 1), 0) // rep
+        qpos = pos0 + jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0) // rep
+        keep = cols <= qpos
+        if window is not None:
+            # a row whose window starts after this block is masked whole
+            # here: what it then adds is wiped when its first real block
+            # raises its maximum (the correction is exp(-1e30 - m) = 0)
+            keep &= cols > qpos - window
         for h in range(num_kv_heads):          # static unroll (GQA groups)
             q = qs_ref[h]                                  # [R, D]
             k = kv_ref[0, :, 0, h, :]                      # [bs, D]
@@ -249,7 +285,7 @@ def _kernel(tables_ref, row_ref, pos_ref, len_ref, base_ref, *rest,
 
 
 def _attend(tiles: TileList, kv_layer, kv_scales, q, out, base, height: int,
-            scale: float, slopes):
+            scale: float, slopes, window=None):
     """One ``pallas_call`` over ``tiles`` → ``out`` with their rows
     written (``out`` is donated to the call and returned)."""
     T, H, D = q.shape
@@ -264,7 +300,11 @@ def _attend(tiles: TileList, kv_layer, kv_scales, q, out, base, height: int,
         # skips the DMA entirely (the kernel skips the compute).  (An
         # empty list's entry 0 has length 0; whatever evaluates this for
         # it must still get a block of the table.)
-        return jnp.minimum(j, (pos[t] + jnp.maximum(length[t], 1) - 1) // bs)
+        if window is None:
+            return jnp.minimum(
+                j, (pos[t] + jnp.maximum(length[t], 1) - 1) // bs)
+        first, last = window_blocks(pos[t], length[t], window, bs)
+        return jnp.minimum(first + j, last)
 
     def _kv_index(t, j, tbl, row, pos, length, base):
         return (tbl[t, _last_block(t, j, pos, length)] + base[0], 0, 0, 0, 0)
@@ -300,10 +340,11 @@ def _attend(tiles: TileList, kv_layer, kv_scales, q, out, base, height: int,
     return pl.pallas_call(
         functools.partial(_kernel, height=height, block_size=bs,
                           scale=scale, num_kv_heads=Hkv, rep=rep,
-                          alibi=alibi, kv_quant=kv_quant),
+                          alibi=alibi, kv_quant=kv_quant, window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),
-            grid=(tiles.count, tiles.blocks),
+            grid=(tiles.count,
+                  tiles.blocks if window is None else tiles.wblocks),
             in_specs=in_specs,
             out_specs=pl.BlockSpec(memory_space=pl.ANY),
             scratch_shapes=[
@@ -321,12 +362,12 @@ def _attend(tiles: TileList, kv_layer, kv_scales, q, out, base, height: int,
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=64 * 1024 * 1024),
         interpret=_use_interpret(),
-        name=f"paged_attention_h{height}",
+        name=f"paged_attention{'' if window is None else '_w'}_h{height}",
     )(*prefetch, *operands)
 
 
 def paged_attention(kv_layer, q, tiles: QueryTiles, scale: float,
-                    slopes=None, layer=None):
+                    slopes=None, layer=None, window=None):
     """kv_layer: [blocks+1, bs, 2, Hkv, D] (last row = trash), or a
     (data, scales) tuple for a quantized cache (scales
     [blocks+1, bs, 2, Hkv] f32; codes dequantized in VMEM so HBM only
@@ -346,7 +387,9 @@ def paged_attention(kv_layer, q, tiles: QueryTiles, scale: float,
     scales are cut to the layer's rows first and indexed without the
     offset, because Mosaic wants them in a lane-padded layout of its
     own: a layer's worth is relaid per call as before, never the
-    stack's."""
+    stack's.
+    ``window``: a window layer's window (module docstring); ``tiles``
+    must have been cut with it."""
     kv_scales = None
     if isinstance(kv_layer, tuple):
         kv_layer, kv_scales = kv_layer
@@ -364,5 +407,5 @@ def paged_attention(kv_layer, q, tiles: QueryTiles, scale: float,
     out = jnp.zeros((T, Hp, -(-D // 128) * 128), q.dtype)
     for tl, height in ((tiles.long, LONG), (tiles.short, SHORT)):
         out = _attend(tl, kv_layer, kv_scales, qp, out, base, height,
-                      scale, slopes)
+                      scale, slopes, window)
     return out[:, :H, :D]

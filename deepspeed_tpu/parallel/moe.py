@@ -211,7 +211,34 @@ def gate_init(key, d_model: int, num_experts: int):
             {"kernel": ("embed", None)})
 
 
-def _ragged_moe(expert_p, x, logits, *, top_k: int, activation, gated: bool,
+def route(logits, bias=None, *, top_k: int, score: str = "softmax",
+          norm_topk: bool = True, route_scale: float = 1.0):
+    """The router's choice without capacity: float32 ``logits [T, E]`` →
+    ``(weights [T, K] f32, experts [T, K] i32)``.
+
+    ``score``: ``softmax`` over the experts, or the ``sigmoid`` of each
+    logit.  ``bias [E]``: added to the scores for the CHOICE of the
+    top-k only (a router balanced without an auxiliary loss); the
+    weights are the unbiased scores of the chosen.  ``norm_topk``: the
+    chosen weights are renormalised to sum 1; ``route_scale`` multiplies
+    them after that."""
+    scores = (jax.nn.softmax(logits, axis=-1) if score == "softmax"
+              else jax.nn.sigmoid(logits))
+    if bias is None:
+        vals, ids = jax.lax.top_k(scores, top_k)
+    else:
+        _, ids = jax.lax.top_k(scores + bias.astype(scores.dtype), top_k)
+        vals = jnp.take_along_axis(scores, ids, axis=1)
+    if top_k > 1 and norm_topk:
+        vals = vals / jnp.maximum(vals.sum(axis=1, keepdims=True), 1e-9)
+    if route_scale != 1.0:
+        vals = vals * route_scale
+    return vals, ids
+
+
+def _ragged_moe(gate_p, expert_p, x, logits, *, top_k: int, activation,
+                gated: bool, score: str = "softmax",
+                route_scale: float = 1.0,
                 norm_topk: bool = True,
                 noise_policy: Optional[str], rng: Optional[jax.Array],
                 dt) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
@@ -231,23 +258,12 @@ def _ragged_moe(expert_p, x, logits, *, top_k: int, activation, gated: bool,
     lf = logits.reshape(T, E)
     if noise_policy == "RSample" and rng is not None:
         lf = lf + jax.random.normal(rng, lf.shape) / E
-    gates = jax.nn.softmax(lf.astype(jnp.float32), axis=-1)       # [T, E]
-
-    remaining = gates
-    ids, vals = [], []
-    for _ in range(top_k):
-        idx = jnp.argmax(remaining, axis=-1)                      # [T]
-        ids.append(idx)
-        vals.append(jnp.take_along_axis(gates, idx[:, None],
-                                        axis=1)[:, 0])
-        remaining = remaining * (1.0 - jax.nn.one_hot(idx, E,
-                                                      dtype=jnp.float32))
-    ids = jnp.stack(ids, axis=1)                                  # [T, K]
-    vals = jnp.stack(vals, axis=1)                                # [T, K]
-    if top_k > 1 and norm_topk:
-        # renormalize to sum 1 per token — same convention as
-        # top_k_gating (reference top2 normalization sharded_moe.py:290)
-        vals = vals / jnp.maximum(vals.sum(axis=1, keepdims=True), 1e-9)
+    lf = lf.astype(jnp.float32)
+    gates = jax.nn.softmax(lf, axis=-1)                           # [T, E]
+    # renormalised to sum 1 per token under norm_topk — same convention
+    # as top_k_gating (reference top2 normalization sharded_moe.py:290)
+    vals, ids = route(lf, gate_p.get("bias"), top_k=top_k, score=score,
+                      norm_topk=norm_topk, route_scale=route_scale)
     me = gates.mean(axis=0)
     ce = jax.nn.one_hot(ids[:, 0], E, dtype=jnp.float32).mean(axis=0)
     aux_loss = (me * ce).sum() * E
@@ -275,18 +291,22 @@ def _ragged_moe(expert_p, x, logits, *, top_k: int, activation, gated: bool,
 
 def moe_serve(gate_p, expert_p, h, valid=None, *, top_k: int, activation,
               gated: bool, norm_topk: bool, kernel: bool = False,
-              layer=None):
+              layer=None, score: str = "softmax",
+              route_scale: float = 1.0, with_ids: bool = False):
     """The serving expert layer: DROPLESS by construction.  h: [T, d]
     rows of one serving step (any mix of sequences); ``valid``: [T] bool,
     False for the rows that pad the step's bucket (None: all real).
-    Returns ``(y [T, d], stats [2] i32)``.
+    Returns ``(y [T, d], stats [3] i32)``, and with ``with_ids`` a third:
+    ``[T, top_k] i32``, the experts each row took (a padding row: E).
 
     Every real row's ``top_k`` assignments are computed whatever else is
     in the step: there is no capacity, so a row's output does not depend
     on its neighbours.  Padding rows are routed nowhere: they sort behind
     the last expert, are counted in no group, and come back zero.  The
-    router's softmax and its top-k are float32 (one ``jax.lax.top_k``);
-    the probabilities are used as they are unless ``norm_topk``.
+    router's scores and its top-k are float32 (:func:`route`, one
+    ``jax.lax.top_k``; ``score``, ``route_scale`` and a ``bias`` in
+    ``gate_p`` are its form); the scores of the chosen are used as they
+    are unless ``norm_topk``.
 
     The three projections are grouped matrix multiplications over the
     rows sorted by expert: ``ops/grouped_matmul.py`` when ``kernel`` (a
@@ -299,7 +319,7 @@ def moe_serve(gate_p, expert_p, h, valid=None, *, top_k: int, activation,
     copied whole on its way into the custom call.
 
     ``stats``: (assignments computed, 1000 x the fullest expert's rows
-    over the mean), for the engine's counters."""
+    over the mean, experts that took a row), for the engine's counters."""
     T, dm = h.shape
     E = expert_p["wi"].shape[-3]
     dt = h.dtype
@@ -317,9 +337,9 @@ def moe_serve(gate_p, expert_p, h, valid=None, *, top_k: int, activation,
     with jax.named_scope("moe_route"):
         logits = jnp.dot(h, gate_p["kernel"].astype(dt),
                          preferred_element_type=jnp.float32)
-        vals, ids = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
-        if top_k > 1 and norm_topk:
-            vals = vals / jnp.maximum(vals.sum(axis=1, keepdims=True), 1e-9)
+        vals, ids = route(logits, gate_p.get("bias"), top_k=top_k,
+                          score=score, norm_topk=norm_topk,
+                          route_scale=route_scale)
         if valid is not None:
             ids = jnp.where(valid[:, None], ids, E)     # expert E: nowhere
         flat = ids.reshape(-1)                                    # [T*K]
@@ -344,8 +364,9 @@ def moe_serve(gate_p, expert_p, h, valid=None, *, top_k: int, activation,
             y = jnp.where(valid[:, None], y, 0)
         n = group_sizes.sum()
         stats = jnp.stack([n, (group_sizes.max() * (1000 * E))
-                           // jnp.maximum(n, 1)])
-    return y, stats
+                           // jnp.maximum(n, 1),
+                           (group_sizes > 0).sum()])
+    return (y, stats, ids) if with_ids else (y, stats)
 
 
 def moe_ffn(gate_p, expert_p, x, *, top_k: int, capacity_factor: float,
@@ -353,7 +374,8 @@ def moe_ffn(gate_p, expert_p, x, *, top_k: int, capacity_factor: float,
             gated: bool = False, rng: Optional[jax.Array] = None,
             noise_policy: Optional[str] = None,
             dispatch_mode: str = "scatter",
-            norm_topk: bool = True
+            norm_topk: bool = True, score: str = "softmax",
+            route_scale: float = 1.0
             ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """Full MoE FFN over x [B, S, d_model] (reference: MOELayer.forward
     sharded_moe.py:533).  Returns (y, metrics) with metrics carrying the
@@ -378,6 +400,10 @@ def moe_ffn(gate_p, expert_p, x, *, top_k: int, capacity_factor: float,
     T16k) it compiled to 2.4x less temp memory than the einsum form (420
     vs 1007 MB, a CPU-mesh compile; no chip run of either is on record).
 
+    ``score``, ``route_scale`` and a ``bias`` in ``gate_p``: the
+    router's form, see :func:`route`; the capacity dispatches know only
+    the softmax router without a bias.
+
     Training only.  Serving does not come here: ``moe_serve`` below is
     dropless whatever ``dispatch_mode`` a config names.
     """
@@ -395,10 +421,15 @@ def moe_ffn(gate_p, expert_p, x, *, top_k: int, capacity_factor: float,
     logits = jnp.einsum("gtd,de->gte", xg, gate_p["kernel"].astype(x.dtype))
     dt = x.dtype
     if dispatch_mode == "ragged":
-        return _ragged_moe(expert_p, x, logits, top_k=top_k,
+        return _ragged_moe(gate_p, expert_p, x, logits, top_k=top_k,
                            activation=activation, gated=gated,
                            noise_policy=noise_policy, rng=rng, dt=dt,
-                           norm_topk=norm_topk)
+                           norm_topk=norm_topk, score=score,
+                           route_scale=route_scale)
+    if score != "softmax" or "bias" in gate_p or route_scale != 1.0:
+        raise ValueError(
+            f"moe_dispatch={dispatch_mode!r} routes by softmax without a "
+            "selection bias; this router needs moe_dispatch='ragged'")
     rngs = jax.random.split(rng, B) if rng is not None else None
 
     gate_fn = functools.partial(

@@ -76,6 +76,10 @@ def make_pipelined_loss_fn(cfg: TransformerConfig, topology: MeshTopology,
     if cfg.num_layers % S:
         raise ValueError(f"num_layers {cfg.num_layers} not divisible by "
                          f"pipe stages {S}")
+    if not cfg.plain_stack:
+        raise NotImplementedError(
+            "the pipeline's stages hold layers of one block type "
+            "(TransformerConfig.plain_stack)")
     if schedule not in ("gpipe", "1f1b"):
         raise ValueError(f"unknown pipeline schedule {schedule!r} "
                          "(gpipe | 1f1b)")

@@ -88,6 +88,12 @@ PAGED = {
     "gpt2-bf16": dict(T=256, H=GPT2["num_heads"], Hkv=GPT2["num_heads"],
                       D=GPT2["d_model"] // GPT2["num_heads"], blocks=256,
                       nb=16, kv_quant=False),
+    # eight query heads to a kv head (1024 folded rows a long tile), a
+    # table of 192 blocks, and the window layers' form of the kernel
+    "trinity-mini-bf16-full": dict(T=512, H=32, Hkv=4, D=128, blocks=1024,
+                                   nb=192, kv_quant=False),
+    "trinity-mini-bf16-window": dict(T=512, H=32, Hkv=4, D=128, blocks=1024,
+                                     nb=192, kv_quant=False, window=2048),
 }
 
 
@@ -106,8 +112,9 @@ def test_paged_attention_compiles(one_chip, on_chip, case):
 
     def fn(kv, q, slot, pos, valid, tables):
         tiles = query_tiles(slot, pos, valid, tables, bs, c["nb"],
-                            trash=c["blocks"])
-        return paged_attention(kv, q, tiles, D ** -0.5)
+                            trash=c["blocks"], window=c.get("window"))
+        return paged_attention(kv, q, tiles, D ** -0.5,
+                               window=c.get("window"))
 
     # the one kernel body at its two heights
     assert _compile(fn, kv, q, idx, idx, S((T,), jnp.bool_), tables) == 2
@@ -231,7 +238,7 @@ def _pstep_compiled(one_chip, cfg, kv_quant, T, seqs, bs, mbs, blocks):
     batch = RaggedBatch(
         token_ids=tok, positions=tok, seq_slot=tok,
         token_valid=S((T,), jnp.bool_),
-        block_tables=S((seqs, 32), jnp.int32),
+        block_tables=S((seqs, max(32, mbs)), jnp.int32),
         context_lens=S((seqs,), jnp.int32),
         logits_idx=S((seqs,), jnp.int32), n_tokens=T, n_seqs=seqs,
         feedback_src=tok, seq_uids=S((seqs,), jnp.uint32))
@@ -309,6 +316,53 @@ def test_moe_serving_step_compiles_and_fits(one_chip, on_chip):
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < leaf_bytes // 8
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+def test_window_serving_step_compiles_fits_and_names_its_kernels(
+        one_chip, on_chip):
+    """The whole serving step of ``trinity-mini-d5`` as the benchmark runs
+    it (a dense layer and a period of three window layers and a full one,
+    6144 blocks of 64, 512 tokens and 64 sequences a step, tables of 192
+    blocks): the dense layer's window kernel outside the scan, the
+    period's three window calls, its full call and the grouped kernel's
+    projections inside it; weights, pool and temporaries fit a 16 GB chip;
+    and every Pallas call's JAX path is one that the configuration file's
+    ``trace_groups`` keys name, the window layers' apart from the full
+    layer's."""
+    import json
+    import re
+
+    from benchmarks.lib.drivers.serve_routed import preset_config
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root,
+                           "benchmarks/configs/trinity-mini-d5.json")) as f:
+        config = json.load(f)
+    cfg = preset_config(config)
+    compiled, _ = _pstep_compiled(one_chip, cfg, False, T=512, seqs=64,
+                                  bs=64, mbs=192, blocks=6144)
+    text = compiled.as_text()
+    paths = set(re.findall(r'op_name="([^"]*pallas_call)"', text))
+    groups = {}
+    for p in paths:
+        tail = next(k.partition("@")[0] for k in config["trace_groups"]
+                    if k.partition("@")[0] and p.endswith(k.partition("@")[0]))
+        groups.setdefault(tail, set()).add(p)
+    window = {t for t in groups if t.startswith("paged_attention_w_")}
+    assert window == {"paged_attention_w_h8/pallas_call",
+                      "paged_attention_w_h128/pallas_call"}
+    # under their scope, outside the scan (the dense layer) and inside it
+    for t in window:
+        assert all("/attn/attn_window/" in p for p in groups[t])
+        assert {"while/body" in p for p in groups[t]} == {True, False}
+    assert all("attn_window" not in p and "/attn/paged_attention_h" in p
+               for p in groups["pallas_call"] if "paged_attention" in p)
+    # window: 2 heights x (1 dense layer + 3 in the period); full: 2;
+    # grouped kernel: 3 projections x 4 expert layers
+    assert text.count("tpu_custom_call") == 8 + 2 + 12
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+    assert mem.temp_size_in_bytes < 256e6
 
 
 @pytest.mark.parametrize("kv_quant", [False, True],
