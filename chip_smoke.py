@@ -77,6 +77,8 @@ REAL = dict(
                prompt_lens=(311, 257, 203), new_tokens=16),
     flash={"gpt2": (4, 1024, 12, 12, 64), "llama3-8b": (1, 2048, 32, 8, 128)},
     paged=dict(T=1024, H=32, Hkv=8, D=128, block=64, blocks=128, nb=16),
+    paged_long=dict(S=16, H=32, Hkv=4, D=128, block=64, blocks=2560, nb=192,
+                    window=2048, ctx=(4100, 12200)),
     gemm=dict(K=4096, N=14336, Ms=(8, 1024)),
     barrier=dict(n=8192, iters=64),
 )
@@ -94,6 +96,8 @@ TINY = dict(
                block=8, blocks=64, prompt_lens=(21, 17, 9), new_tokens=6),
     flash={"gpt2": (1, 128, 4, 4, 32), "llama3-8b": (1, 128, 4, 2, 32)},
     paged=dict(T=16, H=4, Hkv=2, D=32, block=8, blocks=16, nb=4),
+    paged_long=dict(S=4, H=16, Hkv=2, D=32, block=8, blocks=96, nb=24,
+                    window=32, ctx=(70, 190)),
     gemm=dict(K=256, N=512, Ms=(8, 32)),
     barrier=dict(n=256, iters=8),
 )
@@ -370,6 +374,47 @@ def kernels_phase(sz, seed):
         close("out", got["pallas"][:n_real], got["xla"][:n_real], BF16_REL)
         check(not np.asarray(got["pallas"][n_real:], np.float32).any(),
               "paged attention wrote into budget padding")
+
+    # --- the same at long contexts: one decode token a sequence, eight
+    # query heads a kv head, contexts past 4k (dozens of the short
+    # call's groups of KV blocks a tile), a full layer and a window layer
+    c = sz["paged_long"]
+    S, H, Hkv, D, bs, nb = (c[k] for k in ("S", "H", "Hkv", "D", "block",
+                                            "nb"))
+    kq, kc = jax.random.split(jax.random.fold_in(k_paged, 1))
+    q = jax.random.normal(kq, (S, H, D), jnp.bfloat16)
+    cache = jax.random.normal(kc, (c["blocks"] + 1, bs, 2, Hkv, D),
+                              jnp.bfloat16)
+    ctx = r.randint(*c["ctx"], size=S)
+    need = ctx // bs + 1
+    check(need.sum() <= c["blocks"] and need.max() <= nb,
+          "the long-context sample does not fit its pool")
+    order, tables = r.permutation(c["blocks"]), np.full((S, nb), -1)
+    for i, n in enumerate(need):
+        tables[i, :n] = order[need[:i].sum():need[:i].sum() + n]
+    batch = RaggedBatch(
+        token_ids=jnp.zeros(S, jnp.int32),
+        positions=jnp.asarray(ctx, jnp.int32),
+        seq_slot=jnp.arange(S, dtype=jnp.int32),
+        token_valid=jnp.ones(S, bool),
+        block_tables=jnp.asarray(tables, jnp.int32),
+        context_lens=jnp.zeros(S, jnp.int32),
+        logits_idx=jnp.full(S, -1, jnp.int32), n_tokens=S, n_seqs=S)
+    for name, kvl in (("bf16", cache),
+                      ("int8-KV", _quantize_kv(cache, jnp.int8))):
+        for window in (None, c["window"]):
+            print(f"  paged attention {name} decode x{S} H{H}/{Hkv} D{D} "
+                  f"contexts {ctx.min()}-{ctx.max()} "
+                  f"{'full' if window is None else f'window {window}'}")
+            got = {}
+            for impl, fn in (("pallas", _paged_attention_pallas),
+                             ("xla", _paged_attention)):
+                got[impl], t = timed(jax.jit(
+                    lambda kvl, q, _fn=fn, _w=window: _fn(
+                        kvl, q, batch, bs, nb, D ** -0.5, window=_w)),
+                    kvl, q)
+                print(f"    {impl}: {ms(t)}")
+            close("out", got["pallas"], got["xla"], BF16_REL)
 
     # --- mixed-input GEMMs
     K, N = sz["gemm"]["K"], sz["gemm"]["N"]

@@ -33,7 +33,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..comm.mesh import FSDP_AXIS, MeshTopology, TENSOR_AXIS
 from ..models.transformer import Model, TransformerConfig
-from ..ops.paged_attention import LONG, tile_counts
+from ..ops.paged_attention import (LONG, SHORT, group_steps, kv_group,
+                                   tile_counts)
 from ..telemetry import (AnomalyConfig, AnomalyMonitor, CounterDictView,
                          DeviceTelemetry, FlightRecorder, MetricsRegistry,
                          ProfilerCapture, RequestTracker, SloObjective,
@@ -752,6 +753,27 @@ class InferenceEngine:
             "cached tokens one attention layer reads for the dispatched "
             "steps (kind: full = every token of the step's sequences | "
             "window = those inside its queries' windows)", int_valued=True)
+        # the grid steps the kernel's short call (decode tokens, verify
+        # windows) makes in one layer of each kind that hold a needed
+        # block, and how full those groups of KV blocks ran: counted
+        # with the kernel's own rule for its group (``kv_group``), from
+        # the schedule
+        self._c_attn_group_steps = reg.counter(
+            "serving_attn_kv_group_steps_total",
+            "grid steps of the paged-attention kernel's short call that "
+            "hold a needed KV block, one layer of the kind (kind: full | "
+            "window)", int_valued=True)
+        self._group_blocks = self._group_slots = 0
+        kc = self.state.cfg
+        self._attn_group = kv_group(
+            SHORT, self.cfg.num_heads // self.cfg.num_kv_heads,
+            kc.num_kv_heads // (self.topology.tp_size if self._tp_mesh
+                                else 1),
+            kc.head_dim, kc.block_size, kc.store_dtype,
+            self.max_blocks_per_seq, kc.quant != "none")
+        reg.gauge_fn("serving_attn_kv_group_fill", self._attn_group_fill,
+                     "needed KV blocks over the blocks the short call's "
+                     "grid steps hold (absent before the first one)")
         if self._window:
             reg.gauge_fn(
                 "serving_kv_tokens_behind_window", self._behind_window,
@@ -897,25 +919,48 @@ class InferenceEngine:
                                lambda: self._steps_done,
                                self._on_anomaly)
 
-    def _count_attn_kv(self, sched) -> Dict[str, int]:
+    def _count_attn_kv(self, sched, pallas: bool) -> Dict[str, int]:
         """The cached tokens an attention layer of each kind reads for
         the step ``sched``, counted and returned as the stage span's
         arguments: ``kv_tokens_full``, the sum of ``seen + n`` over its
         sequences, and with window layers ``kv_tokens_window``, the sum
-        of ``min(seen + n, window + n - 1)``."""
+        of ``min(seen + n, window + n - 1)``.  Where the Pallas kernel
+        serves, also ``kv_steps_full`` / ``kv_steps_window``: the grid
+        steps its short call makes in one such layer that hold a needed
+        block (``ops/paged_attention.group_steps``)."""
         w = self._window
         full = window = 0
+        short = []
         for uid, toks in sched:
             seq = self.state.seqs.get(uid)
-            ctx = (seq.seen_tokens if seq else 0) + len(toks)
+            seen = seq.seen_tokens if seq else 0
+            ctx = seen + len(toks)
             full += ctx
             if w:
                 window += min(ctx, w + len(toks) - 1)
+            if 0 < len(toks) <= SHORT:
+                short.append((seen, len(toks)))
+        args = {"kv_tokens_full": full}
         self._c_attn_kv.inc(full, kind="full")
-        if not w:
-            return {"kv_tokens_full": full}
-        self._c_attn_kv.inc(window, kind="window")
-        return {"kv_tokens_full": full, "kv_tokens_window": window}
+        if w:
+            self._c_attn_kv.inc(window, kind="window")
+            args["kv_tokens_window"] = window
+        if pallas:
+            k = self._attn_group
+            for kind, win in (("full", None), ("window", w))[:2 if w else 1]:
+                steps, blocks = group_steps(short, self.state.cfg.block_size,
+                                            k, win)
+                self._c_attn_group_steps.inc(steps, kind=kind)
+                self._group_blocks += blocks
+                self._group_slots += steps * k
+                args[f"kv_steps_{kind}"] = steps
+        return args
+
+    def _attn_group_fill(self) -> Optional[float]:
+        """Needed KV blocks over the blocks held by the grid steps the
+        short call made so far; None before the first one."""
+        return (self._group_blocks / self._group_slots
+                if self._group_slots else None)
 
     def _behind_window(self) -> int:
         """Tokens the live sequences hold that lie behind the window of
@@ -1000,6 +1045,7 @@ class InferenceEngine:
         included), the request-lifecycle tracker, and the span ring —
         what a bench leg calls between warmup and its timed region."""
         self.metrics.reset()
+        self._group_blocks = self._group_slots = 0
         self.requests.clear()
         self.tracer.clear()
         # rearm the pool high-water mark so a timed region reports ITS
@@ -3127,7 +3173,7 @@ class InferenceEngine:
                          tile_fill=rows / (n_long * LONG) if n_long else 0.0)
         t1 = tr.phase("ds.serve.stage", track="stage", sid=sid,
                       n_tokens=n_tokens, n_seqs=len(sched), mbs=mbs,
-                      **tiles, **self._count_attn_kv(sched))
+                      **tiles, **self._count_attn_kv(sched, pallas))
         batch = self._stage(
             self.state.build_batch(
                 sched, self.icfg.token_budget, stager=self._stager,

@@ -137,6 +137,25 @@ def _query_tiles(kv, batch: RaggedBatch, block_size: int,
                        trash=_kv_parts(kv)[0].shape[-5] - 1, window=window)
 
 
+def _group_tiles(tiles, kv, num_heads: int, window=None, shard_mesh=None):
+    """``tiles`` with their tables laid out by the grid steps of the
+    kernel's calls in the layers of one kind (``window``: the window
+    layers'; ``ops/paged_attention.group_tiles``): like the tiles, once
+    a step and outside the layer scan.  ``shard_mesh``: as the kernel
+    will run under it, a chip's share of the kv heads decides."""
+    from ..ops.paged_attention import group_tiles
+
+    data, scales = _kv_parts(kv)
+    hkv = data.shape[-2]
+    local = hkv
+    if shard_mesh is not None:
+        from ..comm.mesh import TENSOR_AXIS
+        local //= shard_mesh.shape[TENSOR_AXIS]
+    return group_tiles(tiles, num_heads // hkv, local, data.shape[-1],
+                       data.shape[-4], data.dtype, scales is not None,
+                       window)
+
+
 def _paged_attention_pallas(kv_layer, q, batch: RaggedBatch,
                             block_size: int, max_blocks_per_seq: int,
                             scale: float, shard_mesh=None, slopes=None,
@@ -586,7 +605,7 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
                 o = _paged_attention_pallas(
                     pool, q, batch, block_size, max_blocks_per_seq,
                     scale, shard_mesh=shard_mesh, slopes=slopes,
-                    layer=layer, tiles=tiles, window=window)
+                    layer=layer, tiles=tiles[kind], window=window)
             else:
                 o = _paged_attention(pool, q, batch, block_size,
                                      max_blocks_per_seq, scale,
@@ -625,10 +644,16 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
     P = len(pattern)
     lead, periods, tail = cfg.layer_plan
     # what the Pallas kernel's grid walks is the same for every layer of
-    # a kind: cut the batch into query tiles here, once, outside the scan
-    tiles = (_query_tiles(kv, batch, block_size, max_blocks_per_seq,
-                          cfg.attn_window if "window" in pattern else None)
-             if attn_impl == "pallas" else None)
+    # a kind: cut the batch into query tiles here, once, outside the scan,
+    # and lay their tables out by the grid steps of each kind's calls
+    tiles = {}
+    if attn_impl == "pallas":
+        cut = _query_tiles(kv, batch, block_size, max_blocks_per_seq,
+                           cfg.attn_window if "window" in pattern else None)
+        tiles = {kind: _group_tiles(
+            cut, kv, cfg.num_heads,
+            cfg.attn_window if kind == "window" else None, shard_mesh)
+            for kind in sorted(set(cfg.layer_kinds))}
     rows = _kv_parts(kv)[0].shape[1]       # a layer's blocks + trash row
 
     def at(li):

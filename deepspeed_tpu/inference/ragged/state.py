@@ -126,6 +126,12 @@ class KVCacheConfig:
                 "'int8' or 'fp8' (per-vector scales); weight_quant is "
                 "the option that also takes 'int4'")
 
+    @property
+    def store_dtype(self):
+        """What the pool's blocks hold: the codes' type when quantized."""
+        return {"int8": jnp.int8, "fp8": jnp.float8_e4m3fn}.get(
+            self.quant, self.dtype)
+
     def kv_zeros(self):
         """A pristine cache: a single array, or (data, scales) when
         quantized (a plain tuple — a pytree, so jit/donate/device_put
@@ -134,8 +140,8 @@ class KVCacheConfig:
                  self.num_kv_heads, self.head_dim)
         if self.quant == "none":
             return jnp.zeros(shape, self.dtype)
-        qdt = {"int8": jnp.int8, "fp8": jnp.float8_e4m3fn}[self.quant]
-        return (jnp.zeros(shape, qdt), jnp.zeros(shape[:-1], jnp.float32))
+        return (jnp.zeros(shape, self.store_dtype),
+                jnp.zeros(shape[:-1], jnp.float32))
 
 
 @dataclasses.dataclass

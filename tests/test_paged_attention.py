@@ -305,12 +305,25 @@ TILE_BATCHES = {
     "eight-and-nine": ([(1, 11, 8), (2, 2, 9)], 32),
     # all padding but one token
     "one-token": ([(1, 12, 1)], 64),
+    # bs = 8 and groups of 8 blocks: a grid step of the short call holds
+    # 64 keys.  Contexts that end in the first, a middle and the last
+    # block of their second group, at its two edges, and inside the
+    # first group (shorter than one group); the deepest tile first
+    "group-edges": ([(1, 135, 1), (2, 67, 1), (3, 99, 1), (4, 124, 1),
+                     (5, 63, 1), (6, 64, 1), (7, 127, 1), (8, 10, 1)], 16),
+    # verify windows of 2 to 8 rows whose positions cross a group's edge
+    # (61..66 and 126..133 and 63..64), one that ends on it, and a
+    # decode token; the last tile of the list is the shallowest
+    "verify-across-groups": ([(1, 61, 6), (2, 126, 8), (3, 63, 2),
+                              (4, 57, 7), (5, 180, 1), (6, 2, 3)], 32),
+    # the last tile of the list is the deepest, behind single-group ones
+    "deep-last": ([(1, 5, 1), (2, 30, 2), (3, 250, 1)], 8),
 }
 
 
-def _random_pool(seed, layers=None, quant=False):
+def _random_pool(seed, layers=None, quant=False, hkv=HKV_T, d=D_T):
     r = np.random.RandomState(seed)
-    shape = (NBLK_T + 1, BS_T, 2, HKV_T, D_T)
+    shape = (NBLK_T + 1, BS_T, 2, hkv, d)
     if layers:
         shape = (layers,) + shape
     if quant:
@@ -319,16 +332,18 @@ def _random_pool(seed, layers=None, quant=False):
     return jnp.asarray(r.randn(*shape), jnp.float32)
 
 
-def _check_tiles(kv, batch, H, nb, layer=None, slopes=None, tol=1e-5):
+def _check_tiles(kv, batch, H, nb, layer=None, slopes=None, tol=1e-5,
+                 dtype=jnp.float32):
     T = batch.token_ids.shape[0]
-    q = jnp.asarray(np.random.RandomState(11).randn(T, H, D_T), jnp.float32)
-    scale = 1.0 / np.sqrt(D_T)
+    D = jax.tree.leaves(kv)[0].shape[-1]
+    q = jnp.asarray(np.random.RandomState(11).randn(T, H, D), dtype)
+    scale = 1.0 / np.sqrt(D)
     ref = _paged_attention(kv, q, batch, BS_T, nb, scale, slopes=slopes,
                            layer=layer)
     out = _paged_attention_pallas(kv, q, batch, BS_T, nb, scale,
                                   slopes=slopes, layer=layer)
     valid = np.asarray(batch.token_valid)
-    out, ref = np.asarray(out), np.asarray(ref)
+    out, ref = (np.asarray(a.astype(jnp.float32)) for a in (out, ref))
     assert valid.any()
     np.testing.assert_allclose(out[valid], ref[valid], atol=tol, rtol=tol)
     # budget padding belongs to no tile: nothing is written there
@@ -336,14 +351,16 @@ def _check_tiles(kv, batch, H, nb, layer=None, slopes=None, tol=1e-5):
 
 
 class TestQueryTiles:
-    @pytest.mark.parametrize("rep", [1, 4])
+    @pytest.mark.parametrize("rep", [1, 4, 8])
     @pytest.mark.parametrize("name", sorted(TILE_BATCHES))
     def test_matches_xla_on_built_batches(self, name, rep):
         runs, T = TILE_BATCHES[name]
         batch, _ = _built_batch(runs, T)
         _check_tiles(_random_pool(3), batch, HKV_T * rep, nb=32)
 
-    @pytest.mark.parametrize("name", ["two-chunks", "verify-window"])
+    @pytest.mark.parametrize("name", ["two-chunks", "verify-window",
+                                      "group-edges",
+                                      "verify-across-groups"])
     def test_alibi(self, name):
         from deepspeed_tpu.models import layers as L
         runs, T = TILE_BATCHES[name]
@@ -351,11 +368,94 @@ class TestQueryTiles:
         _check_tiles(_random_pool(4), batch, 8, nb=32,
                      slopes=L.alibi_slopes(8))
 
-    @pytest.mark.parametrize("name", ["two-chunks", "decode-only"])
+    @pytest.mark.parametrize("name", ["two-chunks", "decode-only",
+                                      "group-edges",
+                                      "verify-across-groups"])
     def test_int8_kv(self, name):
         runs, T = TILE_BATCHES[name]
         batch, _ = _built_batch(runs, T)
         _check_tiles(_random_pool(5, quant=True), batch, 8, nb=32, tol=1e-4)
+
+    @pytest.mark.parametrize("name", ["group-edges", "verify-across-groups",
+                                      "two-chunks"])
+    def test_head_size_64(self, name):
+        """gpt2's head: the blocks' lanes are half full and a head's
+        keys are gathered a key at a time, not through the words."""
+        runs, T = TILE_BATCHES[name]
+        batch, _ = _built_batch(runs, T)
+        _check_tiles(_random_pool(9, hkv=3, d=64), batch, 3, nb=32)
+
+    @pytest.mark.parametrize("rep", [1, 8])
+    @pytest.mark.parametrize("store", ["f32", "bf16", "int8"])
+    @pytest.mark.parametrize("name", ["group-edges", "verify-across-groups",
+                                      "chunk-unaligned"])
+    def test_lane_full_heads_are_read_through_the_words(self, name, store,
+                                                        rep):
+        """At ``D = 128`` with a whole number of 32-bit sublanes a key
+        (four heads: two in bf16, one in int8) a head's keys come by
+        strided loads over the block's words, its bits shifted out: the
+        same numbers as the XLA formulation on the same pool."""
+        runs, T = TILE_BATCHES[name]
+        batch, _ = _built_batch(runs, T)
+        kv = _random_pool(10, quant=store == "int8", hkv=4, d=128)
+        dtype, tol = jnp.float32, 1e-4
+        if store == "bf16":
+            kv, dtype, tol = kv.astype(jnp.bfloat16), jnp.bfloat16, 2e-2
+        _check_tiles(kv, batch, 4 * rep, nb=32, tol=tol, dtype=dtype)
+
+    @pytest.mark.parametrize("window", [None, 64, 20])
+    @pytest.mark.parametrize("name", sorted(TILE_BATCHES))
+    def test_host_counts_the_grid_steps_the_kernel_makes(self, name, window):
+        """``group_steps`` on the host against the kernel's own rule on
+        the device's tile list: a short tile's grid row holds
+        ``ceil(span / k)`` steps with a needed block, ``k`` from
+        ``kv_group``; past a tile's needed blocks the index maps repeat
+        the row each operand was left on (no fetch for a block that no
+        tile needs)."""
+        import importlib
+
+        from deepspeed_tpu.inference.model import _query_tiles
+        pa = importlib.import_module("deepspeed_tpu.ops.paged_attention")
+
+        runs, T = TILE_BATCHES[name]
+        batch, _ = _built_batch(runs, T)
+        nb = 32
+        tiles = _query_tiles(_random_pool(8), batch, BS_T, nb, window).short
+        k = pa.kv_group(pa.SHORT, 4, HKV_T, D_T, BS_T, jnp.float32, nb)
+        assert k == pa.GROUP_MAX == 8
+        n = int(tiles.count)
+        pos, length = (np.asarray(a)[:n] for a in (tiles.pos, tiles.length))
+        first, last = pa._tile_span(np.arange(n), pos, length, BS_T, window)
+        span = np.asarray(last) - np.asarray(first) + 1
+        short = [(seen, m) for _, seen, m in runs if 0 < m <= pa.SHORT]
+        assert pa.group_steps(short, BS_T, k, window) == (
+            int((-(-span // k)).sum()), int(span.sum()))
+        if not n:       # an empty list: its grid has no row
+            return
+        # walking the grid with the rows the index maps read
+        # (``_group_rows``): while a tile needs it an operand shows that
+        # block of the tile's table, and it changes rows only at a step
+        # whose block the tile needs
+        rows = np.asarray(pa._group_rows(tiles, k, BS_T, window))
+        tables = np.asarray(tiles.tables)
+        first = np.broadcast_to(np.asarray(first), (n,))
+        on = [None] * k
+        fetched = 0
+        for t in range(n):
+            for j in range(-(-int(np.asarray(
+                    tiles.wblocks if window else tiles.blocks)) // k)):
+                for i in range(k):
+                    b = j * k + i
+                    row = rows[t, b]
+                    if b < span[t]:
+                        assert row == tables[t, first[t] + b]
+                    if row != on[i]:
+                        assert b < span[t] or on[i] is None
+                        fetched, on[i] = fetched + 1, row
+        # nothing beyond the needed blocks, but the one fetch that
+        # opens the grid for an operand no tile of the list needs
+        assert fetched <= span.sum() + sum(i >= span.max()
+                                           for i in range(k))
 
     @pytest.mark.parametrize("quant", [False, True], ids=["fp", "int8kv"])
     @pytest.mark.parametrize("li", [0, 1, 2])
@@ -406,6 +506,36 @@ class TestQueryTiles:
             np.testing.assert_allclose(out[mine], ref[mine], atol=1e-5,
                                        rtol=1e-5)
             assert not out[~mine].any()
+
+    @pytest.mark.parametrize("window", [None, 20])
+    @pytest.mark.parametrize("name", ["group-edges", "verify-across-groups",
+                                      "two-chunks", "decode-only"])
+    def test_tables_laid_out_once_a_step(self, name, window):
+        """``group_tiles`` lays a kind's tables out by its calls' grid
+        steps outside the layers (``ragged_forward`` does, once a step):
+        the rows a call would make itself, and the same output."""
+        from deepspeed_tpu.inference.model import _group_tiles, _query_tiles
+        from deepspeed_tpu.ops.paged_attention import (SHORT, _group_rows,
+                                                        kv_group,
+                                                        paged_attention)
+
+        runs, T = TILE_BATCHES[name]
+        batch, _ = _built_batch(runs, T)
+        kv = _random_pool(12)
+        H = HKV_T * 4
+        q = jnp.asarray(np.random.RandomState(5).randn(T, H, D_T),
+                        jnp.float32)
+        tiles = _query_tiles(kv, batch, BS_T, 32, window)
+        laid = _group_tiles(tiles, kv, H, window)
+        k = kv_group(SHORT, 4, HKV_T, D_T, BS_T, jnp.float32, 32)
+        assert k > 1 and tiles.short.rows is None
+        np.testing.assert_array_equal(
+            np.asarray(laid.short.rows),
+            np.asarray(_group_rows(tiles.short, k, BS_T, window)))
+        scale = 1.0 / np.sqrt(D_T)
+        np.testing.assert_array_equal(
+            np.asarray(paged_attention(kv, q, laid, scale, window=window)),
+            np.asarray(paged_attention(kv, q, tiles, scale, window=window)))
 
     @pytest.mark.parametrize("name", sorted(TILE_BATCHES))
     def test_tile_lists_and_host_count_agree(self, name):
@@ -495,6 +625,44 @@ class TestTileCounter:
         assert len(self._stage_spans(eng)) == 2
         assert eng.metrics_snapshot()["serving_attn_tiles_total"] == tiles
 
+    def test_group_steps_of_the_short_call(self):
+        """``kv_steps_full`` on the stage span, the counter
+        ``serving_attn_kv_group_steps_total`` and the gauge
+        ``serving_attn_kv_group_fill``: the grid steps the decode
+        tokens' call makes in a layer that hold a needed block, with
+        the group the kernel's own rule gives the engine's shapes."""
+        import deepspeed_tpu  # noqa: F401
+        from tests.test_inference import make_fp32_engine, tiny_model
+        from deepspeed_tpu.inference import SamplingParams
+        from deepspeed_tpu.ops.paged_attention import SHORT, kv_group
+
+        eng = make_fp32_engine(tiny_model(max_seq_len=256),
+                               attn_impl="pallas", token_budget=192,
+                               max_seqs=4, kv_block_size=8,
+                               num_kv_blocks=96, trace=True)
+        k = kv_group(SHORT, 2, 2, 16, 8, jnp.float32,
+                     eng.max_blocks_per_seq)
+        assert k == 8
+        sp = SamplingParams(temperature=0.0, max_new_tokens=1 << 30)
+        eng.put(1, list(range(1, 101)))     # a chunk: no short tile
+        eng.put(2, [5, 6, 7])               # a run of three: one block
+        first = eng.step(sampling=sp)
+        span = self._stage_spans(eng)[-1]["args"]
+        assert span["kv_steps_full"] == 1 and "kv_steps_window" not in span
+        assert eng.metrics_snapshot()[
+            "serving_attn_kv_group_fill"] == pytest.approx(1 / k)
+        for uid, tok in first.items():
+            eng.put(uid, [tok])
+        eng.step(sampling=sp)
+        # 101 tokens are 13 blocks, two grid steps of eight; 4 are one
+        span = self._stage_spans(eng)[-1]["args"]
+        assert span["kv_steps_full"] == 2 + 1
+        snap = eng.metrics_snapshot()
+        assert snap["serving_attn_kv_group_steps_total"] == {
+            '{kind="full"}': 4}
+        assert snap["serving_attn_kv_group_fill"] == pytest.approx(
+            (1 + 13 + 1) / (4 * k))
+
     def test_xla_formulation_counts_no_tiles(self):
         import deepspeed_tpu  # noqa: F401
         from tests.test_inference import make_fp32_engine, tiny_model
@@ -506,7 +674,11 @@ class TestTileCounter:
                                          max_new_tokens=4))
         (span,) = self._stage_spans(eng)
         assert "n_tiles_short" not in span["args"]
-        assert eng.metrics_snapshot()["serving_attn_tiles_total"] == 0
+        assert "kv_steps_full" not in span["args"]
+        snap = eng.metrics_snapshot()
+        assert snap["serving_attn_tiles_total"] == 0
+        assert snap["serving_attn_kv_group_steps_total"] == 0
+        assert "serving_attn_kv_group_fill" not in snap
 
 
 def logits_idx_rows(batch):

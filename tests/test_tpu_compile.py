@@ -27,7 +27,11 @@ from deepspeed_tpu.models.presets import PRESETS
 from deepspeed_tpu.ops.flash_attention import flash_attention
 from deepspeed_tpu.ops.grouped_matmul import grouped_matmul
 from deepspeed_tpu.ops.mixed_gemm import mixed_matmul
-from deepspeed_tpu.ops.paged_attention import paged_attention, query_tiles
+from deepspeed_tpu.ops.paged_attention import (GROUP_SCORE_BYTES,
+                                               GROUP_VMEM_BYTES, LONG, SHORT,
+                                               block_vmem_bytes,
+                                               group_vmem_bytes, kv_group,
+                                               paged_attention, query_tiles)
 from deepspeed_tpu.ops.quant import QuantizedTensor
 
 LLAMA = PRESETS["llama3-8b"]
@@ -97,6 +101,34 @@ PAGED = {
 }
 
 
+def _paged_calls(jaxpr):
+    """(equation, is it inside a scan, height, KV blocks a grid step of
+    it holds) for the calls of ``ops/paged_attention.py`` in ``jaxpr``:
+    a step's group is the pool handed to the call once a block."""
+    for e, scanned in _eqns(jaxpr):
+        if e.primitive.name != "pallas_call" or not e.params[
+                "name"].startswith("paged_attention"):
+            continue
+        blocks = [bm for bm in e.params["grid_mapping"].block_mappings
+                  if len(bm.block_shape) == 5]
+        yield (e, scanned, int(e.params["name"].rpartition("_h")[2]),
+               len(blocks))
+
+
+def _tiled_bytes(aval) -> int:
+    """VMEM a scratch buffer takes, its trailing dims padded to whole
+    (sublane, lane) tiles of its type."""
+    if not hasattr(aval.dtype, "itemsize"):     # semaphores
+        return 0
+    *lead, rows, lanes = (1,) + tuple(aval.shape)
+    sub = 32 // aval.dtype.itemsize
+    n = aval.dtype.itemsize * (-(-rows // sub) * sub) * (-(-lanes // 128)
+                                                          * 128)
+    for d in lead:
+        n *= d
+    return n
+
+
 @pytest.mark.parametrize("case", sorted(PAGED))
 def test_paged_attention_compiles(one_chip, on_chip, case):
     c = PAGED[case]
@@ -117,7 +149,30 @@ def test_paged_attention_compiles(one_chip, on_chip, case):
                                window=c.get("window"))
 
     # the one kernel body at its two heights
-    assert _compile(fn, kv, q, idx, idx, S((T,), jnp.bool_), tables) == 2
+    shapes = (kv, q, idx, idx, S((T,), jnp.bool_), tables)
+    assert _compile(fn, *shapes) == 2
+    # each call's grid step holds the group the rule gives its shape,
+    # and what the short call keeps in VMEM (the group's double buffers
+    # and score tile, its scratch) is inside the rule's budget and well
+    # inside what Mosaic scopes by default
+    calls = {h: (e, k) for e, _, h, k in _paged_calls(
+        jax.make_jaxpr(fn)(*shapes).jaxpr)}
+    assert sorted(calls) == [SHORT, LONG]
+    kv_dtype = jnp.int8 if c["kv_quant"] else jnp.bfloat16
+    for height, (e, k) in calls.items():
+        assert k == kv_group(height, H // Hkv, Hkv, D, bs, kv_dtype,
+                             c["nb"], c["kv_quant"])
+    e, k = calls[SHORT]
+    assert k > 1
+    rows = SHORT * H // Hkv
+    held = group_vmem_bytes(k, rows, Hkv, D, bs, kv_dtype, c["kv_quant"])
+    assert rows * k * bs * 4 <= GROUP_SCORE_BYTES
+    assert 2 * k * block_vmem_bytes(Hkv, D, bs, kv_dtype,
+                                    c["kv_quant"]) < held <= GROUP_VMEM_BYTES
+    gm = e.params["grid_mapping"]
+    scratch = sum(_tiled_bytes(v.aval) for v in
+                  e.params["jaxpr"].invars[-gm.num_scratch_operands:])
+    assert held + scratch <= 16 * 1024 * 1024
 
 
 # ---------------------------------------------------------------- flash
@@ -268,28 +323,37 @@ def _eqns(jaxpr, inside_scan=False):
             yield from _eqns(sub, inside_scan or e.primitive.name == "scan")
 
 
-def _tile_grid_conditions(jaxpr, T, seqs, mbs):
+def _tile_grid_conditions(jaxpr, T, seqs, mbs, short_group):
     """The tile grid of ``ops/paged_attention.py`` in the step's jaxpr:
     two calls a layer (one kernel body, two heights), each over a traced
     count of tiles whose static bound is at most ``T/128 + seqs`` rows of
-    ``mbs`` blocks, and the block-table rows those tiles carry gathered
-    once a step, outside the layer scan."""
-    calls = [(e, scanned) for e, scanned in _eqns(jaxpr)
-             if e.primitive.name == "pallas_call"
-             and e.params["name"].startswith("paged_attention")]
-    assert len(calls) == 2 and all(scanned for _, scanned in calls)
-    for e, _ in calls:
+    ``ceil(mbs / group)`` groups of KV blocks, and the block-table rows
+    those tiles carry gathered once a step, outside the layer scan."""
+    calls = list(_paged_calls(jaxpr))
+    assert len(calls) == 2 and all(scanned for _, scanned, _, _ in calls)
+    assert sorted(h for _, _, h, _ in calls) == [SHORT, LONG]
+    for e, _, height, group in calls:
         gm = e.params["grid_mapping"]
-        assert gm.num_dynamic_grid_bounds == 2      # (tiles, blocks)
+        assert gm.num_dynamic_grid_bounds == 2      # (tiles, groups)
         tables = e.invars[gm.num_dynamic_grid_bounds].aval
         assert tables.shape[1] == mbs
-        assert tables.shape[0] * mbs <= (T // 128 + seqs) * mbs
+        # a tile's grid row is as long as the deepest tile's groups of
+        # KV blocks: the decode tokens' call walks that much fewer steps
+        assert group == (short_group if height == SHORT else group)
+        assert tables.shape[0] * -(-mbs // group) <= (
+            T // 128 + seqs) * -(-mbs // group)
+    # (the tables laid out by the calls' grid steps, ``_group_rows``,
+    # among them)
     table_gathers = [scanned for e, scanned in _eqns(jaxpr)
                      if e.primitive.name == "gather"
                      and e.outvars[0].aval.ndim == 2
                      and e.outvars[0].aval.shape[1] >= mbs
                      and e.outvars[0].aval.dtype == jnp.int32]
     assert table_gathers and not any(table_gathers)
+    laid_out = [scanned for e, scanned in _eqns(jaxpr)
+                if e.primitive.name == "jit"
+                and e.params["name"] == "_group_rows"]
+    assert laid_out and not any(laid_out)
 
 
 def test_moe_serving_step_compiles_and_fits(one_chip, on_chip):
@@ -309,7 +373,9 @@ def test_moe_serving_step_compiles_and_fits(one_chip, on_chip):
     text = compiled.as_text()
     # the paged kernel at its two heights, the grouped kernel's three
     assert text.count("tpu_custom_call") == 5
-    _tile_grid_conditions(compiled.jaxpr, T=512, seqs=64, mbs=16)
+    # 16 kv heads: a block is 512 KB and four fill the group's budget
+    _tile_grid_conditions(compiled.jaxpr, T=512, seqs=64, mbs=16,
+                          short_group=4)
     leaf_bytes = cfg.num_experts * cfg.d_model * cfg.d_ff * 2
     assert _moves_of(text, 1), "the reader no longer finds any copy"
     assert _moves_of(text, leaf_bytes) == []
@@ -390,7 +456,8 @@ def test_serving_step_keeps_the_pool_in_place(one_chip, on_chip, kv_quant):
     # (76.8 MB a layer, as before the tile grid)
     assert compiled.memory_analysis().temp_size_in_bytes < (
         100e6 if kv_quant else 16e6)
-    _tile_grid_conditions(compiled.jaxpr, T=512, seqs=64, mbs=16)
+    _tile_grid_conditions(compiled.jaxpr, T=512, seqs=64, mbs=16,
+                          short_group=8)
 
 
 # ------------------------------------------------ ZeRO-3 over four chips

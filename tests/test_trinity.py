@@ -350,8 +350,13 @@ WINDOW_BATCHES = dict(TILE_BATCHES, **{
     "chunk-through-the-window": ([(1, 3, 300)], 320),
 })
 
+# 64 = eight blocks, one group of the short call's grid step: a decode
+# token at 100 reads blocks 4..12 (its first block is not a multiple of
+# the group, W/bs + 1 blocks, two grid steps); 68 = a window that starts
+# inside a block
 
-@pytest.mark.parametrize("window", [24, 20, 8])
+
+@pytest.mark.parametrize("window", [24, 20, 8, 64, 68])
 @pytest.mark.parametrize("name", sorted(WINDOW_BATCHES))
 def test_window_kernel_matches_masked_xla(name, window):
     runs, T = WINDOW_BATCHES[name]
@@ -471,6 +476,41 @@ def test_stage_span_counters_and_gauge(tiny):
     # sequence 1 holds 41 tokens: its next query sees positions 26..41
     assert snap["serving_kv_tokens_behind_window"] == 41 - W + 1
     assert snap["serving_moe_assignments_total"] > 0
+
+
+def test_window_layers_count_their_own_group_steps(tiny):
+    """Under the Pallas kernel the stage span carries the grid steps
+    the short call makes in a full layer and in a window layer: a decode
+    token at context 41 reads 6 blocks of 8 in a full layer and, behind
+    a window of 16, the 3 its window touches."""
+    from deepspeed_tpu.ops.paged_attention import SHORT, kv_group
+    cfg, params, axes = tiny
+    eng = InferenceEngine(
+        Model.from_params(cfg, params, param_axes=axes),
+        InferenceConfig(token_budget=64, max_seqs=4, kv_block_size=8,
+                        num_kv_blocks=64, max_seq_len=128,
+                        attn_impl="pallas", param_dtype=jnp.float32,
+                        kv_dtype=jnp.float32, trace=True))
+    k = kv_group(SHORT, cfg.num_heads // cfg.num_kv_heads, cfg.num_kv_heads,
+                 cfg.head_dim, 8, jnp.float32, eng.max_blocks_per_seq)
+    sp = SamplingParams(temperature=0.0, max_new_tokens=1 << 30)
+    W = cfg.attn_window
+    eng.put(1, list(range(1, 41)))
+    out = eng.step(sampling=sp)
+    eng.put(1, [int(out[1])])
+    eng.step(sampling=sp)
+    span = [e for e in eng.tracer.events()
+            if e["name"] == "ds.serve.stage"][-1]["args"]
+    assert span["kv_steps_full"] == -(-6 // k)
+    first = (40 - (W - 1)) // 8
+    assert span["kv_steps_window"] == -(-(40 // 8 - first + 1) // k)
+    snap = eng.metrics_snapshot()
+    assert snap["serving_attn_kv_group_steps_total"] == {
+        '{kind="full"}': span["kv_steps_full"],
+        '{kind="window"}': span["kv_steps_window"]}
+    assert snap["serving_attn_kv_group_fill"] == pytest.approx(
+        (6 + 40 // 8 - first + 1) / (k * (span["kv_steps_full"]
+                                          + span["kv_steps_window"])))
 
 
 def test_a_model_without_window_layers_counts_the_full_kind_only():
