@@ -577,7 +577,6 @@ class Config(ConfigModel):
     gradient_accumulation_steps: Optional[int] = None
 
     steps_per_print: int = C.STEPS_PER_PRINT_DEFAULT
-    wall_clock_breakdown: bool = False
     gradient_clipping: float = C.GRADIENT_CLIPPING_DEFAULT
     prescale_gradients: bool = False
     gradient_predivide_factor: float = 1.0

@@ -18,8 +18,6 @@ GRADIENT_CLIPPING_DEFAULT = 0.0
 STEPS_PER_PRINT = "steps_per_print"
 STEPS_PER_PRINT_DEFAULT = 10
 
-WALL_CLOCK_BREAKDOWN = "wall_clock_breakdown"
-
 OPTIMIZER = "optimizer"
 SCHEDULER = "scheduler"
 

@@ -483,73 +483,82 @@ def block_apply(cfg: TransformerConfig, lp, x, cos, sin,
         # attention must not silently regain the 1/sqrt(d) factor
         attention_fn = partial(L.causal_attention, scale=cfg.attn_scale)
 
-    h = norm(lp["ln1"], x)
     dt = x.dtype
-    q = jnp.einsum("bsd,dhk->bshk", h, ap["wq"].astype(dt))
-    k = jnp.einsum("bsd,dhk->bshk", h, ap["wk"].astype(dt))
-    v = jnp.einsum("bsd,dhk->bshk", h, ap["wv"].astype(dt))
-    if cfg.attn_bias:
-        q = q + ap["bq"].astype(dt)
-        k = k + ap["bk"].astype(dt)
-        v = v + ap["bv"].astype(dt)
-    if cfg.qk_norm:
-        q = _qk_norm(cfg, ap["q_norm"], q)
-        k = _qk_norm(cfg, ap["k_norm"], k)
-    if cfg.rope_on(kind):
-        q = L.apply_rope(q, cos, sin, positions=positions)
-        k = L.apply_rope(k, cos, sin, positions=positions)
-    if kind == "window":
-        # only the eager attention takes a window (_resolve_attention)
-        o = attention_fn(q, k, v, mask=mask, window=cfg.attn_window)
-    else:
-        o = attention_fn(q, k, v, mask=mask)
-    if cfg.attn_gate:
-        g = jnp.einsum("bsd,dhk->bshk", h, ap["wg"].astype(dt))
-        o = o * jax.nn.sigmoid(g.astype(jnp.float32)).astype(dt)
-    o = jnp.einsum("bshk,hkd->bsd", o, ap["wo"].astype(dt))
-    if cfg.attn_out_bias:
-        o = o + ap["bo"].astype(dt)
-    if cfg.sandwich_norm:
-        o = norm(lp["ln1_post"], o)
-
-    if not cfg.parallel_block:
-        x = x + o
-        h = norm(lp["ln2"], x)
-    elif cfg.parallel_separate_norms:
-        # gpt-neox: the MLP reads its own norm of the ORIGINAL x
-        h = norm(lp["ln2"], x)
-    # parallel residual (falcon/phi): the MLP reads the same ln1 output
-    metrics: Dict[str, Any] = {}
-    if cfg.num_experts > 1 and not dense:
-        from ..parallel import moe as M
-
-        d, metrics = M.moe_ffn(
-            lp["gate"], lp["experts"], h, top_k=cfg.moe_top_k,
-            capacity_factor=cfg.capacity_factor,
-            min_capacity=cfg.min_capacity, activation=act,
-            gated=cfg.gated_mlp, rng=rng, noise_policy=cfg.noise_policy,
-            dispatch_mode=cfg.moe_dispatch,
-            norm_topk=cfg.moe_norm_topk, score=cfg.moe_score,
-            route_scale=cfg.moe_route_scale)
-        if "shared" in lp:       # the dense expert every token takes
-            d = d + _shared_expert(lp["shared"], h, act, cfg.gated_mlp)
-    else:
-        mp = lp["mlp"]
-        u = h @ mp["wi"].astype(dt)
-        if cfg.mlp_bias:
-            u = u + mp["bi"].astype(dt)
-        if cfg.gated_mlp:
-            u = act(h @ mp["wg"].astype(dt)) * u
+    # named scopes at the block's seams, the serving forward's names
+    # (metadata only): autodiff and jax.checkpoint add the pass to an
+    # operation's JAX path, so a device trace tells a layer's forward
+    # from its recomputation and its backward
+    with jax.named_scope("qkv"):
+        h = norm(lp["ln1"], x)
+        q = jnp.einsum("bsd,dhk->bshk", h, ap["wq"].astype(dt))
+        k = jnp.einsum("bsd,dhk->bshk", h, ap["wk"].astype(dt))
+        v = jnp.einsum("bsd,dhk->bshk", h, ap["wv"].astype(dt))
+        if cfg.attn_bias:
+            q = q + ap["bq"].astype(dt)
+            k = k + ap["bk"].astype(dt)
+            v = v + ap["bv"].astype(dt)
+        if cfg.qk_norm:
+            q = _qk_norm(cfg, ap["q_norm"], q)
+            k = _qk_norm(cfg, ap["k_norm"], k)
+        if cfg.rope_on(kind):
+            q = L.apply_rope(q, cos, sin, positions=positions)
+            k = L.apply_rope(k, cos, sin, positions=positions)
+        if cfg.attn_gate:
+            g = jnp.einsum("bsd,dhk->bshk", h, ap["wg"].astype(dt))
+    with jax.named_scope("attn"):
+        if kind == "window":
+            # only the eager attention takes a window (_resolve_attention)
+            o = attention_fn(q, k, v, mask=mask, window=cfg.attn_window)
         else:
-            u = act(u)
-        d = u @ mp["wo"].astype(dt)
-        if cfg.mlp_bias:
-            d = d + mp["bo"].astype(dt)
-    if cfg.sandwich_norm:
-        d = norm(lp["ln2_post"], d)
-    if cfg.parallel_block:
-        return x + o + d, metrics
-    return x + d, metrics
+            o = attention_fn(q, k, v, mask=mask)
+        if cfg.attn_gate:
+            o = o * jax.nn.sigmoid(g.astype(jnp.float32)).astype(dt)
+    with jax.named_scope("attn_out"):
+        o = jnp.einsum("bshk,hkd->bsd", o, ap["wo"].astype(dt))
+        if cfg.attn_out_bias:
+            o = o + ap["bo"].astype(dt)
+        if cfg.sandwich_norm:
+            o = norm(lp["ln1_post"], o)
+
+    with jax.named_scope("ffn"):
+        if not cfg.parallel_block:
+            x = x + o
+            h = norm(lp["ln2"], x)
+        elif cfg.parallel_separate_norms:
+            # gpt-neox: the MLP reads its own norm of the ORIGINAL x
+            h = norm(lp["ln2"], x)
+        # parallel residual (falcon/phi): the MLP reads the same ln1 output
+        metrics: Dict[str, Any] = {}
+        if cfg.num_experts > 1 and not dense:
+            from ..parallel import moe as M
+
+            d, metrics = M.moe_ffn(
+                lp["gate"], lp["experts"], h, top_k=cfg.moe_top_k,
+                capacity_factor=cfg.capacity_factor,
+                min_capacity=cfg.min_capacity, activation=act,
+                gated=cfg.gated_mlp, rng=rng, noise_policy=cfg.noise_policy,
+                dispatch_mode=cfg.moe_dispatch,
+                norm_topk=cfg.moe_norm_topk, score=cfg.moe_score,
+                route_scale=cfg.moe_route_scale)
+            if "shared" in lp:       # the dense expert every token takes
+                d = d + _shared_expert(lp["shared"], h, act, cfg.gated_mlp)
+        else:
+            mp = lp["mlp"]
+            u = h @ mp["wi"].astype(dt)
+            if cfg.mlp_bias:
+                u = u + mp["bi"].astype(dt)
+            if cfg.gated_mlp:
+                u = act(h @ mp["wg"].astype(dt)) * u
+            else:
+                u = act(u)
+            d = u @ mp["wo"].astype(dt)
+            if cfg.mlp_bias:
+                d = d + mp["bo"].astype(dt)
+        if cfg.sandwich_norm:
+            d = norm(lp["ln2_post"], d)
+        if cfg.parallel_block:
+            return x + o + d, metrics
+        return x + d, metrics
 
 
 def apply(cfg: TransformerConfig, params, input_ids, mask=None,
@@ -578,24 +587,26 @@ def apply(cfg: TransformerConfig, params, input_ids, mask=None,
     pl = placement or _AS_IS
     use, keep = pl.use, pl.keep
     dt = dtype or params["embed"]["table"].dtype
-    x = L.embed(use("embed", params["embed"]), input_ids).astype(dt)
-    if cfg.embed_scale is not None:
-        x = x * jnp.asarray(cfg.embed_scale, dt)
-    if cfg.embed_norm:
-        x = _norm(cfg)(use("ln_embed", params["ln_embed"]), x)
-    if cfg.position == "learned":
-        S = input_ids.shape[1]
-        x = x + use("pos_embed",
-                    params["pos_embed"])["table"][:S].astype(dt)
-        cos = sin = None
-    elif cfg.position == "alibi":
-        cos = sin = None
-        # safety net for direct apply() calls: the default eager
-        # attention gains the ALiBi bias (Model wraps attention_fn too)
-        if attention_fn is L.causal_attention:
-            attention_fn = L.make_alibi_attention()
-    else:
-        cos, sin = L.rope_freqs(cfg.rotary_dim, cfg.max_seq_len, cfg.rope_theta)
+    with jax.named_scope("embed"):
+        x = L.embed(use("embed", params["embed"]), input_ids).astype(dt)
+        if cfg.embed_scale is not None:
+            x = x * jnp.asarray(cfg.embed_scale, dt)
+        if cfg.embed_norm:
+            x = _norm(cfg)(use("ln_embed", params["ln_embed"]), x)
+        if cfg.position == "learned":
+            S = input_ids.shape[1]
+            x = x + use("pos_embed",
+                        params["pos_embed"])["table"][:S].astype(dt)
+            cos = sin = None
+        elif cfg.position == "alibi":
+            cos = sin = None
+            # safety net for direct apply() calls: the default eager
+            # attention gains the ALiBi bias (Model wraps attention_fn too)
+            if attention_fn is L.causal_attention:
+                attention_fn = L.make_alibi_attention()
+        else:
+            cos, sin = L.rope_freqs(cfg.rotary_dim, cfg.max_seq_len,
+                                    cfg.rope_theta)
 
     have_rng = rng is not None
     if (pld_theta is not None or ltd_keep is not None) and not have_rng:
@@ -686,26 +697,31 @@ def apply(cfg: TransformerConfig, params, input_ids, mask=None,
     if lead:
         x = outside(x, params["dense_blocks"], 0, lead, 0,
                     name="dense_blocks", dense=True)
-    x, metrics = jax.lax.scan(
-        remat(body), x,
-        (jax.tree.map(lambda a: periods_of(a, 0), params["blocks"]),
-         periods_of(layer_rngs, lead), periods_of(layer_ids, lead)),
-        unroll=max(1, min(cfg.scan_unroll, periods)))
+    # the scan's own work (a layer's weights cut out of the stack, the
+    # saved activations and the weight gradients stacked and cut again)
+    # lies outside the body: it takes this scope, the layers theirs
+    with jax.named_scope("layer_scan"):
+        x, metrics = jax.lax.scan(
+            remat(body), x,
+            (jax.tree.map(lambda a: periods_of(a, 0), params["blocks"]),
+             periods_of(layer_rngs, lead), periods_of(layer_ids, lead)),
+            unroll=max(1, min(cfg.scan_unroll, periods)))
     if tail:
         x = outside(x, params["blocks"], periods * P, tail,
                     lead + periods * P)
     if idx is not None:
         # dropped positions bypass the stack with their embedding
         x = random_ltd_scatter(full_x, x, idx)
-    x = _norm(cfg)(use("ln_f", params["ln_f"]), x)
-    if cfg.tie_embeddings:
-        logits = x @ use("embed", params["embed"])["table"].astype(dt).T
-    else:
-        head = use("lm_head", params["lm_head"])
-        logits = x @ head["kernel"].astype(dt)
-        if cfg.head_bias:
-            logits = logits + head["bias"].astype(dt)
-    logits = keep(logits)
+    with jax.named_scope("unembed"):
+        x = _norm(cfg)(use("ln_f", params["ln_f"]), x)
+        if cfg.tie_embeddings:
+            logits = x @ use("embed", params["embed"])["table"].astype(dt).T
+        else:
+            head = use("lm_head", params["lm_head"])
+            logits = x @ head["kernel"].astype(dt)
+            if cfg.head_bias:
+                logits = logits + head["bias"].astype(dt)
+        logits = keep(logits)
     if with_aux:
         aux = {k: v.mean() for k, v in metrics.items()} if metrics else {}
         tail_ms = [m for m in outside_ms if m]
@@ -739,13 +755,14 @@ def cross_entropy_loss(logits, labels, mask=None,
     ``log_softmax`` so XLA fuses the bf16→fp32 convert into the reduce and
     never materializes an fp32 [B,S,V] buffer (6.6 GB for GPT-2 vocab at
     batch 32·1024 — the difference between fitting in HBM or not)."""
-    lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
-    tgt = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
-    nll = keep(lse - tgt.astype(jnp.float32))
-    if mask is not None:
-        mask = mask.astype(jnp.float32)
-        return (nll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
-    return nll.mean()
+    with jax.named_scope("loss"):
+        lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
+        tgt = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
+        nll = keep(lse - tgt.astype(jnp.float32))
+        if mask is not None:
+            mask = mask.astype(jnp.float32)
+            return (nll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
+        return nll.mean()
 
 
 def lm_loss_fn(cfg: TransformerConfig,
@@ -773,7 +790,8 @@ def lm_loss_fn(cfg: TransformerConfig,
         loss = cross_entropy_loss(logits, labels, tgt_mask,
                                   keep=(placement or _AS_IS).keep)
         if "moe_aux_loss" in aux:
-            loss = loss + cfg.aux_loss_coef * aux["moe_aux_loss"]
+            with jax.named_scope("loss"):
+                loss = loss + cfg.aux_loss_coef * aux["moe_aux_loss"]
             return loss, aux
         return loss
 

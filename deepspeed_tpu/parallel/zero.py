@@ -197,8 +197,11 @@ class ZeroPolicy:
 def _gathered(x, held: NamedSharding, used: NamedSharding):
     """``x`` as its uses read it.  The cotangent goes straight back to the
     layout the parameter is held in: each chip's partial weight gradient
-    (its batch shard's) is reduce-scattered, never summed whole."""
-    return jax.lax.with_sharding_constraint(x, used)
+    (its batch shard's) is reduce-scattered, never summed whole.  Both
+    constraints carry a named scope, so the collectives the partitioner
+    writes for them have a name in whichever pass they run."""
+    with jax.named_scope("zero_gather"):
+        return jax.lax.with_sharding_constraint(x, used)
 
 
 def _gathered_fwd(x, held, used):
@@ -206,7 +209,8 @@ def _gathered_fwd(x, held, used):
 
 
 def _gathered_bwd(held, used, _, ct):
-    return (jax.lax.with_sharding_constraint(ct, held),)
+    with jax.named_scope("zero_scatter"):
+        return (jax.lax.with_sharding_constraint(ct, held),)
 
 
 _gathered.defvjp(_gathered_fwd, _gathered_bwd)

@@ -49,7 +49,7 @@ from ..telemetry import (AnomalyConfig, AnomalyMonitor, DeviceTelemetry,
                          MetricsRegistry, ProfilerCapture, SpanTracer,
                          default_training_detectors)
 from ..utils.logging import log_dist, logger
-from ..utils.timer import SynchronizedWallClockTimer, ThroughputTimer
+from ..utils.timer import ThroughputTimer
 from .loss_scaler import LossScaler, LossScaleState, all_finite
 from .lr_schedules import build_schedule, constant
 from .optimizers import Optimizer, build_optimizer
@@ -302,8 +302,8 @@ class Engine:
         self.global_steps = 0
         self.global_samples = 0
 
-        self.timers = SynchronizedWallClockTimer()
         self.tput = ThroughputTimer(batch_size=self.train_batch_size)
+        self._t_entry: Optional[float] = None   # last train_batch entry
         self._setup_telemetry()
         if monitor is None and (config.tensorboard.enabled
                                 or config.csv_monitor.enabled
@@ -359,6 +359,13 @@ class Engine:
              1000.0, 2000.0, 5000.0, 10000.0, 60000.0),
             "host-side wall ms per train_batch call (dispatch is async: "
             "device time appears here only when something blocks)")
+        self._h_step_interval = reg.histogram(
+            "training_step_interval_ms",
+            (1.0, 2.0, 5.0, 10.0, 20.0, 50.0, 100.0, 200.0, 500.0, 1000.0,
+             2000.0, 5000.0, 10000.0, 60000.0, 600000.0),
+            "wall ms between consecutive train_batch entries: one step's "
+            "time once the device's queue holds the caller back (what "
+            "tput, training_mfu and the flops profile divide by)")
         # compile observatory (docs/OBSERVABILITY.md "Device & compiler
         # telemetry"): always-on host counters — a train-step rebuild
         # after the first is a runtime retrace and warns loudly (the
@@ -403,8 +410,11 @@ class Engine:
         self._compiled_ever: set = set()
         # gated device telemetry (telemetry/device.py): per-program
         # cost_analysis + derived training_mfu / training_hbm_bw_util
-        # gauges (divided by the throughput timer's step wall — the
-        # training dispatch is async, so host phase ms would lie) +
+        # gauges, divided by the throughput timer's step wall: the
+        # interval between consecutive train_batch entries, which a
+        # full device queue holds to the device's own step time (the
+        # dispatch call is async and returns in 2 ms of a 464 ms step,
+        # so neither it nor the host phase ms are a step's time) +
         # memory polling at the steps_per_print boundary.  config:
         # {"telemetry": {"device": true}}
         self.devtel = DeviceTelemetry(
@@ -469,19 +479,17 @@ class Engine:
             return None
         return self._cap.finish_now()
 
-    def _feed_step_signals(self, t0: float, t3: float) -> None:
+    def _feed_step_signals(self, interval_ms: Optional[float],
+                           host_ms: float) -> None:
         """Per-step anomaly feed from timestamps already taken (no
         added clock reads); called only when the monitor exists."""
         anom, prev = self._anom, self._anom_prev
         step = self.global_steps
         fired = []
-        last_t0 = prev.get("t0")
-        prev["t0"] = t0
-        if last_t0 is not None:
-            fired.append(anom.observe("step_interval_ms",
-                                      (t0 - last_t0) * 1e3, step))
-        fired.append(anom.observe("step_host_ms", (t3 - t0) * 1e3,
-                                  step))
+        if interval_ms is not None:
+            fired.append(anom.observe("step_interval_ms", interval_ms,
+                                      step))
+        fired.append(anom.observe("step_host_ms", host_ms, step))
         retr = self._c_retraces.value()
         fired.append(anom.observe("retrace",
                                   retr - prev.get("retrace", 0), step))
@@ -975,8 +983,9 @@ class Engine:
             c = p.astype(self.compute_dtype)
             return jax.lax.with_sharding_constraint(
                 c, NamedSharding(self.topology.mesh, spec))
-        out = jax.tree.map(cast, master, self.param_specs,
-                           self.master_shardings)
+        with jax.named_scope("cast_params"):
+            out = jax.tree.map(cast, master, self.param_specs,
+                               self.master_shardings)
         if qwz and not getattr(self, "_qwz_applied", False) \
                 and not getattr(self, "_qwz_noop_warned", False):
             # plain stage 3: compute and master layouts coincide, so the
@@ -1469,7 +1478,7 @@ class Engine:
             m = put(m, m_host, jax.memory.Space.Host)
             step_h = jax.device_put(step, jax.memory.Space.Host)
             finite_h = jax.device_put(finite, jax.memory.Space.Host)
-            with compute_on("device_host"):
+            with compute_on("device_host"), jax.named_scope("optimizer"):
                 updates, new_o = self.optimizer.update(g, o, m, step_h)
                 new_m = jax.tree.map(lambda p, u: p + u, m, updates)
 
@@ -1530,10 +1539,13 @@ class Engine:
             acc_specs = self.grad_specs
 
         def shard_grads(g):
-            return jax.tree.map(
-                lambda t, spec: jax.lax.with_sharding_constraint(
-                    t, NamedSharding(self.topology.mesh, spec)),
-                g, acc_specs)
+            """fp32 gradients in the ZeRO grad layout."""
+            with jax.named_scope("grad_accumulate"):
+                g = jax.tree.map(lambda t: t.astype(jnp.float32), g)
+                return jax.tree.map(
+                    lambda t, spec: jax.lax.with_sharding_constraint(
+                        t, NamedSharding(self.topology.mesh, spec)),
+                    g, acc_specs)
 
         def pipeline(cparams, batch, rng, scale):
             if gas > 1:
@@ -1543,10 +1555,10 @@ class Engine:
                 def body(acc, xs):
                     mb, r = xs
                     loss, aux, g = grads_of_microbatch(cparams, mb, r, scale)
-                    g = shard_grads(jax.tree.map(
-                        lambda t: t.astype(jnp.float32), g))
+                    g = shard_grads(g)
                     acc_g, acc_loss = acc
-                    acc_g = jax.tree.map(jnp.add, acc_g, g)
+                    with jax.named_scope("grad_accumulate"):
+                        acc_g = jax.tree.map(jnp.add, acc_g, g)
                     return (acc_g, acc_loss + loss), aux
 
                 W = int(np.prod([self.topology.axis_sizes[a]
@@ -1566,8 +1578,7 @@ class Engine:
             else:
                 loss, aux, grads = grads_of_microbatch(cparams, batch, rng,
                                                        scale)
-                grads = shard_grads(jax.tree.map(
-                    lambda t: t.astype(jnp.float32), grads))
+                grads = shard_grads(grads)
             return loss, aux, grads
 
         return pipeline
@@ -1581,10 +1592,12 @@ class Engine:
         predivide = self.config.gradient_predivide_factor
 
         def epilogue(grads, scale):
-            denom = scale * (predivide if prescale else 1.0)
-            grads = jax.tree.map(lambda g: g / denom, grads)
-            finite = all_finite(grads) if use_scaling else jnp.asarray(True)
-            grads, gnorm = clip_by_global_norm(grads, clip)
+            with jax.named_scope("grad_epilogue"):
+                denom = scale * (predivide if prescale else 1.0)
+                grads = jax.tree.map(lambda g: g / denom, grads)
+                finite = all_finite(grads) if use_scaling \
+                    else jnp.asarray(True)
+                grads, gnorm = clip_by_global_norm(grads, clip)
             return grads, finite, gnorm
         return epilogue
 
@@ -1650,10 +1663,12 @@ class Engine:
             step_next = state.step + 1
 
             def update_master(grads, opt_state, master):
-                updates, new_opt = opt_update(
-                    grads, opt_state, master, step_next)
-                new_master = jax.tree.map(lambda p, u: p + u, master, updates)
-                return sel(new_master, master), sel(new_opt, opt_state)
+                with jax.named_scope("optimizer"):
+                    updates, new_opt = opt_update(
+                        grads, opt_state, master, step_next)
+                    new_master = jax.tree.map(lambda p, u: p + u, master,
+                                              updates)
+                    return sel(new_master, master), sel(new_opt, opt_state)
 
             if offloaded:
                 new_master, new_opt = self._offload_update(
@@ -1734,13 +1749,11 @@ class Engine:
     def _train_batch_nvme(self, batch, rng) -> Dict[str, Any]:
         if self._stream is not None:
             # per-layer param streaming: the host loop IS the step
-            self.tput.start()
             metrics = self._stream.train_batch(batch, rng)
             return self._finish_step(batch, rng, metrics)
         if self._nvme_step_fn is None:
             self._nvme_step_fn = self._build_nvme_step()
         batch = self.shard_batch(batch)
-        self.tput.start()
         try:
             grads, finite, new_scale_state, metrics = \
                 self._nvme_step_fn(self.state, batch, rng)
@@ -1827,6 +1840,14 @@ class Engine:
         tr = self.tracer
         sid = self.global_steps + 1
         t0 = tr.phase("ds.train.pre_step", track="pre_step", step=sid)
+        # one step's wall is the interval between consecutive entries
+        # (no new clock read): the step itself is dispatched async
+        last, self._t_entry = self._t_entry, t0
+        interval_ms = None
+        if last is not None:
+            interval_ms = (t0 - last) * 1e3
+            self._h_step_interval.observe(interval_ms)
+            self.tput.record(t0 - last)
         if rng is None:
             rng = jax.random.PRNGKey(self.config.seed + self.global_steps)
         if self.curriculum or self.pld or self._ltd_cfg or self.moq:
@@ -1840,7 +1861,6 @@ class Engine:
         step_fn = self._pick_train_step()
         batch = self.shard_batch(batch)
         t2 = tr.phase("ds.train.dispatch", track="dispatch", step=sid)
-        self.tput.start()
         try:
             self.state, metrics = step_fn(self.state, batch, rng)
             if self.offload_active and not self._offload_validated:
@@ -1877,7 +1897,7 @@ class Engine:
         self._h_step_host.observe((t3 - t0) * 1e3)
         if self._anom is not None:
             # detectors fed from the timestamps above — no added reads
-            self._feed_step_signals(t0, t3)
+            self._feed_step_signals(interval_ms, (t3 - t0) * 1e3)
         return self._finish_step(batch, rng, metrics)
 
     def _pick_train_step(self):
@@ -1921,7 +1941,6 @@ class Engine:
         # actually looks
         self._last_metrics = metrics
         self._last_metrics_host = None
-        self.tput.stop()
         fp_cfg = self.config.flops_profiler
         if fp_cfg.enabled and self.global_steps == fp_cfg.profile_step:
             self._write_flops_profile(batch, rng)
@@ -2012,7 +2031,7 @@ class Engine:
         stats = analyze_fn(self._train_step_fn or self._nvme_step_fn,
                            self.state, batch, rng)
         stats["params"] = float(param_count(self.state.master))
-        # total_elapsed_time only counts steps after tput.start_step
+        # total_elapsed_time only counts intervals after tput.start_step
         counted = self.tput.global_step_count - self.tput.start_step
         if counted > 0 and self.tput.total_elapsed_time:
             stats["latency_s"] = self.tput.total_elapsed_time / counted

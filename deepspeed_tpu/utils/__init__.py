@@ -1,5 +1,4 @@
 from .logging import logger, log_dist, warning_once
-from .timer import SynchronizedWallClockTimer, ThroughputTimer
+from .timer import ThroughputTimer
 
-__all__ = ["logger", "log_dist", "warning_once",
-           "SynchronizedWallClockTimer", "ThroughputTimer"]
+__all__ = ["logger", "log_dist", "warning_once", "ThroughputTimer"]

@@ -20,9 +20,14 @@ def test_deepspeed_alias_micro_batch():
     assert cfg.train_micro_batch_size_per_device == 2
 
 
-def test_unknown_key_rejected():
+@pytest.mark.parametrize("cfg", [
+    {"train_batch_sizes": 8},
+    # accepted and consumed nowhere until PR 40 took the field away
+    {"wall_clock_breakdown": True},
+])
+def test_unknown_key_rejected(cfg):
     with pytest.raises(ConfigError, match="Unknown key"):
-        load_config({"train_batch_sizes": 8})
+        load_config(cfg)
 
 
 def test_duplicate_json_key_rejected(tmp_path):

@@ -114,6 +114,48 @@ class TestFlopsProfiler:
         assert "FLOPs" in flops and "MACs" in macs
 
 
+class TestStepInterval:
+    def test_step_time_is_the_interval_between_entries(self):
+        """The engine's own speed figures divide by the interval between
+        consecutive ``train_batch`` entries, not by the asynchronous
+        dispatch call: a fake clock on which a step lasts 0.5 s and the
+        whole of ``train_batch`` 3 ms."""
+        p, ax, loss_fn = make_mlp()
+        eng = ds.initialize(loss_fn=loss_fn, params=p, param_axes=ax, config={
+            "train_micro_batch_size_per_device": 4,
+            "optimizer": {"type": "adamw", "params": {"lr": 1e-2}},
+            "mesh": {"data": 8}, "steps_per_print": 1000})
+
+        class Clock:
+            """The tracer's three calls, each a millisecond after the
+            last; ``t`` is set by the test before every step."""
+            t = 0.0
+
+            def phase(self, name, **kw):
+                self.t += 1e-3
+                return self.t
+
+            def phase_end(self, **kw):
+                return self.phase(None)
+
+        clock = eng.tracer = Clock()
+        steps = 7
+        for i in range(steps):
+            clock.t = 100.0 + 0.5 * i
+            eng.train_batch(make_batch(eng.train_batch_size, seed=i))
+        snap = eng.metrics_snapshot()
+        gaps = snap["training_step_interval_ms"]
+        assert gaps["count"] == steps - 1
+        assert gaps["sum"] == pytest.approx(500.0 * (steps - 1))
+        assert snap["training_step_host_ms"]["sum"] == pytest.approx(
+            3.0 * steps)
+        # the first two intervals (compile, warm-up) are left out
+        counted = steps - 1 - eng.tput.start_step
+        assert eng.tput.total_elapsed_time == pytest.approx(0.5 * counted)
+        assert eng.tput.avg_samples_per_sec() == pytest.approx(
+            eng.train_batch_size / 0.5)
+
+
 class TestEnvReport:
     def test_env_report_runs(self, capsys):
         from deepspeed_tpu.env_report import main

@@ -146,6 +146,44 @@ def test_readers_report_nothing_without_ds_events(tmp_path):
     assert ps.of({"kind": "serve", "trace_dir": str(tmp_path)}) is None
 
 
+def at(a, b):
+    """A window in the fixture's round microseconds; the file's times lie
+    5 us after them."""
+    return ((a + 5) * US, (b + 5) * US)
+
+
+@pytest.mark.parametrize("window,ops,idle_us,by_us", [
+    # no window: first operation to last
+    (None, None, 480, {}),
+    # [250,650]: of [200,300] only the dispatch's [250,275] and the wait's
+    # [275,300] are left, of [500,600] all of it; [40,60] lies before it
+    (at(250, 650), None, 200,
+     {"ds.serve.dispatch": 55, "ds.serve.wait": 35, "ds.gateway.route": 10,
+      "ds.gateway.apply": 20, "handoff": 40, "ds.serve.schedule": 20,
+      "ds.serve.stage": 20, "unattributed": 0}),
+    # a window that opens and closes while the device idles: the stretches
+    # from its edges to the first and from the last operation count
+    (at(420, 800), None, 230,
+     {"ds.gateway.apply": 20, "ds.serve.wait": 110}),
+    # a device busy past both edges has no idle to book
+    (at(300, 400), [(0.0, 1.0, "%fusion.1 = f32[8] fusion(%a)")], 0, {}),
+])
+def test_idle_is_booked_inside_the_window_only(parts, window, ops, idle_us,
+                                               by_us):
+    """ISSUE 33's cases for ``book_idle``'s ``(lo, hi)`` (they stand in
+    ``benchmarks/tests/test_trace_window.py`` too, which tier-1 does not
+    run)."""
+    threads, recorded = parts
+    booked = ps.book_idle(threads, ops or recorded, window=window)
+    assert booked["window"] == pytest.approx(window or at(0, 1000))
+    assert booked["idle_s"] == pytest.approx(idle_us * US)
+    assert sum(booked["by_span"].values()) == pytest.approx(booked["idle_s"])
+    for name, us in by_us.items():
+        assert booked["by_span"].get(name, 0.0) == pytest.approx(us * US)
+    if not idle_us:
+        assert booked["by_span"] == {}
+
+
 def test_scopes_by_whole_path_component():
     assert ps.scope_of(("jit(pstep)/while/body/kv_write/dynamic_update_slice",
                         "@model.py")) == "kv_write"
