@@ -36,7 +36,6 @@ def main(trace_path=None, profile_dir=None):
     seq = 1024 if on_tpu else 128
     batch = 32 if on_tpu else 2
     model = build_model("gpt2", max_seq_len=seq, remat=False,
-                        scan_unroll=12,
                         attention_impl="xla_flash",
                         **({} if on_tpu else
                            dict(num_layers=2, d_model=128, num_heads=4,
@@ -413,7 +412,7 @@ def moe_train_bench(on_tpu: bool, peak: float):
             "gpt2", max_seq_len=seq, num_experts=8, moe_top_k=2,
             moe_dispatch=mode,
             **(dict(num_layers=6, d_model=768, num_heads=12,
-                    scan_unroll=6, remat=False,
+                    remat=False,
                     attention_impl="xla_flash") if on_tpu else
                dict(num_layers=2, d_model=128, num_heads=4,
                     vocab_size=1024)))
@@ -483,7 +482,7 @@ def llama_train_bench(on_tpu: bool, peak: float):
         "llama-tiny",
         **(dict(vocab_size=32000, num_layers=12, d_model=2048,
                 num_heads=16, num_kv_heads=8, d_ff=5504, max_seq_len=seq,
-                scan_unroll=12, remat=True, remat_policy="xla_flash",
+                remat=True, remat_policy="xla_flash",
                 attention_impl="xla_flash") if on_tpu else
            dict(vocab_size=512, num_layers=2, d_model=128, num_heads=4,
                 num_kv_heads=2, d_ff=352, max_seq_len=seq)))

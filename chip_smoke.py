@@ -68,7 +68,7 @@ INT8_LOGIT_REL = 0.15   # int8 row-wise weights vs the dense forward
 LOSS_REL = 2e-2         # ZeRO-3 on four devices vs one
 
 REAL = dict(
-    train=dict(overrides=dict(max_seq_len=1024, remat=False, scan_unroll=12,
+    train=dict(overrides=dict(max_seq_len=1024, remat=False,
                               attention_impl="xla_flash"),
                seq=1024, batch=32, steps=5),
     serve=dict(overrides=dict(max_seq_len=512), layers=8, int8_layers=2,

@@ -9,9 +9,12 @@ per-model classes.
 
 TPU-first choices:
 * layer params are **stacked** on a leading ``layers`` dim and the block is
-  applied with ``lax.scan`` — one compiled layer body regardless of depth
-  (fast compiles, natural ``jax.checkpoint`` remat point, and the natural
-  unit for pipeline staging later);
+  applied with ``lax.scan``: one traced layer body regardless of depth, the
+  natural ``jax.checkpoint`` remat point and the natural unit for pipeline
+  staging.  Up to ``UNROLL_MAX_LAYERS`` layers (12) the scan is unrolled
+  whole, so the compiled program holds a copy of the body per layer and no
+  per-layer dynamic slice or update of a stack; a deeper model keeps the
+  rolled loop (one compiled body, fast compiles);
 * logical axes on every param (see parallel/sharding.py) give Megatron-style
   TP (column-parallel qkv/up, row-parallel out/down) with zero model code;
 * attention is pluggable: XLA softmax attention today, Pallas flash /
@@ -98,10 +101,6 @@ class TransformerConfig:
     # xla (stock softmax autodiff) | xla_flash (flash-style custom VJP in
     # pure XLA, ops/xla_attention.py) | flash (Pallas kernel)
     attention_impl: str = "xla_flash"
-    # layer-scan unroll factor (lax.scan unroll=): >1 trades compile time
-    # for removing per-layer dynamic-update-slice traffic on the scan
-    # carries (profiled at ~20% of a GPT-2s step on v5e)
-    scan_unroll: int = 1
     # gpt-neo: attention WITHOUT the 1/sqrt(d) scaling; None = default
     attn_scale: Optional[float] = None
     # --- MoE (reference: deepspeed/moe; presets: mixtral) ----------------
@@ -561,6 +560,31 @@ def block_apply(cfg: TransformerConfig, lp, x, cos, sin,
         return x + d, metrics
 
 
+# The layer scan runs unrolled over its whole trip count where its trips
+# hold up to this many layers together (a trip is a period of the layer
+# pattern: one layer, or a few), and rolled above that.  Rolled, every
+# trip cuts its layer's weights and saved activations out of their stacks
+# and writes the weight gradients and the activations into theirs, and
+# the matmuls beside them wait on those stacks; unrolled whole, the slices
+# are static and a stacked gradient is assembled once.  An unroll between
+# 1 and the trip count keeps the stacks and multiplies the bodies, so
+# there is none.  The ceiling is the chip's (TPU v5e; PERF.md section 6,
+# PR 41): 6 layers of pythia-1.4b train 16% faster unrolled, for 29 s
+# more of a first compile and 1.5 s of a warm start; GPT-2 small's 12
+# layers without recomputation fit a chip only unrolled (27 GB rolled:
+# every residual is kept stacked); pythia-1.4b's 24 layers under ZeRO-3
+# over four chips gain nothing (-0.2%) and start 27 s later from a warm
+# compile cache.  Between 12 and 24 nothing is measured.
+UNROLL_MAX_LAYERS = 12
+
+
+def layers_unrolled(cfg: TransformerConfig) -> int:
+    """The layers :func:`apply`'s scan runs unrolled, outside any loop of
+    the compiled program; 0: the scan is rolled."""
+    layers = cfg.layer_plan[1] * len(cfg.layer_pattern)
+    return layers if layers <= UNROLL_MAX_LAYERS else 0
+
+
 def apply(cfg: TransformerConfig, params, input_ids, mask=None,
           attention_fn: Callable = L.causal_attention,
           dtype=None, rng=None, with_aux: bool = False,
@@ -699,13 +723,16 @@ def apply(cfg: TransformerConfig, params, input_ids, mask=None,
                     name="dense_blocks", dense=True)
     # the scan's own work (a layer's weights cut out of the stack, the
     # saved activations and the weight gradients stacked and cut again)
-    # lies outside the body: it takes this scope, the layers theirs
+    # lies outside the body: it takes this scope, the layers theirs.
+    # Unrolled it stays ONE scan: the body is traced once and the
+    # transposed scan stacks a leaf's gradient with one concatenate (a
+    # Python loop over a[i] pads every layer's to the whole stack and sums)
     with jax.named_scope("layer_scan"):
         x, metrics = jax.lax.scan(
             remat(body), x,
             (jax.tree.map(lambda a: periods_of(a, 0), params["blocks"]),
              periods_of(layer_rngs, lead), periods_of(layer_ids, lead)),
-            unroll=max(1, min(cfg.scan_unroll, periods)))
+            unroll=periods if layers_unrolled(cfg) else 1)
     if tail:
         x = outside(x, params["blocks"], periods * P, tail,
                     lead + periods * P)
@@ -796,6 +823,8 @@ def lm_loss_fn(cfg: TransformerConfig,
         return loss
 
     loss_fn.uses_pld = pld
+    # read when the engine builds its step, as apply reads it when traced
+    loss_fn.layers_unrolled = lambda: layers_unrolled(cfg)
     loss_fn.with_ltd = lambda keep: lm_loss_fn(
         cfg, attention_fn, pld=pld, ltd_keep=keep, placement=placement)
     loss_fn.with_placement = lambda pl: lm_loss_fn(

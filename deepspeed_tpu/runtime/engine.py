@@ -513,6 +513,15 @@ class Engine:
                 "#%d) — something invalidated the step executable",
                 key, int(self._c_retraces.value()))
         else:
+            unrolled = getattr(self.loss_fn, "layers_unrolled", None)
+            if unrolled is not None:
+                # what the loss's forward does at this build, from its
+                # layer plan (models/transformer.py layers_unrolled)
+                self.metrics.gauge(
+                    "training_layers_unrolled",
+                    "layers of the layer scan the compiled train step "
+                    "runs unrolled, outside any while loop; 0: the scan "
+                    "is rolled").set(unrolled())
             if self._zero3_gather is not None and not self._compiled_ever:
                 # static, from the specs: no device read
                 leaves, nbytes = self._zero3_gather

@@ -528,9 +528,14 @@ def _zero3_step_compiled(topo, monkeypatch, layers, per_chip, seq):
         eng.state, batch, rng).compile()
 
 
-def test_zero3_step_gathers_parameters_and_fits(topo, on_chip, monkeypatch):
-    """``train-zero3-4chip``'s step at 2 of 24 layers.  What must hold
-    by kind: no all-to-all (q/k/v resharded for the partial rotary, the
+@pytest.mark.parametrize("form", ["rolled", "unrolled"])
+def test_zero3_step_gathers_parameters_and_fits(topo, on_chip, monkeypatch,
+                                                form):
+    """``train-zero3-4chip``'s step at 2 of 24 layers, with the layer scan
+    rolled as the cell's 24 layers run it (the ceiling patched under the
+    two) and unrolled as a model under the ceiling runs it; "inside the
+    layer loop" is under the scan's scope in both.  What must hold by
+    kind: no all-to-all (q/k/v resharded for the partial rotary, the
     logits resharded from vocabulary to batch); inside the layer loop no
     collective carries the batch (every projection all-gathered the whole
     batch's residual stream, 134 MB, before PR 29); each layer's weights
@@ -540,9 +545,13 @@ def test_zero3_step_gathers_parameters_and_fits(topo, on_chip, monkeypatch):
     matmul; the program's temporaries stay a third of what they were
     (6.04 GB at these sizes while each chip ran all 16 sequences)."""
     per_chip, seq = 4, 2048
+    if form == "rolled":
+        from deepspeed_tpu.models import transformer
+        monkeypatch.setattr(transformer, "UNROLL_MAX_LAYERS", 0)
     eng, cfg, compiled = _zero3_step_compiled(topo, monkeypatch, 2,
                                               per_chip, seq)
-    from tests.test_zero3_placement import collectives_of
+    assert (" while(" in compiled.as_text()) == (form == "rolled")
+    from tests.test_zero3_placement import collectives_of, in_layers
     # one entry a channel: XLA repeats an async collective's text
     found = {(kind, ch): (shapes, op) for kind, shapes, op, ch
              in collectives_of(compiled.as_text()) if ch}
@@ -553,13 +562,13 @@ def test_zero3_step_gathers_parameters_and_fits(topo, on_chip, monkeypatch):
         return len(shape) >= 3 and shape[0] in (per_chip, per_chip * 4) \
             and shape[1] == seq
 
-    in_loop = {k: v for k, v in found.items() if "/while/body/" in v[1]}
+    in_loop = {k: v for k, v in found.items() if in_layers(v[1])}
     moved = [(k, s, op) for k, (shapes, op) in found.items()
              for s in shapes if carries_batch(s)]
     # the one exception lies outside the loop: the gradient of the
     # vocabulary-sharded embedding table gathers the batch's cotangent
     # (134 MB) instead of reduce-scattering a table (206 MB)
-    assert all("scatter-add" in op and "/while/body/" not in op
+    assert all("scatter-add" in op and not in_layers(op)
                for _, _, op in moved), moved
     assert len(moved) <= 1
     H, D, dm, ff = cfg.num_heads, cfg.head_dim, cfg.d_model, cfg.d_ff

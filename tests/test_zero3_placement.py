@@ -63,6 +63,12 @@ def collectives_of(text):
     return out
 
 
+def in_layers(op_name):
+    """Whether a JAX path lies inside the layer loop: under the scan's
+    scope, whether the scan runs unrolled or as a ``while``."""
+    return "(layer_scan)" in op_name
+
+
 def engine_for(family, stage, precision="bf16", opt="adamw", lr=1e-3,
                **zero):
     cfg = TransformerConfig(**DIMS, **FAMILIES[family])
@@ -84,12 +90,21 @@ def batch_of(seed=0):
                                       (BATCH, SEQ)).astype(np.int32)}
 
 
+@pytest.mark.parametrize("form", ["unrolled", "rolled"])
 @pytest.mark.parametrize("family", sorted(FAMILIES))
-def test_stage3_gathers_parameters_not_activations(family):
+def test_stage3_gathers_parameters_not_activations(family, form,
+                                                   monkeypatch):
+    """In both forms of the layer scan: unrolled as these three layers
+    run, and rolled as a model over the ceiling runs (the benchmark's 24
+    layers), reached by patching the ceiling."""
+    if form == "rolled":
+        from deepspeed_tpu.models import transformer
+        monkeypatch.setattr(transformer, "UNROLL_MAX_LAYERS", 0)
     eng, cfg = engine_for(family, stage=3)
     step = eng._pick_train_step()
     text = step.lower(eng.state, eng.shard_batch(batch_of()),
                       jax.random.PRNGKey(0)).compile().as_text()
+    assert ("/while/body/" in text) == (form == "rolled")
     found = collectives_of(text)
     kinds = {f[0] for f in found}
     assert "all-to-all" not in kinds and "collective-permute" not in kinds, \
@@ -99,10 +114,10 @@ def test_stage3_gathers_parameters_not_activations(family):
         # no parameter has a dim of SEQ; [b, SEQ] is a per-token scalar
         return SEQ in shape and len(shape) > 2
 
-    in_loop = [f for f in found if "/while/body/" in f[2]]
+    in_loop = [f for f in found if in_layers(f[2])]
     assert in_loop, "the layer scan holds no collective at all"
     for kind, shapes, op, _ in found:
-        if "/while/body/" not in op and "scatter-add" in op:
+        if not in_layers(op) and "scatter-add" in op:
             # the one exception, outside the loop: the gradient of the
             # vocabulary-sharded embedding table gathers the batch's
             # cotangent (rows go to their owners), which is fewer bytes
