@@ -821,6 +821,130 @@ def serve_trinity_phase(sz, seed):
             check(fails, f"a reference with {wrong} agrees with the system")
 
 
+def serve_falcon_h1_phase(sz, seed):
+    """The cell serve-ssm-chat's model (benchmarks/configs/
+    falcon-h1-34b-d6.json, at its published widths) through the engine's
+    paged path against the benchmark's plain reference, by the cell's own
+    three comparisons (benchmarks/lib/drivers/serve_recurrent.py) and
+    under the file's own limit: the cell's three sample sequences
+    prefilled in one step, a long prompt prefilled over several steps,
+    and the sample once more in the slots the others left; 8 fed tokens
+    each.  Then the same logits against every wrong forward the
+    reference knows: each has to FAIL the limit the true forward passes,
+    in the comparison that can see it."""
+    import jax.numpy as jnp
+
+    from benchmarks.lib import common
+    from benchmarks.lib import traffic as T
+    from benchmarks.lib.drivers import serve
+    from benchmarks.lib.drivers import serve_recurrent as R
+    from benchmarks.lib.weights import make_model
+    from deepspeed_tpu.inference import InferenceConfig, InferenceEngine
+
+    _, _, config, mix = common.load_cell("serve-ssm-chat")
+    if sz is TINY:
+        common.apply_rehearsal(config, mix)
+    cfg = R.preset_config(config)
+    model = make_model(cfg, seed + 5, dtype=jnp.bfloat16)
+    ref = common.load_module(
+        os.path.join(common.ROOT, config["reference"]["file"]),
+        "falcon_h1_ref")
+    limit = config["reference"]["tolerance"]["logits_rel"]
+    sample = config["reference"]["sample"]
+    k = int(sample["decode_tokens"])
+    sd = cfg.ssm_dims
+    print(f"  {config['name']}: d{cfg.d_model} H{cfg.num_heads}/"
+          f"{cfg.num_kv_heads}x{cfg.head_dim}, {cfg.num_layers} layers of "
+          f"{cfg.layer_pattern}, mixer {sd.heads}x{sd.head_dim} state "
+          f"{sd.state} groups {sd.groups} chunk {sd.chunk}, bf16, limit "
+          f"{limit}")
+    rng = np.random.RandomState(seed + 5)
+    seqs = {900000 + i: rng.randint(0, cfg.vocab_size, n + k).tolist()
+            for i, n in enumerate(sample["prompt_lens"])}
+    n_prompt = {u: len(s) - k for u, s in seqs.items()}
+    n_long = int(sample["long_prompt"])
+    long_seq = T.rng_for(seed + 5, 11).integers(
+        0, cfg.vocab_size, n_long + k).tolist()
+    sizes = mix["engine"]
+    eng = InferenceEngine(model, InferenceConfig(
+        token_budget=int(sizes["token_budget"]),
+        max_seqs=int(sizes["max_seqs"]),
+        kv_block_size=int(sizes["kv_block_size"]),
+        num_kv_blocks=int(sizes["num_kv_blocks"]),
+        max_seq_len=int(sizes["max_seq_len"]),
+        **config.get("engine_options", {})))
+    report_path(eng)
+    mbs = eng.max_blocks_per_seq
+    first = serve.engine_logits(eng, seqs, n_prompt, mbs)
+    R.left_slots_first(eng)
+    far, steps = R.paged_logits(eng, long_seq, n_long)
+    check(steps >= -(-n_long // eng.icfg.token_budget) + k,
+          f"the long prompt took {steps} steps, not its chunks")
+    R.left_slots_first(eng)
+    again = serve.engine_logits(eng, seqs, n_prompt, mbs)
+    print(f"    long prompt: {n_long} tokens in steps of "
+          f"{eng.icfg.token_budget}, then {k} fed: {steps} steps")
+    u0 = min(seqs)
+
+    def want(tokens, **kw):
+        return np.asarray(ref.logits(model.params, np.asarray(tokens),
+                                     config, last=k + 1, **kw), np.float32)
+
+    def pair(got, ref_rows):
+        got = np.stack(got) if isinstance(got, list) else got
+        return R.rel(got[:1], ref_rows[:1]), R.rel(got[1:], ref_rows[1:])
+
+    def readings(wrong, **kw):
+        """(sample prefill, sample decode, chunked prefill, chunked
+        decode, reused-slots prefill, reused-slots decode); the sample's
+        are the largest over its sequences.  ``at`` defaults to the
+        prompt's end of each sequence."""
+        def at(n):
+            return dict(kw, at=kw.get("at", n)) if wrong in (
+                "state_reset", "tail_cut") else kw
+        refs = {u: want(s, wrong=wrong, **at(n_prompt[u]))
+                for u, s in seqs.items()}
+        one = [pair(first[u], refs[u]) for u in seqs]
+        two = [pair(again[u], refs[u]) for u in seqs]
+        return (max(p for p, _ in one), max(d for _, d in one),
+                *pair(far, want(long_seq, wrong=wrong, **at(n_long))),
+                max(p for p, _ in two), max(d for _, d in two))
+
+    names = ("sample prefill", "sample decode", "chunked prefill",
+             "chunked decode", "reused prefill", "reused decode")
+
+    def line(got):
+        return ", ".join(f"{n} {v:.4g}" for n, v in zip(names, got))
+
+    true = readings(None)
+    print("    true forward: " + line(true))
+    check(max(true) <= limit, f"the engine differs from the reference "
+          f"beyond {limit}: {true}")
+    # where each fault shows: a state or a tail lost at the prompt's end
+    # shows on the fed tokens; a stale state in the slot's next sequence
+    sees = {"state_reset": (1, 3, 5), "tail_cut": (1, 3, 5),
+            "stale_state": (4, 5)}
+    for wrong in ref.WRONG:
+        kw = {"before": np.asarray(long_seq)} if wrong == "stale_state" \
+            else {}
+        got = readings(wrong, **kw)
+        seen = [got[i] for i in sees.get(wrong, range(6))]
+        fails = min(seen) > limit
+        print(f"    reference with {wrong}: " + line(got)
+              + ("  (fails)" if fails else "  (PASSES)"))
+        if sz is not TINY:
+            check(fails, f"a reference with {wrong} agrees with the "
+                  "system where the fault would show")
+    if sz is not TINY:
+        # a tail cut where the prompt's last step began, far from the
+        # rows compared: for the record, not a check
+        cut = (n_long // eng.icfg.token_budget) * eng.icfg.token_budget
+        got = pair(far, want(long_seq, wrong="tail_cut", at=cut))
+        print(f"    reference with tail_cut at {cut} (the last step's "
+              f"first token): chunked prefill {got[0]:.4g}, decode "
+              f"{got[1]:.4g}")
+
+
 # --------------------------------------------------------------------------
 # four chips: the sharded paths and what they are compared with
 # --------------------------------------------------------------------------
@@ -1006,7 +1130,9 @@ def main(argv=None) -> int:
             ("serve", lambda: serve_phase(sz, args.seed)),
             ("serve-int8", lambda: serve_int8_phase(sz, args.seed)),
             ("serve-moe", lambda: serve_moe_phase(sz, args.seed)),
-            ("serve-trinity", lambda: serve_trinity_phase(sz, args.seed)))
+            ("serve-trinity", lambda: serve_trinity_phase(sz, args.seed)),
+            ("serve-falcon-h1",
+             lambda: serve_falcon_h1_phase(sz, args.seed)))
         if args.only and args.only not in dict(one_chip):
             ap.error(f"--only {args.only!r}: no such phase; have "
                      f"{[n for n, _ in one_chip]}")

@@ -387,6 +387,12 @@ class InferenceEngine:
         if self.attn_impl == "auto":
             self.attn_impl = ("pallas" if jax.default_backend() == "tpu"
                               else "xla")
+        # a model with recurrent layers keeps a second kind of per-request
+        # cache state, a row of fixed size by slot, that is advanced and
+        # cannot be aliased, appended to or overwritten.  What each
+        # contract written for block tables does for it is decided here,
+        # by the model (docs/SERVING.md "Per-request cache state")
+        self._recurrent = self._recurrent_config(topology)
         max_len = self.icfg.max_seq_len or self.cfg.max_seq_len
         # a sequence can never hold more blocks than the pool has
         self.max_blocks_per_seq = min(-(-max_len // self.icfg.kv_block_size),
@@ -398,11 +404,15 @@ class InferenceEngine:
             block_size=self.icfg.kv_block_size,
             num_blocks=self.icfg.num_kv_blocks,
             dtype=self.icfg.kv_dtype,
-            quant=self.icfg.kv_quant or "none")
+            quant=self.icfg.kv_quant or "none",
+            recurrent=self._recurrent)
         self.state = StateManager(kv_cfg, max_seqs=self.icfg.max_seqs,
                                   max_blocks_per_seq=self.max_blocks_per_seq,
+                                  # a prefix hit aliases blocks and
+                                  # cannot alias a state: "auto" is off
+                                  # for a model with recurrent layers
                                   prefix_cache=self.icfg.prefix_cache
-                                  != "off")
+                                  != "off" and self._recurrent is None)
         # "auto" resolves OFF today — demotion trades host RAM/disk +
         # drain time for saved recompute, a workload call the ROADMAP-4
         # autotuner (and bench.py's tiered_kv leg) is meant to make
@@ -484,7 +494,10 @@ class InferenceEngine:
                                    self.icfg.max_seqs,
                                    self.icfg.num_kv_blocks,
                                    depth=max(2, self.icfg.pipeline_depth),
-                                   n_verify=self._n_verify)
+                                   n_verify=self._n_verify,
+                                   n_chunks=0 if self._recurrent is None
+                                   else self._recurrent.n_chunks(
+                                       self.icfg.token_budget))
         # spec engines' steps return [S, W] windows, so the feedback
         # operand (and its step-0 zero fallback) is window-shaped too
         # a sparse-expert model's steps append their routing statistics
@@ -535,6 +548,44 @@ class InferenceEngine:
         # direct StateManager.release — flows through one close-out hook
         # so request_metrics() can never leak an open record
         self.state.on_release = self._on_state_release
+
+    def _recurrent_config(self, topology):
+        """The state rows of a model with recurrent layers, or None;
+        refuses by name what cannot serve such a model.  The state is
+        stored in the parameters' serving type (the configuration's,
+        not an option) and advanced in float32."""
+        from .ragged.state import RecurrentConfig
+        cfg, icfg = self.cfg, self.icfg
+        if not cfg.has_ssm:
+            return None
+        why = ("the model's layers hold a recurrent state "
+               "(TransformerConfig.has_ssm): ")
+        if icfg.prefix_cache == "on":
+            raise ValueError(
+                "prefix_cache='on': " + why + "a prefix hit aliases KV "
+                "blocks and cannot alias a state (snapshots of a state at "
+                "block boundaries are not implemented); use 'auto' or "
+                "'off'")
+        if icfg.spec_decode == "on":
+            raise ValueError(
+                "spec_decode='on': " + why + "a rejected draft rewinds "
+                "the KV write cursor and cannot rewind a state; use "
+                "'auto' or 'off'")
+        if icfg.kv_tier == "on":
+            raise ValueError(
+                "kv_tier='on': " + why + "a restaged block chain carries "
+                "no state")
+        if icfg.decode_burst > 1:
+            raise ValueError("decode_burst > 1: " + why + "decode bursts "
+                             "serve a model of one block type")
+        if topology is not None and topology.device_count > 1:
+            raise NotImplementedError(
+                "serving over a mesh: " + why + "the mixer is not sharded")
+        sd = cfg.ssm_dims
+        return RecurrentConfig(
+            heads=sd.heads, head_dim=sd.head_dim, state=sd.state,
+            conv=sd.conv, channels=sd.conv_channels, chunk=sd.chunk,
+            dtype=icfg.param_dtype)
 
     def _setup_telemetry(self) -> None:
         """Build the metrics registry, the span tracer, and the
@@ -711,6 +762,28 @@ class InferenceEngine:
             "sampled rows thrown away at collect because their stream "
             "had ended or paused (reason: finished|cancelled|stalled|...)",
             int_valued=True)
+        # a model with recurrent layers (``_recurrent``): what the
+        # dispatched steps did to the state rows, from the schedule
+        if self._recurrent is not None:
+            self._c_state_updates = reg.counter(
+                "serving_state_updates_total",
+                "tokens the dispatched steps advanced recurrent states by "
+                "(kind: decode = one-token runs | scan = tokens of longer "
+                "runs), one layer's count", int_valued=True)
+            self._c_state_replayed = reg.counter(
+                "serving_state_replayed_rows_total",
+                "rows fed again after a launch ahead was thrown away: "
+                "they read the state they had produced and left it",
+                int_valued=True)
+            reg.gauge_fn("serving_state_slots_in_use",
+                         lambda: len(self.state.seqs),
+                         "sequences that hold a state row")
+            reg.gauge_fn(
+                "serving_state_bytes",
+                lambda: len(self.state.seqs)
+                * self._recurrent.bytes_per_seq(self.cfg.num_layers),
+                "bytes of recurrent state and convolution tail the live "
+                "sequences hold, all layers")
         # sparse experts (parallel/moe.py moe_serve): read from the rows
         # the step appends to its sampled tokens, at their readback;
         # a dense model has neither
@@ -955,6 +1028,30 @@ class InferenceEngine:
                 self._group_slots += steps * k
                 args[f"kv_steps_{kind}"] = steps
         return args
+
+    def _count_state_rows(self, sched) -> Dict[str, int]:
+        """What the step ``sched`` does to the recurrent states, counted
+        and returned as the stage span's arguments: ``state_rows``, the
+        sequences it advances by one token; ``scan_tokens``, the tokens
+        of its longer runs; ``state_starts``, the runs that begin at
+        position 0 (from zeros, whatever the slot held);
+        ``state_replays``, the one-token rows fed again."""
+        rows = scan = starts = replays = 0
+        for uid, toks in sched:
+            seq = self.state.seqs.get(uid)
+            if seq is None or seq.seen_tokens == 0:
+                starts += 1
+            if seq is not None and seq.state_ahead:
+                replays += 1
+            elif len(toks) == 1:
+                rows += 1
+            else:
+                scan += len(toks)
+        self._c_state_updates.inc(rows, kind="decode")
+        self._c_state_updates.inc(scan, kind="scan")
+        self._c_state_replayed.inc(replays)
+        return {"state_rows": rows, "scan_tokens": scan,
+                "state_starts": starts, "state_replays": replays}
 
     def _attn_group_fill(self) -> Optional[float]:
         """Needed KV blocks over the blocks held by the grid steps the
@@ -1532,7 +1629,7 @@ class InferenceEngine:
 
     def _kv_zeros(self):
         """A pristine zero cache with the serving sharding applied."""
-        kv = self.state.cfg.kv_zeros()
+        kv = self.state.cfg.cache_zeros(self.icfg.max_seqs)
         if self._kv_nsh is not None:
             kv = jax.device_put(kv, self._kv_nsh)
         return kv
@@ -1969,12 +2066,16 @@ class InferenceEngine:
         sched_uids: set = set()
         preempts_left = (ocfg.max_preemptions_per_step
                          if ocfg.preemption else 0)
+        # a model with recurrent layers: the runs of several tokens a
+        # step may hold (its chunk table is of fixed size)
+        scan_runs_left = self._recurrent.scan_runs \
+            if self._recurrent is not None else 0
 
         def admit(uid, toks) -> str:
             """"ok" (tokens or a cache match landed), "starved" (the
             block pool or slot table blocked it — a preemption could
             help), or "skip" (nothing a preemption can fix)."""
-            nonlocal budget, reserved_blocks, reserved_slots
+            nonlocal budget, reserved_blocks, reserved_slots, scan_runs_left
             seq = self.state.seqs.get(uid)
             ctx_rem = self.state.context_remaining(uid)
             if ctx_rem <= 0:
@@ -2028,6 +2129,11 @@ class InferenceEngine:
                 if limit > 0:
                     draft = self._spec.propose(uid, toks[0], limit)
             n = min(len(toks), budget, ctx_rem)
+            if self._recurrent is not None and n > 1:
+                if seq is not None and seq.state_ahead:
+                    n = 1            # the row fed again goes alone
+                elif scan_runs_left <= 0:
+                    return "skip"    # next step: the chunk table is full
             if len(toks) > 1 and ocfg.prefill_chunk is not None:
                 # chunked prefill: a prompt takes at most one chunk of
                 # this step's budget; the remainder waits its turn while
@@ -2068,6 +2174,7 @@ class InferenceEngine:
                 return "ok"
             sched.append((uid, toks[:n] + draft))
             sched_uids.add(uid)
+            scan_runs_left -= n > 1
             if draft:
                 self._sched_drafts[uid] = draft
             del toks[:n]
@@ -3171,6 +3278,8 @@ class InferenceEngine:
                 self._c_attn_long_rows.inc(rows)
             tiles = dict(n_tiles_short=n_short, n_tiles_long=n_long,
                          tile_fill=rows / (n_long * LONG) if n_long else 0.0)
+        if self._recurrent is not None:
+            tiles.update(self._count_state_rows(sched))
         t1 = tr.phase("ds.serve.stage", track="stage", sid=sid,
                       n_tokens=n_tokens, n_seqs=len(sched), mbs=mbs,
                       **tiles, **self._count_attn_kv(sched, pallas))

@@ -378,9 +378,13 @@ def _qkv_proj(cfg, ap, h, dt, cos, sin, positions, kind: str = "full"):
     """Shared qkv projection + biases + rotary for the serving forwards
     (ragged step and decode burst).  ``kind``: the layer's attention
     kind, which says whether it takes the rotary embedding."""
+    if cfg.attn_in_scale != 1.0:
+        h = h * jnp.asarray(cfg.attn_in_scale, dt)
     q = _mm(h, ap["wq"], dt)
     k = _mm(h, ap["wk"], dt)
     v = _mm(h, ap["wv"], dt)
+    if cfg.key_scale != 1.0:
+        k = k * jnp.asarray(cfg.key_scale, dt)
     if cfg.attn_bias:
         q = q + ap["bq"].astype(dt)
         k = k + ap["bk"].astype(dt)
@@ -393,6 +397,112 @@ def _qkv_proj(cfg, ap, h, dt, cos, sin, positions, kind: str = "full"):
         q = L.apply_rope(q[None], cos, sin, positions=positions[None])[0]
         k = L.apply_rope(k[None], cos, sin, positions=positions[None])[0]
     return q, k, v
+
+
+def _ssm_runs(batch: RaggedBatch, width: int):
+    """A step's runs as the mixer reads them, from ``batch.rec``: once a
+    step, outside the layer scan.  Per slot: the flat rows of its run's
+    first and last token, whether the run is there, is one token, is a
+    replay, starts at position 0, and where in the slot's tail (of
+    ``width`` entries) the input before the run sits: the newest, or for
+    a replayed row, whose own input is the newest, the one before.  Per
+    row (``row_*``): its slot's."""
+    rec = batch.rec
+    last = jnp.maximum(batch.logits_idx, 0)                    # [S]
+    per_slot = dict(
+        first=last - rec.run_len + 1, one=rec.run_len == 1,
+        fresh=(batch.context_lens == rec.run_len) & (rec.run_len > 0),
+        offset=width - 1 - rec.replay.astype(jnp.int32))
+    return dict(
+        per_slot, S=rec.run_len.shape[0], last=last,
+        has_run=rec.run_len > 0, replay=rec.replay, chunks=rec.chunks,
+        **{"row_" + k: v[batch.seq_slot] for k, v in per_slot.items()})
+
+
+def _ssm_mixer(cfg, mp, u, rec_state, li, batch: RaggedBatch, runs, dt):
+    """A hybrid layer's Mamba-2 mixer over a step's flat rows.
+
+    u: [T, dm], the normed input times its multiplier.  ``rec_state``:
+    ``(ssm [L, S+1, H, P, N], conv [L, S+1, W, C])``, the engine's state
+    rows of every layer; layer ``li`` reads and writes its own in place.
+    A one-token run advances its slot's state by the dense update
+    (``ssm_update``); a longer run goes through the chunked form
+    (``ssm_scan``) from the slot's state, or from zeros where it starts
+    at position 0, and leaves its last state in the slot; the
+    convolution reaches into the slot's tail (``ssm_conv``).
+    → (y [T, dm], rec_state)."""
+    from ..ops import ssm as M
+
+    dims = cfg.ssm_dims
+    ssm, conv = rec_state
+    S, T = runs["S"], u.shape[0]
+    with jax.named_scope("ssm_in"):
+        z, xbc, dt_raw = M.split_in_proj(_mm(u, mp["w_in"], dt), dims,
+                                         cfg.ssm_col_scales)
+    with jax.named_scope("ssm_conv"):
+        tail = jax.lax.dynamic_index_in_dim(conv, li, keepdims=False)
+        x, b, c = M.split_xbc(M.conv_rows(
+            xbc, tail[:S], batch.seq_slot, runs["row_first"],
+            runs["row_offset"], runs["row_fresh"], mp["conv_w"],
+            mp["conv_b"]).astype(dt), dims)
+        new_tail = M.conv_tails(
+            xbc, tail[:S], runs["last"], runs["first"], runs["offset"],
+            runs["fresh"], runs["has_run"])
+        conv = jax.lax.dynamic_update_slice(
+            conv, new_tail[None], (li, 0, 0, 0))
+        dts, a = M.discretise(dt_raw, mp)
+    with jax.named_scope("ssm_update"):
+        at = runs["last"]
+        pool = jax.lax.dynamic_index_in_dim(ssm, li, keepdims=False)
+        y_one, new = M.state_update(
+            pool[:S], x[at], b[at], c[at], dts[at], a, mp["D"],
+            runs["one"], runs["replay"], runs["fresh"], dims)
+        ssm = jax.lax.dynamic_update_slice(ssm, new[None], (li, 0, 0, 0, 0))
+    with jax.named_scope("ssm_scan"):
+        ch = runs["chunks"]
+        start, n, slot, first, lastc = (ch[:, i] for i in range(5))
+        Q = dims.chunk
+        q = jnp.arange(Q)[None, :]
+        there = q < n[:, None]                                   # [NC, Q]
+        rows = jnp.minimum(start[:, None] + q, T - 1)
+        # a chunk's first state is cut out of the stack where it lies
+        # and its last written back there, one row of 2 MiB at a time:
+        # a gather over the layer would copy the layer first
+        row = (1, 1) + ssm.shape[2:]
+        init = jnp.concatenate([jax.lax.dynamic_slice(
+            ssm, (li, slot[i], 0, 0, 0), row)[0]
+            for i in range(ch.shape[0])])
+        fresh = runs["fresh"][jnp.minimum(slot, S - 1)]
+        # about half of a chat mix's steps hold no run of several tokens:
+        # they skip the chunked form's products (the reads and writes of
+        # the 2 MiB rows around it go to the trash row and stay)
+        shape = (ch.shape[0], Q, dims.heads)
+        y_run, left = jax.lax.cond(
+            jnp.any(n > 0),
+            lambda: M.chunk_scan(
+                x[rows], b[rows], c[rows],
+                jnp.where(there[..., None], dts[rows], 0.0), a, mp["D"],
+                first.astype(bool),
+                jnp.where(fresh[:, None, None, None], 0,
+                          init.astype(jnp.float32)), dims),
+            lambda: (jnp.zeros(shape + (dims.head_dim,), jnp.float32),
+                     jnp.zeros(init.shape, jnp.float32)))
+        # a run's last chunk leaves its state in the slot; the others'
+        # (and the chunks that are not there) go to the trash row
+        to = jnp.where(lastc.astype(bool), slot, S)
+        left = left.astype(ssm.dtype)
+        for i in range(ch.shape[0]):
+            ssm = jax.lax.dynamic_update_slice(
+                ssm, left[i][None, None], (li, to[i], 0, 0, 0))
+        y = jnp.zeros((T,) + y_run.shape[2:], jnp.float32).at[
+            jnp.where(there, rows, T).reshape(-1)].set(
+            y_run.reshape((-1,) + y_run.shape[2:]), mode="drop")
+        y = jnp.where(runs["row_one"][:, None, None],
+                      y_one[batch.seq_slot], y)
+    with jax.named_scope("ssm_out"):
+        y = M.gated_norm(y.reshape(T, dims.d_ssm), z, mp["norm"], dims,
+                         cfg.eps).astype(dt)
+        return _mm(y, mp["w_out"], dt), (ssm, conv)
 
 
 def _dense_weight(w) -> bool:
@@ -449,7 +559,10 @@ def _ffn(cfg, lp, h, dt, act, comm: Optional[ServingComm] = None,
     if cfg.mlp_bias:
         u = u + mp["bi"].astype(dt)
     if cfg.gated_mlp:
-        u = act(_mm(h, mp["wg"], dt)) * u
+        g = _mm(h, mp["wg"], dt)
+        if cfg.mlp_gate_scale != 1.0:
+            g = g * jnp.asarray(cfg.mlp_gate_scale, dt)
+        u = act(g) * u
     else:
         u = act(u)
     wo = mp["wo"]
@@ -459,6 +572,8 @@ def _ffn(cfg, lp, h, dt, act, comm: Optional[ServingComm] = None,
         d = _mm(u, wo, dt)
     if cfg.mlp_bias:
         d = d + mp["bo"].astype(dt)
+    if cfg.mlp_out_scale != 1.0:
+        d = d * jnp.asarray(cfg.mlp_out_scale, dt)
     return d, None
 
 
@@ -525,6 +640,14 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
         raise NotImplementedError(
             "weight quantization and the NVMe weight stream serve a model "
             "of one block type (TransformerConfig.plain_stack)")
+    # a model with recurrent layers: the cache is the paged pool AND the
+    # state rows by slot (``KVCacheConfig.cache_zeros``); both ride the
+    # layer scan as carries that every layer updates in place
+    rec = runs = None
+    if cfg.has_ssm:
+        rec = (kv["ssm"], kv["conv"])
+        kv = kv["kv"]
+        runs = _ssm_runs(batch, cfg.ssm_conv)
     if quant is not None:
         from .quantization import merge_layer
         from ..ops.quant import dequantize_any
@@ -577,11 +700,13 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
         experts = blocks["experts"]
         blocks = {k: v for k, v in blocks.items() if k != "experts"}
 
-    def block(x, lp, pool, layer, li, kind):
+    def block(x, lp, pool, layer, li, kind, rec=None):
         """One layer's mathematics.  ``pool`` is the stacked paged
         cache, which holds the layer where ``layer`` says
         (``_layer_of``).  ``li``: the layer's index in ``blocks``, for
-        weights kept stacked.  ``kind``: its attention kind, static."""
+        weights kept stacked.  ``kind``: its kind, static.  ``rec``: the
+        stacked state rows of a model with recurrent layers; a hybrid
+        layer returns them updated, last."""
         ap = lp["attn"]
         window = cfg.attn_window if kind == "window" else None
         # named scopes at the block's seams (metadata only): a device
@@ -622,6 +747,15 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
                 o = o + ap["bo"].astype(dt)
             if cfg.sandwich_norm:
                 o = norm(lp["ln1_post"], o)
+            if cfg.attn_out_scale != 1.0:
+                o = o * jnp.asarray(cfg.attn_out_scale, dt)
+        if kind == "hybrid":
+            # the mixer reads the same normed input as the attention
+            with jax.named_scope("ssm"):
+                m, rec = _ssm_mixer(
+                    cfg, lp["ssm"], h * jnp.asarray(cfg.ssm_in_scale, dt),
+                    rec, li, batch, runs, dt)
+                o = o + m * jnp.asarray(cfg.ssm_out_scale, dt)
         with jax.named_scope("ffn"):
             if not cfg.parallel_block:
                 x = x + o
@@ -636,6 +770,8 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
                             else (experts, li), routing=with_routing)
             if cfg.sandwich_norm:
                 d = norm(lp["ln2_post"], d)
+        if rec is not None:
+            return x + d, pool, stats, rec
         if cfg.parallel_block:
             return x + o + d, pool, stats
         return x + d, pool, stats
@@ -679,14 +815,15 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
         # place, and the layer is an offset into the stacked pool (no
         # per-layer slice, no second pool).  The body holds one period
         # of the layer pattern, each layer of a static kind
-        x, pool = carry
+        x, pool, *state = carry
         if P == 1:
             # a period of one layer is that layer: the general path
             # below gives the same numbers, but another compiled program
             # for every model the system served before it had a pattern
             lp, li = layer_weights(ws)
-            x, pool, stats = block(x, lp, pool, at(li), li, pattern[0])
-            return (x, pool), stats
+            x, pool, stats, *state = block(x, lp, pool, at(li), li,
+                                           pattern[0], *state)
+            return (x, pool, *state), stats
         stats = []
         for j, kind in enumerate(pattern):
             lp, li = layer_weights(jax.tree.map(lambda a: a[j], ws))
@@ -709,11 +846,14 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
     outside_stats = []
     if lead:
         x, pool, _ = outside(x, pool, params["dense_blocks"], 0, lead, 0)
-    (x, pool), stats = jax.lax.scan(carried, (x, pool), layers)
+    (x, pool, *state), stats = jax.lax.scan(
+        carried, (x, pool) if rec is None else (x, pool, rec), layers)
     if tail:
         x, pool, outside_stats = outside(x, pool, blocks, periods * P,
                                          tail, lead + periods * P)
     new_kv = jax.tree.map(lambda a, o: a.reshape(o.shape), pool, kv)
+    if rec is not None:
+        new_kv = {"kv": new_kv, "ssm": state[0][0], "conv": state[0][1]}
 
     with jax.named_scope("unembed"):
         logits = _unembed(cfg, params, embed_tab, x, batch, norm, dt, comm)
@@ -768,6 +908,8 @@ def _unembed(cfg, params, embed_tab, x, batch, norm, dt, comm):
             logits = last @ k.astype(dt)
         if cfg.head_bias:
             logits = logits + params["lm_head"]["bias"].astype(dt)
+    if cfg.head_scale != 1.0:
+        logits = logits * jnp.asarray(cfg.head_scale, dt)
     return logits.astype(jnp.float32)
 
 

@@ -102,6 +102,38 @@ def prefix_chain_digests(tokens, block_size: int,
 
 
 @dataclasses.dataclass
+class RecurrentConfig:
+    """The second kind of per-request cache state: a model with
+    recurrent layers (``TransformerConfig.has_ssm``) keeps, for every
+    sequence and layer, a state of FIXED size that is advanced and not
+    appended to.  It lives in the sequence's slot (``StateManager.slot``,
+    the row of its block table), so it needs no allocator: ``[L,
+    max_seqs + 1, heads, head_dim, state]`` and the convolution's tail
+    ``[L, max_seqs + 1, conv, channels]``, the last row taking the
+    writes of rows that are not there (the pool's trash block's part).
+    Stored in ``dtype``, advanced in float32."""
+    heads: int
+    head_dim: int
+    state: int
+    conv: int                 # tail entries kept: the convolution's width
+    channels: int             # channels the convolution runs over
+    chunk: int                # the chunked form's chunk, in tokens
+    dtype: object = jnp.bfloat16
+    # runs of more than one token a step may hold (the scheduler's
+    # bound): with it a step's chunks are at most ceil(T / chunk) + this
+    scan_runs: int = 4
+
+    def n_chunks(self, token_budget: int) -> int:
+        return -(-token_budget // self.chunk) + self.scan_runs
+
+    def bytes_per_seq(self, num_layers: int) -> int:
+        """A sequence's state and tail over all layers."""
+        item = jnp.dtype(self.dtype).itemsize
+        return num_layers * item * (self.heads * self.head_dim * self.state
+                                    + self.conv * self.channels)
+
+
+@dataclasses.dataclass
 class KVCacheConfig:
     num_layers: int
     num_kv_heads: int
@@ -114,6 +146,8 @@ class KVCacheConfig:
     # stream that dominates long-context decode (reference analog:
     # ZeRO-Inference KV quantization, deepspeed/inference/quantization/)
     quant: str = "none"
+    # a model with recurrent layers: the state rows beside the blocks
+    recurrent: Optional[RecurrentConfig] = None
 
     @property
     def max_context(self) -> int:
@@ -142,6 +176,20 @@ class KVCacheConfig:
             return jnp.zeros(shape, self.dtype)
         return (jnp.zeros(shape, self.store_dtype),
                 jnp.zeros(shape[:-1], jnp.float32))
+
+    def cache_zeros(self, max_seqs: int):
+        """What a serving step carries and donates: the paged cache, and
+        for a model with recurrent layers a dict of it (``"kv"``), the
+        state rows (``"ssm"``) and the convolution tails (``"conv"``)."""
+        kv = self.kv_zeros()
+        rc = self.recurrent
+        if rc is None:
+            return kv
+        rows = (self.num_layers, max_seqs + 1)
+        return {"kv": kv,
+                "ssm": jnp.zeros(rows + (rc.heads, rc.head_dim, rc.state),
+                                 rc.dtype),
+                "conv": jnp.zeros(rows + (rc.conv, rc.channels), rc.dtype)}
 
 
 @dataclasses.dataclass
@@ -177,6 +225,11 @@ class SequenceDescriptor:
     # the first one is content-hashed.
     deferred: List[Tuple[int, int]] = dataclasses.field(
         default_factory=list)
+    # a model with recurrent layers: rows taken back (``rewind``) whose
+    # token the slot's state has ALREADY been advanced by.  A state is
+    # advanced, not overwritten, so the row scheduled next is marked as
+    # a replay: it reads the state it produced and leaves it as it is
+    state_ahead: int = 0
 
     def blocks_needed(self, new_tokens: int, block_size: int) -> int:
         total = self.seen_tokens + new_tokens
@@ -196,6 +249,20 @@ class SequenceDescriptor:
         return (not self.chain_broken and self.draft_len == 0
                 and not self.deferred
                 and len(self.chain) == self.seen_tokens)
+
+
+class RecBatch(NamedTuple):
+    """A step's runs as a model with recurrent layers needs them (a run:
+    the consecutive rows one sequence has in the step; a slot holds at
+    most one).  Host arithmetic over the schedule."""
+    run_len: jnp.ndarray         # [max_seqs] i32, 0: no run in the slot
+    replay: jnp.ndarray          # [max_seqs] bool, the one-token run was
+                                 # computed before (``state_ahead``)
+    chunks: jnp.ndarray          # [NC, 5] i32: the runs of several tokens
+                                 # cut into chunks of at most ``chunk``
+                                 # rows: first flat row, rows, slot
+                                 # (max_seqs: no chunk), first of its
+                                 # run, last of its run
 
 
 class RaggedBatch(NamedTuple):
@@ -232,6 +299,8 @@ class RaggedBatch(NamedTuple):
                                  # Present only on verify-step batches
                                  # (None keeps the legacy single-sample
                                  # program byte-identical)
+    rec: Optional[RecBatch] = None
+                                 # a model with recurrent layers only
 
 
 class BatchStager:
@@ -245,7 +314,7 @@ class BatchStager:
     get ``depth`` sets."""
 
     def __init__(self, token_budget: int, max_seqs: int, max_blocks: int,
-                 depth: int = 2, n_verify: int = 1):
+                 depth: int = 2, n_verify: int = 1, n_chunks: int = 0):
         self.shape_key = (token_budget, max_seqs, max_blocks)
         # widest speculative verify window this engine may stage
         # (spec_max_draft + 1); batches slice the columns they use
@@ -253,6 +322,11 @@ class BatchStager:
         self._bufs = [self._alloc(token_budget, max_seqs, max_blocks,
                                   self.n_verify)
                       for _ in range(max(2, depth))]
+        for b in self._bufs:
+            if n_chunks:     # a model with recurrent layers (RecBatch)
+                b["run_len"] = np.zeros(max_seqs, np.int32)
+                b["replay"] = np.zeros(max_seqs, bool)
+                b["chunks"] = np.zeros((n_chunks, 5), np.int32)
         self._i = 0
 
     @staticmethod
@@ -282,6 +356,10 @@ class BatchStager:
         b["feedback_src"].fill(-1)
         b["seq_uids"].fill(0)
         b["verify_idx"].fill(-1)
+        if "chunks" in b:
+            b["run_len"].fill(0)
+            b["replay"].fill(False)
+            b["chunks"][:] = (0, 0, len(b["run_len"]), 1, 0)
         return b
 
 
@@ -354,8 +432,9 @@ class StateManager:
         self._block_meta: Dict[int, Tuple[bytes, Tuple[int, ...]]] = {}
         # paged KV: [L, blocks+1, block_size, 2, Hkv, D] — the extra row is
         # the trash block that padding tokens' KV writes are routed to
-        # (plus per-vector scales when cfg.quant != "none")
-        self.kv = cfg.kv_zeros()
+        # (plus per-vector scales when cfg.quant != "none"); with
+        # recurrent layers, beside it the state rows by slot
+        self.kv = cfg.cache_zeros(max_seqs)
 
     # ---- sequence lifecycle ---------------------------------------------
     def get_or_create(self, uid: int) -> SequenceDescriptor:
@@ -797,9 +876,16 @@ class StateManager:
         never arrived): the write cursor and the chain move back, the
         rows' KV is overwritten by whatever is scheduled next, blocks
         already allocated for them stay with the sequence (as in
-        :meth:`resolve_draft`)."""
+        :meth:`resolve_draft`).  A recurrent state is not overwritten,
+        it is advanced: the sequence notes how far its state is ahead
+        (``state_ahead``), and the row fed again is a replay that reads
+        the state it produced and leaves it where one pass would."""
         seq = self.seqs[uid]
         seq.seen_tokens -= n_tokens
+        if self.cfg.recurrent is not None:
+            # a recurrent state was advanced by those rows and cannot be
+            # overwritten: the row scheduled next replays (build_batch)
+            seq.state_ahead += n_tokens
         if not seq.chain_broken:
             del seq.chain[-n_tokens:]
             seq.deferred = [d for d in seq.deferred
@@ -847,6 +933,8 @@ class StateManager:
         late, in order."""
         max_blocks = self.cfg.num_blocks
         T = token_budget
+        rc = self.cfg.recurrent
+        n_chunks = 0
         # fresh registration ledger for this round (see round_registered)
         self.round_registered = []
         if stager is not None \
@@ -862,6 +950,8 @@ class StateManager:
             feedback_src = bufs["feedback_src"]
             seq_uids = bufs["seq_uids"]
             verify_idx = bufs["verify_idx"]
+            rec = {k: bufs[k] for k in ("run_len", "replay", "chunks")} \
+                if rc is not None else None
         else:
             token_ids = np.zeros(T, np.int32)
             positions = np.zeros(T, np.int32)
@@ -876,6 +966,13 @@ class StateManager:
             seq_uids = np.zeros(self.max_seqs, np.uint32)
             verify_idx = np.full((self.max_seqs, max(1, n_verify)), -1,
                                  np.int32)
+            rec = None
+            if rc is not None:
+                rec = {"run_len": np.zeros(self.max_seqs, np.int32),
+                       "replay": np.zeros(self.max_seqs, bool),
+                       "chunks": np.tile(
+                           np.array([0, 0, self.max_seqs, 1, 0], np.int32),
+                           (rc.n_chunks(T), 1))}
 
         # keep existing sequences' tables valid even if not in this batch
         for uid, seq in self.seqs.items():
@@ -934,6 +1031,25 @@ class StateManager:
                     # that preemption-by-eviction re-queues (the index
                     # registration below stays cache-gated)
                     seq.chain.extend(int(t) for t in new_tokens)
+            if rc is not None:
+                rec["run_len"][s] = n
+                if seq.state_ahead:
+                    if n != 1 or seq.state_ahead != 1:
+                        raise ValueError(
+                            f"uid {uid}: its recurrent state is "
+                            f"{seq.state_ahead} rows ahead; exactly one "
+                            "row can be replayed")
+                    rec["replay"][s] = True
+                    seq.state_ahead = 0
+                for at in range(0, n if n > 1 else 0, rc.chunk):
+                    if n_chunks >= len(rec["chunks"]):
+                        raise ValueError(
+                            f"more than {rc.scan_runs} runs of several "
+                            "tokens in a step")
+                    rows = min(rc.chunk, n - at)
+                    rec["chunks"][n_chunks] = (cursor + at, rows, s,
+                                               at == 0, at + rows == n)
+                    n_chunks += 1
             positions[cursor:cursor + n] = np.arange(
                 seq.seen_tokens, seq.seen_tokens + n)
             seq_slot[cursor:cursor + n] = s
@@ -971,4 +1087,8 @@ class StateManager:
             feedback_src=jnp.asarray(feedback_src),
             seq_uids=jnp.asarray(seq_uids),
             verify_idx=(jnp.asarray(verify_idx[:, :n_verify])
-                        if n_verify > 1 else None))
+                        if n_verify > 1 else None),
+            rec=None if rc is None else RecBatch(
+                run_len=jnp.asarray(rec["run_len"]),
+                replay=jnp.asarray(rec["replay"]),
+                chunks=jnp.asarray(rec["chunks"])))

@@ -288,6 +288,53 @@ PRESETS: Dict[str, dict] = {
                          moe_select_bias=True, moe_norm_topk=True,
                          moe_route_scale=2.826, moe_dispatch="ragged",
                          attention_impl="xla"),
+    # --- Falcon-H1 (every block: a Mamba-2 mixer BESIDE grouped-query
+    # attention, both reading the same normed input and summed, then a
+    # gated MLP; fourteen constant multipliers; untied head —
+    # tiiuae/Falcon-H1-34B-Instruct config.json + modeling_falcon_h1.py).
+    # Another model than "falcon-7b" above (multi-query attention, a
+    # parallel residual, no state-space layer) ---------------------------
+    "falcon-h1-tiny": dict(vocab_size=1024, num_layers=4, d_model=64,
+                           num_heads=4, num_kv_heads=2, head_dim=32,
+                           d_ff=160, max_seq_len=256,
+                           activation="silu", gated_mlp=True, norm="rmsnorm",
+                           position="rope", rope_theta=1e6,
+                           tie_embeddings=False, attn_bias=False,
+                           mlp_bias=False, eps=1e-5,
+                           layer_pattern=("hybrid",),
+                           ssm_d=64, ssm_heads=4, ssm_head_dim=16,
+                           ssm_groups=2, ssm_state=16, ssm_conv=4,
+                           ssm_chunk=8,
+                           embed_scale=4.0, head_scale=0.125,
+                           attn_in_scale=0.75, attn_out_scale=0.3,
+                           key_scale=0.4, ssm_in_scale=0.5,
+                           ssm_out_scale=0.35, mlp_gate_scale=0.6,
+                           mlp_out_scale=0.2,
+                           ssm_col_scales=(0.7, 0.5, 0.35, 0.8, 0.6),
+                           attention_impl="xla"),
+    "falcon-h1-34b": dict(vocab_size=261120, num_layers=72, d_model=5120,
+                          num_heads=20, num_kv_heads=4, head_dim=128,
+                          d_ff=21504, max_seq_len=262144,
+                          activation="silu", gated_mlp=True, norm="rmsnorm",
+                          position="rope", rope_theta=1e11,
+                          tie_embeddings=False, attn_bias=False,
+                          mlp_bias=False, eps=1e-5,
+                          layer_pattern=("hybrid",),
+                          ssm_d=4096, ssm_heads=32, ssm_head_dim=128,
+                          ssm_groups=2, ssm_state=256, ssm_conv=4,
+                          ssm_chunk=128,
+                          embed_scale=5.656854249492381,
+                          head_scale=0.0078125,
+                          attn_in_scale=1.0, attn_out_scale=0.0375,
+                          key_scale=0.011048543456039804,
+                          ssm_in_scale=0.25,
+                          ssm_out_scale=0.08838834764831845,
+                          mlp_gate_scale=0.1767766952966369,
+                          mlp_out_scale=0.011160714285714284,
+                          ssm_col_scales=(0.3535533905932738, 0.25,
+                                          0.1767766952966369, 0.5,
+                                          0.3535533905932738),
+                          attention_impl="xla"),
     # --- Megatron-GPT (gpt2 architecture, megatron-lm checkpoint naming
     # with per-head-interleaved fused QKV — reference:
     # module_inject/containers/megatron_gpt.py) ---------------------------

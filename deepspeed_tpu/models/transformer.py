@@ -60,11 +60,13 @@ class TransformerConfig:
     # all the query heads and one for the key heads
     qk_norm_form: str = "projection"
     # --- the layer pattern ------------------------------------------------
-    # the attention kind of each layer of one period: "full" (every key
-    # up to the query) | "window" (the last ``attn_window`` keys, the
-    # query's own among them).  The layers behind the leading dense ones
-    # repeat it; a last period may be cut short.  The leading dense
-    # layers are of the period's first kind
+    # the kind of each layer of one period: "full" (attention over every
+    # key up to the query) | "window" (the last ``attn_window`` keys, the
+    # query's own among them) | "hybrid" (falcon-h1: full attention AND
+    # a Mamba-2 mixer, both reading the same normed input, summed into
+    # the residual).  The layers behind the leading dense ones repeat
+    # it; a last period may be cut short.  The leading dense layers are
+    # of the period's first kind
     layer_pattern: Tuple[str, ...] = ("full",)
     attn_window: Optional[int] = None
     # sparse-expert models: this many FIRST layers keep a dense MLP of
@@ -78,8 +80,28 @@ class TransformerConfig:
     attn_gate: bool = False
     # four norms a layer: x + N(attn(N(x))), then x + N(ffn(N(x)))
     sandwich_norm: bool = False
-    # the embedding's output is multiplied by this (trinity: sqrt(d_model))
+    # the embedding's output is multiplied by this (trinity: sqrt(d_model);
+    # falcon-h1: embedding_multiplier)
     embed_scale: Optional[float] = None
+    # --- a hybrid layer's Mamba-2 mixer (ops/ssm.py) ----------------------
+    ssm_d: int = 0                            # d_ssm = heads * head size
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1                       # B and C are shared by group
+    ssm_state: int = 0                        # N, a head's state is [P, N]
+    ssm_conv: int = 4                         # the causal convolution's width
+    ssm_chunk: int = 128                      # the chunked form's chunk
+    # --- constant multipliers (falcon-h1's muP; 1.0 multiplies nothing) ---
+    head_scale: float = 1.0                   # the logits
+    attn_in_scale: float = 1.0                # the attention's input
+    attn_out_scale: float = 1.0               # and its output projection's
+    key_scale: float = 1.0                    # the keys
+    ssm_in_scale: float = 1.0                 # the mixer's input
+    ssm_out_scale: float = 1.0                # and its output projection's
+    mlp_gate_scale: float = 1.0               # the MLP's gate, inside the act
+    mlp_out_scale: float = 1.0                # the MLP's output
+    # over the columns of the mixer's input projection: z, x, B, C, dt
+    ssm_col_scales: Tuple[float, ...] = (1.0, 1.0, 1.0, 1.0, 1.0)
     # bloom: layernorm applied to the word embeddings before the stack
     embed_norm: bool = False
     # parallel residual: x + attn(ln(x)) + mlp(ln(x)), one shared norm
@@ -152,7 +174,14 @@ class TransformerConfig:
             self.head_dim = self.d_model // self.num_heads
         assert self.num_heads % self.num_kv_heads == 0
         self.layer_pattern = tuple(self.layer_pattern)
-        assert set(self.layer_pattern) <= {"full", "window"}
+        assert set(self.layer_pattern) <= {"full", "window", "hybrid"}
+        self.ssm_col_scales = tuple(self.ssm_col_scales)
+        if self.has_ssm:
+            assert self.layer_pattern == ("hybrid",) \
+                and self.num_experts == 1 and not self.parallel_block
+            assert self.ssm_d == self.ssm_heads * self.ssm_head_dim > 0
+            assert self.ssm_heads % self.ssm_groups == 0 and self.ssm_state
+            assert len(self.ssm_col_scales) == 5
         assert "window" not in self.layer_pattern or self.attn_window
         assert self.qk_norm_form in ("projection", "head")
         assert self.moe_score in ("softmax", "sigmoid")
@@ -178,6 +207,19 @@ class TransformerConfig:
         rest = self.num_layers - self.num_dense_layers
         p = len(self.layer_pattern)
         return self.num_dense_layers, rest // p, rest % p
+
+    @property
+    def has_ssm(self) -> bool:
+        """The model's layers hold a recurrent mixer: a served sequence
+        owns a state row beside its block table."""
+        return "hybrid" in self.layer_pattern
+
+    @property
+    def ssm_dims(self):
+        from ..ops.ssm import SSMDims
+        return SSMDims(self.ssm_d, self.ssm_heads, self.ssm_head_dim,
+                       self.ssm_groups, self.ssm_state, self.ssm_conv,
+                       self.ssm_chunk)
 
     @property
     def plain_stack(self) -> bool:
@@ -263,10 +305,21 @@ def init_params(cfg: TransformerConfig, key) -> Tuple[Dict, Dict]:
         a["wq"] = ("embed", "heads", "head_dim")
         p["wk"] = jax.random.normal(k2, (dm, Hkv, D)) / math.sqrt(dm)
         a["wk"] = ("embed", "kv_heads", "head_dim")
+        if cfg.key_scale != 1.0:
+            # a model with constant multipliers is seeded with each
+            # multiplier undone in the weight it stands behind, so that
+            # every term of a block is a visible part of its output and
+            # a forward that leaves one out reads wrong by 1/multiplier
+            p["wk"] = p["wk"] / cfg.key_scale
         p["wv"] = jax.random.normal(k3, (dm, Hkv, D)) / math.sqrt(dm)
         a["wv"] = ("embed", "kv_heads", "head_dim")
         p["wo"] = jax.random.normal(k4, (H, D, dm)) * out_scale
         a["wo"] = ("heads", "head_dim", "embed")
+        if cfg.attn_in_scale * cfg.attn_out_scale != 1.0:
+            p["wq"] = p["wq"] / cfg.attn_in_scale
+            p["wk"] = p["wk"] / cfg.attn_in_scale
+            p["wv"] = p["wv"] / cfg.attn_in_scale
+            p["wo"] = p["wo"] / cfg.attn_out_scale
         if cfg.attn_bias:
             p["bq"] = jnp.zeros((H, D)); a["bq"] = ("heads", "head_dim")
             p["bk"] = jnp.zeros((Hkv, D)); a["bk"] = ("kv_heads", "head_dim")
@@ -306,9 +359,44 @@ def init_params(cfg: TransformerConfig, key) -> Tuple[Dict, Dict]:
             a["wg"] = ("embed", "mlp")
         p["wo"] = jax.random.normal(k2, (dff, dm)) * out_scale
         a["wo"] = ("mlp", "embed")
+        if cfg.mlp_gate_scale * cfg.mlp_out_scale != 1.0:
+            p["wg"] = p["wg"] / cfg.mlp_gate_scale
+            p["wo"] = p["wo"] / cfg.mlp_out_scale
         if cfg.mlp_bias:
             p["bi"] = jnp.zeros((dff,)); a["bi"] = ("mlp",)
             p["bo"] = jnp.zeros((dm,)); a["bo"] = ("embed",)
+        return p, a
+
+    def ssm_init(k):
+        """A hybrid layer's Mamba-2 mixer.  ``A`` and ``dt`` start as
+        Mamba-2 starts them (A uniform in 1..16, softplus(dt_bias)
+        log-uniform in 0.001..0.1), so a state decays over tens to
+        thousands of tokens; ``D`` and the gated norm's scale are
+        seeded away from one, for the reason ``q_norm`` is."""
+        sd = cfg.ssm_dims
+        ks = jax.random.split(k, 8)
+        from ..ops.ssm import column_scales
+        w_in = jax.random.normal(ks[0], (dm, sd.in_proj)) / math.sqrt(dm) \
+            / (cfg.ssm_in_scale * column_scales(sd, cfg.ssm_col_scales))
+        dt0 = jnp.exp(jax.random.uniform(
+            ks[3], (sd.heads,), minval=math.log(1e-3), maxval=math.log(0.1)))
+        p = {"w_in": w_in,
+             "conv_w": jax.random.uniform(ks[1], (sd.conv_channels, sd.conv),
+                                          minval=-0.5, maxval=0.5),
+             "conv_b": jax.random.uniform(ks[2], (sd.conv_channels,),
+                                          minval=-0.5, maxval=0.5),
+             "dt_bias": dt0 + jnp.log(-jnp.expm1(-dt0)),
+             "A_log": jnp.log(jax.random.uniform(ks[4], (sd.heads,),
+                                                 minval=1.0, maxval=16.0)),
+             "D": jax.random.uniform(ks[5], (sd.heads,), minval=0.5,
+                                     maxval=1.5),
+             "norm": jax.random.uniform(ks[6], (sd.d_ssm,), minval=0.5,
+                                        maxval=1.5),
+             "w_out": jax.random.normal(ks[7], (sd.d_ssm, dm)) * out_scale
+             / cfg.ssm_out_scale}
+        a = {"w_in": ("embed", None), "conv_w": (None, None),
+             "conv_b": (None,), "dt_bias": (None,), "A_log": (None,),
+             "D": (None,), "norm": (None,), "w_out": (None, "embed")}
         return p, a
 
     norm_init = L.layernorm_init if cfg.norm == "layernorm" else L.rmsnorm_init
@@ -329,6 +417,9 @@ def init_params(cfg: TransformerConfig, key) -> Tuple[Dict, Dict]:
     blk_p: Dict[str, Any] = {}
     blk_a: Dict[str, Any] = {}
     blk_p["attn"], blk_a["attn"] = stack_init(qkv_init, keys[2])
+    if cfg.has_ssm:
+        blk_p["ssm"], blk_a["ssm"] = stack_init(
+            ssm_init, jax.random.fold_in(keys[2], 3))  # tpulint: disable=rng-discipline
 
     if cfg.num_experts > 1:
         from ..parallel import moe as M
@@ -406,6 +497,9 @@ def init_params(cfg: TransformerConfig, key) -> Tuple[Dict, Dict]:
             {"kernel": jax.random.normal(keys[6], (dm, cfg.vocab_size))
              / math.sqrt(dm)},
             {"kernel": ("embed", "vocab")})
+        if cfg.head_scale != 1.0:
+            params["lm_head"]["kernel"] = (params["lm_head"]["kernel"]
+                                           / cfg.head_scale)
         if cfg.head_bias:
             params["lm_head"]["bias"] = jnp.zeros((cfg.vocab_size,))
             axes["lm_head"]["bias"] = ("vocab",)
@@ -489,9 +583,14 @@ def block_apply(cfg: TransformerConfig, lp, x, cos, sin,
     # from its recomputation and its backward
     with jax.named_scope("qkv"):
         h = norm(lp["ln1"], x)
+        hn = h          # a hybrid layer's mixer reads the same normed input
+        if cfg.attn_in_scale != 1.0:
+            h = h * jnp.asarray(cfg.attn_in_scale, dt)
         q = jnp.einsum("bsd,dhk->bshk", h, ap["wq"].astype(dt))
         k = jnp.einsum("bsd,dhk->bshk", h, ap["wk"].astype(dt))
         v = jnp.einsum("bsd,dhk->bshk", h, ap["wv"].astype(dt))
+        if cfg.key_scale != 1.0:
+            k = k * jnp.asarray(cfg.key_scale, dt)
         if cfg.attn_bias:
             q = q + ap["bq"].astype(dt)
             k = k + ap["bk"].astype(dt)
@@ -518,6 +617,15 @@ def block_apply(cfg: TransformerConfig, lp, x, cos, sin,
             o = o + ap["bo"].astype(dt)
         if cfg.sandwich_norm:
             o = norm(lp["ln1_post"], o)
+        if cfg.attn_out_scale != 1.0:
+            o = o * jnp.asarray(cfg.attn_out_scale, dt)
+    if kind == "hybrid":
+        from ..ops.ssm import mixer_forward
+        with jax.named_scope("ssm"):
+            m = mixer_forward(lp["ssm"],
+                              hn * jnp.asarray(cfg.ssm_in_scale, dt),
+                              cfg.ssm_dims, cfg.ssm_col_scales, cfg.eps)
+            o = o + m * jnp.asarray(cfg.ssm_out_scale, dt)
 
     with jax.named_scope("ffn"):
         if not cfg.parallel_block:
@@ -547,12 +655,17 @@ def block_apply(cfg: TransformerConfig, lp, x, cos, sin,
             if cfg.mlp_bias:
                 u = u + mp["bi"].astype(dt)
             if cfg.gated_mlp:
-                u = act(h @ mp["wg"].astype(dt)) * u
+                g = h @ mp["wg"].astype(dt)
+                if cfg.mlp_gate_scale != 1.0:
+                    g = g * jnp.asarray(cfg.mlp_gate_scale, dt)
+                u = act(g) * u
             else:
                 u = act(u)
             d = u @ mp["wo"].astype(dt)
             if cfg.mlp_bias:
                 d = d + mp["bo"].astype(dt)
+            if cfg.mlp_out_scale != 1.0:
+                d = d * jnp.asarray(cfg.mlp_out_scale, dt)
         if cfg.sandwich_norm:
             d = norm(lp["ln2_post"], d)
         if cfg.parallel_block:
@@ -748,6 +861,8 @@ def apply(cfg: TransformerConfig, params, input_ids, mask=None,
             logits = x @ head["kernel"].astype(dt)
             if cfg.head_bias:
                 logits = logits + head["bias"].astype(dt)
+        if cfg.head_scale != 1.0:
+            logits = logits * jnp.asarray(cfg.head_scale, dt)
         logits = keep(logits)
     if with_aux:
         aux = {k: v.mean() for k, v in metrics.items()} if metrics else {}

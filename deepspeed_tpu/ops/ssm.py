@@ -1,0 +1,291 @@
+"""Mamba-2 mixer: the three computations a served hybrid block needs.
+
+A block of kind ``"hybrid"`` (``models/transformer.py``) holds a
+Mamba-2 mixer beside its attention.  Per head ``i`` of ``H`` (group
+``g = i // (H / G)``), with ``dt_t = softplus(dt_t + dt_bias)`` and
+``A = -exp(A_log)``::
+
+    S_t = exp(dt_t A) S_{t-1} + dt_t * x_t (outer) B_t[g]     S: [P, N]
+    y_t = S_t C_t[g] + D_i x_t
+
+in front of it a depthwise causal convolution of width ``W`` over the
+``x``, ``B`` and ``C`` channels together.  What this file computes:
+
+* :func:`conv_rows` / :func:`conv_tails` (scope ``ssm_conv``): the
+  convolution over a step's flat rows, each row reaching back into its
+  own run and, past the run's first row, into the sequence's carried
+  tail of raw inputs, and the tails the step leaves;
+* :func:`state_update` (scope ``ssm_update``): the one-token update of
+  every sequence that a step advances by one token, dense over the
+  state pool's slots (a slot holds at most one run a step, so nothing is
+  gathered or scattered: the pool is read and written once);
+* :func:`chunk_scan` (scope ``ssm_scan``): the chunked form (SSD) over a
+  list of chunks of ``Q`` tokens, each of one run; a chunk continues the
+  one before it or starts from a given state.
+
+``mixer_forward`` is the whole mixer over whole sequences from a zero
+state (``models/transformer.apply``); the serving forward
+(``inference/model.py``) composes the three itself around the engine's
+state pool.  All of it is XLA: see PERF.md section 6 (PR 42) for what
+the chip said.
+"""
+
+from __future__ import annotations
+
+from typing import NamedTuple, Tuple
+
+import jax
+import jax.numpy as jnp
+
+F32 = jnp.float32
+
+
+class SSMDims(NamedTuple):
+    """The mixer's sizes (``TransformerConfig.ssm_dims``)."""
+    d_ssm: int
+    heads: int
+    head_dim: int
+    groups: int
+    state: int
+    conv: int
+    chunk: int
+
+    @property
+    def conv_channels(self) -> int:
+        """Channels the convolution runs over: x, B and C together."""
+        return self.d_ssm + 2 * self.groups * self.state
+
+    @property
+    def in_proj(self) -> int:
+        """Columns of ``w_in``: z, xBC, dt in that order."""
+        return self.d_ssm + self.conv_channels + self.heads
+
+
+def split_in_proj(p, dims: SSMDims, col_scales):
+    """``p``: [..., in_proj] → (z, xBC, dt).  ``col_scales``: the five
+    multipliers over the columns of z, x, B, C, dt."""
+    p = p * column_scales(dims, col_scales).astype(p.dtype)
+    d, c = dims.d_ssm, dims.conv_channels
+    return p[..., :d], p[..., d:d + c], p[..., d + c:]
+
+
+def column_scales(dims: SSMDims, col_scales) -> jnp.ndarray:
+    """The constant vector over ``w_in``'s columns that holds the five
+    multipliers (z, x, B, C, dt)."""
+    gn = dims.groups * dims.state
+    widths = (dims.d_ssm, dims.d_ssm, gn, gn, dims.heads)
+    return jnp.concatenate([jnp.full((w,), s, F32)
+                            for w, s in zip(widths, col_scales)])
+
+
+def split_xbc(xbc, dims: SSMDims):
+    """[..., conv_channels] → x [..., H, P], B, C [..., G, N]."""
+    d, gn = dims.d_ssm, dims.groups * dims.state
+    lead = xbc.shape[:-1]
+    x = xbc[..., :d].reshape(lead + (dims.heads, dims.head_dim))
+    b = xbc[..., d:d + gn].reshape(lead + (dims.groups, dims.state))
+    c = xbc[..., d + gn:].reshape(lead + (dims.groups, dims.state))
+    return x, b, c
+
+
+def _grouped(t, dims: SSMDims, axis: int):
+    """Split the head axis ``axis`` of ``t`` into (group, head of the
+    group): head i reads B and C of group i // (H / G), so with the
+    heads laid out by group an einsum shares them without a copy."""
+    axis %= t.ndim
+    return t.reshape(t.shape[:axis]
+                     + (dims.groups, dims.heads // dims.groups)
+                     + t.shape[axis + 1:])
+
+
+def _taps(rows, first, offset, width: int):
+    """For each of a convolution's ``width`` taps, oldest first: how far
+    back it reaches, whether that lies inside the row's run, and else
+    where in the slot's tail it lies.  rows, first, offset: [R] (a
+    row's flat index, its run's first row, where in the tail the input
+    before the run sits)."""
+    for j in range(width):
+        back = width - 1 - j
+        src = rows - back
+        # the input ``back`` tokens back lies (first - src) rows before
+        # the run: that many entries before ``offset`` in the tail
+        yield j, back, src >= first, jnp.clip(
+            offset - (first - src) + 1, 0, width - 1)
+
+
+def conv_rows(xbc, tail, seq_slot, first, offset, fresh, conv_w, conv_b):
+    """The causal convolution over a step's flat rows: each row reaches
+    back into its own run and, before the run's first row, into its
+    slot's tail.
+
+    xbc: [T, C] raw inputs; tail: [S, W, C], a slot's last ``W`` raw
+    inputs (the last entry the newest); seq_slot: [T]; first: [T], the
+    flat index of the first row of the row's run; offset: [T], where in
+    the tail the input one before the run's first row sits (``W - 1``,
+    or ``W - 2`` for a replayed row, whose own input is the tail's
+    newest); fresh: [T], the run starts at position 0 (what lies before
+    it is zeros whatever the slot held).
+    → silu(b + sum_j w[:, j] * input W-1-j tokens back) [T, C] float32."""
+    T, W = xbc.shape[0], tail.shape[1]
+    acc = jnp.broadcast_to(conv_b.astype(F32), xbc.shape)
+    # ONE gather of each row's whole tail; a tap then chooses among its
+    # W entries with selects (a gather a tap was most of this scope)
+    mine = jnp.where(fresh[:, None, None], 0, tail[seq_slot])   # [T, W, C]
+    for j, back, inside, at in _taps(jnp.arange(T), first, offset, W):
+        reach = mine[:, 0]
+        for i in range(1, W):
+            reach = jnp.where((at == i)[:, None], mine[:, i], reach)
+        own = jnp.pad(xbc, ((back, 0), (0, 0)))[:T]      # the row ``back`` up
+        acc = acc + conv_w[:, j].astype(F32) \
+            * jnp.where(inside[:, None], own, reach).astype(F32)
+    return jax.nn.silu(acc)
+
+
+def conv_tails(xbc, tail, last, first, offset, fresh, has_run):
+    """The tails the step leaves: for a slot with a run, the ``W`` raw
+    inputs that end at the run's last row ``last`` [S] (out of the run
+    and, where it is shorter than ``W``, out of the old tail); the other
+    slots keep theirs.  first, offset, fresh, has_run: [S], as
+    ``conv_rows``' per row.  → [S, W, C]."""
+    S, W = tail.shape[:2]
+    slots = jnp.arange(S)
+    out = []
+    for j, back, inside, at in _taps(last, first, offset, W):
+        reach = jnp.where(fresh[:, None], 0, tail[slots, at])
+        out.append(jnp.where(inside[:, None],
+                             xbc[jnp.maximum(last - back, 0)], reach))
+    return jnp.where(has_run[:, None, None],
+                     jnp.stack(out, axis=1).astype(tail.dtype), tail)
+
+
+def state_update(state, x, b, c, dt, a, d_skip, active, replay, fresh,
+                 dims: SSMDims):
+    """One token for every slot, dense over the pool.
+
+    state: [S, H, P, N] stored type; x: [S, H, P]; b, c: [S, G, N];
+    dt: [S, H] (after softplus); a: [H] (negative); active: [S], the
+    slot holds a one-token run this step; replay: [S], that row was
+    computed before (its state is already in the slot: read it, do not
+    advance it); fresh: [S], the run starts at position 0.
+    → (y [S, H, P] float32, new state in the stored type)."""
+    s32 = _grouped(state.astype(F32), dims, 1)            # [S, G, K, P, N]
+    s32 = jnp.where(fresh[:, None, None, None, None], 0.0, s32)
+    b32, c32 = b.astype(F32), c.astype(F32)
+    x32 = _grouped(x.astype(F32), dims, 1)                  # [S, G, K, P]
+    dt = _grouped(dt.astype(F32), dims, 1)                  # [S, G, K]
+    decay = jnp.exp(dt * _grouped(a, dims, 0))
+    dtx = dt[..., None] * x32
+    # y off the OLD state: y_t = C.S_t = decay (C.S_{t-1}) + dt x (B.C)
+    read = jnp.einsum("sgkpn,sgn->sgkp", s32, c32)
+    step = decay[..., None] * read \
+        + dtx * jnp.sum(b32 * c32, -1)[:, :, None, None]
+    y = jnp.where(replay[:, None, None, None], read, step) \
+        + _grouped(d_skip.astype(F32), dims, 0)[..., None] * x32
+    new = decay[..., None, None] * s32 \
+        + dtx[..., None] * b32[:, :, None, None, :]
+    advance = active & ~replay
+    out = jnp.where(advance[:, None, None, None],
+                    new.reshape(state.shape).astype(state.dtype), state)
+    return y.reshape(x.shape), out
+
+
+def chunk_scan(x, b, c, dt, a, d_skip, first, init, dims: SSMDims
+               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The chunked form over ``NC`` chunks of ``Q`` tokens.
+
+    x: [NC, Q, H, P]; b, c: [NC, Q, G, N]; dt: [NC, Q, H] (after
+    softplus; 0 marks a row that is not there: it neither moves the
+    state nor is moved by it); a: [H]; first: [NC] bool, the chunk
+    starts from ``init[c]`` [NC, H, P, N] (float32), else from the state
+    the chunk before it left.  → (y [NC, Q, H, P] float32, the state
+    each chunk leaves [NC, H, P, N] float32)."""
+    NC, Q = x.shape[:2]
+    x32 = _grouped(x.astype(F32), dims, 2)                  # [NC, Q, G, K, P]
+    b32, c32 = b.astype(F32), c.astype(F32)                 # [NC, Q, G, N]
+    dt = _grouped(dt.astype(F32), dims, 2)                  # [NC, Q, G, K]
+    # the running sum of log decays down a chunk, as a product with a
+    # triangle of ones (a cumsum is a slow windowed reduction on the
+    # TPU); in full float32: exp() of it is what every row is scaled by
+    tri = jnp.tril(jnp.ones((Q, Q), F32))
+    la = jnp.einsum("qr,crgk->cqgk", tri, dt * _grouped(a, dims, 0),
+                    precision=jax.lax.Precision.HIGHEST)
+    dtx = dt[..., None] * x32
+    # inside a chunk: row q reads row r <= q under exp(la_q - la_r)
+    diff = la[:, :, None] - la[:, None, :]                  # [NC, Q, R, G, K]
+    keep = (jnp.arange(Q)[:, None] >= jnp.arange(Q)[None, :])[
+        None, :, :, None, None]
+    decay = jnp.exp(jnp.where(keep, diff, -jnp.inf))
+    scores = jnp.einsum("cqgn,crgn->cqrg", c32, b32)[..., None] * decay
+    y = jnp.einsum("cqrgk,crgkp->cqgkp", scores, dtx)
+    # what a chunk's own rows leave at its end
+    to_end = jnp.exp(la[:, -1:] - la)                       # [NC, Q, G, K]
+    local = jnp.einsum("cqgkp,cqgn->cgkpn", dtx * to_end[..., None], b32)
+    total = jnp.exp(la[:, -1])                              # [NC, G, K]
+    into = jnp.exp(la)                                      # [NC, Q, G, K]
+
+    def carry(prev, xs):
+        f, s0, loc, tot, c_g, dec = xs
+        s_in = jnp.where(f, s0, prev)
+        off = jnp.einsum("qgn,gkpn->qgkp", c_g, s_in) * dec[..., None]
+        s_out = tot[..., None, None] * s_in + loc
+        return s_out, (off, s_out)
+
+    init = _grouped(init.astype(F32), dims, 1)
+    _, (off, left) = jax.lax.scan(
+        carry, jnp.zeros(init.shape[1:], F32),
+        (first, init, local, total, c32, into))
+    y = y + off + _grouped(d_skip.astype(F32), dims, 0)[..., None] * x32
+    return (y.reshape(x.shape),
+            left.reshape((NC, dims.heads) + left.shape[3:]))
+
+
+def gated_norm(y, z, scale, dims: SSMDims, eps: float):
+    """``y * silu(z)``, then an RMSNorm over each group's channels
+    apart with one learned [d_ssm] scale.  y, z: [..., d_ssm]."""
+    g = y.astype(F32) * jax.nn.silu(z.astype(F32))
+    lead = g.shape[:-1]
+    g = g.reshape(lead + (dims.groups, dims.d_ssm // dims.groups))
+    g = g * jax.lax.rsqrt((g * g).mean(-1, keepdims=True) + eps)
+    return g.reshape(lead + (dims.d_ssm,)) * scale.astype(F32)
+
+
+def discretise(dt_raw, mp):
+    """(dt [..., H] after softplus, A [H] negative) in float32."""
+    dt = jax.nn.softplus(dt_raw.astype(F32) + mp["dt_bias"].astype(F32))
+    return dt, -jnp.exp(mp["A_log"].astype(F32))
+
+
+def mixer_forward(mp, u, dims: SSMDims, col_scales, eps: float):
+    """The whole mixer over whole sequences from a zero state.
+    u: [B, S, dm] → [B, S, dm], in ``u``'s type."""
+    dtype = u.dtype
+    Bsz, S, _ = u.shape
+    with jax.named_scope("ssm_in"):
+        z, xbc, dt_raw = split_in_proj(u @ mp["w_in"].astype(dtype), dims,
+                                       col_scales)
+    with jax.named_scope("ssm_conv"):
+        W = dims.conv
+        padded = jnp.pad(xbc, ((0, 0), (W - 1, 0), (0, 0))).astype(F32)
+        acc = mp["conv_b"].astype(F32)
+        for j in range(W):          # zeros stand before the first token
+            acc = acc + mp["conv_w"][:, j].astype(F32) * padded[:, j:j + S]
+        x, b, c = split_xbc(jax.nn.silu(acc).astype(dtype), dims)
+    with jax.named_scope("ssm_scan"):
+        dt, a = discretise(dt_raw, mp)
+        Q = dims.chunk
+        nc = -(-S // Q)
+        pad = nc * Q - S
+
+        def chunks(t):
+            t = jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+            return t.reshape((Bsz * nc, Q) + t.shape[2:])
+
+        first = (jnp.arange(Bsz * nc) % nc) == 0
+        init = jnp.zeros((Bsz * nc, dims.heads, dims.head_dim, dims.state),
+                         F32)
+        y, _ = chunk_scan(chunks(x), chunks(b), chunks(c), chunks(dt), a,
+                          mp["D"], first, init, dims)
+        y = y.reshape(Bsz, nc * Q, dims.d_ssm)[:, :S]
+    with jax.named_scope("ssm_out"):
+        y = gated_norm(y, z, mp["norm"], dims, eps).astype(dtype)
+        return y @ mp["w_out"].astype(dtype)

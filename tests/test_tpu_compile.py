@@ -289,6 +289,19 @@ def _pstep_compiled(one_chip, cfg, kv_quant, T, seqs, bs, mbs, blocks):
     layer_bytes = kv.size * kv.dtype.itemsize // cfg.num_layers
     if kv_quant:
         kv = (kv, S(pool[:-1], jnp.float32))
+    rec = None
+    if cfg.has_ssm:
+        # a model with recurrent layers: the state rows beside the pool
+        from deepspeed_tpu.inference.ragged.state import RecBatch
+        sd = cfg.ssm_dims
+        rows = (cfg.num_layers, seqs + 1)
+        kv = {"kv": kv,
+              "ssm": S(rows + (sd.heads, sd.head_dim, sd.state),
+                       jnp.bfloat16),
+              "conv": S(rows + (sd.conv, sd.conv_channels), jnp.bfloat16)}
+        rec = RecBatch(run_len=S((seqs,), jnp.int32),
+                       replay=S((seqs,), jnp.bool_),
+                       chunks=S((-(-T // sd.chunk) + 4, 5), jnp.int32))
     tok = S((T,), jnp.int32)
     batch = RaggedBatch(
         token_ids=tok, positions=tok, seq_slot=tok,
@@ -296,7 +309,7 @@ def _pstep_compiled(one_chip, cfg, kv_quant, T, seqs, bs, mbs, blocks):
         block_tables=S((seqs, max(32, mbs)), jnp.int32),
         context_lens=S((seqs,), jnp.int32),
         logits_idx=S((seqs,), jnp.int32), n_tokens=T, n_seqs=seqs,
-        feedback_src=tok, seq_uids=S((seqs,), jnp.uint32))
+        feedback_src=tok, seq_uids=S((seqs,), jnp.uint32), rec=rec)
     greedy = SamplingParams(temperature=0.0, max_new_tokens=1)
     key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
 
@@ -354,6 +367,39 @@ def _tile_grid_conditions(jaxpr, T, seqs, mbs, short_group):
                 if e.primitive.name == "jit"
                 and e.params["name"] == "_group_rows"]
     assert laid_out and not any(laid_out)
+
+
+def test_recurrent_serving_step_compiles_fits_and_keeps_its_pools_in_place(
+        one_chip, on_chip):
+    """The whole serving step of ``falcon-h1-34b-d6`` as the benchmark
+    runs it (6 layers, 1536 blocks of 64, 512 tokens and 128 sequences a
+    step): the paged kernel at five query heads a kv head; the state rows
+    ride the layer scan beside the paged pool and neither is copied,
+    sliced out of its stack or written back whole; weights, both pools
+    and temporaries fit a 16 GB chip."""
+    from deepspeed_tpu.models.presets import build_config
+
+    cfg = build_config("falcon-h1-34b", num_layers=6, max_seq_len=1024)
+    compiled, layer_bytes = _pstep_compiled(
+        one_chip, cfg, False, T=512, seqs=128, bs=64, mbs=16, blocks=1536)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2     # the paged kernel's two
+    sd = cfg.ssm_dims
+    state_layer = 129 * sd.heads * sd.head_dim * sd.state * 2
+    assert layer_bytes == 1537 * 64 * 2 * 4 * 128 * 2
+    # nothing as large as half of one layer's share of either pool is
+    # ever materialized beside the arguments: the one-token update reads
+    # and writes the state rows where they lie (a fused dynamic slice
+    # and a fused in-place update of the stack), and a chunk's first and
+    # last state are cut out and written back a 2 MiB row at a time (a
+    # gather over the layer made a 270 MB copy of it)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < min(layer_bytes, state_layer) // 2
+    moved = [m for m in _moves_of(text, state_layer)
+             if "dynamic-update-slice" not in m and "fusion" not in m]
+    assert moved == [], moved
+    assert mem.argument_size_in_bytes > 13.0e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
 
 
 def test_moe_serving_step_compiles_and_fits(one_chip, on_chip):
