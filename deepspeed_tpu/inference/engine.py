@@ -24,6 +24,7 @@ from __future__ import annotations
 import dataclasses
 import time
 from collections import deque
+from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
@@ -51,7 +52,7 @@ from .overload import (AdmissionVerdict, OverloadConfig, RequestMeta,
                        admission_decision, effective_priority,
                        select_victim)
 from .ragged.state import (FEEDBACK_TOKEN, BatchStager, KVCacheConfig,
-                           RaggedBatch, StateManager)
+                           RaggedBatch, StateManager, step_rows)
 from .sampler import SamplingParams, sample_rows
 
 
@@ -459,7 +460,7 @@ class InferenceEngine:
             if not comm.unembed:
                 comm = None
         self._comm_active = comm
-        self._comm_stats: Optional[Dict[str, float]] = None
+        self._comm_stats: Dict[int, Dict[str, float]] = {}  # by rows
         if self.topology is None:
             self._place_default_device()
         # tpulint: live-set — uid -> unprocessed toks
@@ -468,16 +469,19 @@ class InferenceEngine:
         self._rng = jax.random.PRNGKey(0)
         self._cow_fn = None           # lazy jitted prefix-cache block copy
         self._restage_fn = None       # lazy jitted tier->HBM block upload
-        self._pstep_fns: Dict[tuple, object] = {}  # (bucket, sampler_key)
+        # (bucket, sampler_key) -> the served step, one jit function
+        # that holds an executable for every row count of the ladder
+        # (``_step_rows``): all of them compiled when it is built
+        self._pstep_fns: Dict[tuple, object] = {}
         # always empty: the engine races nothing at start-up any more.
         # Kept because benchmarks/lib/drivers/serve.py iterates it for
         # its "races" notes and a PR of this kind may not edit that file
         # (ROADMAP.md debt B1: retire the readers, then this attribute)
         self.probe_times: Dict[str, Dict[str, float]] = {}
-        # (bucket, sampler_key) -> what the compiled step is, noted once
-        # at its first call: "temp_bytes" is what the program needs
-        # beside its arguments — the layer scan carries the cache in
-        # place, so it stays far under one layer's share of the pool
+        # (rows, bucket, sampler_key) -> what the compiled step is,
+        # noted when it is compiled: "temp_bytes" is what the program
+        # needs beside its arguments — the layer scan carries the cache
+        # in place, so it stays far under one layer's share of the pool
         self.serving_programs: Dict[tuple, Dict] = {}
         self._burst_fns: Dict[tuple, object] = {}
         # serving programs that have COMPLETED at least one call: only
@@ -487,6 +491,11 @@ class InferenceEngine:
         self._steps_done = 0
         # --- model-free speculative decoding (spec_decode.py) ----------
         self._setup_spec_decode()
+        # the row counts a served step is compiled at: a step runs at
+        # the smallest that holds its scheduled tokens (``step_rows``)
+        self._step_rows = step_rows(self.icfg.max_seqs, self._n_verify,
+                                    self.icfg.token_budget)
+        self._row_tokens = self._row_slots = 0    # serving_step_row_fill
         # pipelined-serving state: alternating host staging buffers, the
         # last dispatched step's on-device sample array (the feedback
         # source for the next step), and a zero fallback for step 0
@@ -762,6 +771,17 @@ class InferenceEngine:
             "sampled rows thrown away at collect because their stream "
             "had ended or paused (reason: finished|cancelled|stalled|...)",
             int_valued=True)
+        # the row count each dispatched step ran at (``_step_rows``),
+        # and how much of those rows held a scheduled token
+        self._c_step_rows = reg.counter(
+            "serving_step_rows_total",
+            "dispatched serving steps by the row count their program "
+            "was compiled at (rung)", int_valued=True)
+        reg.gauge_fn("serving_step_row_fill",
+                     lambda: (self._row_tokens / self._row_slots
+                              if self._row_slots else None),
+                     "scheduled tokens over compiled rows of the "
+                     "dispatched steps (absent before the first one)")
         # a model with recurrent layers (``_recurrent``): what the
         # dispatched steps did to the state rows, from the schedule
         if self._recurrent is not None:
@@ -1096,23 +1116,58 @@ class InferenceEngine:
         else:
             self._compiled_ever.add((kind, key))
 
-    def _note_program(self, key, step_fn, args) -> None:
-        """Enter one compiled serving step in ``serving_programs``, on
-        the return of its first call.  ``lower(*args).compile()`` of a
-        jit function that has just run these arguments is two cache
-        lookups (jit keeps its lowering and the lowering its
-        executable), so the temporary bytes are read from the program
-        the step already built: nothing compiles twice."""
-        temp = None
-        try:
-            mem = step_fn.lower(*args).compile().memory_analysis()
-            temp = None if mem is None else int(mem.temp_size_in_bytes)
-        except Exception as e:
-            logger.warning("serving step %r: no memory analysis (%s: %s)",
-                           key, type(e).__name__,
-                           str(e).splitlines()[0][:120] if str(e) else "")
-        self.serving_programs[key] = {"temp_bytes": temp}
-        logger.info("serving step %r: temporaries %s bytes", key, temp)
+    def _compile_rungs(self, key, step_fn, batch, prev, rng) -> None:
+        """Compile the served step ``key`` = (bucket, sampler_key) at
+        every row count of the ladder, ahead of any step that needs it,
+        and keep it: no rung compiles under traffic.
+        ``lower(...).compile()`` fills the caches the jit function's
+        own calls read (jit keeps its lowering and the lowering its
+        executable), so a step's call finds its program built.  Each
+        rung enters ``serving_programs`` with the temporary bytes of
+        the executable just built.  ``batch``: the step about to
+        launch, which gives its own rung; the other is lowered from a
+        batch of its shape that holds no token.  The rungs are built
+        side by side, a thread each (tracing, lowering and the compiler
+        or its cache take seconds a program on a serving host, and
+        set-up would pay them one after the other: 8.6 s against 1.0 in
+        ``serve-prefill``, PERF.md section 6, PR 43); an engine of one
+        rung builds its program on this thread, as it always did."""
+        rungs = self._step_rows
+        batches = {rows: batch if rows == batch.token_ids.shape[0] else
+                   self._stage(self.state.blank_batch(rows, self._n_verify))
+                   for rows in rungs}
+
+        def build(rows):
+            return step_fn.lower(self.params, self._quant, self.state.kv,
+                                 batches[rows], prev, rng).compile()
+
+        if len(rungs) == 1:
+            built = [build(rungs[0])]
+        else:
+            with ThreadPoolExecutor(len(rungs)) as pool:
+                built = list(pool.map(build, rungs))
+        for rows, compiled in zip(rungs, built):
+            rkey = (rows,) + key
+            self._note_compile("p", rkey)
+            temp = None
+            try:
+                mem = compiled.memory_analysis()
+                temp = None if mem is None else int(mem.temp_size_in_bytes)
+            except Exception as e:
+                logger.warning(
+                    "serving step %r: no memory analysis (%s: %s)", rkey,
+                    type(e).__name__,
+                    str(e).splitlines()[0][:120] if str(e) else "")
+            self.serving_programs[rkey] = {"temp_bytes": temp}
+            logger.info("serving step %r: temporaries %s bytes", rkey, temp)
+        if len(self._pstep_fns) >= 16:        # bound retained executables
+            evicted = next(iter(self._pstep_fns))
+            self._pstep_fns.pop(evicted)
+            # a rebuilt executable recompiles: its next call is cold
+            # again or the watchdog would time the compile
+            for rows in rungs:
+                self._warm_keys.discard(("p", (rows,) + evicted))
+        self._pstep_fns[key] = step_fn
 
     def reset_timings(self) -> None:
         """Zero the cumulative per-phase breakdown the serving loop
@@ -1143,6 +1198,7 @@ class InferenceEngine:
         what a bench leg calls between warmup and its timed region."""
         self.metrics.reset()
         self._group_blocks = self._group_slots = 0
+        self._row_tokens = self._row_slots = 0
         self.requests.clear()
         self.tracer.clear()
         # rearm the pool high-water mark so a timed region reports ITS
@@ -3257,18 +3313,19 @@ class InferenceEngine:
             mbs = min(mbs, self.max_blocks_per_seq)
         key = (mbs, sampling.sampler_key)
         step_fn = self._pstep_fns.pop(key, None)
-        if step_fn is None:
-            if len(self._pstep_fns) >= 16:    # bound retained executables
-                evicted = next(iter(self._pstep_fns))
-                self._pstep_fns.pop(evicted)
-                # a rebuilt executable recompiles: its next call is
-                # cold again or the watchdog would time the compile
-                self._warm_keys.discard(("p", evicted))
+        fresh = step_fn is None
+        if fresh:
+            # kept once its first launch has compiled its rungs
             step_fn = self._build_pstep(mbs, sampling)
-            self._note_compile("p", key)
-        self._pstep_fns[key] = step_fn    # reinsert: LRU, not FIFO
-        cold = ("p", key) not in self._warm_keys
+        else:
+            self._pstep_fns[key] = step_fn    # reinsert: LRU, not FIFO
+        # the step runs at the smallest compiled row count that holds
+        # what was scheduled: rows that hold no token cost every matrix
+        # product as much as rows that do
         n_tokens = sum(len(t) for _, t in sched)
+        n_rows = next(r for r in self._step_rows if r >= n_tokens)
+        key = (n_rows,) + key
+        cold = ("p", key) not in self._warm_keys
         tiles = {}
         if pallas:
             n_short, n_long, rows = tile_counts([len(t) for _, t in sched])
@@ -3281,11 +3338,12 @@ class InferenceEngine:
         if self._recurrent is not None:
             tiles.update(self._count_state_rows(sched))
         t1 = tr.phase("ds.serve.stage", track="stage", sid=sid,
-                      n_tokens=n_tokens, n_seqs=len(sched), mbs=mbs,
+                      n_tokens=n_tokens, rows=n_rows, n_seqs=len(sched),
+                      mbs=mbs,
                       **tiles, **self._count_attn_kv(sched, pallas))
         batch = self._stage(
             self.state.build_batch(
-                sched, self.icfg.token_budget, stager=self._stager,
+                sched, n_rows, stager=self._stager,
                 draft_lens={u: len(d)
                             for u, d in self._sched_drafts.items()},
                 n_verify=self._n_verify,
@@ -3306,7 +3364,7 @@ class InferenceEngine:
         # the same interval, under the name that says so
         t2 = tr.phase("ds.serve.compile" if cold else "ds.serve.dispatch",
                       track="dispatch", sid=sid, n_tokens=n_tokens,
-                      n_seqs=len(sched),
+                      rows=n_rows, n_seqs=len(sched),
                       n_decode=sum(1 for _, t in sched if len(t) == 1),
                       mbs=mbs, ahead=int(bool(self._inflight_sched)))
         if callable(rng):
@@ -3319,14 +3377,21 @@ class InferenceEngine:
             else self._zero_toks
         uids = tuple(uid for uid, _ in sched)
         guard: Dict[str, float] = {}      # the watchdog's hand-off time
+
+        def launch():
+            if fresh:
+                # a step function just built compiles every rung with
+                # its first call, which is cold and runs unguarded
+                self._compile_rungs(key[1:], step_fn, batch, prev, rng)
+            return step_fn(self.params, self._quant, self.state.kv, batch,
+                           prev, rng)
+
         try:
             # the one deadline-guarded dispatch seam: the watchdog
             # (and the chaos harness's fault injector) wrap exactly
             # this call — see inference/failures.py
             toks, self.state.kv = self.failures.run(
-                lambda: step_fn(self.params, self._quant,
-                                self.state.kv, batch, prev, rng),
-                uids=uids, cold=cold, site="dispatch", sid=sid,
+                launch, uids=uids, cold=cold, site="dispatch", sid=sid,
                 stamps=guard)
         except Exception as e:
             # every failure on the dispatch path funnels through the
@@ -3361,21 +3426,25 @@ class InferenceEngine:
         tm["stage_ms"] += (t2 - t1) * 1e3
         tm["device_ms"] += (t3 - t2) * 1e3
         tm["steps"] += 1
+        self._c_step_rows.inc(1, rung=str(n_rows))
+        self._row_tokens += n_tokens
+        self._row_slots += n_rows
         if self._comm_active is not None:
-            self._bump_comm_counters()
+            self._bump_comm_counters(n_rows)
         if cold:
-            # first completed call of this program: its dispatch wall
-            # time carried the XLA compile (the timestamps are the ones
-            # above — the compile span costs no extra clock reads)
+            # first completed call of this program: where its step
+            # function was built with it, its dispatch wall time carried
+            # the XLA compiles (the timestamps are the ones above — the
+            # compile span costs no extra clock reads)
             tm["compile_ms"] += (t3 - t2) * 1e3
-            # once per program, on the warm executable — args are the
-            # post-call live buffers (the donated kv was rebound to the
-            # step's output)
-            args = (self.params, self._quant, self.state.kv, batch, prev,
-                    rng)
-            self._note_program(key, step_fn, args)
             if self.devtel is not None:
-                self.devtel.probe_program(("p",) + key, step_fn, args)
+                # once per program, on the warm executable — args are
+                # the post-call live buffers (the donated kv was
+                # rebound to the step's output)
+                self.devtel.probe_program(
+                    ("p",) + key, step_fn,
+                    (self.params, self._quant, self.state.kv, batch, prev,
+                     rng))
         if self.devtel is not None:
             self.devtel.on_dispatch(("p",) + key)
         if self._anom is not None:
@@ -3397,14 +3466,14 @@ class InferenceEngine:
                          registered=tuple(self.state.round_registered),
                          cold=cold)
 
-    def _comm_step_stats(self) -> Dict[str, float]:
+    def _comm_step_stats(self, n_rows: int) -> Dict[str, float]:
         """Modeled wire accounting for ONE dispatched step's decomposed
         TP collectives, derived from the compiled shapes (host
         arithmetic only): the down-projection all-reduces one
-        [token_budget, d_model] partial per layer, the unembed gathers
-        one [rows, vocab] logits block.  Tile counts mirror the
-        compiled program's ``_resolve_tiles`` clamp, not the raw
-        config knob."""
+        [n_rows, d_model] partial per layer (the rung the step ran
+        at), the unembed gathers one [rows, vocab] logits block.  Tile
+        counts mirror the compiled program's ``_resolve_tiles`` clamp,
+        not the raw config knob."""
         from ..comm.overlap import _resolve_tiles, wire_bytes
 
         comm = self._comm_active
@@ -3413,14 +3482,13 @@ class InferenceEngine:
         st = {"ops_exact": 0, "ops_quant": 0, "tiles": 0,
               "bytes_exact": 0.0, "bytes_quant": 0.0}
         if comm.downproj:
-            elems = self.icfg.token_budget * self.cfg.d_model
+            elems = n_rows * self.cfg.d_model
             per = wire_bytes("all_reduce", elems, isz, n, comm.quant_bits)
             L = self.cfg.num_layers
             kind = "quant" if comm.quant_bits else "exact"
             st[f"ops_{kind}"] += L
             st[f"bytes_{kind}"] += per * L
-            st["tiles"] += L * _resolve_tiles(self.icfg.token_budget,
-                                              comm.tiles)
+            st["tiles"] += L * _resolve_tiles(n_rows, comm.tiles)
         if comm.unembed:
             rows = self.icfg.max_seqs * self._n_verify
             per = wire_bytes("all_gather", rows * self.cfg.vocab_size,
@@ -3430,10 +3498,11 @@ class InferenceEngine:
             st["tiles"] += _resolve_tiles(rows, comm.tiles)
         return st
 
-    def _bump_comm_counters(self) -> None:
-        if self._comm_stats is None:
-            self._comm_stats = self._comm_step_stats()
-        st = self._comm_stats
+    def _bump_comm_counters(self, n_rows: int) -> None:
+        st = self._comm_stats.get(n_rows)
+        if st is None:
+            st = self._comm_stats[n_rows] = \
+                self._comm_step_stats(n_rows)
         if st["ops_exact"]:
             self._c_comm_ops.inc(st["ops_exact"], kind="exact")
             self._c_comm_bytes.inc(st["bytes_exact"], kind="exact")

@@ -303,6 +303,25 @@ class RaggedBatch(NamedTuple):
                                  # a model with recurrent layers only
 
 
+STEP_ROWS_ALIGN = 128
+
+
+def step_rows(max_seqs: int, n_verify: int, token_budget: int
+              ) -> Tuple[int, ...]:
+    """The row counts a served step is compiled at, smallest first: a
+    step runs at the smallest that holds the tokens it was scheduled.
+    The bottom rung holds every step without a prompt chunk
+    (``max_seqs`` rows of ``n_verify`` tokens, rounded up to the MXU's
+    128 rows); ``token_budget``, the scheduler's cap on a step, is the
+    top one.  An engine whose bottom rung reaches its budget has the
+    budget alone.  (Rungs between the two, doubling, were measured and
+    left out: PERF.md section 6, PR 43.  A program more costs every
+    set-up its trace, and the paged kernel's is seconds of Python.)"""
+    rows = -(-max_seqs * max(1, n_verify) // STEP_ROWS_ALIGN) \
+        * STEP_ROWS_ALIGN
+    return (rows, token_budget) if rows < token_budget else (token_budget,)
+
+
 class BatchStager:
     """Two alternating host-side staging buffer sets for RaggedBatch
     metadata (the reference's pinned "fast host buffer",
@@ -322,11 +341,9 @@ class BatchStager:
         self._bufs = [self._alloc(token_budget, max_seqs, max_blocks,
                                   self.n_verify)
                       for _ in range(max(2, depth))]
-        for b in self._bufs:
-            if n_chunks:     # a model with recurrent layers (RecBatch)
-                b["run_len"] = np.zeros(max_seqs, np.int32)
-                b["replay"] = np.zeros(max_seqs, bool)
-                b["chunks"] = np.zeros((n_chunks, 5), np.int32)
+        if n_chunks:         # a model with recurrent layers (RecBatch)
+            for b in self._bufs:
+                b.update(self._alloc_rec(max_seqs, n_chunks))
         self._i = 0
 
     @staticmethod
@@ -341,6 +358,15 @@ class BatchStager:
             "feedback_src": np.full(T, -1, np.int32),
             "seq_uids": np.zeros(S, np.uint32),
             "verify_idx": np.full((S, nv), -1, np.int32),
+        }
+
+    @staticmethod
+    def _alloc_rec(S: int, n_chunks: int) -> Dict[str, np.ndarray]:
+        return {
+            "run_len": np.zeros(S, np.int32),
+            "replay": np.zeros(S, bool),
+            "chunks": np.tile(np.array([0, 0, S, 1, 0], np.int32),
+                              (n_chunks, 1)),
         }
 
     def next_buffers(self) -> Dict[str, np.ndarray]:
@@ -931,48 +957,21 @@ class StateManager:
         keeps its place in the chain (``SequenceDescriptor.deferred``)
         instead of breaking it: the host learns the token one step
         late, in order."""
-        max_blocks = self.cfg.num_blocks
         T = token_budget
         rc = self.cfg.recurrent
         n_chunks = 0
         # fresh registration ledger for this round (see round_registered)
         self.round_registered = []
-        if stager is not None \
-                and stager.shape_key == (T, self.max_seqs, max_blocks) \
-                and stager.n_verify >= n_verify:
-            bufs = stager.next_buffers()
-            token_ids = bufs["token_ids"]
-            positions = bufs["positions"]
-            seq_slot = bufs["seq_slot"]
-            block_tables = bufs["block_tables"]
-            context_lens = bufs["context_lens"]
-            logits_idx = bufs["logits_idx"]
-            feedback_src = bufs["feedback_src"]
-            seq_uids = bufs["seq_uids"]
-            verify_idx = bufs["verify_idx"]
-            rec = {k: bufs[k] for k in ("run_len", "replay", "chunks")} \
-                if rc is not None else None
-        else:
-            token_ids = np.zeros(T, np.int32)
-            positions = np.zeros(T, np.int32)
-            seq_slot = np.full(T, 0, np.int32)
-            # -1 pad: negative gather wraps to the KV array's last row,
-            # which is the zeroed trash block — padded columns can never
-            # alias a live block (they are also masked by position)
-            block_tables = np.full((self.max_seqs, max_blocks), -1, np.int32)
-            context_lens = np.zeros(self.max_seqs, np.int32)
-            logits_idx = np.full(self.max_seqs, -1, np.int32)
-            feedback_src = np.full(T, -1, np.int32)
-            seq_uids = np.zeros(self.max_seqs, np.uint32)
-            verify_idx = np.full((self.max_seqs, max(1, n_verify)), -1,
-                                 np.int32)
-            rec = None
-            if rc is not None:
-                rec = {"run_len": np.zeros(self.max_seqs, np.int32),
-                       "replay": np.zeros(self.max_seqs, bool),
-                       "chunks": np.tile(
-                           np.array([0, 0, self.max_seqs, 1, 0], np.int32),
-                           (rc.n_chunks(T), 1))}
+        bufs = self._host_buffers(T, n_verify, stager)
+        token_ids = bufs["token_ids"]
+        positions = bufs["positions"]
+        seq_slot = bufs["seq_slot"]
+        block_tables = bufs["block_tables"]
+        context_lens = bufs["context_lens"]
+        logits_idx = bufs["logits_idx"]
+        feedback_src = bufs["feedback_src"]
+        seq_uids = bufs["seq_uids"]
+        verify_idx = bufs["verify_idx"]
 
         # keep existing sequences' tables valid even if not in this batch
         for uid, seq in self.seqs.items():
@@ -1032,23 +1031,23 @@ class StateManager:
                     # registration below stays cache-gated)
                     seq.chain.extend(int(t) for t in new_tokens)
             if rc is not None:
-                rec["run_len"][s] = n
+                bufs["run_len"][s] = n
                 if seq.state_ahead:
                     if n != 1 or seq.state_ahead != 1:
                         raise ValueError(
                             f"uid {uid}: its recurrent state is "
                             f"{seq.state_ahead} rows ahead; exactly one "
                             "row can be replayed")
-                    rec["replay"][s] = True
+                    bufs["replay"][s] = True
                     seq.state_ahead = 0
                 for at in range(0, n if n > 1 else 0, rc.chunk):
-                    if n_chunks >= len(rec["chunks"]):
+                    if n_chunks >= len(bufs["chunks"]):
                         raise ValueError(
                             f"more than {rc.scan_runs} runs of several "
                             "tokens in a step")
                     rows = min(rc.chunk, n - at)
-                    rec["chunks"][n_chunks] = (cursor + at, rows, s,
-                                               at == 0, at + rows == n)
+                    bufs["chunks"][n_chunks] = (cursor + at, rows, s,
+                                                at == 0, at + rows == n)
                     n_chunks += 1
             positions[cursor:cursor + n] = np.arange(
                 seq.seen_tokens, seq.seen_tokens + n)
@@ -1075,20 +1074,60 @@ class StateManager:
                 # that may roll back
                 self._register_chain_blocks(seq)
 
+        return self._device_batch(bufs, cursor, n_seqs, n_verify)
+
+    def _host_buffers(self, T: int, n_verify: int,
+                      stager: Optional[BatchStager] = None
+                      ) -> Dict[str, np.ndarray]:
+        """A step's host arrays at ``T`` rows, at their fill values:
+        the stager's next set cut to ``T`` rows where it is as wide as
+        this manager and at least as long, else fresh ones."""
+        S, nb = self.max_seqs, self.cfg.num_blocks
+        rc = self.cfg.recurrent
+        if stager is not None and stager.shape_key[1:] == (S, nb) \
+                and stager.shape_key[0] >= T \
+                and stager.n_verify >= n_verify:
+            bufs = dict(stager.next_buffers())
+            for k in ("token_ids", "positions", "seq_slot", "feedback_src"):
+                bufs[k] = bufs[k][:T]
+            if rc is not None:
+                bufs["chunks"] = bufs["chunks"][:rc.n_chunks(T)]
+            return bufs
+        # block_tables' -1 pad: a negative gather wraps to the KV
+        # array's last row, which is the zeroed trash block — padded
+        # columns can never alias a live block (they are also masked by
+        # position)
+        bufs = BatchStager._alloc(T, S, nb, max(1, n_verify))
+        if rc is not None:
+            bufs.update(BatchStager._alloc_rec(S, rc.n_chunks(T)))
+        return bufs
+
+    @staticmethod
+    def _device_batch(bufs: Dict[str, np.ndarray], n_tokens: int,
+                      n_seqs: int, n_verify: int) -> RaggedBatch:
+        T = len(bufs["token_ids"])
         return RaggedBatch(
-            token_ids=jnp.asarray(token_ids),
-            positions=jnp.asarray(positions),
-            seq_slot=jnp.asarray(seq_slot),
-            token_valid=jnp.asarray(np.arange(T) < cursor),
-            block_tables=jnp.asarray(block_tables),
-            context_lens=jnp.asarray(context_lens),
-            logits_idx=jnp.asarray(logits_idx),
-            n_tokens=cursor, n_seqs=n_seqs,
-            feedback_src=jnp.asarray(feedback_src),
-            seq_uids=jnp.asarray(seq_uids),
-            verify_idx=(jnp.asarray(verify_idx[:, :n_verify])
+            token_ids=jnp.asarray(bufs["token_ids"]),
+            positions=jnp.asarray(bufs["positions"]),
+            seq_slot=jnp.asarray(bufs["seq_slot"]),
+            token_valid=jnp.asarray(np.arange(T) < n_tokens),
+            block_tables=jnp.asarray(bufs["block_tables"]),
+            context_lens=jnp.asarray(bufs["context_lens"]),
+            logits_idx=jnp.asarray(bufs["logits_idx"]),
+            n_tokens=n_tokens, n_seqs=n_seqs,
+            feedback_src=jnp.asarray(bufs["feedback_src"]),
+            seq_uids=jnp.asarray(bufs["seq_uids"]),
+            verify_idx=(jnp.asarray(bufs["verify_idx"][:, :n_verify])
                         if n_verify > 1 else None),
-            rec=None if rc is None else RecBatch(
-                run_len=jnp.asarray(rec["run_len"]),
-                replay=jnp.asarray(rec["replay"]),
-                chunks=jnp.asarray(rec["chunks"])))
+            rec=None if "chunks" not in bufs else RecBatch(
+                run_len=jnp.asarray(bufs["run_len"]),
+                replay=jnp.asarray(bufs["replay"]),
+                chunks=jnp.asarray(bufs["chunks"])))
+
+    def blank_batch(self, rows: int, n_verify: int = 1) -> RaggedBatch:
+        """A step of ``rows`` rows that holds no token, with the shapes
+        and types :meth:`build_batch` gives one: what a serving program
+        is compiled from ahead of its first step.  Touches no
+        sequence."""
+        return self._device_batch(self._host_buffers(rows, n_verify),
+                                  0, 0, n_verify)

@@ -409,6 +409,43 @@ def test_scheduler_bounds_the_runs_of_several_tokens(tiny):
     assert len(eng._schedule()) == 3
 
 
+def test_chunk_table_follows_the_rung(tiny):
+    """A served step runs at the smallest compiled row count that holds
+    it (``ragged/state.step_rows``), and the table of a step's chunks is
+    as long as that many rows can need: a prompt of 150 tokens beside a
+    stream's row rides the top rung (160 rows, 24 chunks of 8), the
+    decode rows the bottom one (128, 20), and the streams are those of
+    an engine whose one rung is its budget."""
+    rng = np.random.default_rng(4)
+    prompts = {1: rng.integers(0, 1024, 150).tolist(),
+               2: rng.integers(0, 1024, 12).tolist()}
+    sp = SamplingParams(temperature=0.0, max_new_tokens=5)
+
+    def run(max_seqs):
+        eng = engine(tiny, token_budget=160, max_seqs=max_seqs,
+                     kv_block_size=64, num_kv_blocks=16, max_seq_len=256)
+        staged, stage = [], eng._stage
+
+        def noted(tree):
+            if hasattr(tree, "rec"):
+                staged.append((tree.token_ids.shape[0],
+                               tree.rec.chunks.shape[0]))
+            return stage(tree)
+
+        eng._stage = noted
+        return eng, eng.generate(prompts, sp), set(staged)
+
+    eng, got, staged = run(4)
+    rc = eng._recurrent
+    assert eng._step_rows == (128, 160)
+    assert staged == {(160, 24), (128, 20)}
+    assert all(eng.state.blank_batch(r).rec.chunks.shape
+               == (rc.n_chunks(r), 5) for r in eng._step_rows)
+    one, want, staged = run(160)
+    assert one._step_rows == (160,) and staged == {(160, 24)}
+    assert got == want
+
+
 # The five configurations the benchmark had before this one, at the tiny
 # sizes their files give for a rehearsal: the lowered programs of the
 # training forward and of the serving step, hashed on the parent commit

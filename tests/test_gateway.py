@@ -662,13 +662,33 @@ def test_fleet_backed_gateway(model):
 def test_drain_finishes_inflight_and_503s_late_arrivals(model):
     """The SIGTERM contract via the programmatic trigger the handler
     schedules: in-flight streams complete, late arrivals 503 with
-    Retry-After, the backend drain snapshot lands, exit is clean."""
+    Retry-After, the backend drain snapshot lands, exit is clean.
+
+    The stream in flight is held two tokens in (the engine's ``step``
+    hands the gateway empty rounds: the engine thread and the event
+    loop go on answering) until the late arrival has its answer.  Left
+    to run, its six tokens are a few milliseconds: on a loaded host the
+    drain was through and the listener closed before this thread had
+    sent the late request, which then met a refused connection, not a
+    503."""
     import threading
     eng, _ = build_engine(model=model)
     h = spawn_gateway(eng, GatewayConfig())
     # warm so "in-flight" means decoding, not compiling
     http_completion(h.host, h.port, {"prompt": [1, 2], "max_tokens": 1})
-    box = {}
+    box, held = {}, []
+    answered = threading.Event()
+    step = eng.step
+
+    def gated(*a, **k):
+        seq = eng.state.seqs.get(700)
+        if not answered.is_set() and seq is not None \
+                and len(seq.tokens) >= 2:
+            held.append(1)
+            return {}
+        return step(*a, **k)
+
+    eng.step = gated
 
     def drive():
         box["r"] = http_completion(
@@ -686,9 +706,13 @@ def test_drain_finishes_inflight_and_503s_late_arrivals(model):
     while not h.gateway._draining \
             and time.perf_counter() < deadline:
         time.sleep(0.005)
-    late = http_completion(h.host, h.port,
-                           {"prompt": [1], "max_tokens": 1})
+    try:
+        late = http_completion(h.host, h.port,
+                               {"prompt": [1], "max_tokens": 1})
+    finally:
+        answered.set()
     t.join(60)
+    assert held, "the stream was not in flight when the late one came"
     assert late["code"] == 503 and late["retry_after"] >= 1
     assert box["r"]["finish_reason"] == "length"
     assert len(box["r"]["tokens"]) == 6

@@ -671,43 +671,60 @@ class TestQuantizedKV:
 
 
 class TestServingProgramRecord:
-    """Each compiled serving bucket notes once, at its first call, what
-    the program needs beside its arguments (``engine.serving_programs``,
-    one pull gauge) — read from the executable the call built, so
-    nothing compiles a second time."""
+    """Each compiled serving program notes once, when it is compiled
+    (ahead of its first call, with its step function's other row
+    counts), what it needs beside its arguments
+    (``engine.serving_programs``, one pull gauge) — read from the
+    executable just built, which is the one its calls run: nothing
+    compiles a second time."""
 
     @pytest.mark.parametrize("attn_impl,over", [
         ("xla", {}), ("xla", {"kv_quant": "int8"}),
-        ("xla", {"kv_donate": "off"}), ("pallas", {"pipeline_depth": 1})],
-        ids=["bf", "int8kv", "no-donation", "pallas-sync"])
+        ("xla", {"kv_donate": "off"}), ("pallas", {"pipeline_depth": 1}),
+        ("xla", {"token_budget": 256})],
+        ids=["bf", "int8kv", "no-donation", "pallas-sync", "two-rungs"])
     def test_noted_once_without_a_second_compile(self, attn_impl, over):
         eng = make_fp32_engine(tiny_model(), attn_impl=attn_impl, **over)
         assert eng.serving_programs == {}
         assert "serving_step_temp_bytes" not in eng.metrics_snapshot()
-        noted = []
-        note = eng._note_program
+        built, launches = [], []
+        compile_rungs, dispatch = eng._compile_rungs, eng._dispatch
 
-        def counted(key, fn, args):
+        def counted(key, *a):
             before = len(compiles)
-            note(key, fn, args)
-            noted.append((key, len(compiles) - before))
+            compile_rungs(key, *a)
+            built.append((key, len(compiles) - before))
 
-        eng._note_program = counted
+        def launch(*a, **k):
+            before, n_built = len(compiles), sum(n for _, n in built)
+            st = dispatch(*a, **k)
+            launches.append(len(compiles) - before
+                            - (sum(n for _, n in built) - n_built))
+            return st
+
+        eng._compile_rungs, eng._dispatch = counted, launch
         with backend_compiles() as compiles:
             sp = SamplingParams(temperature=0.0, max_new_tokens=20)
             out = eng.generate({0: list(range(1, 30)), 1: [5, 6, 7]}, sp)
         assert len(out[0]) == 20
-        # one note a program, none of which compiled anything: under an
-        # XLA formulation a program a context bucket (16-token blocks:
-        # 29 + 20 tokens reach the 2- and 4-block buckets); the Pallas
-        # kernel's grid follows the batch, so there one program bounded
-        # by the engine's longest context serves every step
-        assert sorted(k for k, _ in noted) == sorted(eng._pstep_fns)
+        # one executable a row count of a step function, built with the
+        # function, and no launch compiled anything beside them: under
+        # an XLA formulation a step function a context bucket (16-token
+        # blocks: 29 + 20 tokens reach the 2- and 4-block buckets); the
+        # Pallas kernel's grid follows the batch, so there one function
+        # bounded by the engine's longest context serves every step
+        rungs = eng._step_rows
+        assert len(rungs) == (2 if "token_budget" in over else 1)
+        assert sorted(k for k, _ in built) == sorted(eng._pstep_fns)
         if attn_impl == "pallas":
-            assert [k[0] for k, _ in noted] == [eng.max_blocks_per_seq]
+            assert [k[0] for k, _ in built] == [eng.max_blocks_per_seq]
         else:
-            assert len(noted) >= 2
-        assert all(n == 0 for _, n in noted)
+            assert len(built) >= 2
+        assert all(n == len(rungs) for _, n in built)
+        assert len(launches) >= 20 and not any(launches)
+        assert sorted(eng.serving_programs) == sorted(
+            (r,) + k for k in eng._pstep_fns for r in rungs)
+        assert eng.timings["compiles"] == len(built) * len(rungs)
         for rec in eng.serving_programs.values():
             assert isinstance(rec["temp_bytes"], int)
         snap = eng.metrics_snapshot()

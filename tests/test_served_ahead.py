@@ -367,6 +367,53 @@ def test_hold_takes_the_continuation_back(model, owned):
 
 
 # ==========================================================================
+# consecutive launches at different row counts
+# ==========================================================================
+
+def test_a_launch_ahead_may_run_at_another_rung_than_the_one_unread():
+    """A stream decodes alone (the bottom rung, 128 rows) when a prompt
+    of 140 tokens arrives: the step that takes it runs at the top rung
+    (160) and is launched with the 128-row step before it unread, then
+    the next 128-row step with the 160-row one unread.  The sampled row
+    that feeds a launch and the pool it donates know no rung: the
+    tokens are those of an engine whose one rung is its budget."""
+    rng = np.random.default_rng(3)
+    late = rng.integers(1, 120, 140).tolist()
+
+    def run(max_seqs, model=None):
+        eng, model = build_engine(
+            token_budget=160, max_seqs=max_seqs, max_seq_len=256,
+            num_kv_blocks=64, model=model, trace=True,
+            overload=OverloadConfig(prefill_chunk=160))
+        eng.put(1, PROMPTS[102], max_new_tokens=14)
+        got = {1: [], 2: []}
+        for i in range(80):
+            if i == 5:
+                eng.put(2, late, max_new_tokens=6)
+            for u, t in eng.step(sampling=GREEDY).items():
+                got[u].append(t)
+            if [len(got[1]), len(got[2])] == [14, 6] and not eng.in_flight:
+                break
+        for uid in got:
+            eng.flush(uid)
+        assert_clean(eng)
+        launches = [(e["args"]["rows"], e["args"]["ahead"])
+                    for e in eng.tracer.events()
+                    if e["name"] in ("ds.serve.dispatch", "ds.serve.compile")]
+        return eng, model, got, launches
+
+    eng, model, got, launches = run(4)
+    assert eng._step_rows == (128, 160)
+    ahead = {(a[0], b[0]) for a, b in zip(launches, launches[1:]) if b[1]}
+    assert {(128, 160), (160, 128), (128, 128)} <= ahead, launches
+    one, _, want, plain = run(160, model)
+    assert one._step_rows == (160,) and {r for r, _ in plain} == {160}
+    assert got == want
+    # every rung compiled with the step function, none under the stream
+    assert eng.timings["compiles"] == 2 * len(eng._pstep_fns)
+
+
+# ==========================================================================
 # faults with a launch in flight
 # ==========================================================================
 
