@@ -332,6 +332,8 @@ class _InFlight(NamedTuple):
     # failure at collect must withdraw exactly these (the dispatch-
     # failure path uses the state manager's live round ledger instead)
     registered: Tuple[Tuple[bytes, int], ...] = ()
+    # tokens the step scheduled (a router makes top_k assignments each)
+    n_tokens: int = 0
     # the dispatch rode a first-call program (compile may still be in
     # flight on async backends): its readback runs unguarded too
     cold: bool = False
@@ -385,6 +387,16 @@ class InferenceEngine:
         # the process can observe, so two engines of one process always
         # agree and nothing is compiled or timed to settle it
         self.attn_impl = self.icfg.attn_impl
+        if self.cfg.mixer_stacks:
+            # latent attention and the delta rule have one formulation
+            # (ops/mla.py, ops/kda.py): no call comes from the paged kernel
+            if self.attn_impl == "pallas":
+                raise ValueError(
+                    "attn_impl='pallas': the model's layers are of kinds "
+                    f"{self.cfg.mixer_stacks} (TransformerConfig."
+                    "mixer_stacks), which the paged-attention kernel does "
+                    "not serve; use 'auto' or 'xla'")
+            self.attn_impl = "xla"
         if self.attn_impl == "auto":
             self.attn_impl = ("pallas" if jax.default_backend() == "tpu"
                               else "xla")
@@ -398,10 +410,16 @@ class InferenceEngine:
         # a sequence can never hold more blocks than the pool has
         self.max_blocks_per_seq = min(-(-max_len // self.icfg.kv_block_size),
                                       self.icfg.num_kv_blocks)
+        # a model whose layers are of kinds that each hold ONE cache: the
+        # pool holds its latent layers' rows (a row a token), the state
+        # rows its recurrent layers'
+        latent = "mla" in self.cfg.mixer_stacks
         kv_cfg = KVCacheConfig(
-            num_layers=self.cfg.num_layers,
+            num_layers=(self.cfg.layers_of("mla") if self.cfg.mixer_stacks
+                        else self.cfg.num_layers),
             num_kv_heads=self.cfg.num_kv_heads,
             head_dim=self.cfg.head_dim,
+            latent_dim=self.cfg.mla_dims.row if latent else 0,
             block_size=self.icfg.kv_block_size,
             num_blocks=self.icfg.num_kv_blocks,
             dtype=self.icfg.kv_dtype,
@@ -561,8 +579,9 @@ class InferenceEngine:
     def _recurrent_config(self, topology):
         """The state rows of a model with recurrent layers, or None;
         refuses by name what cannot serve such a model.  The state is
-        stored in the parameters' serving type (the configuration's,
-        not an option) and advanced in float32."""
+        stored in the parameters' serving type (a delta-rule state in
+        float32; the configuration's, not an option) and advanced in
+        float32."""
         from .ragged.state import RecurrentConfig
         cfg, icfg = self.cfg, self.icfg
         if not cfg.has_ssm:
@@ -590,6 +609,19 @@ class InferenceEngine:
         if topology is not None and topology.device_count > 1:
             raise NotImplementedError(
                 "serving over a mesh: " + why + "the mixer is not sharded")
+        if cfg.recurrent_kind == "kda":
+            # a delta-rule state is stored in float32 (the model's, not
+            # an option): stored in bfloat16 it is rounded once a token,
+            # a channel whose decay is 0.9999 keeps thousands of those
+            # roundings, and a sequence decoded for 4,000 tokens read
+            # 7.6e-2 of the largest logit off a float32 reference where
+            # every shorter comparison read 2.3e-2 (PERF.md, PR 44)
+            kd = cfg.kda_dims
+            return RecurrentConfig(
+                heads=kd.heads, head_dim=kd.key_dim, state=kd.value_dim,
+                conv=kd.conv, channels=kd.conv_channels, chunk=kd.chunk,
+                dtype=icfg.param_dtype, state_dtype=jnp.float32,
+                layers=cfg.layers_of("kda"))
         sd = cfg.ssm_dims
         return RecurrentConfig(
             heads=sd.heads, head_dim=sd.head_dim, state=sd.state,
@@ -801,9 +833,16 @@ class InferenceEngine:
             reg.gauge_fn(
                 "serving_state_bytes",
                 lambda: len(self.state.seqs)
-                * self._recurrent.bytes_per_seq(self.cfg.num_layers),
+                * self._recurrent.bytes_per_seq(
+                    self._recurrent.layers or self.cfg.num_layers),
                 "bytes of recurrent state and convolution tail the live "
-                "sequences hold, all layers")
+                "sequences hold, all the layers that hold one")
+        if self.state.cfg.latent_dim:
+            reg.gauge_fn(
+                "serving_latent_pool_bytes",
+                lambda: self.state.kv["kv"].nbytes,
+                "bytes of the latent pool: a row a token, the latent "
+                "layers only")
         # sparse experts (parallel/moe.py moe_serve): read from the rows
         # the step appends to its sampled tokens, at their readback;
         # a dense model has neither
@@ -815,7 +854,10 @@ class InferenceEngine:
                     "(token, expert) assignments the serving steps "
                     "computed, summed over the layers: every real "
                     "token's top-k, none dropped (decode bursts are not "
-                    "counted)"),
+                    "counted).  A model that holds a share of its experts "
+                    "(experts_held) labels them where: held = computed "
+                    "here | absent = made by the router for experts that "
+                    "are not here"),
                 reg.gauge(
                     "serving_moe_expert_load_max_over_mean",
                     "rows of the fullest expert over the mean rows an "
@@ -845,7 +887,8 @@ class InferenceEngine:
             "serving_attn_kv_tokens_total",
             "cached tokens one attention layer reads for the dispatched "
             "steps (kind: full = every token of the step's sequences | "
-            "window = those inside its queries' windows)", int_valued=True)
+            "window = those inside its queries' windows | latent = the "
+            "cached rows a latent layer reads)", int_valued=True)
         # the grid steps the kernel's short call (decode tokens, verify
         # windows) makes in one layer of each kind that hold a needed
         # block, and how full those groups of KV blocks ran: counted
@@ -1033,6 +1076,9 @@ class InferenceEngine:
                 window += min(ctx, w + len(toks) - 1)
             if 0 < len(toks) <= SHORT:
                 short.append((seen, len(toks)))
+        if self.state.cfg.latent_dim:
+            self._c_attn_kv.inc(full, kind="latent")
+            return {"latent_tokens": full}
         args = {"kv_tokens_full": full}
         self._c_attn_kv.inc(full, kind="full")
         if w:
@@ -3297,10 +3343,13 @@ class InferenceEngine:
         # XLA formulations do work proportional to that bound; the
         # Pallas kernel's grid follows the batch (its tiles, and the
         # blocks of the deepest one), so there one program, bounded by
-        # the engine's longest context, serves every step
+        # the engine's longest context, serves every step; so does the
+        # latent layers' loop over cached blocks (``ops/mla.py``
+        # ``latent_attend`` stops behind the last block a query reads),
+        # and a delta-rule layer reads no block
         pallas = self.attn_impl == "pallas"
         mbs = self.max_blocks_per_seq
-        if not pallas:
+        if not pallas and not self.cfg.mixer_stacks:
             bs_blk = self.icfg.kv_block_size
             need = 1
             for uid, toks in sched:
@@ -3459,7 +3508,7 @@ class InferenceEngine:
             self._inflight_sched[uid] = self._inflight_sched.get(uid, 0) + 1
         self._dispatch_seq += 1
         return _InFlight(toks=toks, emit=emit, sid=self._dispatch_seq,
-                         uids=uids,
+                         uids=uids, n_tokens=n_tokens,
                          drafts=tuple((u, tuple(d)) for u, d in
                                       self._sched_drafts.items()),
                          stop=sampling.stop_token,
@@ -3705,7 +3754,16 @@ class InferenceEngine:
                 MOE_STAT_ROWS, -1)[:, 0]
             moe = {"moe_assignments": int(n), "moe_load": load / 1e3,
                    "moe_experts_touched": int(touched)}
-            self._moe_metrics[0].inc(moe["moe_assignments"])
+            if self.cfg.experts_held is None:
+                self._moe_metrics[0].inc(moe["moe_assignments"])
+            else:
+                # the router made top_k a real row a layer; the step
+                # computed those that fell on the experts held here
+                made = st.n_tokens * self.cfg.moe_top_k * (
+                    self.cfg.num_layers - self.cfg.num_dense_layers)
+                moe["moe_assignments_made"] = made
+                self._moe_metrics[0].inc(int(n), where="held")
+                self._moe_metrics[0].inc(made - int(n), where="absent")
             self._moe_metrics[1].set(moe["moe_load"])
         t2 = tr.phase_end(**moe)
         self._c_guard_hop.inc(hop_us / 1e3)

@@ -30,7 +30,8 @@ from jax import shard_map
 from ..comm.overlap import (ServingComm, shard_matmul_allgather,
                             shard_matmul_allreduce)
 from ..models import layers as L
-from ..models.transformer import TransformerConfig, _norm, _qk_norm
+from ..models.transformer import (TransformerConfig, _norm, _qk_norm,
+                                  moe_share, stack_layer)
 from .ragged.state import RaggedBatch
 from .sampler import row_keys, window_keys
 
@@ -505,6 +506,159 @@ def _ssm_mixer(cfg, mp, u, rec_state, li, batch: RaggedBatch, runs, dt):
         return _mm(y, mp["w_out"], dt), (ssm, conv)
 
 
+def _chunk_rows(runs, T: int, Q: int):
+    """The flat rows of a step's chunks (``RecBatch.chunks``) → (rows
+    [NC, Q] clipped into the step, there [NC, Q], and the chunks'
+    columns: rows, slot, first of its run, last of its run)."""
+    ch = runs["chunks"]
+    start, n, slot, first, lastc = (ch[:, i] for i in range(5))
+    q = jnp.arange(Q)[None, :]
+    return (jnp.minimum(start[:, None] + q, T - 1), q < n[:, None], n,
+            slot, first.astype(bool), lastc.astype(bool))
+
+
+def _scatter_chunks(y_run, rows, there, T: int):
+    """The chunks' rows back in the step's flat order, zeros elsewhere."""
+    return jnp.zeros((T,) + y_run.shape[2:], y_run.dtype).at[
+        jnp.where(there, rows, T).reshape(-1)].set(
+        y_run.reshape((-1,) + y_run.shape[2:]), mode="drop")
+
+
+def _kda_mixer(cfg, mp, h, rec_state, li, batch: RaggedBatch, runs, dt):
+    """A "kda" layer's mixer over a step's flat rows (``ops/kda.py``).
+
+    h: [T, dm], the normed input.  ``rec_state``: ``(state [L, S+1, H,
+    K, V], conv [L, S+1, W, C])``, the engine's state rows of the layers
+    that hold one; ``li``: this layer's rank among them.  A one-token
+    run advances its slot's state by the dense update (``kda_update``);
+    a longer run goes through the chunked form (``kda_chunk``) from the
+    slot's state, or from zeros where it starts at position 0, and
+    leaves its last state in the slot; the convolution reaches into the
+    slot's tail (``kda_conv``).  → (y [T, dm], rec_state)."""
+    from ..ops import kda as K
+    from ..ops.ssm import conv_rows, conv_tails
+
+    dims = cfg.kda_dims
+    ssm, conv = rec_state
+    S, T = runs["S"], h.shape[0]
+    with jax.named_scope("kda_in"):
+        xc = _mm(h, mp["w_qkv"], dt)
+        a_raw, b_raw = _mm(h, mp["w_f"], dt), _mm(h, mp["w_b"], dt)
+        gate_raw = _mm(h, mp["w_g"], dt)
+    with jax.named_scope("kda_conv"):
+        tail = jax.lax.dynamic_index_in_dim(conv, li, keepdims=False)
+        q, k, v = K.split_qkv(conv_rows(
+            xc, tail[:S], batch.seq_slot, runs["row_first"],
+            runs["row_offset"], runs["row_fresh"], mp["conv_w"],
+            jnp.zeros((xc.shape[-1],), jnp.float32)).astype(dt), dims,
+            cfg.eps)
+        new_tail = conv_tails(
+            xc, tail[:S], runs["last"], runs["first"], runs["offset"],
+            runs["fresh"], runs["has_run"])
+        conv = jax.lax.dynamic_update_slice(
+            conv, new_tail[None], (li, 0, 0, 0))
+    with jax.named_scope("kda_gate"):
+        g, beta = K.gates(a_raw, b_raw, mp, dims)
+    with jax.named_scope("kda_update"):
+        at = runs["last"]
+        pool = jax.lax.dynamic_index_in_dim(ssm, li, keepdims=False)
+        o_one, new = K.state_update(
+            pool[:S], q[at], k[at], v[at], g[at], beta[at], runs["one"],
+            runs["replay"], runs["fresh"])
+        ssm = jax.lax.dynamic_update_slice(ssm, new[None], (li, 0, 0, 0, 0))
+    with jax.named_scope("kda_chunk"):
+        rows, there, n, slot, first, lastc = _chunk_rows(runs, T, dims.chunk)
+        NC = n.shape[0]
+        # a chunk's first state is cut out of the stack where it lies
+        # and its last written back there, a row at a time (``_ssm_mixer``)
+        row = (1, 1) + ssm.shape[2:]
+        init = jnp.concatenate([jax.lax.dynamic_slice(
+            ssm, (li, slot[i], 0, 0, 0), row)[0] for i in range(NC)])
+        fresh = runs["fresh"][jnp.minimum(slot, S - 1)]
+        o_run, left = jax.lax.cond(
+            jnp.any(n > 0),
+            lambda: K.chunk_rule(
+                q[rows], k[rows], v[rows],
+                jnp.where(there[..., None, None], g[rows], 0.0),
+                jnp.where(there[..., None], beta[rows], 0.0), first,
+                jnp.where(fresh[:, None, None, None], 0,
+                          init.astype(jnp.float32)), dims),
+            lambda: (jnp.zeros((NC, dims.chunk, dims.heads,
+                                dims.value_dim), jnp.float32),
+                     jnp.zeros(init.shape, jnp.float32)))
+        # a run's last chunk leaves its state in the slot; the others'
+        # (and the chunks that are not there) go to the trash row
+        to = jnp.where(lastc, slot, S)
+        left = left.astype(ssm.dtype)
+        for i in range(NC):
+            ssm = jax.lax.dynamic_update_slice(
+                ssm, left[i][None, None], (li, to[i], 0, 0, 0))
+        o = jnp.where(runs["row_one"][:, None, None], o_one[batch.seq_slot],
+                      _scatter_chunks(o_run, rows, there, T))
+    with jax.named_scope("kda_gate"):
+        o = K.gated_norm(o, gate_raw, mp["norm"], cfg.eps).astype(dt)
+    with jax.named_scope("kda_out"):
+        return _mm(o, mp["w_o"], dt), (ssm, conv)
+
+
+# cached blocks one pass of the latent attention's loop reads: the
+# one-token rows', a group a slot, and the chunks' of longer runs
+_LATENT_BLOCKS_ONE = 8
+_LATENT_BLOCKS_RUN = 4
+
+
+def _latent_attention(cfg, ap, h, pool, layer, batch: RaggedBatch, runs,
+                      cos, sin, dt, block_size: int, max_blocks_per_seq: int):
+    """An "mla" layer's attention over a step's flat rows
+    (``ops/mla.py``): every row's ``[c | k_r]`` written into the latent
+    pool by block table (``latent_write``; a replayed row writes its row
+    again), then all the query heads over the cached rows of the row's
+    sequence, ``W_kvb`` folded into the query and the output
+    (``latent_attn``): the one-token rows as one group a slot, the longer
+    runs by their chunks.  ``pool``: the latent pool ``[L * rows, bs,
+    row]``, which holds the layer where ``layer`` says (``_layer_of``).
+    → (o [T, dm], pool)."""
+    from ..ops import mla as A
+
+    dims = cfg.mla_dims
+    S, T = runs["S"], h.shape[0]
+    base, nrows = layer
+    with jax.named_scope("latent_in"):
+        q_n, q_r, row = A.project(ap, h, cos, sin, batch.positions, dims,
+                                  cfg.eps, lambda x, w: _mm(x, w, dt))
+        qf = A.fold_query(ap, q_n, q_r, dims)               # [T, H, row]
+        if cfg.mla_gate == "head":
+            gate = _mm(h, ap["wg"], dt)
+    with jax.named_scope("latent_write"):
+        blk = batch.block_tables[batch.seq_slot,
+                                 batch.positions // block_size]
+        blk = jnp.where(batch.token_valid, blk + base, base + nrows - 1)
+        pool = A.latent_write(pool, row, blk, batch.positions % block_size)
+    with jax.named_scope("latent_attn"):
+        tables = _layer_tables(batch.block_tables[:, :max_blocks_per_seq],
+                               layer)                           # [S, nb]
+        qpos = jnp.where(runs["one"], batch.context_lens - 1, -1)
+        o_one = A.latent_attend(pool, qf[runs["last"]][:, None],
+                                qpos[:, None], tables[:S], dims,
+                                _LATENT_BLOCKS_ONE)[:, 0]
+        rows, there, n, slot, _, _ = _chunk_rows(runs, T, cfg.kda_chunk)
+        o_run = jax.lax.cond(
+            jnp.any(n > 0),
+            lambda: A.latent_attend(
+                pool, qf[rows], jnp.where(there, batch.positions[rows], -1),
+                tables[jnp.minimum(slot, S - 1)], dims, _LATENT_BLOCKS_RUN),
+            lambda: jnp.zeros(rows.shape + (dims.heads, dims.kv_rank),
+                              jnp.float32))
+        o = jnp.where(runs["row_one"][:, None, None], o_one[batch.seq_slot],
+                      _scatter_chunks(o_run, rows, there, T))
+        o = A.unfold_output(ap, o, dims, dt)                # [T, H, V]
+    with jax.named_scope("latent_out"):
+        if cfg.mla_gate == "head":
+            o = o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(
+                dt)[..., None]
+        return _mm(o.reshape(T, -1), ap["wo"], dt, contract_dims=2), pool
+
+
 def _dense_weight(w) -> bool:
     """Whether ``w`` is a plain array (mixed-GEMM QuantizedTensor
     weights keep their VMEM-dequant kernel path and never route through
@@ -547,7 +701,7 @@ def _ffn(cfg, lp, h, dt, act, comm: Optional[ServingComm] = None,
             norm_topk=cfg.moe_norm_topk, layer=layer,
             kernel=jax.default_backend() == "tpu" and not sharded,
             score=cfg.moe_score, route_scale=cfg.moe_route_scale,
-            with_ids=routing)
+            with_ids=routing, **moe_share(cfg))
         stats = tuple(stats) if routing else stats[0]
         if "shared" in lp:       # the dense expert every token takes
             with jax.named_scope("moe_shared"):
@@ -647,7 +801,8 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
     if cfg.has_ssm:
         rec = (kv["ssm"], kv["conv"])
         kv = kv["kv"]
-        runs = _ssm_runs(batch, cfg.ssm_conv)
+        runs = _ssm_runs(batch, cfg.kda_conv if cfg.mixer_stacks
+                         else cfg.ssm_conv)
     if quant is not None:
         from .quantization import merge_layer
         from ..ops.quant import dequantize_any
@@ -700,13 +855,40 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
         experts = blocks["experts"]
         blocks = {k: v for k, v in blocks.items() if k != "experts"}
 
-    def block(x, lp, pool, layer, li, kind, rec=None):
+    def ffn(x, o, lp, li):
+        """A layer's second half: the residual, the MLP or the experts
+        → (x, stats)."""
+        with jax.named_scope("ffn"):
+            x = x + o
+            d, stats = _ffn(cfg, lp, norm(lp["ln2"], x), dt, act, comm=comm,
+                            valid=batch.token_valid,
+                            sharded=shard_mesh is not None,
+                            experts=None if experts is None
+                            else (experts, li), routing=with_routing)
+        return x + d, stats
+
+    def block(x, lp, pool, layer, li, kind, rec=None, rank=None):
         """One layer's mathematics.  ``pool`` is the stacked paged
         cache, which holds the layer where ``layer`` says
         (``_layer_of``).  ``li``: the layer's index in ``blocks``, for
         weights kept stacked.  ``kind``: its kind, static.  ``rec``: the
         stacked state rows of a model with recurrent layers; a hybrid
-        layer returns them updated, last."""
+        layer returns them updated, last.  ``rank``: a "kda" layer's
+        rank among the layers that hold a state (it holds no blocks; an
+        "mla" layer holds no state, and ``layer`` says where in the
+        latent pool its blocks lie)."""
+        if kind in ("kda", "mla"):
+            h = norm(lp["ln1"], x)
+            with jax.named_scope("attn"):
+                if kind == "kda":
+                    o, rec = _kda_mixer(cfg, lp["kda"], h, rec, rank, batch,
+                                        runs, dt)
+                else:
+                    o, pool = _latent_attention(
+                        cfg, lp["mla"], h, pool, layer, batch, runs, cos,
+                        sin, dt, block_size, max_blocks_per_seq)
+            x, stats = ffn(x, o, lp, li)
+            return x, pool, stats, rec
         ap = lp["attn"]
         window = cfg.attn_window if kind == "window" else None
         # named scopes at the block's seams (metadata only): a device
@@ -838,19 +1020,26 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
         a = a if not tail else a[:periods * P]
         return a if P == 1 else a.reshape((periods, P) + a.shape[1:])
 
-    layer_ids = periods_of(jnp.arange(cfg.num_layers - lead,
-                                      dtype=jnp.int32))
-    layers = ((layer_ids,) if stream is not None
-              else (jax.tree.map(periods_of, blocks), layer_ids))
-    pool = jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), kv)
+    def flat(kv):
+        return jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), kv)
+
     outside_stats = []
-    if lead:
-        x, pool, _ = outside(x, pool, params["dense_blocks"], 0, lead, 0)
-    (x, pool, *state), stats = jax.lax.scan(
-        carried, (x, pool) if rec is None else (x, pool, rec), layers)
-    if tail:
-        x, pool, outside_stats = outside(x, pool, blocks, periods * P,
-                                         tail, lead + periods * P)
+    if cfg.mixer_stacks:
+        x, pool, state, stats, outside_stats = _stacked_layers(
+            cfg, params, blocks, block, x, flat(kv), rec, rows)
+    else:
+        layer_ids = periods_of(jnp.arange(cfg.num_layers - lead,
+                                          dtype=jnp.int32))
+        layers = ((layer_ids,) if stream is not None
+                  else (jax.tree.map(periods_of, blocks), layer_ids))
+        pool = flat(kv)
+        if lead:
+            x, pool, _ = outside(x, pool, params["dense_blocks"], 0, lead, 0)
+        (x, pool, *state), stats = jax.lax.scan(
+            carried, (x, pool) if rec is None else (x, pool, rec), layers)
+        if tail:
+            x, pool, outside_stats = outside(x, pool, blocks, periods * P,
+                                             tail, lead + periods * P)
     new_kv = jax.tree.map(lambda a, o: a.reshape(o.shape), pool, kv)
     if rec is not None:
         new_kv = {"kv": new_kv, "ssm": state[0][0], "conv": state[0][1]}
@@ -874,6 +1063,72 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
         if with_routing:
             out += (ids,)
     return out
+
+
+def _stacked_layers(cfg, params, blocks, block, x, pool, rec, rows: int):
+    """The layers of a model whose mixers are stacked by kind
+    (``TransformerConfig.mixer_stacks``), as ``layer_plan`` says: the
+    leading dense layers, one scan over the whole periods, a last period
+    cut short.  A layer reads its kind's stack at its rank among the
+    layers of that kind, and its cache likewise: a "kda" layer its state
+    rows, an "mla" layer its blocks of the latent pool (``rows`` a
+    layer).  ``block``: ``ragged_forward``'s.  → (x, pool, [rec], the
+    scan's stats [periods, P, ...], the tail's)."""
+    stacks = cfg.mixer_stacks
+    pattern = cfg.layer_pattern
+    P = len(pattern)
+    lead, periods, tail = cfg.layer_plan
+    per_period = {k: pattern.count(k) for k in stacks}
+    in_pattern = [pattern[:j].count(kind) for j, kind in enumerate(pattern)]
+    before = {k: cfg.kind_rank(lead, k) for k in stacks}
+
+    def one(x, pool, rec, lp, li, kind, rank):
+        return block(x, lp, pool, (rank * rows, rows), li, kind, rec,
+                     rank=rank)
+
+    def outside(x, pool, rec, stack, first, n, layer0):
+        stats = []
+        for i in range(n):
+            layer = layer0 + i
+            x, pool, st, rec = one(
+                x, pool, rec, stack_layer(cfg, stack, layer,
+                                          layer0 - first), first + i,
+                cfg.layer_kinds[layer], cfg.kind_rank(layer))
+            stats.append(st)
+        return x, pool, rec, stats
+
+    def carried(carry, ws):
+        x, pool, rec = carry
+        period_w, period = ws
+        stats = []
+        for j, kind in enumerate(pattern):
+            lp = {name: jax.tree.map(
+                lambda a, at=in_pattern[j] if name in stacks else j: a[at],
+                sub) for name, sub in period_w.items()
+                if name not in stacks or name == kind}
+            x, pool, st, rec = one(
+                x, pool, rec, lp, period * P + j, kind,
+                before[kind] + period * per_period[kind] + in_pattern[j])
+            stats.append(st)
+        return (x, pool, rec), jax.tree.map(lambda *v: jnp.stack(v), *stats)
+
+    def periods_of(name, a):
+        n = per_period.get(name, P)
+        return a[:periods * n].reshape((periods, n) + a.shape[1:])
+
+    if lead:
+        x, pool, rec, _ = outside(x, pool, rec, params["dense_blocks"], 0,
+                                  lead, 0)
+    (x, pool, rec), stats = jax.lax.scan(
+        carried, (x, pool, rec),
+        ({name: jax.tree.map(lambda a, name=name: periods_of(name, a), sub)
+          for name, sub in blocks.items()},
+         jnp.arange(periods, dtype=jnp.int32)))
+    outside_stats = []
+    if tail:
+        x, pool, rec, outside_stats = outside(
+            x, pool, rec, blocks, periods * P, tail, lead + periods * P)
+    return x, pool, [rec], stats, outside_stats
 
 
 def _unembed(cfg, params, embed_tab, x, batch, norm, dt, comm):

@@ -122,15 +122,26 @@ class RecurrentConfig:
     # runs of more than one token a step may hold (the scheduler's
     # bound): with it a step's chunks are at most ceil(T / chunk) + this
     scan_runs: int = 4
+    # the layers that hold a state, where not every layer does (a layer
+    # holds ONE kind of cache: its state rows lie at its rank among the
+    # layers of its kind); None: all of ``KVCacheConfig.num_layers``
+    layers: Optional[int] = None
+
+    # the state's own stored type where it is not ``dtype`` (the
+    # tail's, which holds raw inputs as they were computed): a state
+    # that a decoding sequence advances thousands of times is rounded
+    # once a token in its stored type
+    state_dtype: object = None
 
     def n_chunks(self, token_budget: int) -> int:
         return -(-token_budget // self.chunk) + self.scan_runs
 
     def bytes_per_seq(self, num_layers: int) -> int:
-        """A sequence's state and tail over all layers."""
-        item = jnp.dtype(self.dtype).itemsize
-        return num_layers * item * (self.heads * self.head_dim * self.state
-                                    + self.conv * self.channels)
+        """A sequence's state and tail over ``num_layers`` layers."""
+        state = jnp.dtype(self.state_dtype or self.dtype).itemsize
+        return num_layers * (
+            state * self.heads * self.head_dim * self.state
+            + jnp.dtype(self.dtype).itemsize * self.conv * self.channels)
 
 
 @dataclasses.dataclass
@@ -148,12 +159,29 @@ class KVCacheConfig:
     quant: str = "none"
     # a model with recurrent layers: the state rows beside the blocks
     recurrent: Optional[RecurrentConfig] = None
+    # latent attention: a token leaves ONE row of this many values in a
+    # block (``[L, blocks + 1, block_size, latent_row]``) in place of
+    # the keys and values of ``num_kv_heads`` heads; ``num_layers`` then
+    # counts the latent layers
+    latent_dim: int = 0
 
     @property
     def max_context(self) -> int:
         return self.num_blocks * self.block_size
 
+    @property
+    def latent_row(self) -> int:
+        """The width of a latent pool's rows: ``latent_dim`` and zeros up
+        to whole vectors of 128 lanes.  The chip's tiles pad a row to
+        that anyway; of a pool whose rows are not whole tiles its
+        compiler keeps the block axis innermost instead and transposes
+        the whole pool into every step and out of it (two copies of
+        0.9 GB a step at 12288 blocks of 64 rows of 576)."""
+        return -(-self.latent_dim // 128) * 128
+
     def __post_init__(self):
+        if self.latent_dim and self.quant != "none":
+            raise ValueError("kv_quant: a latent pool is not quantized")
         if self.quant not in ("none", "int8", "fp8"):
             raise ValueError(
                 f"kv_quant={self.quant!r}: the paged cache supports "
@@ -172,6 +200,8 @@ class KVCacheConfig:
         treat it like the array everywhere the engine is agnostic)."""
         shape = (self.num_layers, self.num_blocks + 1, self.block_size, 2,
                  self.num_kv_heads, self.head_dim)
+        if self.latent_dim:
+            shape = shape[:3] + (self.latent_row,)
         if self.quant == "none":
             return jnp.zeros(shape, self.dtype)
         return (jnp.zeros(shape, self.store_dtype),
@@ -185,10 +215,10 @@ class KVCacheConfig:
         rc = self.recurrent
         if rc is None:
             return kv
-        rows = (self.num_layers, max_seqs + 1)
+        rows = (rc.layers or self.num_layers, max_seqs + 1)
         return {"kv": kv,
                 "ssm": jnp.zeros(rows + (rc.heads, rc.head_dim, rc.state),
-                                 rc.dtype),
+                                 rc.state_dtype or rc.dtype),
                 "conv": jnp.zeros(rows + (rc.conv, rc.channels), rc.dtype)}
 
 

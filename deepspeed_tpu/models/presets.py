@@ -335,6 +335,55 @@ PRESETS: Dict[str, dict] = {
                                           0.1767766952966369, 0.5,
                                           0.3535533905932738),
                           attention_impl="xla"),
+    # --- Ling-3.0-flash (inclusionAI/Ling-3.0-flash config.json,
+    # model_type bailing_hybrid): delta-rule linear attention with a
+    # per-channel decay (KDA) in five layers of six and latent attention
+    # (MLA, no query latent, a head-wise output gate) in the sixth; two
+    # leading dense layers, then 512 sigmoid-routed experts in 8 groups
+    # of which 4 stay, 8 a token, a selection bias, one shared expert.
+    # Layer l is MLA where (l + 1) % 6 == 0: behind the two dense layers
+    # the period reads kda, kda, kda, mla, kda, kda.  The multi-token-
+    # prediction module is not part of the served forward; the SwiGLU
+    # clamp of the last eight layers has no form in the config and none
+    # here ---------------------------------------------------------------
+    "ling-tiny": dict(vocab_size=1024, num_layers=7, d_model=64,
+                      num_heads=4, head_dim=32, d_ff=160, max_seq_len=512,
+                      activation="silu", gated_mlp=True, norm="rmsnorm",
+                      position="rope", rope_theta=6e6, rope_pct=0.25,
+                      tie_embeddings=False, attn_bias=False, mlp_bias=False,
+                      eps=1e-6, layer_pattern=("kda", "kda", "mla"),
+                      num_dense_layers=1,
+                      kda_heads=4, kda_key_dim=16, kda_value_dim=16,
+                      kda_conv=4, kda_chunk=64, kda_gate_bound=-5.0,
+                      mla_kv_rank=16, mla_nope_dim=16, mla_rope_dim=8,
+                      mla_value_dim=16, mla_gate="head",
+                      num_experts=16, moe_top_k=4, moe_d_ff=48,
+                      moe_shared_ff=48, moe_shared_gate=False,
+                      moe_score="sigmoid", moe_select_bias=True,
+                      moe_norm_topk=True, moe_route_scale=2.5,
+                      moe_groups=4, moe_groups_kept=2,
+                      moe_dispatch="ragged", attention_impl="xla"),
+    "ling-3.0-flash": dict(vocab_size=157184, num_layers=42, d_model=2560,
+                           num_heads=32, head_dim=128, d_ff=6144,
+                           max_seq_len=262144,
+                           activation="silu", gated_mlp=True, norm="rmsnorm",
+                           position="rope", rope_theta=6e6, rope_pct=0.5,
+                           tie_embeddings=False, attn_bias=False,
+                           mlp_bias=False, eps=1e-6,
+                           layer_pattern=("kda", "kda", "kda", "mla",
+                                          "kda", "kda"),
+                           num_dense_layers=2,
+                           kda_heads=32, kda_key_dim=128, kda_value_dim=128,
+                           kda_conv=4, kda_chunk=64, kda_gate_bound=-5.0,
+                           mla_kv_rank=512, mla_nope_dim=128,
+                           mla_rope_dim=64, mla_value_dim=128,
+                           mla_gate="head",
+                           num_experts=512, moe_top_k=8, moe_d_ff=768,
+                           moe_shared_ff=768, moe_shared_gate=False,
+                           moe_score="sigmoid", moe_select_bias=True,
+                           moe_norm_topk=True, moe_route_scale=2.5,
+                           moe_groups=8, moe_groups_kept=4,
+                           moe_dispatch="ragged", attention_impl="xla"),
     # --- Megatron-GPT (gpt2 architecture, megatron-lm checkpoint naming
     # with per-head-interleaved fused QKV — reference:
     # module_inject/containers/megatron_gpt.py) ---------------------------

@@ -64,9 +64,14 @@ class TransformerConfig:
     # key up to the query) | "window" (the last ``attn_window`` keys, the
     # query's own among them) | "hybrid" (falcon-h1: full attention AND
     # a Mamba-2 mixer, both reading the same normed input, summed into
-    # the residual).  The layers behind the leading dense ones repeat
-    # it; a last period may be cut short.  The leading dense layers are
-    # of the period's first kind
+    # the residual) | "kda" (delta-rule linear attention with a
+    # per-channel decay, ops/kda.py, in place of attention) | "mla"
+    # (latent attention, ops/mla.py: one cached vector a token).  The
+    # layers behind the leading dense ones repeat it; a last period may
+    # be cut short.  The leading dense layers are of the period's first
+    # kind.  A model with "kda" or "mla" layers stacks each kind's mixer
+    # weights apart (``mixer_stacks``), a layer reading its kind's stack
+    # at its rank among the layers of that kind
     layer_pattern: Tuple[str, ...] = ("full",)
     attn_window: Optional[int] = None
     # sparse-expert models: this many FIRST layers keep a dense MLP of
@@ -78,6 +83,21 @@ class TransformerConfig:
     # sigmoid(h Wg) [H * D] multiplied into the attention output before
     # the output projection
     attn_gate: bool = False
+    # an "mla" layer's output gate: "head" (sigmoid(h Wy) [H], one
+    # number a head, where ``attn_gate``'s is one an element) | None
+    mla_gate: Optional[str] = None
+    # --- a "kda" layer's mixer (ops/kda.py) -------------------------------
+    kda_heads: int = 0
+    kda_key_dim: int = 0                      # a head's state is [K, V]
+    kda_value_dim: int = 0
+    kda_conv: int = 4                         # the causal convolution's width
+    kda_chunk: int = 64                       # the chunked form's chunk
+    kda_gate_bound: float = -5.0              # log decay in (bound, 0)
+    # --- an "mla" layer's sizes (ops/mla.py) ------------------------------
+    mla_kv_rank: int = 0                      # the cached latent's size
+    mla_nope_dim: int = 0                     # a head's unrotated q/k part
+    mla_rope_dim: int = 0                     # the rotated part; ONE shared k
+    mla_value_dim: int = 0
     # four norms a layer: x + N(attn(N(x))), then x + N(ffn(N(x)))
     sandwich_norm: bool = False
     # the embedding's output is multiplied by this (trinity: sqrt(d_model);
@@ -144,6 +164,17 @@ class TransformerConfig:
     moe_select_bias: bool = False
     # the chosen weights are multiplied by this after renormalisation
     moe_route_scale: float = 1.0
+    # the experts in order form this many groups; a token chooses among
+    # the experts of the ``moe_groups_kept`` groups whose two best
+    # (biased) scores sum highest
+    moe_groups: int = 1
+    moe_groups_kept: int = 1
+    # ``(first, count)``: the experts whose weights THIS model instance
+    # holds, a chip's share of an expert layer spread over several.  The
+    # router keeps its ``num_experts`` outputs and its ``moe_top_k`` a
+    # token; an assignment to an expert that is not held adds nothing
+    # here.  None: all of them
+    experts_held: Optional[Tuple[int, int]] = None
     # renormalize kept top-k gate weights to sum 1 (mixtral yes;
     # qwen2-moe norm_topk_prob=False keeps raw softmax probabilities)
     moe_norm_topk: bool = True
@@ -174,14 +205,35 @@ class TransformerConfig:
             self.head_dim = self.d_model // self.num_heads
         assert self.num_heads % self.num_kv_heads == 0
         self.layer_pattern = tuple(self.layer_pattern)
-        assert set(self.layer_pattern) <= {"full", "window", "hybrid"}
+        kinds = set(self.layer_pattern)
+        assert kinds <= {"full", "window", "hybrid", "kda", "mla"}
         self.ssm_col_scales = tuple(self.ssm_col_scales)
-        if self.has_ssm:
+        if "hybrid" in kinds:
             assert self.layer_pattern == ("hybrid",) \
                 and self.num_experts == 1 and not self.parallel_block
             assert self.ssm_d == self.ssm_heads * self.ssm_head_dim > 0
             assert self.ssm_heads % self.ssm_groups == 0 and self.ssm_state
             assert len(self.ssm_col_scales) == 5
+        if self.mixer_stacks:
+            assert kinds <= {"kda", "mla"} and not self.parallel_block \
+                and not self.sandwich_norm and self.position == "rope"
+            if "kda" in kinds:
+                assert self.kda_heads and self.kda_key_dim \
+                    and self.kda_value_dim and self.kda_chunk % 16 == 0
+            if "mla" in kinds:
+                # a latent layer reads a step's runs as the state's
+                # bookkeeping cuts them (RecBatch)
+                assert "kda" in kinds
+                assert self.mla_kv_rank and self.mla_value_dim \
+                    and self.mla_rope_dim == self.rotary_dim \
+                    and self.mla_gate in (None, "head")
+        if self.experts_held is not None:
+            self.experts_held = tuple(self.experts_held)
+            first, count = self.experts_held
+            assert 0 <= first and count > 0 \
+                and first + count <= self.num_experts
+        assert self.num_experts % self.moe_groups == 0 \
+            and 1 <= self.moe_groups_kept <= self.moe_groups
         assert "window" not in self.layer_pattern or self.attn_window
         assert self.qk_norm_form in ("projection", "head")
         assert self.moe_score in ("softmax", "sigmoid")
@@ -210,9 +262,53 @@ class TransformerConfig:
 
     @property
     def has_ssm(self) -> bool:
-        """The model's layers hold a recurrent mixer: a served sequence
+        """The model's layers hold a recurrent kind: a served sequence
         owns a state row beside its block table."""
-        return "hybrid" in self.layer_pattern
+        return self.recurrent_kind is not None
+
+    @property
+    def recurrent_kind(self) -> Optional[str]:
+        """The layer kind that keeps a recurrent state, or None."""
+        return next((k for k in ("hybrid", "kda")
+                     if k in self.layer_pattern), None)
+
+    @property
+    def mixer_stacks(self) -> Tuple[str, ...]:
+        """The layer kinds whose mixer weights are stacked apart, by
+        kind (``params["blocks"][kind]``); () for a model whose layers
+        all hold one ``"attn"`` stack."""
+        return tuple(k for k in ("kda", "mla") if k in self.layer_pattern)
+
+    def kind_rank(self, layer: int, of: Optional[str] = None,
+                  first: int = 0) -> int:
+        """How many layers of kind ``of`` (default: layer ``layer``'s
+        own) lie in ``[first, layer)``: a layer's rank among its kind,
+        in the model (``first=0``) or in its stack."""
+        kinds = self.layer_kinds
+        of = of or kinds[layer]
+        return sum(1 for k in kinds[first:layer] if k == of)
+
+    def layers_of(self, kind: str) -> int:
+        return sum(1 for k in self.layer_kinds if k == kind)
+
+    @property
+    def kda_dims(self):
+        from ..ops.kda import KDADims
+        return KDADims(self.kda_heads, self.kda_key_dim, self.kda_value_dim,
+                       self.kda_conv, self.kda_chunk, 16,
+                       self.kda_gate_bound)
+
+    @property
+    def mla_dims(self):
+        from ..ops.mla import MLADims
+        return MLADims(self.num_heads, self.mla_kv_rank, self.mla_nope_dim,
+                       self.mla_rope_dim, self.mla_value_dim)
+
+    @property
+    def experts_here(self) -> int:
+        """Experts whose weights the model holds."""
+        return self.experts_held[1] if self.experts_held \
+            else self.num_experts
 
     @property
     def ssm_dims(self):
@@ -228,7 +324,8 @@ class TransformerConfig:
         quantization and the pipeline stages were written for."""
         return (self.layer_pattern == ("full",) and not self.num_dense_layers
                 and not self.attn_gate and not self.sandwich_norm
-                and self.embed_scale is None)
+                and self.embed_scale is None and self.moe_groups == 1
+                and self.experts_held is None)
 
     def rope_on(self, kind: str) -> bool:
         """Whether a layer of attention kind ``kind`` rotates q and k."""
@@ -399,6 +496,80 @@ def init_params(cfg: TransformerConfig, key) -> Tuple[Dict, Dict]:
              "D": (None,), "norm": (None,), "w_out": (None, "embed")}
         return p, a
 
+    def kda_init(k):
+        """A "kda" layer's mixer.  Seeded so that the per-token decays
+        ``exp(g)`` spread over about 0.9 to 0.9999 across channels (a
+        state lives tens to thousands of tokens) and move with the
+        token, and ``beta`` over about 0.1 to 0.9; the norm's scale away
+        from one, for the reason ``q_norm`` is."""
+        kd = cfg.kda_dims
+        H, K, V = kd.heads, kd.key_dim, kd.value_dim
+        ks = jax.random.split(k, 9)
+        a = jax.random.uniform(ks[4], (H,), minval=0.5, maxval=2.0)
+        p = {"w_qkv": jax.random.normal(ks[0], (dm, kd.conv_channels))
+             / math.sqrt(dm),
+             "conv_w": jax.random.uniform(ks[1], (kd.conv_channels, kd.conv),
+                                          minval=-0.5, maxval=0.5),
+             "w_f": jax.random.normal(ks[2], (dm, H * K)) / math.sqrt(dm),
+             "w_b": jax.random.normal(ks[3], (dm, H)) * 2.0 / math.sqrt(dm),
+             "A_log": jnp.log(a),
+             # exp(A_log) (a + dt_bias) in about (-10.8, -3.8): the
+             # bounded gate's sigmoid in 2e-5 .. 0.021
+             "dt_bias": (jax.random.uniform(ks[5], (H, K), minval=-10.8,
+                                            maxval=-3.8)
+                         / a[:, None]).reshape(H * K),
+             "w_g": jax.random.normal(ks[6], (dm, H * V)) / math.sqrt(dm),
+             "norm": jax.random.uniform(ks[7], (V,), minval=0.5, maxval=1.5),
+             "w_o": jax.random.normal(ks[8], (H * V, dm)) * out_scale}
+        ax = {"w_qkv": ("embed", None), "conv_w": (None, None),
+              "w_f": ("embed", None), "w_b": ("embed", None),
+              "A_log": (None,), "dt_bias": (None,), "w_g": ("embed", None),
+              "norm": (None,), "w_o": (None, "embed")}
+        return p, ax
+
+    def mla_init(k):
+        """An "mla" layer's attention: no query latent, one key-value
+        latent with its norm (scale seeded away from one), one shared
+        rotated key, a gate by head."""
+        md = cfg.mla_dims
+        ks = jax.random.split(k, 6)
+        r = md.kv_rank
+        p = {"wq": jax.random.normal(ks[0], (dm, H, md.nope_dim
+                                             + md.rope_dim)) / math.sqrt(dm),
+             "w_kva": jax.random.normal(ks[1], (dm, md.row)) / math.sqrt(dm),
+             "c_norm": jax.random.uniform(ks[2], (r,), minval=0.5,
+                                          maxval=1.5),
+             "w_kvb": jax.random.normal(ks[3], (r, H, md.nope_dim
+                                                + md.value_dim))
+             / math.sqrt(r),
+             "wo": jax.random.normal(ks[4], (H, md.value_dim, dm))
+             * out_scale}
+        ax = {"wq": ("embed", "heads", "head_dim"), "w_kva": ("embed", None),
+              "c_norm": (None,), "w_kvb": (None, "heads", "head_dim"),
+              "wo": ("heads", "head_dim", "embed")}
+        if cfg.mla_gate == "head":
+            p["wg"] = jax.random.normal(ks[5], (dm, H)) / math.sqrt(dm)
+            ax["wg"] = ("embed", "heads")
+        return p, ax
+
+    mixer_inits = {"kda": kda_init, "mla": mla_init}
+
+    def mixers_init(key, first, n):
+        """The mixers of layers ``[first, first + n)``, a stack a kind
+        under the kind's name; one ``"attn"`` stack for a model whose
+        layers all hold attention."""
+        if not cfg.mixer_stacks:
+            return {"attn": stack_init(qkv_init, key, n)}
+        out = {}
+        for j, kind in enumerate(cfg.mixer_stacks):
+            m = cfg.kind_rank(first + n, kind, first)
+            if m:
+                out[kind] = stack_init(
+                    mixer_inits[kind],
+                    jax.random.fold_in(key, 11 + j),  # tpulint: disable=rng-discipline
+                    m)
+        return out
+
     norm_init = L.layernorm_init if cfg.norm == "layernorm" else L.rmsnorm_init
 
     def norms_init(n):
@@ -416,8 +587,9 @@ def init_params(cfg: TransformerConfig, key) -> Tuple[Dict, Dict]:
 
     blk_p: Dict[str, Any] = {}
     blk_a: Dict[str, Any] = {}
-    blk_p["attn"], blk_a["attn"] = stack_init(qkv_init, keys[2])
-    if cfg.has_ssm:
+    for name, (mp_, ma_) in mixers_init(keys[2], lead, nl - lead).items():
+        blk_p[name], blk_a[name] = mp_, ma_
+    if "hybrid" in cfg.layer_pattern:
         blk_p["ssm"], blk_a["ssm"] = stack_init(
             ssm_init, jax.random.fold_in(keys[2], 3))  # tpulint: disable=rng-discipline
 
@@ -442,7 +614,7 @@ def init_params(cfg: TransformerConfig, key) -> Tuple[Dict, Dict]:
 
         blk_p["gate"], blk_a["gate"] = stack_init(gate_init, keys[7])
         blk_p["experts"], blk_a["experts"] = stack_init(
-            lambda k: M.experts_init(k, cfg.num_experts, dm, cfg.moe_d_ff,
+            lambda k: M.experts_init(k, cfg.experts_here, dm, cfg.moe_d_ff,
                                      gated=cfg.gated_mlp,
                                      out_scale=out_scale), keys[3])
         if cfg.moe_shared_ff:        # a dense expert every token takes
@@ -484,7 +656,8 @@ def init_params(cfg: TransformerConfig, key) -> Tuple[Dict, Dict]:
 
         dp: Dict[str, Any] = {}
         da: Dict[str, Any] = {}
-        dp["attn"], da["attn"] = stack_init(qkv_init, lead_key(2), lead)
+        for name, (mp_, ma_) in mixers_init(lead_key(2), 0, lead).items():
+            dp[name], da[name] = mp_, ma_
         dp["mlp"], da["mlp"] = stack_init(mlp_init, lead_key(3), lead)
         for tree, part in zip((dp, da), norms_init(lead)):
             tree.update(part)
@@ -550,6 +723,34 @@ def _shared_expert(sp, h, act, gated: bool):
     return d * g.astype(dt)
 
 
+def moe_share(cfg) -> Dict[str, Any]:
+    """The router's group limit and the share of the experts held, as
+    the expert layers take them; {} for a model with neither (its calls
+    are what they were)."""
+    out = {}
+    if cfg.moe_groups > 1:
+        out["groups"] = (cfg.moe_groups, cfg.moe_groups_kept)
+    if cfg.experts_held is not None:
+        out["held"] = cfg.experts_held
+    return out
+
+
+def _mixer_apply(cfg, lp, h, kind: str, cos, sin):
+    """A "kda" or "mla" layer's mixer over whole sequences from a zero
+    state.  h: [B, S, dm], the normed input → [B, S, dm]."""
+    dt = h.dtype
+    if kind == "kda":
+        from ..ops.kda import mixer_forward
+        return mixer_forward(lp["kda"], h, cfg.kda_dims, cfg.eps)
+    from ..ops.mla import attention_forward
+    ap = lp["mla"]
+    o = attention_forward(ap, h, cos, sin, cfg.mla_dims, cfg.eps)
+    if cfg.mla_gate == "head":
+        g = jnp.tensordot(h, ap["wg"].astype(dt), 1).astype(jnp.float32)
+        o = o * jax.nn.sigmoid(g).astype(dt)[..., None]
+    return jnp.einsum("bshk,hkd->bsd", o, ap["wo"].astype(dt))
+
+
 def _qk_norm(cfg, scale, x):
     """The config's QK-norm of q or k ``[..., heads, head_dim]``."""
     if cfg.qk_norm_form == "head":
@@ -569,7 +770,7 @@ def block_apply(cfg: TransformerConfig, lp, x, cos, sin,
     static.  Returns (x, metrics) — metrics non-empty for MoE."""
     norm = _norm(cfg)
     act = L.ACTIVATIONS[cfg.activation]
-    ap = lp["attn"]
+    ap = lp.get("attn")
     if cfg.attn_scale is not None and attention_fn is L.causal_attention:
         # safety net for call sites that never resolved attention_fn
         # (pipeline stage bodies, streamed sweeps): gpt-neo's unscaled
@@ -577,55 +778,59 @@ def block_apply(cfg: TransformerConfig, lp, x, cos, sin,
         attention_fn = partial(L.causal_attention, scale=cfg.attn_scale)
 
     dt = x.dtype
-    # named scopes at the block's seams, the serving forward's names
-    # (metadata only): autodiff and jax.checkpoint add the pass to an
-    # operation's JAX path, so a device trace tells a layer's forward
-    # from its recomputation and its backward
-    with jax.named_scope("qkv"):
-        h = norm(lp["ln1"], x)
-        hn = h          # a hybrid layer's mixer reads the same normed input
-        if cfg.attn_in_scale != 1.0:
-            h = h * jnp.asarray(cfg.attn_in_scale, dt)
-        q = jnp.einsum("bsd,dhk->bshk", h, ap["wq"].astype(dt))
-        k = jnp.einsum("bsd,dhk->bshk", h, ap["wk"].astype(dt))
-        v = jnp.einsum("bsd,dhk->bshk", h, ap["wv"].astype(dt))
-        if cfg.key_scale != 1.0:
-            k = k * jnp.asarray(cfg.key_scale, dt)
-        if cfg.attn_bias:
-            q = q + ap["bq"].astype(dt)
-            k = k + ap["bk"].astype(dt)
-            v = v + ap["bv"].astype(dt)
-        if cfg.qk_norm:
-            q = _qk_norm(cfg, ap["q_norm"], q)
-            k = _qk_norm(cfg, ap["k_norm"], k)
-        if cfg.rope_on(kind):
-            q = L.apply_rope(q, cos, sin, positions=positions)
-            k = L.apply_rope(k, cos, sin, positions=positions)
-        if cfg.attn_gate:
-            g = jnp.einsum("bsd,dhk->bshk", h, ap["wg"].astype(dt))
-    with jax.named_scope("attn"):
-        if kind == "window":
-            # only the eager attention takes a window (_resolve_attention)
-            o = attention_fn(q, k, v, mask=mask, window=cfg.attn_window)
-        else:
-            o = attention_fn(q, k, v, mask=mask)
-        if cfg.attn_gate:
-            o = o * jax.nn.sigmoid(g.astype(jnp.float32)).astype(dt)
-    with jax.named_scope("attn_out"):
-        o = jnp.einsum("bshk,hkd->bsd", o, ap["wo"].astype(dt))
-        if cfg.attn_out_bias:
-            o = o + ap["bo"].astype(dt)
-        if cfg.sandwich_norm:
-            o = norm(lp["ln1_post"], o)
-        if cfg.attn_out_scale != 1.0:
-            o = o * jnp.asarray(cfg.attn_out_scale, dt)
-    if kind == "hybrid":
-        from ..ops.ssm import mixer_forward
-        with jax.named_scope("ssm"):
-            m = mixer_forward(lp["ssm"],
-                              hn * jnp.asarray(cfg.ssm_in_scale, dt),
-                              cfg.ssm_dims, cfg.ssm_col_scales, cfg.eps)
-            o = o + m * jnp.asarray(cfg.ssm_out_scale, dt)
+    if kind in ("kda", "mla"):
+        with jax.named_scope("attn"):
+            o = _mixer_apply(cfg, lp, norm(lp["ln1"], x), kind, cos, sin)
+    else:
+        # named scopes at the block's seams, the serving forward's names
+        # (metadata only): autodiff and jax.checkpoint add the pass to an
+        # operation's JAX path, so a device trace tells a layer's forward
+        # from its recomputation and its backward
+        with jax.named_scope("qkv"):
+            h = norm(lp["ln1"], x)
+            hn = h          # a hybrid layer's mixer reads the same normed input
+            if cfg.attn_in_scale != 1.0:
+                h = h * jnp.asarray(cfg.attn_in_scale, dt)
+            q = jnp.einsum("bsd,dhk->bshk", h, ap["wq"].astype(dt))
+            k = jnp.einsum("bsd,dhk->bshk", h, ap["wk"].astype(dt))
+            v = jnp.einsum("bsd,dhk->bshk", h, ap["wv"].astype(dt))
+            if cfg.key_scale != 1.0:
+                k = k * jnp.asarray(cfg.key_scale, dt)
+            if cfg.attn_bias:
+                q = q + ap["bq"].astype(dt)
+                k = k + ap["bk"].astype(dt)
+                v = v + ap["bv"].astype(dt)
+            if cfg.qk_norm:
+                q = _qk_norm(cfg, ap["q_norm"], q)
+                k = _qk_norm(cfg, ap["k_norm"], k)
+            if cfg.rope_on(kind):
+                q = L.apply_rope(q, cos, sin, positions=positions)
+                k = L.apply_rope(k, cos, sin, positions=positions)
+            if cfg.attn_gate:
+                g = jnp.einsum("bsd,dhk->bshk", h, ap["wg"].astype(dt))
+        with jax.named_scope("attn"):
+            if kind == "window":
+                # only the eager attention takes a window (_resolve_attention)
+                o = attention_fn(q, k, v, mask=mask, window=cfg.attn_window)
+            else:
+                o = attention_fn(q, k, v, mask=mask)
+            if cfg.attn_gate:
+                o = o * jax.nn.sigmoid(g.astype(jnp.float32)).astype(dt)
+        with jax.named_scope("attn_out"):
+            o = jnp.einsum("bshk,hkd->bsd", o, ap["wo"].astype(dt))
+            if cfg.attn_out_bias:
+                o = o + ap["bo"].astype(dt)
+            if cfg.sandwich_norm:
+                o = norm(lp["ln1_post"], o)
+            if cfg.attn_out_scale != 1.0:
+                o = o * jnp.asarray(cfg.attn_out_scale, dt)
+        if kind == "hybrid":
+            from ..ops.ssm import mixer_forward
+            with jax.named_scope("ssm"):
+                m = mixer_forward(lp["ssm"],
+                                  hn * jnp.asarray(cfg.ssm_in_scale, dt),
+                                  cfg.ssm_dims, cfg.ssm_col_scales, cfg.eps)
+                o = o + m * jnp.asarray(cfg.ssm_out_scale, dt)
 
     with jax.named_scope("ffn"):
         if not cfg.parallel_block:
@@ -646,7 +851,7 @@ def block_apply(cfg: TransformerConfig, lp, x, cos, sin,
                 gated=cfg.gated_mlp, rng=rng, noise_policy=cfg.noise_policy,
                 dispatch_mode=cfg.moe_dispatch,
                 norm_topk=cfg.moe_norm_topk, score=cfg.moe_score,
-                route_scale=cfg.moe_route_scale)
+                route_scale=cfg.moe_route_scale, **moe_share(cfg))
             if "shared" in lp:       # the dense expert every token takes
                 d = d + _shared_expert(lp["shared"], h, act, cfg.gated_mlp)
         else:
@@ -671,6 +876,24 @@ def block_apply(cfg: TransformerConfig, lp, x, cos, sin,
         if cfg.parallel_block:
             return x + o + d, metrics
         return x + d, metrics
+
+
+def stack_layer(cfg: TransformerConfig, stack, layer: int, first: int):
+    """Model layer ``layer``'s weights out of ``stack`` (``blocks`` or
+    ``dense_blocks``), whose first layer is model layer ``first``: row
+    ``layer - first`` of every leaf, but of a mixer stacked by kind
+    (``mixer_stacks``) the row of the layer's rank among its kind."""
+    kind = cfg.layer_kinds[layer]
+    out = {}
+    for name, sub in stack.items():
+        if name in cfg.mixer_stacks:
+            if name != kind:
+                continue
+            at = cfg.kind_rank(layer, kind, first)
+        else:
+            at = layer - first
+        out[name] = jax.tree.map(lambda a, at=at: a[at], sub)
+    return out
 
 
 # The layer scan runs unrolled over its whole trip count where its trips
@@ -831,24 +1054,39 @@ def apply(cfg: TransformerConfig, params, input_ids, mask=None,
         return x
 
     x = keep(x)
-    if lead:
-        x = outside(x, params["dense_blocks"], 0, lead, 0,
-                    name="dense_blocks", dense=True)
-    # the scan's own work (a layer's weights cut out of the stack, the
-    # saved activations and the weight gradients stacked and cut again)
-    # lies outside the body: it takes this scope, the layers theirs.
-    # Unrolled it stays ONE scan: the body is traced once and the
-    # transposed scan stacks a leaf's gradient with one concatenate (a
-    # Python loop over a[i] pads every layer's to the whole stack and sums)
-    with jax.named_scope("layer_scan"):
-        x, metrics = jax.lax.scan(
-            remat(body), x,
-            (jax.tree.map(lambda a: periods_of(a, 0), params["blocks"]),
-             periods_of(layer_rngs, lead), periods_of(layer_ids, lead)),
-            unroll=periods if layers_unrolled(cfg) else 1)
-    if tail:
-        x = outside(x, params["blocks"], periods * P, tail,
-                    lead + periods * P)
+    metrics = {}
+    if cfg.mixer_stacks:
+        # the mixers are stacked by kind, so no scan cuts a period out
+        # of one stack: the layers run one by one (the forward that
+        # tests and comparisons read; such a model is not trained here)
+        for i, kind in enumerate(cfg.layer_kinds):
+            name, first = ("dense_blocks", 0) if i < lead else ("blocks",
+                                                               lead)
+            x, m = remat(partial(layer, name=name, kind=kind,
+                                 dense=i < lead))(
+                x, stack_layer(cfg, params[name], i, first),
+                layer_rngs[i], layer_ids[i])
+            outside_ms.append(m)
+    else:
+        if lead:
+            x = outside(x, params["dense_blocks"], 0, lead, 0,
+                        name="dense_blocks", dense=True)
+        # the scan's own work (a layer's weights cut out of the stack,
+        # the saved activations and the weight gradients stacked and cut
+        # again) lies outside the body: it takes this scope, the layers
+        # theirs.  Unrolled it stays ONE scan: the body is traced once
+        # and the transposed scan stacks a leaf's gradient with one
+        # concatenate (a Python loop over a[i] pads every layer's to the
+        # whole stack and sums)
+        with jax.named_scope("layer_scan"):
+            x, metrics = jax.lax.scan(
+                remat(body), x,
+                (jax.tree.map(lambda a: periods_of(a, 0), params["blocks"]),
+                 periods_of(layer_rngs, lead), periods_of(layer_ids, lead)),
+                unroll=periods if layers_unrolled(cfg) else 1)
+        if tail:
+            x = outside(x, params["blocks"], periods * P, tail,
+                        lead + periods * P)
     if idx is not None:
         # dropped positions bypass the stack with their embedding
         x = random_ltd_scatter(full_x, x, idx)
@@ -867,7 +1105,10 @@ def apply(cfg: TransformerConfig, params, input_ids, mask=None,
     if with_aux:
         aux = {k: v.mean() for k, v in metrics.items()} if metrics else {}
         tail_ms = [m for m in outside_ms if m]
-        if tail_ms:     # expert layers outside the scan count as its do
+        if tail_ms and not metrics:       # no layer ran inside a scan
+            aux = {k: sum(m[k] for m in tail_ms) / len(tail_ms)
+                   for k in tail_ms[0]}
+        elif tail_ms:   # expert layers outside the scan count as its do
             aux = {k: (v.sum() + sum(m[k] for m in tail_ms))
                    / (v.size + len(tail_ms)) for k, v in metrics.items()}
         return logits, aux

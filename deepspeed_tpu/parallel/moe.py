@@ -212,7 +212,8 @@ def gate_init(key, d_model: int, num_experts: int):
 
 
 def route(logits, bias=None, *, top_k: int, score: str = "softmax",
-          norm_topk: bool = True, route_scale: float = 1.0):
+          norm_topk: bool = True, route_scale: float = 1.0,
+          groups: Optional[Tuple[int, int]] = None):
     """The router's choice without capacity: float32 ``logits [T, E]`` →
     ``(weights [T, K] f32, experts [T, K] i32)``.
 
@@ -221,13 +222,27 @@ def route(logits, bias=None, *, top_k: int, score: str = "softmax",
     top-k only (a router balanced without an auxiliary loss); the
     weights are the unbiased scores of the chosen.  ``norm_topk``: the
     chosen weights are renormalised to sum 1; ``route_scale`` multiplies
-    them after that."""
+    them after that.  ``groups``: ``(n, kept)``, a limit on expert
+    groups (DeepSeek-V3's ``noaux_tc``): the experts in order form ``n``
+    groups, a group's score is the sum of its two largest (biased)
+    scores, and the top-k is taken among the experts of the ``kept``
+    best groups."""
     scores = (jax.nn.softmax(logits, axis=-1) if score == "softmax"
               else jax.nn.sigmoid(logits))
-    if bias is None:
+    choice = scores if bias is None else scores + bias.astype(scores.dtype)
+    if groups is not None and groups[0] > 1:
+        n, kept = groups
+        T, E = choice.shape
+        best2, _ = jax.lax.top_k(choice.reshape(T, n, E // n), 2)
+        _, keep = jax.lax.top_k(best2.sum(-1), kept)              # [T, kept]
+        open_ = jnp.zeros((T, n), bool).at[
+            jnp.arange(T)[:, None], keep].set(True)
+        choice = jnp.where(jnp.repeat(open_, E // n, axis=1), choice,
+                           -jnp.inf)
+    if choice is scores:            # the choice is by the scores alone
         vals, ids = jax.lax.top_k(scores, top_k)
     else:
-        _, ids = jax.lax.top_k(scores + bias.astype(scores.dtype), top_k)
+        _, ids = jax.lax.top_k(choice, top_k)
         vals = jnp.take_along_axis(scores, ids, axis=1)
     if top_k > 1 and norm_topk:
         vals = vals / jnp.maximum(vals.sum(axis=1, keepdims=True), 1e-9)
@@ -241,7 +256,8 @@ def _ragged_moe(gate_p, expert_p, x, logits, *, top_k: int, activation,
                 route_scale: float = 1.0,
                 norm_topk: bool = True,
                 noise_policy: Optional[str], rng: Optional[jax.Array],
-                dt) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+                dt, groups=None, held=None
+                ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """DROPLESS grouped-GEMM MoE (``dispatch_mode="ragged"``): tokens
     sort by assigned expert and each projection is ONE
     ``jax.lax.ragged_dot`` over per-expert row groups — the megablox
@@ -263,10 +279,17 @@ def _ragged_moe(gate_p, expert_p, x, logits, *, top_k: int, activation,
     # renormalised to sum 1 per token under norm_topk — same convention
     # as top_k_gating (reference top2 normalization sharded_moe.py:290)
     vals, ids = route(lf, gate_p.get("bias"), top_k=top_k, score=score,
-                      norm_topk=norm_topk, route_scale=route_scale)
+                      norm_topk=norm_topk, route_scale=route_scale,
+                      groups=groups)
     me = gates.mean(axis=0)
     ce = jax.nn.one_hot(ids[:, 0], E, dtype=jnp.float32).mean(axis=0)
     aux_loss = (me * ce).sum() * E
+    if held is not None:
+        # the experts held here are [first, first + count): the others'
+        # assignments go nowhere (see ``moe_serve``: id ``count``, which
+        # the scatter of group sizes drops) and weigh nothing
+        ids, E = _held_ids(ids, held)
+        vals = jnp.where(ids < E, vals, 0.0)
 
     flat_ids = ids.reshape(-1)                                    # [T*K]
     order = jnp.argsort(flat_ids, stable=True)
@@ -283,16 +306,29 @@ def _ragged_moe(gate_p, expert_p, x, logits, *, top_k: int, activation,
     out = jax.lax.ragged_dot(u, expert_p["wo"].astype(dt), group_sizes)
 
     w = vals.reshape(-1)[order].astype(dt)
+    if held is not None:
+        out = jnp.where((flat_ids[order] < E)[:, None], out, 0)
     y = jnp.zeros((T, dm), dt).at[tok].add(out * w[:, None])
     return y.reshape(B, S, dm), {
         "moe_aux_loss": aux_loss,
         "moe_dropped": jnp.float32(0.0)}
 
 
+def _held_ids(ids, held):
+    """Expert ids ``[T, K]`` as the holder of experts ``[first, first +
+    count)`` numbers them: 0..count-1 its own, ``count`` (nowhere) every
+    other.  → (ids, count)."""
+    first, count = held
+    local = ids - first
+    return jnp.where((local >= 0) & (local < count), local, count), count
+
+
 def moe_serve(gate_p, expert_p, h, valid=None, *, top_k: int, activation,
               gated: bool, norm_topk: bool, kernel: bool = False,
               layer=None, score: str = "softmax",
-              route_scale: float = 1.0, with_ids: bool = False):
+              route_scale: float = 1.0, with_ids: bool = False,
+              groups: Optional[Tuple[int, int]] = None,
+              held: Optional[Tuple[int, int]] = None):
     """The serving expert layer: DROPLESS by construction.  h: [T, d]
     rows of one serving step (any mix of sequences); ``valid``: [T] bool,
     False for the rows that pad the step's bucket (None: all real).
@@ -318,8 +354,20 @@ def moe_serve(gate_p, expert_p, h, valid=None, *, top_k: int, activation,
     the stack where it lies; a layer sliced out of it first would be
     copied whole on its way into the custom call.
 
+    ``groups``: the router's limit on expert groups (:func:`route`).
+    ``held``: ``(first, count)``, the experts whose weights ``expert_p``
+    holds (``[.., count, ...]``), a chip's share of the layer.  The
+    router keeps all its outputs and its ``top_k`` a row, the weights
+    are normalised over all the chosen, and an assignment to an expert
+    that is not held goes nowhere, as a padding row's does: it is
+    counted in no group and adds nothing.  Nothing stands in for the
+    chips that hold the others.  ``with_ids`` gives the router's own
+    numbering.
+
     ``stats``: (assignments computed, 1000 x the fullest expert's rows
-    over the mean, experts that took a row), for the engine's counters."""
+    over the mean, experts that took a row), for the engine's counters
+    (with ``held``: of the experts held; the router made ``top_k`` a
+    real row)."""
     T, dm = h.shape
     E = expert_p["wi"].shape[-3]
     dt = h.dtype
@@ -339,9 +387,12 @@ def moe_serve(gate_p, expert_p, h, valid=None, *, top_k: int, activation,
                          preferred_element_type=jnp.float32)
         vals, ids = route(logits, gate_p.get("bias"), top_k=top_k,
                           score=score, norm_topk=norm_topk,
-                          route_scale=route_scale)
+                          route_scale=route_scale, groups=groups)
         if valid is not None:
-            ids = jnp.where(valid[:, None], ids, E)     # expert E: nowhere
+            ids = jnp.where(valid[:, None], ids, gate_p["kernel"].shape[-1])
+        taken = ids                         # expert E: nowhere
+        if held is not None:
+            ids, _ = _held_ids(ids, held)
         flat = ids.reshape(-1)                                    # [T*K]
         order = jnp.argsort(flat, stable=True)
         group_sizes = jnp.zeros((E,), jnp.int32).at[flat].add(
@@ -359,6 +410,8 @@ def moe_serve(gate_p, expert_p, h, valid=None, *, top_k: int, activation,
         back = jnp.zeros_like(order).at[order].set(
             jnp.arange(order.shape[0], dtype=order.dtype))
         picked = out[back].reshape(T, top_k, dm).astype(jnp.float32)
+        if held is not None:
+            picked = jnp.where((ids < E)[..., None], picked, 0.0)
         y = (picked * vals[..., None]).sum(axis=1).astype(dt)
         if valid is not None:
             y = jnp.where(valid[:, None], y, 0)
@@ -366,7 +419,7 @@ def moe_serve(gate_p, expert_p, h, valid=None, *, top_k: int, activation,
         stats = jnp.stack([n, (group_sizes.max() * (1000 * E))
                            // jnp.maximum(n, 1),
                            (group_sizes > 0).sum()])
-    return (y, stats, ids) if with_ids else (y, stats)
+    return (y, stats, taken) if with_ids else (y, stats)
 
 
 def moe_ffn(gate_p, expert_p, x, *, top_k: int, capacity_factor: float,
@@ -375,7 +428,7 @@ def moe_ffn(gate_p, expert_p, x, *, top_k: int, capacity_factor: float,
             noise_policy: Optional[str] = None,
             dispatch_mode: str = "scatter",
             norm_topk: bool = True, score: str = "softmax",
-            route_scale: float = 1.0
+            route_scale: float = 1.0, groups=None, held=None
             ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """Full MoE FFN over x [B, S, d_model] (reference: MOELayer.forward
     sharded_moe.py:533).  Returns (y, metrics) with metrics carrying the
@@ -408,7 +461,7 @@ def moe_ffn(gate_p, expert_p, x, *, top_k: int, capacity_factor: float,
     dropless whatever ``dispatch_mode`` a config names.
     """
     B, S, dm = x.shape
-    E = expert_p["wi"].shape[0]
+    E = gate_p["kernel"].shape[-1]
     cap = capacity_for(S, E, top_k, capacity_factor, min_capacity)
     if noise_policy == "Jitter" and rng is not None:
         # jitter gets its own stream: reusing ``rng`` here would
@@ -425,8 +478,10 @@ def moe_ffn(gate_p, expert_p, x, *, top_k: int, capacity_factor: float,
                            activation=activation, gated=gated,
                            noise_policy=noise_policy, rng=rng, dt=dt,
                            norm_topk=norm_topk, score=score,
-                           route_scale=route_scale)
-    if score != "softmax" or "bias" in gate_p or route_scale != 1.0:
+                           route_scale=route_scale, groups=groups,
+                           held=held)
+    if score != "softmax" or "bias" in gate_p or route_scale != 1.0 \
+            or groups is not None or held is not None:
         raise ValueError(
             f"moe_dispatch={dispatch_mode!r} routes by softmax without a "
             "selection bias; this router needs moe_dispatch='ragged'")

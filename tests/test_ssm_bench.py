@@ -240,4 +240,5 @@ def test_benchmark_json_lists_the_cell_and_its_metrics():
         if "serve-ssm-chat" in m.get("workloads", ()):
             assert m in mine
     e2e = {m["name"]: m for m in bench["end_to_end"]}
-    assert e2e["out_tokens_per_s"]["workloads"][-1] == "serve-ssm-chat"
+    # (a later cell is appended behind it)
+    assert "serve-ssm-chat" in e2e["out_tokens_per_s"]["workloads"]
