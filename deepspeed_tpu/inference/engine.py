@@ -46,8 +46,8 @@ from .failures import (FATAL_ENGINE, POISON_STEP,
                        DispatchTimeoutError, EngineDeadError,
                        FailureConfig, FailurePolicy, InjectedFault,
                        InjectedTimeout, bisect_groups, classify_failure)
-from .model import (MOE_STAT_ROWS, pipelined_ragged_step,
-                    ragged_forward)
+from .model import (MOE_STAT_ROWS, fold_projection, fold_projections,
+                    pipelined_ragged_step, ragged_forward)
 from .overload import (AdmissionVerdict, OverloadConfig, RequestMeta,
                        admission_decision, effective_priority,
                        select_victim)
@@ -443,12 +443,10 @@ class InferenceEngine:
                 nvme_bytes=int(self.icfg.kv_tier_nvme_mb * (1 << 20)))
         self.topology = topology if (
             topology is not None and topology.device_count > 1) else None
-        self.params = jax.tree.map(
-            lambda x: x.astype(self.icfg.param_dtype)
-            if x.dtype == jnp.float32 else x, model.params)
+        self.params = self._serving_weights(model.params, announce=True)
         self._quant = None
         if quant_tree is not None:
-            self._quant = quant_tree
+            self._quant = fold_projections(quant_tree)
         elif self.icfg.weight_quant:
             from .quantization import quantize_model_params
             from ..ops.quant import WEIGHT_QUANT_BITS
@@ -1427,6 +1425,34 @@ class InferenceEngine:
         same pipeline as training scalars."""
         self.metrics.publish(monitor, step)
 
+    def _serving_weights(self, params, announce: bool = False):
+        """The tree this engine serves, from a model's parameter tree:
+        float32 leaves in the serving type, and the attention
+        projections folded to the matrices their products read
+        (``model.fold_projection``; ``wo`` only where this engine serves
+        it dense), a leaf at a time, so that no cast copy of a
+        projection outlives its fold.  The caller's tree is
+        left as it is; a tree that is folded already (the one this
+        engine served, a template's weight store) passes through.
+        ``announce``: log the folded leaves and their bytes (once an
+        engine, at construction)."""
+        folded = {}
+
+        def take(path, x):
+            if x.dtype == jnp.float32:
+                x = x.astype(self.icfg.param_dtype)
+            y = fold_projection(path, x, wo=not self.icfg.weight_quant)
+            if y is not x:
+                folded[jax.tree_util.keystr(path)] = y.nbytes
+            return y
+
+        tree = jax.tree_util.tree_map_with_path(take, params)
+        if folded and announce:
+            logger.info("serving weights: %d attention projections folded "
+                        "to rank 3, %d bytes (%s)", len(folded),
+                        sum(folded.values()), ", ".join(folded))
+        return tree
+
     def refresh_params(self, params) -> None:
         """Swap the served weights (hybrid-engine policy refresh).
 
@@ -1438,9 +1464,7 @@ class InferenceEngine:
             raise NotImplementedError(
                 "refresh_params under weight_stream: re-spill the store "
                 "by rebuilding the engine")
-        self.params = jax.tree.map(
-            lambda x: x.astype(self.icfg.param_dtype)
-            if x.dtype == jnp.float32 else x, params)
+        self.params = self._serving_weights(params)
         if self.icfg.weight_quant:
             from .quantization import quantize_model_params
             from ..ops.quant import WEIGHT_QUANT_BITS
@@ -1586,12 +1610,7 @@ class InferenceEngine:
 
         if self._quant is None:
             shapes = jax.tree.map(lambda x: tuple(x.shape), self.params)
-            axes = self.model.param_axes
-            if self._stream is not None:
-                # block weights were spilled to the NVMe store; only the
-                # resident remainder needs placement
-                axes = {k: v for k, v in axes.items() if k in self.params}
-            specs = shd.tree_specs(axes, topo, shapes=shapes)
+            specs = shd.tree_specs(self._folded_axes(), topo, shapes=shapes)
             is_spec = lambda s: isinstance(s, P)   # noqa: E731
             specs = jax.tree.map(
                 lambda s, x: shd.add_fsdp_to_spec(s, tuple(x.shape), topo,
@@ -1603,6 +1622,33 @@ class InferenceEngine:
             # dense remainder (norms/biases/embeds) + quantized payloads
             self.params = generic(self.params)
             self._quant = generic(self._quant)
+
+    def _folded_axes(self):
+        """The model's logical axes with a folded projection's ``(heads,
+        head_dim)`` as the one axis ``heads`` (``kv_heads``): the rules
+        give it the tensor axis, and ``H*D / tp`` contiguous columns
+        (rows of ``wo``) are whole heads where ``tp`` divides the heads,
+        which is all the rules could ask of the unfolded leaf's ``H``;
+        where it does not, the axis is left whole."""
+        tp = self.topology.tp_size
+        is_axes = lambda a: isinstance(a, tuple) and all(  # noqa: E731
+            e is None or isinstance(e, str) for e in a)
+
+        def fold(ax, w):
+            if len(ax) == w.ndim:
+                return ax
+            i = ax.index("head_dim")
+            heads = {"heads": self.cfg.num_heads,
+                     "kv_heads": self.cfg.num_kv_heads}[ax[i - 1]]
+            return ax[:i - 1] + (ax[i - 1] if heads % tp == 0 else None,) \
+                + ax[i + 1:]
+
+        axes = self.model.param_axes
+        if self._stream is not None:
+            # block weights were spilled to the NVMe store; only the
+            # resident remainder needs placement
+            axes = {k: v for k, v in axes.items() if k in self.params}
+        return jax.tree.map(fold, axes, self.params, is_leaf=is_axes)
 
     def _setup_weight_stream(self) -> None:
         """Spill per-layer block weights (quantized payloads under

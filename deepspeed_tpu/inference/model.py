@@ -375,15 +375,99 @@ def _mm(x, w, dt, contract_dims: int = 1):
     return y.reshape(*x.shape[:-1], *wshape[contract_dims:])
 
 
+# the projections of a block's ``attn`` group whose columns are heads:
+# the model keeps them ``[L, d, H, D]`` (and ``wo``, whose rows are,
+# ``[L, H, D, d]``)
+_HEAD_PROJECTIONS = ("wq", "wk", "wv", "wg")
+
+
+def fold_projection(path, w, wo: bool = True):
+    """The rule of :func:`fold_projections` for one leaf at ``path`` of
+    the tree: a stacked head projection ``[L, d, H, D]`` as ``[L, d,
+    H*D]``, attention's ``wo`` ``[L, H, D, d]`` as ``[L, H*D, d]``, every
+    other leaf itself.  A QuantizedTensor head projection is folded in
+    its own layout (row-wise scales go by ``(layer, row)`` and the grouped
+    form runs over the flat layer: the same numbers either way).  ``wo``'s
+    row-wise scales go by HEAD, so a ``wo`` that is or will be quantized
+    stays ``[L, H, D, d]`` (``wo=False``: an engine that quantizes its
+    weights), which ``_out_proj`` takes as well; a layer of it is
+    dequantized into a temporary a step in any case."""
+    from ..ops.quant import QuantizedTensor
+    group, name = [getattr(p, "key", None) for p in path[-2:]] \
+        if len(path) >= 2 else (None, None)
+    if group != "attn" or len(w.shape) != 4:
+        return w
+    quantized = isinstance(w, QuantizedTensor)
+    L_, a, b, c = w.shape
+    if name == "wo":
+        return w.reshape(L_, a * b, c) if wo and not quantized else w
+    if name not in _HEAD_PROJECTIONS:
+        return w
+    shape = (L_, a, b * c)
+    if not quantized:
+        return w.reshape(shape)
+    data, scale = w.data, w.scale
+    if tuple(data.shape) == tuple(w.shape):         # row-wise int8
+        data, scale = data.reshape(shape), scale.reshape(L_, a, 1)
+    elif data.ndim == 4:            # packed row-wise fp6/fp12: ``D`` packed
+        data = data.reshape(L_, a, -1)
+    return QuantizedTensor(data, scale, w.zero, w.bits, shape, w.dtype,
+                           layout=w.layout)
+
+
+def fold_projections(tree):
+    """The tree the engine serves, from the model's parameter tree (or
+    the quantized tree beside it): every stacked projection of an
+    ``attn`` group (``blocks`` and ``dense_blocks`` alike) as the matrix
+    its product reads, ``[L, d, H*D]`` (``wq``, ``wk``, ``wv``, the gate)
+    and ``[L, H*D, d]`` (``wo``).  On the chip a stacked weight of rank
+    four is tiled over its two minor dimensions and a step does not read
+    a layer of it where it lies: it cuts the layer out into a temporary
+    first, every byte, a layer a step; of rank three the product reads
+    the stack, as the MLP's do (PERF.md section 6, PR 45).  A leaf that
+    is folded already no longer has the rank that folds: the fold
+    applied twice is the fold applied once."""
+    from ..ops.quant import QuantizedTensor
+    return jax.tree_util.tree_map_with_path(
+        fold_projection, tree,
+        is_leaf=lambda x: isinstance(x, QuantizedTensor))
+
+
+def _head_proj(h, w, dt, heads: int, head_dim: int):
+    """``h @ w`` for a head projection as the engine holds it, one
+    layer's ``[d, heads * head_dim]`` (dense or quantized) → ``[T, heads,
+    head_dim]``: the activation is what is reshaped.  A weight of the
+    model's own ``[d, H, D]`` form is refused: the product would not read
+    it where it lies in the stack (``fold_projections``)."""
+    assert len(w.shape) == 2, (
+        "a head projection reaches the serving forward unfolded "
+        f"{tuple(w.shape)}: pass the tree through fold_projections")
+    # the product's rows exist before they are cut into heads.  Left to
+    # choose the product's layout by the reshape behind it, the TPU's
+    # compiler makes the reshape a bitcast by computing the product
+    # transposed, and transposes the weight for it: every byte of it, a
+    # layer a step (PERF.md section 6, PR 45)
+    y = jax.lax.optimization_barrier(_mm(h, w, dt))
+    return y.reshape(-1, heads, head_dim)
+
+
+def _out_proj(o, wo, dt):
+    """``o [T, H, D]`` through attention's output projection as the
+    engine holds it, one layer's ``[H*D, d]`` (``[H, D, d]`` where it is
+    quantized: ``fold_projection``) → ``[T, d]``."""
+    return _mm(o.reshape(o.shape[0], -1), wo, dt,
+               contract_dims=len(wo.shape) - 1)
+
+
 def _qkv_proj(cfg, ap, h, dt, cos, sin, positions, kind: str = "full"):
     """Shared qkv projection + biases + rotary for the serving forwards
     (ragged step and decode burst).  ``kind``: the layer's attention
     kind, which says whether it takes the rotary embedding."""
     if cfg.attn_in_scale != 1.0:
         h = h * jnp.asarray(cfg.attn_in_scale, dt)
-    q = _mm(h, ap["wq"], dt)
-    k = _mm(h, ap["wk"], dt)
-    v = _mm(h, ap["wv"], dt)
+    q = _head_proj(h, ap["wq"], dt, cfg.num_heads, cfg.head_dim)
+    k = _head_proj(h, ap["wk"], dt, cfg.num_kv_heads, cfg.head_dim)
+    v = _head_proj(h, ap["wv"], dt, cfg.num_kv_heads, cfg.head_dim)
     if cfg.key_scale != 1.0:
         k = k * jnp.asarray(cfg.key_scale, dt)
     if cfg.attn_bias:
@@ -900,7 +984,8 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
                                 batch.positions, kind)
             if cfg.attn_gate:
                 with jax.named_scope("attn_gate"):
-                    g = _mm(h, ap["wg"], dt)
+                    g = _head_proj(h, ap["wg"], dt, cfg.num_heads,
+                                   cfg.head_dim)
         with jax.named_scope("kv_write"):
             pool = _write_kv(pool, k, v, batch, block_size, layer=layer)
         # a window layer's calls under a scope of their own (inside
@@ -923,8 +1008,7 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
                 with jax.named_scope("attn_gate"):
                     o = o * jax.nn.sigmoid(
                         g.astype(jnp.float32)).astype(o.dtype)
-            o = _mm(o.reshape(o.shape[0], -1), ap["wo"], dt,
-                    contract_dims=2)
+            o = _out_proj(o, ap["wo"], dt)
             if cfg.attn_out_bias:
                 o = o + ap["bo"].astype(dt)
             if cfg.sandwich_norm:
@@ -1378,8 +1462,7 @@ def decode_burst_forward(cfg: TransformerConfig, params, prefix,
             jnp.maximum(denom, 1e-30)[..., None]
         o = o.reshape(S, H, D).astype(dt)
 
-        o = _mm(o.reshape(o.shape[0], -1), ap["wo"], dt,
-                contract_dims=2)
+        o = _out_proj(o, ap["wo"], dt)
         if cfg.attn_out_bias:
             o = o + ap["bo"].astype(dt)
         if not cfg.parallel_block:

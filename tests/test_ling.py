@@ -617,6 +617,10 @@ def test_rehearsal_of_the_new_cell_exits_zero():
 
 def test_falcon_h1_compiles_to_the_programs_it_did():
     """The sixth older configuration (the other five:
-    ``test_falcon_h1.py``), hashed on the parent commit (956a699)."""
+    ``test_falcon_h1.py``), hashed on the parent commit (956a699); its
+    serving step again by PR 45, which folds the attention projections
+    it reads.  This model's own serving step reads none of them (no
+    layer goes through ``_qkv_proj``) and is PR 44's."""
     assert older_programs("falcon-h1-34b-d6") \
-        == ("27b9176e4c465481", "6f88fcff44297646")
+        == ("27b9176e4c465481", "9907eada4b3b23a2")
+    assert older_programs("ling-3.0-flash-d7")[1] == "7b06bdf07f814cbf"

@@ -209,8 +209,9 @@ class TestCarriedCache:
         from deepspeed_tpu.inference import model as im
 
         def f(params, kv):
-            return im.ragged_forward(m.config, params, kv, batch, bs, 4,
-                                     **kw)
+            # the model's tree as the engine holds it
+            return im.ragged_forward(m.config, im.fold_projections(params),
+                                     kv, batch, bs, 4, **kw)
         return f, jax.jit(f)(m.params, kv)
 
     @pytest.mark.parametrize("attn_impl", ["xla", "pallas"])

@@ -273,6 +273,7 @@ def _pstep_compiled(one_chip, cfg, kv_quant, T, seqs, bs, mbs, blocks):
     layer's share of the pool)."""
     from deepspeed_tpu.inference import SamplingParams
     from deepspeed_tpu.inference.model import (MOE_STAT_ROWS,
+                                               fold_projections,
                                                pipelined_ragged_step)
     from deepspeed_tpu.inference.ragged.state import RaggedBatch
     from deepspeed_tpu.inference.sampler import sample_rows
@@ -281,7 +282,7 @@ def _pstep_compiled(one_chip, cfg, kv_quant, T, seqs, bs, mbs, blocks):
     S = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
     params = jax.tree.map(
         lambda a: S(a.shape, jnp.bfloat16),
-        jax.eval_shape(lambda k: init_params(cfg, k)[0],
+        jax.eval_shape(lambda k: fold_projections(init_params(cfg, k)[0]),
                        jax.random.PRNGKey(0)))
     pool = (cfg.num_layers, blocks + 1, bs, 2, cfg.num_kv_heads,
             cfg.head_dim)
@@ -553,6 +554,31 @@ def test_serving_step_keeps_the_pool_in_place(one_chip, on_chip, kv_quant):
         100e6 if kv_quant else 16e6)
     _tile_grid_conditions(compiled.jaxpr, T=512, seqs=64, mbs=16,
                           short_group=8)
+
+
+@pytest.mark.parametrize("rows", [128, 512])
+def test_serving_step_reads_the_attention_projections_where_they_lie(
+        one_chip, on_chip, rows):
+    """Both rungs of ``serve-decode``'s step (128 and 512 rows): the
+    engine holds ``wq``/``wk``/``wv`` as ``[L, d, H*D]`` and ``wo`` as
+    ``[L, H*D, d]``, and the product's rows are reshaped into heads
+    behind a barrier, so neither program cuts a layer's projection out
+    of the stack into a temporary (``constant_dynamic-slice_fusion``) or
+    transposes it (``copy``; 33.5 and 8.4 MB a layer a step at the
+    parent of PR 45): the products read the stack as the MLP's do."""
+    from deepspeed_tpu.models.presets import build_config
+
+    cfg = build_config("mistral-7b", num_layers=16)
+    compiled, _ = _pstep_compiled(one_chip, cfg, False, T=rows, seqs=64,
+                                  bs=64, mbs=16, blocks=1024)
+    text = compiled.as_text()
+    assert _moves_of(text, 1), "the reader no longer finds any copy"
+    # no bf16 array as large as a layer's ``wk`` (8.4 MB) is copied, cut
+    # out or written back outside a fusion (a float32 ``q`` of 512 rows,
+    # as large, is relaid once a layer)
+    assert [m for m in _moves_of(
+        text, cfg.d_model * cfg.num_kv_heads * cfg.head_dim * 2)
+        if ": bf16[" in m] == []
 
 
 # ------------------------------------------------ ZeRO-3 over four chips
