@@ -80,6 +80,9 @@ REAL = dict(
     paged_long=dict(S=16, H=32, Hkv=4, D=128, block=64, blocks=2560, nb=192,
                     window=2048, ctx=(4100, 12200)),
     gemm=dict(K=4096, N=14336, Ms=(8, 1024)),
+    # the one-token state update at the two cells' shapes (serve-ssm-chat,
+    # serve-kda-reason): a 2 MiB row a slot a layer
+    state=dict(L=2, S=128, H=32, ssm=(128, 256, 2), kda=(128, 128)),
     barrier=dict(n=8192, iters=64),
 )
 TINY = dict(
@@ -99,6 +102,7 @@ TINY = dict(
     paged_long=dict(S=4, H=16, Hkv=2, D=32, block=8, blocks=96, nb=24,
                     window=32, ctx=(70, 190)),
     gemm=dict(K=256, N=512, Ms=(8, 32)),
+    state=dict(L=2, S=4, H=4, ssm=(8, 128, 2), kda=(16, 128)),
     barrier=dict(n=256, iters=8),
 )
 
@@ -415,6 +419,71 @@ def kernels_phase(sz, seed):
                     kvl, q)
                 print(f"    {impl}: {ms(t)}")
             close("out", got["pallas"], got["xla"], BF16_REL)
+
+    # --- the recurrent mixers' one-token state update, a layer of the
+    # stack in place against XLA's over the layer cut out of it; beside
+    # each its rows read once and written once at the HBM's peak
+    from deepspeed_tpu.ops import kda as kda_ops
+    from deepspeed_tpu.ops import ssm as ssm_ops
+
+    c = sz["state"]
+    L, S, H = c["L"], c["S"], c["H"]
+    ks = jax.random.split(jax.random.fold_in(k_paged, 2), 8)
+    active = jnp.arange(S) != 1
+    replay, fresh = jnp.arange(S) == 2, jnp.arange(S) == 3
+    P, N, G = c["ssm"]
+    dims = ssm_ops.SSMDims(H * P, H, P, G, N, 4, 128)
+    ssm_args = (
+        jax.random.normal(ks[0], (S, H, P), jnp.bfloat16),
+        jax.random.normal(ks[1], (S, G, N), jnp.bfloat16),
+        jax.random.normal(ks[2], (S, G, N), jnp.bfloat16),
+        jax.nn.softplus(jax.random.normal(ks[3], (S, H)) - 3.0),
+        -jnp.exp(jax.random.normal(ks[4], (H,))),
+        jax.random.normal(ks[5], (H,)), active, replay, fresh, dims)
+    Kd, V = c["kda"]
+    unit = lambda t: t / jnp.linalg.norm(t, axis=-1, keepdims=True)  # noqa: E731
+    kda_args = (
+        unit(jax.random.normal(ks[0], (S, H, Kd))),
+        unit(jax.random.normal(ks[1], (S, H, Kd))),
+        jax.random.normal(ks[2], (S, H, V)),
+        -jax.nn.softplus(jax.random.normal(ks[3], (S, H, Kd))),
+        jax.nn.sigmoid(jax.random.normal(ks[4], (S, H))),
+        active, replay, fresh)
+    for name, ops, tile, store, args in (
+            ("ssm_state_update", ssm_ops, (P, N), jnp.bfloat16, ssm_args),
+            ("kda_state_update", kda_ops, (Kd, V), jnp.float32, kda_args)):
+        stack = 0.1 * jax.random.normal(ks[6], (L, S + 1, H) + tile, store)
+        moved = 2 * S * H * tile[0] * tile[1] * stack.dtype.itemsize
+        print(f"  {name} x{S} H{H} {list(tile)} {stack.dtype.name}: "
+              f"{moved / 1e6:.0f} MB read and written, "
+              f"{moved / 819e9 * 1e3:.3f} ms at 819 GB/s")
+
+        def xla(stack, li, _ops=ops, _args=args):
+            pool = jax.lax.dynamic_index_in_dim(stack, li, keepdims=False)
+            out, new = _ops.state_update(pool[:S], *_args)
+            return out, jax.lax.dynamic_update_slice(
+                stack, new[None], (li, 0, 0, 0, 0))
+
+        got = {}
+        for impl, fn in (
+                ("pallas", lambda st, li, _ops=ops, _args=args:
+                 _ops.state_update_in_place(st, li, *_args)),
+                ("xla", xla)):
+            # the stack donated and carried from call to call, as the
+            # served step carries it: without that each call copies it
+            step = jax.jit(fn, donate_argnums=0)
+            got[impl] = jax.block_until_ready(step(jnp.copy(stack), 1))
+            st, t0 = jnp.copy(stack), time.perf_counter()
+            for _ in range(10):
+                _, st = step(st, 1)
+            jax.block_until_ready(st)
+            print(f"    {impl}: {ms((time.perf_counter() - t0) / 10)}")
+        close("out", got["pallas"][0], got["xla"][0], 1e-4)
+        close("rows", got["pallas"][1][1, :S], got["xla"][1][1, :S],
+              2e-2 if store == jnp.bfloat16 else 1e-5)
+        check(bool((got["pallas"][1][0] == stack[0]).all()
+                   and (got["pallas"][1][1, S] == stack[1, S]).all()),
+              f"{name} moved another layer's rows or the trash row")
 
     # --- mixed-input GEMMs
     K, N = sz["gemm"]["K"], sz["gemm"]["N"]

@@ -828,6 +828,15 @@ class InferenceEngine:
             reg.gauge_fn("serving_state_slots_in_use",
                          lambda: len(self.state.seqs),
                          "sequences that hold a state row")
+            # the one-token update is dense over the pool's slots: how
+            # many of the rows it moved it advanced
+            self._state_rows = self._state_slots = 0
+            reg.gauge_fn("serving_state_update_fill",
+                         lambda: (self._state_rows / self._state_slots
+                                  if self._state_slots else None),
+                         "state rows the dispatched steps advanced by one "
+                         "token over the slot rows their dense update read "
+                         "and wrote (absent before the first step)")
             reg.gauge_fn(
                 "serving_state_bytes",
                 lambda: len(self.state.seqs)
@@ -1113,6 +1122,8 @@ class InferenceEngine:
                 scan += len(toks)
         self._c_state_updates.inc(rows, kind="decode")
         self._c_state_updates.inc(scan, kind="scan")
+        self._state_rows += rows
+        self._state_slots += self.icfg.max_seqs
         self._c_state_replayed.inc(replays)
         return {"state_rows": rows, "scan_tokens": scan,
                 "state_starts": starts, "state_replays": replays}
@@ -1243,6 +1254,7 @@ class InferenceEngine:
         self.metrics.reset()
         self._group_blocks = self._group_slots = 0
         self._row_tokens = self._row_slots = 0
+        self._state_rows = self._state_slots = 0
         self.requests.clear()
         self.tracer.clear()
         # rearm the pool high-water mark so a timed region reports ITS

@@ -504,14 +504,17 @@ def _ssm_runs(batch: RaggedBatch, width: int):
         **{"row_" + k: v[batch.seq_slot] for k, v in per_slot.items()})
 
 
-def _ssm_mixer(cfg, mp, u, rec_state, li, batch: RaggedBatch, runs, dt):
+def _ssm_mixer(cfg, mp, u, rec_state, li, batch: RaggedBatch, runs, dt,
+               kernel: bool = False):
     """A hybrid layer's Mamba-2 mixer over a step's flat rows.
 
     u: [T, dm], the normed input times its multiplier.  ``rec_state``:
     ``(ssm [L, S+1, H, P, N], conv [L, S+1, W, C])``, the engine's state
     rows of every layer; layer ``li`` reads and writes its own in place.
     A one-token run advances its slot's state by the dense update
-    (``ssm_update``); a longer run goes through the chunked form
+    (``ssm_update``: with ``kernel`` the Pallas kernel over the stack,
+    one read and one write a row; else XLA's over the layer cut out of
+    it, which reads a row twice); a longer run goes through the chunked form
     (``ssm_scan``) from the slot's state, or from zeros where it starts
     at position 0, and leaves its last state in the slot; the
     convolution reaches into the slot's tail (``ssm_conv``).
@@ -538,11 +541,19 @@ def _ssm_mixer(cfg, mp, u, rec_state, li, batch: RaggedBatch, runs, dt):
         dts, a = M.discretise(dt_raw, mp)
     with jax.named_scope("ssm_update"):
         at = runs["last"]
-        pool = jax.lax.dynamic_index_in_dim(ssm, li, keepdims=False)
-        y_one, new = M.state_update(
-            pool[:S], x[at], b[at], c[at], dts[at], a, mp["D"],
-            runs["one"], runs["replay"], runs["fresh"], dims)
-        ssm = jax.lax.dynamic_update_slice(ssm, new[None], (li, 0, 0, 0, 0))
+        # (XLA's branch as it stood, operation for operation: the CPU's
+        # lowered step is hashed, tests/test_ling.py)
+        if kernel:
+            y_one, ssm = M.state_update_in_place(
+                ssm, li, x[at], b[at], c[at], dts[at], a, mp["D"],
+                runs["one"], runs["replay"], runs["fresh"], dims)
+        else:
+            pool = jax.lax.dynamic_index_in_dim(ssm, li, keepdims=False)
+            y_one, new = M.state_update(
+                pool[:S], x[at], b[at], c[at], dts[at], a, mp["D"],
+                runs["one"], runs["replay"], runs["fresh"], dims)
+            ssm = jax.lax.dynamic_update_slice(ssm, new[None],
+                                               (li, 0, 0, 0, 0))
     with jax.named_scope("ssm_scan"):
         ch = runs["chunks"]
         start, n, slot, first, lastc = (ch[:, i] for i in range(5))
@@ -608,13 +619,15 @@ def _scatter_chunks(y_run, rows, there, T: int):
         y_run.reshape((-1,) + y_run.shape[2:]), mode="drop")
 
 
-def _kda_mixer(cfg, mp, h, rec_state, li, batch: RaggedBatch, runs, dt):
+def _kda_mixer(cfg, mp, h, rec_state, li, batch: RaggedBatch, runs, dt,
+               kernel: bool = False):
     """A "kda" layer's mixer over a step's flat rows (``ops/kda.py``).
 
     h: [T, dm], the normed input.  ``rec_state``: ``(state [L, S+1, H,
     K, V], conv [L, S+1, W, C])``, the engine's state rows of the layers
     that hold one; ``li``: this layer's rank among them.  A one-token
-    run advances its slot's state by the dense update (``kda_update``);
+    run advances its slot's state by the dense update (``kda_update``,
+    by the Pallas kernel or by XLA as ``_ssm_mixer``'s);
     a longer run goes through the chunked form (``kda_chunk``) from the
     slot's state, or from zeros where it starts at position 0, and
     leaves its last state in the slot; the convolution reaches into the
@@ -645,11 +658,17 @@ def _kda_mixer(cfg, mp, h, rec_state, li, batch: RaggedBatch, runs, dt):
         g, beta = K.gates(a_raw, b_raw, mp, dims)
     with jax.named_scope("kda_update"):
         at = runs["last"]
-        pool = jax.lax.dynamic_index_in_dim(ssm, li, keepdims=False)
-        o_one, new = K.state_update(
-            pool[:S], q[at], k[at], v[at], g[at], beta[at], runs["one"],
-            runs["replay"], runs["fresh"])
-        ssm = jax.lax.dynamic_update_slice(ssm, new[None], (li, 0, 0, 0, 0))
+        if kernel:
+            o_one, ssm = K.state_update_in_place(
+                ssm, li, q[at], k[at], v[at], g[at], beta[at], runs["one"],
+                runs["replay"], runs["fresh"])
+        else:
+            pool = jax.lax.dynamic_index_in_dim(ssm, li, keepdims=False)
+            o_one, new = K.state_update(
+                pool[:S], q[at], k[at], v[at], g[at], beta[at], runs["one"],
+                runs["replay"], runs["fresh"])
+            ssm = jax.lax.dynamic_update_slice(ssm, new[None],
+                                               (li, 0, 0, 0, 0))
     with jax.named_scope("kda_chunk"):
         rows, there, n, slot, first, lastc = _chunk_rows(runs, T, dims.chunk)
         NC = n.shape[0]
@@ -882,6 +901,9 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
     # state rows by slot (``KVCacheConfig.cache_zeros``); both ride the
     # layer scan as carries that every layer updates in place
     rec = runs = None
+    # the one-token state update is the Pallas kernel where the experts'
+    # is (``_ffn``): a TPU, the state rows on one device
+    state_kernel = jax.default_backend() == "tpu" and shard_mesh is None
     if cfg.has_ssm:
         rec = (kv["ssm"], kv["conv"])
         kv = kv["kv"]
@@ -966,7 +988,7 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
             with jax.named_scope("attn"):
                 if kind == "kda":
                     o, rec = _kda_mixer(cfg, lp["kda"], h, rec, rank, batch,
-                                        runs, dt)
+                                        runs, dt, kernel=state_kernel)
                 else:
                     o, pool = _latent_attention(
                         cfg, lp["mla"], h, pool, layer, batch, runs, cos,
@@ -1020,7 +1042,7 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
             with jax.named_scope("ssm"):
                 m, rec = _ssm_mixer(
                     cfg, lp["ssm"], h * jnp.asarray(cfg.ssm_in_scale, dt),
-                    rec, li, batch, runs, dt)
+                    rec, li, batch, runs, dt, kernel=state_kernel)
                 o = o + m * jnp.asarray(cfg.ssm_out_scale, dt)
         with jax.named_scope("ffn"):
             if not cfg.parallel_block:

@@ -20,7 +20,10 @@ head's values under a sigmoid gate.  What this file computes:
   ``(bound, 0)``, ``beta``, and the output's gated norm;
 * :func:`state_update` (scope ``kda_update``): one token for every slot
   of the state pool that a step advances by one token, dense over the
-  pool as ``ops/ssm.state_update`` is;
+  pool as ``ops/ssm.state_update`` is, in XLA (two reads of the rows
+  and a write), and :func:`state_update_in_place`, the same as one pass
+  by a Pallas kernel over the stack in place (the call's scaffolding is
+  ``ops/ssm.update_rows_in_place``; a TPU, the rows on one device);
 * :func:`chunk_rule` (scope ``kda_chunk``): the chunked form over a list
   of chunks of ``Q`` tokens, each of one run: inside a chunk the WY form
   of the delta rule (``u = (I + A)^-1 beta (v - ...)``), between chunks
@@ -31,15 +34,18 @@ head's values under a sigmoid gate.  What this file computes:
   factors inside float32 (16 rows at -5: at most ``e^80``).
 
 ``mixer_forward`` is the whole mixer over whole sequences from a zero
-state (``models/transformer.apply``).  All of it is XLA.
+state (``models/transformer.apply``).  All but that kernel is XLA.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from .ssm import head_columns, heads_per_step, update_rows_in_place
 
 F32 = jnp.float32
 HI = jax.lax.Precision.HIGHEST
@@ -120,6 +126,50 @@ def state_update(state, q, k, v, g, beta, active, replay, fresh):
     advance = active & ~replay
     return o, jnp.where(advance[:, None, None, None],
                         new.astype(state.dtype), state)
+
+
+def _kda_head(h, old, ins, outs, *, hb: int):
+    """The delta rule's head ``h`` of a block: ``old [K, V]`` float32 →
+    ``Diag(alpha) old + k (outer) delta``, both reads of the old state
+    (the prediction for ``k``, the output's for ``q``) up the tile's
+    rows, and the output into row ``h`` of the block's."""
+    cols, rows = ins
+    ak, aq, alpha, k = (cols[:, i * hb + h:i * hb + h + 1] for i in range(4))
+    v, beta, kq = (rows[i, h:h + 1, :] for i in range(3))
+    delta = beta * (v - jnp.sum(old * ak, axis=0, keepdims=True))
+    outs[0][h:h + 1, :] = jnp.sum(old * aq, axis=0, keepdims=True) \
+        + kq * delta
+    return alpha * old + k * delta
+
+
+def state_update_in_place(stack, li, q, k, v, g, beta, active, replay, fresh,
+                          hb=None):
+    """``state_update`` of layer ``li``'s rows ``stack[li, :S]`` by the
+    Pallas kernel, in place on ``stack [L, S+1, H, K, V]``: a row is
+    read once and written once (XLA's reads it for the two products,
+    then again for the update).  The other arguments as
+    ``state_update``'s.  → (o [S, H, V] float32, the stack)."""
+    S, H, V = v.shape
+    K = k.shape[-1]
+    hb = hb or heads_per_step(stack)
+    alpha = jnp.exp(g)
+    on = replay[:, None, None]
+    # a replayed row's state is S_t already: o_t = S_t^T q_t, which is
+    # the read for q with no decay on it and no delta behind it
+    cols = head_columns(
+        [alpha * k, jnp.where(on, q, alpha * q), alpha, k], hb)
+    kq = jnp.where(on, 0.0, (k * q).sum(-1, keepdims=True))
+    rows = jnp.stack([v, jnp.broadcast_to(beta[..., None], v.shape),
+                      jnp.broadcast_to(kq, v.shape)], 1)       # [S, 3, H, V]
+    stack, o = update_rows_in_place(
+        functools.partial(_kda_head, hb=hb), stack, li, active & ~replay,
+        fresh,
+        ins=[(cols, (None, None, K, 4 * hb), lambda s, j: (s, j, 0, 0)),
+             (rows, (None, 3, hb, V), lambda s, j: (s, 0, j, 0))],
+        outs=[(jax.ShapeDtypeStruct((S, H, V), F32), (None, hb, V),
+               lambda s, j: (s, j, 0))],
+        hb=hb, name="kda_state_update")
+    return o, stack
 
 
 def _decayed_products(x, y, G, block: int, strict: bool):

@@ -18,7 +18,13 @@ in front of it a depthwise causal convolution of width ``W`` over the
 * :func:`state_update` (scope ``ssm_update``): the one-token update of
   every sequence that a step advances by one token, dense over the
   state pool's slots (a slot holds at most one run a step, so nothing is
-  gathered or scattered: the pool is read and written once);
+  gathered or scattered), in XLA: three passes over the rows, the read
+  for ``C . S`` and the update's read and write;
+* :func:`state_update_in_place` (the same scope): that update as ONE
+  pass, a Pallas kernel over the stack of all layers' rows in place
+  (:func:`update_rows_in_place`, which ``ops/kda.py``'s kernel shares);
+  the serving forward takes it on a TPU where the rows lie on one
+  device, and XLA's elsewhere;
 * :func:`chunk_scan` (scope ``ssm_scan``): the chunked form (SSD) over a
   list of chunks of ``Q`` tokens, each of one run; a chunk continues the
   one before it or starts from a given state.
@@ -26,16 +32,19 @@ in front of it a depthwise causal convolution of width ``W`` over the
 ``mixer_forward`` is the whole mixer over whole sequences from a zero
 state (``models/transformer.apply``); the serving forward
 (``inference/model.py``) composes the three itself around the engine's
-state pool.  All of it is XLA: see PERF.md section 6 (PR 42) for what
-the chip said.
+state pool.  The convolution and the chunked form are XLA: see PERF.md
+section 6 (PR 42, PR 46) for what the chip said.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 F32 = jnp.float32
 
@@ -187,6 +196,143 @@ def state_update(state, x, b, c, dt, a, d_skip, active, replay, fresh,
     out = jnp.where(advance[:, None, None, None],
                     new.reshape(state.shape).astype(state.dtype), state)
     return y.reshape(x.shape), out
+
+
+# ------------------------------------------------- the update, a kernel
+# What a grid step of the update kernels holds of a slot's state: its
+# heads' tiles, in and out and each double-buffered, so four times this
+STATE_TILE_BYTES = 2 * 1024 * 1024
+
+
+def heads_per_step(stack) -> int:
+    """Heads of a slot's state ``stack [L, S+1, H, ., .]`` that one grid
+    step of an update kernel takes: all of them where they fit
+    ``STATE_TILE_BYTES``, else the largest halving of them that does and
+    still fills the rows' sublanes (a multiple of 8)."""
+    H = stack.shape[2]
+    head = stack.shape[3] * stack.shape[4] * stack.dtype.itemsize
+    while H * head > STATE_TILE_BYTES and H % 16 == 0:
+        H //= 2
+    return H
+
+
+def head_columns(vectors, hb: int):
+    """``vectors``: a list of ``[S, H, A]`` float32 → ``[S, H / hb, A,
+    len * hb]``: for a block of ``hb`` heads the vectors as COLUMNS,
+    vector ``i`` of the block's head ``h`` in column ``i * hb + h``.  A
+    kernel multiplies a state tile ``[A, B]`` down its rows by a column
+    ``[A, 1]`` it cuts out with a static lane index; the transposes are
+    XLA's, over vectors a state's ``1 / B`` in size."""
+    S, H, A = vectors[0].shape
+    t = jnp.stack(vectors, 1).reshape(S, len(vectors), H // hb, hb, A)
+    return t.transpose(0, 2, 4, 1, 3).reshape(S, H // hb, A, -1)
+
+
+def _rows_kernel(li, advance, fresh, tile, *refs, head, n_in: int, hb: int):
+    """One slot's block of ``hb`` heads: every head's tile loaded once in
+    the stored type, advanced in float32 by ``head(h, old, ins, outs) →
+    new`` (which takes its reads of the old state from the same tile and
+    leaves them in ``outs``), stored once."""
+    s = pl.program_id(0)
+    ins, (out, *outs) = refs[:n_in], refs[n_in:]
+    zero, move = fresh[s] != 0, advance[s] != 0
+    for h in range(hb):
+        kept = tile[h]
+        new = head(h, jnp.where(zero, 0.0, kept.astype(F32)), ins, outs)
+        # a slot that is not advanced keeps its bits
+        out[h] = jnp.where(move, new.astype(out.dtype), kept)
+
+
+def update_rows_in_place(head, stack, li, advance, fresh, ins, outs, *,
+                         hb: int, name: str):
+    """The one-token update of layer ``li``'s state rows as ONE pass
+    over them, in place on the stack: what ``ops/ssm.py`` and
+    ``ops/kda.py``'s kernels share.
+
+    stack: ``[L, S+1, H, A, B]`` in the stored type, aliased to the
+    first result: the grid walks the ``S`` slots of layer ``li`` (a
+    scalar, prefetched into the block index map) and each slot's blocks
+    of ``hb`` heads, so the trash row ``S`` and every other layer are
+    never visited and keep what they hold.  advance, fresh: ``[S]``, the
+    slot's row is advanced (else it is stored back as it was loaded),
+    and starts from zeros.  ins: ``(array [S, ...], block, index map
+    (s, j) → block index)`` per input; outs likewise with a
+    ``ShapeDtypeStruct``; a block's ``None`` dimensions are squeezed.
+    ``head``: see ``_rows_kernel``.  → (stack, *outs)."""
+    H, A, B = stack.shape[2:]
+    S = advance.shape[0]
+
+    def spec(block, index):
+        return pl.BlockSpec(block, lambda s, j, *_: index(s, j))
+
+    tile = pl.BlockSpec((None, None, hb, A, B),
+                        lambda s, j, li, *_: (li[0], s, j, 0, 0))
+    scalars = (jnp.asarray(li, jnp.int32).reshape(1),
+               advance.astype(jnp.int32), fresh.astype(jnp.int32))
+    return pl.pallas_call(
+        functools.partial(_rows_kernel, head=head, n_in=len(ins), hb=hb),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=(S, H // hb),
+            in_specs=[tile] + [spec(b, i) for _, b, i in ins],
+            out_specs=[tile] + [spec(b, i) for _, b, i in outs],
+        ),
+        out_shape=[jax.ShapeDtypeStruct(stack.shape, stack.dtype)]
+        + [o for o, _, _ in outs],
+        input_output_aliases={len(scalars): 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=jax.default_backend() != "tpu",
+        name=name,
+    )(*scalars, stack, *[a for a, _, _ in ins])
+
+
+def _ssm_head(h, old, ins, outs, *, per_group: int):
+    """Mamba-2's head ``h`` of a block: ``old [P, N]`` float32 →
+    ``decay * old + (dt x) (outer) B``, and ``old . C`` down the state's
+    lanes into column ``h`` of the block's reads."""
+    dtx, decay, bc = ins
+    g = h // per_group
+    outs[0][:, h:h + 1] = jnp.sum(old * bc[1, g], axis=1, keepdims=True)
+    return decay[h:h + 1, :] * old + dtx[:, h:h + 1] * bc[0, g]
+
+
+def state_update_in_place(stack, li, x, b, c, dt, a, d_skip, active, replay,
+                          fresh, dims: SSMDims, hb=None):
+    """``state_update`` of layer ``li``'s rows ``stack[li, :S]`` by the
+    Pallas kernel, in place on ``stack [L, S+1, H, P, N]``: a row is
+    read once and written once (XLA's reads it for ``C . S``, then again
+    for the update).  The other arguments as ``state_update``'s.
+    → (y [S, H, P] float32, the stack)."""
+    S, H, P = x.shape
+    N, per_group = dims.state, dims.heads // dims.groups
+    hb = hb or heads_per_step(stack)
+    dt, x32 = dt.astype(F32), x.astype(F32)
+    b32, c32 = b.astype(F32), c.astype(F32)
+    decay = jnp.exp(dt * a)                                     # [S, H]
+    dtx = dt[..., None] * x32                                   # [S, H, P]
+    # a block's groups: whole ones, or the one its heads lie in
+    gb = max(1, hb // per_group)
+    stack, read = update_rows_in_place(
+        functools.partial(_ssm_head, per_group=per_group),
+        stack, li, active & ~replay, fresh,
+        ins=[(head_columns([dtx], hb), (None, None, P, hb),
+              lambda s, j: (s, j, 0, 0)),
+             (jnp.broadcast_to(decay[..., None], (S, H, N)), (None, hb, N),
+              lambda s, j: (s, j, 0)),
+             (jnp.stack([b32, c32], 1)[:, :, :, None], (None, 2, gb, 1, N),
+              lambda s, j: (s, 0, j * hb // (per_group * gb), 0, 0))],
+        outs=[(jax.ShapeDtypeStruct((S, H // hb, P, hb), F32),
+               (None, None, P, hb), lambda s, j: (s, j, 0, 0))],
+        hb=hb, name="ssm_state_update")
+    read = read.transpose(0, 1, 3, 2).reshape(S, H, P)
+    # y off the OLD state, as ``state_update``
+    bc = jnp.repeat(jnp.sum(b32 * c32, -1), per_group, axis=1)   # [S, H]
+    step = decay[..., None] * read + dtx * bc[..., None]
+    y = jnp.where(replay[:, None, None], read, step) \
+        + d_skip.astype(F32)[:, None] * x32
+    return y, stack
 
 
 def chunk_scan(x, b, c, dt, a, d_skip, first, init, dims: SSMDims
