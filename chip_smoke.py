@@ -338,7 +338,8 @@ def kernels_phase(sz, seed):
     T, H, Hkv, D, bs, nb = (c[k] for k in ("T", "H", "Hkv", "D", "block",
                                             "nb"))
     S = 8
-    kq, kc = jax.random.split(k_paged)
+    k_short, k_long, k_state = jax.random.split(k_paged, 3)
+    kq, kc = jax.random.split(k_short)
     q = jax.random.normal(kq, (T, H, D), jnp.bfloat16)
     cache = jax.random.normal(kc, (c["blocks"] + 1, bs, 2, Hkv, D),
                               jnp.bfloat16)
@@ -385,7 +386,7 @@ def kernels_phase(sz, seed):
     c = sz["paged_long"]
     S, H, Hkv, D, bs, nb = (c[k] for k in ("S", "H", "Hkv", "D", "block",
                                             "nb"))
-    kq, kc = jax.random.split(jax.random.fold_in(k_paged, 1))
+    kq, kc = jax.random.split(k_long)
     q = jax.random.normal(kq, (S, H, D), jnp.bfloat16)
     cache = jax.random.normal(kc, (c["blocks"] + 1, bs, 2, Hkv, D),
                               jnp.bfloat16)
@@ -428,7 +429,7 @@ def kernels_phase(sz, seed):
 
     c = sz["state"]
     L, S, H = c["L"], c["S"], c["H"]
-    ks = jax.random.split(jax.random.fold_in(k_paged, 2), 8)
+    ks = jax.random.split(k_state, 8)
     active = jnp.arange(S) != 1
     replay, fresh = jnp.arange(S) == 2, jnp.arange(S) == 3
     P, N, G = c["ssm"]

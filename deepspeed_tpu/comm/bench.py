@@ -151,7 +151,7 @@ def overlap_bench(mesh=None, axis: str = "x", rows: int = 256,
                   dtype: str = "float32",
                   profile_dir: Optional[str] = None) -> Dict:
     """Overlapped-vs-serial matmul+allreduce microbench — the T3 leg
-    (arxiv 2401.16677) the multichip driver and bench.py record.
+    (arxiv 2401.16677) the multichip driver records.
 
     One row-parallel GEMM ([rows, k] x [k, nmodel], contraction sharded
     over ``axis``) under four comm plans: serial psum (the GSPMD
@@ -162,7 +162,7 @@ def overlap_bench(mesh=None, axis: str = "x", rows: int = 256,
     its error bound), so a bench capture that would publish wrong
     numerics fails instead.
 
-    Returns benchdiff-gateable metrics (``*_ms`` down-is-better,
+    Returns metrics with a direction (``*_ms`` down-is-better,
     ``*_speedup`` up) plus the modeled wire-byte halving.  With
     ``profile_dir``, the timed overlapped run executes inside a
     ``jax.profiler`` trace so ``tools/tracemerge`` can render the tile

@@ -23,7 +23,6 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from collections import deque
 from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
@@ -78,7 +77,7 @@ class InferenceConfig:
     # "int8" | "fp8": store the paged KV cache quantized (one scale per
     # written token/head vector, per-block layout).  Halves (int8) the
     # dominant HBM stream of long-context decode; all paged-attention
-    # paths and the decode burst consume it natively (reference analog:
+    # paths consume it natively (reference analog:
     # ZeRO-Inference KV quantization, deepspeed/inference/quantization/)
     kv_quant: Optional[str] = None
     # --- ZeRO-Inference (reference: inference/quantization, README:35) --
@@ -97,31 +96,15 @@ class InferenceConfig:
     # partitioned_param_swapper.py:290 / ZeRO-Inference NVMe): directory
     # to spill the per-layer (quantized, when weight_quant is set)
     # payloads; the forward fetches one layer at a time via io_callback,
-    # so HBM never holds the block weights. Disables decode bursts.
+    # so HBM never holds the block weights.
     weight_stream: Optional[str] = None
-    # device-side decode bursts: run K decode iterations in ONE dispatch
-    # (sampled tokens fed back on-device via lax.scan), amortizing the
-    # host round trip over K tokens.  1 disables.  Sequences that hit
-    # their stop token mid-burst over-generate up to K-1 tokens, which
-    # generate() discards (the usual multi-step-scheduling trade).
-    decode_burst: int = 1
-    # serving-pipeline depth for generate(): 2 keeps one step in flight —
-    # sampling happens INSIDE the jitted step, the sampled token array
-    # stays on device and feeds the next step's batch directly, and the
-    # host schedules/stages step N+1 (and reads step N's tokens back)
-    # while step N computes.  1 is the strict-sync debug mode; both
-    # depths run the same step computation, so outputs are
-    # token-for-token identical.  Sequences that hit their stop token
-    # over-generate one speculative token, which the driver discards
-    # (as decode bursts do).
-    pipeline_depth: int = 2
     # KV-cache donation across steps: "on" aliases the cache in place
     # (the right call wherever HBM is the constraint), "off" lets XLA
     # allocate a fresh result cache per step.  "auto" donates everywhere
-    # EXCEPT a pipelined engine on the CPU backend: XLA:CPU blocks a
-    # dispatch whose donated operand is still being produced by the
-    # in-flight step (measured: chained donated calls serialize at full
-    # step latency), which would silently turn the depth-2 pipeline back
+    # EXCEPT on the CPU backend: XLA:CPU blocks a dispatch whose donated
+    # operand is still being produced by the in-flight step (measured:
+    # chained donated calls serialize at full step latency), which
+    # would silently turn the step that runs ahead (``step()``) back
     # into the synchronous loop.  Host RAM pays one transient cache copy
     # instead.
     kv_donate: str = "auto"
@@ -183,13 +166,13 @@ class InferenceConfig:
     # already-warm program), derived ``serving_mfu`` /
     # ``serving_hbm_bw_util`` pull-gauges computed from the existing
     # step timings at export time, and ``device.memory_stats()`` polled
-    # at phase boundaries (health checks, dumps, bench captures).  Off
-    # by default: the cost probe pays one duplicate compile per program
-    # — "on" is what bench legs and the future autotuner (ROADMAP
-    # item 4) opt into; "auto" defers to the engine and today resolves
-    # OFF.  The compile/retrace COUNTERS, the KV-pool pull-gauges, and
-    # the flight recorder are always on — they are host counter bumps
-    # and read-time probes that cost the hot path nothing.
+    # at phase boundaries (health checks, dumps).  Off by default: the
+    # cost probe pays one duplicate compile per program — "on" is what
+    # the future autotuner (ROADMAP item 4) opts into; "auto" defers to
+    # the engine and today resolves OFF.  The compile/retrace COUNTERS,
+    # the KV-pool pull-gauges, and the flight recorder are always on —
+    # they are host counter bumps and read-time probes that cost the
+    # hot path nothing.
     device_telemetry: str = "auto"
     # streaming anomaly detection (telemetry/anomaly.py,
     # docs/OBSERVABILITY.md "Anomaly detection & deep capture"): EWMA+
@@ -213,8 +196,8 @@ class InferenceConfig:
     # ``<profile>/capture_<n>_<reason>/``, which
     # ``tools/tracemerge.py`` merges into ONE Perfetto timeline.
     # Setting ``profile`` with ``profile_steps > 0`` arms an explicit
-    # window over the first ``profile_steps`` engine steps (the bench
-    # ``--profile`` path); ``profile_steps = 0`` just designates the
+    # window over the first ``profile_steps`` engine steps;
+    # ``profile_steps = 0`` just designates the
     # directory (anomaly-armed captures land there).  Explicit windows
     # can also be armed any time via ``engine.capture(steps=N)``.
     # Backends/builds without profiler support degrade loudly: the
@@ -237,10 +220,8 @@ class InferenceConfig:
     # engine: today it resolves OFF — acceptance is workload-dependent
     # and the autotuner (ROADMAP item 4) is meant to flip it from the
     # measured acceptance_rate/draft-length profiles this engine
-    # records.  Forced off (one shared needs-resident-weights gate with
-    # decode_burst) under weight_stream, and incompatible with
-    # decode_burst > 1 ("on" raises; "auto" quietly defers to bursts —
-    # both paths multi-token the decode, bursts device-side).
+    # records.  Forced off under weight_stream (a verify window is
+    # worthless when each layer streams from NVMe at step latency).
     spec_decode: str = "auto"
     # widest draft window per sequence per verify step; the proposer
     # may draft fewer (budget/context capped), and an empty draft
@@ -280,7 +261,7 @@ class InferenceConfig:
     # enables (requires prefix_cache != "off"); "off" disables; "auto"
     # defers to the engine and today resolves OFF (the tier trades host
     # RAM/disk for recompute — the ROADMAP-4 autotuner is the intended
-    # flipper, and bench.py's tiered_kv leg records the tradeoff).
+    # flipper).
     kv_tier: str = "auto"
     # host-RAM ring budget; overflow spills to kv_tier_dir (if set)
     kv_tier_ram_mb: float = 64.0
@@ -434,7 +415,7 @@ class InferenceEngine:
                                   != "off" and self._recurrent is None)
         # "auto" resolves OFF today — demotion trades host RAM/disk +
         # drain time for saved recompute, a workload call the ROADMAP-4
-        # autotuner (and bench.py's tiered_kv leg) is meant to make
+        # autotuner is meant to make
         if self.icfg.kv_tier == "on":
             from .ragged.tier import KVBlockTier
             self.state.tier = KVBlockTier(
@@ -499,7 +480,6 @@ class InferenceEngine:
         # needs beside its arguments — the layer scan carries the cache
         # in place, so it stays far under one layer's share of the pool
         self.serving_programs: Dict[tuple, Dict] = {}
-        self._burst_fns: Dict[tuple, object] = {}
         # serving programs that have COMPLETED at least one call: only
         # these run under the dispatch watchdog — a first call may
         # carry an unboundedly-slow (and legitimate) compile
@@ -512,13 +492,12 @@ class InferenceEngine:
         self._step_rows = step_rows(self.icfg.max_seqs, self._n_verify,
                                     self.icfg.token_budget)
         self._row_tokens = self._row_slots = 0    # serving_step_row_fill
-        # pipelined-serving state: alternating host staging buffers, the
+        # served-loop state: alternating host staging buffers, the
         # last dispatched step's on-device sample array (the feedback
         # source for the next step), and a zero fallback for step 0
         self._stager = BatchStager(self.icfg.token_budget,
                                    self.icfg.max_seqs,
                                    self.icfg.num_kv_blocks,
-                                   depth=max(2, self.icfg.pipeline_depth),
                                    n_verify=self._n_verify,
                                    n_chunks=0 if self._recurrent is None
                                    else self._recurrent.n_chunks(
@@ -543,7 +522,7 @@ class InferenceEngine:
         # result is void (uid -> sid; hold())
         self._cont: Dict[int, int] = {}
         self._ahead: Optional[_InFlight] = None
-        self._held: Dict[int, int] = {}
+        self._held: Dict[int, List[int]] = {}
         self._void: Dict[int, int] = {}
         self._zero_key = jax.random.PRNGKey(0)
         # --- overload policy state (inference/overload.py) -------------
@@ -601,9 +580,6 @@ class InferenceEngine:
             raise ValueError(
                 "kv_tier='on': " + why + "a restaged block chain carries "
                 "no state")
-        if icfg.decode_burst > 1:
-            raise ValueError("decode_burst > 1: " + why + "decode bursts "
-                             "serve a model of one block type")
         if topology is not None and topology.device_count > 1:
             raise NotImplementedError(
                 "serving over a mesh: " + why + "the mixer is not sharded")
@@ -860,8 +836,8 @@ class InferenceEngine:
                     "serving_moe_assignments_total",
                     "(token, expert) assignments the serving steps "
                     "computed, summed over the layers: every real "
-                    "token's top-k, none dropped (decode bursts are not "
-                    "counted).  A model that holds a share of its experts "
+                    "token's top-k, none dropped.  A model that holds a "
+                    "share of its experts "
                     "(experts_held) labels them where: held = computed "
                     "here | absent = made by the router for experts that "
                     "are not here"),
@@ -1230,8 +1206,8 @@ class InferenceEngine:
         batch staging, the jitted call (pure enqueue when dispatch is
         async; the whole device step when something — e.g. CPU-backend
         donation — forces it synchronous), the wait for the collected
-        step's sample array, and the pure device->host fetch.  A
-        pipelined engine's per-step critical-path host overhead is
+        step's sample array, and the pure device->host fetch.  The
+        served loop's per-step critical-path host overhead is
         roughly wall/steps - (device_ms + wait_ms)/steps.
 
         Also zeroes the token counters: ``prompt_tokens`` (total prompt
@@ -1250,7 +1226,7 @@ class InferenceEngine:
     def reset_metrics(self) -> None:
         """Full telemetry reset: every registry metric (timings view
         included), the request-lifecycle tracker, and the span ring —
-        what a bench leg calls between warmup and its timed region."""
+        what a benchmark calls between warmup and its timed region."""
         self.metrics.reset()
         self._group_blocks = self._group_slots = 0
         self._row_tokens = self._row_slots = 0
@@ -1275,14 +1251,14 @@ class InferenceEngine:
     def device_snapshot(self) -> Optional[Dict]:
         """JSON-able device-telemetry summary (per-program cost
         analysis, derived MFU / HBM-bandwidth utilization, last memory
-        poll) — what bench legs embed next to their request-metrics
+        poll) — what a benchmark embeds next to its request-metrics
         aggregates.  None when ``device_telemetry`` is off."""
         return None if self.devtel is None else self.devtel.snapshot()
 
     def anomaly_summary(self) -> Optional[Dict]:
         """JSON-able anomaly tally — total fires, per-signal counts,
         the most recent events, and the completed capture-window dirs
-        — what bench legs and the loadgen SLO sweep embed.  None when
+        — what the loadgen SLO sweep embeds.  None when
         anomaly detection is off."""
         if self._anom is None:
             return None
@@ -1483,10 +1459,9 @@ class InferenceEngine:
             self.params, self._quant = quantize_model_params(
                 self.params, bits=WEIGHT_QUANT_BITS[self.icfg.weight_quant],
                 quantize_embeddings=self.icfg.quantize_embeddings)
-            # step/burst closures hold the old quant tree
+            # step closures hold the old quant tree
             self._pstep_fns.clear()
             self.serving_programs.clear()
-            self._burst_fns.clear()
             # the rebuilt programs recompile on their next call: they
             # are cold again (warm programs run under the watchdog,
             # and a deadline must never time an XLA compile)
@@ -1705,34 +1680,6 @@ class InferenceEngine:
                 for qt in grp.values())
         store.spill(record)
         self._stream = store
-        self._force_resident_weight_modes()
-
-    def _force_resident_weight_modes(self) -> None:
-        """THE needs-resident-weights gate: every decode mode that runs
-        multiple model invocations per host round trip — device-side
-        bursts (the scan feeds weights per iteration) and speculative
-        verify windows (worthless when each layer streams from NVMe at
-        step latency anyway) — is forced off in ONE place when
-        ``weight_stream`` keeps block weights non-resident.  New modes
-        with the same requirement belong here, not in a copy-pasted
-        warning branch."""
-        forced = {}
-        if self.icfg.decode_burst > 1:
-            forced["decode_burst"] = 1
-        if self.icfg.spec_decode == "on":
-            # "auto" stays untouched: it resolves off today, silently —
-            # an auto that learns to turn itself on (ROADMAP item 4)
-            # must consult this gate in _setup_spec_decode
-            forced["spec_decode"] = "off"
-        if forced:
-            logger.warning(
-                "weight_stream: "
-                + " and ".join(f"{k}={getattr(self.icfg, k)!r}"
-                               for k in forced)
-                + (" need" if len(forced) > 1 else " needs")
-                + " resident weights; forcing "
-                + ", ".join(f"{k}={v!r}" for k, v in forced.items()))
-            self.icfg = dataclasses.replace(self.icfg, **forced)
 
     def _setup_spec_decode(self) -> None:
         """Resolve the ``spec_decode`` config to a proposer (or None)
@@ -1743,10 +1690,13 @@ class InferenceEngine:
         if mode not in ("auto", "on", "off"):
             raise ValueError(f"spec_decode={mode!r}: expected 'auto', "
                              "'on', or 'off'")
-        if mode == "on" and self.icfg.decode_burst > 1:
-            raise ValueError(
-                "spec_decode='on' with decode_burst > 1: both multi-token"
-                " the decode path (bursts device-side); pick one")
+        if mode == "on" and self._stream is not None:
+            # a verify window is worthless when each layer streams from
+            # NVMe at step latency anyway ("auto" resolves off silently)
+            logger.warning("weight_stream: spec_decode='on' needs "
+                           "resident weights; forcing spec_decode='off'")
+            mode = "off"
+            self.icfg = dataclasses.replace(self.icfg, spec_decode=mode)
         # "auto" currently resolves OFF: draft acceptance is workload-
         # dependent, and the per-request acceptance_rate / draft-length
         # profiles recorded below are exactly the measured signal the
@@ -1807,18 +1757,12 @@ class InferenceEngine:
         """Whether serving programs donate the paged cache.  See
         ``InferenceConfig.kv_donate``: donation on XLA:CPU blocks each
         dispatch until the in-flight producer of the donated cache
-        finishes, so a pipelined CPU engine trades one transient cache
-        copy for async dispatch."""
+        finishes, so a CPU engine trades one transient cache copy for
+        async dispatch."""
         mode = self.icfg.kv_donate
-        if mode == "off":
-            return False
-        if mode == "auto" and self.icfg.pipeline_depth >= 2 \
-                and self.icfg.decode_burst <= 1 \
-                and jax.default_backend() == "cpu":
-            # burst engines route generate() to the strict-sync driver,
-            # so their steps never pipeline — keep donating for them
-            return False
-        return True
+        if mode == "auto":
+            return jax.default_backend() != "cpu"
+        return mode != "off"
 
     def _serving_jit(self, fn, kv_argnum: int = 2,
                      kv_only_output: bool = False):
@@ -1846,11 +1790,11 @@ class InferenceEngine:
         (sparse-expert models): a third output, the experts each row
         took in each expert layer (``ragged_forward``).
 
-        Steps are compiled per power-of-two context bucket (like the
-        decode-burst prefix buckets): the XLA attention paths do work
-        proportional to the compiled block bound, so early prefill steps
-        must not pay for the engine's maximum context (the Pallas kernel
-        skips dead blocks dynamically; the dense paths cannot)."""
+        Steps are compiled per power-of-two context bucket: the XLA
+        attention paths do work proportional to the compiled block
+        bound, so early prefill steps must not pay for the engine's
+        maximum context (the Pallas kernel skips dead blocks
+        dynamically; the dense paths cannot)."""
         cfg = self.cfg
         bs = self.icfg.kv_block_size
         fw, mbs = self._resolve_fw(mbs)
@@ -2120,8 +2064,8 @@ class InferenceEngine:
 
     def _drain_reaped(self) -> set:
         """Uids the ENGINE terminally closed since the last call
-        (deadline expiry, ``cancel()``, shed-by-eviction) — the
-        ``generate()`` drivers drop them from their active sets;
+        (deadline expiry, ``cancel()``, shed-by-eviction, context
+        exhausted) — ``generate()`` drops them from its active set;
         direct-API callers can poll ``query()["status"]`` instead."""
         out = self._reaped
         self._reaped = set()
@@ -2161,8 +2105,7 @@ class InferenceEngine:
         }
 
     # ------------------------------------------------------------------
-    def _schedule(self, room: Optional[Dict[int, int]] = None
-                  ) -> List[tuple]:  # tpulint: serving-loop
+    def _schedule(self) -> List[tuple]:  # tpulint: serving-loop
         """Dynamic SplitFuse + overload policy: pack the fixed token
         budget — decode tokens first (latency), then prompt chunks
         (throughput) — while *reserving* KV blocks and slots as requests
@@ -2187,11 +2130,10 @@ class InferenceEngine:
         room.  With the default config every knob is inert and this is
         exactly the legacy FIFO SplitFuse packer.
 
-        ``room``: uid -> tokens its driver still wants (generate()'s
-        count-based stop).  A verify window emits up to 1 + len(draft)
-        tokens, so drafts are capped to fit: the engine never emits —
-        or counts — a token its driver would discard (bursts are capped
-        by the same room in ``_generate_sync``)."""
+        A verify window emits up to 1 + len(draft) tokens, so the
+        drafts of a request the engine continues are capped by what it
+        may still emit (``_cont``): the engine never emits — or counts
+        — a token past ``max_new_tokens``."""
         budget = self.icfg.token_budget
         bs = self.icfg.kv_block_size
         ocfg = self.ocfg
@@ -2284,8 +2226,8 @@ class InferenceEngine:
                 # spec_max_draft — drafts compete with prefill chunks
                 # for the same fixed SplitFuse budget
                 limit = min(self._n_verify - 1, budget - 1, ctx_rem - 1)
-                if room is not None and uid in room:
-                    limit = min(limit, room[uid] - 1)
+                if uid in self._cont:
+                    limit = min(limit, self._cont[uid] - 1)
                 if limit > 0:
                     draft = self._spec.propose(uid, toks[0], limit)
             n = min(len(toks), budget, ctx_rem)
@@ -2356,14 +2298,6 @@ class InferenceEngine:
                 continue
             if probe_allowed is not None and uid not in probe_allowed:
                 continue
-            if t[0] == FEEDBACK_TOKEN \
-                    and self._fb_step.get(uid) != self._dispatch_seq:
-                # deferred sample owned by an OLDER still-uncollected
-                # step (possible at pipeline_depth >= 3 when the budget
-                # starves a decode for a step): the jitted feedback path
-                # only sees the last dispatch's sample array, so hold the
-                # request until its owner's collect patches it concrete
-                continue
             m = self._meta.get(uid)
             # aged priority: waiting promotes a tier per aging_ms, so a
             # low tier is delayed under load but never starved.  Equal
@@ -2406,8 +2340,8 @@ class InferenceEngine:
         """``(uid, raw_priority, n_blocks)`` for every live sequence
         preemption may legally evict: nothing scheduled this round or
         still in flight (its KV rows are being written), nothing whose
-        KV contents the host cannot reconstruct (broken chain — decode
-        bursts, or a deferred on-device token), nothing already at the
+        KV contents the host cannot reconstruct (a broken chain, or a
+        deferred on-device token), nothing already at the
         context limit (re-queueing it would re-prefill to exhaustion)."""
         out = []
         for uid, seq in self.state.seqs.items():
@@ -2488,13 +2422,11 @@ class InferenceEngine:
         """Terminally close context-exhausted sequences once nothing is
         in flight for them (status ``context_exhausted``) — without this
         the direct step() API leaks their open lifecycle records
-        forever.  Closure reaps the uid (``_drain_reaped`` tells the
-        sync generate() driver) and ``_forget`` drops it from
+        forever.  Closure reaps the uid (``_drain_reaped`` tells
+        ``generate()``) and ``_forget`` drops it from
         ``_ctx_exhausted``, so the set never grows without bound under
         long direct-API traffic and a later reused uid is not
-        permanently unschedulable.  (The pipelined driver never calls
-        this: it drains the set itself and finishes those requests
-        through its own flush.)"""
+        permanently unschedulable."""
         for uid in list(self._ctx_exhausted):
             if uid not in self.state.seqs:
                 # closed through another exit path (flush/cancel/...)
@@ -2718,8 +2650,8 @@ class InferenceEngine:
     def finish_capture(self) -> Optional[str]:
         """Close any ACTIVE capture window immediately with the steps
         it has (the artifact is written; the jax profiler session and
-        the force-enabled tracer are released).  The generate()
-        drivers and ``drain()`` call this when their work runs out —
+        the force-enabled tracer are released).  ``generate()`` and
+        ``drain()`` call this when their work runs out —
         a window armed for more steps than the workload will run must
         not strand the process-wide profiler session — and direct
         step()-API callers can call it themselves.  Returns the
@@ -2871,8 +2803,8 @@ class InferenceEngine:
         replayable token stream (KV chain + still-pending tokens),
         generated output so far, and admission metadata — the unit of
         currency snapshots, drains, and fleet migrations all move.  A
-        stream the host cannot replay (broken chain — decode bursts,
-        an in-flight feedback marker) is recorded ``exact: False``."""
+        stream the host cannot replay (a broken chain, an in-flight
+        feedback marker) is recorded ``exact: False``."""
         seq = self.state.seqs.get(uid)
         pend = [int(t) for t in self._pending.get(uid, [])]
         gen = list(self._preempt_gen.get(uid, []))
@@ -2919,9 +2851,9 @@ class InferenceEngine:
         that is the warm-restart story: catch
         :class:`EngineDeadError`, ``snapshot()``, ``restore()``.  Take
         it at a step boundary (no dispatched-but-uncollected step); a
-        sequence whose stream the host cannot replay (broken chain —
-        decode bursts, an in-flight feedback marker) is recorded
-        ``exact: False`` and closed ``failed`` at restore."""
+        sequence whose stream the host cannot replay (a broken chain,
+        an in-flight feedback marker) is recorded ``exact: False`` and
+        closed ``failed`` at restore."""
         from .. import __version__
         self._settle()
         now = time.perf_counter()
@@ -3265,8 +3197,14 @@ class InferenceEngine:
         sequence (an accepted verify window); the returned token is the
         LAST one — exactly the right continuation to feed back via
         ``put`` — and the full stream accumulates on the sequence
-        (``query()["generated"]``).  The generate() drivers consume the
-        full per-step lists internally."""
+        (``query()["generated"]``); :meth:`generate` reads the whole
+        lists (:meth:`_step`)."""
+        return self._last(self._step(rng, sampling))
+
+    def _step(self, rng: Optional[jax.Array], sampling: SamplingParams
+              ) -> Dict[int, List[int]]:  # tpulint: serving-loop
+        """:meth:`step` with every token the call emitted, a LIST per
+        uid (several for a resolved verify window)."""
         if self._held:
             # a launch read back outside step() (snapshot, a failed
             # launch behind it): its tokens are handed over first
@@ -3279,16 +3217,15 @@ class InferenceEngine:
             # a caller-fed request waits: read the launch in flight
             # back now, its step is launched strict by the next call
             self._ahead = None
-            return self._last(self._collect(prev))
-        st = self._dispatch(sampling, rng, self._cont or None)
+            return self._collect(prev)
+        st = self._dispatch(sampling, rng)
         if prev is not None and self._ahead is None:
             # the launch failed, and its failure path read ``prev`` back
             out, self._held = self._held, {}
             return out
         if st is None:
             self._ahead = None
-            return self._last(self._collect(prev)) if prev is not None \
-                else {}
+            return self._collect(prev) if prev is not None else {}
         if prev is None:
             why = "spec_decode" if self._spec is not None \
                 else "probe" if self._probe_groups \
@@ -3304,7 +3241,7 @@ class InferenceEngine:
                             and uid in self.state.seqs \
                             and not self._pending.get(uid):
                         self._pending[uid] = [toks[-1]]
-                return self._last(out)
+                return out
             self._c_strict.inc(reason="idle")
         else:
             self._c_ahead.inc()
@@ -3317,7 +3254,7 @@ class InferenceEngine:
         self._ahead = st
         if prev is None:
             return {}
-        return self._last(self._collect(prev, nxt=st))
+        return self._collect(prev, nxt=st)
 
     @staticmethod
     def _last(out: Dict[int, List[int]]) -> Dict[int, int]:
@@ -3333,10 +3270,10 @@ class InferenceEngine:
     def _settle(self) -> None:
         """Read the launch :meth:`step` left in flight back now, at a
         boundary that needs every request's stream on the host
-        (snapshot, migration, hand-off, a weight refresh, a capture, a
-        drain's end, another driver taking over).  Its tokens are
-        emitted as ever and handed to the caller by the next
-        :meth:`step`; a dead engine's launch is dropped unread."""
+        (snapshot, migration, hand-off, a weight refresh, a capture, the
+        end of a drain or of :meth:`generate`).  Its tokens are emitted
+        as ever and handed to the caller by the next :meth:`step`; a
+        dead engine's launch is dropped unread."""
         st, self._ahead = self._ahead, None
         if st is None:
             return
@@ -3344,33 +3281,23 @@ class InferenceEngine:
             self._uncount_inflight(st.uids)
             return
         try:
-            self._held.update(self._last(self._collect(st)))
+            self._held.update(self._collect(st))
         except EngineDeadError:
             pass        # the host's truth stands; the caller reads it
 
-    @staticmethod
-    def _rng_drawer(rng: Optional[jax.Array]):
-        """None, or a zero-arg callable yielding the BASE sampling key
-        for each dispatched step.  An explicit caller key is reused
-        verbatim for every step of the call: per-token randomness comes
-        from the (uid, position) fold inside the jitted step
-        (``sampler.row_keys``), which makes seeded outputs
-        schedule-invariant — pipeline depth, prompt chunking, decode
-        bursts, and prefix-cache hits all change the step stream, but
-        never a token's folded key."""
-        if rng is None:
-            return None
-        return lambda: rng
-
-    def _dispatch(self, sampling: SamplingParams, rng=None,
-                  room: Optional[Dict[int, int]] = None
+    def _dispatch(self, sampling: SamplingParams,
+                  rng: Optional[jax.Array] = None
                   ) -> Optional[_InFlight]:  # tpulint: serving-loop
         """Schedule, stage, and launch one serving step WITHOUT reading
         the sampled tokens back; returns the in-flight record (tokens
         still on device) or None when nothing is schedulable.  ``rng``:
-        an explicit PRNG key, a zero-arg callable invoked only once a
-        step is known to launch, or None (engine-internal key stream
-        when the sampler needs one).  ``room``: see ``_schedule``."""
+        an explicit PRNG key, used verbatim — per-token randomness is
+        the (uid, position) fold inside the jitted step
+        (``sampler.row_keys``), which makes seeded outputs
+        schedule-invariant: prompt chunking, the step that runs ahead
+        and prefix-cache hits all change the step stream, but never a
+        token's folded key — or None (engine-internal key stream when
+        the sampler needs one)."""
         self._ensure_alive()
         # the step's phases are live spans (telemetry/tracer.py): each
         # cut below ends one phase, begins the next, and returns the one
@@ -3378,7 +3305,7 @@ class InferenceEngine:
         tr = self.tracer
         sid = self._dispatch_seq + 1
         t0 = tr.phase("ds.serve.schedule", track="schedule", sid=sid)
-        sched = self._schedule(room)
+        sched = self._schedule()
         self._close_ctx_exhausted()
         if not sched:
             # an idle round still moves tier work: evictions queued by
@@ -3474,8 +3401,6 @@ class InferenceEngine:
                       rows=n_rows, n_seqs=len(sched),
                       n_decode=sum(1 for _, t in sched if len(t) == 1),
                       mbs=mbs, ahead=int(bool(self._inflight_sched)))
-        if callable(rng):
-            rng = rng()
         if rng is None and sampling.needs_rng:
             self._rng, rng = jax.random.split(self._rng)
         if rng is None:
@@ -3514,9 +3439,9 @@ class InferenceEngine:
                 # in flight elsewhere.  Should that read fail too, it
                 # takes this step's rows with it (_collect, ``nxt``)
                 retries = self.timings["step_retries"]
-                self._held.update(self._last(self._collect(
+                self._held.update(self._collect(
                     prev, nxt=_InFlight(toks=None, emit=(), sid=sid,
-                                        uids=uids, registered=registered))))
+                                        uids=uids, registered=registered)))
                 if self.timings["step_retries"] != retries:
                     return None
             self._handle_step_failure(e, uids, "dispatch",
@@ -3724,9 +3649,8 @@ class InferenceEngine:
 
     def _fetch_tokens(self, arr) -> np.ndarray:  # tpulint: serving-loop
         """THE sanctioned serving-loop readback: every device->host token
-        fetch (step collect, decode bursts) funnels through here so the
-        ``serving-sync`` lint rule can keep ad-hoc syncs off the decode
-        critical path."""
+        fetch funnels through here so the ``serving-sync`` lint rule can
+        keep ad-hoc syncs off the decode critical path."""
         return np.asarray(arr)  # tpulint: disable=serving-sync
 
     def _collect(self, st: _InFlight, nxt: Optional[_InFlight] = None
@@ -3924,450 +3848,53 @@ class InferenceEngine:
         return out
 
     # ------------------------------------------------------------------
-    # device-side decode bursts
-    # ------------------------------------------------------------------
-    def _build_burst(self, steps: int, sampling: SamplingParams, P: int):
-        """One jitted burst program per (steps, sampling, prefix bucket):
-        gather a dense READ-ONLY prefix of every live context, scan
-        ``steps`` decode iterations carrying only the tiny in-burst KV
-        tail, then scatter the tail into the (donated) paged cache.
-        Carrying the paged cache itself through this scan copied the
-        full pool every iteration on an older rig (~80 ms/iter for a
-        GPT-2-sized pool) and the prefix/tail split removed that.  The
-        step's layer scan has since shown that the TPU compiler keeps a
-        carried pool in place when the body writes it and then reads it
-        once (``ragged_forward``: 39 ms of a 112 ms decode step back on
-        a v5e); the burst keeps its split, which also spares the
-        block-table indirection, and has not been retried carried."""
-        from .model import (decode_burst_forward, scatter_tail,
-                            snapshot_prefix)
-
-        cfg = self.cfg
-        bs = self.icfg.kv_block_size
-
-        def sample_fn(logits, keys):
-            return sample_rows(logits, sampling, keys)
-
-        # quant is a jit argument (closure capture would bake the whole
-        # quantized model into the HLO as constants — see _build_step)
-        def burst(params, quant, kv, block_tables, base_ctx, token0, uids,
-                  rng):
-            prefix = snapshot_prefix(kv, block_tables, P, bs)
-            toks, tail = decode_burst_forward(
-                cfg, params, prefix, base_ctx, token0, steps, sample_fn,
-                rng, uids=uids, quant=quant,
-                mixed_gemm=self._mixed_gemm_active,
-                sharded=self._tp_mesh is not None)
-            kv = scatter_tail(kv, tail, block_tables, base_ctx, bs)
-            return toks, kv
-
-        jit_kw = {}
-        if self._kv_nsh is not None:
-            jit_kw["out_shardings"] = (self._repl, self._kv_nsh)
-        return jax.jit(burst, donate_argnums=(2,), **jit_kw)
-
-    def decode_burst(self, steps: Optional[int] = None,
-                     sampling: SamplingParams = SamplingParams(),
-                     rng: Optional[jax.Array] = None
-                     ) -> Dict[int, List[int]]:  # tpulint: serving-loop
-        """Run ``steps`` decode iterations in ONE device dispatch: the
-        sampled token feeds the next forward on-device (lax.scan), so the
-        host round trip — which dominates decode latency on
-        high-latency links — is paid once per burst instead of once per
-        token.  All pending requests must be single-token continuations
-        of live sequences (pure decode); KV blocks for the whole burst
-        are pre-reserved host-side.  Returns {uid: [token, ...]}."""
-        self._ensure_alive()
-        self._settle()
-        steps = steps or max(1, self.icfg.decode_burst)
-        pending = {u: t for u, t in self._pending.items() if t}
-        if not pending:
-            return {}
-        if any(len(t) != 1 or t[0] < 0 or u not in self.state.seqs
-               for u, t in pending.items()):
-            raise ValueError("decode_burst requires every pending request "
-                             "to be a single-token continuation (with a "
-                             "concrete, non-deferred token id); use "
-                             "step() for prefill")
-        if self._stream is not None:
-            # bursts need the block weights resident (streamed layers
-            # cannot feed the burst scan) — degrade to single steps
-            out = self.step(rng=rng, sampling=sampling)
-            return {u: [t] for u, t in out.items()}
-        # cap the burst by context headroom, then reserve its KV blocks
-        steps = min([steps] + [self.state.context_remaining(u)
-                               for u in pending])
-        # shrink the burst until the whole reservation fits the free
-        # pool (the stepwise scheduler degrades the same way — a burst
-        # must never crash a workload step() would survive)
-        bs_blk = self.icfg.kv_block_size
-        while steps > 1:
-            need = sum(self.state.seqs[u].blocks_needed(steps, bs_blk)
-                       for u in pending)
-            if need <= self.state.allocator.free_blocks:
-                break
-            steps -= 1
-        if steps <= 1:
-            out = self.step(rng=rng, sampling=sampling)
-            return {u: [t] for u, t in out.items()}
-        for uid in pending:
-            if not self.state.reserve_ahead(uid, steps):
-                raise RuntimeError(      # unreachable after the fit check
-                    f"uid {uid}: cannot reserve {steps} tokens of KV")
-
-        capw = self._cap
-        if capw is not None and capw.armed:
-            # capture windows count bursts as one step each (the one
-            # profiler seam — profile_decode8b drives this path)
-            capw.begin(sid=self._dispatch_seq, step=self._steps_done)
-        # same bracket as _dispatch: demote reads, then COW copies, then
-        # restage uploads — all enqueued before the burst's writes
-        self._drain_tier_demote()
-        self._drain_cow()        # pending COW copies precede burst writes
-        self._drain_tier_restage(dispatching=True)
-        st = self.state
-        S = self.icfg.max_seqs
-        base = np.zeros(S, np.int32)
-        tok0 = np.zeros(S, np.int32)
-        uids_arr = np.zeros(S, np.uint32)
-        tables = np.full((S, self.icfg.num_kv_blocks), -1, np.int32)
-        for uid in pending:
-            slot = st.slot(uid)
-            seq = st.seqs[uid]
-            base[slot] = seq.seen_tokens
-            tok0[slot] = pending[uid][0]
-            uids_arr[slot] = np.uint32(uid & 0xFFFFFFFF)
-            tables[slot, :len(seq.blocks)] = seq.blocks
-        # prefix bucket: geometric (doubling) block-aligned sizes, so a
-        # 32k-context engine compiles O(log) burst programs, not one per
-        # 256 tokens of context growth
-        chunk = self.icfg.kv_block_size * max(
-            1, -(-256 // self.icfg.kv_block_size))
-        cap = self.max_blocks_per_seq * self.icfg.kv_block_size
-        P = chunk
-        while P < min(int(base.max()), cap):
-            P *= 2
-        P = int(min(P, cap))
-
-        key = (steps, sampling, P)
-        if key not in self._burst_fns:
-            if len(self._burst_fns) >= 8:     # bound retained executables
-                evicted = next(iter(self._burst_fns))
-                self._burst_fns.pop(evicted)
-                self._warm_keys.discard(("b", evicted))
-            self._burst_fns[key] = self._build_burst(steps, sampling, P)
-            self._note_compile("b", key)
-        burst_cold = ("b", key) not in self._warm_keys
-        if rng is None:
-            self._rng, rng = jax.random.split(self._rng)
-        tr = self.tracer
-        guard: Dict[str, float] = {}      # the watchdog's hand-off time
-        t0 = tr.phase("ds.serve.burst", track="dispatch", steps=steps,
-                      n_seqs=len(pending))
-        burst_fn = self._burst_fns[key]
-        # staging runs INSIDE the guarded call: a device error (or
-        # hang) during the host->device transfers must route through
-        # the watchdog + classifier like the dispatch itself.  The
-        # staged operands are kept for the one-time cost probe below
-        staged_box: List[tuple] = []
-
-        def _staged_burst():
-            staged = (self._stage(jnp.asarray(tables)),
-                      self._stage(jnp.asarray(base)),
-                      self._stage(jnp.asarray(tok0)),
-                      self._stage(jnp.asarray(uids_arr)),
-                      self._stage(rng))
-            staged_box.append(staged)
-            return burst_fn(self.params, self._quant, self.state.kv,
-                            *staged)
-
-        try:
-            toks, self.state.kv = self.failures.run(
-                _staged_burst, uids=tuple(pending), cold=burst_cold,
-                site="burst", sid=self._dispatch_seq, stamps=guard)
-            hop_us = guard.get("hop_us", 0.0)
-            tr.phase_set(hop_us=round(hop_us, 1))
-            t1 = tr.phase("ds.serve.burst_readback", track="readback",
-                          steps=steps)
-            toks_np = self._fetch_tokens(toks)         # ONE fetch
-        except Exception as e:
-            tr.phase_end(failed=type(e).__name__)
-            # blocks reserved ahead for the burst release with the
-            # re-queue; seen_tokens was not advanced yet, so a
-            # resumable chain re-prefills token-identically (the fetch
-            # rides the same seam: a transfer failure degrades too)
-            self._handle_step_failure(e, tuple(pending), "burst")
-            return {}
-        self._warm_keys.add(("b", key))
-        if burst_cold:
-            self.timings["compile_ms"] += (t1 - t0) * 1e3
-            if self.devtel is not None and staged_box:
-                self.devtel.probe_program(
-                    ("b",) + key, burst_fn,
-                    (self.params, self._quant, self.state.kv)
-                    + staged_box[-1])
-        if self.devtel is not None:
-            # one burst = `steps` model invocations of this program's
-            # scan body; cost_analysis already prices the WHOLE scan,
-            # so the program cost is attributed once per dispatch
-            self.devtel.on_dispatch(("b",) + key)
-        self._steps_done += steps
-        # burst success resets escalation/strikes like a collected
-        # step — without this a burst-heavy workload would count
-        # expiries thousands of clean bursts apart as "consecutive"
-        self._note_step_success(tuple(pending))
-        t2 = tr.phase_end()
-        self._c_guard_hop.inc(hop_us / 1e3)
-        if capw is not None and capw.active:
-            fin = capw.end_step(sid=self._dispatch_seq,
-                                step=self._steps_done)
-            if fin is not None:
-                self._finish_capture(fin)
-        tm = self.timings
-        out: Dict[int, List[int]] = {}
-        for uid in pending:
-            slot = st.slot(uid)
-            seq_toks = [int(t) for t in toks_np[:, slot]]
-            adv = steps
-            if sampling.stop_token is not None \
-                    and sampling.stop_token in seq_toks:
-                # truncate at the stop token so direct-API callers never
-                # see an over-advanced context: KV rows written = the fed
-                # token + sampled tokens before the stop
-                i = seq_toks.index(sampling.stop_token)
-                seq_toks = seq_toks[:i + 1]
-                adv = i + 1
-            st.seqs[uid].tokens.extend(seq_toks)
-            # emitted to a live sequence: the engine counter and the
-            # request record move together (the same parity invariant
-            # _collect holds — tests/test_telemetry.py)
-            tm["generated_tokens"] += len(seq_toks)
-            self.requests.on_tokens(uid, len(seq_toks), t2, t_dispatch=t0)
-            # the burst wrote `steps` KV rows (fed token + first steps-1
-            # sampled); only the pre-stop prefix is committed
-            st.advance(uid, adv)
-            self._pending[uid] = []
-            out[uid] = seq_toks
-        return out
-
-    # ------------------------------------------------------------------
     def generate(self, prompts: Dict[int, Sequence[int]],
                  sampling: SamplingParams = SamplingParams(),
                  rng: Optional[jax.Array] = None
                  ) -> Dict[int, List[int]]:  # tpulint: serving-loop
-        """Convenience loop: run all prompts to max_new_tokens/stop.
-        With ``InferenceConfig.decode_burst > 1``, decode-only rounds run
-        as device-side bursts; otherwise ``pipeline_depth >= 2`` (the
-        default) keeps one step in flight — host scheduling/staging and
-        token readback overlap device compute, and the sampled-token
-        array feeds the next step on device."""
-        self._settle()      # another driver's launch is read back first
+        """Convenience loop: run all prompts to max_new_tokens/stop over
+        the served path — every prompt is put with ``max_new_tokens``
+        and :meth:`step` runs one launch ahead until each request of
+        the call is closed.  A stream that stops is flushed, and the
+        token launched ahead for it is thrown away at its read
+        (``serving_ahead_discarded_rows_total{reason="finished"}``): the
+        caller sees no token past the stop and never more than
+        ``max_new_tokens``."""
         done: Dict[int, List[int]] = {}
         active = set()
         for uid, p in prompts.items():
             done[uid] = []
-            if self.put(uid, p):
+            if self.put(uid, p, max_new_tokens=sampling.max_new_tokens):
                 # under a bounded admission queue a prompt may be shed
                 # at put() time — its row stays empty (query() says why)
                 active.add(uid)
-        if self.icfg.decode_burst <= 1 and self.icfg.pipeline_depth >= 2:
-            return self._generate_pipelined(done, active, sampling, rng)
-        return self._generate_sync(done, active, sampling, rng)
-
-    def _draft_room(self, done: Dict[int, List[int]], active: set,
-                    sampling: SamplingParams) -> Optional[Dict[int, int]]:
-        """uid -> tokens still wanted, for ``_schedule``'s draft cap
-        (None on a non-speculative engine: nothing to cap).  Exact at
-        schedule time: a row only drafts from a concrete fed token,
-        which its collect put AFTER extending ``done``."""
-        if self._spec is None:
-            return None
-        return {u: sampling.max_new_tokens - len(done[u]) for u in active}
-
-    def _generate_sync(self, done: Dict[int, List[int]], active: set,
-                       sampling: SamplingParams,
-                       rng: Optional[jax.Array]
-                       ) -> Dict[int, List[int]]:  # tpulint: serving-loop
-        """Strict step-at-a-time driver (``pipeline_depth=1`` debug mode,
-        and the burst dispatcher when ``decode_burst > 1``)."""
-        i = 0
-        draw = self._rng_drawer(rng)
+        idle = 0
         while active:
+            out = self._step(rng, sampling)
             # engine-side terminal closures (deadline expiry, cancel,
-            # shed-by-eviction) end those requests' generation here
+            # shed-by-eviction, context exhausted) end those requests'
+            # generation here
             active -= self._drain_reaped()
-            if not active:
-                break
-            pending = {u: t for u, t in self._pending.items() if t}
-            decode_only = pending and all(
-                len(t) == 1 and u in self.state.seqs
-                for u, t in pending.items())
-            burst = 1
-            if decode_only and self.icfg.decode_burst > 1:
-                # pending uids fed via put() outside this generate() call
-                # have no 'done' row; default=0 forces burst=1 for them
-                room = min((sampling.max_new_tokens - len(done[u])
-                            for u in pending if u in done), default=0)
-                # only burst at the full configured width: a shrinking
-                # tail would mint one compiled program per remaining-K
-                burst = (self.icfg.decode_burst
-                         if room >= self.icfg.decode_burst else 1)
-            if burst > 1:
-                outs = self.decode_burst(burst, sampling=sampling,
-                                         rng=draw() if draw else None)
-            else:
-                # dispatch + collect directly: a verify window's step
-                # emits a LIST per uid and every token must reach done
-                st = self._dispatch(sampling, draw,
-                                    self._draft_room(done, active, sampling))
-                outs = self._collect(st) if st is not None else {}
-            # sequences that hit the context limit end their generation
-            for uid in list(self._ctx_exhausted):
-                if uid in active:
-                    active.discard(uid)
-                    self.flush(uid)
-                self._ctx_exhausted.discard(uid)
-            for uid, toks in outs.items():
+            for uid, toks in out.items():
                 if uid not in active:
-                    continue
-                finished = False
-                for tok in toks:
-                    done[uid].append(tok)
-                    stop = (sampling.stop_token is not None
-                            and tok == sampling.stop_token)
-                    if stop or len(done[uid]) >= sampling.max_new_tokens:
-                        finished = True
-                        break
-                if finished:
+                    continue               # put() outside generate()
+                row = done[uid]
+                row.extend(toks)
+                if sampling.stop_token in toks \
+                        or len(row) >= sampling.max_new_tokens:
                     active.discard(uid)
                     self.flush(uid)
-                else:
-                    self.put(uid, [toks[-1]])
-            i += 1
-            if i > 100_000:
+            # nothing read and nothing in flight: either every remaining
+            # request just finished above, or the pool is wedged
+            idle = 0 if out or self.in_flight else idle + 1
+            if idle > 100_000:
                 raise RuntimeError("generate() did not terminate")
+        # a stream that stopped left its next token launched: that
+        # launch is read back here (the token is thrown away there), so
+        # the engine is handed back with nothing in flight
+        self._settle()
         # the workload ran out before an active capture window did:
         # close it with the steps it has rather than strand the
         # process-wide profiler session
-        self.finish_capture()
-        return done
-
-    def _generate_pipelined(self, done: Dict[int, List[int]], active: set,
-                            sampling: SamplingParams,
-                            rng: Optional[jax.Array]
-                            ) -> Dict[int, List[int]]:  # tpulint: serving-loop
-        """Depth-``pipeline_depth`` dispatch-ahead driver.
-
-        The loop keeps up to ``depth`` steps dispatched-but-unread: after
-        launching step N it immediately schedules, stages, and launches
-        step N+1 — continuing decodes ride the FEEDBACK_TOKEN marker, so
-        their token ids are read from step N's on-device sample array
-        inside the jitted step — and only then reads step N's tokens
-        back (by which point the device has long started N+1).  Host
-        work therefore overlaps device compute, and blocking readback
-        happens one step behind dispatch.
-
-        Stop tokens are the one thing the host cannot predict: a
-        sequence that stops at step N already has a speculative token in
-        flight at N+1, which is discarded at its collect (the same
-        over-generation trade decode bursts make).  max_new_tokens is
-        count-based, so the driver simply stops speculating a step
-        early.  Outputs are token-for-token identical to the sync driver
-        — both run the same compiled step program."""
-        depth = self.icfg.pipeline_depth
-        inflight: deque = deque()
-        finishing: set = set()    # ctx-exhausted, last token still in flight
-        counts = {uid: 0 for uid in done}   # emitted + in-flight samples
-        draw = self._rng_drawer(rng)
-        stall = 0
-        while active or inflight:
-            # engine-side terminal closures (deadline expiry, cancel,
-            # shed-by-eviction) end those requests' generation here
-            reaped = self._drain_reaped()
-            if reaped:
-                active -= reaped
-                finishing -= reaped
-            # fill the pipeline while there is schedulable work
-            while len(inflight) < depth and any(self._pending.values()):
-                st = self._dispatch(sampling, draw,
-                                    self._draft_room(done, active, sampling))
-                # sequences that hit the context limit stop being
-                # scheduled; finish them once their last sampled token
-                # (possibly still in flight) has been emitted
-                for uid in list(self._ctx_exhausted):
-                    self._ctx_exhausted.discard(uid)
-                    if uid in active:
-                        finishing.add(uid)
-                if st is None:
-                    break
-                # speculate continuations for this step's sampled seqs
-                draft_uids = {u for u, _ in st.drafts}
-                for uid, _slot in st.emit:
-                    if uid not in active:
-                        continue               # put() outside generate()
-                    if uid in draft_uids:
-                        # a verify window's next fed token depends on
-                        # host-side acceptance — its collect puts the
-                        # concrete continuation instead of a marker
-                        continue
-                    if self._spec is not None \
-                            and self._spec.lookahead(uid):
-                        # predictable stream: trade the dispatch-ahead
-                        # marker for one pipeline bubble so the collect
-                        # can anchor a draft window on the concrete
-                        # token (up to spec_max_draft tokens next step)
-                        continue
-                    counts[uid] += 1
-                    if counts[uid] >= sampling.max_new_tokens:
-                        continue               # finishes by count at emit
-                    self._mark_feedback(uid, st)
-                inflight.append(st)
-            if inflight:
-                stall = 0
-                out = self._collect(inflight.popleft())
-                for uid, toks in out.items():
-                    if uid not in active:
-                        continue               # stopped earlier: discard
-                    finished = False
-                    for tok in toks:
-                        done[uid].append(tok)
-                        stop = (sampling.stop_token is not None
-                                and tok == sampling.stop_token)
-                        if stop or len(done[uid]) \
-                                >= sampling.max_new_tokens:
-                            finished = True
-                            break
-                    if finished:
-                        active.discard(uid)
-                        finishing.discard(uid)
-                        self.flush(uid)
-                    elif not self._pending.get(uid) \
-                            and uid not in finishing \
-                            and not self._inflight_sched.get(uid, 0):
-                        # no marker was speculated (drafting or
-                        # lookahead-positive row) and no NEWER step is
-                        # in flight for this sequence (an older step's
-                        # collect must never restart a stream a later
-                        # dispatch already continues): feed the concrete
-                        # tail token; the next schedule may anchor a
-                        # draft window on it
-                        self.put(uid, [toks[-1]])
-                        counts[uid] = len(done[uid])
-            # ctx-exhausted seqs end once no in-flight step still holds
-            # their final token
-            for uid in list(finishing):
-                if not any(uid == u for s in inflight for u, _ in s.emit):
-                    finishing.discard(uid)
-                    active.discard(uid)
-                    self.flush(uid)
-            if not inflight and active:
-                # nothing running and nothing schedulable: either every
-                # remaining seq just finished above, or the pool is
-                # wedged (mirror the sync driver's bound)
-                stall += 1
-                if stall > 100_000:
-                    raise RuntimeError("generate() did not terminate")
-        # close any still-active capture window with the steps it has
-        # (see _generate_sync — the session must not outlive the work)
         self.finish_capture()
         return done

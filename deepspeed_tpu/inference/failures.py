@@ -422,7 +422,7 @@ class FailurePolicy:
             slow_note: Optional[Callable[[], Dict]] = None):
         """Run one guarded device call: consume any armed injection,
         then execute under the current watchdog deadline.  ``site``
-        (``dispatch``/``collect``/``burst``) and ``sid`` name the call
+        (``dispatch``/``collect``) and ``sid`` name the call
         in the watchdog's slow-call records; ``stamps`` receives the
         hand-off's ``hop_us`` and ``slow_note`` adds to a slow call's
         record (``Watchdog.run``).  ``cold``

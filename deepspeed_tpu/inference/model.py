@@ -460,8 +460,8 @@ def _out_proj(o, wo, dt):
 
 
 def _qkv_proj(cfg, ap, h, dt, cos, sin, positions, kind: str = "full"):
-    """Shared qkv projection + biases + rotary for the serving forwards
-    (ragged step and decode burst).  ``kind``: the layer's attention
+    """The qkv projection + biases + rotary of the served step's
+    attention.  ``kind``: the layer's attention
     kind, which says whether it takes the rotary embedding."""
     if cfg.attn_in_scale != 1.0:
         h = h * jnp.asarray(cfg.attn_in_scale, dt)
@@ -1347,214 +1347,3 @@ def pipelined_ragged_step(cfg: TransformerConfig, params, quant, kv,
         keys = row_keys(rng, batch.seq_uids, batch.context_lens)
         toks = sample_fn(logits, keys)
     return with_stats(toks), new_kv
-
-
-# --------------------------------------------------------------------------
-# Device-side decode bursts (multi-token decode in one dispatch)
-# --------------------------------------------------------------------------
-
-def snapshot_prefix(kv, block_tables, P: int, block_size: int):
-    """Gather each slot's first ``P`` context tokens into a dense
-    read-only buffer [L, S, P, 2, Hkv, D] (the burst's attention operand;
-    gathered ONCE per burst, never carried through the burst's scan over
-    decode iterations.  On an older rig that scan copied a carried pool
-    every iteration; ``ragged_forward``'s layer scan now does carry the
-    pool, in place, on a TPU v5e — one write then one read of it per
-    body — but the burst has not been retried in that form: its dense
-    prefix also spares the block-table indirection).  A quantized
-    cache snapshots as a (codes, scales [L, S, P, 2, Hkv]) pair — the
-    burst dequantizes per layer in its attention, so the snapshot stays
-    1 byte/element."""
-    data, scales = _kv_parts(kv)
-    nb = P // block_size
-    tables = block_tables[:, :nb]                     # [S, nb]
-    trash = data.shape[1] - 1
-    tables = jnp.where(tables < 0, trash, tables)
-    ctx = data[:, tables]          # [L, S, nb, bs, 2, Hkv, D]
-    L, S = ctx.shape[0], ctx.shape[1]
-    ctx = ctx.reshape(L, S, P, 2, ctx.shape[-2], ctx.shape[-1])
-    if scales is None:
-        return ctx
-    sctx = scales[:, tables].reshape(L, S, P, 2, ctx.shape[-2])
-    return (ctx, sctx)
-
-
-def decode_burst_forward(cfg: TransformerConfig, params, prefix,
-                         base_ctx, token0, steps: int, sample_fn,
-                         rng, uids=None, quant=None,
-                         mixed_gemm: bool = False, sharded: bool = False):
-    """Run ``steps`` decode iterations entirely on device.
-
-    prefix: [L, S, P, 2, Hkv, D] dense read-only context (closure-sized
-    operand); base_ctx: [S] i32 tokens already in context per slot;
-    token0: [S] i32 the last fed token per slot; uids: [S] u32 the uid
-    occupying each slot (sampling keys fold the base ``rng`` by
-    (uid, position) exactly like the stepwise path, so seeded bursts
-    match seeded steps token-for-token).  Returns
-    (tokens [steps, S], tail [L, S, steps, 2, Hkv, D]) — the caller
-    scatters the tail back into the paged cache.
-
-    Attention per token = ONLINE-SOFTMAX MERGE of (a) dense attention
-    over the prefix (masked by base_ctx) and (b) attention over the
-    in-burst tail (masked by iteration) — no concatenation, the prefix
-    is never copied."""
-    if not cfg.plain_stack:
-        raise NotImplementedError(
-            "decode bursts serve a model of one block type "
-            "(TransformerConfig.plain_stack)")
-    pdata, pscales = _kv_parts(prefix)
-    nL = pdata.shape[0]
-    S, P = pdata.shape[1], pdata.shape[2]
-    Hkv, D = pdata.shape[4], pdata.shape[5]
-    H = cfg.num_heads
-    rep = H // Hkv
-    norm = _norm(cfg)
-    act = L.ACTIVATIONS[cfg.activation]
-    scale = (cfg.attn_scale if cfg.attn_scale is not None
-             else 1.0 / (cfg.head_dim ** 0.5))
-    if quant is not None:
-        from .quantization import merge_layer
-        from ..ops.quant import dequantize_any
-    if quant is not None and "embed" in quant:
-        embed_tab = {"table": dequantize_any(quant["embed"]["table"])}
-    else:
-        embed_tab = params["embed"]
-    dt = embed_tab["table"].dtype
-    cos = sin = slopes = None
-    if cfg.position == "rope":
-        cos, sin = L.rope_freqs(cfg.rotary_dim, cfg.max_seq_len,
-                                cfg.rope_theta)
-    elif cfg.position == "alibi":
-        slopes = L.alibi_slopes(H).reshape(Hkv, rep)
-
-    def one_layer(x, lp, li, tail_l, pos, j):
-        """x: [S, dm]; tail_l: [S, K, 2, Hkv, D] this layer's in-burst
-        KV.  Returns (y, tail_l with slot j written)."""
-        if quant is not None:
-            lp = merge_layer(lp, quant["blocks"], li, dt,
-                             mixed=mixed_gemm)
-        ap = lp["attn"]
-        h = norm(lp["ln1"], x)
-        q, k, v = _qkv_proj(cfg, ap, h, dt, cos, sin, pos)
-        tail_l = tail_l.at[:, j, 0].set(k)
-        tail_l = tail_l.at[:, j, 1].set(v)
-
-        qg = q.reshape(S, Hkv, rep, D)
-        # (a) prefix attention, masked by each slot's true context length
-        kp = pdata[li, :, :, 0]                       # [S, P, Hkv, D]
-        vp = pdata[li, :, :, 1]
-        if pscales is not None:
-            kp = _dequant_ctx(kp, pscales[li, :, :, 0], dt)
-            vp = _dequant_ctx(vp, pscales[li, :, :, 1], dt)
-        sa = jnp.einsum("shrd,sphd->shrp", qg, kp.astype(dt)
-                        ).astype(jnp.float32) * scale
-        cols = jnp.arange(P)[None, :]
-        if slopes is not None:      # ALiBi over absolute prefix positions
-            sa = sa + (slopes[None, :, :, None]
-                       * cols[:, None, None, :].astype(jnp.float32))
-        valid = cols < base_ctx[:, None]              # [S, P]
-        sa = jnp.where(valid[:, None, None, :], sa, -1e30)
-        ma = sa.max(axis=-1)
-        pa = jnp.exp(sa - ma[..., None])
-        la = pa.sum(axis=-1)
-        oa = jnp.einsum("shrp,sphd->shrd", pa.astype(dt), vp.astype(dt))
-        # (b) in-burst tail attention, masked by iteration (<= j)
-        kt = tail_l[:, :, 0]                          # [S, K, Hkv, D]
-        vt = tail_l[:, :, 1]
-        sb = jnp.einsum("shrd,skhd->shrk", qg, kt).astype(jnp.float32) \
-            * scale
-        if slopes is not None:  # tail key k sits at position base_ctx+k
-            kpos = (base_ctx[:, None]
-                    + jnp.arange(tail_l.shape[1])[None, :]).astype(
-                        jnp.float32)                  # [S, K]
-            sb = sb + slopes[None, :, :, None] * kpos[:, None, None, :]
-        it_valid = jnp.arange(tail_l.shape[1]) <= j
-        sb = jnp.where(it_valid[None, None, None, :], sb, -1e30)
-        mb = sb.max(axis=-1)
-        pb = jnp.exp(sb - mb[..., None])
-        lb = pb.sum(axis=-1)
-        ob = jnp.einsum("shrk,skhd->shrd", pb.astype(dt), vt)
-        # online-softmax merge of the two parts
-        m = jnp.maximum(ma, mb)
-        wa = jnp.exp(ma - m)
-        wb = jnp.exp(mb - m)
-        denom = la * wa + lb * wb
-        o = (oa.astype(jnp.float32) * wa[..., None]
-             + ob.astype(jnp.float32) * wb[..., None]) / \
-            jnp.maximum(denom, 1e-30)[..., None]
-        o = o.reshape(S, H, D).astype(dt)
-
-        o = _out_proj(o, ap["wo"], dt)
-        if cfg.attn_out_bias:
-            o = o + ap["bo"].astype(dt)
-        if not cfg.parallel_block:
-            x = x + o
-            h = norm(lp["ln2"], x)
-        elif cfg.parallel_separate_norms:
-            h = norm(lp["ln2"], x)   # gpt-neox: MLP norms the original x
-        d, _ = _ffn(cfg, lp, h, dt, act, sharded=sharded)
-        y = (x + o + d) if cfg.parallel_block else (x + d)
-        return y, tail_l
-
-    tail0 = jnp.zeros((nL, S, steps, 2, Hkv, D), dt)
-    if uids is None:
-        uids = jnp.zeros(S, jnp.uint32)
-
-    def iteration(carry, xs):
-        tok, tail = carry
-        j = xs
-        pos = base_ctx + j                           # this token's position
-        x = L.embed(embed_tab, tok).astype(dt)
-        if cfg.embed_norm:              # bloom word_embeddings_layernorm
-            x = norm(params["ln_embed"], x)
-        if cfg.position == "learned":
-            x = x + params["pos_embed"]["table"][pos].astype(dt)
-
-        def body(x, xs2):
-            lp, li, tl = xs2
-            y, tl = one_layer(x, lp, li, tl, pos, j)
-            return y, tl
-
-        x, tail = jax.lax.scan(
-            body, x, (params["blocks"],
-                      jnp.arange(cfg.num_layers, dtype=jnp.int32), tail))
-        x = norm(params["ln_f"], x)
-        if cfg.tie_embeddings:
-            logits = x @ embed_tab["table"].astype(dt).T
-        else:
-            logits = x @ params["lm_head"]["kernel"].astype(dt)
-            if cfg.head_bias:
-                logits = logits + params["lm_head"]["bias"].astype(dt)
-        # sampled token j lands at position pos+1 = its post-step context
-        # length — the same (uid, position) fold the stepwise path uses
-        keys = row_keys(rng, uids, pos + 1)
-        nxt = sample_fn(logits.astype(jnp.float32), keys)
-        return (nxt, tail), nxt
-
-    (_, tail), toks = jax.lax.scan(
-        iteration, (token0, tail0),
-        jnp.arange(steps, dtype=jnp.int32))
-    return toks, tail
-
-
-def scatter_tail(kv, tail, block_tables, base_ctx, block_size: int):
-    """Write the burst's tail KV into the paged cache (one donated
-    dispatch after the scan): token (slot s, iter j) lands at block
-    tables[s, (base+j)//bs], offset (base+j)%bs.  Quantized caches
-    quantize the dense in-burst tail here, on commit."""
-    data, scales = _kv_parts(kv)
-    nL, S, K = tail.shape[0], tail.shape[1], tail.shape[2]
-    pos = base_ctx[:, None] + jnp.arange(K)[None, :]          # [S, K]
-    blk = jnp.take_along_axis(block_tables, pos // block_size,
-                              axis=1)                          # [S, K]
-    trash = data.shape[1] - 1
-    blk = jnp.where(blk < 0, trash, blk)
-    off = pos % block_size
-    li = jnp.arange(nL)[:, None, None]
-    # kv[l, blk[s,k], off[s,k]] <- tail[l, s, k]  ([2, Hkv, D] payload)
-    if scales is None:
-        return data.at[li, blk[None], off[None]].set(tail)
-    tq, ts = _quantize_kv(tail, data.dtype)   # ts: [L, S, K, 2, Hkv]
-    data = data.at[li, blk[None], off[None]].set(tq)
-    scales = scales.at[li, blk[None], off[None]].set(ts)
-    return (data, scales)

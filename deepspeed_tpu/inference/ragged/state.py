@@ -232,7 +232,7 @@ class SequenceDescriptor:
     # --- prefix-cache state --------------------------------------------
     cached_tokens: int = 0        # tokens served from the prefix cache
     # token ids in KV order while every value is host-known; a deferred
-    # on-device token (FEEDBACK_TOKEN) or a device-side decode burst
+    # on-device token (FEEDBACK_TOKEN) that no launch is named for
     # breaks the chain — blocks past the break are never content-hashed
     chain: List[int] = dataclasses.field(default_factory=list)
     chain_broken: bool = False
@@ -275,7 +275,7 @@ class SequenceDescriptor:
         ``engine.snapshot()`` — a resumable sequence can be released
         and re-prefilled token-identically; a non-resumable one holds
         device-side tokens the host never saw (a deferred feedback
-        marker or a decode burst) and can only be closed."""
+        marker) and can only be closed."""
         return (not self.chain_broken and self.draft_len == 0
                 and not self.deferred
                 and len(self.chain) == self.seen_tokens)
@@ -355,22 +355,21 @@ def step_rows(max_seqs: int, n_verify: int, token_budget: int
 class BatchStager:
     """Two alternating host-side staging buffer sets for RaggedBatch
     metadata (the reference's pinned "fast host buffer",
-    ragged_wrapper.py).  The depth-2 serving pipeline builds step N+1's
-    metadata while step N executes on device; alternating buffers
-    guarantee the host never rewrites a set whose ``device_put`` transfer
-    for the previous step may still be draining.  Two sets suffice for
-    exactly one step in flight (``pipeline_depth=2``); deeper pipelines
-    get ``depth`` sets."""
+    ragged_wrapper.py).  The served loop builds step N+1's metadata
+    while step N executes on device; alternating buffers guarantee the
+    host never rewrites a set whose ``device_put`` transfer for the
+    previous step may still be draining.  Two sets suffice for the one
+    step ``InferenceEngine.step`` keeps in flight."""
 
     def __init__(self, token_budget: int, max_seqs: int, max_blocks: int,
-                 depth: int = 2, n_verify: int = 1, n_chunks: int = 0):
+                 n_verify: int = 1, n_chunks: int = 0):
         self.shape_key = (token_budget, max_seqs, max_blocks)
         # widest speculative verify window this engine may stage
         # (spec_max_draft + 1); batches slice the columns they use
         self.n_verify = max(1, n_verify)
         self._bufs = [self._alloc(token_budget, max_seqs, max_blocks,
                                   self.n_verify)
-                      for _ in range(max(2, depth))]
+                      for _ in range(2)]
         if n_chunks:         # a model with recurrent layers (RecBatch)
             for b in self._bufs:
                 b.update(self._alloc_rec(max_seqs, n_chunks))
@@ -865,21 +864,6 @@ class StateManager:
         return (need <= self.allocator.free_blocks and slot_ok
                 and new_tokens <= self.context_remaining(uid))
 
-    def reserve_ahead(self, uid: int, n_tokens: int) -> bool:
-        """Pre-allocate KV blocks covering ``n_tokens`` beyond the
-        current context (device-side decode bursts write K tokens
-        between host block allocations).  Returns False when the pool
-        or context limit cannot cover it."""
-        seq = self.seqs[uid]
-        if n_tokens > self.context_remaining(uid):
-            return False
-        need = seq.blocks_needed(n_tokens, self.cfg.block_size)
-        if need > self.allocator.free_blocks:
-            return False
-        if need:
-            seq.blocks.extend(self.allocator.allocate(need))
-        return True
-
     def resolve_draft(self, uid: int, accepted: int) -> int:
         """Resolve a speculative verify step for ``uid``: commit the
         ``accepted`` leading draft tokens and REWIND the write cursor
@@ -946,15 +930,6 @@ class StateManager:
             del seq.chain[-n_tokens:]
             seq.deferred = [d for d in seq.deferred
                             if d[1] < len(seq.chain)]
-
-    def advance(self, uid: int, n_tokens: int) -> None:
-        """Account tokens written device-side (burst iterations past the
-        first host-fed token).  Burst-written KV bypasses build_batch, so
-        the content hash chain ends here — prompt blocks registered
-        earlier stay matchable."""
-        seq = self.seqs[uid]
-        seq.seen_tokens += n_tokens
-        seq.chain_broken = True
 
     # ---- batch building --------------------------------------------------
     def build_batch(self, requests: List[tuple], token_budget: int,

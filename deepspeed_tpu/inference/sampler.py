@@ -25,8 +25,8 @@ class SamplingParams:
     def sampler_key(self) -> tuple:
         """The fields that change the compiled sampling computation —
         ``stop_token``/``max_new_tokens`` are host-side loop concerns, so
-        jitted steps that bake the sampler in (pipelined serving, decode
-        bursts) cache executables on this key, not the full params."""
+        the jitted step that bakes the sampler in caches executables on
+        this key, not the full params."""
         return (self.temperature, self.top_k, self.top_p)
 
     @property
@@ -67,9 +67,9 @@ def row_keys(rng: jax.Array, uids: jnp.ndarray,
     This makes a sequence's sampled-token randomness a pure function of
     (base key, uid, position) — invariant to HOW the serving loop
     scheduled the work.  That is what keeps seeded sampling
-    token-for-token identical across pipeline depths, decode bursts, and
-    prefix-cache hits/misses (a cache hit collapses prefill steps, so
-    any per-step key stream would diverge)."""
+    token-for-token identical whether a step ran ahead or strict, and
+    across prefix-cache hits/misses (a cache hit collapses prefill
+    steps, so any per-step key stream would diverge)."""
     def one(u, c):
         return jax.random.fold_in(jax.random.fold_in(rng, u), c)
     return jax.vmap(one)(uids, context_lens)

@@ -152,15 +152,3 @@ class NgramProposer:
         # wrapping continues the established cycle
         period = len(h) - src
         return [h[src + (j % period)] for j in range(limit)]
-
-    def lookahead(self, uid: int) -> bool:
-        """Cheap "is this stream currently predictable" signal: does the
-        current history suffix have an earlier occurrence?  The
-        pipelined driver uses it to choose, per sequence per step,
-        between the feedback-marker fast path (dispatch ahead without
-        waiting — no drafts possible, the next token id is still on
-        device) and the verify path (wait for the collect so the
-        concrete token can anchor a draft window).  Random streams keep
-        full dispatch-ahead pipelining; repetitive streams trade one
-        pipeline bubble for up to ``max_draft`` extra tokens per step."""
-        return self._prev_occurrence(uid) is not None

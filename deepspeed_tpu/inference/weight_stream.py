@@ -101,8 +101,8 @@ class NVMeWeightStore:
         replica materializes its block weights from the store spilled
         once at deploy instead of re-tracing checkpoint load, and
         because the weights end resident (``icfg.weight_stream`` unset
-        on the new engine) none of the modes streaming forces off —
-        decode bursts, speculative decode — are forced on it."""
+        on the new engine) the mode streaming forces off —
+        speculative decode — is not forced on it."""
         assert self._treedef is not None, "restore before spill"
         leaves = []
         for j, sds in enumerate(self._shapes):

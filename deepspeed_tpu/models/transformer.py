@@ -320,8 +320,8 @@ class TransformerConfig:
     @property
     def plain_stack(self) -> bool:
         """One block type and none of the per-layer mechanisms: what the
-        decode burst, the NVMe weight stream, ZeRO-Inference's weight
-        quantization and the pipeline stages were written for."""
+        NVMe weight stream, ZeRO-Inference's weight quantization and
+        the pipeline stages were written for."""
         return (self.layer_pattern == ("full",) and not self.num_dense_layers
                 and not self.attn_gate and not self.sandwich_norm
                 and self.embed_scale is None and self.moe_groups == 1

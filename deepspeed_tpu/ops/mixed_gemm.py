@@ -70,7 +70,7 @@ def _mixed_kernel(x_ref, d_ref, s_ref, o_ref, acc_ref, *, interpret):
 def _tile_plan(x, Kb: int, N: int, block_m: int, block_n: int,
                block_k: int):
     """Shared tiling scaffold for the mixed-GEMM kernels: auto block_m
-    (decode bursts are small — pad M up to a lane-friendly multiple),
+    (decode steps are small — pad M up to a lane-friendly multiple),
     clamp K/N blocks, and reject non-dividing contractions rather than
     silently pad them.  ``Kb``: the kernel's K-walk extent (K for int8,
     K/2 packed rows for int4).  Returns (x_padded, M, Mp, block_m, bk,

@@ -2,9 +2,9 @@
 
 A cold start compiles every program the run touches — minutes for a
 trainer plus a server at real widths — and a fresh machine has nothing
-compiled.  The entry scripts (``chip_smoke.py``, ``bench.py``,
-``python -m deepspeed_tpu.gateway``, ``python -m deepspeed_tpu.comm.bench``,
-``tools/profile_decode8b.py``) call :func:`enable_compile_cache` before
+compiled.  The entry scripts (``chip_smoke.py``,
+``python -m deepspeed_tpu.gateway``, ``python -m deepspeed_tpu.comm.bench``)
+call :func:`enable_compile_cache` before
 their first compile; ``import deepspeed_tpu`` does not, and neither does
 the test suite.
 """

@@ -248,8 +248,8 @@ class WeightStreamColdStart:
     store's aio read path (``NVMeWeightStore.restore_stacked``)
     instead of re-running checkpoint load — the fleet's weight fabric
     is the cold-start fabric.  Because the new engine never sets
-    ``icfg.weight_stream``, none of the modes streaming forces off
-    (decode bursts, speculative decode) are forced on it — the test
+    ``icfg.weight_stream``, the mode streaming forces off
+    (speculative decode) is not forced on it — the test
     bar the satellite names.
 
     ``build`` is a zero-arg engine constructor (same config the pool

@@ -4,7 +4,7 @@
 PR 5 made the serving loop *observable* and PR 9 made the device and
 compiler observable — but nothing watched those streams live: a latency
 regression, retrace storm, or KV-pool leak was only discovered after
-the fact by benchdiff or a crash dump.  This module is the watcher:
+the fact by a benchmark run or a crash dump.  This module is the watcher:
 cheap streaming detectors the engines feed once per step with values
 they already computed (no added clock reads), each firing a structured
 :class:`AnomalyEvent` that the engine notes into the flight recorder,
@@ -400,7 +400,7 @@ class AnomalyMonitor:
         return recent >= self.cfg.sustained_count
 
     def summary(self) -> Dict[str, Any]:
-        """JSON-able tally for bench legs / SLO sweeps / health."""
+        """JSON-able tally for SLO sweeps / health."""
         return {"total": self.total(),
                 "by_signal": dict(self.counts),
                 "recent": [e.as_dict() for e in list(self.events)[-8:]]}

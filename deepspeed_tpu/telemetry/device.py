@@ -42,10 +42,9 @@ from ..utils.logging import logger
 from .metrics import MetricsRegistry
 
 # bf16 peak FLOP/s and HBM bandwidth (bytes/s) per chip generation —
-# THE one table: bench.py reads it for its one-shot MFU (and raises on
-# an unknown kind), the live gauges read it here.  Matched by substring
-# against device_kind (lowercased); unknown kinds (the CPU included)
-# yield None -> absent gauges.
+# the one table of this package: the live gauges read it here.  Matched
+# by substring against device_kind (lowercased); unknown kinds (the CPU
+# included) yield None -> absent gauges.
 PEAK_FLOPS = {"v4": 275e12, "v5 lite": 197e12, "v5e": 197e12,
               "v5p": 459e12, "v5": 459e12, "v6e": 918e12, "v6": 918e12}
 PEAK_HBM_BW = {"v4": 1.2e12, "v5 lite": 0.82e12, "v5e": 0.82e12,
@@ -236,18 +235,18 @@ class DeviceTelemetry:
         self.program_costs[key] = cost
         return cost
 
-    def on_dispatch(self, key, n: int = 1) -> None:
-        """Attribute one dispatched execution of program ``key`` (``n``
-        model invocations for burst scans) to the flop/byte counters."""
+    def on_dispatch(self, key) -> None:
+        """Attribute one dispatched execution of program ``key`` to the
+        flop/byte counters."""
         cost = self.program_costs.get(key)
         if not cost:
             return
         f = cost.get("flops")
         b = cost.get("bytes_accessed")
         if f:
-            self._c_flops.inc(f * n)
+            self._c_flops.inc(f)
         if b:
-            self._c_bytes.inc(b * n)
+            self._c_bytes.inc(b)
 
     # ---- derived utilization gauges (read-time, FnGauge) --------------
     def _busy_s(self) -> Optional[float]:
@@ -306,7 +305,7 @@ class DeviceTelemetry:
 
     # ---- export --------------------------------------------------------
     def snapshot(self) -> Dict[str, Any]:
-        """JSON-able device-telemetry summary (what bench legs embed):
+        """JSON-able device-telemetry summary (what a benchmark embeds):
         per-program costs, the derived utilizations (None when absent),
         and the last memory poll."""
         mfu = self._mfu()

@@ -45,11 +45,8 @@ FLIGHT_REQUIRED_KEYS = ("version", "reason", "time", "fingerprint",
 def config_fingerprint() -> Dict[str, str]:
     """Engine version + a short digest over the serving/overload/
     failure config DEFAULTS — the knobs whose defaults PRs keep
-    evolving.  Two artifacts (BENCH JSONs, flight dumps) with different
-    hashes came from different default engines; compare only within a
-    hash.  Shared by ``bench.py`` (the BENCH JSON fingerprint) and the
-    flight recorder, so the bench trajectory and the post-mortems are
-    joinable on the same key."""
+    evolving.  Two flight dumps with different hashes came from
+    different default engines; compare only within a hash."""
     import dataclasses
     import hashlib
 

@@ -100,12 +100,6 @@ class RequestRecord:
     t_first_token: Optional[float] = None
     t_last_token: Optional[float] = None
     t_finish: Optional[float] = None
-    # decode-tail anchor for TPOT.  Stepwise emission: == t_first_token.
-    # When the record's FIRST emission is a multi-token burst (all n
-    # tokens materialize at one readback instant), this anchors at the
-    # burst's dispatch time instead, so the tail isn't zero-width and
-    # TPOT doesn't collapse to 0 (see RequestTracker.on_tokens).
-    t_tail_start: Optional[float] = None
     prompt_tokens: int = 0
     cached_tokens: int = 0
     generated_tokens: int = 0
@@ -141,9 +135,7 @@ class RequestRecord:
         if self.t_first_token is None or self.t_last_token is None \
                 or self.generated_tokens < 2:
             return None
-        tail0 = self.t_tail_start if self.t_tail_start is not None \
-            else self.t_first_token
-        return (self.t_last_token - tail0) * 1e3 \
+        return (self.t_last_token - self.t_first_token) * 1e3 \
             / (self.generated_tokens - 1)
 
     @property
@@ -290,20 +282,15 @@ class RequestTracker:
         if rec is not None and rec.t_prefill_start is None:
             rec.t_prefill_start = now
 
-    def on_tokens(self, uid: int, n: int, now: float,
-                  t_dispatch: Optional[float] = None) -> None:
-        """``t_dispatch``: for an ``n > 1`` burst emission (all tokens
-        land at one readback), the burst's dispatch time — used as the
-        decode-tail anchor when these are the record's first tokens.
-        TTFT stays at ``now``: the tokens are not visible to the host
-        before readback."""
+    def on_tokens(self, uid: int, n: int, now: float) -> None:
+        """``n`` tokens of ``uid`` reached the host at ``now`` (one for
+        a plain row, several for a resolved verify window: they land at
+        one readback)."""
         rec = self.open.get(uid)
         if rec is None or n <= 0:
             return
         if rec.t_first_token is None:
             rec.t_first_token = now
-            rec.t_tail_start = t_dispatch \
-                if (t_dispatch is not None and n > 1) else now
             self._h_ttft.observe((now - rec.t_arrival) * 1e3)
             if self.slo is not None:
                 # same statement the TTFT histogram observes at —

@@ -23,6 +23,7 @@ from deepspeed_tpu.inference import (InferenceConfig, InferenceEngine,
 from deepspeed_tpu.inference.overload import OverloadConfig
 from deepspeed_tpu.models.presets import build_config
 from deepspeed_tpu.models.transformer import Model, apply, init_params
+from tests.serving_ref import strict_generate
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TOL = 1e-4          # float32 system against the float32 reference
@@ -301,8 +302,8 @@ def test_a_transient_failure_requeues_from_position_zero(tiny):
     rng = np.random.default_rng(11)
     prompts = {u: rng.integers(0, tiny[0].vocab_size, n).tolist()
                for u, n in ((1, 19), (2, 7))}
-    want = served_engine(tiny, pipeline_depth=1).generate(
-        prompts, SamplingParams(max_new_tokens=10))
+    want = strict_generate(served_engine(tiny), prompts,
+                           SamplingParams(max_new_tokens=10))
     eng = served_engine(tiny)
     for u, p in prompts.items():
         eng.put(u, p, max_new_tokens=10)
@@ -329,8 +330,7 @@ def test_a_transient_failure_requeues_from_position_zero(tiny):
 
 
 @pytest.mark.parametrize("option,value", [
-    ("prefix_cache", "on"), ("spec_decode", "on"), ("kv_tier", "on"),
-    ("decode_burst", 4)])
+    ("prefix_cache", "on"), ("spec_decode", "on"), ("kv_tier", "on")])
 def test_engine_refuses_by_name_what_cannot_hold_a_state(tiny, option,
                                                          value):
     with pytest.raises(ValueError, match=option):

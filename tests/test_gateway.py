@@ -699,7 +699,7 @@ def test_drain_finishes_inflight_and_503s_late_arrivals(model):
     t.start()
     deadline = time.perf_counter() + 30.0
     while time.perf_counter() < deadline:
-        if eng.query(700)["status"] == "running":
+        if held:            # two tokens in and held there: in flight
             break
         time.sleep(0.01)
     h.begin_drain(deadline_ms=60_000.0)

@@ -15,6 +15,7 @@ from deepspeed_tpu.inference import (BlockedAllocator, InferenceConfig,
                                      StateManager, KVCacheConfig)
 from deepspeed_tpu.inference.sampler import sample
 from deepspeed_tpu.models import apply, build_model
+from tests.serving_ref import strict_generate
 
 
 def tiny_model(**over):
@@ -284,94 +285,12 @@ class TestSchedulerSafety:
         assert eng.query(0)["seen_tokens"] == 4   # decode went through
 
 
-class TestDecodeBurst:
-    """Device-side multi-token decode (one dispatch per K tokens)."""
-
-    def test_burst_matches_stepwise_greedy(self):
-        m = tiny_model()
-        sp = SamplingParams(temperature=0.0, max_new_tokens=12)
-        prompts = {0: [5, 9, 2, 17, 3], 1: [7, 7, 1]}
-        ref = make_fp32_engine(m).generate(dict(prompts), sp)
-        eng = make_fp32_engine(m, decode_burst=4)
-        got = eng.generate(dict(prompts), sp)
-        assert got == ref
-
-    def test_burst_respects_stop_token(self):
-        m = tiny_model()
-        eng = make_fp32_engine(m, decode_burst=4)
-        prompt = [3, 1, 4, 1, 5]
-        base = make_fp32_engine(m).generate(
-            {0: prompt}, SamplingParams(temperature=0.0,
-                                        max_new_tokens=10))[0]
-        stop = base[3]                      # force a mid-burst stop
-        sp = SamplingParams(temperature=0.0, max_new_tokens=10,
-                            stop_token=stop)
-        got = eng.generate({0: prompt}, sp)[0]
-        # fresh engine: the reference's state still holds the finished seq
-        want = make_fp32_engine(m).generate({0: prompt}, sp)[0]
-        assert got == want
-
-    def test_burst_api_direct(self):
-        m = tiny_model()
-        eng = make_fp32_engine(m)
-        sp = SamplingParams(temperature=0.0, max_new_tokens=32)
-        eng.put(0, [2, 4, 6, 8])
-        while eng.step(sampling=sp).get(0) is None:
-            pass
-        first = eng.state.seqs[0].tokens[-1]
-        eng.put(0, [first])
-        out = eng.decode_burst(5, sampling=sp)
-        assert len(out[0]) == 5
-        # bookkeeping: the burst advanced the context by its iterations
-        assert eng.state.seqs[0].seen_tokens == 4 + 1 + 4
-
-    def test_burst_rejects_prefill(self):
-        m = tiny_model()
-        eng = make_fp32_engine(m)
-        eng.put(0, [1, 2, 3, 4])
-        with pytest.raises(ValueError, match="single-token"):
-            eng.decode_burst(4)
-
-    def test_burst_learned_positions_and_moe(self):
-        """Burst parity on the other layer variants: learned positions
-        (gpt2-style) and MoE experts."""
-        from deepspeed_tpu.models import build_model
-        for name, kw in (("gpt2", dict(vocab_size=128, num_layers=2,
-                                       d_model=64, num_heads=4,
-                                       max_seq_len=64)),
-                         ("mixtral-tiny", dict(vocab_size=128, num_layers=2,
-                                               d_model=64, num_heads=4,
-                                               num_kv_heads=2, d_ff=128,
-                                               num_experts=4,
-                                               max_seq_len=64))):
-            m = build_model(name, **kw)
-            sp = SamplingParams(temperature=0.0, max_new_tokens=9)
-            prompt = {0: [5, 9, 2, 17]}
-            ref = make_fp32_engine(m).generate(dict(prompt), sp)
-            got = make_fp32_engine(m, decode_burst=3).generate(
-                dict(prompt), sp)
-            assert got == ref, name
-
-    def test_burst_shrinks_under_pool_pressure(self):
-        """With a nearly-exhausted KV pool the burst shrinks (or falls
-        back to stepwise) instead of raising — parity with the stepwise
-        scheduler's graceful degradation."""
-        m = tiny_model()
-        # tiny pool: 8 blocks of 16 = 128 tokens total for 2 seqs
-        eng = make_fp32_engine(m, num_kv_blocks=8, kv_block_size=16,
-                               decode_burst=64)
-        sp = SamplingParams(temperature=0.0, max_new_tokens=40)
-        out = eng.generate({0: list(range(1, 30)),
-                            1: list(range(30, 55))}, sp)
-        # both sequences produced tokens until context/pool limits
-        assert len(out[0]) > 0 and len(out[1]) > 0
-
-
 class TestPipelinedServing:
-    """Depth-2 dispatch-ahead serving loop (on-device sampling + deferred
-    token feedback + double-buffered staging) must be token-for-token
-    identical to the strict-sync loop — both run the same step
-    computation; only dispatch/readback cadence differs."""
+    """``generate()`` runs on the step that runs ahead (on-device
+    sampling + deferred token feedback + double-buffered staging) and
+    must be token-for-token identical to the strict caller-fed loop —
+    both run the same step computation; only dispatch/readback cadence
+    differs."""
 
     PROMPTS = {0: [5, 17, 99, 3, 42], 1: [7, 7, 1]}
 
@@ -380,33 +299,33 @@ class TestPipelinedServing:
         return eng.generate({u: list(p) for u, p in prompts.items()},
                             sp, rng=rng)
 
-    def test_depth2_matches_sync(self):
+    def test_ahead_matches_strict(self):
         """Greedy, stop-token, and seeded-sampling parity on one engine
-        pair (generate() flushes everything, so the engines are reused
+        pair (both loops flush everything, so the engines are reused
         across phases — and greedy/stop share one compiled step)."""
         m = tiny_model()
-        e1 = make_fp32_engine(m, pipeline_depth=1)
-        e2 = make_fp32_engine(m, pipeline_depth=2)
+        e1 = make_fp32_engine(m)
+        e2 = make_fp32_engine(m)
         sp = SamplingParams(max_new_tokens=10)
-        sync = self._gen(e1, self.PROMPTS, sp)
+        sync = strict_generate(e1, self.PROMPTS, sp)
         piped = self._gen(e2, self.PROMPTS, sp)
         assert piped == sync
-        # stop token mid-stream: the pipelined driver has one speculative
-        # step in flight when it fires; its token must be discarded
+        # stop token mid-stream: the served loop has one step launched
+        # ahead when it fires; its token must be discarded
         sps = SamplingParams(max_new_tokens=50, stop_token=sync[0][3])
         one = {0: self.PROMPTS[0]}
         got = self._gen(e2, one, sps)
-        assert got == self._gen(e1, one, sps)
+        assert got == strict_generate(e1, one, sps)
         assert got[0][-1] == sync[0][3]
-        # fixed-rng sampling: both drivers consume the key stream
-        # identically (one split per launched step)
+        # fixed-rng sampling: a token's key is its (uid, position) fold
         spr = SamplingParams(temperature=1.0, top_k=8, max_new_tokens=8)
         assert self._gen(e2, self.PROMPTS, spr,
                          rng=jax.random.PRNGKey(7)) \
-            == self._gen(e1, self.PROMPTS, spr, rng=jax.random.PRNGKey(7))
+            == strict_generate(e1, self.PROMPTS, spr,
+                               rng=jax.random.PRNGKey(7))
         # no leaked feedback markers, sequences, slots, or blocks after
-        # the pipelined runs (speculation fully rolled up)
-        assert e2._fb_step == {}
+        # the runs ahead (speculation fully rolled up), nothing in flight
+        assert e2._fb_step == {} and not e2.in_flight
         assert not e2.state.seqs and not e2.state._slots
         assert e2.state.allocator.free_blocks \
             == e2.state.allocator.total_blocks
@@ -417,45 +336,44 @@ class TestPipelinedServing:
                                          "device_ms", "wait_ms",
                                          "readback_ms"))
 
-    def test_depth2_mixed_prefill_decode_traffic(self):
+    def test_mixed_prefill_decode_traffic(self):
         """Prompts straddling the token budget: chunked prefill, decode,
-        and prefill+decode mixed steps all pipeline identically."""
+        and prefill+decode mixed steps all run ahead identically."""
         m = tiny_model()
         r = np.random.RandomState(3)
         prompts = {0: list(r.randint(1, 128, 50)), 1: [3, 1, 4],
                    2: list(r.randint(1, 128, 20))}
         sp = SamplingParams(max_new_tokens=6)
-        sync = self._gen(make_fp32_engine(m, pipeline_depth=1,
-                                          token_budget=16), prompts, sp)
-        piped = self._gen(make_fp32_engine(m, pipeline_depth=2,
-                                           token_budget=16), prompts, sp)
+        sync = strict_generate(make_fp32_engine(m, token_budget=16),
+                               prompts, sp)
+        piped = self._gen(make_fp32_engine(m, token_budget=16), prompts, sp)
         assert piped == sync
 
-    def test_depth3_budget_starvation(self):
-        """pipeline_depth=3 with a budget smaller than the live decode
-        count: a sequence's deferred feedback can outlive TWO dispatches,
-        so the scheduler must hold it until its owning step's collect
-        patches it concrete (feeding it the wrong step's sample array
-        would be silently wrong, not an error)."""
+    def test_budget_starvation(self):
+        """A budget smaller than the live decode count: a decode whose
+        continuation was launched ahead may wait a step for its turn,
+        and by then its marker has been patched concrete by the read of
+        the launch that owned it (feeding it another step's sample
+        array would be silently wrong, not an error)."""
         m = tiny_model()
         prompts = {0: [5, 9], 1: [7, 7], 2: [3, 1], 3: [8, 2]}
         sp = SamplingParams(max_new_tokens=5)
-        sync = self._gen(make_fp32_engine(m, pipeline_depth=1,
-                                          token_budget=2), prompts, sp)
-        piped = self._gen(make_fp32_engine(m, pipeline_depth=3,
-                                           token_budget=2), prompts, sp)
-        assert piped == sync
+        sync = strict_generate(make_fp32_engine(m, token_budget=2),
+                               prompts, sp)
+        eng = make_fp32_engine(m, token_budget=2)
+        assert self._gen(eng, prompts, sp) == sync
+        assert eng.metrics_snapshot()["serving_steps_ahead_total"] > 0
 
-    def test_depth2_context_limit(self):
+    def test_context_limit(self):
         """A sequence ending at the context limit still emits its final
-        in-flight token before the driver finishes it."""
+        in-flight token before the engine closes it."""
         m = tiny_model()
         eng = make_fp32_engine(m, num_kv_blocks=2, kv_block_size=16,
-                               max_seqs=1, max_seq_len=32,
-                               pipeline_depth=2)
+                               max_seqs=1, max_seq_len=32)
         out = eng.generate({0: [1, 2, 3, 4]},
                            SamplingParams(max_new_tokens=100))
-        assert len(out[0]) == 29        # same bound as the sync loop
+        assert len(out[0]) == 29        # same bound as the strict loop
+        assert eng.query(0)["status"] == "context_exhausted"
 
 
 class TestChunkedPagedAttention:
@@ -474,34 +392,6 @@ class TestChunkedPagedAttention:
         chunked = make_fp32_engine(m, attn_impl="xla").generate(
             {u: list(p) for u, p in prompt.items()}, sp)
         assert ref == chunked
-
-
-class TestBurstStopToken:
-    def test_direct_burst_truncates_at_stop(self):
-        """Direct decode_burst() callers with a stop_token must not get
-        over-advanced contexts: tokens and seen_tokens stop at the stop
-        token (advisor round-2 finding)."""
-        m = tiny_model()
-        eng = make_fp32_engine(m, decode_burst=4)
-        # prefill
-        eng.put(0, [5, 17, 99])
-        while any(eng._pending.values()):
-            out = eng.step(sampling=SamplingParams(temperature=0.0))
-        first = out[0]
-        before = eng.state.seqs[0].seen_tokens
-        # find what greedy decode produces, pick token #2 as the stop
-        probe = make_fp32_engine(m, decode_burst=4)
-        ref = probe.generate({0: [5, 17, 99]},
-                             SamplingParams(temperature=0.0,
-                                            max_new_tokens=5))
-        stop = ref[0][2]          # fires mid-burst (index 1 of the burst)
-        eng.put(0, [first])
-        out = eng.decode_burst(
-            4, sampling=SamplingParams(temperature=0.0, stop_token=stop))
-        assert out[0][-1] == stop
-        i = out[0].index(stop)
-        # KV rows committed = fed token + sampled tokens before the stop
-        assert eng.state.seqs[0].seen_tokens == before + i + 1
 
 
 class TestNewFamilyServing:
@@ -577,13 +467,6 @@ class TestAlibiServing:
         out = eng.generate({0: prompt}, SamplingParams(max_new_tokens=6))
         assert out[0] == self._eval_tokens(m, prompt, 6)
 
-    def test_burst_matches_eval(self):
-        m = self._model()
-        eng = make_fp32_engine(m, decode_burst=4)
-        prompt = [3, 1, 4, 1, 5]
-        out = eng.generate({0: prompt}, SamplingParams(max_new_tokens=8))
-        assert out[0] == self._eval_tokens(m, prompt, 8)
-
     def test_gqa_alibi_slopes_per_group(self):
         """GQA + ALiBi: slopes index full head ids (h = hkv*rep + r)."""
         m = self._model(num_heads=4, num_kv_heads=2)
@@ -598,9 +481,7 @@ class TestQuantizedKV:
     ZeRO-Inference KV quantization, deepspeed/inference/quantization/).
     The step-mode consumers — one-shot gather, chunked online-softmax,
     Pallas kernel — read the same quantized cache, so their outputs must
-    match each other EXACTLY.  The decode burst attends its in-burst
-    tail in full precision (quantized only on commit), so it is checked
-    by logits closeness, not exact tokens."""
+    match each other EXACTLY."""
 
     PROMPT = [5, 17, 99, 3, 42]
     GR = SamplingParams(temperature=0.0, max_new_tokens=8)
@@ -632,17 +513,6 @@ class TestQuantizedKV:
                                        eng.state.kv, b)
             lg[name] = np.asarray(out)[0]
         np.testing.assert_allclose(lg["q"], lg["fp"], atol=0.05, rtol=0.05)
-
-    def test_burst_runs_and_tracks_step_mode(self):
-        """The burst path serves a quantized cache; its tokens track the
-        step-mode quantized engine (exactness not guaranteed — the
-        in-burst tail is attended in full precision)."""
-        m = tiny_model()
-        xla = self._outs(m, kv_quant="int8", attn_impl="xla")
-        burst = self._outs(m, kv_quant="int8", attn_impl="xla",
-                           decode_burst=4)
-        assert len(burst) == self.GR.max_new_tokens
-        assert sum(a == b for a, b in zip(burst, xla)) >= 6
 
     def test_fp8_runs_and_matches_xla(self):
         m = tiny_model()
@@ -680,9 +550,9 @@ class TestServingProgramRecord:
 
     @pytest.mark.parametrize("attn_impl,over", [
         ("xla", {}), ("xla", {"kv_quant": "int8"}),
-        ("xla", {"kv_donate": "off"}), ("pallas", {"pipeline_depth": 1}),
+        ("xla", {"kv_donate": "off"}), ("pallas", {}),
         ("xla", {"token_budget": 256})],
-        ids=["bf", "int8kv", "no-donation", "pallas-sync", "two-rungs"])
+        ids=["bf", "int8kv", "no-donation", "pallas", "two-rungs"])
     def test_noted_once_without_a_second_compile(self, attn_impl, over):
         eng = make_fp32_engine(tiny_model(), attn_impl=attn_impl, **over)
         assert eng.serving_programs == {}

@@ -17,6 +17,7 @@ from deepspeed_tpu.config.config import MeshConfig
 from deepspeed_tpu.inference.engine import InferenceConfig, InferenceEngine
 from deepspeed_tpu.inference.sampler import SamplingParams
 from deepspeed_tpu.models.transformer import Model, TransformerConfig
+from tests.serving_ref import strict_generate
 
 PROMPTS = {0: list(range(1, 20)), 1: list(range(30, 37)),
            2: list(range(100, 103))}
@@ -47,9 +48,13 @@ def model():
     return Model(small_cfg(), seed=0)
 
 
-def run(model, cfg, topology=None, prompts=PROMPTS, sampling=GREEDY):
-    eng = InferenceEngine(model, cfg, topology=topology)
+def run_on(eng, prompts=PROMPTS, sampling=GREEDY):
     return eng.generate({u: list(p) for u, p in prompts.items()}, sampling)
+
+
+def run(model, cfg, topology=None, prompts=PROMPTS, sampling=GREEDY):
+    return run_on(InferenceEngine(model, cfg, topology=topology),
+                  prompts, sampling)
 
 
 def test_tp_generate_parity(devices, model):
@@ -66,12 +71,14 @@ def test_tp_pallas_shard_map_parity(devices, model):
     assert ref == tp
 
 
-def test_tp_gqa_decode_burst_parity(devices):
-    """GQA (Hkv < H) + device-side decode bursts under TP."""
+def test_tp_gqa_generate_equals_the_strict_loop(devices):
+    """GQA (Hkv < H) under TP: ``generate()`` over the mesh, one launch
+    ahead, against one chip's caller-fed loop."""
     model = Model(small_cfg(num_heads=8, num_kv_heads=4), seed=1)
-    ref = run(model, icfg(decode_burst=4))
-    tp = run(model, icfg(decode_burst=4), topology=topo_tp4_fsdp2(devices))
-    assert ref == tp
+    ref = strict_generate(InferenceEngine(model, icfg()), PROMPTS, GREEDY)
+    tp = InferenceEngine(model, icfg(), topology=topo_tp4_fsdp2(devices))
+    assert run_on(tp) == ref
+    assert tp.metrics_snapshot()["serving_steps_ahead_total"] > 0
 
 
 def test_tp_weight_quant_parity(devices, model):

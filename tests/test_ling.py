@@ -301,7 +301,7 @@ def test_hold_and_resume_with_a_row_launched_ahead(tiny):
 
 @pytest.mark.parametrize("option,value", [
     ("prefix_cache", "on"), ("spec_decode", "on"), ("kv_tier", "on"),
-    ("decode_burst", 4), ("attn_impl", "pallas")])
+    ("attn_impl", "pallas")])
 def test_engine_refuses_by_name_what_cannot_serve_the_model(tiny, option,
                                                             value):
     with pytest.raises(ValueError, match=option):

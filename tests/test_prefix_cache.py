@@ -20,6 +20,7 @@ from deepspeed_tpu.inference import (InferenceConfig, InferenceEngine,
                                      StateManager)
 from deepspeed_tpu.inference.ragged.allocator import BlockedAllocator
 from deepspeed_tpu.models import build_model
+from tests.serving_ref import strict_generate
 
 
 @pytest.fixture(scope="module")
@@ -298,14 +299,19 @@ class TestPrefixCacheParity:
         assert got[1][1][-1] == base[2]
         assert eng.timings["cached_tokens"] > 0
 
-    @pytest.mark.parametrize("depth", [1, 2])
-    def test_pipeline_depth_parity(self, model, depth):
+    @pytest.mark.parametrize("drive", [
+        strict_generate, InferenceEngine.generate], ids=["strict", "ahead"])
+    def test_parity_under_either_cadence(self, model, drive):
+        """A hit aliases blocks whether the caller feeds each token or
+        the engine runs a launch ahead; the cache-less reference is the
+        strict loop."""
         shared, tail = self._shared_traffic(3)
         waves = [{0: shared + tail(6)}, {1: shared + tail(2)}]
-        ref = self._run(mk(model, prefix_cache="off", pipeline_depth=depth,
-                           token_budget=16), waves, GREEDY)
-        eng = mk(model, pipeline_depth=depth, token_budget=16)
-        got = self._run(eng, waves, GREEDY)
+        off = mk(model, prefix_cache="off", token_budget=16)
+        ref = [strict_generate(off, w, GREEDY) for w in waves]
+        eng = mk(model, token_budget=16)
+        got = [drive(eng, {u: list(p) for u, p in w.items()}, GREEDY)
+               for w in waves]
         assert got == ref
         assert eng.timings["cached_tokens"] > 0
         check_allocator(eng)

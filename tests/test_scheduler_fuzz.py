@@ -196,11 +196,11 @@ def test_prefix_cache_fuzz_invariants(model, seed):
 
 
 def test_schedule_feedback_markers_admit_like_decodes(model):
-    """Deferred-feedback pendings (the pipelined driver's speculative
-    continuations) schedule exactly like concrete decode tokens — but
-    ONLY while owned by the most recent dispatch; a marker deferring to
-    an older still-uncollected step is held back (its value would be
-    read from the wrong sample array)."""
+    """Deferred-feedback pendings (the continuations ``step()`` launches
+    ahead) schedule exactly like concrete decode tokens.  A marker is
+    always owned by the most recent dispatch: ``step()`` reads launch N
+    back, which patches its markers concrete, in the call that launches
+    N+1."""
     eng = InferenceEngine(model, InferenceConfig(
         token_budget=16, max_seqs=3, kv_block_size=8, num_kv_blocks=6,
         max_seq_len=48))
@@ -215,10 +215,6 @@ def test_schedule_feedback_markers_admit_like_decodes(model):
     assert int(b.feedback_src[0]) == eng.state.slot(0)
     assert int(b.token_ids[0]) == 0          # host stages a benign id
     _check_pool_accounting(eng)
-    # marker owned by an OLDER dispatch: unschedulable until patched
-    eng._pending[0] = [FEEDBACK_TOKEN]
-    eng._fb_step[0] = eng._dispatch_seq - 1
-    assert eng._schedule() == []
 
 
 @pytest.mark.parametrize("seed", range(4))

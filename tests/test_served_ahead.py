@@ -26,6 +26,7 @@ from deepspeed_tpu.inference.overload import OverloadConfig
 from deepspeed_tpu.inference.ragged.state import (FEEDBACK_TOKEN,
                                                   KVCacheConfig,
                                                   StateManager)
+from tests.serving_ref import strict_generate
 from tools.loadgen import build_engine, build_fleet, http_completion
 
 GREEDY = SamplingParams(temperature=0.0, max_new_tokens=1 << 30)
@@ -189,10 +190,10 @@ def test_wire_streams_match_a_fleet_of_ones_strict_loop(model, sampling,
 @MODES
 def test_engine_continued_requests_match_generate(model, sampling, seed):
     rng = None if seed is None else jax.random.PRNGKey(seed)
-    want = engine(model, pipeline_depth=1).generate(
-        PROMPTS, SamplingParams(temperature=sampling.temperature,
-                                top_k=sampling.top_k, max_new_tokens=9),
-        rng=rng)
+    want = strict_generate(
+        engine(model), PROMPTS,
+        SamplingParams(temperature=sampling.temperature,
+                       top_k=sampling.top_k, max_new_tokens=9), rng=rng)
     eng = engine(model)
     for uid, p in PROMPTS.items():
         eng.put(uid, p, max_new_tokens=9)
@@ -335,8 +336,8 @@ def test_stalled_stream_pauses_and_resumes_with_the_right_token(model,
 @pytest.mark.parametrize("owned", [True, False],
                          ids=["engine_continued", "caller_fed"])
 def test_hold_takes_the_continuation_back(model, owned):
-    want = engine(model, pipeline_depth=1).generate(
-        {7: PROMPTS[101]}, SamplingParams(max_new_tokens=8))[7]
+    want = strict_generate(engine(model), {7: PROMPTS[101]},
+                           SamplingParams(max_new_tokens=8))[7]
     eng = engine(model)
     eng.put(7, PROMPTS[101], **({"max_new_tokens": 8} if owned else {}))
     got = []
@@ -480,8 +481,8 @@ def test_direct_put_step_caller_gets_its_token_from_the_same_call(model):
 
 
 def test_a_caller_fed_request_among_engine_continued_ones(model):
-    want = engine(model, pipeline_depth=1).generate(
-        {u: PROMPTS[u] for u in (100, 101, 104)},
+    want = strict_generate(
+        engine(model), {u: PROMPTS[u] for u in (100, 101, 104)},
         SamplingParams(max_new_tokens=10))
     eng = engine(model)
     eng.put(100, PROMPTS[100], max_new_tokens=10)
@@ -518,8 +519,8 @@ def test_speculative_engine_stays_strict(model):
         got += list(out.values())
         if eng._cont.get(3, 0) <= 0:
             break
-    want = engine(model, pipeline_depth=1).generate(
-        {3: prompt}, SamplingParams(max_new_tokens=12))[3]
+    want = strict_generate(engine(model), {3: prompt},
+                           SamplingParams(max_new_tokens=12))[3]
     assert eng.query(3)["generated"][:12] == want
     _, ahead, strict, _ = served(eng)
     assert ahead == 0 and set(strict) == {"spec_decode"}
@@ -599,8 +600,8 @@ def test_slow_collect_says_whether_the_next_launch_was_ready(model,
 # ==========================================================================
 
 def test_snapshot_reads_the_launch_in_flight_back_first(model):
-    want = engine(model, pipeline_depth=1).generate(
-        {u: PROMPTS[u] for u in (100, 101)},
+    want = strict_generate(
+        engine(model), {u: PROMPTS[u] for u in (100, 101)},
         SamplingParams(max_new_tokens=10))
     eng = engine(model)
     for uid in want:

@@ -19,6 +19,7 @@ from deepspeed_tpu.inference import (InferenceConfig, InferenceEngine,
                                      NgramProposer, SamplingParams,
                                      StateManager, KVCacheConfig)
 from deepspeed_tpu.models import build_model
+from tests.serving_ref import strict_generate
 
 
 @pytest.fixture(scope="module")
@@ -45,39 +46,20 @@ MIXED = {0: list(REPETITIVE), 1: [9, 2, 9, 2, 9, 2, 44],
 
 
 def drive_full(eng, prompts, sp, rng=None, preempt=None):
-    """Direct-API serving loop that keeps EVERY emitted token (an
+    """The strict caller-fed loop, keeping EVERY emitted token (an
     accepted verify window emits several per step); ``preempt=(uid,
     after_n_steps)`` force-evicts mid-run like the overload suite."""
-    for uid, p in prompts.items():
-        eng.put(uid, p)
-    done = {u: [] for u in prompts}
-    active = set(prompts)
-    draw = eng._rng_drawer(rng)
-    n = 0
-    while active:
-        st = eng._dispatch(sp, draw)
-        outs = eng._collect(st) if st is not None else {}
-        active -= eng._drain_reaped()
-        for uid, toks in outs.items():
-            if uid not in active:
-                continue
-            finished = False
-            for tok in toks:
-                done[uid].append(tok)
-                if len(done[uid]) >= sp.max_new_tokens:
-                    finished = True
-                    break
-            if finished:
-                active.discard(uid)
-                eng.flush(uid)
-            else:
-                eng.put(uid, [toks[-1]])
-        n += 1
+    def after_step(n):
         if preempt is not None and n == preempt[1] \
                 and preempt[0] in eng.state.seqs:
             eng._preempt(preempt[0])
-        assert n < 500, "drive_full() did not terminate"
-    return done
+
+    return strict_generate(eng, prompts, sp, rng=rng, after_step=after_step)
+
+
+DRIVES = pytest.mark.parametrize(
+    "drive", [drive_full, InferenceEngine.generate],
+    ids=["caller_fed", "engine_continued"])
 
 
 # --------------------------------------------------------------------------
@@ -236,14 +218,6 @@ class TestConfigGating:
         with pytest.raises(ValueError, match="spec_decode"):
             mk(model, spec_decode="maybe")
 
-    def test_on_with_burst_raises(self, model):
-        with pytest.raises(ValueError, match="decode_burst"):
-            mk(model, spec_decode="on", decode_burst=4)
-
-    def test_auto_defers_to_bursts(self, model):
-        eng = mk(model, spec_decode="auto", decode_burst=4)
-        assert eng._spec is None and eng._n_verify == 1
-
     def test_auto_resolves_off_today(self, model):
         """'auto' is the autotuner seam (ROADMAP item 4): until measured
         acceptance profiles drive it, it must resolve off so the
@@ -260,11 +234,9 @@ class TestConfigGating:
         assert eng._spec is not None and eng._n_verify == 4
 
     def test_weight_stream_forces_spec_off(self, tmp_path):
-        """THE needs-resident-weights gate: under ``weight_stream`` both
-        decode bursts and speculative windows force off through ONE
-        shared branch — one combined warning, and the engine really is
-        draft-free (its compiled step is the legacy single-sample
-        program)."""
+        """Under ``weight_stream`` speculative windows are forced off
+        with one warning, and the engine really is draft-free (its
+        compiled step is the legacy single-sample program)."""
         import logging
 
         m = build_model("llama-tiny", vocab_size=128, num_layers=3,
@@ -284,7 +256,7 @@ class TestConfigGating:
                 token_budget=16, max_seqs=2, kv_block_size=8,
                 num_kv_blocks=32, attn_impl="xla",
                 weight_stream=str(tmp_path / "w"),
-                spec_decode="on", spec_max_draft=2, decode_burst=1))
+                spec_decode="on", spec_max_draft=2))
         finally:
             lg.removeHandler(tap)
         assert eng.icfg.spec_decode == "off"
@@ -322,19 +294,21 @@ class TestConfigGating:
 # --------------------------------------------------------------------------
 
 class TestSpecParity:
-    """generate() outputs must be token-identical with spec_decode on vs
-    off — the draft source may only change HOW FAST tokens arrive."""
+    """Outputs must be token-identical with spec_decode on vs off — the
+    draft source may only change HOW FAST tokens arrive — whether the
+    caller feeds each token (a window is cut by the caller) or the
+    engine continues the request (``generate()``: a window is capped by
+    what the request may still emit)."""
 
-    @pytest.mark.parametrize("depth", [1, 2])
+    @DRIVES
     @pytest.mark.parametrize("cache", ["on", "off"])
-    def test_greedy_parity(self, model, depth, cache):
+    def test_greedy_parity(self, model, drive, cache):
         sp = SamplingParams(max_new_tokens=24)
-        ref = mk(model, spec_decode="off", pipeline_depth=depth,
-                 prefix_cache=cache).generate(
-            {u: list(p) for u, p in MIXED.items()}, sp)
+        ref = drive_full(mk(model, spec_decode="off", prefix_cache=cache),
+                         MIXED, sp)
         eng = mk(model, spec_decode="on", spec_max_draft=4,
-                 pipeline_depth=depth, prefix_cache=cache)
-        got = eng.generate({u: list(p) for u, p in MIXED.items()}, sp)
+                 prefix_cache=cache)
+        got = drive(eng, {u: list(p) for u, p in MIXED.items()}, sp)
         assert got == ref
         # the repetitive stream actually speculated (cycle attractor)
         assert eng.timings["spec_drafted_tokens"] > 0
@@ -342,16 +316,16 @@ class TestSpecParity:
         assert not eng.state.seqs and not eng.state._slots
         eng.state.allocator.assert_invariants()
 
-    @pytest.mark.parametrize("depth", [1, 2])
+    @DRIVES
     @pytest.mark.parametrize("cache", ["on", "off"])
-    def test_seeded_parity(self, model, depth, cache):
+    def test_seeded_parity(self, model, drive, cache):
         sp = SamplingParams(temperature=1.0, top_k=8, max_new_tokens=16)
         outs = {}
         for spec in ("off", "on"):
             eng = mk(model, spec_decode=spec, spec_max_draft=4,
-                     pipeline_depth=depth, prefix_cache=cache)
-            outs[spec] = eng.generate(
-                {u: list(p) for u, p in MIXED.items()}, sp,
+                     prefix_cache=cache)
+            outs[spec] = drive(
+                eng, {u: list(p) for u, p in MIXED.items()}, sp,
                 rng=jax.random.PRNGKey(7))
         assert outs["on"] == outs["off"]
 
@@ -371,11 +345,10 @@ class TestSpecParity:
         stop = max(first, key=first.get)
         sps = SamplingParams(max_new_tokens=32, stop_token=stop)
         want = ref[:ref.index(stop) + 1]
-        for depth in (1, 2):
-            eng = mk(model, spec_decode="on", spec_max_draft=4,
-                     pipeline_depth=depth)
-            got = eng.generate({1: list(REPETITIVE)}, sps)[1]
-            assert got == want, f"depth={depth}"
+        for drive in (drive_full, InferenceEngine.generate):
+            eng = mk(model, spec_decode="on", spec_max_draft=4)
+            got = drive(eng, {1: list(REPETITIVE)}, sps)[1]
+            assert got == want, drive.__name__
             assert eng.timings["spec_accepted_tokens"] > 0
 
     def test_preemption_parity(self, model):
@@ -455,8 +428,7 @@ class TestSpecAccounting:
         sp = SamplingParams(max_new_tokens=32)
         steps = {}
         for spec in ("off", "on"):
-            eng = mk(model, spec_decode=spec, spec_max_draft=4,
-                     pipeline_depth=1)
+            eng = mk(model, spec_decode=spec, spec_max_draft=4)
             eng.generate({1: list(REPETITIVE)}, sp)
             steps[spec] = eng.timings["steps"]
         assert steps["on"] < steps["off"]

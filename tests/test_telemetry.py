@@ -22,6 +22,7 @@ from deepspeed_tpu.models import build_model
 from deepspeed_tpu.telemetry import (CounterDictView, MetricsRegistry,
                                      RequestTracker, SpanTracer,
                                      parse_prometheus_text)
+from tests.serving_ref import strict_generate
 
 
 def tiny_model(**over):
@@ -461,20 +462,20 @@ class TestRequestTracker:
         assert rec.tpot_ms is None               # no decode tail
         assert t.aggregate()["tpot_ms"]["count"] == 0
 
-    def test_burst_emission_anchors_decode_tail(self):
-        """An n>1 burst lands all tokens at one readback instant; the
-        decode tail anchors at the burst's dispatch time so TPOT
-        doesn't collapse to zero, while TTFT stays at readback (the
-        host can't see the tokens earlier)."""
+    def test_window_emission_counts_every_token_of_the_tail(self):
+        """A resolved verify window lands several tokens at one
+        readback instant: all of them count in the decode tail, which
+        is anchored at the first token."""
         t = RequestTracker(MetricsRegistry())
         t.on_arrival(1, now=0.0)
         t.on_admitted(1, 2, 0, now=0.1)
-        t.on_tokens(1, 4, 1.0, t_dispatch=0.2)   # one 4-token burst
+        t.on_tokens(1, 1, 0.2)
+        t.on_tokens(1, 3, 1.0)                   # one 3-token window
         t.on_finish(1, now=1.1)
         (rec,) = t.records()
-        assert rec.ttft_ms == pytest.approx(1000.0)
+        assert rec.ttft_ms == pytest.approx(200.0)
+        assert rec.generated_tokens == 4
         assert rec.tpot_ms == pytest.approx((1.0 - 0.2) * 1e3 / 3)
-        # stepwise records are unaffected: tail anchor == first token
         t.on_arrival(2, now=0.0)
         t.on_tokens(2, 1, 1.0)
         t.on_tokens(2, 1, 1.5)
@@ -515,13 +516,14 @@ def _assert_parity(eng):
 class TestEngineTelemetry:
     MIXED = {0: list(range(1, 51)), 1: [3, 1, 4], 2: list(range(60, 80))}
 
-    @pytest.mark.parametrize("depth", [1, 2])
-    def test_parity_mixed_chunked_traffic(self, model, depth):
+    @pytest.mark.parametrize("drive", [
+        strict_generate, InferenceEngine.generate], ids=["strict", "ahead"])
+    def test_parity_mixed_chunked_traffic(self, model, drive):
         """Prompts straddling the token budget (chunked prefill + decode
-        mixed steps) at both pipeline depths."""
-        eng = make_engine(model, pipeline_depth=depth, token_budget=16)
+        mixed steps), fed by the caller and run a launch ahead."""
+        eng = make_engine(model, token_budget=16)
         sp = SamplingParams(max_new_tokens=6)
-        out = eng.generate({u: list(p) for u, p in self.MIXED.items()}, sp)
+        out = drive(eng, {u: list(p) for u, p in self.MIXED.items()}, sp)
         _assert_parity(eng)
         tm = eng.timings
         assert tm["prompt_tokens"] == sum(len(p) for p in
@@ -560,22 +562,11 @@ class TestEngineTelemetry:
             assert tm["cached_tokens"] == 0 == tm["prefix_hits"]
         assert eng.request_metrics()["aggregate"]["finished"] == 3
 
-    def test_parity_decode_burst(self, model):
-        """The burst path (device-side multi-token decode) bumps the
-        same counters as the stepwise collect."""
-        eng = make_engine(model, decode_burst=4)
-        sp = SamplingParams(max_new_tokens=8)
-        out = eng.generate({0: [5, 17, 99], 1: [7, 7, 1, 2]}, sp)
-        assert all(len(v) == 8 for v in out.values())
-        _assert_parity(eng)
-        assert eng.timings["generated_tokens"] \
-            >= sum(len(v) for v in out.values())
-
     def test_trace_export_has_serving_span_types(self, model, tmp_path):
-        """A pipelined generate() with tracing on exports a valid Chrome
+        """A generate() with tracing on exports a valid Chrome
         trace carrying >= 4 distinct serving-loop span types, one track
         each (the acceptance-criteria artifact)."""
-        eng = make_engine(model, pipeline_depth=2, trace=True)
+        eng = make_engine(model, trace=True)
         eng.generate({0: list(range(1, 40)), 1: [9, 8, 7]},
                      SamplingParams(max_new_tokens=5))
         path = str(tmp_path / "serving_trace.json")

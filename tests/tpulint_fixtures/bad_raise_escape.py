@@ -28,7 +28,7 @@ class Engine:
             raise DispatchTimeoutError("device stalled")
         return fn()
 
-    def decode_burst(self, fn):  # tpulint: serving-loop  # BAD: direct raise
+    def generate(self, fn):  # tpulint: serving-loop  # BAD: direct raise
         if fn is None:
             raise InjectedFault("chaos tier fault")
         return fn()

@@ -23,11 +23,11 @@ class Engine:
             logger.warning("collect failed; dropping step")
             return {}
 
-    def decode_burst(self, fn):  # tpulint: serving-loop
+    def generate(self, fn):  # tpulint: serving-loop
         try:
             return fn()
         except BaseException as e:                   # BAD: swallows all
-            logger.error("burst failed: %s", e)
+            logger.error("generate failed: %s", e)
             self._retry = True
             return {}
 

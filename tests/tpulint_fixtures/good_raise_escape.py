@@ -27,7 +27,7 @@ class Engine:
             raise DispatchTimeoutError("device stalled")
         return fn()
 
-    def decode_burst(self, fn):  # tpulint: serving-loop
+    def generate(self, fn):  # tpulint: serving-loop
         try:
             return self._inject(fn)
         except Exception as e:

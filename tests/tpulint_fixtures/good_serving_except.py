@@ -24,7 +24,7 @@ class Engine:
                 raise
             return {}
 
-    def decode_burst(self, fn):  # tpulint: serving-loop
+    def generate(self, fn):  # tpulint: serving-loop
         try:
             return fn()
         except Exception:

@@ -949,8 +949,7 @@ def _metric_name_literal(arg: ast.AST):
       "registry metric names must match ^(serving|training)_[a-z0-9_]+$ "
       "and each name must be registered from exactly one source site — "
       "a typo'd or duplicated registration silently forks a second "
-      "series that dashboards and the benchdiff sentinel never join "
-      "back up", library_only=True, scope="program")
+      "series that dashboards never join back up", library_only=True, scope="program")
 def check_metric_name(program) -> Iterator[Finding]:
     sites: Dict[str, List[Tuple[str, int]]] = {}
     for mod in program.modules.values():
@@ -1142,8 +1141,8 @@ def check_profiler_capture(ctx: FileContext) -> Iterator[Finding]:
 # trip it while `self.backend.step()` / `eng.generate()` do
 _ASYNC_ENGINE_SEAMS = {"generate", "step", "drain", "put", "flush",
                        "cancel", "query", "snapshot", "load_snapshot",
-                       "decode_burst", "migrate_out", "health",
-                       "health_state", "prometheus_text"}
+                       "migrate_out", "health", "health_state",
+                       "prometheus_text"}
 _ASYNC_ENGINE_RECV = {"backend", "engine", "eng", "router", "fleet",
                       "replica", "rep", "metrics", "fleet_registry"}
 
