@@ -45,13 +45,13 @@ from .failures import (FATAL_ENGINE, POISON_STEP,
                        DispatchTimeoutError, EngineDeadError,
                        FailureConfig, FailurePolicy, InjectedFault,
                        InjectedTimeout, bisect_groups, classify_failure)
-from .model import (MOE_STAT_ROWS, fold_projection, fold_projections,
+from .model import (fold_projection, fold_projections, moe_stat_rows,
                     pipelined_ragged_step, ragged_forward)
 from .overload import (AdmissionVerdict, OverloadConfig, RequestMeta,
                        admission_decision, effective_priority,
                        select_victim)
 from .ragged.state import (FEEDBACK_TOKEN, BatchStager, KVCacheConfig,
-                           RaggedBatch, StateManager, step_rows)
+                           RaggedBatch, RunCut, StateManager, step_rows)
 from .sampler import SamplingParams, sample_rows
 
 
@@ -395,6 +395,21 @@ class InferenceEngine:
         # pool holds its latent layers' rows (a row a token), the state
         # rows its recurrent layers'
         latent = "mla" in self.cfg.mixer_stacks
+        run_cut = None
+        if latent and self._recurrent is None:
+            # a latent-only model: the pool is its one cache, a plain
+            # block pool (prefix hits alias its blocks, a rewound row is
+            # written again).  What is not written for it is refused
+            if self.icfg.spec_decode == "on":
+                raise ValueError(
+                    "spec_decode='on': a latent layer reads a verify "
+                    "window as a run of several tokens and a step holds "
+                    "a bounded number of those (RunCut.scan_runs); use "
+                    "'auto' or 'off'")
+            if topology is not None and topology.device_count > 1:
+                raise NotImplementedError(
+                    "serving over a mesh: latent attention is not sharded")
+            run_cut = RunCut(chunk=self.cfg.kda_chunk)
         kv_cfg = KVCacheConfig(
             num_layers=(self.cfg.layers_of("mla") if self.cfg.mixer_stacks
                         else self.cfg.num_layers),
@@ -405,7 +420,7 @@ class InferenceEngine:
             num_blocks=self.icfg.num_kv_blocks,
             dtype=self.icfg.kv_dtype,
             quant=self.icfg.kv_quant or "none",
-            recurrent=self._recurrent)
+            recurrent=self._recurrent, run_cut=run_cut)
         self.state = StateManager(kv_cfg, max_seqs=self.icfg.max_seqs,
                                   max_blocks_per_seq=self.max_blocks_per_seq,
                                   # a prefix hit aliases blocks and
@@ -499,14 +514,14 @@ class InferenceEngine:
                                    self.icfg.max_seqs,
                                    self.icfg.num_kv_blocks,
                                    n_verify=self._n_verify,
-                                   n_chunks=0 if self._recurrent is None
-                                   else self._recurrent.n_chunks(
+                                   n_chunks=0 if kv_cfg.runs is None
+                                   else kv_cfg.runs.n_chunks(
                                        self.icfg.token_budget))
         # spec engines' steps return [S, W] windows, so the feedback
         # operand (and its step-0 zero fallback) is window-shaped too
         # a sparse-expert model's steps append their routing statistics
-        rows = self.icfg.max_seqs + (MOE_STAT_ROWS if self.cfg.num_experts
-                                     > 1 else 0)
+        rows = self.icfg.max_seqs + (moe_stat_rows(self.cfg)
+                                     if self.cfg.num_experts > 1 else 0)
         self._zero_toks = self._stage(jnp.zeros(
             (rows,) if self._n_verify == 1
             else (rows, self._n_verify), jnp.int32))
@@ -531,6 +546,7 @@ class InferenceEngine:
         self._deadline_uids: set = set()          # uids with a deadline
         self._inflight_sched: Dict[int, int] = {} # uid -> uncollected steps
         self._preempting: set = set()             # release() = preemption
+        self._round_preemptions = 0     # evictions of the last schedule
         self._preempt_gen: Dict[int, List[int]] = {}  # pre-eviction tokens
         # tpulint: live-set — uid -> staged terminal status
         self._closing: Dict[int, str] = {}
@@ -823,7 +839,8 @@ class InferenceEngine:
         if self.state.cfg.latent_dim:
             reg.gauge_fn(
                 "serving_latent_pool_bytes",
-                lambda: self.state.kv["kv"].nbytes,
+                lambda: (self.state.kv["kv"] if self._recurrent is not None
+                         else self.state.kv).nbytes,
                 "bytes of the latent pool: a row a token, the latent "
                 "layers only")
         # sparse experts (parallel/moe.py moe_serve): read from the rows
@@ -840,7 +857,9 @@ class InferenceEngine:
                     "share of its experts "
                     "(experts_held) labels them where: held = computed "
                     "here | absent = made by the router for experts that "
-                    "are not here"),
+                    "are not here | zero = made for experts that compute "
+                    "nothing (moe_zero_experts): the row's input back, "
+                    "times the weight"),
                 reg.gauge(
                     "serving_moe_expert_load_max_over_mean",
                     "rows of the fullest expert over the mean rows an "
@@ -1046,22 +1065,26 @@ class InferenceEngine:
         of ``min(seen + n, window + n - 1)``.  Where the Pallas kernel
         serves, also ``kv_steps_full`` / ``kv_steps_window``: the grid
         steps its short call makes in one such layer that hold a needed
-        block (``ops/paged_attention.group_steps``)."""
+        block (``ops/paged_attention.group_steps``).  A model whose cache
+        is a latent pool: ``latent_tokens``, that sum for ONE latent layer,
+        and ``latent_pairs``, the (query, cached row) pairs its causal
+        attention holds (``n * seen + n (n + 1) / 2`` a run of n rows)."""
         w = self._window
-        full = window = 0
+        full = window = pairs = 0
         short = []
         for uid, toks in sched:
             seq = self.state.seqs.get(uid)
             seen = seq.seen_tokens if seq else 0
             ctx = seen + len(toks)
             full += ctx
+            pairs += len(toks) * seen + len(toks) * (len(toks) + 1) // 2
             if w:
                 window += min(ctx, w + len(toks) - 1)
             if 0 < len(toks) <= SHORT:
                 short.append((seen, len(toks)))
         if self.state.cfg.latent_dim:
             self._c_attn_kv.inc(full, kind="latent")
-            return {"latent_tokens": full}
+            return {"latent_tokens": full, "latent_pairs": pairs}
         args = {"kv_tokens_full": full}
         self._c_attn_kv.inc(full, kind="full")
         if w:
@@ -2168,10 +2191,11 @@ class InferenceEngine:
         sched_uids: set = set()
         preempts_left = (ocfg.max_preemptions_per_step
                          if ocfg.preemption else 0)
+        self._round_preemptions = 0     # the stage span's ``preemptions``
         # a model with recurrent layers: the runs of several tokens a
         # step may hold (its chunk table is of fixed size)
-        scan_runs_left = self._recurrent.scan_runs \
-            if self._recurrent is not None else 0
+        run_cut = self.state.cfg.runs
+        scan_runs_left = run_cut.scan_runs if run_cut is not None else 0
 
         def admit(uid, toks) -> str:
             """"ok" (tokens or a cache match landed), "starved" (the
@@ -2231,7 +2255,7 @@ class InferenceEngine:
                 if limit > 0:
                     draft = self._spec.propose(uid, toks[0], limit)
             n = min(len(toks), budget, ctx_rem)
-            if self._recurrent is not None and n > 1:
+            if run_cut is not None and n > 1:
                 if seq is not None and seq.state_ahead:
                     n = 1            # the row fed again goes alone
                 elif scan_runs_left <= 0:
@@ -2394,6 +2418,7 @@ class InferenceEngine:
         (tests/test_scheduler_fuzz.py parity test)."""
         self._evict_to_queue(uid)
         self.requests.on_preempted(uid)
+        self._round_preemptions += 1
 
     def _reap_deadlines(self, now: float) -> None:
         """Terminally close every request whose ``deadline_ms`` elapsed
@@ -3373,7 +3398,7 @@ class InferenceEngine:
             tiles.update(self._count_state_rows(sched))
         t1 = tr.phase("ds.serve.stage", track="stage", sid=sid,
                       n_tokens=n_tokens, rows=n_rows, n_seqs=len(sched),
-                      mbs=mbs,
+                      mbs=mbs, preemptions=self._round_preemptions,
                       **tiles, **self._count_attn_kv(sched, pallas))
         batch = self._stage(
             self.state.build_batch(
@@ -3732,20 +3757,27 @@ class InferenceEngine:
         moe: Dict[str, float] = {}
         if self._moe_metrics is not None:
             # the routing statistics rode the tokens' own readback
-            n, load, touched = toks_np[-MOE_STAT_ROWS:].reshape(
-                MOE_STAT_ROWS, -1)[:, 0]
+            rows = moe_stat_rows(self.cfg)
+            n, load, touched, *nothing = toks_np[-rows:].reshape(
+                rows, -1)[:, 0]
             moe = {"moe_assignments": int(n), "moe_load": load / 1e3,
                    "moe_experts_touched": int(touched)}
-            if self.cfg.experts_held is None:
+            if self.cfg.experts_held is None and not nothing:
                 self._moe_metrics[0].inc(moe["moe_assignments"])
             else:
                 # the router made top_k a real row a layer; the step
-                # computed those that fell on the experts held here
-                made = st.n_tokens * self.cfg.moe_top_k * (
-                    self.cfg.num_layers - self.cfg.num_dense_layers)
+                # computed those that fell on the experts held here, and
+                # gave the input back for those that compute nothing
+                made = st.n_tokens * self.cfg.moe_top_k \
+                    * self.cfg.expert_layers
+                zero = int(nothing[0]) if nothing else 0
                 moe["moe_assignments_made"] = made
                 self._moe_metrics[0].inc(int(n), where="held")
-                self._moe_metrics[0].inc(made - int(n), where="absent")
+                self._moe_metrics[0].inc(made - int(n) - zero,
+                                         where="absent")
+                if nothing:
+                    moe["moe_zero_assignments"] = zero
+                    self._moe_metrics[0].inc(zero, where="zero")
             self._moe_metrics[1].set(moe["moe_load"])
         t2 = tr.phase_end(**moe)
         self._c_guard_hop.inc(hop_us / 1e3)

@@ -41,6 +41,12 @@ from .sampler import row_keys, window_keys
 # experts that took a row
 MOE_STAT_ROWS = 3
 
+
+def moe_stat_rows(cfg: TransformerConfig) -> int:
+    """``MOE_STAT_ROWS``, and one more for a model with experts that
+    compute nothing: the assignments to them."""
+    return MOE_STAT_ROWS + bool(cfg.moe_zero_experts)
+
 _KV_QMAX = {jnp.dtype(jnp.int8): 127.0,
             jnp.dtype(jnp.float8_e4m3fn): 448.0}
 
@@ -484,6 +490,17 @@ def _qkv_proj(cfg, ap, h, dt, cos, sin, positions, kind: str = "full"):
     return q, k, v
 
 
+def _latent_runs(batch: RaggedBatch):
+    """A step's runs as a latent layer reads them, for a model that has
+    no recurrent layer beside it (``_ssm_runs`` gives the same keys and
+    the state's): from ``batch.rec``, once a step."""
+    rec = batch.rec
+    one = rec.run_len == 1
+    return dict(S=rec.run_len.shape[0], one=one, chunks=rec.chunks,
+                last=jnp.maximum(batch.logits_idx, 0),
+                row_one=one[batch.seq_slot])
+
+
 def _ssm_runs(batch: RaggedBatch, width: int):
     """A step's runs as the mixer reads them, from ``batch.rec``: once a
     step, outside the layer scan.  Per slot: the flat rows of its run's
@@ -726,9 +743,15 @@ def _latent_attention(cfg, ap, h, pool, layer, batch: RaggedBatch, runs,
     dims = cfg.mla_dims
     S, T = runs["S"], h.shape[0]
     base, nrows = layer
+    def mm(x, w):
+        y = _mm(x, w, dt)
+        # a product whose rows are cut into heads behind it exists as
+        # rows first, as ``_head_proj``'s (the query latent's ``W_qb``)
+        return jax.lax.optimization_barrier(y) if dims.q_rank else y
+
     with jax.named_scope("latent_in"):
         q_n, q_r, row = A.project(ap, h, cos, sin, batch.positions, dims,
-                                  cfg.eps, lambda x, w: _mm(x, w, dt))
+                                  cfg.eps, mm)
         qf = A.fold_query(ap, q_n, q_r, dims)               # [T, H, row]
         if cfg.mla_gate == "head":
             gate = _mm(h, ap["wg"], dt)
@@ -759,7 +782,8 @@ def _latent_attention(cfg, ap, h, pool, layer, batch: RaggedBatch, runs,
         if cfg.mla_gate == "head":
             o = o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(
                 dt)[..., None]
-        return _mm(o.reshape(T, -1), ap["wo"], dt, contract_dims=2), pool
+        return _mm(o.reshape(T, -1), ap["wo"], dt,
+                   contract_dims=len(ap["wo"].shape) - 1), pool
 
 
 def _dense_weight(w) -> bool:
@@ -909,6 +933,8 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
         kv = kv["kv"]
         runs = _ssm_runs(batch, cfg.kda_conv if cfg.mixer_stacks
                          else cfg.ssm_conv)
+    elif "mla" in cfg.mixer_stacks:
+        runs = _latent_runs(batch)
     if quant is not None:
         from .quantization import merge_layer
         from ..ops.quant import dequantize_any
@@ -922,6 +948,9 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
     act = L.ACTIVATIONS[cfg.activation]
     scale = (cfg.attn_scale if cfg.attn_scale is not None
              else 1.0 / (cfg.head_dim ** 0.5))
+    pattern = cfg.layer_pattern
+    P = len(pattern)
+    lead, periods, tail = cfg.layer_plan
 
     x = L.embed(embed_tab, batch.token_ids).astype(dt)             # [T, dm]
     if cfg.embed_scale is not None:
@@ -961,19 +990,31 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
         experts = blocks["experts"]
         blocks = {k: v for k, v in blocks.items() if k != "experts"}
 
-    def ffn(x, o, lp, li):
+    def ffn(x, o, lp, li, skip=None):
         """A layer's second half: the residual, the MLP or the experts
-        → (x, stats)."""
+        → (x, stats, skip).  In a shortcut-connected layer
+        (``moe_shortcut``) every sublayer has a dense MLP; the first
+        holds the experts too, which read the same normed input and
+        whose output is ``skip``: it joins the stream behind the last
+        sublayer's MLP and nothing between reads it."""
+        kw = dict(comm=comm, valid=batch.token_valid,
+                  sharded=shard_mesh is not None, routing=with_routing)
         with jax.named_scope("ffn"):
             x = x + o
-            d, stats = _ffn(cfg, lp, norm(lp["ln2"], x), dt, act, comm=comm,
-                            valid=batch.token_valid,
-                            sharded=shard_mesh is not None,
-                            experts=None if experts is None
-                            else (experts, li), routing=with_routing)
-        return x + d, stats
+            h = norm(lp["ln2"], x)
+            if not cfg.moe_shortcut:
+                d, stats = _ffn(cfg, lp, h, dt, act, experts=None
+                                if experts is None else (experts, li), **kw)
+                return x + d, stats, None
+            d, stats = _ffn(cfg, {"mlp": lp["mlp"]}, h, dt, act, **kw)
+            if "gate" not in lp:
+                return x + d + skip, None, None
+            skip, stats = _ffn(cfg, {"gate": lp["gate"]}, h, dt, act,
+                               experts=(experts, li // P), **kw)
+        return x + d, stats, skip
 
-    def block(x, lp, pool, layer, li, kind, rec=None, rank=None):
+    def block(x, lp, pool, layer, li, kind, rec=None, rank=None,
+              skip=None):
         """One layer's mathematics.  ``pool`` is the stacked paged
         cache, which holds the layer where ``layer`` says
         (``_layer_of``).  ``li``: the layer's index in ``blocks``, for
@@ -982,7 +1023,9 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
         layer returns them updated, last.  ``rank``: a "kda" layer's
         rank among the layers that hold a state (it holds no blocks; an
         "mla" layer holds no state, and ``layer`` says where in the
-        latent pool its blocks lie)."""
+        latent pool its blocks lie).  ``skip``: the expert output a
+        shortcut-connected layer carries to its end (``ffn``), which a
+        "kda" or "mla" layer returns behind ``rec``."""
         if kind in ("kda", "mla"):
             h = norm(lp["ln1"], x)
             with jax.named_scope("attn"):
@@ -993,8 +1036,8 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
                     o, pool = _latent_attention(
                         cfg, lp["mla"], h, pool, layer, batch, runs, cos,
                         sin, dt, block_size, max_blocks_per_seq)
-            x, stats = ffn(x, o, lp, li)
-            return x, pool, stats, rec
+            x, stats, skip = ffn(x, o, lp, li, skip)
+            return x, pool, stats, rec, skip
         ap = lp["attn"]
         window = cfg.attn_window if kind == "window" else None
         # named scopes at the block's seams (metadata only): a device
@@ -1064,9 +1107,6 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
             return x + o + d, pool, stats
         return x + d, pool, stats
 
-    pattern = cfg.layer_pattern
-    P = len(pattern)
-    lead, periods, tail = cfg.layer_plan
     # what the Pallas kernel's grid walks is the same for every layer of
     # a kind: cut the batch into query tiles here, once, outside the scan,
     # and lay their tables out by the grid steps of each kind's calls
@@ -1164,8 +1204,9 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
         if with_routing:
             stats, ids = stats
         if with_moe_stats:       # per-layer [L, 3] -> the step's [3]
-            out += (jnp.stack([stats[:, 0].sum(), stats[:, 1].max(),
-                               stats[:, 2].sum()]),)
+            out += (jnp.stack(
+                [stats[:, 0].sum(), stats[:, 1].max(), stats[:, 2].sum()]
+                + [stats[:, i].sum() for i in range(3, stats.shape[1])]),)
         if with_routing:
             out += (ids,)
     return out
@@ -1185,38 +1226,60 @@ def _stacked_layers(cfg, params, blocks, block, x, pool, rec, rows: int):
     P = len(pattern)
     lead, periods, tail = cfg.layer_plan
     per_period = {k: pattern.count(k) for k in stacks}
+    # what a period's first layer alone holds (``moe_shortcut``)
+    firsts = ("gate",) if cfg.moe_shortcut else ()
+    per_period.update({k: 1 for k in firsts})
     in_pattern = [pattern[:j].count(kind) for j, kind in enumerate(pattern)]
     before = {k: cfg.kind_rank(lead, k) for k in stacks}
 
-    def one(x, pool, rec, lp, li, kind, rank):
+    def one(x, pool, rec, lp, li, kind, rank, skip=None):
         return block(x, lp, pool, (rank * rows, rows), li, kind, rec,
-                     rank=rank)
+                     rank=rank, skip=skip)
 
     def outside(x, pool, rec, stack, first, n, layer0):
         stats = []
         for i in range(n):
             layer = layer0 + i
-            x, pool, st, rec = one(
+            x, pool, st, rec, _ = one(
                 x, pool, rec, stack_layer(cfg, stack, layer,
                                           layer0 - first), first + i,
                 cfg.layer_kinds[layer], cfg.kind_rank(layer))
             stats.append(st)
         return x, pool, rec, stats
 
+    def layer_of(name, sub, j, period):
+        """Layer ``j`` of a period's weights of ``name``, out of the
+        period's rows of the stack (``periods_of``), or for a shortcut-
+        connected model out of the whole stack."""
+        row = in_pattern[j] if name in stacks else 0 if name in firsts \
+            else j
+        if not cfg.moe_shortcut:
+            return jax.tree.map(lambda a: a[row], sub)
+        # read where it lies, at its own row of the stack: cut out of a
+        # period's rows (the stack viewed ``[periods, rows a period,
+        # ...]``) a layer is copied whole on its way into its products,
+        # every byte of it a step (PERF.md section 6, PR 49)
+        row += period * per_period.get(name, P)
+        return jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
+            a, row, keepdims=False), sub)
+
     def carried(carry, ws):
         x, pool, rec = carry
         period_w, period = ws
-        stats = []
+        stats, skip = [], None
         for j, kind in enumerate(pattern):
-            lp = {name: jax.tree.map(
-                lambda a, at=in_pattern[j] if name in stacks else j: a[at],
-                sub) for name, sub in period_w.items()
-                if name not in stacks or name == kind}
-            x, pool, st, rec = one(
+            lp = {name: layer_of(name, sub, j, period)
+                  for name, sub in period_w.items()
+                  if (name not in stacks or name == kind)
+                  and (name not in firsts or j == 0)}
+            x, pool, st, rec, skip = one(
                 x, pool, rec, lp, period * P + j, kind,
-                before[kind] + period * per_period[kind] + in_pattern[j])
-            stats.append(st)
-        return (x, pool, rec), jax.tree.map(lambda *v: jnp.stack(v), *stats)
+                before[kind] + period * per_period[kind] + in_pattern[j],
+                skip)
+            if st is not None:
+                stats.append(st)
+        return (x, pool, rec), (jax.tree.map(lambda *v: jnp.stack(v), *stats)
+                                if stats else None)
 
     def periods_of(name, a):
         n = per_period.get(name, P)
@@ -1225,11 +1288,17 @@ def _stacked_layers(cfg, params, blocks, block, x, pool, rec, rows: int):
     if lead:
         x, pool, rec, _ = outside(x, pool, rec, params["dense_blocks"], 0,
                                   lead, 0)
-    (x, pool, rec), stats = jax.lax.scan(
-        carried, (x, pool, rec),
-        ({name: jax.tree.map(lambda a, name=name: periods_of(name, a), sub)
-          for name, sub in blocks.items()},
-         jnp.arange(periods, dtype=jnp.int32)))
+    if cfg.moe_shortcut:
+        # the stacks stay whole outside the scan's sliced inputs
+        (x, pool, rec), stats = jax.lax.scan(
+            lambda carry, period: carried(carry, (blocks, period)),
+            (x, pool, rec), jnp.arange(periods, dtype=jnp.int32))
+    else:
+        (x, pool, rec), stats = jax.lax.scan(
+            carried, (x, pool, rec),
+            ({name: jax.tree.map(lambda a, name=name: periods_of(name, a),
+                                 sub) for name, sub in blocks.items()},
+             jnp.arange(periods, dtype=jnp.int32)))
     outside_stats = []
     if tail:
         x, pool, rec, outside_stats = outside(
@@ -1322,14 +1391,15 @@ def pipelined_ragged_step(cfg: TransformerConfig, params, quant, kv,
 
     def with_stats(toks):
         # a sparse-expert model's routing statistics ride the sampled
-        # tokens' own readback as MOE_STAT_ROWS trailing rows (the
+        # tokens' own readback as ``moe_stat_rows`` trailing rows (the
         # feedback gather never reaches them: slots are < max_seqs)
         if not moe:
             return toks
+        n = moe_stat_rows(cfg)
         rows = stats[0].astype(toks.dtype).reshape(
-            (MOE_STAT_ROWS,) + (1,) * (toks.ndim - 1))
+            (n,) + (1,) * (toks.ndim - 1))
         return jnp.concatenate([toks, jnp.broadcast_to(
-            rows, (MOE_STAT_ROWS,) + toks.shape[1:])])
+            rows, (n,) + toks.shape[1:])])
 
     if batch.verify_idx is not None:
         S, W = batch.verify_idx.shape

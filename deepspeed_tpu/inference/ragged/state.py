@@ -145,6 +145,19 @@ class RecurrentConfig:
 
 
 @dataclasses.dataclass
+class RunCut:
+    """How a step's runs of several tokens are cut for a latent layer of
+    a model with no recurrent layer beside it (``RecurrentConfig`` says
+    the same of a model that has one): chunks of at most ``chunk`` rows,
+    at most ``scan_runs`` such runs a step (``RecBatch``)."""
+    chunk: int
+    scan_runs: int = 4
+
+    def n_chunks(self, token_budget: int) -> int:
+        return -(-token_budget // self.chunk) + self.scan_runs
+
+
+@dataclasses.dataclass
 class KVCacheConfig:
     num_layers: int
     num_kv_heads: int
@@ -164,6 +177,15 @@ class KVCacheConfig:
     # the keys and values of ``num_kv_heads`` heads; ``num_layers`` then
     # counts the latent layers
     latent_dim: int = 0
+    # a latent-only model: how its layers read a step's runs
+    run_cut: Optional[RunCut] = None
+
+    @property
+    def runs(self):
+        """What cuts a step's runs into ``RecBatch``: the recurrent
+        layers' configuration, a latent-only model's ``run_cut``, or
+        None for a model whose layers read rows alone."""
+        return self.recurrent or self.run_cut
 
     @property
     def max_context(self) -> int:
@@ -963,7 +985,7 @@ class StateManager:
         instead of breaking it: the host learns the token one step
         late, in order."""
         T = token_budget
-        rc = self.cfg.recurrent
+        rc = self.cfg.runs
         n_chunks = 0
         # fresh registration ledger for this round (see round_registered)
         self.round_registered = []
@@ -1088,7 +1110,7 @@ class StateManager:
         the stager's next set cut to ``T`` rows where it is as wide as
         this manager and at least as long, else fresh ones."""
         S, nb = self.max_seqs, self.cfg.num_blocks
-        rc = self.cfg.recurrent
+        rc = self.cfg.runs
         if stager is not None and stager.shape_key[1:] == (S, nb) \
                 and stager.shape_key[0] >= T \
                 and stager.n_verify >= n_verify:

@@ -384,6 +384,46 @@ PRESETS: Dict[str, dict] = {
                            moe_norm_topk=True, moe_route_scale=2.5,
                            moe_groups=8, moe_groups_kept=4,
                            moe_dispatch="ragged", attention_impl="xla"),
+    # --- LongCat-Flash (meituan-longcat/LongCat-Flash-Chat config.json):
+    # 28 shortcut-connected layers, each TWO latent-attention sublayers
+    # (MLA with a query latent of 1536 and constant multipliers on both
+    # normed latents) and two dense MLPs of width 12288, with ONE expert
+    # layer that reads the first sublayer's normed MLP input and joins
+    # the stream at the layer's end: 512 experts of width 2048 and 256
+    # zero-compute (identity) experts behind one softmax router of 768
+    # outputs, 12 a token, a selection bias, weights 6 x the unbiased
+    # scores, not renormalised, no shared expert.  ``num_layers`` counts
+    # sublayers (``moe_shortcut``) -----------------------------------------
+    "longcat-tiny": dict(vocab_size=1024, num_layers=4, d_model=64,
+                         num_heads=4, head_dim=16, d_ff=160,
+                         max_seq_len=512, activation="silu", gated_mlp=True,
+                         norm="rmsnorm", position="rope", rope_theta=1e7,
+                         rope_pct=0.5, tie_embeddings=False, attn_bias=False,
+                         mlp_bias=False, eps=1e-5,
+                         layer_pattern=("mla", "mla"), kda_chunk=16,
+                         mla_kv_rank=16, mla_nope_dim=16, mla_rope_dim=8,
+                         mla_value_dim=16, mla_q_rank=24,
+                         mla_scale_latents=True, moe_shortcut=True,
+                         num_experts=16, moe_zero_experts=8, moe_top_k=4,
+                         moe_d_ff=48, moe_score="softmax",
+                         moe_select_bias=True, moe_norm_topk=False,
+                         moe_route_scale=6.0, moe_dispatch="ragged",
+                         attention_impl="xla"),
+    "longcat-flash": dict(vocab_size=131072, num_layers=56, d_model=6144,
+                          num_heads=64, head_dim=128, d_ff=12288,
+                          max_seq_len=131072, activation="silu",
+                          gated_mlp=True, norm="rmsnorm", position="rope",
+                          rope_theta=1e7, rope_pct=0.5, tie_embeddings=False,
+                          attn_bias=False, mlp_bias=False, eps=1e-5,
+                          layer_pattern=("mla", "mla"), kda_chunk=64,
+                          mla_kv_rank=512, mla_nope_dim=128, mla_rope_dim=64,
+                          mla_value_dim=128, mla_q_rank=1536,
+                          mla_scale_latents=True, moe_shortcut=True,
+                          num_experts=512, moe_zero_experts=256,
+                          moe_top_k=12, moe_d_ff=2048, moe_score="softmax",
+                          moe_select_bias=True, moe_norm_topk=False,
+                          moe_route_scale=6.0, moe_dispatch="ragged",
+                          attention_impl="xla"),
     # --- Megatron-GPT (gpt2 architecture, megatron-lm checkpoint naming
     # with per-head-interleaved fused QKV — reference:
     # module_inject/containers/megatron_gpt.py) ---------------------------

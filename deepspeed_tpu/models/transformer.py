@@ -98,6 +98,12 @@ class TransformerConfig:
     mla_nope_dim: int = 0                     # a head's unrotated q/k part
     mla_rope_dim: int = 0                     # the rotated part; ONE shared k
     mla_value_dim: int = 0
+    # a query latent (DeepSeek-V3's form): q = N_q(h W_qa) W_qb over this
+    # many values; 0: q = h W_q in one product
+    mla_q_rank: int = 0
+    # constant multipliers on the normed latents: sqrt(d_model / rank) on
+    # the query's and on the keys' and values' (not on the rotated key)
+    mla_scale_latents: bool = False
     # four norms a layer: x + N(attn(N(x))), then x + N(ffn(N(x)))
     sandwich_norm: bool = False
     # the embedding's output is multiplied by this (trinity: sqrt(d_model);
@@ -175,6 +181,17 @@ class TransformerConfig:
     # token; an assignment to an expert that is not held adds nothing
     # here.  None: all of them
     experts_held: Optional[Tuple[int, int]] = None
+    # experts that compute nothing: the router has this many outputs
+    # BEHIND its ``num_experts`` (which count the experts with weights,
+    # as ``experts_held`` does); a token that takes one gets its input
+    # back, times the weight
+    moe_zero_experts: int = 0
+    # a shortcut-connected expert layer: a period of ``layer_pattern``
+    # is ONE layer of two sublayers, each an attention and a dense MLP;
+    # the experts read the first sublayer's normed MLP input and their
+    # output joins the stream at the period's end.  ``num_layers``
+    # counts sublayers; the router and the experts are stacked a period
+    moe_shortcut: bool = False
     # renormalize kept top-k gate weights to sum 1 (mixtral yes;
     # qwen2-moe norm_topk_prob=False keeps raw softmax probabilities)
     moe_norm_topk: bool = True
@@ -221,9 +238,6 @@ class TransformerConfig:
                 assert self.kda_heads and self.kda_key_dim \
                     and self.kda_value_dim and self.kda_chunk % 16 == 0
             if "mla" in kinds:
-                # a latent layer reads a step's runs as the state's
-                # bookkeeping cuts them (RecBatch)
-                assert "kda" in kinds
                 assert self.mla_kv_rank and self.mla_value_dim \
                     and self.mla_rope_dim == self.rotary_dim \
                     and self.mla_gate in (None, "head")
@@ -239,6 +253,13 @@ class TransformerConfig:
         assert self.moe_score in ("softmax", "sigmoid")
         assert 0 <= self.num_dense_layers <= self.num_layers
         assert self.num_dense_layers == 0 or self.num_experts > 1
+        assert self.moe_zero_experts == 0 or self.num_experts > 1
+        if self.moe_shortcut:
+            # the one form written: two stacked-mixer sublayers a layer
+            assert self.num_experts > 1 and self.mixer_stacks \
+                and len(self.layer_pattern) == 2 \
+                and not self.num_dense_layers and not self.moe_shared_ff \
+                and self.num_layers % 2 == 0
         if self.moe_d_ff is None:
             self.moe_d_ff = self.d_ff
 
@@ -301,8 +322,25 @@ class TransformerConfig:
     @property
     def mla_dims(self):
         from ..ops.mla import MLADims
-        return MLADims(self.num_heads, self.mla_kv_rank, self.mla_nope_dim,
-                       self.mla_rope_dim, self.mla_value_dim)
+        dims = MLADims(self.num_heads, self.mla_kv_rank, self.mla_nope_dim,
+                       self.mla_rope_dim, self.mla_value_dim,
+                       self.mla_q_rank)
+        if self.mla_scale_latents:
+            dims = dims._replace(
+                q_scale=math.sqrt(self.d_model / self.mla_q_rank),
+                kv_scale=math.sqrt(self.d_model / self.mla_kv_rank))
+        return dims
+
+    @property
+    def router_outputs(self) -> int:
+        """The router's width: the experts and the zero-compute ones."""
+        return self.num_experts + self.moe_zero_experts
+
+    @property
+    def expert_layers(self) -> int:
+        """The layers that hold a router and experts."""
+        n = self.num_layers - self.num_dense_layers
+        return n // len(self.layer_pattern) if self.moe_shortcut else n
 
     @property
     def experts_here(self) -> int:
@@ -325,7 +363,7 @@ class TransformerConfig:
         return (self.layer_pattern == ("full",) and not self.num_dense_layers
                 and not self.attn_gate and not self.sandwich_norm
                 and self.embed_scale is None and self.moe_groups == 1
-                and self.experts_held is None)
+                and self.experts_held is None and not self.moe_zero_experts)
 
     def rope_on(self, kind: str) -> bool:
         """Whether a layer of attention kind ``kind`` rotates q and k."""
@@ -528,9 +566,10 @@ def init_params(cfg: TransformerConfig, key) -> Tuple[Dict, Dict]:
         return p, ax
 
     def mla_init(k):
-        """An "mla" layer's attention: no query latent, one key-value
-        latent with its norm (scale seeded away from one), one shared
-        rotated key, a gate by head."""
+        """An "mla" layer's attention: one key-value latent with its
+        norm (scale seeded away from one), one shared rotated key, a gate
+        by head; the query in one product, or through a latent with a
+        norm of its own (``mla_q_rank``)."""
         md = cfg.mla_dims
         ks = jax.random.split(k, 6)
         r = md.kv_rank
@@ -550,6 +589,29 @@ def init_params(cfg: TransformerConfig, key) -> Tuple[Dict, Dict]:
         if cfg.mla_gate == "head":
             p["wg"] = jax.random.normal(ks[5], (dm, H)) / math.sqrt(dm)
             ax["wg"] = ("embed", "heads")
+        if md.q_rank:
+            # the query latent's weights, and the head projections as the
+            # matrices their products read ([rank, H * D], [H * V, dm]): a
+            # step reads a layer of a stacked matrix where it lies and
+            # copies one of a stack of higher rank out first (PERF.md
+            # section 6, PR 45).  Each constant multiplier is undone in
+            # the weight it stands behind (as ``key_scale``'s is), so a
+            # forward that leaves one out reads wrong by the multiplier
+            qa, qn = jax.random.split(jax.random.fold_in(  # tpulint: disable=rng-discipline
+                k, 3))
+            del p["wq"], ax["wq"]
+            p["wq_a"] = jax.random.normal(qa, (dm, md.q_rank)) \
+                / math.sqrt(dm)
+            p["q_norm"] = jax.random.uniform(qn, (md.q_rank,), minval=0.5,
+                                             maxval=1.5)
+            p["wq_b"] = jax.random.normal(
+                ks[0], (md.q_rank, H * (md.nope_dim + md.rope_dim))) \
+                / math.sqrt(md.q_rank) / md.q_scale
+            p["w_kvb"] = p["w_kvb"].reshape(r, -1) / md.kv_scale
+            p["wo"] = p["wo"].reshape(-1, dm)
+            ax.update({"wq_a": ("embed", None), "q_norm": (None,),
+                       "wq_b": (None, "heads"), "w_kvb": (None, "heads"),
+                       "wo": ("heads", "embed")})
         return p, ax
 
     mixer_inits = {"kda": kda_init, "mla": mla_init}
@@ -596,8 +658,11 @@ def init_params(cfg: TransformerConfig, key) -> Tuple[Dict, Dict]:
     if cfg.num_experts > 1:
         from ..parallel import moe as M
 
+        # a shortcut-connected layer's router and experts: one a period
+        n_moe = cfg.expert_layers
+
         def gate_init(k):
-            p, a = M.gate_init(k, dm, cfg.num_experts)
+            p, a = M.gate_init(k, dm, cfg.router_outputs)
             if cfg.moe_select_bias:
                 # seeded away from zero, as a trained router's is: a
                 # forward that added it to the weights, or left it out
@@ -606,17 +671,21 @@ def init_params(cfg: TransformerConfig, key) -> Tuple[Dict, Dict]:
                 # spread (0.1 at these logits): at +-0.1 it, not the
                 # token, chose the experts, the fullest took 7.6 times
                 # the mean and a third of them no token at all
+                # (softmax scores over E outputs lie about 1/E with a
+                # spread of 0.9/E at these logits: the same sixth)
+                lim = 0.02 if cfg.moe_score == "sigmoid" \
+                    else 0.15 / cfg.router_outputs
                 p["bias"] = jax.random.uniform(
                     jax.random.fold_in(k, 1),  # tpulint: disable=rng-discipline
-                    (cfg.num_experts,), minval=-0.02, maxval=0.02)
+                    (cfg.router_outputs,), minval=-lim, maxval=lim)
                 a["bias"] = (None,)
             return p, a
 
-        blk_p["gate"], blk_a["gate"] = stack_init(gate_init, keys[7])
+        blk_p["gate"], blk_a["gate"] = stack_init(gate_init, keys[7], n_moe)
         blk_p["experts"], blk_a["experts"] = stack_init(
             lambda k: M.experts_init(k, cfg.experts_here, dm, cfg.moe_d_ff,
                                      gated=cfg.gated_mlp,
-                                     out_scale=out_scale), keys[3])
+                                     out_scale=out_scale), keys[3], n_moe)
         if cfg.moe_shared_ff:        # a dense expert every token takes
             sff = cfg.moe_shared_ff
 
@@ -638,8 +707,10 @@ def init_params(cfg: TransformerConfig, key) -> Tuple[Dict, Dict]:
 
             blk_p["shared"], blk_a["shared"] = stack_init(
                 shared_init, keys[8])
-    else:
-        blk_p["mlp"], blk_a["mlp"] = stack_init(mlp_init, keys[3])
+    if cfg.num_experts == 1 or cfg.moe_shortcut:
+        blk_p["mlp"], blk_a["mlp"] = stack_init(
+            mlp_init, jax.random.fold_in(keys[3], 5)  # tpulint: disable=rng-discipline
+            if cfg.moe_shortcut else keys[3])
 
     for tree, part in zip((blk_p, blk_a), norms_init(nl - lead)):
         tree.update(part)
@@ -732,6 +803,8 @@ def moe_share(cfg) -> Dict[str, Any]:
         out["groups"] = (cfg.moe_groups, cfg.moe_groups_kept)
     if cfg.experts_held is not None:
         out["held"] = cfg.experts_held
+    if cfg.moe_zero_experts:
+        out["zero"] = cfg.moe_zero_experts
     return out
 
 
@@ -748,7 +821,59 @@ def _mixer_apply(cfg, lp, h, kind: str, cos, sin):
     if cfg.mla_gate == "head":
         g = jnp.tensordot(h, ap["wg"].astype(dt), 1).astype(jnp.float32)
         o = o * jax.nn.sigmoid(g).astype(dt)[..., None]
-    return jnp.einsum("bshk,hkd->bsd", o, ap["wo"].astype(dt))
+    wo = ap["wo"].astype(dt)
+    return jnp.einsum("bshk,hkd->bsd", o, wo.reshape(o.shape[2:] + (-1,)))
+
+
+def _dense_mlp(cfg, mp, h):
+    """A layer's dense MLP on its normed input."""
+    dt = h.dtype
+    act = L.ACTIVATIONS[cfg.activation]
+    u = h @ mp["wi"].astype(dt)
+    if cfg.mlp_bias:
+        u = u + mp["bi"].astype(dt)
+    if cfg.gated_mlp:
+        g = h @ mp["wg"].astype(dt)
+        if cfg.mlp_gate_scale != 1.0:
+            g = g * jnp.asarray(cfg.mlp_gate_scale, dt)
+        u = act(g) * u
+    else:
+        u = act(u)
+    d = u @ mp["wo"].astype(dt)
+    if cfg.mlp_bias:
+        d = d + mp["bo"].astype(dt)
+    if cfg.mlp_out_scale != 1.0:
+        d = d * jnp.asarray(cfg.mlp_out_scale, dt)
+    return d
+
+
+def shortcut_layer(cfg, lps, x, cos, sin):
+    """One shortcut-connected layer over whole sequences
+    (``moe_shortcut``): its sublayers ``lps`` in order, each ``x +
+    mixer(N(x))`` then ``x + MLP(N(x))``; the experts read the first
+    sublayer's normed MLP input and their output joins the stream at the
+    layer's end, so nothing between reads it.  The experts are served
+    dropless (``moe_serve``): no capacity dispatch knows this router.
+    x: [B, S, dm] → [B, S, dm]."""
+    from ..parallel import moe as M
+
+    norm = _norm(cfg)
+    B, S, dm = x.shape
+    for kind, lp in zip(cfg.layer_pattern, lps):
+        with jax.named_scope("attn"):
+            x = x + _mixer_apply(cfg, lp, norm(lp["ln1"], x), kind, cos, sin)
+        with jax.named_scope("ffn"):
+            h = norm(lp["ln2"], x)
+            if "gate" in lp:
+                skip, _ = M.moe_serve(
+                    lp["gate"], lp["experts"], h.reshape(B * S, dm),
+                    top_k=cfg.moe_top_k,
+                    activation=L.ACTIVATIONS[cfg.activation],
+                    gated=cfg.gated_mlp, norm_topk=cfg.moe_norm_topk,
+                    score=cfg.moe_score, route_scale=cfg.moe_route_scale,
+                    **moe_share(cfg))
+            x = x + _dense_mlp(cfg, lp["mlp"], h)
+    return x + skip.reshape(B, S, dm)
 
 
 def _qk_norm(cfg, scale, x):
@@ -855,22 +980,7 @@ def block_apply(cfg: TransformerConfig, lp, x, cos, sin,
             if "shared" in lp:       # the dense expert every token takes
                 d = d + _shared_expert(lp["shared"], h, act, cfg.gated_mlp)
         else:
-            mp = lp["mlp"]
-            u = h @ mp["wi"].astype(dt)
-            if cfg.mlp_bias:
-                u = u + mp["bi"].astype(dt)
-            if cfg.gated_mlp:
-                g = h @ mp["wg"].astype(dt)
-                if cfg.mlp_gate_scale != 1.0:
-                    g = g * jnp.asarray(cfg.mlp_gate_scale, dt)
-                u = act(g) * u
-            else:
-                u = act(u)
-            d = u @ mp["wo"].astype(dt)
-            if cfg.mlp_bias:
-                d = d + mp["bo"].astype(dt)
-            if cfg.mlp_out_scale != 1.0:
-                d = d * jnp.asarray(cfg.mlp_out_scale, dt)
+            d = _dense_mlp(cfg, lp["mlp"], h)
         if cfg.sandwich_norm:
             d = norm(lp["ln2_post"], d)
         if cfg.parallel_block:
@@ -890,6 +1000,11 @@ def stack_layer(cfg: TransformerConfig, stack, layer: int, first: int):
             if name != kind:
                 continue
             at = cfg.kind_rank(layer, kind, first)
+        elif cfg.moe_shortcut and name in ("gate", "experts"):
+            # a period's first sublayer holds them (``moe_shortcut``)
+            at, later = divmod(layer - first, len(cfg.layer_pattern))
+            if later:
+                continue
         else:
             at = layer - first
         out[name] = jax.tree.map(lambda a, at=at: a[at], sub)
@@ -1055,7 +1170,13 @@ def apply(cfg: TransformerConfig, params, input_ids, mask=None,
 
     x = keep(x)
     metrics = {}
-    if cfg.mixer_stacks:
+    if cfg.moe_shortcut:
+        for i in range(0, cfg.num_layers, P):
+            x = keep(shortcut_layer(
+                cfg, [use("blocks", stack_layer(cfg, params["blocks"],
+                                                i + j, 0), layer_slice=True)
+                      for j in range(P)], keep(x), cos, sin))
+    elif cfg.mixer_stacks:
         # the mixers are stacked by kind, so no scan cuts a period out
         # of one stack: the layers run one by one (the forward that
         # tests and comparisons read; such a model is not trained here)

@@ -1,5 +1,6 @@
-"""Latent attention (DeepSeek-V2's MLA with no query latent): what a
-served ``"mla"`` layer needs.
+"""Latent attention (DeepSeek-V2's MLA; the query in one product, or
+through a latent of its own as DeepSeek-V3's): what a served ``"mla"``
+layer needs.
 
 A token leaves ONE vector in the cache, ``[c | k_r]``: the normed
 key-value latent ``c`` (``kv_rank`` values) and the rotated shared key
@@ -43,6 +44,12 @@ class MLADims(NamedTuple):
     nope_dim: int
     rope_dim: int
     value_dim: int
+    # the query latent's size (``q = N_q(h W_qa) W_qb``); 0: ``h W_q``
+    q_rank: int = 0
+    # constant multipliers on the normed query latent and on the normed
+    # key-value latent (1.0 multiplies nothing)
+    q_scale: float = 1.0
+    kv_scale: float = 1.0
 
     @property
     def row(self) -> int:
@@ -123,17 +130,34 @@ def latent_attend(pool, q, qpos, tables, dims: MLADims, blocks: int):
     return acc / jnp.maximum(l, 1e-30)[..., None]
 
 
+def _normed(x, scale, eps: float, times: float):
+    """A latent's RMSNorm in float32, then its constant multiplier."""
+    x = x.astype(F32)
+    x = x * jax.lax.rsqrt((x * x).mean(-1, keepdims=True) + eps) \
+        * scale.astype(F32)
+    return x if times == 1.0 else x * times
+
+
+def w_kvb(ap, dims: MLADims):
+    """``W_kvb`` as ``[kv_rank, H, nope + value]`` (a model with a query
+    latent keeps it as the matrix ``[kv_rank, H * (nope + value)]``)."""
+    return ap["w_kvb"].reshape(dims.kv_rank, dims.heads, -1)
+
+
 def project(ap, h, cos, sin, positions, dims: MLADims, eps: float, mm):
     """A layer's projections of ``h [T, dm]`` → (q_n [T, H, nope], q_r
     [T, H, rope] rotated, the cache row ``[c | k_r]`` [T, row]: the
     latent after its norm, the shared key after its rotation).
     ``mm(x, w)``: the caller's matrix product."""
-    q = mm(h, ap["wq"])                                 # [T, H, nope + rope]
+    if dims.q_rank:
+        c_q = _normed(mm(h, ap["wq_a"]), ap["q_norm"], eps, dims.q_scale)
+        q = mm(c_q.astype(h.dtype), ap["wq_b"]).reshape(
+            h.shape[0], dims.heads, -1)
+    else:
+        q = mm(h, ap["wq"])                             # [T, H, nope + rope]
     q_n, q_r = q[..., :dims.nope_dim], q[..., dims.nope_dim:]
     kva = mm(h, ap["w_kva"])                                 # [T, row]
-    c = kva[..., :dims.kv_rank].astype(F32)
-    c = c * jax.lax.rsqrt((c * c).mean(-1, keepdims=True) + eps) \
-        * ap["c_norm"].astype(F32)
+    c = _normed(kva[..., :dims.kv_rank], ap["c_norm"], eps, dims.kv_scale)
     k_r = rope_interleaved(kva[..., dims.kv_rank:], cos, sin, positions)
     q_r = rope_interleaved(q_r, cos, sin, positions)
     return q_n, q_r, jnp.concatenate([c.astype(h.dtype), k_r], axis=-1)
@@ -141,14 +165,14 @@ def project(ap, h, cos, sin, positions, dims: MLADims, eps: float, mm):
 
 def fold_query(ap, q_n, q_r, dims: MLADims):
     """``[q_n W_kb^T | q_r]``: the query as it meets a cache row."""
-    w_kb = ap["w_kvb"][..., :dims.nope_dim].astype(q_n.dtype)   # [c, H, n]
+    w_kb = w_kvb(ap, dims)[..., :dims.nope_dim].astype(q_n.dtype)  # [c,H,n]
     return jnp.concatenate(
         [jnp.einsum("thn,chn->thc", q_n, w_kb), q_r], axis=-1)
 
 
 def unfold_output(ap, o, dims: MLADims, dtype):
     """``o [T, H, kv_rank]`` over the latents → the heads' values."""
-    w_vb = ap["w_kvb"][..., dims.nope_dim:].astype(dtype)       # [c, H, v]
+    w_vb = w_kvb(ap, dims)[..., dims.nope_dim:].astype(dtype)   # [c, H, v]
     return jnp.einsum("thc,chv->thv", o.astype(dtype), w_vb)
 
 
@@ -165,7 +189,7 @@ def attention_forward(ap, h, cos, sin, dims: MLADims, eps: float):
     q_n, q_r, row = project(ap, h.reshape(B * S, -1), cos, sin, pos, dims,
                             eps, mm)
     c, k_r = row[..., :dims.kv_rank], row[..., dims.kv_rank:]
-    kv = jnp.einsum("tc,chx->thx", c, ap["w_kvb"].astype(dt))
+    kv = jnp.einsum("tc,chx->thx", c, w_kvb(ap, dims).astype(dt))
     k_n, v = kv[..., :dims.nope_dim], kv[..., dims.nope_dim:]
 
     def seqs(t):

@@ -328,7 +328,7 @@ def moe_serve(gate_p, expert_p, h, valid=None, *, top_k: int, activation,
               layer=None, score: str = "softmax",
               route_scale: float = 1.0, with_ids: bool = False,
               groups: Optional[Tuple[int, int]] = None,
-              held: Optional[Tuple[int, int]] = None):
+              held: Optional[Tuple[int, int]] = None, zero: int = 0):
     """The serving expert layer: DROPLESS by construction.  h: [T, d]
     rows of one serving step (any mix of sequences); ``valid``: [T] bool,
     False for the rows that pad the step's bucket (None: all real).
@@ -364,13 +364,24 @@ def moe_serve(gate_p, expert_p, h, valid=None, *, top_k: int, activation,
     chips that hold the others.  ``with_ids`` gives the router's own
     numbering.
 
+    ``zero``: experts that compute nothing, the router's LAST ``zero``
+    outputs (``held`` and ``expert_p`` number the ones before them, which
+    have weights).  An assignment to one is counted in no group and
+    reaches no grouped product: it gives the row's input back, times the
+    weight (scope ``moe_zero``).  That part needs no weights and no
+    exchange: every holder of a share computes it for its own rows.
+
     ``stats``: (assignments computed, 1000 x the fullest expert's rows
     over the mean, experts that took a row), for the engine's counters
     (with ``held``: of the experts held; the router made ``top_k`` a
-    real row)."""
+    real row); with ``zero`` a fourth, the assignments to experts that
+    compute nothing."""
     T, dm = h.shape
     E = expert_p["wi"].shape[-3]
     dt = h.dtype
+    outputs = gate_p["kernel"].shape[-1]
+    if zero and held is None:
+        held = (0, outputs - zero)      # the experts that have weights
     if kernel:
         from ..ops.grouped_matmul import grouped_matmul
 
@@ -389,7 +400,7 @@ def moe_serve(gate_p, expert_p, h, valid=None, *, top_k: int, activation,
                           score=score, norm_topk=norm_topk,
                           route_scale=route_scale, groups=groups)
         if valid is not None:
-            ids = jnp.where(valid[:, None], ids, gate_p["kernel"].shape[-1])
+            ids = jnp.where(valid[:, None], ids, outputs)
         taken = ids                         # expert E: nowhere
         if held is not None:
             ids, _ = _held_ids(ids, held)
@@ -412,13 +423,19 @@ def moe_serve(gate_p, expert_p, h, valid=None, *, top_k: int, activation,
         picked = out[back].reshape(T, top_k, dm).astype(jnp.float32)
         if held is not None:
             picked = jnp.where((ids < E)[..., None], picked, 0.0)
-        y = (picked * vals[..., None]).sum(axis=1).astype(dt)
+        y = (picked * vals[..., None]).sum(axis=1)
+        if zero:
+            with jax.named_scope("moe_zero"):
+                nothing = (taken >= outputs - zero) & (taken < outputs)
+                y = y + jnp.where(nothing, vals, 0.0).sum(
+                    axis=1, keepdims=True) * h.astype(jnp.float32)
+        y = y.astype(dt)
         if valid is not None:
             y = jnp.where(valid[:, None], y, 0)
         n = group_sizes.sum()
-        stats = jnp.stack([n, (group_sizes.max() * (1000 * E))
-                           // jnp.maximum(n, 1),
-                           (group_sizes > 0).sum()])
+        stats = [n, (group_sizes.max() * (1000 * E)) // jnp.maximum(n, 1),
+                 (group_sizes > 0).sum()]
+        stats = jnp.stack(stats + [nothing.sum()] if zero else stats)
     return (y, stats, taken) if with_ids else (y, stats)
 
 
@@ -428,8 +445,8 @@ def moe_ffn(gate_p, expert_p, x, *, top_k: int, capacity_factor: float,
             noise_policy: Optional[str] = None,
             dispatch_mode: str = "scatter",
             norm_topk: bool = True, score: str = "softmax",
-            route_scale: float = 1.0, groups=None, held=None
-            ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+            route_scale: float = 1.0, groups=None, held=None,
+            zero: int = 0) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """Full MoE FFN over x [B, S, d_model] (reference: MOELayer.forward
     sharded_moe.py:533).  Returns (y, metrics) with metrics carrying the
     aux load-balancing loss.
@@ -460,6 +477,10 @@ def moe_ffn(gate_p, expert_p, x, *, top_k: int, capacity_factor: float,
     Training only.  Serving does not come here: ``moe_serve`` below is
     dropless whatever ``dispatch_mode`` a config names.
     """
+    if zero:
+        raise ValueError(
+            "experts that compute nothing (moe_zero_experts) are served "
+            "(moe_serve); no training dispatch knows the form")
     B, S, dm = x.shape
     E = gate_p["kernel"].shape[-1]
     cap = capacity_for(S, E, top_k, capacity_factor, min_capacity)

@@ -43,8 +43,13 @@ def ref():
 @pytest.fixture(scope="module")
 def tiny():
     cfg = build_config("falcon-h1-tiny")
-    params, axes = init_params(cfg, jax.random.PRNGKey(3))
-    return cfg, params, axes
+    axes = {}
+
+    def init(key):          # one compiled call, not an operation at a time
+        params, axes["axes"] = init_params(cfg, key)
+        return params
+
+    return cfg, jax.jit(init)(jax.random.PRNGKey(3)), axes["axes"]
 
 
 def ref_config(cfg):
@@ -147,7 +152,8 @@ def test_apply_agrees_with_the_reference(tiny, ref, n):
     ids = np.random.default_rng(n).integers(0, cfg.vocab_size, n)
     want = np.asarray(ref.logits(params, ids, ref_config(cfg)))
     with jax.default_matmul_precision("highest"):
-        got = np.asarray(apply(cfg, params, jnp.asarray(ids)[None]))[0]
+        got = np.asarray(jax.jit(lambda p, i: apply(cfg, p, i))(
+            params, jnp.asarray(ids)[None]))[0]
     assert rel(got, want) < TOL
 
 

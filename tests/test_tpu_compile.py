@@ -272,8 +272,8 @@ def _pstep_compiled(one_chip, cfg, kv_quant, T, seqs, bs, mbs, blocks):
     for the described chip, the cache donated → (compiled, bytes of one
     layer's share of the pool)."""
     from deepspeed_tpu.inference import SamplingParams
-    from deepspeed_tpu.inference.model import (MOE_STAT_ROWS,
-                                               fold_projections,
+    from deepspeed_tpu.inference.model import (fold_projections,
+                                               moe_stat_rows,
                                                pipelined_ragged_step)
     from deepspeed_tpu.inference.ragged.state import RaggedBatch
     from deepspeed_tpu.inference.sampler import sample_rows
@@ -315,6 +315,13 @@ def _pstep_compiled(one_chip, cfg, kv_quant, T, seqs, bs, mbs, blocks):
         rec = RecBatch(run_len=S((seqs,), jnp.int32),
                        replay=S((seqs,), jnp.bool_),
                        chunks=S((-(-T // sd.chunk) + 4, 5), jnp.int32))
+    elif cfg.mixer_stacks:
+        # a latent-only model: its runs cut as the engine's RunCut cuts
+        from deepspeed_tpu.inference.ragged.state import RecBatch, RunCut
+        rec = RecBatch(run_len=S((seqs,), jnp.int32),
+                       replay=S((seqs,), jnp.bool_),
+                       chunks=S((RunCut(cfg.kda_chunk).n_chunks(T), 5),
+                                jnp.int32))
     tok = S((T,), jnp.int32)
     batch = RaggedBatch(
         token_ids=tok, positions=tok, seq_slot=tok,
@@ -332,7 +339,7 @@ def _pstep_compiled(one_chip, cfg, kv_quant, T, seqs, bs, mbs, blocks):
             lambda logits, keys: sample_rows(logits, greedy, keys),
             bs, mbs, attn_impl="xla" if cfg.mixer_stacks else "pallas")
 
-    prev = seqs + (MOE_STAT_ROWS if cfg.num_experts > 1 else 0)
+    prev = seqs + (moe_stat_rows(cfg) if cfg.num_experts > 1 else 0)
     args = (params, kv, batch, S((prev,), jnp.int32),
             S(key.shape, key.dtype))
     compiled = jax.jit(pstep, donate_argnums=(1,)).lower(*args).compile()
@@ -453,6 +460,41 @@ def test_delta_rule_and_latent_serving_step_compiles_and_fits(one_chip,
     assert moved == [], moved
     mem = compiled.memory_analysis()
     print("ling-3.0-flash-d7 step:", mem.argument_size_in_bytes,
+          mem.temp_size_in_bytes)
+    assert mem.argument_size_in_bytes > 12.0e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+@pytest.mark.parametrize("rows", [128, 512])
+def test_latent_shortcut_serving_step_compiles_and_fits(one_chip, on_chip,
+                                                        rows):
+    """The whole serving step of ``longcat-flash-d4`` as the benchmark
+    runs it, at both of its row counts (four shortcut-connected layers of
+    two latent-attention sublayers each, 16 of 512 experts behind a
+    router of 768 outputs, 4608 blocks of 64 latent rows in each of eight
+    sublayers, 48 sequences a step, tables of 160 blocks): the grouped
+    kernel's three projections a layer are the program's only Pallas
+    calls; the latent pool rides the layer scan and is not copied whole;
+    weights, pool and temporaries fit a 16 GB chip."""
+    import json
+
+    from benchmarks.lib.drivers.serve_latent_share import preset_config
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root,
+                           "benchmarks/configs/longcat-flash-d4.json")) as f:
+        cfg = preset_config(json.load(f))
+    compiled, layer_bytes = _pstep_compiled(
+        one_chip, cfg, False, T=rows, seqs=48, bs=64, mbs=160, blocks=4608)
+    text = compiled.as_text()
+    # four expert layers under a rolled scan: one body, three projections
+    assert text.count("tpu_custom_call") == 3
+    assert layer_bytes == 4609 * 64 * 640 * 2
+    moved = [m for m in _moves_of(text, layer_bytes)
+             if "dynamic-update-slice" not in m and "fusion" not in m]
+    assert moved == [], moved
+    mem = compiled.memory_analysis()
+    print("longcat-flash-d4 step:", rows, mem.argument_size_in_bytes,
           mem.temp_size_in_bytes)
     assert mem.argument_size_in_bytes > 12.0e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9

@@ -42,8 +42,13 @@ def ref():
 @pytest.fixture(scope="module")
 def tiny():
     cfg = build_config("trinity-tiny")
-    params, axes = init_params(cfg, jax.random.PRNGKey(3))
-    return cfg, params, axes
+    axes = {}
+
+    def init(key):          # one compiled call, not an operation at a time
+        params, axes["axes"] = init_params(cfg, key)
+        return params
+
+    return cfg, jax.jit(init)(jax.random.PRNGKey(3)), axes["axes"]
 
 
 def ref_config(cfg):
@@ -96,7 +101,8 @@ def test_apply_agrees_with_the_reference(tiny, ref):
     ids = np.random.default_rng(0).integers(0, cfg.vocab_size, 80)
     want = np.asarray(ref.logits(params, ids, ref_config(cfg)))
     with jax.default_matmul_precision("highest"):
-        got = np.asarray(apply(cfg, params, jnp.asarray(ids)[None]))[0]
+        got = np.asarray(jax.jit(lambda p, i: apply(cfg, p, i))(
+            params, jnp.asarray(ids)[None]))[0]
     assert rel(got, want) < TOL
 
 
@@ -105,11 +111,12 @@ def test_apply_with_a_tail_agrees_with_the_reference(ref):
     the next, which run outside the scan."""
     cfg = build_config("trinity-tiny", num_layers=8)
     assert cfg.layer_plan == (1, 1, 3)
-    params, _ = init_params(cfg, jax.random.PRNGKey(5))
+    params = jax.jit(lambda k: init_params(cfg, k)[0])(jax.random.PRNGKey(5))
     ids = np.random.default_rng(1).integers(0, cfg.vocab_size, 50)
     want = np.asarray(ref.logits(params, ids, ref_config(cfg)))
     with jax.default_matmul_precision("highest"):
-        got, aux = apply(cfg, params, jnp.asarray(ids)[None], with_aux=True)
+        got, aux = jax.jit(lambda p, i: apply(cfg, p, i, with_aux=True))(
+            params, jnp.asarray(ids)[None])
     assert rel(np.asarray(got)[0], want) < TOL
     assert np.isfinite(float(aux["moe_aux_loss"]))
 
