@@ -8,6 +8,9 @@ checks what comes out by the repo's own means:
            decode attention bf16 and int8-KV, the int8 and packed-int4
            mixed GEMMs) once at GPT-2-small / llama3-8b widths against
            the XLA formulation it competes with, to bf16 tolerance;
+           (``latent kernel``, a phase of its own: the latent layers'
+           attention kernel at the two latent cells' shapes against
+           ``ops/mla.py`` ``latent_attend``, each with its milliseconds);
 * train    ``ds.initialize(model=build_model("gpt2"), ...)`` ->
            ``engine.train_batch`` on the whole GPT-2-small preset at
            seq 1024, bf16, ZeRO-1: loss finite on every step and falling
@@ -83,6 +86,14 @@ REAL = dict(
     # the one-token state update at the two cells' shapes (serve-ssm-chat,
     # serve-kda-reason): a 2 MiB row a slot a layer
     state=dict(L=2, S=128, H=32, ssm=(128, 256, 2), kda=(128, 128)),
+    # latent attention at the two cells' shapes (serve-mla-docqa: 64
+    # heads, 47 one-token rows at contexts of 1k-10k beside a run of 464
+    # rows; serve-kda-reason: 32 heads, 127 rows at 0.5k-6k, a run of 64)
+    latent=dict(row=(512, 64), block=64, cases={
+        "docqa": dict(H=64, S=48, T=512, ctx=(1024, 10000), run=(464, 2500),
+                      chunk=64),
+        "reason": dict(H=32, S=128, T=512, ctx=(512, 6000), run=(64, 2300),
+                       chunk=64)}),
     barrier=dict(n=8192, iters=64),
 )
 TINY = dict(
@@ -103,6 +114,8 @@ TINY = dict(
                     window=32, ctx=(70, 190)),
     gemm=dict(K=256, N=512, Ms=(8, 32)),
     state=dict(L=2, S=4, H=4, ssm=(8, 128, 2), kda=(16, 128)),
+    latent=dict(row=(96, 16), block=8, cases={
+        "docqa": dict(H=4, S=4, T=32, ctx=(9, 70), run=(21, 30), chunk=8)}),
     barrier=dict(n=256, iters=8),
 )
 
@@ -499,6 +512,105 @@ def kernels_phase(sz, seed):
             ref, t_ref = timed(jax.jit(dequant_matmul_reference), x, qt)
             print(f"    pallas: {ms(t)}\n    xla dequant+matmul: {ms(t_ref)}")
             close("out", y, ref, BF16_REL)
+
+
+def latent_kernel_phase(sz, seed):
+    """The latent layers' attention by the Pallas kernel
+    (``ops/mla.py`` ``latent_attend_tiles``) against the XLA formulation
+    (``latent_attend``, called as ``inference/model.py`` calls it: the
+    one-token rows a group a slot, the run by its chunks), on a step as
+    the scheduler stages it, layer 1 of a pool of two; beside each the
+    cached rows it reads at the HBM's peak."""
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.ops import mla as A
+
+    c = sz["latent"]
+    (kv_rank, rope), bs = c["row"], c["block"]
+    ms = lambda s: f"{1e3 * s:.3f} ms"    # noqa: E731
+    for name, g in c["cases"].items():
+        H, S, T = g["H"], g["S"], g["T"]
+        dims = A.MLADims(heads=H, kv_rank=kv_rank, nope_dim=128,
+                         rope_dim=rope, value_dim=128)
+        rng = np.random.default_rng(seed + H)
+        width = -(-dims.row // 128) * 128
+        ctx = rng.integers(*g["ctx"], size=S - 1)
+        n_run, seen = g["run"]
+        ends = np.append(ctx, seen + n_run)         # rows cached behind the step
+        nb = -(-int(ends.max()) // bs)
+        need = -(-ends // bs)
+        rows = int(need.sum()) + 1
+        tables = np.full((S, nb), -1, np.int32)
+        order = rng.permutation(rows - 1)
+        at = 0
+        for i, n in enumerate(need):
+            tables[i, :n] = order[at:at + n]
+            at += n
+        k_pool, k_q = jax.random.split(jax.random.PRNGKey(seed + H))
+        pool = jnp.zeros((2 * rows, bs, width), jnp.bfloat16).at[
+            ..., :dims.row].set(jax.random.normal(
+                k_pool, (2 * rows, bs, dims.row), jnp.bfloat16))
+        q = (jax.random.normal(k_q, (T, H, dims.row), jnp.float32)
+             * dims.row ** -0.25).astype(jnp.bfloat16)
+        slot = np.zeros(T, np.int32)
+        pos = np.zeros(T, np.int32)
+        slot[:S - 1], pos[:S - 1] = np.arange(S - 1), ctx - 1
+        slot[S - 1:S - 1 + n_run] = S - 1
+        pos[S - 1:S - 1 + n_run] = seen + np.arange(n_run)
+        valid = np.arange(T) < S - 1 + n_run
+        layer = (rows, rows)
+        read = (int(ctx.sum()) + seen + n_run) * width * 2
+        pairs = int(ctx.sum()) + n_run * seen + n_run * (n_run + 1) // 2
+        flops = 2 * pairs * H * (width + kv_rank)
+        print(f"  latent attention {name} H{H} rows of {width}: {S - 1} "
+              f"one-token rows at contexts {ctx.min()}-{ctx.max()}, a run of "
+              f"{n_run} behind {seen}: {read / 1e6:.0f} MB of rows, "
+              f"{read / 819e9 * 1e3:.3f} ms at 819 GB/s; {flops / 1e9:.0f} G "
+              f"operations, {flops / 197e12 * 1e3:.3f} ms at 197 T/s")
+        j = jnp.asarray
+
+        def kernel(pool, q):
+            tiles = A.latent_tiles(j(slot), j(pos), j(valid), j(tables), bs,
+                                   nb, rows - 1, H)
+            return A.latent_attend_tiles(pool, q, tiles, dims, layer)
+
+        Q = g["chunk"]
+        NC = -(-n_run // Q)
+        crow = np.minimum(S - 1 + np.arange(NC * Q), T - 1).reshape(NC, Q)
+        there = (np.arange(NC * Q) < n_run).reshape(NC, Q)
+        lt = np.where(tables < 0, rows - 1, tables) + rows
+
+        def xla(pool, q):
+            one = A.latent_attend(pool, q[:S - 1][:, None],
+                                  j(pos[:S - 1])[:, None], j(lt[:S - 1]),
+                                  dims, 8)[:, 0]
+            run = A.latent_attend(
+                pool, q[j(crow)], j(np.where(there, pos[crow], -1)),
+                j(np.broadcast_to(lt[S - 1], (NC, nb))), dims, 4)
+            return jnp.concatenate([one, run.reshape(
+                (NC * Q,) + run.shape[2:])[:n_run]])
+
+        got, t_k = timed(jax.jit(kernel), pool, q)
+        ref, t_x = timed(jax.jit(xla), pool, q)
+        print(f"    pallas: {ms(t_k)}\n    xla: {ms(t_x)}")
+        n = S - 1 + n_run
+        close("out", got[:n], ref, BF16_REL)
+        check(not np.asarray(got[n:], np.float32).any(),
+              "latent attention wrote rows of no tile")
+        # the decode rows alone: what a step without a prompt chunk runs
+        only = valid & (np.arange(T) < S - 1)
+
+        def decode(pool, q):
+            tiles = A.latent_tiles(j(slot), j(pos), j(only), j(tables), bs,
+                                   nb, rows - 1, H)
+            return A.latent_attend_tiles(pool, q, tiles, dims, layer)
+
+        rows_mb = int(ctx.sum()) * width * 2
+        dec, t_d = timed(jax.jit(decode), pool, q)
+        print(f"    pallas, the one-token rows alone: {ms(t_d)} "
+              f"({rows_mb / 819e9 * 1e3:.3f} ms at 819 GB/s)")
+        close("one-token rows", dec[:S - 1], ref[:S - 1], BF16_REL)
 
 
 # --------------------------------------------------------------------------
@@ -1167,14 +1279,14 @@ def serve_longcat_phase(sz, seed):
     n_prompt = {u: len(s) - k for u, s in seqs.items()}
     sizes = mix["engine"]
 
-    def system():
+    def system(**over):
         eng = InferenceEngine(model, InferenceConfig(
             token_budget=int(sizes["token_budget"]),
             max_seqs=int(sizes["max_seqs"]),
             kv_block_size=int(sizes["kv_block_size"]),
             num_kv_blocks=int(sizes["num_kv_blocks"]),
             max_seq_len=int(sizes["max_seq_len"]),
-            **config.get("engine_options", {})))
+            **{**config.get("engine_options", {}), **over}))
         out = D.system_side(eng, routing_step(eng), config, seqs, n_prompt,
                             seed + 7)
         return eng.icfg.token_budget, out
@@ -1232,14 +1344,17 @@ def serve_longcat_phase(sz, seed):
                 mock.patch.object(jax.lax, "fori_loop", bf16_loop):
             return attend(*a, **kw).astype(jnp.float32)
 
-    for name, patch in (
+    for name, patch, over in (
             ("the router's softmax and top-k in bfloat16",
-             mock.patch.object(moe, "route", bf16_route)),
+             mock.patch.object(moe, "route", bf16_route), {}),
+            # (the XLA formulation's: the one whose types a patch can
+            # lower; the kernel's are float32 in its body)
             ("the online softmax's scores and accumulators in bfloat16",
-             mock.patch.object(mla, "latent_attend", bf16_attend))
+             mock.patch.object(mla, "latent_attend", bf16_attend),
+             {"attn_impl": "xla"})
     )[None if sz is REAL else 1:]:     # (the rehearsal: the second alone)
         with patch:
-            _, (_, _, lowered) = system()
+            _, (_, _, lowered) = system(**over)
         gc.collect()
         got = D.readings(ref, model.params, config,
                          {n: named[n] for n in few}, prompts,
@@ -1438,6 +1553,7 @@ def main(argv=None) -> int:
             ("block_until_ready", lambda: barrier_phase(sz)),
             ("profiler window", lambda: profiler_phase(sz)),
             ("kernels vs XLA", lambda: kernels_phase(sz, args.seed)),
+            ("latent kernel", lambda: latent_kernel_phase(sz, args.seed)),
             ("train", lambda: train_run(sz, args.seed, devices[:1],
                                         {"data": 1}, 1, "trainer")),
             ("serve", lambda: serve_phase(sz, args.seed)),

@@ -68,11 +68,12 @@ class InferenceConfig:
     kv_dtype: object = jnp.bfloat16
     param_dtype: object = jnp.bfloat16
     # paged attention implementation: "pallas" is the streaming kernel
-    # (ops/paged_attention.py), "xla" the gather formulation it is tested
-    # against.  "auto" is a rule, settled at construction: "pallas" on a
-    # TPU backend (where it wins every shape the chip has timed, and the
-    # XLA path's gather does not fit beside a 7B model), "xla" everywhere
-    # else (there the kernel only runs interpreted)
+    # (ops/paged_attention.py; a latent layer's: ops/mla.py), "xla" the
+    # gather formulation it is tested against.  "auto" is a rule,
+    # settled at construction: "pallas" on a TPU backend (where it wins
+    # every shape the chip has timed, and the XLA path's gather does not
+    # fit beside a 7B model), "xla" everywhere else (there the kernel
+    # only runs interpreted)
     attn_impl: str = "auto"
     # "int8" | "fp8": store the paged KV cache quantized (one scale per
     # written token/head vector, per-block layout).  Halves (int8) the
@@ -367,17 +368,10 @@ class InferenceEngine:
         # formulation where it would run interpreted: a rule over what
         # the process can observe, so two engines of one process always
         # agree and nothing is compiled or timed to settle it
+        # (a latent layer's kernel is ``ops/mla.py``'s, the other layers'
+        # ``ops/paged_attention.py``'s; a delta-rule layer has no call
+        # to make and is served the same under either)
         self.attn_impl = self.icfg.attn_impl
-        if self.cfg.mixer_stacks:
-            # latent attention and the delta rule have one formulation
-            # (ops/mla.py, ops/kda.py): no call comes from the paged kernel
-            if self.attn_impl == "pallas":
-                raise ValueError(
-                    "attn_impl='pallas': the model's layers are of kinds "
-                    f"{self.cfg.mixer_stacks} (TransformerConfig."
-                    "mixer_stacks), which the paged-attention kernel does "
-                    "not serve; use 'auto' or 'xla'")
-            self.attn_impl = "xla"
         if self.attn_impl == "auto":
             self.attn_impl = ("pallas" if jax.default_backend() == "tpu"
                               else "xla")
@@ -864,18 +858,28 @@ class InferenceEngine:
                     "serving_moe_expert_load_max_over_mean",
                     "rows of the fullest expert over the mean rows an "
                     "expert, worst layer of the last collected step"))
-        # the Pallas paged-attention kernel's grid (ops/paged_attention
+        # the Pallas attention kernel's grid (ops/paged_attention
         # ``tile_counts``): how many query tiles the dispatched steps
         # were cut into, and how full the long ones were.  Counted on
         # the host from the schedule's run lengths; steps that ran an
-        # XLA formulation count nothing
+        # XLA formulation count nothing.  A model with a latent layer is
+        # cut at that kernel's two heights (ops/mla ``tile_heights``: a
+        # one-token run is a tile of ONE row, a longer run tiles of a
+        # thousand MXU rows), under labels of their own
+        self._tile_heights = (SHORT, LONG)
+        self._tile_labels = ("short", "long")
+        if self.state.cfg.latent_dim:
+            from ..ops.mla import tile_heights
+            self._tile_heights = tile_heights(self.cfg.mla_dims.heads)
+            self._tile_labels = ("one", "run")
         self._c_attn_tiles = reg.counter(
             "serving_attn_tiles_total",
-            "query tiles of the paged-attention kernel over the "
-            "dispatched steps (height: short|long)", int_valued=True)
+            "query tiles of the attention kernel over the dispatched "
+            "steps (height: short|long; a latent model's: one|run)",
+            int_valued=True)
         self._c_attn_long_rows = reg.counter(
             "serving_attn_long_tile_tokens_total",
-            "real tokens in the long query tiles", int_valued=True)
+            "real tokens in the long (run) query tiles", int_valued=True)
         reg.gauge_fn("serving_attn_tile_fill", self._attn_tile_fill,
                      "real tokens over tile rows of the long query tiles "
                      "(absent before the first one)")
@@ -1144,9 +1148,9 @@ class InferenceEngine:
     def _attn_tile_fill(self) -> Optional[float]:
         """Real tokens over rows of the long query tiles dispatched so
         far; None before the first one."""
-        tiles = self._c_attn_tiles.value(height="long")
-        return self._c_attn_long_rows.value() / (tiles * LONG) \
-            if tiles else None
+        tiles = self._c_attn_tiles.value(height=self._tile_labels[1])
+        return self._c_attn_long_rows.value() / (
+            tiles * self._tile_heights[1]) if tiles else None
 
     def _prefix_hit_rate(self):
         prompt = self.timings["prompt_tokens"]
@@ -3351,12 +3355,14 @@ class InferenceEngine:
         # sequence's post-step context, rounded to a power of two so a
         # growing context mints O(log) programs, not one per block.  The
         # XLA formulations do work proportional to that bound; the
-        # Pallas kernel's grid follows the batch (its tiles, and the
-        # blocks of the deepest one), so there one program, bounded by
-        # the engine's longest context, serves every step; so does the
-        # latent layers' loop over cached blocks (``ops/mla.py``
-        # ``latent_attend`` stops behind the last block a query reads),
-        # and a delta-rule layer reads no block
+        # Pallas kernels' grids follow the batch (their tiles, and the
+        # blocks of the deepest one: the paged kernel's and the latent
+        # layers', ``ops/mla.py`` ``latent_attend_tiles``), so there
+        # one program, bounded by the engine's longest context, serves
+        # every step; so does the latent layers' XLA formulation, whose
+        # loop over cached blocks stops behind the last block a query
+        # reads (``latent_attend``), and a delta-rule layer reads no
+        # block
         pallas = self.attn_impl == "pallas"
         mbs = self.max_blocks_per_seq
         if not pallas and not self.cfg.mixer_stacks:
@@ -3387,13 +3393,16 @@ class InferenceEngine:
         cold = ("p", key) not in self._warm_keys
         tiles = {}
         if pallas:
-            n_short, n_long, rows = tile_counts([len(t) for _, t in sched])
-            self._c_attn_tiles.inc(n_short, height="short")
+            short, long = self._tile_labels
+            n_short, n_long, rows = tile_counts(
+                [len(t) for _, t in sched], *self._tile_heights)
+            self._c_attn_tiles.inc(n_short, height=short)
             if n_long:
-                self._c_attn_tiles.inc(n_long, height="long")
+                self._c_attn_tiles.inc(n_long, height=long)
                 self._c_attn_long_rows.inc(rows)
-            tiles = dict(n_tiles_short=n_short, n_tiles_long=n_long,
-                         tile_fill=rows / (n_long * LONG) if n_long else 0.0)
+            tiles = {f"n_tiles_{short}": n_short, f"n_tiles_{long}": n_long,
+                     "tile_fill": rows / (n_long * self._tile_heights[1])
+                     if n_long else 0.0}
         if self._recurrent is not None:
             tiles.update(self._count_state_rows(sched))
         t1 = tr.phase("ds.serve.stage", track="stage", sid=sid,

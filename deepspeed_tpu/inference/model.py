@@ -163,6 +163,20 @@ def _group_tiles(tiles, kv, num_heads: int, window=None, shard_mesh=None):
                        window)
 
 
+def _latent_tiles(cfg, pool, batch: RaggedBatch, block_size: int,
+                  max_blocks_per_seq: int):
+    """The step's tiles for the latent layers' kernel (``ops/mla.py``
+    ``latent_tiles``): cut once a step, outside the layer scan, as
+    ``_query_tiles`` are for the paged kernel.  ``pool``: the latent
+    pool ``[L, rows, bs, row]`` (a layer's last row is its trash
+    block)."""
+    from ..ops.mla import latent_tiles
+
+    return latent_tiles(batch.seq_slot, batch.positions, batch.token_valid,
+                        batch.block_tables, block_size, max_blocks_per_seq,
+                        trash=pool.shape[-3] - 1, heads=cfg.mla_dims.heads)
+
+
 def _paged_attention_pallas(kv_layer, q, batch: RaggedBatch,
                             block_size: int, max_blocks_per_seq: int,
                             scale: float, shard_mesh=None, slopes=None,
@@ -727,21 +741,52 @@ _LATENT_BLOCKS_ONE = 8
 _LATENT_BLOCKS_RUN = 4
 
 
+def _latent_attend_xla(cfg, qf, pool, layer, batch: RaggedBatch, runs,
+                       max_blocks_per_seq: int):
+    """The XLA formulation of a step's latent attention
+    (``ops/mla.py`` ``latent_attend``, twice): the one-token rows as one
+    group a slot, the longer runs by their chunks.  qf: [T, H, row]
+    folded queries → [T, H, kv_rank] float32."""
+    from ..ops import mla as A
+
+    dims = cfg.mla_dims
+    S, T = runs["S"], qf.shape[0]
+    tables = _layer_tables(batch.block_tables[:, :max_blocks_per_seq],
+                           layer)                               # [S, nb]
+    qpos = jnp.where(runs["one"], batch.context_lens - 1, -1)
+    o_one = A.latent_attend(pool, qf[runs["last"]][:, None],
+                            qpos[:, None], tables[:S], dims,
+                            _LATENT_BLOCKS_ONE)[:, 0]
+    rows, there, n, slot, _, _ = _chunk_rows(runs, T, cfg.kda_chunk)
+    o_run = jax.lax.cond(
+        jnp.any(n > 0),
+        lambda: A.latent_attend(
+            pool, qf[rows], jnp.where(there, batch.positions[rows], -1),
+            tables[jnp.minimum(slot, S - 1)], dims, _LATENT_BLOCKS_RUN),
+        lambda: jnp.zeros(rows.shape + (dims.heads, dims.kv_rank),
+                          jnp.float32))
+    return jnp.where(runs["row_one"][:, None, None], o_one[batch.seq_slot],
+                     _scatter_chunks(o_run, rows, there, T))
+
+
 def _latent_attention(cfg, ap, h, pool, layer, batch: RaggedBatch, runs,
-                      cos, sin, dt, block_size: int, max_blocks_per_seq: int):
+                      cos, sin, dt, block_size: int, max_blocks_per_seq: int,
+                      tiles=None):
     """An "mla" layer's attention over a step's flat rows
     (``ops/mla.py``): every row's ``[c | k_r]`` written into the latent
     pool by block table (``latent_write``; a replayed row writes its row
     again), then all the query heads over the cached rows of the row's
     sequence, ``W_kvb`` folded into the query and the output
-    (``latent_attn``): the one-token rows as one group a slot, the longer
-    runs by their chunks.  ``pool``: the latent pool ``[L * rows, bs,
-    row]``, which holds the layer where ``layer`` says (``_layer_of``).
-    → (o [T, dm], pool)."""
+    (``latent_attn``): by the Pallas kernel over ``tiles``
+    (``_latent_tiles`` of the step) where the caller has them, else by
+    the XLA formulation, the one-token rows as one group a slot, the
+    longer runs by their chunks.  ``pool``: the latent pool ``[L * rows,
+    bs, row]``, which holds the layer where ``layer`` says
+    (``_layer_of``).  → (o [T, dm], pool)."""
     from ..ops import mla as A
 
     dims = cfg.mla_dims
-    S, T = runs["S"], h.shape[0]
+    T = h.shape[0]
     base, nrows = layer
     def mm(x, w):
         y = _mm(x, w, dt)
@@ -761,22 +806,11 @@ def _latent_attention(cfg, ap, h, pool, layer, batch: RaggedBatch, runs,
         blk = jnp.where(batch.token_valid, blk + base, base + nrows - 1)
         pool = A.latent_write(pool, row, blk, batch.positions % block_size)
     with jax.named_scope("latent_attn"):
-        tables = _layer_tables(batch.block_tables[:, :max_blocks_per_seq],
-                               layer)                           # [S, nb]
-        qpos = jnp.where(runs["one"], batch.context_lens - 1, -1)
-        o_one = A.latent_attend(pool, qf[runs["last"]][:, None],
-                                qpos[:, None], tables[:S], dims,
-                                _LATENT_BLOCKS_ONE)[:, 0]
-        rows, there, n, slot, _, _ = _chunk_rows(runs, T, cfg.kda_chunk)
-        o_run = jax.lax.cond(
-            jnp.any(n > 0),
-            lambda: A.latent_attend(
-                pool, qf[rows], jnp.where(there, batch.positions[rows], -1),
-                tables[jnp.minimum(slot, S - 1)], dims, _LATENT_BLOCKS_RUN),
-            lambda: jnp.zeros(rows.shape + (dims.heads, dims.kv_rank),
-                              jnp.float32))
-        o = jnp.where(runs["row_one"][:, None, None], o_one[batch.seq_slot],
-                      _scatter_chunks(o_run, rows, there, T))
+        if tiles is not None:
+            o = A.latent_attend_tiles(pool, qf, tiles, dims, layer)
+        else:
+            o = _latent_attend_xla(cfg, qf, pool, layer, batch, runs,
+                                   max_blocks_per_seq)
         o = A.unfold_output(ap, o, dims, dt)                # [T, H, V]
     with jax.named_scope("latent_out"):
         if cfg.mla_gate == "head":
@@ -898,7 +932,8 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
     ``block_tables[pos // bs], pos % bs``, and attention masks by
     absolute key position ≤ query position over whatever the block
     table references.
-    ``attn_impl``: "xla" (gather) | "pallas" (streaming kernel).
+    ``attn_impl``: "xla" (gather) | "pallas" (streaming kernel; for an
+    "mla" layer the latent kernel of ``ops/mla.py``).
     ``quant``: ZeRO-Inference weight-quant tree (inference/quantization
     ``quantize_model_params``) — one layer is dequantized at a time
     inside the scan body, so dense weights never all coexist in HBM.
@@ -1035,7 +1070,8 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
                 else:
                     o, pool = _latent_attention(
                         cfg, lp["mla"], h, pool, layer, batch, runs, cos,
-                        sin, dt, block_size, max_blocks_per_seq)
+                        sin, dt, block_size, max_blocks_per_seq,
+                        tiles=tiles.get("mla"))
             x, stats, skip = ffn(x, o, lp, li, skip)
             return x, pool, stats, rec, skip
         ap = lp["attn"]
@@ -1111,7 +1147,12 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
     # a kind: cut the batch into query tiles here, once, outside the scan,
     # and lay their tables out by the grid steps of each kind's calls
     tiles = {}
-    if attn_impl == "pallas":
+    if attn_impl == "pallas" and "mla" in cfg.mixer_stacks:
+        # a layer holds ONE kind of cache: the latent layers' kernel
+        # walks tiles of its own heights; a "kda" layer reads no block
+        tiles = {"mla": _latent_tiles(cfg, kv, batch, block_size,
+                                      max_blocks_per_seq)}
+    elif attn_impl == "pallas" and not cfg.mixer_stacks:
         cut = _query_tiles(kv, batch, block_size, max_blocks_per_seq,
                            cfg.attn_window if "window" in pattern else None)
         tiles = {kind: _group_tiles(

@@ -18,23 +18,76 @@ which is attention of all the query heads over one shared key of
 * :func:`rope_interleaved`: the rotary embedding over adjacent pairs;
 * :func:`latent_write` (scope ``latent_write``): a step's rows into the
   latent pool by block table;
-* :func:`latent_attend` (scope ``latent_attn``): groups of query rows,
-  each group of one sequence, over that sequence's cached rows read by
-  block table, a few blocks at a time under an online softmax.  The
-  serving forward calls it twice: the step's one-token rows, one group a
-  slot, and the chunks of its longer runs;
+* :func:`latent_attend` (scope ``latent_attn``), the XLA formulation
+  (``attn_impl="xla"``: what a step lowered for the CPU runs): groups of
+  query rows, each group of one sequence, over that sequence's cached
+  rows read by block table, a few blocks at a time under an online
+  softmax.  The serving forward calls it twice: the step's one-token
+  rows, one group a slot, and the chunks of its longer runs;
+* :func:`latent_attend_tiles` (the same scope), the Pallas kernel
+  (``attn_impl="pallas"``: what a TPU runs), below;
 * :func:`attention_forward`: the layer over whole sequences with keys
   and values expanded (``models/transformer.apply``).
+
+The kernel does for a pool of latent rows what ``ops/paged_attention.py``
+does for keys and values, on that module's tiles (the runs cut once a
+step by ``query_tiles``, the lists' tables, first rows, first positions
+and lengths by scalar prefetch, a finished tile's rows written by the
+kernel's own DMAs: ``send_tile_rows``), with a body of its own:
+
+* grid ``(tiles,)``, traced.  A tile is ``height`` rows of ONE run at
+  ALL heads, its queries one ``[height * H, width]`` operand (a view of
+  the tile's ``[height, H, width]`` window of ``q``, fetched once a
+  tile).  It walks its OWN sequence's blocks in *groups* of ``k``
+  consecutive blocks and no further: a one-token row at 1.5k cached rows
+  costs 1.5k rows whatever the step's longest context;
+* the blocks are fetched by the kernel's own DMAs, one a block, from the
+  stacked pool ``[L * rows, bs, width]`` where it lies (the table's
+  entry plus the layer's base; never gathered) into one of two VMEM
+  buffers that hold a group's rows one block behind another, so the
+  products read the buffer as it is.  While a group is attended the
+  next one's blocks are on their way, and behind a tile's last group
+  the NEXT tile's first: the DMA queue does not drain between tiles.  A
+  block behind the tile's last position is not read.  (The paged
+  kernel's BlockSpec pipeline, an operand a block of the group, was
+  measured first: its bookkeeping costs 0.17 us a block a grid step
+  where the block's DMA takes 0.10, PERF.md section 6, PR 50);
+* one block in VMEM serves both products: the key is the block's whole
+  row (``[c | k_r]`` and the zeros behind it), the value its first
+  ``kv_rank`` lanes: one DMA a block, no second operand;
+* scores, the softmax statistics (m, l) and the accumulator
+  ``[height * H, kv_rank]`` are float32 and stay in VMEM across the
+  tile's groups; ``p`` meets the rows in the pool's type, as
+  ``latent_attend`` casts it; only a tile's finished rows are written;
+* two heights, a property of the batch as ``SHORT``/``LONG`` are there
+  (``tile_heights``): a one-token run is a tile of one row (``H`` rows
+  for the MXU: that call is bound by the rows' bytes), a longer run is
+  cut into tiles whose ``height * H`` is ``RUN_ROWS`` (16 rows at 64
+  heads, 32 at 32).  ``k`` is ``latent_group``'s: 16 blocks for a
+  one-token tile, 8 for a run's.  Both are static functions of what the
+  call can see; nothing selects them.
 """
 
 from __future__ import annotations
 
-from typing import NamedTuple
+import functools
+from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .paged_attention import (GROUP_VMEM_BYTES, LONG, NEG_INF,
+                              QueryTiles, TileList, _use_interpret,
+                              query_tiles, send_tile_rows)
 
 F32 = jnp.float32
+# MXU rows (query rows x heads) of a tile of a run of several tokens
+RUN_ROWS = 1024
+# the most blocks a group holds, and the most its score tile may take
+GROUP_BLOCKS = 16
+GROUP_SCORE_BYTES = 2 * 1024 * 1024
 
 
 class MLADims(NamedTuple):
@@ -128,6 +181,216 @@ def latent_attend(pool, q, qpos, tables, dims: MLADims, blocks: int):
             jnp.zeros((G, R, H, dims.kv_rank), F32))
     _, l, acc = jax.lax.fori_loop(0, jnp.minimum(need, passes), one, init)
     return acc / jnp.maximum(l, 1e-30)[..., None]
+
+
+def tile_heights(heads: int) -> Tuple[int, int]:
+    """(rows of a one-token run's tile, rows of a tile of a longer
+    run) at ``heads`` query heads."""
+    return 1, max(8, min(LONG, RUN_ROWS // heads))
+
+
+def latent_group(rows: int, width: int, block_size: int, dtype,
+                 table_blocks: int) -> int:
+    """Blocks a group of a tile of ``rows`` MXU rows holds: the largest
+    power of two, at most ``GROUP_BLOCKS`` and the table's width, whose
+    float32 score tile is at most ``GROUP_SCORE_BYTES`` and fits
+    ``ops/paged_attention``'s ``GROUP_VMEM_BYTES`` with the two buffers.
+    More blocks a group are fewer passes of the loop and more DMAs in
+    flight (what a one-token tile wants: 16); a tile of a thousand rows
+    rescales a 2 MB accumulator a group and wastes what a last group
+    holds too much of (8)."""
+    block = block_size * width * jnp.dtype(dtype).itemsize
+    k = 1
+    while (2 * k <= min(GROUP_BLOCKS, table_blocks)
+           and rows * 2 * k * block_size * 4 <= GROUP_SCORE_BYTES
+           and 2 * 2 * k * block + rows * 2 * k * block_size * 4
+           <= GROUP_VMEM_BYTES):
+        k *= 2
+    return k
+
+
+def latent_tiles(seq_slot, positions, token_valid, block_tables,
+                 block_size: int, max_blocks_per_seq: int, trash: int,
+                 heads: int, heights: Tuple[int, int] = None) -> QueryTiles:
+    """A step's runs cut into the kernel's tiles (``query_tiles`` at
+    ``tile_heights(heads)``: ``short`` holds the one-token runs,
+    ``long`` the tiles of the longer ones), once a step and outside the
+    layer scan.  ``trash``: the pool row of a layer's trash block.
+    ``heights``: other heights than the kernel's own, for a test of
+    small tiles."""
+    short, long = heights or tile_heights(heads)
+    return query_tiles(seq_slot, positions, token_valid, block_tables,
+                       block_size, max_blocks_per_seq, trash,
+                       short=short, long=long)
+
+
+def _tile_kernel(tab_ref, row_ref, pos_ref, len_ref, base_ref, q_ref,
+                 pool_ref, _, o_ref, ob_ref, buf_ref, acc_ref, m_ref, l_ref,
+                 par_ref, sem, sem_in, *, height: int, heads: int,
+                 block_size: int, scale: float, kv_rank: int, group: int):
+    t = pl.program_id(0)
+    nt = pl.num_programs(0)
+    R = height * heads
+    keys = group * block_size
+
+    def last_block(tile):
+        return (pos_ref[tile] + jnp.maximum(len_ref[tile], 1)
+                - 1) // block_size
+
+    def each_block(do, tile, g, slot):
+        """``do`` (start or wait) the DMA of every block of group ``g``
+        of ``tile`` that the tile needs, into buffer ``slot`` where the
+        group's rows lie one block behind another; a block behind the
+        tile's last position is not read (what the buffer holds there is
+        an earlier group's, and masked).  (A loop, not ``group``
+        branches: every function that holds the step lowers this body
+        anew, and unrolled at four places it took three times as long
+        to lower: 20 s of a cell's set-up, PERF.md section 6, PR 50.)"""
+        def one(i, _):
+            do(pltpu.make_async_copy(
+                pool_ref.at[tab_ref[tile, g * group + i] + base_ref[0]],
+                buf_ref.at[slot, pl.ds(pl.multiple_of(i * block_size,
+                                                      block_size),
+                                       block_size)],
+                sem_in.at[slot]))
+
+        jax.lax.fori_loop(
+            0, jnp.minimum(last_block(tile) - g * group + 1, group), one,
+            None)
+
+    start, wait = (lambda cp: cp.start()), (lambda cp: cp.wait())
+    pos0 = pos_ref[t]
+    groups = last_block(t) // group + 1
+
+    @pl.when(t == 0)
+    def _():
+        # (masked rows meet the second product too: zeros, not whatever
+        # the buffers held)
+        buf_ref[...] = jnp.zeros_like(buf_ref)
+        par_ref[0] = 0
+        each_block(start, 0, 0, 0)
+
+    # the buffer that holds this tile's first group: the one the tile
+    # before left free, whose last step started these DMAs
+    par = par_ref[0]
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    # row = token * heads + head
+    q = q_ref[...].reshape(R, q_ref.shape[-1])
+    qpos = pos0 + jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0) // heads
+
+    def attend(g, _):
+        slot = (par + g) % 2
+
+        # the next group's blocks (the next tile's first, behind this
+        # tile's last) are on their way while this one is attended
+        @pl.when(g + 1 < groups)
+        def _():
+            each_block(start, t, g + 1, 1 - slot)
+
+        @pl.when((g + 1 == groups) & (t + 1 < nt))
+        def _():
+            each_block(start, t + 1, 0, 1 - slot)
+
+        each_block(wait, t, g, slot)
+        ctx = buf_ref[slot]                                  # [keys, width]
+        s = jax.lax.dot_general(
+            q, ctx, dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=F32) * scale               # [R, keys]
+        cols = g * keys + jax.lax.broadcasted_iota(jnp.int32, (R, keys), 1)
+        # this also masks whole the blocks of the group that lie past
+        # the tile's last position and were not read
+        s = jnp.where(cols <= qpos, s, NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        m_ref[...] = m_new
+        l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
+        # the value is the row's first kv_rank lanes, where it lies
+        pv = jax.lax.dot_general(
+            p.astype(ctx.dtype), ctx[:, :kv_rank],
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=F32)                       # [R, kv_rank]
+        acc_ref[...] = acc_ref[...] * corr + pv
+        return 0
+
+    jax.lax.fori_loop(0, groups, attend, 0)
+    par_ref[0] = (par + groups) % 2
+
+    def fill(ob):
+        ob[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                   ).reshape(ob.shape).astype(ob.dtype)
+
+    send_tile_rows(t, nt, fill, ob_ref, o_ref, sem, row_ref, len_ref)
+
+
+def _attend_tiles(tiles: TileList, pool, q, out, base, height: int,
+                  dims: MLADims):
+    """One ``pallas_call`` over ``tiles`` → ``out`` with their rows
+    written (``out`` is donated to the call and returned)."""
+    _, H, W = q.shape
+    bs = pool.shape[1]
+    R = height * H
+    group = latent_group(R, W, bs, pool.dtype, tiles.tables.shape[1])
+    prefetch = [tiles.tables, tiles.row, tiles.pos, tiles.length,
+                jnp.reshape(base, (1,)).astype(jnp.int32)]
+    in_specs = [
+        pl.BlockSpec((pl.Element(height), pl.Element(H), pl.Element(W)),
+                     lambda t, tables, row, *_: (row[t], 0, 0)),
+        pl.BlockSpec(memory_space=pl.ANY),
+        pl.BlockSpec(memory_space=pl.ANY)]
+    return pl.pallas_call(
+        functools.partial(_tile_kernel, height=height, heads=H,
+                          block_size=bs, scale=dims.scale,
+                          kv_rank=dims.kv_rank, group=group),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(tiles.count,),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[
+                pltpu.VMEM((2, height) + out.shape[1:], out.dtype),
+                pltpu.VMEM((2, group * bs, W), pool.dtype),  # two groups
+                pltpu.VMEM((R, dims.kv_rank), F32),
+                pltpu.VMEM((R, 1), F32),
+                pltpu.VMEM((R, 1), F32),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct(out.shape, out.dtype),
+        input_output_aliases={len(prefetch) + 2: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=_use_interpret(),
+        name=f"latent_attention_h{height}",
+    )(*prefetch, q, pool, out)
+
+
+def latent_attend_tiles(pool, q, tiles: QueryTiles, dims: MLADims,
+                        layer=None, heights: Tuple[int, int] = None):
+    """The step's rows over their sequences' cached rows, by the kernel.
+
+    pool: [rows, bs, >= row] (zeros behind a row's values; the stacked
+    pool viewed ``[L * rows, ...]`` with ``layer = (base, rows)`` of the
+    layer this call attends, ``None``: a pool of one layer); q: [T, H,
+    row] folded queries (the stored type); ``tiles``: ``latent_tiles``
+    of the step (cut at ``heights``, where a test gave it others)
+    → [T, H, kv_rank] in q's type, zero in the rows of no tile."""
+    T, H, _ = q.shape
+    one, run = heights or tile_heights(H)
+    base = 0 if layer is None else layer[0]
+    # rows of 128 lanes as the pool's; and the element-offset window of
+    # a tile that starts in the last rows reads past them
+    qp = jnp.pad(q, ((0, run), (0, 0), (0, pool.shape[-1] - q.shape[-1])))
+    out = jnp.zeros((T, H, dims.kv_rank), q.dtype)
+    for tl, height in ((tiles.long, run), (tiles.short, one)):
+        out = _attend_tiles(tl, pool, qp, out, base, height, dims)
+    return out
 
 
 def _normed(x, scale, eps: float, times: float):

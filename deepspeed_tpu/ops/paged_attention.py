@@ -135,13 +135,14 @@ class QueryTiles(NamedTuple):
     long: TileList
 
 
-def tile_counts(run_lengths: Sequence[int]) -> Tuple[int, int, int]:
+def tile_counts(run_lengths: Sequence[int], short: int = SHORT,
+                long: int = LONG) -> Tuple[int, int, int]:
     """(short tiles, long tiles, real rows in the long tiles) of a step
     whose runs have these lengths — the host's count of what
-    ``query_tiles`` builds on the device."""
-    n_short = sum(1 for n in run_lengths if 0 < n <= SHORT)
-    long_runs = [n for n in run_lengths if n > SHORT]
-    return n_short, sum(-(-n // LONG) for n in long_runs), sum(long_runs)
+    ``query_tiles`` builds on the device at the same two heights."""
+    n_short = sum(1 for n in run_lengths if 0 < n <= short)
+    long_runs = [n for n in run_lengths if n > short]
+    return n_short, sum(-(-n // long) for n in long_runs), sum(long_runs)
 
 
 def _pad(n: int, to: int) -> int:
@@ -219,7 +220,8 @@ def window_blocks(pos, length, window: int, block_size: int):
 
 def query_tiles(seq_slot, positions, token_valid, block_tables,
                 block_size: int, max_blocks_per_seq: int,
-                trash: int, window: int = None) -> QueryTiles:
+                trash: int, window: int = None, short: int = SHORT,
+                long: int = LONG) -> QueryTiles:
     """Cut a ragged batch into query tiles, on the device, once a step.
 
     seq_slot/positions: [T] i32, token_valid: [T] bool,
@@ -227,9 +229,11 @@ def query_tiles(seq_slot, positions, token_valid, block_tables,
     a layer's pool).  A run is a maximal stretch of valid rows of one
     slot at consecutive positions; a slot holds at most one run a step
     (``StateManager.build_batch`` schedules a sequence once), which
-    bounds the lists: ``max_seqs`` short tiles, ``T // LONG`` full long
+    bounds the lists: ``max_seqs`` short tiles, ``T // long`` full long
     tiles and one partial one a long run.  ``window``: the model's
-    attention window where it has window layers (``wblocks``)."""
+    attention window where it has window layers (``wblocks``).
+    ``short``, ``long``: the two heights (this kernel's; the latent
+    kernel of ``ops/mla.py`` cuts the same runs at its own)."""
     T = seq_slot.shape[0]
     max_seqs = block_tables.shape[0]
     i = jnp.arange(T, dtype=jnp.int32)
@@ -244,7 +248,7 @@ def query_tiles(seq_slot, positions, token_valid, block_tables,
     end = jax.lax.cummin(jnp.where(last, i, T), reverse=True)
     run_len = end - start + 1
     off = i - start
-    is_short = run_len <= SHORT
+    is_short = run_len <= short
 
     def collect(flag, length, bound):
         rows = jnp.flatnonzero(flag, size=bound, fill_value=0).astype(
@@ -266,11 +270,11 @@ def query_tiles(seq_slot, positions, token_valid, block_tables,
         return TileList(tables, rows, pos[rows], length, count, blocks,
                         wblocks)
 
-    short = collect(first & is_short, run_len, min(T, max_seqs))
-    long = collect(valid & ~is_short & (off % LONG == 0),
-                   jnp.minimum(run_len - off, LONG),
-                   max(1, T // LONG + min(max_seqs, T // (SHORT + 1))))
-    return QueryTiles(short, long)
+    return QueryTiles(
+        collect(first & is_short, run_len, min(T, max_seqs)),
+        collect(valid & ~is_short & (off % long == 0),
+                jnp.minimum(run_len - off, long),
+                max(1, T // long + min(max_seqs, T // (short + 1)))))
 
 
 def _each_row_copy(do, src, dst, sem, row, n):
@@ -287,6 +291,30 @@ def _each_row_copy(do, src, dst, sem, row, n):
 
     jax.lax.fori_loop(0, n // g, lambda i, _: copy(i * g, g), None)
     jax.lax.fori_loop(0, n % g, lambda i, _: copy(n // g * g + i, 1), None)
+
+
+def send_tile_rows(t, nt, fill, ob_ref, o_ref, sem, row_ref, len_ref):
+    """A finished tile's rows out of the kernel by its own DMAs, double
+    buffered: those of tile ``t`` of ``nt`` (the grid's row and rows,
+    read outside any ``pl.when``) are made by ``fill(buffer)`` and start
+    here; they are waited for when the next tile (or the grid) ends, so
+    they overlap its blocks.  ``ob_ref``: ``[2, height, ...]`` in VMEM,
+    ``o_ref`` the output in HBM, ``sem`` two DMA semaphores."""
+    slot = t % 2
+    start, wait = (lambda cp: cp.start()), (lambda cp: cp.wait())
+
+    @pl.when(t > 0)
+    def _():
+        _each_row_copy(wait, ob_ref.at[1 - slot], o_ref, sem.at[1 - slot],
+                       row_ref[t - 1], len_ref[t - 1])
+
+    fill(ob_ref.at[slot])
+    mine = (ob_ref.at[slot], o_ref, sem.at[slot], row_ref[t], len_ref[t])
+    _each_row_copy(start, *mine)
+
+    @pl.when(t == nt - 1)
+    def _():
+        _each_row_copy(wait, *mine)
 
 
 def _tile_span(t, pos, length, block_size: int, window):
@@ -418,30 +446,16 @@ def _kernel(rows_ref, row_ref, pos_ref, len_ref, base_ref, *rest,
 
     @pl.when(j == ng - 1)
     def _finalize():
-        # the output rows leave by the kernel's own DMAs, double
-        # buffered: this tile's start here and are waited for when the
-        # next tile (or the grid) ends, so they overlap its blocks
-        slot = t % 2
-        start, wait = (lambda cp: cp.start()), (lambda cp: cp.wait())
+        def fill(ob):
+            for h in range(num_kv_heads):
+                # unfolded in f32: Mosaic has no such shape cast for packed
+                # rows narrower than a lane tile (gpt2's D = 64 in bf16)
+                o = (acc_ref[h] / jnp.maximum(l_ref[h], 1e-30)).reshape(
+                    height, rep, acc_ref.shape[-1])
+                ob[:, h * rep:(h + 1) * rep, :o.shape[-1]] = o.astype(
+                    ob.dtype)
 
-        @pl.when(t > 0)
-        def _():
-            _each_row_copy(wait, ob_ref.at[1 - slot], o_ref,
-                           sem.at[1 - slot], row_ref[t - 1], len_ref[t - 1])
-
-        for h in range(num_kv_heads):
-            # unfolded in f32: Mosaic has no such shape cast for packed
-            # rows narrower than a lane tile (gpt2's D = 64 in bf16)
-            o = (acc_ref[h] / jnp.maximum(l_ref[h], 1e-30)).reshape(
-                height, rep, acc_ref.shape[-1])
-            ob_ref[slot, :, h * rep:(h + 1) * rep, :o.shape[-1]] = o.astype(
-                ob_ref.dtype)
-        mine = (ob_ref.at[slot], o_ref, sem.at[slot], row_ref[t], n)
-        _each_row_copy(start, *mine)
-
-        @pl.when(t == nt - 1)
-        def _():
-            _each_row_copy(wait, *mine)
+        send_tile_rows(t, nt, fill, ob_ref, o_ref, sem, row_ref, len_ref)
 
 
 @functools.partial(jax.jit, static_argnums=(1, 2, 3))

@@ -212,6 +212,22 @@ def test_chunked_prefill_and_decode_agree_with_the_reference(
                for s in scheds)
 
 
+def test_the_kernel_serves_what_the_xla_formulation_serves(tiny, seqs,
+                                                           system_rows):
+    """``attn_impl="pallas"`` (the latent layers' kernel, interpreted
+    here; a delta-rule layer has no call to make): the same schedules
+    and the XLA formulation's logits over chunked prefill and decode."""
+    eng = engine(tiny, attn_impl="pallas")
+    assert eng.attn_impl == "pallas"
+    with jax.default_matmul_precision("highest"):
+        rows, scheds = paged_logits(eng, *seqs)
+    assert scheds == system_rows[1]
+    for u, want in system_rows[0].items():
+        got, want = np.stack(rows[u]), np.stack(want)
+        assert rel(got, want) < 1e-4, u
+        assert (got.argmax(-1) == want.argmax(-1)).all()
+
+
 def test_step_of_decode_rows_and_two_prefill_runs(tiny, ref):
     """Two sequences decoding while two prompts prefill in one step."""
     cfg, params, _ = tiny
@@ -268,7 +284,9 @@ def served_engine(tiny, **kw):
 def test_hold_and_resume_with_a_row_launched_ahead(tiny):
     """The row launched ahead and thrown away has moved the state one
     token and written its latent row: fed again it must leave the state
-    where one pass would, and write the same row again."""
+    where one pass would, and write the same row again.  (The engine
+    that replays reads its latent rows by the kernel, the plain pass by
+    the XLA formulation: one test holds both to one answer.)"""
     rng = np.random.default_rng(9)
     prompt = rng.integers(0, tiny[0].vocab_size, 21).tolist()
     plain = served_engine(tiny)
@@ -276,7 +294,7 @@ def test_hold_and_resume_with_a_row_launched_ahead(tiny):
     want = []
     while len(want) < 9:
         want += list(plain.step(sampling=GREEDY).values())
-    eng = served_engine(tiny)
+    eng = served_engine(tiny, attn_impl="pallas")
     eng.put(7, prompt, max_new_tokens=40)
     got = []
     while len(got) < 3:
@@ -306,8 +324,7 @@ def test_hold_and_resume_with_a_row_launched_ahead(tiny):
 
 
 @pytest.mark.parametrize("option,value", [
-    ("prefix_cache", "on"), ("spec_decode", "on"), ("kv_tier", "on"),
-    ("attn_impl", "pallas")])
+    ("prefix_cache", "on"), ("spec_decode", "on"), ("kv_tier", "on")])
 def test_engine_refuses_by_name_what_cannot_serve_the_model(tiny, option,
                                                             value):
     with pytest.raises(ValueError, match=option):
@@ -468,6 +485,7 @@ def test_stage_and_readback_spans_and_counters(tiny):
         Model.from_params(held, p2, param_axes=axes),
         InferenceConfig(token_budget=32, max_seqs=4, kv_block_size=8,
                         num_kv_blocks=64, max_seq_len=256, trace=True,
+                        attn_impl="pallas",
                         param_dtype=jnp.float32, kv_dtype=jnp.float32))
     rng = np.random.default_rng(2)
     eng.put(3, [5])
@@ -483,6 +501,11 @@ def test_stage_and_readback_spans_and_counters(tiny):
     # the cached rows the latent layer reads: the sum of seen + n
     assert stage["latent_tokens"] == 1 + 9 + 22
     assert "kv_tokens_full" not in stage
+    # the latent layers' kernel: the one-token run a tile of one row, the
+    # runs of 9 and of 22 a tile each of 128 rows (four heads)
+    assert (stage["n_tiles_one"], stage["n_tiles_run"],
+            stage["tile_fill"]) == (1, 2, 31 / 256)
+    assert "n_tiles_short" not in stage
     back = [e["args"] for e in ev if e["name"] == "ds.serve.readback"][0]
     made = 32 * held.moe_top_k * 6
     assert back["moe_assignments_made"] == made
@@ -492,6 +515,9 @@ def test_stage_and_readback_spans_and_counters(tiny):
     asg = snap["serving_moe_assignments_total"]
     assert asg['{where="held"}'] + asg['{where="absent"}'] >= made
     assert snap["serving_attn_kv_tokens_total"]['{kind="latent"}'] >= 32
+    assert snap["serving_attn_tiles_total"]['{height="run"}'] >= 2
+    assert snap["serving_attn_tile_fill"] == pytest.approx(31 / 256,
+                                                           abs=1e-6)
     assert snap["serving_latent_pool_bytes"] == 2 * 65 * 8 * 128 * 4
     assert snap["serving_state_bytes"] \
         == 3 * eng._recurrent.bytes_per_seq(5)

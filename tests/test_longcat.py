@@ -81,12 +81,25 @@ def served(held):
     so every later sequence takes blocks that others left.  → (engine,
     the logits-returning step that also says the experts each row
     took)."""
+    eng = _engine(held, "auto")
+    return eng, eng._build_step(eng.max_blocks_per_seq, with_routing=True)
+
+
+def _engine(held, attn_impl):
     cfg, params, axes = held
-    eng = InferenceEngine(
+    return InferenceEngine(
         Model.from_params(cfg, params, param_axes=axes),
         InferenceConfig(token_budget=37, max_seqs=4, kv_block_size=8,
                         num_kv_blocks=24, max_seq_len=192, trace=True,
+                        attn_impl=attn_impl,
                         param_dtype=jnp.float32, kv_dtype=jnp.float32))
+
+
+@pytest.fixture(scope="module")
+def served_kernel(held):
+    """``served``'s engine and step with the latent layers' attention by
+    the Pallas kernel (``attn_impl="pallas"``; interpreted here)."""
+    eng = _engine(held, "pallas")
     return eng, eng._build_step(eng.max_blocks_per_seq, with_routing=True)
 
 
@@ -207,6 +220,19 @@ def test_chunked_prefill_and_decode_agree_with_the_reference(
     assert any(any(n == 1 for _, n in s) and any(n > 1 for _, n in s)
                for s in scheds), scheds
     assert sum(len(b) for b in blocks.values()) == 22       # of 24
+
+
+def test_the_kernel_serves_what_the_xla_formulation_serves(
+        seqs, system_rows, served_kernel):
+    """Chunked prefill and decode through ``attn_impl="pallas"``: the
+    same schedules, the same blocks, the XLA formulation's logits."""
+    rows, scheds, blocks = paged(served_kernel, *seqs)
+    assert served_kernel[0].attn_impl == "pallas"
+    assert (scheds, blocks) == system_rows[1:]
+    for u, want in system_rows[0].items():
+        got, want = np.stack(rows[u]), np.stack(want)
+        assert rel(got, want) < 1e-4, u
+        assert (got.argmax(-1) == want.argmax(-1)).all()
 
 
 def test_freed_blocks_are_taken_again(held, served, ref, system_rows):
@@ -374,7 +400,7 @@ def test_the_latent_pool_is_a_plain_block_pool(held, served):
     """What the engine resolves for a latent-only model: no state rows,
     the prefix cache on, speculation refused by name."""
     eng, _ = served
-    assert eng._recurrent is None and eng.attn_impl == "xla"
+    assert eng._recurrent is None and eng.attn_impl == "xla"    # on a CPU
     assert eng.state.cfg.latent_dim == 24 and eng.state.cfg.num_layers == 4
     assert eng.state.cfg.runs.chunk == 16
     assert eng.state.kv.shape == (4, 25, 8, 128)
@@ -383,12 +409,12 @@ def test_the_latent_pool_is_a_plain_block_pool(held, served):
     model = Model.from_params(cfg, params, param_axes=axes)
     with pytest.raises(ValueError, match="spec_decode"):
         InferenceEngine(model, InferenceConfig(spec_decode="on"))
-    with pytest.raises(ValueError, match="pallas"):
-        InferenceEngine(model, InferenceConfig(attn_impl="pallas"))
 
 
-def test_a_prefix_hit_aliases_latent_blocks(held, served, ref):
+@pytest.mark.parametrize("engine", ["served", "served_kernel"])
+def test_a_prefix_hit_aliases_latent_blocks(held, ref, engine, request):
     cfg, params, _ = held
+    served = request.getfixturevalue(engine)
     eng, _ = served
     rng = np.random.default_rng(11)
     shared = rng.integers(0, cfg.vocab_size, 32).tolist()
@@ -418,6 +444,68 @@ def test_a_prefix_hit_aliases_latent_blocks(held, served, ref):
     want = np.asarray(ref.logits(params, np.asarray(b), ref_config(cfg),
                                  last=1))
     assert rel(rows[2], want[0]) < TOL
+
+
+def test_the_kernel_serves_the_loop_and_counts_its_tiles(held, served,
+                                                         served_kernel):
+    """``attn_impl="pallas"`` is served, not refused: the served loop
+    (a row launched ahead, thrown away by a hold and fed again among its
+    steps) emits the XLA formulation's tokens, and the latent kernel's
+    tiles are counted where the paged kernel's are."""
+    from deepspeed_tpu.inference import SamplingParams
+    greedy = SamplingParams(temperature=0.0, max_new_tokens=1)
+    rng = np.random.default_rng(4)
+    prompt = rng.integers(0, 1024, 43).tolist()
+    beside = rng.integers(0, 1024, 9).tolist()
+
+    def run(eng):
+        eng.state.reset_prefix_cache()
+        eng.tracer.clear()
+        eng.put(7, prompt, max_new_tokens=12)
+        eng.put(8, beside, max_new_tokens=12)
+        got = {7: [], 8: []}
+
+        def steps(until):
+            while not until():
+                for u, t in eng.step(sampling=greedy).items():
+                    got[u].append(t)
+
+        steps(lambda: len(got[7]) >= 3)
+        assert eng._ahead is not None and 7 in eng._ahead.uids
+        eng.hold(7)
+        steps(lambda: len(got[8]) >= 6)
+        eng.put(7, [got[7][-1]])                  # the held token resumes
+        steps(lambda: len(got[7]) >= 8 and len(got[8]) >= 8)
+        for u in got:
+            eng.hold(u)
+        while eng.in_flight:
+            eng.step(sampling=greedy)
+        stages = [e["args"] for e in eng.tracer.events()
+                  if e["name"] == "ds.serve.stage"]
+        for u in got:
+            eng.flush(u)
+        return {u: t[:8] for u, t in got.items()}, stages
+
+    want, plain = run(served[0])
+    got, stages = run(served_kernel[0])
+    assert got == want
+    assert all("n_tiles_one" not in st for st in plain)
+    # the first step: 37 rows of the long prompt in tiles of 128 rows
+    # (four heads), nothing else
+    first = stages[0]
+    assert (first["n_tokens"], first["n_tiles_one"], first["n_tiles_run"],
+            first["tile_fill"]) == (37, 0, 1, 37 / 128)
+    # a decode step of the two: two tiles of one row
+    assert any((st["n_tiles_one"], st["n_tiles_run"], st["tile_fill"])
+               == (2, 0, 0.0) for st in stages)
+    assert all("n_tiles_short" not in st for st in stages)
+    snap = served_kernel[0].metrics.snapshot()
+    tiles = snap["serving_attn_tiles_total"]
+    assert tiles['{height="one"}'] == sum(st["n_tiles_one"] for st in stages)
+    assert tiles['{height="run"}'] == sum(st["n_tiles_run"] for st in stages)
+    assert 0 < snap["serving_attn_tile_fill"] < 1
+    # (an engine that ran the XLA formulation counted none)
+    assert not served[0].metrics.snapshot().get("serving_attn_tiles_total")
 
 
 def test_served_loop_spans_and_counters(held, served):

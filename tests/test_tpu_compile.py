@@ -270,7 +270,8 @@ def _moves_of(text: str, floor: int):
 def _pstep_compiled(one_chip, cfg, kv_quant, T, seqs, bs, mbs, blocks):
     """``InferenceEngine._build_pstep``'s program for ``cfg`` compiled
     for the described chip, the cache donated → (compiled, bytes of one
-    layer's share of the pool)."""
+    layer's share of the pool); ``compiled.jaxpr()`` traces the step
+    again for a test that reads its equations."""
     from deepspeed_tpu.inference import SamplingParams
     from deepspeed_tpu.inference.model import (fold_projections,
                                                moe_stat_rows,
@@ -337,13 +338,13 @@ def _pstep_compiled(one_chip, cfg, kv_quant, T, seqs, bs, mbs, blocks):
         return pipelined_ragged_step(
             cfg, params, None, kv, batch, prev, rng,
             lambda logits, keys: sample_rows(logits, greedy, keys),
-            bs, mbs, attn_impl="xla" if cfg.mixer_stacks else "pallas")
+            bs, mbs, attn_impl="pallas")
 
     prev = seqs + (moe_stat_rows(cfg) if cfg.num_experts > 1 else 0)
     args = (params, kv, batch, S((prev,), jnp.int32),
             S(key.shape, key.dtype))
     compiled = jax.jit(pstep, donate_argnums=(1,)).lower(*args).compile()
-    compiled.jaxpr = jax.make_jaxpr(pstep)(*args).jaxpr
+    compiled.jaxpr = lambda: jax.make_jaxpr(pstep)(*args).jaxpr
     return compiled, layer_bytes
 
 
@@ -432,8 +433,9 @@ def test_delta_rule_and_latent_serving_step_compiles_and_fits(one_chip,
     runs it (a dense layer and a period of five delta-rule layers and a
     latent one, 128 of 512 experts a layer, 12288 blocks of 64 latent
     rows, 512 tokens and 128 sequences a step, tables of 96 blocks): the
-    grouped kernel's three projections a layer and the delta rule's
-    state update in six are the program's only Pallas calls; the state
+    grouped kernel's three projections a layer, the delta rule's state
+    update in six and the latent layer's attention at its two heights
+    are the program's only Pallas calls; the state
     rows of six layers and the latent pool of one ride
     the layer scan and neither is copied whole; weights, both caches and
     temporaries fit a 16 GB chip."""
@@ -449,8 +451,10 @@ def test_delta_rule_and_latent_serving_step_compiles_and_fits(one_chip,
         one_chip, cfg, False, T=512, seqs=128, bs=64, mbs=96, blocks=12288)
     text = compiled.as_text()
     # one period of six expert layers, which a scan of one trip unrolls
-    assert text.count("tpu_custom_call") == 6 * 3 + 6
+    assert text.count("tpu_custom_call") == 6 * 3 + 6 + 2
     assert len(re.findall(r"%kda_state_update[\w.]* = ", text)) == 6
+    # 32 heads: a one-token run's tile, and tiles of 32 rows
+    assert len(re.findall(r"%latent_attention_h(1|32)[\w.]* = ", text)) == 2
     # a row of 576 values in five whole vectors of 128 lanes
     assert layer_bytes == 12289 * 64 * 640 * 2
     kd = cfg.kda_dims
@@ -473,9 +477,11 @@ def test_latent_shortcut_serving_step_compiles_and_fits(one_chip, on_chip,
     two latent-attention sublayers each, 16 of 512 experts behind a
     router of 768 outputs, 4608 blocks of 64 latent rows in each of eight
     sublayers, 48 sequences a step, tables of 160 blocks): the grouped
-    kernel's three projections a layer are the program's only Pallas
-    calls; the latent pool rides the layer scan and is not copied whole;
-    weights, pool and temporaries fit a 16 GB chip."""
+    kernel's three projections a layer and the latent attention's two
+    calls a sublayer (64 heads: tiles of one row and of 16) are the
+    program's only Pallas calls; the latent pool rides the layer scan
+    and is not copied whole; weights, pool and temporaries fit a 16 GB
+    chip."""
     import json
 
     from benchmarks.lib.drivers.serve_latent_share import preset_config
@@ -487,8 +493,10 @@ def test_latent_shortcut_serving_step_compiles_and_fits(one_chip, on_chip,
     compiled, layer_bytes = _pstep_compiled(
         one_chip, cfg, False, T=rows, seqs=48, bs=64, mbs=160, blocks=4608)
     text = compiled.as_text()
-    # four expert layers under a rolled scan: one body, three projections
-    assert text.count("tpu_custom_call") == 3
+    # four expert layers under a rolled scan: one body, three
+    # projections, two sublayers of two attention calls
+    assert text.count("tpu_custom_call") == 3 + 2 * 2
+    assert len(re.findall(r"%latent_attention_h(1|16)[\w.]* = ", text)) == 4
     assert layer_bytes == 4609 * 64 * 640 * 2
     moved = [m for m in _moves_of(text, layer_bytes)
              if "dynamic-update-slice" not in m and "fusion" not in m]
@@ -518,7 +526,7 @@ def test_moe_serving_step_compiles_and_fits(one_chip, on_chip):
     # the paged kernel at its two heights, the grouped kernel's three
     assert text.count("tpu_custom_call") == 5
     # 16 kv heads: a block is 512 KB and four fill the group's budget
-    _tile_grid_conditions(compiled.jaxpr, T=512, seqs=64, mbs=16,
+    _tile_grid_conditions(compiled.jaxpr(), T=512, seqs=64, mbs=16,
                           short_group=4)
     leaf_bytes = cfg.num_experts * cfg.d_model * cfg.d_ff * 2
     assert _moves_of(text, 1), "the reader no longer finds any copy"
@@ -600,7 +608,7 @@ def test_serving_step_keeps_the_pool_in_place(one_chip, on_chip, kv_quant):
     # (76.8 MB a layer, as before the tile grid)
     assert compiled.memory_analysis().temp_size_in_bytes < (
         100e6 if kv_quant else 16e6)
-    _tile_grid_conditions(compiled.jaxpr, T=512, seqs=64, mbs=16,
+    _tile_grid_conditions(compiled.jaxpr(), T=512, seqs=64, mbs=16,
                           short_group=8)
 
 
