@@ -308,6 +308,7 @@ def kernels_phase(sz, seed):
 
     from deepspeed_tpu.inference.model import (_paged_attention,
                                                _paged_attention_pallas,
+                                               _pool_scales,
                                                _quantize_kv)
     from deepspeed_tpu.inference.ragged.state import RaggedBatch
     from deepspeed_tpu.models.layers import causal_attention
@@ -378,8 +379,13 @@ def kernels_phase(sz, seed):
         context_lens=jnp.zeros(S, jnp.int32),
         logits_idx=jnp.full(S, -1, jnp.int32), n_tokens=n_real,
         n_seqs=len(runs))
-    codes, scales = _quantize_kv(cache, jnp.int8)
-    for name, kvl in (("bf16", cache), ("int8-KV", (codes, scales))):
+    def quantized(cache):
+        """``cache`` as an int8 pool: codes, and scales as the pool
+        lays them (a head a row of a block's)."""
+        codes, scales = _quantize_kv(cache, jnp.int8)
+        return codes, _pool_scales(scales)
+
+    for name, kvl in (("bf16", cache), ("int8-KV", quantized(cache))):
         print(f"  paged attention {name} T{T} H{H}/{Hkv} D{D} "
               f"block {bs} x{nb}")
         got = {}
@@ -419,7 +425,7 @@ def kernels_phase(sz, seed):
         context_lens=jnp.zeros(S, jnp.int32),
         logits_idx=jnp.full(S, -1, jnp.int32), n_tokens=S, n_seqs=S)
     for name, kvl in (("bf16", cache),
-                      ("int8-KV", _quantize_kv(cache, jnp.int8))):
+                      ("int8-KV", quantized(cache))):
         for window in (None, c["window"]):
             print(f"  paged attention {name} decode x{S} H{H}/{Hkv} D{D} "
                   f"contexts {ctx.min()}-{ctx.max()} "

@@ -414,7 +414,11 @@ class InferenceEngine:
             num_blocks=self.icfg.num_kv_blocks,
             dtype=self.icfg.kv_dtype,
             quant=self.icfg.kv_quant or "none",
-            recurrent=self._recurrent, run_cut=run_cut)
+            recurrent=self._recurrent, run_cut=run_cut,
+            # the kernel's DMAs move whole memory tiles: where it runs,
+            # each chip's slab of the pool is allocated filled up to them
+            tiled=self.attn_impl == "pallas" and not latent,
+            head_groups=self._kv_head_groups(topology))
         self.state = StateManager(kv_cfg, max_seqs=self.icfg.max_seqs,
                                   max_blocks_per_seq=self.max_blocks_per_seq,
                                   # a prefix hit aliases blocks and
@@ -895,27 +899,26 @@ class InferenceEngine:
             "steps (kind: full = every token of the step's sequences | "
             "window = those inside its queries' windows | latent = the "
             "cached rows a latent layer reads)", int_valued=True)
-        # the grid steps the kernel's short call (decode tokens, verify
-        # windows) makes in one layer of each kind that hold a needed
-        # block, and how full those groups of KV blocks ran: counted
-        # with the kernel's own rule for its group (``kv_group``), from
-        # the schedule
+        # the groups of KV blocks the kernel's short call (decode tokens,
+        # verify windows) walks in one layer of each kind, a trip of a
+        # tile's loop each, and how full they ran (a needed block is a
+        # copy the kernel starts): counted with the kernel's own rule
+        # for its group (``kv_group``), from the schedule
         self._c_attn_group_steps = reg.counter(
             "serving_attn_kv_group_steps_total",
-            "grid steps of the paged-attention kernel's short call that "
-            "hold a needed KV block, one layer of the kind (kind: full | "
-            "window)", int_valued=True)
+            "groups of KV blocks the paged-attention kernel's short call "
+            "walks (a trip of a tile's loop each), one layer of the kind "
+            "(kind: full | window)", int_valued=True)
         self._group_blocks = self._group_slots = 0
         kc = self.state.cfg
+        heads, lanes = kc.slab      # a chip's own: the call's shapes
         self._attn_group = kv_group(
             SHORT, self.cfg.num_heads // self.cfg.num_kv_heads,
-            kc.num_kv_heads // (self.topology.tp_size if self._tp_mesh
-                                else 1),
-            kc.head_dim, kc.block_size, kc.store_dtype,
+            heads // kc.head_groups, lanes, kc.block_size, kc.store_dtype,
             self.max_blocks_per_seq, kc.quant != "none")
         reg.gauge_fn("serving_attn_kv_group_fill", self._attn_group_fill,
                      "needed KV blocks over the blocks the short call's "
-                     "grid steps hold (absent before the first one)")
+                     "groups hold (absent before the first one)")
         if self._window:
             reg.gauge_fn(
                 "serving_kv_tokens_behind_window", self._behind_window,
@@ -1067,9 +1070,9 @@ class InferenceEngine:
         arguments: ``kv_tokens_full``, the sum of ``seen + n`` over its
         sequences, and with window layers ``kv_tokens_window``, the sum
         of ``min(seen + n, window + n - 1)``.  Where the Pallas kernel
-        serves, also ``kv_steps_full`` / ``kv_steps_window``: the grid
-        steps its short call makes in one such layer that hold a needed
-        block (``ops/paged_attention.group_steps``).  A model whose cache
+        serves, also ``kv_steps_full`` / ``kv_steps_window``: the groups
+        of KV blocks its short call walks in one such layer
+        (``ops/paged_attention.group_steps``).  A model whose cache
         is a latent pool: ``latent_tokens``, that sum for ONE latent layer,
         and ``latent_pairs``, the (query, cached row) pairs its causal
         attention holds (``n * seen + n (n + 1) / 2`` a run of n rows)."""
@@ -1132,8 +1135,8 @@ class InferenceEngine:
                 "state_starts": starts, "state_replays": replays}
 
     def _attn_group_fill(self) -> Optional[float]:
-        """Needed KV blocks over the blocks held by the grid steps the
-        short call made so far; None before the first one."""
+        """Needed KV blocks over the blocks held by the groups the
+        short call walked so far; None before the first one."""
         return (self._group_blocks / self._group_slots
                 if self._group_slots else None)
 
@@ -1504,6 +1507,14 @@ class InferenceEngine:
     # ------------------------------------------------------------------
     # SPMD sharding (TP + ZeRO-Inference weight sharding)
     # ------------------------------------------------------------------
+    def _kv_head_groups(self, topology) -> int:
+        """The chips a tensor mesh splits the kv heads over (1: none)."""
+        tp = topology.tp_size if (
+            topology is not None and topology.device_count > 1) else 1
+        split = (tp > 1 and self.cfg.num_kv_heads % tp == 0
+                 and self.cfg.num_heads % tp == 0)
+        return tp if split else 1
+
     def _setup_sharding(self) -> None:
         """Resolve mesh shardings once: KV head-split + weight specs."""
         self._repl = None
@@ -1513,14 +1524,15 @@ class InferenceEngine:
         if topo is None:
             return
         self._repl = topo.replicated
-        tp = topo.tp_size
-        cfg = self.cfg
-        head_split = (tp > 1 and cfg.num_kv_heads % tp == 0
-                      and cfg.num_heads % tp == 0)
-        # kv: [L, blocks, bs, 2, Hkv, D] — split the kv-head dim
-        kv_spec = P(None, None, None, None,
-                    TENSOR_AXIS if head_split else None)
-        self._kv_nsh = NamedSharding(topo.mesh, kv_spec)
+        head_split = self._kv_head_groups(topo) > 1
+        heads = TENSOR_AXIS if head_split else None
+        # kv: [L, blocks, bs, 2, Hkv, D] — split the kv-head dim; a
+        # quantized cache's scales [L, blocks, Hkv, 2 * bs] with it
+        self._kv_nsh = NamedSharding(topo.mesh,
+                                     P(None, None, None, None, heads))
+        if isinstance(self.state.kv, tuple):
+            self._kv_nsh = (self._kv_nsh,
+                            NamedSharding(topo.mesh, P(None, None, heads)))
         if head_split:
             # the Pallas kernel runs under shard_map, one head group/chip
             self._tp_mesh = topo.mesh

@@ -124,43 +124,70 @@ def _write_kv(kv_layer, k, v, batch: RaggedBatch, block_size: int,
     vq, vs = _quantize_kv(v, data.dtype)
     data = data.at[blk, off, 0].set(kq)
     data = data.at[blk, off, 1].set(vq)
-    scales = scales.at[blk, off, 0].set(ks)
-    scales = scales.at[blk, off, 1].set(vs)
+    # a block's scales are [Hkv, 2 * bs]: a head's keys', then its values'
+    scales = scales.at[blk, :, off].set(ks)
+    scales = scales.at[blk, :, block_size + off].set(vs)
     return (data, scales)
 
 
+def _block_scales(scales):
+    """Gathered blocks' scales ``[..., Hkv, 2 * bs]`` (a head a row, its
+    keys' then its values': what the kernel's DMAs want whole,
+    ``KVCacheConfig.kv_zeros``) → ``[..., bs, 2, Hkv]``, laid like the
+    blocks' codes."""
+    *lead, heads, lanes = scales.shape
+    x = scales.reshape(*lead, heads, 2, lanes // 2)
+    return jnp.swapaxes(jnp.moveaxis(x, -3, -1), -3, -2)
+
+
+def _pool_scales(scales):
+    """``_block_scales`` undone: scales laid like the blocks' codes
+    ``[..., bs, 2, Hkv]`` (what ``_quantize_kv`` gives for a pool's
+    worth of keys and values) → the pool's ``[..., Hkv, 2 * bs]``."""
+    x = jnp.moveaxis(jnp.swapaxes(scales, -3, -2), -1, -3)
+    return x.reshape(*x.shape[:-2], -1)
+
+
+def _fill_heads(x, num_kv_heads: int, groups: int, slab):
+    """x: ``[T, num_kv_heads * r, D]`` → ``[T, slab[0] * r, slab[1]]``: a
+    layer's queries, keys or values with the heads and lanes of a pool
+    allocated for the kernel (``KVCacheConfig.tiled``: each of the
+    ``groups`` chips' kv heads, ``r`` query heads each, and the head's
+    lanes filled up with zeros); as they are where the pool holds the
+    model's own."""
+    T, H, D = x.shape
+    heads, lanes = slab
+    if (heads, lanes) == (num_kv_heads, D):
+        return x
+    mine = num_kv_heads // groups
+    x = x.reshape(T, groups, mine, H // num_kv_heads, D)
+    x = jnp.pad(x, ((0, 0), (0, 0), (0, heads // groups - mine), (0, 0),
+                    (0, lanes - D)))
+    return x.reshape(T, -1, lanes)
+
+
+def _cut_heads(o, num_kv_heads: int, groups: int, num_heads: int, D: int):
+    """``_fill_heads`` undone on the attention's output."""
+    T, H, lanes = o.shape
+    if (H, lanes) == (num_heads, D):
+        return o
+    r = num_heads // num_kv_heads
+    o = o.reshape(T, groups, H // (groups * r), r, lanes)
+    return o[:, :, :num_kv_heads // groups, :, :D].reshape(T, num_heads, D)
+
+
 def _query_tiles(kv, batch: RaggedBatch, block_size: int,
-                 max_blocks_per_seq: int, window=None):
+                 max_blocks_per_seq: int):
     """The step's query tiles for the Pallas kernel
     (``ops/paged_attention.query_tiles``): built once a step, outside
     the layer scan, block-table rows gathered per tile.  ``kv``: the
     cache, stacked ``[L, rows, ...]`` or one layer's ``[rows, ...]``
-    (its last row is the trash row either way).  ``window``: the
-    model's attention window, where it has window layers."""
+    (its last row is the trash row either way)."""
     from ..ops.paged_attention import query_tiles
 
     return query_tiles(batch.seq_slot, batch.positions, batch.token_valid,
                        batch.block_tables, block_size, max_blocks_per_seq,
-                       trash=_kv_parts(kv)[0].shape[-5] - 1, window=window)
-
-
-def _group_tiles(tiles, kv, num_heads: int, window=None, shard_mesh=None):
-    """``tiles`` with their tables laid out by the grid steps of the
-    kernel's calls in the layers of one kind (``window``: the window
-    layers'; ``ops/paged_attention.group_tiles``): like the tiles, once
-    a step and outside the layer scan.  ``shard_mesh``: as the kernel
-    will run under it, a chip's share of the kv heads decides."""
-    from ..ops.paged_attention import group_tiles
-
-    data, scales = _kv_parts(kv)
-    hkv = data.shape[-2]
-    local = hkv
-    if shard_mesh is not None:
-        from ..comm.mesh import TENSOR_AXIS
-        local //= shard_mesh.shape[TENSOR_AXIS]
-    return group_tiles(tiles, num_heads // hkv, local, data.shape[-1],
-                       data.shape[-4], data.dtype, scales is not None,
-                       window)
+                       trash=_kv_parts(kv)[0].shape[-5] - 1)
 
 
 def _latent_tiles(cfg, pool, batch: RaggedBatch, block_size: int,
@@ -198,7 +225,7 @@ def _paged_attention_pallas(kv_layer, q, batch: RaggedBatch,
 
     if tiles is None:
         tiles = _query_tiles(kv_layer, batch, block_size,
-                             max_blocks_per_seq, window)
+                             max_blocks_per_seq)
     if shard_mesh is None:
         return paged_attention(kv_layer, q, tiles, scale, slopes=slopes,
                                layer=layer, window=window)
@@ -208,7 +235,7 @@ def _paged_attention_pallas(kv_layer, q, batch: RaggedBatch,
 
     data_spec = P(None, None, None, TENSOR_AXIS, None)  # [blocks,bs,2,Hkv,D]
     kv_spec = (data_spec if not isinstance(kv_layer, tuple)
-               else (data_spec, P(None, None, None, TENSOR_AXIS)))
+               else (data_spec, P(None, TENSOR_AXIS, None)))
     q_spec = P(None, TENSOR_AXIS, None)               # [T, H, D]
     base, rows = _layer_of(kv_layer, layer)
     # the tile list is the same on every chip: heads split, rows do not
@@ -270,7 +297,7 @@ def _paged_attention(kv_layer, q, batch: RaggedBatch, block_size: int,
     ctx = ctx.reshape(T, C, 2, Hkv, D)
     k_ctx, v_ctx = ctx[:, :, 0], ctx[:, :, 1]                     # [T, C, Hkv, D]
     if scales is not None:
-        sctx = scales[tables].reshape(T, C, 2, Hkv)
+        sctx = _block_scales(scales[tables]).reshape(T, C, 2, Hkv)
         k_ctx = _dequant_ctx(k_ctx, sctx[:, :, 0], q.dtype)
         v_ctx = _dequant_ctx(v_ctx, sctx[:, :, 1], q.dtype)
 
@@ -315,7 +342,7 @@ def _paged_attention_chunked(kv_layer, q, batch: RaggedBatch,
         ctx = data[blk]                             # [T, bs, 2, Hkv, D]
         k, v = ctx[:, :, 0], ctx[:, :, 1]           # [T, bs, Hkv, D]
         if scales is not None:
-            sc = scales[blk]                        # [T, bs, 2, Hkv]
+            sc = _block_scales(scales[blk])         # [T, bs, 2, Hkv]
             k = _dequant_ctx(k, sc[:, :, 0], q.dtype)
             v = _dequant_ctx(v, sc[:, :, 1], q.dtype)
         s = jnp.einsum("thrd,tbhd->thrb", qg, k).astype(jnp.float32) * scale
@@ -983,6 +1010,12 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
     act = L.ACTIVATIONS[cfg.activation]
     scale = (cfg.attn_scale if cfg.attn_scale is not None
              else 1.0 / (cfg.head_dim ** 0.5))
+    # the chips a tensor mesh splits the kv heads over: a pool allocated
+    # for the kernel holds each chip's own heads filled up (``_fill_heads``)
+    head_groups = 1
+    if shard_mesh is not None:
+        from ..comm.mesh import TENSOR_AXIS
+        head_groups = shard_mesh.shape[TENSOR_AXIS]
     pattern = cfg.layer_pattern
     P = len(pattern)
     lead, periods, tail = cfg.layer_plan
@@ -998,6 +1031,11 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
         x = x + params["pos_embed"]["table"][batch.positions].astype(dt)
     elif cfg.position == "alibi":
         slopes = L.alibi_slopes(cfg.num_heads)
+        if "mla" not in cfg.mixer_stacks:   # the pool's heads, as the queries
+            slopes = _fill_heads(
+                jnp.asarray(slopes, jnp.float32).reshape(1, -1, 1),
+                cfg.num_kv_heads, head_groups,
+                (_kv_parts(kv)[0].shape[-2], 1)).reshape(-1)
     else:
         cos, sin = L.rope_freqs(cfg.rotary_dim, cfg.max_seq_len, cfg.rope_theta)
 
@@ -1088,6 +1126,12 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
                     g = _head_proj(h, ap["wg"], dt, cfg.num_heads,
                                    cfg.head_dim)
         with jax.named_scope("kv_write"):
+            # the one seam where a pool allocated in whole memory tiles
+            # shows: behind it the write and every formulation see a
+            # model with the pool's heads and lanes
+            q, k, v = (_fill_heads(a, cfg.num_kv_heads, head_groups,
+                                   _kv_parts(pool)[0].shape[-2:])
+                       for a in (q, k, v))
             pool = _write_kv(pool, k, v, batch, block_size, layer=layer)
         # a window layer's calls under a scope of their own (inside
         # ``attn``): a trace tells its kernel from the full layers'
@@ -1104,6 +1148,8 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
                                      max_blocks_per_seq, scale,
                                      slopes=slopes, layer=layer,
                                      window=window)
+            o = _cut_heads(o, cfg.num_kv_heads, head_groups, cfg.num_heads,
+                           cfg.head_dim)
         with jax.named_scope("attn_out"):
             if cfg.attn_gate:
                 with jax.named_scope("attn_gate"):
@@ -1143,9 +1189,9 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
             return x + o + d, pool, stats
         return x + d, pool, stats
 
-    # what the Pallas kernel's grid walks is the same for every layer of
-    # a kind: cut the batch into query tiles here, once, outside the scan,
-    # and lay their tables out by the grid steps of each kind's calls
+    # what the Pallas kernel's grid walks is the same for every layer:
+    # cut the batch into query tiles here, once, outside the scan (a
+    # window layer's call finds its tiles' first blocks itself)
     tiles = {}
     if attn_impl == "pallas" and "mla" in cfg.mixer_stacks:
         # a layer holds ONE kind of cache: the latent layers' kernel
@@ -1153,12 +1199,8 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
         tiles = {"mla": _latent_tiles(cfg, kv, batch, block_size,
                                       max_blocks_per_seq)}
     elif attn_impl == "pallas" and not cfg.mixer_stacks:
-        cut = _query_tiles(kv, batch, block_size, max_blocks_per_seq,
-                           cfg.attn_window if "window" in pattern else None)
-        tiles = {kind: _group_tiles(
-            cut, kv, cfg.num_heads,
-            cfg.attn_window if kind == "window" else None, shard_mesh)
-            for kind in sorted(set(cfg.layer_kinds))}
+        tiles = dict.fromkeys(cfg.layer_kinds, _query_tiles(
+            kv, batch, block_size, max_blocks_per_seq))
     rows = _kv_parts(kv)[0].shape[1]       # a layer's blocks + trash row
 
     def at(li):

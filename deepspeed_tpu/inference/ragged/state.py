@@ -179,6 +179,16 @@ class KVCacheConfig:
     latent_dim: int = 0
     # a latent-only model: how its layers read a step's runs
     run_cut: Optional[RunCut] = None
+    # the pool is read by the Pallas kernel, whose DMAs move whole memory
+    # tiles (``ops/paged_attention.py``): a block's ``(Hkv, D)`` slab is
+    # allocated filled up to them (``slab``: gpt2's 12 x 64 as 16 x 128;
+    # the heads and lanes a model lacks stay zero), so that the kernel
+    # reads a block where it lies.  The engine says so where it runs the
+    # kernel; the XLA formulations read any shape
+    tiled: bool = False
+    # the kv heads split over this many chips (a tensor mesh): each
+    # chip's own heads are filled up, ``head_groups`` slabs side by side
+    head_groups: int = 1
 
     @property
     def runs(self):
@@ -216,18 +226,38 @@ class KVCacheConfig:
         return {"int8": jnp.int8, "fp8": jnp.float8_e4m3fn}.get(
             self.quant, self.dtype)
 
+    @property
+    def slab(self):
+        """(heads, lanes) a block of the pool holds a key or a value in:
+        the model's, or with ``tiled`` each chip's share of them filled
+        up to whole memory tiles."""
+        if not self.tiled:
+            return self.num_kv_heads, self.head_dim
+        from ...ops.paged_attention import slab
+        heads, lanes = slab(self.num_kv_heads // self.head_groups,
+                            self.head_dim, self.store_dtype)
+        return self.head_groups * heads, lanes
+
     def kv_zeros(self):
-        """A pristine cache: a single array, or (data, scales) when
-        quantized (a plain tuple — a pytree, so jit/donate/device_put
-        treat it like the array everywhere the engine is agnostic)."""
-        shape = (self.num_layers, self.num_blocks + 1, self.block_size, 2,
-                 self.num_kv_heads, self.head_dim)
+        """A pristine cache: a single array ``[L, blocks + 1, bs, 2,
+        heads, lanes]`` (``slab``), or (data, scales) when quantized (a
+        plain tuple — a pytree, so jit/donate/device_put treat it like
+        the array everywhere the engine is agnostic).  The scales are
+        ``[L, blocks + 1, heads, 2 * bs]`` f32: a head a row, its keys'
+        scales of the block and then its values', which is a whole
+        memory tile a block at 8 kv heads and blocks of 64 (a layout
+        with the heads innermost is 16 lanes of 128, and the TPU's
+        compiler then keeps the block axis innermost and relays a
+        layer's worth for every kernel call)."""
+        shape = (self.num_layers, self.num_blocks + 1, self.block_size, 2
+                 ) + self.slab
         if self.latent_dim:
             shape = shape[:3] + (self.latent_row,)
         if self.quant == "none":
             return jnp.zeros(shape, self.dtype)
         return (jnp.zeros(shape, self.store_dtype),
-                jnp.zeros(shape[:-1], jnp.float32))
+                jnp.zeros(shape[:2] + (shape[4], 2 * shape[2]),
+                          jnp.float32))
 
     def cache_zeros(self, max_seqs: int):
         """What a serving step carries and donates: the paged cache, and

@@ -30,10 +30,12 @@ which is attention of all the query heads over one shared key of
   and values expanded (``models/transformer.apply``).
 
 The kernel does for a pool of latent rows what ``ops/paged_attention.py``
-does for keys and values, on that module's tiles (the runs cut once a
-step by ``query_tiles``, the lists' tables, first rows, first positions
-and lengths by scalar prefetch, a finished tile's rows written by the
-kernel's own DMAs: ``send_tile_rows``), with a body of its own:
+does for keys and values, in that kernel's form and on that module's
+parts (the runs cut once a step by ``query_tiles``; the lists' tables,
+first rows, first positions and lengths by scalar prefetch; the walk
+that starts and waits for a group's needed blocks,
+``each_group_block``; a finished tile's rows written by the kernel's
+own DMAs, ``send_tile_rows``), with a body of its own:
 
 * grid ``(tiles,)``, traced.  A tile is ``height`` rows of ONE run at
   ALL heads, its queries one ``[height * H, width]`` operand (a view of
@@ -48,10 +50,11 @@ kernel's own DMAs: ``send_tile_rows``), with a body of its own:
   products read the buffer as it is.  While a group is attended the
   next one's blocks are on their way, and behind a tile's last group
   the NEXT tile's first: the DMA queue does not drain between tiles.  A
-  block behind the tile's last position is not read.  (The paged
-  kernel's BlockSpec pipeline, an operand a block of the group, was
-  measured first: its bookkeeping costs 0.17 us a block a grid step
-  where the block's DMA takes 0.10, PERF.md section 6, PR 50);
+  block behind the tile's last position is not read.  (This form was
+  measured here first, against a BlockSpec pipeline with an operand a
+  block of the group, whose bookkeeping cost 0.17 us a block a grid
+  step where the block's DMA takes 0.10, PERF.md section 6, PR 50; the
+  paged kernel took it over in PR 51);
 * one block in VMEM serves both products: the key is the block's whole
   row (``[c | k_r]`` and the zeros behind it), the value its first
   ``kv_rank`` lanes: one DMA a block, no second operand;
@@ -78,16 +81,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .paged_attention import (GROUP_VMEM_BYTES, LONG, NEG_INF,
-                              QueryTiles, TileList, _use_interpret,
+from .paged_attention import (GROUP_MAX, GROUP_SCORE_BYTES, GROUP_VMEM_BYTES,
+                              LONG, NEG_INF, QueryTiles, TileList, _start,
+                              _use_interpret, each_group_block, fetch_ahead,
                               query_tiles, send_tile_rows)
 
 F32 = jnp.float32
 # MXU rows (query rows x heads) of a tile of a run of several tokens
 RUN_ROWS = 1024
-# the most blocks a group holds, and the most its score tile may take
-GROUP_BLOCKS = 16
-GROUP_SCORE_BYTES = 2 * 1024 * 1024
 
 
 class MLADims(NamedTuple):
@@ -192,16 +193,17 @@ def tile_heights(heads: int) -> Tuple[int, int]:
 def latent_group(rows: int, width: int, block_size: int, dtype,
                  table_blocks: int) -> int:
     """Blocks a group of a tile of ``rows`` MXU rows holds: the largest
-    power of two, at most ``GROUP_BLOCKS`` and the table's width, whose
+    power of two, at most ``GROUP_MAX`` and the table's width, whose
     float32 score tile is at most ``GROUP_SCORE_BYTES`` and fits
-    ``ops/paged_attention``'s ``GROUP_VMEM_BYTES`` with the two buffers.
+    ``GROUP_VMEM_BYTES`` with the two buffers (the paged kernel's
+    budgets, ``ops/paged_attention``: 16 blocks, 2 MiB, 16 MiB).
     More blocks a group are fewer passes of the loop and more DMAs in
     flight (what a one-token tile wants: 16); a tile of a thousand rows
     rescales a 2 MB accumulator a group and wastes what a last group
     holds too much of (8)."""
     block = block_size * width * jnp.dtype(dtype).itemsize
     k = 1
-    while (2 * k <= min(GROUP_BLOCKS, table_blocks)
+    while (2 * k <= min(GROUP_MAX, table_blocks)
            and rows * 2 * k * block_size * 4 <= GROUP_SCORE_BYTES
            and 2 * 2 * k * block + rows * 2 * k * block_size * 4
            <= GROUP_VMEM_BYTES):
@@ -240,25 +242,19 @@ def _tile_kernel(tab_ref, row_ref, pos_ref, len_ref, base_ref, q_ref,
     def each_block(do, tile, g, slot):
         """``do`` (start or wait) the DMA of every block of group ``g``
         of ``tile`` that the tile needs, into buffer ``slot`` where the
-        group's rows lie one block behind another; a block behind the
-        tile's last position is not read (what the buffer holds there is
-        an earlier group's, and masked).  (A loop, not ``group``
-        branches: every function that holds the step lowers this body
-        anew, and unrolled at four places it took three times as long
-        to lower: 20 s of a cell's set-up, PERF.md section 6, PR 50.)"""
-        def one(i, _):
-            do(pltpu.make_async_copy(
-                pool_ref.at[tab_ref[tile, g * group + i] + base_ref[0]],
+        group's rows lie one block behind another (the paged kernel's
+        walk: ``each_group_block``)."""
+        def copies(i, block):
+            return (pltpu.make_async_copy(
+                pool_ref.at[block + base_ref[0]],
                 buf_ref.at[slot, pl.ds(pl.multiple_of(i * block_size,
                                                       block_size),
                                        block_size)],
-                sem_in.at[slot]))
+                sem_in.at[slot]),)
 
-        jax.lax.fori_loop(
-            0, jnp.minimum(last_block(tile) - g * group + 1, group), one,
-            None)
+        last = last_block(tile)
+        each_group_block(do, tab_ref, tile, g * group, last, group, copies)
 
-    start, wait = (lambda cp: cp.start()), (lambda cp: cp.wait())
     pos0 = pos_ref[t]
     groups = last_block(t) // group + 1
 
@@ -268,7 +264,7 @@ def _tile_kernel(tab_ref, row_ref, pos_ref, len_ref, base_ref, q_ref,
         # the buffers held)
         buf_ref[...] = jnp.zeros_like(buf_ref)
         par_ref[0] = 0
-        each_block(start, 0, 0, 0)
+        each_block(_start, 0, 0, 0)
 
     # the buffer that holds this tile's first group: the one the tile
     # before left free, whose last step started these DMAs
@@ -282,18 +278,7 @@ def _tile_kernel(tab_ref, row_ref, pos_ref, len_ref, base_ref, q_ref,
 
     def attend(g, _):
         slot = (par + g) % 2
-
-        # the next group's blocks (the next tile's first, behind this
-        # tile's last) are on their way while this one is attended
-        @pl.when(g + 1 < groups)
-        def _():
-            each_block(start, t, g + 1, 1 - slot)
-
-        @pl.when((g + 1 == groups) & (t + 1 < nt))
-        def _():
-            each_block(start, t + 1, 0, 1 - slot)
-
-        each_block(wait, t, g, slot)
+        fetch_ahead(each_block, t, nt, g, groups, slot)
         ctx = buf_ref[slot]                                  # [keys, width]
         s = jax.lax.dot_general(
             q, ctx, dimension_numbers=(((1,), (1,)), ((), ())),

@@ -13,50 +13,65 @@ outside the layer scan, and gathers each tile's block-table row; budget
 padding belongs to no tile.  The kernel then streams KV blocks through
 VMEM once per tile, not once per token:
 
-* grid ``(tiles, groups)``, both traced: a step runs as many grid rows
-  as it has tiles, each as long as the deepest tile's context in
-  *groups* of ``k`` consecutive KV blocks (the compiled bucket
-  ``max_blocks_per_seq`` only bounds it).  One grid step attends one
-  tile (all heads) to one group, ``k * block_size`` keys: per kv head
-  ONE score product, mask, online-softmax update and value product, so
-  the serial chain between them (a few hundred cycles whatever the
-  width) is paid once a group and not once a block.  ``k`` is a static
-  function of the call's shapes (``kv_group``: 8 for the decode tokens'
-  call at 4 and 8 kv heads of 128, 4 at 16; 1 for a 512-row prefill
-  tile, whose products already fill the step) and nothing selects it;
-* the group's blocks are fetched block by block: the pool is handed to
-  the call ``k`` times, one BlockSpec a block of the group, each block
-  carrying every kv head so the trailing block dims are full-size (a
-  Mosaic tiling requirement).  The tiles' tables (laid out by grid
-  step, below), first rows, first positions and lengths ride scalar
-  prefetch (``PrefetchScalarGridSpec``): a BlockSpec's index map picks
-  the DMA'd block, the query's picks the tile's first row as an
-  element offset into ``[T, H, D]`` — paged indirection and ragged rows
-  both happen in the DMA engine, never as a gather;
+* grid ``(tiles,)``, traced: a launch row is one tile (all heads), and
+  inside it a loop walks the tile's OWN context in *groups* of ``k``
+  consecutive KV blocks and no further: a decode token at 0.5k cached
+  tokens costs its eight blocks whatever the step's deepest context
+  (the compiled bucket ``max_blocks_per_seq`` only bounds the table).
+  One trip attends the tile to one group, ``k * block_size`` keys: per
+  kv head ONE score product, mask, online-softmax update and value
+  product, so the serial chain between them (a few hundred cycles
+  whatever the width) is paid once a group and not once a block.  ``k``
+  is a static function of the call's shapes (``kv_group``: 16 for the
+  decode tokens' call at 4 and 8 kv heads of 128, 8 at 16; 16 for a
+  512-row prefill tile and 8 for one of 1,024 rows, a score tile of
+  2 MiB) and nothing selects it;
+* the blocks are fetched by the kernel's own DMAs, one a block, from
+  the pool where it lies (handed over once, ``memory_space=pl.ANY``;
+  the table's entry plus the layer's base; never gathered) into one of
+  two VMEM buffers ``[k, bs, 2, Hkv, D]``, a block behind another.
+  While a group is attended the next one's blocks are on their way, and
+  behind a tile's last group the NEXT tile's first: the DMA queue does
+  not drain between tiles.  A block past the tile's last position is
+  neither read nor waited for; it stays masked (the buffers are zeroed
+  when the grid opens: a masked key's value still meets the second
+  product).  The walk over a group's needed blocks
+  (``each_group_block``) is the latent kernel's too (``ops/mla.py``).
+  The tiles' tables, first rows, first positions and lengths ride
+  scalar prefetch (``PrefetchScalarGridSpec``); the query's index map
+  picks the tile's first row as an element offset into ``[T, H, D]`` —
+  paged indirection and ragged rows both happen in the DMA engine.
+  (Until PR 51 the grid was ``(tiles, groups)`` with the pool handed
+  over ``k`` times, one BlockSpec a block of the group, and a table
+  laid out once a step told every operand which row to show at every
+  grid step: that pipeline's bookkeeping, and a grid row as long as the
+  step's deepest tile for every tile, are what this form takes away,
+  PERF.md section 6, PR 51.)  A DMA moves whole memory tiles, so a
+  pool the kernel reads is ALLOCATED with a slab that fills them
+  (``slab``: gpt2's 12 x 64 as 16 x 128, each chip's own heads under a
+  tensor mesh; ``KVCacheConfig.tiled``, the engine's where it runs the
+  kernel) and a quantized cache's scales lie ``[Hkv, 2 * bs]`` a block,
+  a head a row (one whole tile at 8 kv heads and blocks of 64): nothing
+  is cut, padded or relaid for a call, and the TPU's compiler has no
+  reason to keep the block axis innermost, as it does for a pool whose
+  rows are not whole tiles (it then relays the whole stack around every
+  call);
 * per kv head the products are ``[height * rep, D] x [D, k * bs]`` and
   ``[height * rep, k * bs] x [k * bs, D]``: the tile's queries are
-  folded to that shape once, at its first group, and kept in VMEM; the
-  causal mask is ``col <= first_pos + row``; the online softmax keeps
-  (m, l, acc) per row in f32 across the tile's groups;
-* a head's keys are read through the block's 32-bit sublanes: in VMEM a
-  block is ``bs * 2 * Hkv`` rows of ``D`` lanes, a sublane holding two
-  heads in bf16 (four in int8), so head ``h``'s 64 keys are eight
-  strided loads and a shift where indexing ``[:, c, h, :]`` is 64 loads,
-  64 rotates and 56 selects (that gather, not the chain, was all of a
-  block's 1.2 us at 8 kv heads and 2.7 us at 16).  Shapes that have no
-  such view (an odd head count a sublane, ``D`` under 128 lanes, fp8)
-  are gathered as before;
-* groups past the tile's last position are skipped (``pl.when``); a
-  block of a group past it is masked whole and NOT read: its operand's
-  index map stays on the block the operand already holds (the last one
-  it showed for this tile, or the row an earlier tile left it on, or
-  the first row a later tile will ask of it), so nothing is DMA'd for
-  it.  A call reads the blocks its tiles need and, when the grid opens,
-  at most one block for each operand that no tile of the list needs at
-  all.  Which pool row each operand shows at each grid step is laid out
-  as a table (``_group_rows``) that the index maps look up: once a
-  step and outside the layer scan where the caller asks for it with
-  the tiles (``group_tiles``), else in the call;
+  folded to that shape once a tile and kept in VMEM; the causal mask is
+  ``col <= first_pos + row``; the online softmax keeps (m, l, acc) per
+  row in f32 across the tile's groups;
+* a head's keys are read through the buffer's 32-bit sublanes: in VMEM
+  a group is ``k * bs * 2 * Hkv`` rows of ``D`` lanes, a sublane holding
+  two heads in bf16 (four in int8), so head ``h``'s ``k * 64`` keys are
+  one strided load, eight keys an instruction, and a shift where
+  indexing ``[:, c, h, :]`` is 64 loads, 64 rotates and 56 selects a
+  block (that gather, not the chain, was all of a block's 1.2 us at 8
+  kv heads and 2.7 us at 16).  A type with no such view (fp8) is
+  gathered as before.  A quantized cache's codes go into the products
+  as they are (exact in bf16) and a head's scales, a row ``[1, k * bs]``
+  in the scores' column order, multiply the scores and the weights:
+  ``q . (code * scale) = (q . code) * scale``;
 * the output is written by the kernel's own DMAs, ``length`` rows of it
   and no more (groups of 8 rows, then single rows: static sizes, a
   traced count): the row after a tile's last belongs to another run.
@@ -68,11 +83,12 @@ property of the batch, not an option.
 
 A *window* layer (``window=W``: a query sees its last ``W`` keys, its
 own among them) SKIPS what lies behind the window and does not only mask
-it: a tile's grid row starts at the block that holds position ``first
+it: a tile's loop starts at the block that holds position ``first
 query - (W - 1)`` and ends at the block of its last query, at most
 ``ceil((W + height) / block_size) + 1`` blocks whatever the context
 (its groups count from that first block, which need not be a multiple
-of ``k``), and the mask cuts inside the first of them.  Those calls are named
+of ``k``; the same tiles serve both kinds of layer), and the mask cuts
+inside the first of them.  Those calls are named
 ``paged_attention_w_h<height>``, so that a trace tells them from the
 full layers'.
 
@@ -98,16 +114,20 @@ NEG_INF = -1e30
 SHORT, LONG = 8, 128
 
 
-# A grid step of a tile attends a GROUP of consecutive KV blocks.  How many
+# A trip of a tile's loop attends a GROUP of consecutive KV blocks.  How many
 # is a static function of what a call can see (``kv_group``): the largest
-# power of two, at most ``GROUP_MAX`` and the table's width, whose double
+# power of two, at most ``GROUP_MAX`` and the table's width, whose two
 # buffers (as Mosaic tiles them in VMEM) and f32 score tile fit
-# ``GROUP_VMEM_BYTES`` (half of Mosaic's default scoped VMEM), the score
-# tile alone ``GROUP_SCORE_BYTES`` (half the vector registers: what a
-# step's softmax keeps live)
-GROUP_MAX = 8
-GROUP_VMEM_BYTES = 8 * 1024 * 1024
-GROUP_SCORE_BYTES = 128 * 1024
+# ``GROUP_VMEM_BYTES`` (a quarter of the 64 MiB the call scopes), the
+# score tile alone ``GROUP_SCORE_BYTES``.  Measured on the chip with the
+# blocks fetched by the kernel (PERF.md section 6, PR 51): 16 blocks beat
+# 8 for the decode tokens' tiles at 4 and 8 kv heads (a block's DMA is
+# what is left at 4), and a prefill tile's call falls by two thirds from
+# one block a trip to a score tile of 2 MiB (its accumulator is rescaled
+# once a group)
+GROUP_MAX = 16
+GROUP_VMEM_BYTES = 16 * 1024 * 1024
+GROUP_SCORE_BYTES = 2 * 1024 * 1024
 
 
 def _use_interpret() -> bool:
@@ -121,13 +141,6 @@ class TileList(NamedTuple):
     pos: jnp.ndarray        # [n] i32 position of that row in its sequence
     length: jnp.ndarray     # [n] i32 rows, 1..height
     count: jnp.ndarray      # [] i32 real tiles; the rest is padding
-    blocks: jnp.ndarray     # [] i32 KV blocks the deepest real tile needs
-    wblocks: jnp.ndarray    # [] i32 the same in a window layer (the most
-                            # blocks one tile's window touches)
-    rows: jnp.ndarray = None    # [n, steps * k] i32 ``tables`` laid out by
-                                # the grid steps of one kind of layer's
-                                # call (``group_tiles``); None: the call
-                                # lays them out itself
 
 
 class QueryTiles(NamedTuple):
@@ -149,30 +162,43 @@ def _pad(n: int, to: int) -> int:
     return -(-n // to) * to
 
 
-def block_vmem_bytes(num_kv_heads: int, head_dim: int, block_size: int,
-                     kv_dtype, quant: bool = False) -> int:
-    """VMEM one ``[bs, 2, Hkv, D]`` KV block takes as Mosaic tiles it: the
-    ``(Hkv, D)`` slab in memory tiles of 128 lanes by a power of two of
-    rows, at least a 32-bit sublane's worth and at most 8 (4 kv heads in
-    bf16 take no padding, 12 take 16, 64 lanes take 128); an int8
-    cache's f32 scales ``[bs, 2, Hkv]`` with it, their heads padded to
-    128 lanes."""
-    item = jnp.dtype(kv_dtype).itemsize
-    rows = 4 // item
+def slab_rows(num_kv_heads: int, kv_dtype) -> int:
+    """Rows of the memory tiles Mosaic lays a block's ``(Hkv, D)`` slab
+    out in, 128 lanes each: a power of two, at least a 32-bit sublane's
+    worth and at most 8 (4 kv heads in bf16 fill theirs, 12 take 16)."""
+    rows = 4 // jnp.dtype(kv_dtype).itemsize
     while rows < min(num_kv_heads, 8):
         rows *= 2
-    block = block_size * 2 * _pad(num_kv_heads, rows) * _pad(
-        head_dim, 128) * item
+    return rows
+
+
+def slab(num_kv_heads: int, head_dim: int, kv_dtype) -> Tuple[int, int]:
+    """(heads, lanes) of a block's slab filled up to whole memory tiles:
+    what a pool the kernel reads is allocated with
+    (``KVCacheConfig.tiled``) and what a block takes in VMEM."""
+    return (_pad(num_kv_heads, slab_rows(num_kv_heads, kv_dtype)),
+            _pad(head_dim, 128))
+
+
+def block_vmem_bytes(num_kv_heads: int, head_dim: int, block_size: int,
+                     kv_dtype, quant: bool = False) -> int:
+    """VMEM one ``[bs, 2, Hkv, D]`` KV block takes as Mosaic tiles it
+    (``slab``: 4 kv heads in bf16 take no padding, 12 take 16, 64 lanes
+    take 128); a quantized cache's f32 scales ``[Hkv, 2 * bs]`` with
+    it."""
+    heads, lanes = slab(num_kv_heads, head_dim, kv_dtype)
+    block = block_size * 2 * heads * lanes * jnp.dtype(kv_dtype).itemsize
     if quant:
-        block += block_size * 2 * _pad(num_kv_heads, 128) * 4
+        block += _pad(num_kv_heads, slab_rows(num_kv_heads, jnp.float32)
+                      ) * _pad(2 * block_size, 128) * 4
     return block
 
 
 def group_vmem_bytes(group: int, rows: int, num_kv_heads: int, head_dim: int,
                      block_size: int, kv_dtype, quant: bool = False) -> int:
-    """VMEM a grid step's group of ``group`` KV blocks takes: every
-    block twice (double buffered, ``block_vmem_bytes``) and the f32
-    score tile ``[rows, group * bs]``."""
+    """VMEM a group of ``group`` KV blocks takes: every block twice
+    (the kernel's two buffers, ``block_vmem_bytes``) and the f32 score
+    tile ``[rows, group * bs]``."""
     return (2 * group * block_vmem_bytes(num_kv_heads, head_dim, block_size,
                                          kv_dtype, quant)
             + rows * group * block_size * 4)
@@ -181,9 +207,9 @@ def group_vmem_bytes(group: int, rows: int, num_kv_heads: int, head_dim: int,
 def kv_group(height: int, rep: int, num_kv_heads: int, head_dim: int,
              block_size: int, kv_dtype, table_blocks: int,
              quant: bool = False) -> int:
-    """KV blocks a grid step of the call at ``height`` attends: what
-    the kernel takes and what the host counts with (``group_steps``).
-    Nothing but the call's own shapes decides it."""
+    """KV blocks a trip of the loop of the call at ``height`` attends:
+    what the kernel takes and what the host counts with
+    (``group_steps``).  Nothing but the call's own shapes decides it."""
     rows = height * rep
     k = 1
     while (2 * k <= min(GROUP_MAX, table_blocks)
@@ -197,10 +223,11 @@ def kv_group(height: int, rep: int, num_kv_heads: int, head_dim: int,
 
 def group_steps(runs: Sequence[Tuple[int, int]], block_size: int, group: int,
                 window: int = None) -> Tuple[int, int]:
-    """(grid steps that hold at least one needed block, needed blocks)
-    of one layer's short call over ``runs``, ``(first position, rows)``
-    each, rows <= ``SHORT`` — the host's count of what the kernel's
-    grid does for them (a window layer with ``window``)."""
+    """(groups, needed blocks) of one layer's short call over ``runs``,
+    ``(first position, rows)`` each, rows <= ``SHORT`` — the host's
+    count of what the kernel does for them (a window layer with
+    ``window``): a group is a trip of a tile's loop, a needed block a
+    copy it starts; there is no other trip and no other copy."""
     steps = blocks = 0
     for pos, n in runs:
         first = 0 if window is None else max(pos - (window - 1),
@@ -213,14 +240,16 @@ def group_steps(runs: Sequence[Tuple[int, int]], block_size: int, group: int,
 
 def window_blocks(pos, length, window: int, block_size: int):
     """(first, last) KV block that the window layer's tile starting at
-    position ``pos`` with ``length`` rows has to read."""
-    first = jnp.maximum(pos - (window - 1), 0) // block_size
-    return first, (pos + jnp.maximum(length, 1) - 1) // block_size
+    position ``pos`` with ``length`` rows has to read — the host's
+    arithmetic (numbers or arrays) for what the kernel's ``_tile_span``
+    computes a tile."""
+    first = np.maximum(pos - (window - 1), 0) // block_size
+    return first, (pos + np.maximum(length, 1) - 1) // block_size
 
 
 def query_tiles(seq_slot, positions, token_valid, block_tables,
                 block_size: int, max_blocks_per_seq: int,
-                trash: int, window: int = None, short: int = SHORT,
+                trash: int, short: int = SHORT,
                 long: int = LONG) -> QueryTiles:
     """Cut a ragged batch into query tiles, on the device, once a step.
 
@@ -230,8 +259,7 @@ def query_tiles(seq_slot, positions, token_valid, block_tables,
     slot at consecutive positions; a slot holds at most one run a step
     (``StateManager.build_batch`` schedules a sequence once), which
     bounds the lists: ``max_seqs`` short tiles, ``T // long`` full long
-    tiles and one partial one a long run.  ``window``: the model's
-    attention window where it has window layers (``wblocks``).
+    tiles and one partial one a long run.
     ``short``, ``long``: the two heights (this kernel's; the latent
     kernel of ``ops/mla.py`` cuts the same runs at its own)."""
     T = seq_slot.shape[0]
@@ -258,23 +286,21 @@ def query_tiles(seq_slot, positions, token_valid, block_tables,
         tables = block_tables[slot[rows], :max_blocks_per_seq]
         tables = jnp.where(tables < 0, trash, tables).astype(jnp.int32)
         length = jnp.where(real, length[rows], 0)
-        blocks = jnp.minimum(jnp.max(jnp.where(
-            real, (pos[rows] + length - 1) // block_size + 1, 1)),
-            max_blocks_per_seq)
-        wblocks = blocks
-        if window is not None:
-            first, last = window_blocks(pos[rows], length, window,
-                                        block_size)
-            wblocks = jnp.minimum(
-                jnp.max(jnp.where(real, last - first + 1, 1)), blocks)
-        return TileList(tables, rows, pos[rows], length, count, blocks,
-                        wblocks)
+        return TileList(tables, rows, pos[rows], length, count)
 
     return QueryTiles(
         collect(first & is_short, run_len, min(T, max_seqs)),
         collect(valid & ~is_short & (off % long == 0),
                 jnp.minimum(run_len - off, long),
                 max(1, T // long + min(max_seqs, T // (short + 1)))))
+
+
+def _start(cp):
+    cp.start()
+
+
+def _wait(cp):
+    cp.wait()
 
 
 def _each_row_copy(do, src, dst, sem, row, n):
@@ -289,8 +315,10 @@ def _each_row_copy(do, src, dst, sem, row, n):
         do(pltpu.make_async_copy(src.at[pl.ds(at, size)],
                                  dst.at[pl.ds(row + at, size)], sem))
 
-    jax.lax.fori_loop(0, n // g, lambda i, _: copy(i * g, g), None)
-    jax.lax.fori_loop(0, n % g, lambda i, _: copy(n // g * g + i, 1), None)
+    whole = jax.lax.div(n, jnp.int32(g))
+    jax.lax.fori_loop(0, whole, lambda i, _: copy(i * g, g), None)
+    jax.lax.fori_loop(0, jax.lax.rem(n, jnp.int32(g)),
+                      lambda i, _: copy(whole * g + i, 1), None)
 
 
 def send_tile_rows(t, nt, fill, ob_ref, o_ref, sem, row_ref, len_ref):
@@ -300,122 +328,212 @@ def send_tile_rows(t, nt, fill, ob_ref, o_ref, sem, row_ref, len_ref):
     here; they are waited for when the next tile (or the grid) ends, so
     they overlap its blocks.  ``ob_ref``: ``[2, height, ...]`` in VMEM,
     ``o_ref`` the output in HBM, ``sem`` two DMA semaphores."""
-    slot = t % 2
-    start, wait = (lambda cp: cp.start()), (lambda cp: cp.wait())
+    slot = jax.lax.rem(t, 2)
 
     @pl.when(t > 0)
     def _():
-        _each_row_copy(wait, ob_ref.at[1 - slot], o_ref, sem.at[1 - slot],
+        _each_row_copy(_wait, ob_ref.at[1 - slot], o_ref, sem.at[1 - slot],
                        row_ref[t - 1], len_ref[t - 1])
 
     fill(ob_ref.at[slot])
     mine = (ob_ref.at[slot], o_ref, sem.at[slot], row_ref[t], len_ref[t])
-    _each_row_copy(start, *mine)
+    _each_row_copy(_start, *mine)
 
     @pl.when(t == nt - 1)
     def _():
-        _each_row_copy(wait, *mine)
+        _each_row_copy(_wait, *mine)
 
 
-def _tile_span(t, pos, length, block_size: int, window):
-    """(first, last) KV block tile ``t`` has to read; ``pos``/``length``
-    are the tile list's (refs in an index map or the kernel, arrays in
-    ``_group_rows``)."""
+def _tile_span(t, pos_ref, len_ref, block_size: int, window):
+    """(first, last) KV block tile ``t`` has to read, in the kernel
+    (``window_blocks`` is the host's).  The primitives, not ``jnp``'s
+    ``//``, ``%`` and ``maximum``: each of those traces and lowers a
+    nested function with the signs' handling at every use, and every
+    function that holds the step lowers the kernels' bodies anew (a
+    tenth of a second a kernel: seconds of a cell's set-up)."""
+    bs = jnp.int32(block_size)
+    last = jax.lax.div(
+        pos_ref[t] + jax.lax.max(len_ref[t], jnp.int32(1)) - 1, bs)
     if window is None:
-        return 0, (pos[t] + jnp.maximum(length[t], 1) - 1) // block_size
-    return window_blocks(pos[t], length[t], window, block_size)
+        return 0, last
+    return jax.lax.div(jax.lax.max(pos_ref[t] - (window - 1), jnp.int32(0)),
+                       bs), last
 
 
-def _kernel(rows_ref, row_ref, pos_ref, len_ref, base_ref, *rest,
-            height: int, block_size: int, scale: float,
+def each_group_block(do, tab_ref, tile, at, last, group: int, copies):
+    """``do`` (start or wait) the DMAs of the blocks ``at``, ``at + 1``,
+    ... of ``tile``'s table that the tile needs: at most ``group`` of
+    them and none behind its block ``last``, which is neither read nor
+    waited for (what a buffer holds in its place is an earlier group's,
+    or the zeros the buffers open with, and masked).  ``copies(i,
+    block)``: the DMAs that bring pool block ``block`` to place ``i`` of
+    a buffer; all of a buffer's count on one semaphore, so waiting takes
+    the same walk.  (A loop, not ``group`` branches: every function that
+    holds the step lowers the kernel's body anew, and unrolled at four
+    places it took three times as long to lower: 20 s of a cell's
+    set-up, PERF.md section 6, PR 50.)"""
+    def one(i, _):
+        for cp in copies(i, tab_ref[tile, at + i]):
+            do(cp)
+
+    jax.lax.fori_loop(0, jax.lax.min(last - at + 1, jnp.int32(group)), one,
+                      None)
+
+
+def fetch_ahead(fetch, t, nt, g, groups, slot):
+    """Group ``g`` of tile ``t`` of ``nt`` arrived in buffer ``slot``,
+    and the next one's blocks (the next tile's first group, behind this
+    tile's last of ``groups``) on their way into the other buffer while
+    this one is attended.  ``fetch(do, tile, g, slot)``: ``do`` the DMAs
+    of group ``g`` of ``tile`` into buffer ``slot``
+    (``each_group_block``); the grid's first is started by the kernel
+    when it opens."""
+    @pl.when(g + 1 < groups)
+    def _():
+        fetch(_start, t, g + 1, 1 - slot)
+
+    @pl.when((g + 1 == groups) & (t + 1 < nt))
+    def _():
+        fetch(_start, t + 1, 0, 1 - slot)
+
+    fetch(_wait, t, g, slot)
+
+
+def _kernel(tab_ref, row_ref, pos_ref, len_ref, base_ref, q_ref, kv_ref,
+            *rest, height: int, block_size: int, scale: float,
             num_kv_heads: int, rep: int, alibi: bool, kv_quant: bool,
             window, group: int):
-    # the group's blocks come one operand each; optional inputs (order:
-    # the blocks' scales, alibi slopes) sit between them and the aliased
-    # output
+    # optional inputs (order: the blocks' scales, alibi slopes) sit
+    # between the pool and the aliased output; the scales' buffers are
+    # the last scratch
     rest = list(rest)
-    q_ref = rest.pop(0)
-    kv_refs = [rest.pop(0) for _ in range(group)]
-    ks_refs = [rest.pop(0) for _ in range(group)] if kv_quant else None
+    ks_ref = rest.pop(0) if kv_quant else None
     slopes_ref = rest.pop(0) if alibi else None
-    _, o_ref, qs_ref, ob_ref, acc_ref, m_ref, l_ref, sem = rest
+    (_, o_ref, qs_ref, ob_ref, acc_ref, m_ref, l_ref, par_ref, sem, sem_in,
+     buf_ref, *sbuf_ref) = rest
     t = pl.program_id(0)
-    j = pl.program_id(1)
     nt = pl.num_programs(0)
-    ng = pl.num_programs(1)
     R = height * rep
     keys = group * block_size
+    D = buf_ref.shape[-1]
+
+    def fetch(do, tile, g, slot):
+        """``do`` (start or wait) the DMAs of group ``g`` of ``tile``
+        into buffer ``slot``: each needed block from the pool row where
+        it lies (the stack's: the layer's base added), a quantized
+        cache's scales from the same row of theirs.  A window layer's
+        groups count from the first block its first query's window
+        touches."""
+        first, last = _tile_span(tile, pos_ref, len_ref, block_size, window)
+
+        def copies(i, block):
+            cps = [pltpu.make_async_copy(kv_ref.at[block + base_ref[0]],
+                                         buf_ref.at[slot, i],
+                                         sem_in.at[slot])]
+            if kv_quant:
+                cps.append(pltpu.make_async_copy(
+                    ks_ref.at[block + base_ref[0]], sbuf_ref[0].at[slot, i],
+                    sem_in.at[slot]))
+            return cps
+
+        each_group_block(do, tab_ref, tile, first + g * group, last, group,
+                         copies)
+
     pos0 = pos_ref[t]
-    n = len_ref[t]
-    # the first KV block of the group this grid step attends: a window
-    # layer's row starts at the first block its first query's window
-    # touches
-    blk = j * group + _tile_span(t, pos_ref, len_ref, block_size, window)[0]
+    first, last = _tile_span(t, pos_ref, len_ref, block_size, window)
+    groups = jax.lax.div(last - first, jnp.int32(group)) + 1
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        # fold the tile's queries [height, H, D] to one [height * rep, D]
-        # operand per kv head, once for all its blocks (row = token * rep
-        # + head of the group)
-        for h in range(num_kv_heads):
-            qs_ref[h] = (q_ref[:, h, :] if rep == 1 else
-                         q_ref[:, h * rep:(h + 1) * rep, :].reshape(
-                             R, q_ref.shape[-1]))
-
-    # a block seen as its 32-bit sublanes, [bs * 2 * hw, D], where it has
-    # such a view (``of_group``; made once: a ref's bitcast is slow to
-    # trace)
-    pack = 4 // kv_refs[0].dtype.itemsize   # heads a 32-bit sublane holds
+    # the two buffers seen as their 32-bit sublanes, [2, keys * 2 * hw,
+    # D], where a block has such a view (``of_group``)
+    pack = 4 // buf_ref.dtype.itemsize      # heads a 32-bit sublane holds
     hw = num_kv_heads // pack
     words = None
-    if not (num_kv_heads % pack or kv_refs[0].shape[-1] % 128
-            or kv_refs[0].dtype not in (jnp.float32, jnp.bfloat16, jnp.int8)):
-        words = [(ref if pack == 1 else ref.bitcast(jnp.uint32)).reshape(
-            block_size * 2 * hw, ref.shape[-1]) for ref in kv_refs]
+    if not (num_kv_heads % pack or D % 128
+            or buf_ref.dtype not in (jnp.float32, jnp.bfloat16, jnp.int8)):
+        words = (buf_ref if pack == 1 else buf_ref.bitcast(jnp.uint32)
+                 ).reshape(2, keys * 2 * hw, D)
 
-    def each(part):
+    @pl.when(t == 0)
+    def _():
+        # (a masked key's value meets the second product too: zeros, not
+        # whatever the buffers held)
+        def zero(i, _):
+            buf_ref[jax.lax.div(i, group), jax.lax.rem(i, group)] = jnp.zeros(
+                buf_ref.shape[2:], buf_ref.dtype)
+
+        jax.lax.fori_loop(0, 2 * group, zero, None)
+        for ref in sbuf_ref:
+            ref[...] = jnp.zeros_like(ref)
+        par_ref[0] = 0
+        fetch(_start, 0, 0, 0)
+
+    # the buffer that holds this tile's first group: the one the tile
+    # before left free, whose last step started these DMAs
+    par = par_ref[0]
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    # fold the tile's queries [height, H, D] to one [height * rep, D]
+    # operand per kv head, once for all its groups (row = token * rep +
+    # head of the group)
+    for h in range(num_kv_heads):
+        qs_ref[h] = (q_ref[:, h, :] if rep == 1 else
+                     q_ref[:, h * rep:(h + 1) * rep, :].reshape(
+                         R, q_ref.shape[-1]))
+    # a folded row's position: row // rep tokens after the first
+    qpos = pos0 + jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0) // rep
+
+    def each(part, axis=0):
         """``part(i)`` of the group's every block, one after another."""
         parts = [part(i) for i in range(group)]
-        return parts[0] if group == 1 else jnp.concatenate(parts, axis=0)
+        return parts[0] if group == 1 else jnp.concatenate(parts, axis=axis)
 
-    def of_group(c, h, dtype):
-        """Keys (c = 0) or values (1) of kv head ``h`` in the group's
-        blocks, [keys, D]."""
-        kv = kv_refs[0]
+    def of_group(slot, c, h, dtype):
+        """Keys (c = 0) or values (1) of kv head ``h`` in the group that
+        buffer ``slot`` holds, [keys, D]."""
         if words is None:   # gathered a key at a time
-            x = each(lambda i: kv_refs[i][0, :, c, h, :])
+            x = each(lambda i: buf_ref[slot, i, :, c, h, :])
         else:
-            # in a block's words head h's sublanes lie ``2 * hw`` apart,
-            # a key each, so strided loads bring eight keys an
-            # instruction where indexing the head brings one and rotates
-            # it into place; a sublane holds ``pack`` heads of one key,
-            # h's bits are shifted out (once for the whole group)
-            x = each(lambda i: words[i][
-                pl.ds(c * hw + h // pack, block_size, stride=2 * hw), :])
+            # in a buffer's words head h's sublanes lie ``2 * hw`` apart,
+            # a key each and a block's behind the block's before it, so
+            # one strided load brings the group's keys, eight an
+            # instruction, where indexing the head brings one and
+            # rotates it into place; a sublane holds ``pack`` heads of
+            # one key, h's bits are shifted out
+            x = words.at[slot][
+                pl.ds(c * hw + h // pack, keys, stride=2 * hw), :]
             at = h % pack * (32 // pack)
-            if kv.dtype == jnp.bfloat16:    # the high half of an f32
+            if buf_ref.dtype == jnp.bfloat16:   # the high half of an f32
                 x = pltpu.bitcast(
                     x & jnp.uint32(0xFFFF0000) if at else x << 16,
                     jnp.float32).astype(jnp.bfloat16)
-            elif kv.dtype == jnp.int8:      # sign-extended
+            elif buf_ref.dtype == jnp.int8:     # sign-extended
                 x = pltpu.bitcast(x << (24 - at), jnp.int32) >> 24
-        if kv_quant:        # in-VMEM dequant: HBM only streamed codes
-            x = (x.astype(jnp.float32) * each(
-                lambda i: ks_refs[i][0, :, c, h][:, None])).astype(dtype)
-        return x
+        # (a quantized cache's codes are exact in ``dtype``; their
+        # scales meet the scores and the weights, ``scales_of``)
+        return x.astype(jnp.float32).astype(dtype) if kv_quant else x
 
-    # the whole group is past the tile's last position → nothing to add
-    @pl.when(blk * block_size <= pos0 + n - 1)
-    def _compute():
-        cols = blk * block_size + jax.lax.broadcasted_iota(
+    def scales_of(slot, c, h):
+        """The scales of head ``h``'s keys (c = 0) or values (1) in the
+        group that buffer ``slot`` holds, [1, keys] in the scores'
+        column order: in-VMEM dequant, HBM only streamed the codes.  A
+        block's scales are ``[Hkv, 2 * bs]`` (a head a row; its keys'
+        then its values', ``KVCacheConfig.kv_zeros``), so head ``h``'s
+        rows of the group are one strided load."""
+        rows = sbuf_ref[0].at[slot].reshape(group * num_kv_heads,
+                                            2 * block_size)[
+            pl.ds(h, group, stride=num_kv_heads), :]
+        return each(lambda i: rows[i:i + 1,
+                                   c * block_size:(c + 1) * block_size],
+                    axis=1)
+
+    def attend(g, _):
+        slot = jax.lax.rem(par + g, 2)
+        fetch_ahead(fetch, t, nt, g, groups, slot)
+        cols = (first + g * group) * block_size + jax.lax.broadcasted_iota(
             jnp.int32, (R, keys), 1)
-        # a folded row's position: row // rep tokens after the first
-        qpos = pos0 + jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0) // rep
         # this also masks whole the blocks of the group that lie past the
-        # tile's last position: their operands hold some earlier block
+        # tile's last position and were not read
         keep = cols <= qpos
         if window is not None:
             # a row whose window starts after this group is masked whole
@@ -424,11 +542,13 @@ def _kernel(rows_ref, row_ref, pos_ref, len_ref, base_ref, *rest,
             keep &= cols > qpos - window
         for h in range(num_kv_heads):          # static unroll (GQA groups)
             q = qs_ref[h]                                  # [R, D]
-            k = of_group(0, h, q.dtype)
-            v = of_group(1, h, q.dtype)
+            k = of_group(slot, 0, h, q.dtype)
+            v = of_group(slot, 1, h, q.dtype)
             s = jax.lax.dot_general(
                 q, k, dimension_numbers=(((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale  # [R, keys]
+            if kv_quant:    # q . (code * scale) = (q . code) * scale
+                s = s * scales_of(slot, 0, h)
             if alibi:       # ALiBi: slope_h * absolute key position
                 s = s + slopes_ref[h] * cols.astype(jnp.float32)
             s = jnp.where(keep, s, NEG_INF)
@@ -438,88 +558,27 @@ def _kernel(rows_ref, row_ref, pos_ref, len_ref, base_ref, *rest,
             corr = jnp.exp(m_prev - m_new)
             m_ref[h] = m_new
             l_ref[h] = l_prev * corr + p.sum(axis=1, keepdims=True)
+            if kv_quant:
+                p = p * scales_of(slot, 1, h)
             pv = jax.lax.dot_general(
                 p.astype(v.dtype), v,
                 dimension_numbers=(((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)          # [R, D]
             acc_ref[h] = acc_ref[h] * corr + pv
+        return 0
 
-    @pl.when(j == ng - 1)
-    def _finalize():
-        def fill(ob):
-            for h in range(num_kv_heads):
-                # unfolded in f32: Mosaic has no such shape cast for packed
-                # rows narrower than a lane tile (gpt2's D = 64 in bf16)
-                o = (acc_ref[h] / jnp.maximum(l_ref[h], 1e-30)).reshape(
-                    height, rep, acc_ref.shape[-1])
-                ob[:, h * rep:(h + 1) * rep, :o.shape[-1]] = o.astype(
-                    ob.dtype)
+    jax.lax.fori_loop(0, groups, attend, 0)
+    par_ref[0] = jax.lax.rem(par + groups, 2)
 
-        send_tile_rows(t, nt, fill, ob_ref, o_ref, sem, row_ref, len_ref)
+    def fill(ob):
+        for h in range(num_kv_heads):
+            # unfolded in f32: Mosaic has no such shape cast for packed
+            # rows narrower than a lane tile (gpt2's D = 64 in bf16)
+            o = (acc_ref[h] / jnp.maximum(l_ref[h], 1e-30)).reshape(
+                height, rep, acc_ref.shape[-1])
+            ob[:, h * rep:(h + 1) * rep, :o.shape[-1]] = o.astype(ob.dtype)
 
-
-@functools.partial(jax.jit, static_argnums=(1, 2, 3))
-def _group_rows(tiles: TileList, group: int, block_size: int, window):
-    """[n, steps * group] i32: the pool row the ``i``-th operand of a
-    group of several shows at grid step ``(t, j)``, at
-    ``[t, j * group + i]``; made once a call (and traced once a shape),
-    so that an index map is one lookup.  While tile ``t`` needs it, that
-    is block ``first + j * group + i`` of its table; past the tile's
-    last block the operand stays on the last block it held for this
-    tile: consecutive grid steps then revisit the same block and Pallas
-    skips the DMA entirely (the kernel masks what it holds).  An operand
-    the tile needs no block of at all (its span has ``i`` blocks or
-    fewer) stays on the row it was left on by the last tile that needed
-    it, so nothing is fetched, or, before any did, on the first row the
-    next such tile will ask for (the fetch that opens the grid is then
-    that tile's own)."""
-    n, nb = tiles.tables.shape
-    t = jnp.arange(n, dtype=jnp.int32)
-    first, last = _tile_span(t, tiles.pos, tiles.length, block_size, window)
-    span = (last - first)[:, None]
-    slot = np.arange(_pad(nb, group), dtype=np.int32)[None, :]
-    i = slot % group
-    # the operand's last block of this tile, once the row is past it
-    # (``group`` is a power of two: the mask rounds down to a multiple)
-    stay = i + ((span - i) & -group)
-    rows = jnp.take_along_axis(
-        tiles.tables,
-        jnp.clip(jnp.broadcast_to(first, (n,))[:, None]
-                 + jnp.minimum(slot, stay), 0, nb - 1), axis=1)
-    needs = (i[:, :group] <= span) & (t < tiles.count)[:, None]
-    before = jax.lax.cummax(jnp.where(needs, t[:, None], -1), axis=0)
-    before = jnp.concatenate(
-        [jnp.full((1, group), -1, jnp.int32), before[:-1]])
-    after = jax.lax.cummin(jnp.where(needs, t[:, None], n), axis=0,
-                           reverse=True)
-    # a tile's last step shows each operand's last block of it, its
-    # first step the first
-    left_on = jnp.take_along_axis(rows[:, -group:], jnp.maximum(before, 0),
-                                  axis=0)
-    opens_on = jnp.take_along_axis(rows[:, :group],
-                                   jnp.minimum(after, n - 1), axis=0)
-    held = jnp.where(before >= 0, left_on,
-                     jnp.where(after < n, opens_on, tiles.tables[0, 0]))
-    return jnp.where(i <= span, rows,
-                     jnp.tile(held, (1, slot.shape[1] // group)))
-
-
-def group_tiles(tiles: QueryTiles, rep: int, num_kv_heads: int,
-                head_dim: int, block_size: int, kv_dtype,
-                quant: bool = False, window: int = None) -> QueryTiles:
-    """``tiles`` with each list's tables laid out by the grid steps of
-    the call that will walk it (``rows``), for the layers of one kind
-    (``window``: a window layer's) over a pool of these shapes (what
-    ``kv_group`` reads; under ``shard_map`` a chip's own).  Made once a
-    step, outside the layer scan, like the tiles themselves; a call
-    whose tiles come without lays them out itself, every layer."""
-    def laid(tl: TileList, height: int) -> TileList:
-        k = kv_group(height, rep, num_kv_heads, head_dim, block_size,
-                     kv_dtype, tl.tables.shape[1], quant)
-        return tl._replace(rows=None if k == 1 else _group_rows(
-            tl, k, block_size, window))
-
-    return QueryTiles(laid(tiles.short, SHORT), laid(tiles.long, LONG))
+    send_tile_rows(t, nt, fill, ob_ref, o_ref, sem, row_ref, len_ref)
 
 
 def _attend(tiles: TileList, kv_layer, kv_scales, q, out, base, height: int,
@@ -533,60 +592,38 @@ def _attend(tiles: TileList, kv_layer, kv_scales, q, out, base, height: int,
     kv_quant = kv_scales is not None
     group = kv_group(height, rep, Hkv, D, bs, kv_layer.dtype,
                      tiles.tables.shape[1], kv_quant)
-
-    if group == 1:
-        # the one operand has a block of every tile: the tile's table,
-        # clamped to its last block in the map.  (An empty list's entry
-        # 0 has length 0; whatever evaluates this for it must still get
-        # a block of the table.)
-        rows = tiles.tables
-
-        def _at(i, t, j, pos, length):
-            first, last = _tile_span(t, pos, length, bs, window)
-            return jnp.minimum(first + j, last)
-    else:
-        rows = (_group_rows(tiles, group, bs, window)
-                if tiles.rows is None else tiles.rows)
-        assert rows.shape == (tiles.tables.shape[0],
-                              _pad(tiles.tables.shape[1], group))
-
-        def _at(i, t, j, pos, length):
-            return j * group + i
-
-    def _kv_index(i):
-        return lambda t, j, rows, row, pos, length, base: (
-            rows[t, _at(i, t, j, pos, length)] + base[0], 0, 0, 0, 0)
-
-    def _ks_index(i):
-        return lambda t, j, rows, row, pos, length, base: (
-            rows[t, _at(i, t, j, pos, length)], 0, 0, 0)
-
-    def _q_index(t, j, rows, row, *_):
-        return (row[t], 0, 0)
-
     alibi = slopes is not None
-    prefetch = [rows, tiles.row, tiles.pos, tiles.length,
+    prefetch = [tiles.tables, tiles.row, tiles.pos, tiles.length,
                 jnp.reshape(base, (1,)).astype(jnp.int32)]
     in_specs = [
         pl.BlockSpec((pl.Element(height), pl.Element(H), pl.Element(D)),
-                     _q_index)]
-    in_specs += [pl.BlockSpec((1, bs, 2, Hkv, D), _kv_index(i))
-                 for i in range(group)]
-    operands = [q] + [kv_layer] * group
+                     lambda t, tables, row, *_: (row[t], 0, 0)),
+        pl.BlockSpec(memory_space=pl.ANY)]
+    operands = [q, kv_layer]
+    scratch = [
+        pltpu.VMEM((Hkv, R, D), q.dtype),            # folded queries
+        pltpu.VMEM((2, height) + out.shape[1:], q.dtype),  # output rows
+        pltpu.VMEM((Hkv, R, D), jnp.float32),
+        pltpu.VMEM((Hkv, R, 1), jnp.float32),
+        pltpu.VMEM((Hkv, R, 1), jnp.float32),
+        pltpu.SMEM((1,), jnp.int32),
+        pltpu.SemaphoreType.DMA((2,)),
+        pltpu.SemaphoreType.DMA((2,)),
+        pltpu.VMEM((2, group, bs, 2, Hkv, D), kv_layer.dtype)]  # two groups
     if kv_quant:
-        in_specs += [pl.BlockSpec((1, bs, 2, Hkv), _ks_index(i))
-                     for i in range(group)]
-        operands += [kv_scales] * group
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+        operands.append(kv_scales)
+        scratch.append(pltpu.VMEM((2, group) + kv_scales.shape[1:],
+                                  jnp.float32))
     if alibi:
         # per folded row (token * rep + head of the group)
-        in_specs.append(pl.BlockSpec((Hkv, R, 1), lambda t, j, *_: (0, 0, 0)))
+        in_specs.append(pl.BlockSpec((Hkv, R, 1), lambda t, *_: (0, 0, 0)))
         operands.append(jnp.tile(
             jnp.asarray(slopes, jnp.float32).reshape(Hkv, 1, rep),
             (1, height, 1)).reshape(Hkv, R, 1))
     in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
     operands.append(out)
 
-    blocks = tiles.blocks if window is None else tiles.wblocks
     return pl.pallas_call(
         functools.partial(_kernel, height=height, block_size=bs,
                           scale=scale, num_kv_heads=Hkv, rep=rep,
@@ -594,22 +631,15 @@ def _attend(tiles: TileList, kv_layer, kv_scales, q, out, base, height: int,
                           group=group),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),
-            grid=(tiles.count, (blocks + group - 1) // group),
+            grid=(tiles.count,),
             in_specs=in_specs,
             out_specs=pl.BlockSpec(memory_space=pl.ANY),
-            scratch_shapes=[
-                pltpu.VMEM((Hkv, R, D), q.dtype),            # folded queries
-                pltpu.VMEM((2, height) + out.shape[1:], q.dtype),  # output rows
-                pltpu.VMEM((Hkv, R, D), jnp.float32),
-                pltpu.VMEM((Hkv, R, 1), jnp.float32),
-                pltpu.VMEM((Hkv, R, 1), jnp.float32),
-                pltpu.SemaphoreType.DMA((2,)),
-            ],
+            scratch_shapes=scratch,
         ),
         out_shape=jax.ShapeDtypeStruct(out.shape, out.dtype),
         input_output_aliases={len(prefetch) + len(operands) - 1: 0},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
+            dimension_semantics=("arbitrary",),
             vmem_limit_bytes=64 * 1024 * 1024),
         interpret=_use_interpret(),
         name=f"paged_attention{'' if window is None else '_w'}_h{height}",
@@ -620,12 +650,10 @@ def paged_attention(kv_layer, q, tiles: QueryTiles, scale: float,
                     slopes=None, layer=None, window=None):
     """kv_layer: [blocks+1, bs, 2, Hkv, D] (last row = trash), or a
     (data, scales) tuple for a quantized cache (scales
-    [blocks+1, bs, 2, Hkv] f32; codes dequantized in VMEM so HBM only
-    streams the 1-byte payloads);
-    q: [T, H, D]; ``tiles``: ``query_tiles`` of the step, or
-    ``group_tiles`` of them for this kind of layer and these shapes
-    where the caller has many layers → out [T, H, D], zero in the rows
-    of no tile (budget padding).
+    [blocks+1, Hkv, 2 * bs] f32, a head's keys' then its values';
+    codes dequantized in VMEM so HBM only streams the 1-byte payloads);
+    q: [T, H, D]; ``tiles``: ``query_tiles`` of the step → out
+    [T, H, D], zero in the rows of no tile (budget padding).
     ``slopes``: optional ALiBi per-head slopes, any shape reshapeable to
     [Hkv, rep] in head order h = hkv*rep + r (reference analog: the alibi
     operand of the inference softmax kernels, csrc/transformer/inference/
@@ -633,30 +661,29 @@ def paged_attention(kv_layer, q, tiles: QueryTiles, scale: float,
     ``layer``: ``(base, rows)`` when ``kv_layer`` is the stacked cache
     viewed as ``[L * rows, ...]`` and this call attends layer ``li``,
     whose ``rows`` rows (its blocks, then its trash row) start at row
-    ``base = li * rows``; ``None`` is a pool of one layer.  The codes
-    need only the offset: the index map picks row ``base + block`` as
-    it picks any row, and the DMA engine reads it where it lies.  The
-    scales are cut to the layer's rows first and indexed without the
-    offset, because Mosaic wants them in a lane-padded layout of its
-    own: a layer's worth is relaid per call as before, never the
-    stack's.
-    ``window``: a window layer's window (module docstring); ``tiles``
-    must have been cut, and laid out, with it."""
+    ``base = li * rows``; ``None`` is a pool of one layer.  The call
+    needs only the offset: the kernel copies row ``base + block`` of the
+    codes, and of the scales, as it copies any row, from where it lies;
+    nothing of the pool is cut, padded or relaid for the call.
+    ``window``: a window layer's window (module docstring).
+
+    A DMA moves whole memory tiles, so where Mosaic compiles the kernel
+    a block's ``(Hkv, D)`` slab has to fill them (``slab_rows`` rows of
+    128 lanes) and a block's scales ``[Hkv, 2 * bs]`` theirs: a pool
+    that the kernel reads is allocated so (``KVCacheConfig.tiled``: the
+    heads and lanes a model lacks are zeros, and so are the queries'),
+    never filled up for a call.  (The interpreter copies any shape.)"""
     kv_scales = None
     if isinstance(kv_layer, tuple):
         kv_layer, kv_scales = kv_layer
-    base, rows = (0, kv_layer.shape[0]) if layer is None else layer
-    if kv_scales is not None:
-        kv_scales = jax.lax.dynamic_slice_in_dim(kv_scales, base, rows)
+    base = 0 if layer is None else layer[0]
+    T, H, D = q.shape
     # the element-offset query window of a tile that starts in the last
     # rows reads past them: give it rows to read
     qp = jnp.pad(q, ((0, LONG), (0, 0), (0, 0)))
-    # the kernel's own DMAs move whole (sublane, lane) tiles: heads and
-    # head size that do not fill theirs (gpt2's 12 x 64) get an output
-    # that does, cut back here
-    T, H, D = q.shape
-    Hp = H if H < 8 else -(-H // 8) * 8
-    out = jnp.zeros((T, Hp, -(-D // 128) * 128), q.dtype)
+    # the output's rows leave by DMA too: heads that do not fill their
+    # sublanes get an output that does, cut back here
+    out = jnp.zeros((T, H if H < 8 else _pad(H, 8), _pad(D, 128)), q.dtype)
     for tl, height in ((tiles.long, LONG), (tiles.short, SHORT)):
         out = _attend(tl, kv_layer, kv_scales, qp, out, base, height,
                       scale, slopes, window)

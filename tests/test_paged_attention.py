@@ -1,6 +1,9 @@
 """Pallas paged-attention kernel vs the XLA gather formulation
 (reference analog: inference/v2/kernels/ragged_ops blocked_flash tests)."""
 
+import functools
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -156,35 +159,46 @@ def _built_batch(runs, T, max_seqs=8):
     return batch, dict(sm._slots)
 
 
-# name -> (runs, token budget); bs = 8, so 128-row tiles cross 16 blocks
+# name -> (runs, token budget); bs = 8, so 128-row tiles cross 16 blocks.
+# (Two budgets, 32 and 192, for the ten of them: cases of one shape
+# share a compiled program.)
 TILE_BATCHES = {
     # runs of one at different depths, then budget padding
-    "decode-only": ([(1, 19, 1), (2, 0, 1), (3, 70, 1), (4, 8, 1)], 16),
+    "decode-only": ([(1, 19, 1), (2, 0, 1), (3, 70, 1), (4, 8, 1)], 32),
     # 150 rows = a whole 128-row tile and 22 of the next; starts at
     # position 5, in the middle of a block
-    "chunk-unaligned": ([(1, 5, 150)], 160),
+    "chunk-unaligned": ([(1, 5, 150)], 192),
     # a verify window (k + 1 = 4 rows) between two decode tokens
-    "verify-window": ([(1, 30, 1), (2, 19, 4), (3, 3, 1)], 16),
+    "verify-window": ([(1, 30, 1), (2, 19, 4), (3, 3, 1)], 32),
     # two chunks and three decode tokens in one step
     "two-chunks": ([(1, 40, 1), (2, 3, 140), (3, 9, 1), (4, 61, 30),
                     (5, 0, 1)], 192),
     # a run of exactly the short height and one just over it
     "eight-and-nine": ([(1, 11, 8), (2, 2, 9)], 32),
     # all padding but one token
-    "one-token": ([(1, 12, 1)], 64),
-    # bs = 8 and groups of 8 blocks: a grid step of the short call holds
-    # 64 keys.  Contexts that end in the first, a middle and the last
-    # block of their second group, at its two edges, and inside the
-    # first group (shorter than one group); the deepest tile first
-    "group-edges": ([(1, 135, 1), (2, 67, 1), (3, 99, 1), (4, 124, 1),
-                     (5, 63, 1), (6, 64, 1), (7, 127, 1), (8, 10, 1)], 16),
+    "one-token": ([(1, 12, 1)], 32),
+    # bs = 8 and groups of 16 blocks: a group of the short call holds
+    # 128 keys, a table of 32 blocks two groups.  Contexts that end in
+    # the first and a middle block of their second group, at the edge
+    # between the two (the first group's last key, the second's first)
+    # and inside the first group; the deepest tile first
+    "group-edges": ([(1, 250, 1), (2, 135, 1), (3, 127, 1), (4, 128, 1),
+                     (5, 10, 1), (6, 67, 1)], 32),
     # verify windows of 2 to 8 rows whose positions cross a group's edge
-    # (61..66 and 126..133 and 63..64), one that ends on it, and a
+    # (125..130 and 127..128), one that ends on it (120..127), and a
     # decode token; the last tile of the list is the shallowest
-    "verify-across-groups": ([(1, 61, 6), (2, 126, 8), (3, 63, 2),
+    "verify-across-groups": ([(1, 125, 6), (2, 120, 8), (3, 127, 2),
                               (4, 57, 7), (5, 180, 1), (6, 2, 3)], 32),
     # the last tile of the list is the deepest, behind single-group ones
-    "deep-last": ([(1, 5, 1), (2, 30, 2), (3, 250, 1)], 8),
+    "deep-last": ([(1, 5, 1), (2, 30, 2), (3, 250, 1)], 32),
+    # tiles of one block and deep tiles in turn (what is fetched ahead
+    # across tiles, and into which of the two buffers: at groups of
+    # four blocks 7, 5, 4 and 3 groups, odd and even counts between the
+    # shallow ones); contexts that end on a block's last key (7, 199)
+    # and on a group's (127)
+    "deep-and-shallow-in-turn": ([(1, 3, 1), (2, 199, 1), (3, 7, 1),
+                                  (4, 135, 1), (5, 0, 1), (6, 127, 1),
+                                  (7, 64, 1)], 32),
 }
 
 
@@ -195,8 +209,29 @@ def _random_pool(seed, layers=None, quant=False, hkv=HKV_T, d=D_T):
         shape = (layers,) + shape
     if quant:
         return (jnp.asarray(r.randint(-127, 128, shape), jnp.int8),
-                jnp.asarray(r.uniform(0.01, 0.03, shape[:-1]), jnp.float32))
+                jnp.asarray(r.uniform(0.01, 0.03,
+                                      shape[:-4] + (hkv, 2 * BS_T)),
+                            jnp.float32))
     return jnp.asarray(r.randn(*shape), jnp.float32)
+
+
+# One compile for the cases that differ in data only: the interpreted
+# kernel traced eagerly is lowered anew at every call (2.5-4.5 s), under
+# ``jit`` once a shape.  ``most``: the ``GROUP_MAX`` a test has patched
+# in, which the trace reads and the cache's key has to hold.
+@functools.partial(jax.jit, static_argnames=("nb", "scale", "layer",
+                                             "window", "most"))
+def _on_kernel(kv, q, batch, nb, scale, slopes=None, layer=None, window=None,
+               most=None):
+    return _paged_attention_pallas(kv, q, batch, BS_T, nb, scale,
+                                   slopes=slopes, layer=layer, window=window)
+
+
+@functools.partial(jax.jit, static_argnames=("nb", "scale", "layer",
+                                             "window"))
+def _on_xla(kv, q, batch, nb, scale, slopes=None, layer=None, window=None):
+    return _paged_attention(kv, q, batch, BS_T, nb, scale, slopes=slopes,
+                            layer=layer, window=window)
 
 
 def _check_tiles(kv, batch, H, nb, layer=None, slopes=None, tol=1e-5,
@@ -204,17 +239,35 @@ def _check_tiles(kv, batch, H, nb, layer=None, slopes=None, tol=1e-5,
     T = batch.token_ids.shape[0]
     D = jax.tree.leaves(kv)[0].shape[-1]
     q = jnp.asarray(np.random.RandomState(11).randn(T, H, D), dtype)
-    scale = 1.0 / np.sqrt(D)
-    ref = _paged_attention(kv, q, batch, BS_T, nb, scale, slopes=slopes,
-                           layer=layer)
-    out = _paged_attention_pallas(kv, q, batch, BS_T, nb, scale,
-                                  slopes=slopes, layer=layer)
+    scale = float(1.0 / np.sqrt(D))
+    ref = _on_xla(kv, q, batch, nb, scale, slopes=slopes, layer=layer)
+    out = _on_kernel(kv, q, batch, nb, scale, slopes=slopes, layer=layer)
     valid = np.asarray(batch.token_valid)
     out, ref = (np.asarray(a.astype(jnp.float32)) for a in (out, ref))
     assert valid.any()
     np.testing.assert_allclose(out[valid], ref[valid], atol=tol, rtol=tol)
     # budget padding belongs to no tile: nothing is written there
     assert not out[~valid].any()
+
+
+def _poisoned(kv, batch, window=None, layer=None):
+    """``kv`` with NaN in every pool row that no query of ``batch`` has
+    to read (the scales of a quantized pool: codes cannot hold one); of
+    a stacked pool viewed ``[L * rows, ...]`` every other layer's rows
+    too (``layer``: ``(base, rows)``)."""
+    data = jax.tree.leaves(kv)[0]
+    base = 0 if layer is None else layer[0]
+    need = np.zeros(data.shape[0], bool)
+    tables = np.asarray(batch.block_tables)
+    valid = np.asarray(batch.token_valid)
+    for slot, pos in zip(np.asarray(batch.seq_slot)[valid],
+                         np.asarray(batch.positions)[valid]):
+        first = 0 if window is None else max(pos - (window - 1), 0) // BS_T
+        need[base + tables[slot, first:pos // BS_T + 1]] = True
+    bad = jnp.asarray(~need).reshape((-1,) + (1,) * (data.ndim - 1))
+    if isinstance(kv, tuple):
+        return kv[0], jnp.where(bad[..., 0, 0], jnp.nan, kv[1])
+    return jnp.where(bad, jnp.nan, kv)
 
 
 class TestQueryTiles:
@@ -252,7 +305,8 @@ class TestQueryTiles:
         keys are gathered a key at a time, not through the words."""
         runs, T = TILE_BATCHES[name]
         batch, _ = _built_batch(runs, T)
-        _check_tiles(_random_pool(9, hkv=3, d=64), batch, 3, nb=32)
+        kv = _random_pool(9, hkv=3, d=64)
+        _check_tiles(kv, batch, 3, nb=32)
 
     @pytest.mark.parametrize("rep", [1, 8])
     @pytest.mark.parametrize("store", ["f32", "bf16", "int8"])
@@ -272,59 +326,94 @@ class TestQueryTiles:
             kv, dtype, tol = kv.astype(jnp.bfloat16), jnp.bfloat16, 2e-2
         _check_tiles(kv, batch, 4 * rep, nb=32, tol=tol, dtype=dtype)
 
-    @pytest.mark.parametrize("window", [None, 64, 20])
+    @pytest.mark.parametrize("window,most", [(None, 4), (64, 16), (20, 8)])
     @pytest.mark.parametrize("name", sorted(TILE_BATCHES))
-    def test_host_counts_the_grid_steps_the_kernel_makes(self, name, window):
-        """``group_steps`` on the host against the kernel's own rule on
-        the device's tile list: a short tile's grid row holds
-        ``ceil(span / k)`` steps with a needed block, ``k`` from
-        ``kv_group``; past a tile's needed blocks the index maps repeat
-        the row each operand was left on (no fetch for a block that no
-        tile needs)."""
-        import importlib
-
+    def test_host_counts_the_trips_and_copies_the_kernel_makes(
+            self, name, window, most, monkeypatch):
+        """``group_steps`` on the host against what the short call does
+        on the device for the same runs: a trip of a tile's loop is a
+        group that holds a needed block (``ceil(span / k)`` of them, ``k``
+        from ``kv_group``: its own, and smaller ones, so that a table of
+        32 blocks is up to eight groups deep), and each needed block is
+        one copy started and one waited for, no more."""
         from deepspeed_tpu.inference.model import _query_tiles
         pa = importlib.import_module("deepspeed_tpu.ops.paged_attention")
 
         runs, T = TILE_BATCHES[name]
         batch, _ = _built_batch(runs, T)
         nb = 32
-        tiles = _query_tiles(_random_pool(8), batch, BS_T, nb, window).short
+        kv = _random_pool(8)
+        tiles = _query_tiles(kv, batch, BS_T, nb)
+        assert pa.GROUP_MAX == 16
+        monkeypatch.setattr(pa, "GROUP_MAX", most)
         k = pa.kv_group(pa.SHORT, 4, HKV_T, D_T, BS_T, jnp.float32, nb)
-        assert k == pa.GROUP_MAX == 8
-        n = int(tiles.count)
-        pos, length = (np.asarray(a)[:n] for a in (tiles.pos, tiles.length))
+        assert k == most
+        n = int(tiles.short.count)
+        pos, length = (np.asarray(a)[:n]
+                       for a in (tiles.short.pos, tiles.short.length))
         first, last = pa._tile_span(np.arange(n), pos, length, BS_T, window)
         span = np.asarray(last) - np.asarray(first) + 1
         short = [(seen, m) for _, seen, m in runs if 0 < m <= pa.SHORT]
-        assert pa.group_steps(short, BS_T, k, window) == (
-            int((-(-span // k)).sum()), int(span.sum()))
-        if not n:       # an empty list: its grid has no row
+        steps, blocks = pa.group_steps(short, BS_T, k, window)
+        assert (steps, blocks) == (int((-(-span // k)).sum()),
+                                   int(span.sum()))
+        if window and name not in ("deep-and-shallow-in-turn",
+                                   "verify-across-groups", "two-chunks"):
             return
-        # walking the grid with the rows the index maps read
-        # (``_group_rows``): while a tile needs it an operand shows that
-        # block of the tile's table, and it changes rows only at a step
-        # whose block the tile needs
-        rows = np.asarray(pa._group_rows(tiles, k, BS_T, window))
-        tables = np.asarray(tiles.tables)
-        first = np.broadcast_to(np.asarray(first), (n,))
-        on = [None] * k
-        fetched = 0
-        for t in range(n):
-            for j in range(-(-int(np.asarray(
-                    tiles.wblocks if window else tiles.blocks)) // k)):
-                for i in range(k):
-                    b = j * k + i
-                    row = rows[t, b]
-                    if b < span[t]:
-                        assert row == tables[t, first[t] + b]
-                    if row != on[i]:
-                        assert b < span[t] or on[i] is None
-                        fetched, on[i] = fetched + 1, row
-        # nothing beyond the needed blocks, but the one fetch that
-        # opens the grid for an operand no tile of the list needs
-        assert fetched <= span.sum() + sum(i >= span.max()
-                                           for i in range(k))
+        # the walk itself, counted where the kernel makes it (the short
+        # call alone: the long tiles' list emptied): every full layer's,
+        # and three of the batches behind each window
+        made = {"walks": 0, "copies": 0}
+        walk = pa.each_group_block
+
+        def count(key):
+            jax.debug.callback(
+                lambda: made.__setitem__(key, made[key] + 1))
+
+        def counted(do, *args):
+            count("walks")
+            walk(lambda cp: (count("copies"), do(cp)), *args)
+
+        monkeypatch.setattr(pa, "each_group_block", counted)
+        q = jnp.asarray(np.random.RandomState(3).randn(T, 4 * HKV_T, D_T),
+                        jnp.float32)
+        pa.paged_attention(kv, q, tiles._replace(long=jax.tree.map(
+            jnp.zeros_like, tiles.long)), 0.25, window=window)
+        jax.effects_barrier()
+        # every group and every block once started and once waited for
+        assert made == {"walks": 2 * steps, "copies": 2 * blocks}
+
+    @pytest.mark.parametrize("store", ["bf16", "int8"])
+    @pytest.mark.parametrize("window,most", [(None, 4), (20, 16)])
+    @pytest.mark.parametrize("name", ["deep-and-shallow-in-turn",
+                                      "verify-across-groups", "two-chunks"])
+    def test_a_block_no_tile_needs_is_never_read(self, name, window, most,
+                                                 store, monkeypatch):
+        """Every pool row outside the tiles' needed blocks holds NaN
+        (an int8 cache's scales do): the trash row, the blocks behind a
+        tile's last position, a window layer's blocks before its first.
+        The kernel's output is the reference's over the clean pool, at
+        its own groups and at groups of four blocks (a full layer's
+        tiles up to eight groups deep)."""
+        monkeypatch.setattr(importlib.import_module(
+            "deepspeed_tpu.ops.paged_attention"), "GROUP_MAX", most)
+        runs, T = TILE_BATCHES[name]
+        batch, _ = _built_batch(runs, T)
+        nb, H = 32, 4 * HKV_T
+        kv = _random_pool(14, quant=store == "int8")
+        dtype, tol = jnp.float32, 1e-4
+        if store == "bf16":
+            kv, dtype, tol = kv.astype(jnp.bfloat16), jnp.bfloat16, 2e-2
+        q = jnp.asarray(np.random.RandomState(15).randn(T, H, D_T), dtype)
+        scale = float(1.0 / np.sqrt(D_T))
+        want = _on_xla(kv, q, batch, nb, scale, window=window)
+        got = _on_kernel(_poisoned(kv, batch, window), q, batch, nb, scale,
+                         window=window, most=most)
+        valid = np.asarray(batch.token_valid)
+        got, want = (np.asarray(a.astype(jnp.float32)) for a in (got, want))
+        np.testing.assert_allclose(got[valid], want[valid], atol=tol,
+                                   rtol=tol)
+        assert not got[~valid].any()
 
     def test_row_after_a_tile_is_not_overwritten(self):
         """Each height alone writes its own tiles' rows and no other:

@@ -2,33 +2,64 @@
 kernel (``tests/test_paged_attention.py`` holds the kernel against the
 XLA formulation, shape by shape): the engine's greedy decode on the
 kernel, the cache carried in place through the layer scan, a layer of
-the stacked pool, the tables laid out once a step, and the counters the
-tile grid brings."""
+the stacked pool, the tiles cut once a step for both kinds of layer, and
+the counters the tile grid brings."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from deepspeed_tpu.inference.model import _paged_attention_pallas
 from deepspeed_tpu.inference.ragged.state import RaggedBatch
 from tests.test_paged_attention import (BS_T, D_T, HKV_T, NBLK_T,
                                         TILE_BATCHES, _built_batch,
-                                        _check_tiles, _random_pool)
+                                        _check_tiles, _on_kernel, _on_xla,
+                                        _poisoned, _random_pool)
 
 
 class TestEngineOnTheKernel:
-    def test_engine_forced_pallas_decode_parity(self):
+    # (model, engine, the slab of the pool the engine allocates for the
+    # kernel): llama-tiny's two heads of 16 lanes; three kv heads under
+    # ALiBi (a head and 112 lanes of zeros a key); the same behind an
+    # int8 cache, whose codes pack four heads a sublane
+    CASES = {
+        "lanes": ({}, {}, (2, 128)),
+        "heads-and-lanes-alibi": (
+            dict(num_heads=6, num_kv_heads=3, d_model=96,
+                 position="alibi", attention_impl="xla"), {}, (4, 128)),
+        "heads-and-lanes-int8kv": (
+            dict(num_heads=6, num_kv_heads=3, d_model=96),
+            dict(kv_quant="int8"), (4, 128)),
+    }
+
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_engine_forced_pallas_decode_parity(self, case):
         """Full serving stack with attn_impl=pallas matches the dense
-        forward (the greedy-parity bar from test_inference.py)."""
+        forward (the greedy-parity bar from test_inference.py; behind a
+        quantized cache, the engine on the XLA formulation).  The engine
+        allocates the kernel's pool with a slab of whole memory tiles
+        (``KVCacheConfig.tiled``): the layer's queries, keys and values
+        are filled up to it and the output cut back."""
         import deepspeed_tpu  # noqa: F401  (registers presets)
         from tests.test_inference import make_fp32_engine, tiny_model
         from deepspeed_tpu.models import apply
 
-        m = tiny_model()
-        eng = make_fp32_engine(m, attn_impl="pallas")
+        over, opts, slab = self.CASES[case]
+        m = tiny_model(**over)
+        eng = make_fp32_engine(m, attn_impl="pallas", **opts)
+        data, *scales = jax.tree.leaves(eng.state.kv)
+        assert data.shape[-2:] == slab
+        assert [a.shape[2:] for a in scales] == [
+            (slab[0], 2 * data.shape[2])] * len(scales)
         prompt = list(np.random.RandomState(3).randint(1, 128, 12))
         out = eng.generate({7: prompt}, SamplingParams_greedy())[7]
+        if opts:
+            plain = make_fp32_engine(m, attn_impl="xla", **opts)
+            assert jax.tree.leaves(plain.state.kv)[0].shape[-2:] == (
+                m.config.num_kv_heads, m.config.head_dim)
+            assert out == plain.generate({7: prompt},
+                                         SamplingParams_greedy())[7]
+            return
         # dense reference: greedy continuation with full attention
         ids = list(prompt)
         for _ in range(len(out)):
@@ -36,6 +67,32 @@ class TestEngineOnTheKernel:
                            jnp.asarray([ids], jnp.int32))
             ids.append(int(jnp.argmax(logits[0, -1])))
         assert out == ids[len(prompt):]
+
+    @pytest.mark.parametrize("heads,dim,quant,groups,slab", [
+        (12, 64, "none", 1, (16, 128)),     # gpt2
+        (8, 128, "none", 1, (8, 128)),      # whole tiles as they are
+        (4, 128, "none", 1, (4, 128)),
+        (1, 128, "none", 1, (2, 128)),      # a bf16 sublane holds two
+        (8, 128, "none", 8, (16, 128)),     # one kv head a chip
+        (8, 128, "int8", 4, (16, 128)),     # two a chip, four a sublane
+        (12, 64, "int8", 1, (16, 128)),
+    ])
+    def test_a_pool_for_the_kernel_fills_its_slab(self, heads, dim, quant,
+                                                  groups, slab):
+        """``KVCacheConfig(tiled=True)``: every chip's share of a block's
+        ``(Hkv, D)`` slab filled up to Mosaic's memory tiles, the scales
+        ``[heads, 2 * bs]`` a block; as the model has them without."""
+        from deepspeed_tpu.inference.ragged.state import KVCacheConfig
+
+        kw = dict(num_layers=2, num_kv_heads=heads, head_dim=dim,
+                  num_blocks=3, quant=quant, head_groups=groups)
+        data, *scales = jax.tree.leaves(jax.eval_shape(
+            KVCacheConfig(tiled=True, **kw).kv_zeros))
+        assert data.shape == (2, 4, 64, 2) + slab
+        assert [a.shape for a in scales] == [(2, 4, slab[0], 128)] * (
+            quant != "none")
+        plain = jax.tree.leaves(jax.eval_shape(KVCacheConfig(**kw).kv_zeros))
+        assert plain[0].shape[-2:] == (heads, dim)
 
 
 class TestCarriedCache:
@@ -58,8 +115,8 @@ class TestCarriedCache:
         # a read of another layer's rows cannot pass for the right one
         if kv_quant:
             kv = (jnp.asarray(r.randint(-127, 128, shape), jnp.int8),
-                  jnp.asarray(r.uniform(0.01, 0.03, shape[:-1]),
-                              jnp.float32))
+                  jnp.asarray(r.uniform(0.01, 0.03, shape[:2] + (
+                      cfg.num_kv_heads, 2 * self.BS)), jnp.float32))
         else:
             kv = jnp.asarray(r.randn(*shape), jnp.float32)
         # seq 0 decodes at 19 (blocks 5, 2, 9); seq 1 prefills the chunk
@@ -125,9 +182,14 @@ class TestCarriedCache:
             assert new.shape == old.shape
             trash = old.shape[1] - 1
             for li in range(self.L):
+                diff = new[li] != old[li]
+                if diff.ndim == 3:
+                    # the scales [rows, Hkv, 2 * bs]: an offset is a lane
+                    # of a head's keys' half and of its values'
+                    diff = diff.reshape(len(diff), -1, self.BS).swapaxes(
+                        1, 2)
                 changed = {(int(b), int(o)) for b, o in zip(*np.nonzero(
-                    (new[li] != old[li]).reshape(
-                        old.shape[1], old.shape[2], -1).any(-1)))}
+                    diff.reshape(len(diff), self.BS, -1).any(-1)))}
                 # layer li took its tokens in its own rows, its padding
                 # in its own trash row, and nothing anywhere else
                 assert changed - {(trash, 0)} == written, li
@@ -168,10 +230,10 @@ class TestTilesInAStep:
         own = jax.tree.map(lambda a: a[li], kv)
         q = jnp.asarray(np.random.RandomState(12).randn(T, 8, D_T),
                         jnp.float32)
-        scale = 1.0 / np.sqrt(D_T)
-        stacked = _paged_attention_pallas(flat, q, batch, BS_T, 32, scale,
-                                          layer=(li * rows, rows))
-        alone = _paged_attention_pallas(own, q, batch, BS_T, 32, scale)
+        scale = float(1.0 / np.sqrt(D_T))
+        stacked = _on_kernel(flat, q, batch, 32, scale,
+                             layer=(li * rows, rows))
+        alone = _on_kernel(own, q, batch, 32, scale)
         np.testing.assert_array_equal(np.asarray(stacked),
                                       np.asarray(alone))
         _check_tiles(flat, batch, 8, nb=32, layer=(li * rows, rows),
@@ -180,32 +242,35 @@ class TestTilesInAStep:
     @pytest.mark.parametrize("window", [None, 20])
     @pytest.mark.parametrize("name", ["group-edges", "verify-across-groups",
                                       "two-chunks", "decode-only"])
-    def test_tables_laid_out_once_a_step(self, name, window):
-        """``group_tiles`` lays a kind's tables out by its calls' grid
-        steps outside the layers (``ragged_forward`` does, once a step):
-        the rows a call would make itself, and the same output."""
-        from deepspeed_tpu.inference.model import _group_tiles, _query_tiles
-        from deepspeed_tpu.ops.paged_attention import (SHORT, _group_rows,
-                                                        kv_group,
-                                                        paged_attention)
+    def test_one_cut_serves_both_kinds_of_layer(self, name, window):
+        """The step cuts its tiles once (``ragged_forward`` does, outside
+        the layers) and a layer of either kind walks them as they are:
+        the kernel finds a window tile's first block itself, and fetches
+        each block from the stack's row where it lies.  Every other
+        layer's rows, and every row of this layer's that no query needs,
+        hold NaN."""
+        from deepspeed_tpu.inference.model import _query_tiles
+        from deepspeed_tpu.ops.paged_attention import paged_attention
 
         runs, T = TILE_BATCHES[name]
         batch, _ = _built_batch(runs, T)
-        kv = _random_pool(12)
-        H = HKV_T * 4
-        q = jnp.asarray(np.random.RandomState(5).randn(T, H, D_T),
+        kv = _random_pool(12, layers=3)
+        rows = NBLK_T + 1
+        flat = kv.reshape((-1,) + kv.shape[2:])
+        q = jnp.asarray(np.random.RandomState(5).randn(T, HKV_T * 4, D_T),
                         jnp.float32)
-        tiles = _query_tiles(kv, batch, BS_T, 32, window)
-        laid = _group_tiles(tiles, kv, H, window)
-        k = kv_group(SHORT, 4, HKV_T, D_T, BS_T, jnp.float32, 32)
-        assert k > 1 and tiles.short.rows is None
-        np.testing.assert_array_equal(
-            np.asarray(laid.short.rows),
-            np.asarray(_group_rows(tiles.short, k, BS_T, window)))
-        scale = 1.0 / np.sqrt(D_T)
-        np.testing.assert_array_equal(
-            np.asarray(paged_attention(kv, q, laid, scale, window=window)),
-            np.asarray(paged_attention(kv, q, tiles, scale, window=window)))
+        scale = float(1.0 / np.sqrt(D_T))
+        tiles = _query_tiles(flat, batch, BS_T, 32)
+        layer = (2 * rows, rows)
+        got = jax.jit(paged_attention, static_argnums=(3,),
+                      static_argnames=("layer", "window"))(
+            _poisoned(flat, batch, window, layer), q, tiles, scale,
+            layer=layer, window=window)
+        want = _on_xla(kv[2], q, batch, 32, scale, window=window)
+        valid = np.asarray(batch.token_valid)
+        np.testing.assert_allclose(np.asarray(got)[valid],
+                                   np.asarray(want)[valid], atol=1e-5,
+                                   rtol=1e-5)
 
 
 class TestTileCounter:
@@ -259,8 +324,8 @@ class TestTileCounter:
     def test_group_steps_of_the_short_call(self):
         """``kv_steps_full`` on the stage span, the counter
         ``serving_attn_kv_group_steps_total`` and the gauge
-        ``serving_attn_kv_group_fill``: the grid steps the decode
-        tokens' call makes in a layer that hold a needed block, with
+        ``serving_attn_kv_group_fill``: the groups the decode
+        tokens' call walks in a layer, with
         the group the kernel's own rule gives the engine's shapes."""
         import deepspeed_tpu  # noqa: F401
         from tests.test_inference import make_fp32_engine, tiny_model
@@ -273,9 +338,10 @@ class TestTileCounter:
                                num_kv_blocks=96, trace=True)
         k = kv_group(SHORT, 2, 2, 16, 8, jnp.float32,
                      eng.max_blocks_per_seq)
-        assert k == 8
+        assert k == 16
         sp = SamplingParams(temperature=0.0, max_new_tokens=1 << 30)
-        eng.put(1, list(range(1, 101)))     # a chunk: no short tile
+        # a chunk: no short tile
+        eng.put(1, [i % 100 + 1 for i in range(180)])
         eng.put(2, [5, 6, 7])               # a run of three: one block
         first = eng.step(sampling=sp)
         span = self._stage_spans(eng)[-1]["args"]
@@ -285,14 +351,14 @@ class TestTileCounter:
         for uid, tok in first.items():
             eng.put(uid, [tok])
         eng.step(sampling=sp)
-        # 101 tokens are 13 blocks, two grid steps of eight; 4 are one
+        # 181 tokens are 23 blocks, two groups of sixteen; 4 are one
         span = self._stage_spans(eng)[-1]["args"]
         assert span["kv_steps_full"] == 2 + 1
         snap = eng.metrics_snapshot()
         assert snap["serving_attn_kv_group_steps_total"] == {
             '{kind="full"}': 4}
         assert snap["serving_attn_kv_group_fill"] == pytest.approx(
-            (1 + 13 + 1) / (4 * k))
+            (1 + 23 + 1) / (4 * k))
 
     def test_xla_formulation_counts_no_tiles(self):
         import deepspeed_tpu  # noqa: F401

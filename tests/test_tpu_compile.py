@@ -32,6 +32,7 @@ from deepspeed_tpu.ops.paged_attention import (GROUP_SCORE_BYTES,
                                                block_vmem_bytes,
                                                group_vmem_bytes, kv_group,
                                                paged_attention, query_tiles)
+from deepspeed_tpu.inference.ragged.state import KVCacheConfig
 from deepspeed_tpu.ops.quant import QuantizedTensor
 
 LLAMA = PRESETS["llama3-8b"]
@@ -102,17 +103,21 @@ PAGED = {
 
 
 def _paged_calls(jaxpr):
-    """(equation, is it inside a scan, height, KV blocks a grid step of
-    it holds) for the calls of ``ops/paged_attention.py`` in ``jaxpr``:
-    a step's group is the pool handed to the call once a block."""
+    """(equation, is it inside a scan, height, KV blocks a group of it
+    holds) for the calls of ``ops/paged_attention.py`` in ``jaxpr``: the
+    kernel fetches a group into one of two VMEM buffers ``[2, k, bs, 2,
+    Hkv, D]``, its only scratch of that rank."""
     for e, scanned in _eqns(jaxpr):
         if e.primitive.name != "pallas_call" or not e.params[
                 "name"].startswith("paged_attention"):
             continue
-        blocks = [bm for bm in e.params["grid_mapping"].block_mappings
-                  if len(bm.block_shape) == 5]
+        gm = e.params["grid_mapping"]
+        (buf,) = [v.aval for v in
+                  e.params["jaxpr"].invars[-gm.num_scratch_operands:]
+                  if len(getattr(v.aval, "shape", ())) == 6]
+        assert buf.shape[0] == 2
         yield (e, scanned, int(e.params["name"].rpartition("_h")[2]),
-               len(blocks))
+               buf.shape[1])
 
 
 def _tiled_bytes(aval) -> int:
@@ -132,29 +137,36 @@ def _tiled_bytes(aval) -> int:
 @pytest.mark.parametrize("case", sorted(PAGED))
 def test_paged_attention_compiles(one_chip, on_chip, case):
     c = PAGED[case]
-    T, H, Hkv, D, bs = c["T"], c["H"], c["Hkv"], c["D"], 64
+    T, bs = c["T"], 64
     S = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
-    kv_shape = (c["blocks"] + 1, bs, 2, Hkv, D)
-    kv = S(kv_shape, jnp.int8 if c["kv_quant"] else jnp.bfloat16)
-    if c["kv_quant"]:
-        kv = (kv, S(kv_shape[:-1], jnp.float32))
+    # the pool as the engine allocates it for the kernel (a block's slab
+    # filled up to whole memory tiles: gpt2's 12 x 64 as 16 x 128), the
+    # queries with its heads and lanes
+    cache = KVCacheConfig(1, c["Hkv"], c["D"], block_size=bs,
+                          num_blocks=c["blocks"],
+                          quant="int8" if c["kv_quant"] else "none",
+                          tiled=True)
+    Hkv, D = cache.slab
+    H = c["H"] // c["Hkv"] * Hkv
+    kv = jax.tree.map(lambda a: S(a.shape[1:], a.dtype),
+                      jax.eval_shape(cache.kv_zeros))
     q = S((T, H, D), jnp.bfloat16)
     idx = S((T,), jnp.int32)
     tables = S((8, c["nb"]), jnp.int32)
 
     def fn(kv, q, slot, pos, valid, tables):
         tiles = query_tiles(slot, pos, valid, tables, bs, c["nb"],
-                            trash=c["blocks"], window=c.get("window"))
-        return paged_attention(kv, q, tiles, D ** -0.5,
+                            trash=c["blocks"])
+        return paged_attention(kv, q, tiles, c["D"] ** -0.5,
                                window=c.get("window"))
 
     # the one kernel body at its two heights
     shapes = (kv, q, idx, idx, S((T,), jnp.bool_), tables)
     assert _compile(fn, *shapes) == 2
-    # each call's grid step holds the group the rule gives its shape,
-    # and what the short call keeps in VMEM (the group's double buffers
-    # and score tile, its scratch) is inside the rule's budget and well
-    # inside what Mosaic scopes by default
+    # each call's group is what the rule gives its shape, and what the
+    # short call keeps in VMEM (the group's two buffers, as Mosaic tiles
+    # them, the rest of its scratch and the score tile) is inside the
+    # rule's budget and well inside what Mosaic scopes by default
     calls = {h: (e, k) for e, _, h, k in _paged_calls(
         jax.make_jaxpr(fn)(*shapes).jaxpr)}
     assert sorted(calls) == [SHORT, LONG]
@@ -162,6 +174,11 @@ def test_paged_attention_compiles(one_chip, on_chip, case):
     for height, (e, k) in calls.items():
         assert k == kv_group(height, H // Hkv, Hkv, D, bs, kv_dtype,
                              c["nb"], c["kv_quant"])
+        # one launch row a tile; the pool (and its scales) stay in HBM
+        gm = e.params["grid_mapping"]
+        assert gm.num_dynamic_grid_bounds == 1
+        assert [len(bm.block_shape) for bm in gm.block_mappings] == [
+            3] + [5, 3][:1 + c["kv_quant"]] + [3, 3]
     e, k = calls[SHORT]
     assert k > 1
     rows = SHORT * H // Hkv
@@ -170,9 +187,11 @@ def test_paged_attention_compiles(one_chip, on_chip, case):
     assert 2 * k * block_vmem_bytes(Hkv, D, bs, kv_dtype,
                                     c["kv_quant"]) < held <= GROUP_VMEM_BYTES
     gm = e.params["grid_mapping"]
-    scratch = sum(_tiled_bytes(v.aval) for v in
-                  e.params["jaxpr"].invars[-gm.num_scratch_operands:])
-    assert held + scratch <= 16 * 1024 * 1024
+    scratch = [v.aval for v in
+               e.params["jaxpr"].invars[-gm.num_scratch_operands:]]
+    # (``held`` counts the two buffers, the scratch of rank 5 and 6)
+    assert held + sum(_tiled_bytes(a) for a in scratch
+                      if len(getattr(a, "shape", ())) < 5) <= 16 * 2 ** 20
 
 
 # ---------------------------------------------------------------- flash
@@ -285,17 +304,19 @@ def _pstep_compiled(one_chip, cfg, kv_quant, T, seqs, bs, mbs, blocks):
         lambda a: S(a.shape, jnp.bfloat16),
         jax.eval_shape(lambda k: fold_projections(init_params(cfg, k)[0]),
                        jax.random.PRNGKey(0)))
-    pool = (cfg.num_layers, blocks + 1, bs, 2, cfg.num_kv_heads,
-            cfg.head_dim)
-    if cfg.mixer_stacks:
-        # a layer holds ONE kind of cache: the latent layers' rows
-        from deepspeed_tpu.inference.ragged.state import KVCacheConfig
-        pool = (cfg.layers_of("mla"), blocks + 1, bs, KVCacheConfig(
-            1, 1, 1, latent_dim=cfg.mla_dims.row).latent_row)
-    kv = S(pool, jnp.int8 if kv_quant else jnp.bfloat16)
-    layer_bytes = kv.size * kv.dtype.itemsize // pool[0]
-    if kv_quant:
-        kv = (kv, S(pool[:-1], jnp.float32))
+    # the pool as the engine allocates it where it runs the kernel; a
+    # layer holds ONE kind of cache: a latent model's is its latent rows
+    latent = "mla" in cfg.mixer_stacks
+    kv = jax.tree.map(
+        lambda a: S(a.shape, a.dtype),
+        jax.eval_shape(KVCacheConfig(
+            cfg.layers_of("mla") if latent else cfg.num_layers,
+            cfg.num_kv_heads, cfg.head_dim, block_size=bs,
+            num_blocks=blocks, quant="int8" if kv_quant else "none",
+            latent_dim=cfg.mla_dims.row if latent else 0,
+            tiled=not latent).kv_zeros))
+    data = jax.tree.leaves(kv)[0]
+    layer_bytes = data.size * data.dtype.itemsize // data.shape[0]
     rec = None
     if cfg.has_ssm:
         # a model with recurrent layers: the state rows beside the pool
@@ -357,37 +378,31 @@ def _eqns(jaxpr, inside_scan=False):
             yield from _eqns(sub, inside_scan or e.primitive.name == "scan")
 
 
-def _tile_grid_conditions(jaxpr, T, seqs, mbs, short_group):
+def _tile_grid_conditions(jaxpr, T, seqs, mbs, groups):
     """The tile grid of ``ops/paged_attention.py`` in the step's jaxpr:
     two calls a layer (one kernel body, two heights), each over a traced
-    count of tiles whose static bound is at most ``T/128 + seqs`` rows of
-    ``ceil(mbs / group)`` groups of KV blocks, and the block-table rows
-    those tiles carry gathered once a step, outside the layer scan."""
+    count of tiles whose static bound is at most ``T/128 + seqs`` launch
+    rows (a tile walks its own groups of KV blocks inside its row), and
+    the block-table rows those tiles carry gathered once a step, outside
+    the layer scan, and handed to the calls as they are.  ``groups``:
+    the KV blocks a group of the short and of the long call holds."""
     calls = list(_paged_calls(jaxpr))
     assert len(calls) == 2 and all(scanned for _, scanned, _, _ in calls)
     assert sorted(h for _, _, h, _ in calls) == [SHORT, LONG]
     for e, _, height, group in calls:
         gm = e.params["grid_mapping"]
-        assert gm.num_dynamic_grid_bounds == 2      # (tiles, groups)
+        assert gm.num_dynamic_grid_bounds == 1      # (tiles,)
         tables = e.invars[gm.num_dynamic_grid_bounds].aval
         assert tables.shape[1] == mbs
-        # a tile's grid row is as long as the deepest tile's groups of
-        # KV blocks: the decode tokens' call walks that much fewer steps
-        assert group == (short_group if height == SHORT else group)
-        assert tables.shape[0] * -(-mbs // group) <= (
-            T // 128 + seqs) * -(-mbs // group)
-    # (the tables laid out by the calls' grid steps, ``_group_rows``,
-    # among them)
+        assert group == groups[height != SHORT]
+        assert tables.shape[0] <= T // 128 + seqs
     table_gathers = [scanned for e, scanned in _eqns(jaxpr)
                      if e.primitive.name == "gather"
                      and e.outvars[0].aval.ndim == 2
                      and e.outvars[0].aval.shape[1] >= mbs
                      and e.outvars[0].aval.dtype == jnp.int32]
-    assert table_gathers and not any(table_gathers)
-    laid_out = [scanned for e, scanned in _eqns(jaxpr)
-                if e.primitive.name == "jit"
-                and e.params["name"] == "_group_rows"]
-    assert laid_out and not any(laid_out)
+    # one gather a height: nothing lays the tables out a second time
+    assert len(table_gathers) == 2 and not any(table_gathers)
 
 
 def test_recurrent_serving_step_compiles_fits_and_keeps_its_pools_in_place(
@@ -525,9 +540,9 @@ def test_moe_serving_step_compiles_and_fits(one_chip, on_chip):
     text = compiled.as_text()
     # the paged kernel at its two heights, the grouped kernel's three
     assert text.count("tpu_custom_call") == 5
-    # 16 kv heads: a block is 512 KB and four fill the group's budget
+    # 16 kv heads: a block is 512 KB and eight fill the group's budget
     _tile_grid_conditions(compiled.jaxpr(), T=512, seqs=64, mbs=16,
-                          short_group=4)
+                          groups=(8, 8))
     leaf_bytes = cfg.num_experts * cfg.d_model * cfg.d_ff * 2
     assert _moves_of(text, 1), "the reader no longer finds any copy"
     assert _moves_of(text, leaf_bytes) == []
@@ -583,33 +598,48 @@ def test_window_serving_step_compiles_fits_and_names_its_kernels(
     assert mem.temp_size_in_bytes < 256e6
 
 
-@pytest.mark.parametrize("kv_quant", [False, True],
-                         ids=["mistral-7b-d16-bf16", "mistral-7b-d16-int8kv"])
-def test_serving_step_keeps_the_pool_in_place(one_chip, on_chip, kv_quant):
+@pytest.mark.parametrize("model,kv_quant", [
+    ("mistral-7b", False), ("mistral-7b", True), ("gpt2", False)],
+    ids=["mistral-7b-d16-bf16", "mistral-7b-d16-int8kv", "gpt2-bf16"])
+def test_serving_step_keeps_the_pool_in_place(one_chip, on_chip, model,
+                                              kv_quant):
     """The layer scan carries the paged cache and the kernels address
     ``(layer, block)`` in it: the compiled step holds no temporary of a
     layer's share of the pool (it held the whole pool, 4.0 GiB, while
     the cache was a scanned input and output), and neither its entry
     computation nor its ``while`` body copies, slices or update-slices
-    that much."""
+    that much.  So for an int8 cache's scales, a whole memory tile a
+    block where they lie (their relayout was 76.8 MB a layer a step
+    while the heads were their innermost axis), and for gpt2's pool,
+    whose 12 x 64 slab is allocated as 16 x 128 (as 12 x 64 the TPU's
+    compiler keeps the block axis innermost and every kernel call took
+    a copy of the whole stack relaid)."""
     from deepspeed_tpu.models.presets import build_config
 
     compiled, layer_bytes = _pstep_compiled(
-        one_chip, build_config("mistral-7b", num_layers=16), kv_quant,
+        one_chip, build_config(model, num_layers=16 if model != "gpt2"
+                               else 12), kv_quant,
         T=512, seqs=64, bs=64, mbs=16, blocks=1024)
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 2
     assert _moves_of(text, 1), "the reader no longer finds any copy"
     assert _moves_of(text, layer_bytes) == []
+    if kv_quant:
+        # nor a layer's share of the scales (8 x 128 a block) relaid,
+        # cut or padded.  (XLA's memory-space assignment may still take
+        # a stack of scales small enough for VMEM there and back around
+        # the write's scatter: a pair of ``copy-start``/``copy-done``.)
+        assert [m for m in _moves_of(text, 1025 * 8 * 128 * 4)
+                if m.endswith(",8,128]")
+                and not m.startswith(("copy-start", "copy-done"))] == []
     # the queries reach the kernel in batch order and the output leaves
     # it so: at most a relaid ``q`` and output (4 MB each at these
     # sizes), never a copy of them padded to tiles (68 tiles of 128 rows
-    # would be 71 MB); the int8 pair still pays its scales' relayout
-    # (76.8 MB a layer, as before the tile grid)
-    assert compiled.memory_analysis().temp_size_in_bytes < (
-        100e6 if kv_quant else 16e6)
+    # would be 71 MB)
+    assert compiled.memory_analysis().temp_size_in_bytes < 16e6
+    # (gpt2's sixteen heads a block: 512 KB, eight blocks a group)
     _tile_grid_conditions(compiled.jaxpr(), T=512, seqs=64, mbs=16,
-                          short_group=8)
+                          groups=(8, 8) if model == "gpt2" else (16, 16))
 
 
 @pytest.mark.parametrize("rows", [128, 512])
