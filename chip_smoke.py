@@ -1383,6 +1383,98 @@ def serve_longcat_phase(sz, seed):
 # four chips: the sharded paths and what they are compared with
 # --------------------------------------------------------------------------
 
+def serve_granite_phase(sz, seed):
+    """The cell serve-ssm-moe-rag's model (benchmarks/configs/
+    granite-4.0-h-small-d10.json, at its published widths: a Mamba-2
+    mixer alone in nine layers with a state row a sequence, one attention
+    layer without positions over the block pool, 36 of 72 softmax-routed
+    experts beside a shared MLP in every layer) through the engine's
+    paged path against the benchmark's plain reference that follows the
+    engine's routing, by the cell's own comparisons (benchmarks/lib/
+    drivers/serve_state_share.py) and under the file's own limits: the
+    sample, a long prompt over several steps, the sample again in the
+    slot the others left.  Then the same logits against every wrong
+    forward the reference knows: each has to FAIL a limit the true
+    forward passes."""
+    import jax.numpy as jnp
+
+    from benchmarks.lib import common
+    from benchmarks.lib import traffic as T
+    from benchmarks.lib.drivers import serve_state_share as D
+    from benchmarks.lib.drivers.serve_hybrid_share import routing_step
+    from benchmarks.lib.weights import make_model
+    from deepspeed_tpu.inference import InferenceConfig, InferenceEngine
+
+    _, _, config, mix = common.load_cell("serve-ssm-moe-rag")
+    if sz is TINY:
+        common.apply_rehearsal(config, mix)
+    cfg = D.preset_config(config)
+    model = make_model(cfg, seed + 7, dtype=jnp.bfloat16)
+    ref = common.load_module(
+        os.path.join(common.ROOT, config["reference"]["file"]),
+        "granite_ref")
+    tol = config["reference"]["tolerance"]
+    limit, short_limit = tol["followed_rel"], tol["routing_short"]
+    sample = config["reference"]["sample"]
+    k = int(sample["decode_tokens"])
+    sd = cfg.ssm_dims
+    print(f"  {config['name']}: d{cfg.d_model}, layers {cfg.layer_kinds}, "
+          f"Mamba-2 {sd.heads}x{sd.head_dim}x{sd.state} chunk {sd.chunk}, "
+          f"attention {cfg.num_heads}/{cfg.num_kv_heads} heads of "
+          f"{cfg.head_dim} without positions, experts {cfg.experts_held} of "
+          f"{cfg.num_experts} top-{cfg.moe_top_k}, bf16, limit {limit} with "
+          f"the routing followed, {short_limit} on a taken expert's logit")
+    rng = T.rng_for(seed + 7, 9)
+    seqs = {900000 + i: rng.integers(0, cfg.vocab_size, n + k).tolist()
+            for i, n in enumerate(sample["prompt_lens"])}
+    n_prompt = {u: len(s) - k for u, s in seqs.items()}
+    sizes = mix["engine"]
+    eng = InferenceEngine(model, InferenceConfig(
+        token_budget=int(sizes["token_budget"]),
+        max_seqs=int(sizes["max_seqs"]),
+        kv_block_size=int(sizes["kv_block_size"]),
+        num_kv_blocks=int(sizes["num_kv_blocks"]),
+        max_seq_len=int(sizes["max_seq_len"]),
+        **config.get("engine_options", {})))
+    report_path(eng)
+    named, prompts, system = D.system_side(
+        eng, routing_step(eng), config, seqs, n_prompt, seed + 7)
+    budget = eng.icfg.token_budget
+    print(f"    long prompt: {prompts['chunked']} tokens in steps of "
+          f"{budget}, then {k} fed: {system['chunked'][2]} steps")
+
+    def line(got):
+        return ", ".join(f"{n} {v:.4g}" for n, v in got.items())
+
+    def failing(got):
+        return [n for n, v in got.items()
+                if v > (short_limit if n == "routing_shortfall" else limit)]
+
+    true = D.readings(ref, model.params, config, named, prompts, system,
+                      budget)
+    print("    true forward: " + line(true))
+    # every wrong forward against one sequence of each comparison; all
+    # of them are read before any is judged
+    few = [n for n in named if not n.endswith(("1", "2"))]
+    agree = []
+    # (a rehearsal proves the control flow with the first of them: a wrong
+    # forward is a program of its own a layer kind and a length)
+    for wrong in ref.WRONG[:1] if sz is TINY else ref.WRONG:
+        got = D.readings(ref, model.params, config,
+                         {n: named[n] for n in few}, prompts,
+                         {n: system[n] for n in few}, budget, wrong=wrong)
+        fails = [f for f in failing(got) if f != "routing_shortfall"]
+        print(f"    reference with {wrong}: " + line(got)
+              + (f"  (fails {fails})" if fails else "  (PASSES)"),
+              flush=True)
+        if not fails:
+            agree.append(wrong)
+    check(not failing(true), f"the engine differs from the reference "
+          f"that follows its routing: {failing(true)} of {true}")
+    check(sz is TINY or not agree, f"a reference with {agree} agrees with "
+          "the system under the limit that would have to tell it")
+
+
 def spread(name, tree, n):
     """Every sharded array of ``tree`` has shards on ``n`` distinct
     devices at 1/n of its size; returns the sharded share of the bytes."""
@@ -1569,7 +1661,8 @@ def main(argv=None) -> int:
             ("serve-falcon-h1",
              lambda: serve_falcon_h1_phase(sz, args.seed)),
             ("serve-ling", lambda: serve_ling_phase(sz, args.seed)),
-            ("serve-longcat", lambda: serve_longcat_phase(sz, args.seed)))
+            ("serve-longcat", lambda: serve_longcat_phase(sz, args.seed)),
+            ("serve-granite", lambda: serve_granite_phase(sz, args.seed)))
         if args.only and args.only not in dict(one_chip):
             ap.error(f"--only {args.only!r}: no such phase; have "
                      f"{[n for n, _ in one_chip]}")

@@ -386,8 +386,9 @@ class InferenceEngine:
         self.max_blocks_per_seq = min(-(-max_len // self.icfg.kv_block_size),
                                       self.icfg.num_kv_blocks)
         # a model whose layers are of kinds that each hold ONE cache: the
-        # pool holds its latent layers' rows (a row a token), the state
-        # rows its recurrent layers'
+        # pool holds its latent layers' rows (a row a token) or its
+        # "full" layers' keys and values, the state rows its recurrent
+        # layers'; both are sized by the layers that use them
         latent = "mla" in self.cfg.mixer_stacks
         run_cut = None
         if latent and self._recurrent is None:
@@ -405,8 +406,7 @@ class InferenceEngine:
                     "serving over a mesh: latent attention is not sharded")
             run_cut = RunCut(chunk=self.cfg.kda_chunk)
         kv_cfg = KVCacheConfig(
-            num_layers=(self.cfg.layers_of("mla") if self.cfg.mixer_stacks
-                        else self.cfg.num_layers),
+            num_layers=self.cfg.block_layers,
             num_kv_heads=self.cfg.num_kv_heads,
             head_dim=self.cfg.head_dim,
             latent_dim=self.cfg.mla_dims.row if latent else 0,
@@ -614,7 +614,10 @@ class InferenceEngine:
         return RecurrentConfig(
             heads=sd.heads, head_dim=sd.head_dim, state=sd.state,
             conv=sd.conv, channels=sd.conv_channels, chunk=sd.chunk,
-            dtype=icfg.param_dtype)
+            dtype=icfg.param_dtype,
+            # a "mamba" layer holds a state and no blocks; a "hybrid"
+            # layer both, and every layer is one
+            layers=cfg.layers_of("mamba") or None)
 
     def _setup_telemetry(self) -> None:
         """Build the metrics registry, the span tracer, and the
@@ -834,6 +837,21 @@ class InferenceEngine:
                     self._recurrent.layers or self.cfg.num_layers),
                 "bytes of recurrent state and convolution tail the live "
                 "sequences hold, all the layers that hold one")
+            # the division of memory, as allocated (what the live
+            # sequences hold of it is ``serving_state_bytes``)
+            reg.gauge_fn(
+                "serving_state_rows_bytes",
+                lambda: self.state.kv["ssm"].nbytes
+                + self.state.kv["conv"].nbytes,
+                "bytes of the state rows and convolution tails as "
+                "allocated: every slot and the trash row, the layers "
+                "that hold a state")
+            reg.gauge_fn(
+                "serving_block_pool_bytes",
+                lambda: sum(a.nbytes for a in jax.tree.leaves(
+                    self.state.kv["kv"])),
+                "bytes of the paged pool as allocated beside the state "
+                "rows: the layers that hold blocks")
         if self.state.cfg.latent_dim:
             reg.gauge_fn(
                 "serving_latent_pool_bytes",
@@ -3377,7 +3395,8 @@ class InferenceEngine:
         # block
         pallas = self.attn_impl == "pallas"
         mbs = self.max_blocks_per_seq
-        if not pallas and not self.cfg.mixer_stacks:
+        if not pallas and (not self.cfg.mixer_stacks
+                           or "full" in self.cfg.mixer_stacks):
             bs_blk = self.icfg.kv_block_size
             need = 1
             for uid, toks in sched:

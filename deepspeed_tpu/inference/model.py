@@ -422,7 +422,8 @@ def _mm(x, w, dt, contract_dims: int = 1):
     return y.reshape(*x.shape[:-1], *wshape[contract_dims:])
 
 
-# the projections of a block's ``attn`` group whose columns are heads:
+# the projections of a block's ``attn`` group (``full``: a "full" layer's
+# in a model whose mixers are stacked by kind) whose columns are heads:
 # the model keeps them ``[L, d, H, D]`` (and ``wo``, whose rows are,
 # ``[L, H, D, d]``)
 _HEAD_PROJECTIONS = ("wq", "wk", "wv", "wg")
@@ -442,7 +443,7 @@ def fold_projection(path, w, wo: bool = True):
     from ..ops.quant import QuantizedTensor
     group, name = [getattr(p, "key", None) for p in path[-2:]] \
         if len(path) >= 2 else (None, None)
-    if group != "attn" or len(w.shape) != 4:
+    if group not in ("attn", "full") or len(w.shape) != 4:
         return w
     quantized = isinstance(w, QuantizedTensor)
     L_, a, b, c = w.shape
@@ -564,11 +565,13 @@ def _ssm_runs(batch: RaggedBatch, width: int):
 
 def _ssm_mixer(cfg, mp, u, rec_state, li, batch: RaggedBatch, runs, dt,
                kernel: bool = False):
-    """A hybrid layer's Mamba-2 mixer over a step's flat rows.
+    """A "hybrid" or "mamba" layer's Mamba-2 mixer over a step's flat
+    rows.
 
     u: [T, dm], the normed input times its multiplier.  ``rec_state``:
     ``(ssm [L, S+1, H, P, N], conv [L, S+1, W, C])``, the engine's state
-    rows of every layer; layer ``li`` reads and writes its own in place.
+    rows of every layer that holds a state; ``li``: the layer's rank
+    among them, whose rows it reads and writes in place.
     A one-token run advances its slot's state by the dense update
     (``ssm_update``: with ``kernel`` the Pallas kernel over the stack,
     one read and one write a row; else XLA's over the layer cut out of
@@ -993,7 +996,7 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
     if cfg.has_ssm:
         rec = (kv["ssm"], kv["conv"])
         kv = kv["kv"]
-        runs = _ssm_runs(batch, cfg.kda_conv if cfg.mixer_stacks
+        runs = _ssm_runs(batch, cfg.kda_conv if "kda" in cfg.mixer_stacks
                          else cfg.ssm_conv)
     elif "mla" in cfg.mixer_stacks:
         runs = _latent_runs(batch)
@@ -1036,7 +1039,7 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
                 jnp.asarray(slopes, jnp.float32).reshape(1, -1, 1),
                 cfg.num_kv_heads, head_groups,
                 (_kv_parts(kv)[0].shape[-2], 1)).reshape(-1)
-    else:
+    elif cfg.position == "rope":
         cos, sin = L.rope_freqs(cfg.rotary_dim, cfg.max_seq_len, cfg.rope_theta)
 
     def layer_weights(ws):
@@ -1063,6 +1066,11 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
         experts = blocks["experts"]
         blocks = {k: v for k, v in blocks.items() if k != "experts"}
 
+    def branch(y):
+        """A residual branch's output as it joins the stream."""
+        return y if cfg.residual_scale == 1.0 \
+            else y * jnp.asarray(cfg.residual_scale, dt)
+
     def ffn(x, o, lp, li, skip=None):
         """A layer's second half: the residual, the MLP or the experts
         → (x, stats, skip).  In a shortcut-connected layer
@@ -1073,12 +1081,12 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
         kw = dict(comm=comm, valid=batch.token_valid,
                   sharded=shard_mesh is not None, routing=with_routing)
         with jax.named_scope("ffn"):
-            x = x + o
+            x = x + branch(o)
             h = norm(lp["ln2"], x)
             if not cfg.moe_shortcut:
                 d, stats = _ffn(cfg, lp, h, dt, act, experts=None
                                 if experts is None else (experts, li), **kw)
-                return x + d, stats, None
+                return x + branch(d), stats, None
             d, stats = _ffn(cfg, {"mlp": lp["mlp"]}, h, dt, act, **kw)
             if "gate" not in lp:
                 return x + d + skip, None, None
@@ -1093,26 +1101,33 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
         (``_layer_of``).  ``li``: the layer's index in ``blocks``, for
         weights kept stacked.  ``kind``: its kind, static.  ``rec``: the
         stacked state rows of a model with recurrent layers; a hybrid
-        layer returns them updated, last.  ``rank``: a "kda" layer's
-        rank among the layers that hold a state (it holds no blocks; an
-        "mla" layer holds no state, and ``layer`` says where in the
-        latent pool its blocks lie).  ``skip``: the expert output a
-        shortcut-connected layer carries to its end (``ffn``), which a
-        "kda" or "mla" layer returns behind ``rec``."""
-        if kind in ("kda", "mla"):
+        layer returns them updated, last.  ``rank``: a "kda" or "mamba"
+        layer's rank among the layers that hold a state (it holds no
+        blocks; an "mla" layer, and a "full" layer beside such kinds,
+        holds no state, and ``layer`` says where in the pool its blocks
+        lie).  ``skip``: the expert output a shortcut-connected layer
+        carries to its end (``ffn``), which a layer of a model whose
+        mixers are stacked by kind returns behind ``rec``."""
+        if kind in ("kda", "mla", "mamba"):
             h = norm(lp["ln1"], x)
-            with jax.named_scope("attn"):
-                if kind == "kda":
-                    o, rec = _kda_mixer(cfg, lp["kda"], h, rec, rank, batch,
-                                        runs, dt, kernel=state_kernel)
-                else:
-                    o, pool = _latent_attention(
-                        cfg, lp["mla"], h, pool, layer, batch, runs, cos,
-                        sin, dt, block_size, max_blocks_per_seq,
-                        tiles=tiles.get("mla"))
+            if kind == "mamba":
+                with jax.named_scope("ssm"):
+                    o, rec = _ssm_mixer(cfg, lp["mamba"], h, rec, rank,
+                                        batch, runs, dt, kernel=state_kernel)
+            else:
+                with jax.named_scope("attn"):
+                    if kind == "kda":
+                        o, rec = _kda_mixer(cfg, lp["kda"], h, rec, rank,
+                                            batch, runs, dt,
+                                            kernel=state_kernel)
+                    else:
+                        o, pool = _latent_attention(
+                            cfg, lp["mla"], h, pool, layer, batch, runs,
+                            cos, sin, dt, block_size, max_blocks_per_seq,
+                            tiles=tiles.get("mla"))
             x, stats, skip = ffn(x, o, lp, li, skip)
             return x, pool, stats, rec, skip
-        ap = lp["attn"]
+        ap = lp["full" if cfg.mixer_stacks else "attn"]
         window = cfg.attn_window if kind == "window" else None
         # named scopes at the block's seams (metadata only): a device
         # trace's operations carry them in their JAX path, which is how
@@ -1162,6 +1177,10 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
                 o = norm(lp["ln1_post"], o)
             if cfg.attn_out_scale != 1.0:
                 o = o * jnp.asarray(cfg.attn_out_scale, dt)
+        if cfg.mixer_stacks:
+            # a "full" layer beside kinds that hold no attention
+            x, stats, skip = ffn(x, o, lp, li, skip)
+            return x, pool, stats, rec, skip
         if kind == "hybrid":
             # the mixer reads the same normed input as the attention
             with jax.named_scope("ssm"):
@@ -1169,6 +1188,7 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
                     cfg, lp["ssm"], h * jnp.asarray(cfg.ssm_in_scale, dt),
                     rec, li, batch, runs, dt, kernel=state_kernel)
                 o = o + m * jnp.asarray(cfg.ssm_out_scale, dt)
+        o = branch(o)
         with jax.named_scope("ffn"):
             if not cfg.parallel_block:
                 x = x + o
@@ -1183,6 +1203,7 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
                             else (experts, li), routing=with_routing)
             if cfg.sandwich_norm:
                 d = norm(lp["ln2_post"], d)
+            d = branch(d)
         if rec is not None:
             return x + d, pool, stats, rec
         if cfg.parallel_block:
@@ -1198,7 +1219,10 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
         # walks tiles of its own heights; a "kda" layer reads no block
         tiles = {"mla": _latent_tiles(cfg, kv, batch, block_size,
                                       max_blocks_per_seq)}
-    elif attn_impl == "pallas" and not cfg.mixer_stacks:
+    elif attn_impl == "pallas" and (not cfg.mixer_stacks
+                                    or "full" in cfg.mixer_stacks):
+        # (beside "mamba" layers, which read no block, the "full"
+        # layers' calls are the paged kernel's)
         tiles = dict.fromkeys(cfg.layer_kinds, _query_tiles(
             kv, batch, block_size, max_blocks_per_seq))
     rows = _kv_parts(kv)[0].shape[1]       # a layer's blocks + trash row
@@ -1295,15 +1319,31 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
     return out
 
 
+# A period of a model whose mixers are stacked by kind is a scan's body,
+# and every layer in it is traced, lowered and compiled on its own.  Up to
+# this many layers a period that is how it stays (Ling's six, LongCat's
+# two: the programs they compiled to); in a longer period the layers of
+# one kind in a row run as a rolled loop over ONE traced layer.  The ten
+# layers of granite-4.0-h's period (Mamba-2 x5, attention, Mamba-2 x4)
+# took the chip's compiler 66-74 s a step program where the
+# configuration's set-up has four of them to build and the machine's
+# compile cache room for none; as three bodies they take 20 and run 1.6%
+# faster (PERF.md section 6, PR 52).
+UNROLLED_PERIOD = 8
+
+
 def _stacked_layers(cfg, params, blocks, block, x, pool, rec, rows: int):
     """The layers of a model whose mixers are stacked by kind
     (``TransformerConfig.mixer_stacks``), as ``layer_plan`` says: the
     leading dense layers, one scan over the whole periods, a last period
     cut short.  A layer reads its kind's stack at its rank among the
-    layers of that kind, and its cache likewise: a "kda" layer its state
-    rows, an "mla" layer its blocks of the latent pool (``rows`` a
-    layer).  ``block``: ``ragged_forward``'s.  → (x, pool, [rec], the
-    scan's stats [periods, P, ...], the tail's)."""
+    layers of that kind, and its cache likewise: a "kda" or "mamba"
+    layer its state rows, an "mla" or "full" layer its blocks of the pool
+    (``rows`` a layer).  Inside a period of more than ``UNROLLED_PERIOD``
+    layers, the layers of one kind in a row run as a rolled loop of their
+    own (``run_of``).
+    ``block``: ``ragged_forward``'s.  → (x, pool, [rec], the scan's
+    stats [periods, P, ...], the tail's)."""
     stacks = cfg.mixer_stacks
     pattern = cfg.layer_pattern
     P = len(pattern)
@@ -1314,6 +1354,14 @@ def _stacked_layers(cfg, params, blocks, block, x, pool, rec, rows: int):
     per_period.update({k: 1 for k in firsts})
     in_pattern = [pattern[:j].count(kind) for j, kind in enumerate(pattern)]
     before = {k: cfg.kind_rank(lead, k) for k in stacks}
+    # the period's runs of one kind: (kind, first layer, layers)
+    runs = []
+    for j, kind in enumerate(pattern):
+        if runs and runs[-1][0] == kind:
+            runs[-1][2] += 1
+        else:
+            runs.append([kind, j, 1])
+    rolled = P > UNROLLED_PERIOD and not cfg.moe_shortcut
 
     def one(x, pool, rec, lp, li, kind, rank, skip=None):
         return block(x, lp, pool, (rank * rows, rows), li, kind, rec,
@@ -1364,6 +1412,50 @@ def _stacked_layers(cfg, params, blocks, block, x, pool, rec, rows: int):
         return (x, pool, rec), (jax.tree.map(lambda *v: jnp.stack(v), *stats)
                                 if stats else None)
 
+    def run_of(carry, period_w, period, kind, j0, n):
+        """Layers ``[j0, j0 + n)`` of a period, all of ``kind``, as ONE
+        traced layer in a rolled loop: each trip reads its layer's
+        weights where they lie in the period's rows, at a row the trip
+        counts, as the layer scan of a model of one block type does.
+        → (carry, the layers' stats [n, ...])."""
+        def trip(carry, t):
+            x, pool, rec = carry
+            lp = {name: jax.tree.map(
+                lambda a, row=(in_pattern[j0] if name in stacks else j0) + t:
+                jax.lax.dynamic_index_in_dim(a, row, keepdims=False), sub)
+                for name, sub in period_w.items()
+                if name not in stacks or name == kind}
+            x, pool, st, rec, _ = one(
+                x, pool, rec, lp, period * P + j0 + t, kind,
+                before[kind] + period * per_period[kind] + in_pattern[j0]
+                + t)
+            return (x, pool, rec), st
+
+        return jax.lax.scan(trip, carry, jnp.arange(n, dtype=jnp.int32))
+
+    def carried_runs(carry, ws):
+        """``carried`` for a period long enough to roll: a run of
+        several layers a loop, a layer alone as it is."""
+        period_w, period = ws
+        stats = []
+        for kind, j0, n in runs:
+            if n > 1:
+                carry, st = run_of(carry, period_w, period, kind, j0, n)
+                stats.append(st)
+                continue
+            for j in range(j0, j0 + n):
+                x, pool, rec = carry
+                lp = {name: layer_of(name, sub, j, period)
+                      for name, sub in period_w.items()
+                      if name not in stacks or name == kind}
+                x, pool, st, rec, _ = one(
+                    x, pool, rec, lp, period * P + j, kind,
+                    before[kind] + period * per_period[kind]
+                    + in_pattern[j])
+                carry = (x, pool, rec)
+                stats.append(jax.tree.map(lambda a: a[None], st))
+        return carry, jax.tree.map(lambda *v: jnp.concatenate(v), *stats)
+
     def periods_of(name, a):
         n = per_period.get(name, P)
         return a[:periods * n].reshape((periods, n) + a.shape[1:])
@@ -1378,7 +1470,7 @@ def _stacked_layers(cfg, params, blocks, block, x, pool, rec, rows: int):
             (x, pool, rec), jnp.arange(periods, dtype=jnp.int32))
     else:
         (x, pool, rec), stats = jax.lax.scan(
-            carried, (x, pool, rec),
+            carried_runs if rolled else carried, (x, pool, rec),
             ({name: jax.tree.map(lambda a, name=name: periods_of(name, a),
                                  sub) for name, sub in blocks.items()},
              jnp.arange(periods, dtype=jnp.int32)))

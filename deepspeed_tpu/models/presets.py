@@ -424,6 +424,53 @@ PRESETS: Dict[str, dict] = {
                           moe_select_bias=True, moe_norm_topk=False,
                           moe_route_scale=6.0, moe_dispatch="ragged",
                           attention_impl="xla"),
+    # --- Granite-4.0-H (ibm-granite/granite-4.0-h-small config.json,
+    # model_type granitemoehybrid): a layer holds ONE mixer, a Mamba-2
+    # mixer (128 heads of 64, state 128, ONE group, chunk 256) in nine
+    # layers of ten and grouped-query attention with NO positions in the
+    # sixth (published layers 5, 15, 25, 35); every layer's feed-forward
+    # is 72 softmax-routed experts of width 768, ten a token (softmax
+    # over the ten chosen logits), beside an ungated shared MLP of width
+    # 1536.  One multiplier on both residual branches (0.22), on the
+    # embedding (12), the scores (1/128) and the logits (1/16); the
+    # embedding is tied.  ``d_ff`` is the file's ``intermediate_size``,
+    # an expert's width: no layer holds a dense MLP of it ------------------
+    "granite-h-tiny": dict(vocab_size=1024, num_layers=6, d_model=64,
+                           num_heads=4, num_kv_heads=2, d_ff=32,
+                           max_seq_len=512, activation="silu",
+                           gated_mlp=True, norm="rmsnorm", position="none",
+                           tie_embeddings=True, attn_bias=False,
+                           mlp_bias=False, eps=1e-5,
+                           layer_pattern=("mamba", "mamba", "full",
+                                          "mamba"),
+                           ssm_d=128, ssm_heads=8, ssm_head_dim=16,
+                           ssm_groups=1, ssm_state=16, ssm_conv=4,
+                           ssm_chunk=8,
+                           embed_scale=6.0, head_scale=0.25,
+                           attn_scale=0.125, residual_scale=0.3,
+                           num_experts=8, moe_top_k=3, moe_shared_ff=64,
+                           moe_shared_gate=False, moe_score="softmax",
+                           moe_norm_topk=True, moe_dispatch="ragged",
+                           attention_impl="xla"),
+    "granite-4.0-h-small": dict(vocab_size=100352, num_layers=40,
+                                d_model=4096, num_heads=32, num_kv_heads=8,
+                                d_ff=768, max_seq_len=131072,
+                                activation="silu", gated_mlp=True,
+                                norm="rmsnorm", position="none",
+                                tie_embeddings=True, attn_bias=False,
+                                mlp_bias=False, eps=1e-5,
+                                layer_pattern=("mamba",) * 5 + ("full",)
+                                + ("mamba",) * 4,
+                                ssm_d=8192, ssm_heads=128, ssm_head_dim=64,
+                                ssm_groups=1, ssm_state=128, ssm_conv=4,
+                                ssm_chunk=256,
+                                embed_scale=12.0, head_scale=0.0625,
+                                attn_scale=0.0078125, residual_scale=0.22,
+                                num_experts=72, moe_top_k=10,
+                                moe_shared_ff=1536, moe_shared_gate=False,
+                                moe_score="softmax", moe_norm_topk=True,
+                                moe_dispatch="ragged",
+                                attention_impl="xla"),
     # --- Megatron-GPT (gpt2 architecture, megatron-lm checkpoint naming
     # with per-head-interleaved fused QKV — reference:
     # module_inject/containers/megatron_gpt.py) ---------------------------

@@ -49,7 +49,9 @@ class TransformerConfig:
     activation: str = "gelu"
     gated_mlp: bool = False                   # SwiGLU-style (llama)
     norm: str = "layernorm"                   # layernorm | rmsnorm
-    position: str = "learned"                 # learned | rope | alibi
+    # learned | rope | alibi | none (granite-4.0-h: no positions at all,
+    # neither added to the stream nor rotated into q and k)
+    position: str = "learned"
     rope_theta: float = 10000.0
     rope_pct: float = 1.0                     # partial rotary (phi: 0.4)
     # olmoe / olmo-2: RMSNorm with a learned scale over the WHOLE q and
@@ -66,12 +68,15 @@ class TransformerConfig:
     # a Mamba-2 mixer, both reading the same normed input, summed into
     # the residual) | "kda" (delta-rule linear attention with a
     # per-channel decay, ops/kda.py, in place of attention) | "mla"
-    # (latent attention, ops/mla.py: one cached vector a token).  The
+    # (latent attention, ops/mla.py: one cached vector a token) |
+    # "mamba" (granite-4.0-h: a Mamba-2 mixer ALONE in place of
+    # attention, ops/ssm.py: a state and no blocks).  The
     # layers behind the leading dense ones repeat it; a last period may
     # be cut short.  The leading dense layers are of the period's first
-    # kind.  A model with "kda" or "mla" layers stacks each kind's mixer
-    # weights apart (``mixer_stacks``), a layer reading its kind's stack
-    # at its rank among the layers of that kind
+    # kind.  A model with "kda", "mla" or "mamba" layers stacks each
+    # kind's mixer weights apart (``mixer_stacks``; its "full" layers'
+    # attention too), a layer reading its kind's stack at its rank among
+    # the layers of that kind
     layer_pattern: Tuple[str, ...] = ("full",)
     attn_window: Optional[int] = None
     # sparse-expert models: this many FIRST layers keep a dense MLP of
@@ -109,7 +114,7 @@ class TransformerConfig:
     # the embedding's output is multiplied by this (trinity: sqrt(d_model);
     # falcon-h1: embedding_multiplier)
     embed_scale: Optional[float] = None
-    # --- a hybrid layer's Mamba-2 mixer (ops/ssm.py) ----------------------
+    # --- a "hybrid" or "mamba" layer's Mamba-2 mixer (ops/ssm.py) ---------
     ssm_d: int = 0                            # d_ssm = heads * head size
     ssm_heads: int = 0
     ssm_head_dim: int = 0
@@ -128,6 +133,9 @@ class TransformerConfig:
     mlp_out_scale: float = 1.0                # the MLP's output
     # over the columns of the mixer's input projection: z, x, B, C, dt
     ssm_col_scales: Tuple[float, ...] = (1.0, 1.0, 1.0, 1.0, 1.0)
+    # ONE multiplier on both residual branches of every layer (granite:
+    # x + r * mixer(N(x)), then x + r * ffn(N(x)))
+    residual_scale: float = 1.0
     # bloom: layernorm applied to the word embeddings before the stack
     embed_norm: bool = False
     # parallel residual: x + attn(ln(x)) + mlp(ln(x)), one shared norm
@@ -223,17 +231,22 @@ class TransformerConfig:
         assert self.num_heads % self.num_kv_heads == 0
         self.layer_pattern = tuple(self.layer_pattern)
         kinds = set(self.layer_pattern)
-        assert kinds <= {"full", "window", "hybrid", "kda", "mla"}
+        assert kinds <= {"full", "window", "hybrid", "kda", "mla", "mamba"}
+        assert self.position in ("learned", "rope", "alibi", "none")
         self.ssm_col_scales = tuple(self.ssm_col_scales)
         if "hybrid" in kinds:
             assert self.layer_pattern == ("hybrid",) \
                 and self.num_experts == 1 and not self.parallel_block
+        if kinds & {"hybrid", "mamba"}:
             assert self.ssm_d == self.ssm_heads * self.ssm_head_dim > 0
             assert self.ssm_heads % self.ssm_groups == 0 and self.ssm_state
             assert len(self.ssm_col_scales) == 5
         if self.mixer_stacks:
-            assert kinds <= {"kda", "mla"} and not self.parallel_block \
-                and not self.sandwich_norm and self.position == "rope"
+            assert kinds <= {"kda", "mla", "mamba", "full"} \
+                and not self.parallel_block and not self.sandwich_norm \
+                and self.position in ("rope", "none")
+            # one pool: no second kind among the layers that hold blocks
+            assert not {"mla", "full"} <= kinds
             if "kda" in kinds:
                 assert self.kda_heads and self.kda_key_dim \
                     and self.kda_value_dim and self.kda_chunk % 16 == 0
@@ -290,15 +303,19 @@ class TransformerConfig:
     @property
     def recurrent_kind(self) -> Optional[str]:
         """The layer kind that keeps a recurrent state, or None."""
-        return next((k for k in ("hybrid", "kda")
+        return next((k for k in ("hybrid", "kda", "mamba")
                      if k in self.layer_pattern), None)
 
     @property
     def mixer_stacks(self) -> Tuple[str, ...]:
         """The layer kinds whose mixer weights are stacked apart, by
         kind (``params["blocks"][kind]``); () for a model whose layers
-        all hold one ``"attn"`` stack."""
-        return tuple(k for k in ("kda", "mla") if k in self.layer_pattern)
+        all hold one ``"attn"`` stack.  Beside a kind that holds no
+        attention, a "full" layer's attention is such a stack too."""
+        stacks = tuple(k for k in ("kda", "mla", "mamba")
+                       if k in self.layer_pattern)
+        return stacks + (("full",) if stacks and "full" in self.layer_pattern
+                         else ())
 
     def kind_rank(self, layer: int, of: Optional[str] = None,
                   first: int = 0) -> int:
@@ -311,6 +328,15 @@ class TransformerConfig:
 
     def layers_of(self, kind: str) -> int:
         return sum(1 for k in self.layer_kinds if k == kind)
+
+    @property
+    def block_layers(self) -> int:
+        """The layers that hold blocks of the paged pool: all of them,
+        or in a model whose mixers are stacked by kind the "mla" or
+        "full" ones (a layer holds ONE kind of cache)."""
+        if not self.mixer_stacks:
+            return self.num_layers
+        return self.layers_of("mla") + self.layers_of("full")
 
     @property
     def kda_dims(self):
@@ -455,6 +481,14 @@ def init_params(cfg: TransformerConfig, key) -> Tuple[Dict, Dict]:
             p["wk"] = p["wk"] / cfg.attn_in_scale
             p["wv"] = p["wv"] / cfg.attn_in_scale
             p["wo"] = p["wo"] / cfg.attn_out_scale
+        if cfg.attn_scale is not None:
+            # a stated score multiplier is undone half in W_q and half in
+            # W_k: the scores keep the spread 1/sqrt(D) gives them
+            s = math.sqrt(cfg.attn_scale * math.sqrt(D))
+            p["wq"] = p["wq"] / s
+            p["wk"] = p["wk"] / s
+        if cfg.residual_scale != 1.0:
+            p["wo"] = p["wo"] / cfg.residual_scale
         if cfg.attn_bias:
             p["bq"] = jnp.zeros((H, D)); a["bq"] = ("heads", "head_dim")
             p["bk"] = jnp.zeros((Hkv, D)); a["bk"] = ("kv_heads", "head_dim")
@@ -528,7 +562,7 @@ def init_params(cfg: TransformerConfig, key) -> Tuple[Dict, Dict]:
              "norm": jax.random.uniform(ks[6], (sd.d_ssm,), minval=0.5,
                                         maxval=1.5),
              "w_out": jax.random.normal(ks[7], (sd.d_ssm, dm)) * out_scale
-             / cfg.ssm_out_scale}
+             / (cfg.ssm_out_scale * cfg.residual_scale)}
         a = {"w_in": ("embed", None), "conv_w": (None, None),
              "conv_b": (None,), "dt_bias": (None,), "A_log": (None,),
              "D": (None,), "norm": (None,), "w_out": (None, "embed")}
@@ -614,7 +648,8 @@ def init_params(cfg: TransformerConfig, key) -> Tuple[Dict, Dict]:
                        "wo": ("heads", "embed")})
         return p, ax
 
-    mixer_inits = {"kda": kda_init, "mla": mla_init}
+    mixer_inits = {"kda": kda_init, "mla": mla_init, "mamba": ssm_init,
+                   "full": qkv_init}
 
     def mixers_init(key, first, n):
         """The mixers of layers ``[first, first + n)``, a stack a kind
@@ -685,7 +720,8 @@ def init_params(cfg: TransformerConfig, key) -> Tuple[Dict, Dict]:
         blk_p["experts"], blk_a["experts"] = stack_init(
             lambda k: M.experts_init(k, cfg.experts_here, dm, cfg.moe_d_ff,
                                      gated=cfg.gated_mlp,
-                                     out_scale=out_scale), keys[3], n_moe)
+                                     out_scale=out_scale
+                                     / cfg.residual_scale), keys[3], n_moe)
         if cfg.moe_shared_ff:        # a dense expert every token takes
             sff = cfg.moe_shared_ff
 
@@ -693,7 +729,8 @@ def init_params(cfg: TransformerConfig, key) -> Tuple[Dict, Dict]:
                 k1, k2, k3, k4 = jax.random.split(k, 4)
                 p = {"wi": jax.random.normal(k1, (dm, sff))
                      / math.sqrt(dm),
-                     "wo": jax.random.normal(k2, (sff, dm)) * out_scale}
+                     "wo": jax.random.normal(k2, (sff, dm))
+                     * (out_scale / cfg.residual_scale)}
                 a = {"wi": ("embed", "mlp"), "wo": ("mlp", "embed")}
                 if cfg.gated_mlp:
                     p["wg"] = jax.random.normal(k3, (dm, sff)) \
@@ -895,7 +932,7 @@ def block_apply(cfg: TransformerConfig, lp, x, cos, sin,
     static.  Returns (x, metrics) — metrics non-empty for MoE."""
     norm = _norm(cfg)
     act = L.ACTIVATIONS[cfg.activation]
-    ap = lp.get("attn")
+    ap = lp.get("attn", lp.get("full"))
     if cfg.attn_scale is not None and attention_fn is L.causal_attention:
         # safety net for call sites that never resolved attention_fn
         # (pipeline stage bodies, streamed sweeps): gpt-neo's unscaled
@@ -906,6 +943,11 @@ def block_apply(cfg: TransformerConfig, lp, x, cos, sin,
     if kind in ("kda", "mla"):
         with jax.named_scope("attn"):
             o = _mixer_apply(cfg, lp, norm(lp["ln1"], x), kind, cos, sin)
+    elif kind == "mamba":
+        from ..ops.ssm import mixer_forward
+        with jax.named_scope("ssm"):
+            o = mixer_forward(lp["mamba"], norm(lp["ln1"], x), cfg.ssm_dims,
+                              cfg.ssm_col_scales, cfg.eps)
     else:
         # named scopes at the block's seams, the serving forward's names
         # (metadata only): autodiff and jax.checkpoint add the pass to an
@@ -957,6 +999,8 @@ def block_apply(cfg: TransformerConfig, lp, x, cos, sin,
                                   cfg.ssm_dims, cfg.ssm_col_scales, cfg.eps)
                 o = o + m * jnp.asarray(cfg.ssm_out_scale, dt)
 
+    if cfg.residual_scale != 1.0:
+        o = o * jnp.asarray(cfg.residual_scale, dt)
     with jax.named_scope("ffn"):
         if not cfg.parallel_block:
             x = x + o
@@ -983,6 +1027,8 @@ def block_apply(cfg: TransformerConfig, lp, x, cos, sin,
             d = _dense_mlp(cfg, lp["mlp"], h)
         if cfg.sandwich_norm:
             d = norm(lp["ln2_post"], d)
+        if cfg.residual_scale != 1.0:
+            d = d * jnp.asarray(cfg.residual_scale, dt)
         if cfg.parallel_block:
             return x + o + d, metrics
         return x + d, metrics
@@ -1079,6 +1125,8 @@ def apply(cfg: TransformerConfig, params, input_ids, mask=None,
             # attention gains the ALiBi bias (Model wraps attention_fn too)
             if attention_fn is L.causal_attention:
                 attention_fn = L.make_alibi_attention()
+        elif cfg.position == "none":
+            cos = sin = None
         else:
             cos, sin = L.rope_freqs(cfg.rotary_dim, cfg.max_seq_len,
                                     cfg.rope_theta)
