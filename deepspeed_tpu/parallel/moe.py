@@ -346,7 +346,10 @@ def moe_serve(gate_p, expert_p, h, valid=None, *, top_k: int, activation,
 
     The three projections are grouped matrix multiplications over the
     rows sorted by expert: ``ops/grouped_matmul.py`` when ``kernel`` (a
-    TPU, weights on one device), else ``jax.lax.ragged_dot``.
+    TPU, weights on one device), else ``jax.lax.ragged_dot``.  The
+    assignments are listed choice by choice, so a row's ``top_k`` expert
+    rows come back as ``[top_k, T, d]`` and their weighted sum is one
+    pass over them: float32 products, a float32 sum, one cast.
 
     ``layer``: with it ``expert_p`` holds the experts of ALL layers,
     ``[L, E, ...]`` as the model stacks them, and this call is layer
@@ -404,11 +407,15 @@ def moe_serve(gate_p, expert_p, h, valid=None, *, top_k: int, activation,
         taken = ids                         # expert E: nowhere
         if held is not None:
             ids, _ = _held_ids(ids, held)
-        flat = ids.reshape(-1)                                    # [T*K]
+        # the assignments choice-major, ``k * T + t``: the expert rows
+        # gathered back are then ``[K, T, d]`` as they lie
+        flat = ids.T.reshape(-1)                                  # [K*T]
         order = jnp.argsort(flat, stable=True)
-        group_sizes = jnp.zeros((E,), jnp.int32).at[flat].add(
-            1, mode="drop")
-        xs = h[order // top_k]                                # [T*K, d]
+        # counted by comparison (a scatter-add of K*T ones into E
+        # counters serialises on the chip); id E, nowhere, fits none
+        group_sizes = (flat[:, None] == jnp.arange(E, dtype=flat.dtype)
+                       ).sum(axis=0, dtype=jnp.int32)
+        xs = h[order % T]                                     # [K*T, d]
     with jax.named_scope("moe_experts"):
         u = mm(xs, expert_p["wi"].astype(dt), group_sizes)
         if gated:
@@ -418,12 +425,15 @@ def moe_serve(gate_p, expert_p, h, valid=None, *, top_k: int, activation,
             u = activation(u)
         out = mm(u, expert_p["wo"].astype(dt), group_sizes)
     with jax.named_scope("moe_route"):
-        back = jnp.zeros_like(order).at[order].set(
-            jnp.arange(order.shape[0], dtype=order.dtype))
-        picked = out[back].reshape(T, top_k, dm).astype(jnp.float32)
-        if held is not None:
-            picked = jnp.where((ids < E)[..., None], picked, 0.0)
-        y = (picked * vals[..., None]).sum(axis=1)
+        back = jnp.argsort(order)       # the permutation's inverse
+        picked = out[back].reshape(top_k, T, dm)
+        # one pass over the rows as the experts wrote them: float32
+        # products, a float32 sum over the K slabs ([T, K, d] in float32
+        # is never an array).  An assignment that went nowhere (not held,
+        # a padding row's) weighs nothing; its row of ``out`` is zero
+        w = jnp.where(ids < E, vals, 0.0)
+        y = sum(picked[k].astype(jnp.float32) * w[:, k, None]
+                for k in range(top_k))
         if zero:
             with jax.named_scope("moe_zero"):
                 nothing = (taken >= outputs - zero) & (taken < outputs)

@@ -210,6 +210,72 @@ def test_padding_rows_are_routed_nowhere(n_real):
                                atol=1e-6)
 
 
+def _combine_by_rows(gate, experts, h, valid, top_k, held, zero):
+    """``moe_serve`` as a float32 loop over rows and choices: (y, stats,
+    taken) from the router's own choice (``M.route``, not under test)."""
+    outputs = gate["kernel"].shape[-1]
+    first, count = held or (0, outputs - zero)
+    hf = np.asarray(h, np.float32)
+    vals, ids = M.route(jnp.dot(h, gate["kernel"].astype(h.dtype),
+                                preferred_element_type=jnp.float32),
+                        top_k=top_k, norm_topk=True)
+    vals, ids = np.asarray(vals), np.asarray(ids)
+    wi, wg, wo = (np.asarray(experts[k], np.float32)
+                  for k in ("wi", "wg", "wo"))
+    y = np.zeros_like(hf)
+    rows = np.zeros(count, np.int64)
+    nothing = 0
+    taken = np.full(ids.shape, outputs, np.int32)
+    for t in np.flatnonzero(np.asarray(valid)):
+        taken[t] = ids[t]
+        for w, e in zip(vals[t], ids[t]):
+            if e >= outputs - zero:         # computes nothing: the input
+                y[t] += w * hf[t]
+                nothing += 1
+            elif first <= e < first + count:
+                g = hf[t] @ wg[e - first]
+                y[t] += w * ((g / (1 + np.exp(-g)) * (hf[t] @ wi[e - first]))
+                             @ wo[e - first])
+                rows[e - first] += 1
+    n = int(rows.sum())
+    stats = [n, int(rows.max()) * 1000 * count // max(n, 1),
+             int((rows > 0).sum())] + ([nothing] if zero else [])
+    return y, np.asarray(stats), taken
+
+
+@pytest.mark.parametrize("zero", [0, 4])
+@pytest.mark.parametrize("share", [None, (4, 6)], ids=["whole", "share"])
+@pytest.mark.parametrize("top_k", [3, 8, 10, 12])
+def test_combine_is_a_weighted_sum_over_a_rows_own_choices(top_k, share,
+                                                           zero):
+    """The choice-major order and the sum over ``K`` slabs against a
+    loop over rows and choices, at ``top_k`` on and off the tiling's 8:
+    a share of the experts held, experts that compute nothing, padding
+    rows inside the step and at its end.  The whole layer on float32
+    rows, a share of it on bfloat16 rows (as the cells hold one)."""
+    outputs, d, T = 16, 32, 24
+    dt, eps = (jnp.float32, 1e-6) if share is None else (jnp.bfloat16,
+                                                         2.0 ** -8)
+    gate, experts = _experts(top_k, E=outputs, d=d)
+    first, count = share or (0, outputs - zero)
+    experts = jax.tree.map(lambda w: w[first:first + count], experts)
+    valid = (jnp.arange(T) < T - 4) & (jnp.arange(T) != 3)
+    h = jax.random.normal(jax.random.PRNGKey(top_k), (T, d)).astype(dt)
+    y, stats, taken = jax.jit(lambda h: M.moe_serve(
+        gate, experts, h, valid, top_k=top_k, activation=jax.nn.silu,
+        gated=True, norm_topk=True, held=share, zero=zero,
+        with_ids=True))(h)
+    want, want_stats, want_taken = _combine_by_rows(
+        gate, experts, h, valid, top_k, share, zero)
+    assert y.dtype == dt
+    y = np.asarray(y, np.float32)
+    np.testing.assert_allclose(y, want, rtol=8 * eps,
+                               atol=8 * eps * np.abs(want).max())
+    np.testing.assert_array_equal(np.asarray(stats), want_stats)
+    np.testing.assert_array_equal(np.asarray(taken), want_taken)
+    assert not y[~np.asarray(valid)].any()
+
+
 def test_served_sequence_is_bit_equal_alone_and_in_a_crowd():
     """Through ``ragged_forward``: a prompt prefilled alone, and beside
     seven prompts of one repeated token (every row of theirs asks for

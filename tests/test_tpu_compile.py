@@ -15,6 +15,7 @@ live in this one file and compile in the test's own process.
 """
 
 import functools
+import math
 import os
 import re
 
@@ -251,6 +252,38 @@ def test_grouped_matmul_compiles(one_chip, on_chip, K, N):
     S = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
     assert _compile(grouped_matmul, S((rows, K), jnp.bfloat16),
                     S((E, K, N), jnp.bfloat16), S((E,), jnp.int32)) == 1
+
+
+# granite-4.0-h-small's expert layer at the token budget: 512 rows, 36 of
+# 72 experts held, ten choices a row (and twelve, longcat-flash's count):
+# 5,120 and 6,144 expert rows of 4,096 come back to 512
+@pytest.mark.parametrize("top_k", [10, 12])
+def test_expert_combine_is_one_pass_over_the_expert_rows(one_chip, on_chip,
+                                                         top_k):
+    """``moe_serve``'s weighted sum reads the experts' bf16 rows where
+    the gather wrote them: no float32 array of ``T * K * d`` elements
+    stands in the compiled layer.  (A sum over the middle axis of
+    ``[T, K, d]`` makes one, ``f32[512,10,4096]`` with its second-minor
+    10 padded to 16 under the (8, 128) tiling: 134 MB written and read a
+    layer, PERF.md, PR 53.)"""
+    from deepspeed_tpu.parallel.moe import moe_serve
+
+    T, d, ff, outputs, held = 512, 4096, 768, 72, (0, 36)
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    gate = {"kernel": S((d, outputs), jnp.bfloat16)}
+    experts = {k: S((held[1],) + ((ff, d) if k == "wo" else (d, ff)),
+                    jnp.bfloat16) for k in ("wi", "wg", "wo")}
+    text = jax.jit(functools.partial(
+        moe_serve, top_k=top_k, activation=jax.nn.silu, gated=True,
+        norm_topk=True, kernel=True, held=held)).lower(
+            gate, experts, S((T, d), jnp.bfloat16),
+            S((T,), jnp.bool_)).compile().as_text()
+    entry = text[text.index("ENTRY "):]
+    # the reader reads: the rows gathered for the experts and back
+    assert f" = bf16[{T * top_k},{d}]" in entry
+    largest = max(math.prod(map(int, dims.split(",")))
+                  for dims in re.findall(r" = f32\[([\d,]+)\]", entry))
+    assert largest < T * top_k * d // 8
 
 
 # --------------------------------------------------------- serving step
