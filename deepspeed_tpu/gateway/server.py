@@ -174,6 +174,12 @@ def _jsonable(obj):
     return repr(obj)
 
 
+# the event loop's heartbeat (``Gateway._beat``): its period, and the
+# buckets of its lateness
+LOOP_BEAT_S = 0.02
+LOOP_LAG_BUCKETS_MS = (0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 25.0, 50.0,
+                       100.0, 250.0, 500.0, 1000.0, 2500.0)
+
 # engine-side terminal statuses -> the finish_reason the wire reports
 _STATUS_REASON = {"finished": "stop", "cancelled": "cancelled",
                   "deadline_exceeded": "deadline_exceeded",
@@ -237,6 +243,7 @@ class Gateway:
         self._launched = False       # the last pump left work in flight
         self._server: Optional[asyncio.AbstractServer] = None
         self._driver_task: Optional[asyncio.Task] = None
+        self._beat_handle: Optional[asyncio.TimerHandle] = None
         self.port: Optional[int] = None
         self.final_snapshot: Optional[Dict] = None
         self._setup_metrics()
@@ -271,6 +278,14 @@ class Gateway:
         self._g_open = reg.gauge(
             "serving_gateway_open_streams",
             "wire requests currently open")
+        # how late the loop runs a callback that was due (``_beat``): a
+        # loop that a long callback, a collection or a starved thread
+        # holds up reads here, and in the engine's slow_round record
+        self._h_lag = reg.histogram(
+            "serving_gateway_event_loop_lag_ms", LOOP_LAG_BUCKETS_MS,
+            "lateness of the event loop's heartbeat, a reading every "
+            f"{LOOP_BEAT_S * 1e3:.0f} ms while the driver runs")
+        self._note_lag = getattr(self.backend, "note_loop_lag", None)
 
     def _journey(self, uid: int, phase: str, **info) -> None:
         stamp = {"phase": phase,
@@ -531,12 +546,30 @@ class Gateway:
     # ------------------------------------------------------------------
     # the driver: pumps the engine off the event loop
     # ------------------------------------------------------------------
+    def _beat(self, loop: asyncio.AbstractEventLoop, due: float) -> None:
+        """The loop's heartbeat: a ``call_later`` that says how late it
+        ran, to the histogram and to the engine (whose round under way
+        keeps the worst, ``note_loop_lag``; it is also told when the next
+        beat is due, on ``time.monotonic``, so a beat still overdue when
+        a round is judged counts), and sets the next."""
+        now = loop.time()
+        lag_ms = max(0.0, now - due) * 1e3
+        self._h_lag.observe(lag_ms)
+        if self._note_lag is not None:
+            self._note_lag(lag_ms, now + LOOP_BEAT_S)
+        # only ever touched on the loop's own thread (start(), here, and
+        # the driver's exit)
+        self._beat_handle = loop.call_later(  # tpulint: disable=shared-state-race
+            LOOP_BEAT_S, self._beat, loop, now + LOOP_BEAT_S)
+
     async def _drive(self) -> None:
         # what the last routed step left for the engine: it rides with
         # the next pump (``_apply_then_pump``), or is applied by itself
         # where no pump follows at once
         fb: List[Tuple[int, Optional[int]]] = []
         fl: List[int] = []
+        loop = asyncio.get_running_loop()
+        self._beat(loop, loop.time())
         try:
             while not self._stop_driver:
                 # a launch the engine left in flight is read back even
@@ -591,6 +624,10 @@ class Gateway:
             logger.exception("gateway: driver crashed — failing open "
                              "streams and going dead")
             self._mark_dead()
+        finally:
+            self._beat_handle.cancel()
+            if self._note_lag is not None:
+                self._note_lag(0.0, 0.0)        # no beat is due any more
 
     def _resume_stalled(self, fb: List[Tuple[int, Optional[int]]],
                         fl: List[int]) -> None:

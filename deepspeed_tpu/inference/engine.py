@@ -553,7 +553,12 @@ class InferenceEngine:
         # --- failure-domain state (inference/failures.py) --------------
         self.fcfg = self.icfg.failure or FailureConfig()
         self.failures = FailurePolicy(self.fcfg, self.timings,
-                                      flight=self.flight)
+                                      flight=self.flight,
+                                      metrics=self.metrics,
+                                      tracer=self.tracer)
+        # the round's fixed slot: the cuts below are written into it, and
+        # a round that ran long leaves one ``slow_round`` record
+        self._round = self.failures.rounds
         self._strikes: Dict[int, int] = {}   # uid -> failing-batch count
         self._probe_groups: List[List[int]] = []  # bisection quarantine
         self._backoff_rounds = 0             # rounds admitting nothing
@@ -1281,6 +1286,8 @@ class InferenceEngine:
         self._state_rows = self._state_slots = 0
         self.requests.clear()
         self.tracer.clear()
+        # the timed region's rounds are judged against their own mean
+        self._round.reset()
         # rearm the pool high-water mark so a timed region reports ITS
         # peak, not the warmup's (the pull-gauges read live truth)
         self.state.allocator.reset_peaks()
@@ -3263,7 +3270,20 @@ class InferenceEngine:
     def _step(self, rng: Optional[jax.Array], sampling: SamplingParams
               ) -> Dict[int, List[int]]:  # tpulint: serving-loop
         """:meth:`step` with every token the call emitted, a LIST per
-        uid (several for a resolved verify window)."""
+        uid (several for a resolved verify window).  One call is one
+        ROUND of the loop: its return closes the round and judges it
+        (``failures.RoundWatch``: a round that ran long leaves one
+        ``slow_round`` record saying where it went)."""
+        try:
+            out = self._advance(rng, sampling)
+        except BaseException:
+            self._round.void = True     # a failed round is not judged
+            raise
+        self._round.end(self._ahead is not None)
+        return out
+
+    def _advance(self, rng: Optional[jax.Array], sampling: SamplingParams
+                 ) -> Dict[int, List[int]]:  # tpulint: serving-loop
         if self._held:
             # a launch read back outside step() (snapshot, a failed
             # launch behind it): its tokens are handed over first
@@ -3336,6 +3356,7 @@ class InferenceEngine:
         st, self._ahead = self._ahead, None
         if st is None:
             return
+        self._round.void = True         # read back outside a round
         if self._health == "dead":
             self._uncount_inflight(st.uids)
             return
@@ -3495,6 +3516,7 @@ class InferenceEngine:
             # classifier seam (tpulint's serving-except rule holds the
             # loop to this); the live ledger IS this step's build
             tr.phase_end(failed=type(e).__name__)
+            self._round.void = True
             registered = tuple(self.state.round_registered)
             prev, self._ahead = self._ahead, None
             if prev is not None:
@@ -3523,6 +3545,7 @@ class InferenceEngine:
         tm["stage_ms"] += (t2 - t1) * 1e3
         tm["device_ms"] += (t3 - t2) * 1e3
         tm["steps"] += 1
+        self._round.cut_dispatch(t0, t1, t2, t3, cold, guard)
         self._c_step_rows.inc(1, rung=str(n_rows))
         self._row_tokens += n_tokens
         self._row_slots += n_rows
@@ -3712,6 +3735,25 @@ class InferenceEngine:
             else:
                 self._inflight_sched.pop(uid, None)
 
+    @staticmethod
+    def _samples_ready(nxt: Optional[_InFlight]) -> Optional[bool]:
+        """Had the launch behind the one read back (``nxt``) already
+        produced its samples?  None where there is none to ask."""
+        if nxt is None or nxt.toks is None:
+            return None
+        return bool(nxt.toks.is_ready())
+
+    def note_loop_lag(self, lag_ms: float, next_due_s: float) -> None:
+        """The gateway's event loop reports how late its heartbeat ran
+        and when the next is due, on ``time.monotonic`` (0.0: none, the
+        driver has stopped) (``Gateway._beat``); the round under way
+        keeps the worst lateness.  Called on the loop's thread: two
+        floats, written bare."""
+        rw = self._round
+        rw.beat_due = next_due_s
+        if lag_ms > rw.lag_ms:
+            rw.lag_ms = lag_ms
+
     def _fetch_tokens(self, arr) -> np.ndarray:  # tpulint: serving-loop
         """THE sanctioned serving-loop readback: every device->host token
         fetch funnels through here so the ``serving-sync`` lint rule can
@@ -3744,9 +3786,9 @@ class InferenceEngine:
         ``nxt``: the step :meth:`step` launched behind this one, still
         unread.  It took this step's tokens from the device, so when
         this read fails its rows are re-queued with this step's; when
-        this read is slow, the slow-call note says whether ``nxt``'s
-        samples were ready by then (``next_ready``: the device had gone
-        on and only the completion came late)."""
+        this read makes the round a slow one, its record says whether
+        ``nxt``'s samples were ready by then (``next_ready``: the device
+        had gone on and only the completion came late)."""
         self._uncount_inflight(st.uids)
         tr = self.tracer
         guard: Dict[str, float] = {}      # the watchdog's hand-off time
@@ -3760,16 +3802,31 @@ class InferenceEngine:
             self.failures.run(
                 lambda: jax.block_until_ready(st.toks),
                 uids=st.uids, cold=st.cold, site="collect", sid=st.sid,
-                stamps=guard,
-                slow_note=None if nxt is None or nxt.toks is None else
-                lambda: {"next_ready": bool(nxt.toks.is_ready())})
+                stamps=guard)
             hop_us = guard.get("hop_us", 0.0)
-            tr.phase_set(hop_us=round(hop_us, 1))
+            rw = self._round
+            if guard:
+                # the hand-off's stamps lie on the trace's clock too
+                fn_us = guard["fn_us"]
+                tr.phase_set(hop_us=round(hop_us, 1),
+                             queued_us=round(guard["queued_us"], 1),
+                             fn_us=round(fn_us, 1),
+                             taken_us=round(guard["taken_us"], 1))
+                if hop_us + fn_us > rw.limit_us and not rw.void:
+                    # this wait alone makes the round a slow one: the
+                    # verdict is reached while its phase is open
+                    tr.phase_set(slow=rw.judge_wait(
+                        st.sid, guard, self._samples_ready(nxt)))
+            else:
+                tr.phase_set(hop_us=0.0)
             t1 = tr.phase("ds.serve.readback", track="readback",
                           sid=st.sid)
+            if not guard and (t1 - t0) * 1e6 > rw.limit_us:
+                rw.next_ready = self._samples_ready(nxt)
             toks_np = self._fetch_tokens(st.toks)
         except Exception as e:
             tr.phase_end(failed=type(e).__name__)
+            self._round.void = True
             uids, registered = st.uids, st.registered
             if nxt is not None:
                 # the launch behind this one read this step's tokens on
@@ -3825,6 +3882,7 @@ class InferenceEngine:
         tm = self.timings
         tm["wait_ms"] += (t1 - t0) * 1e3
         tm["readback_ms"] += (t2 - t1) * 1e3
+        rw.cut_collect(st.sid, t0, t1, t2, st.cold, guard)
         if self._anom is not None:
             ev = self._anom.observe("step_wait_ms", (t1 - t0) * 1e3,
                                     self._steps_done)

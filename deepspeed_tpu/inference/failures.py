@@ -53,6 +53,7 @@ import threading
 import time
 from typing import Callable, Dict, List, Optional, Tuple
 
+from ..telemetry.host import HostSampler, thread_usage, watch_gc
 from ..utils.logging import logger
 
 # classifier verdicts (docs/SERVING.md "Failure domains & recovery")
@@ -70,6 +71,12 @@ _POISON_MARKERS = ("resource_exhausted", "out of memory", "oom",
 _FATAL_MARKERS = ("aborted", "data_loss", "device halted", "terminated",
                   "unavailable", "failed to connect", "socket closed",
                   "deadline exceeded for tpu")
+
+
+# the watchdog worker reads its own usage after a call this long, and
+# otherwise this often; the engine's thread likewise (``RoundWatch``)
+USAGE_LONG_CALL_S = 0.01
+USAGE_PERIOD_S = 0.1
 
 
 class DispatchTimeoutError(RuntimeError):
@@ -231,15 +238,23 @@ class Watchdog:
 
     The hand-off is stamped, always: four ``perf_counter`` readings per
     guarded call — put on the request queue, taken by the worker,
-    ``fn()`` returned, result taken by the caller.  ``hop_us`` (queue
-    to worker plus worker back to caller; handed back through the
-    caller's ``stamps`` dict, absent for an inline call) is what the
-    thread hops cost the step.  A call that lasts over a
-    tenth of its deadline, and an abandoned worker whose call finally
-    returns, each hand ``on_note`` one record with the stamps: a
+    ``fn()`` returned, result taken by the caller.  They are handed back
+    through the caller's ``stamps`` dict (left empty by an inline call):
+    ``queued_us``, ``fn_us``, ``taken_us``, and the two hand-overs as
+    ``hop_us`` (what the thread hops cost the step).  After a call that
+    lasted over ``USAGE_LONG_CALL_S``, and otherwise once every
+    ``USAGE_PERIOD_S``, the worker also reads its own ``getrusage`` (a
+    system call, so not every call): ``cpu_us``, ``vcsw`` and ``ivcsw``
+    are then its rise since its previous reading, which is this call's
+    and the short ones' before it (the worker only sleeps on its queue
+    between calls).  The watchdog judges none of
+    them: a round that ran long is the engine's to note, once, with
+    these beside its own cuts (:class:`RoundWatch`, ``slow_round``) — a
     completion the runtime delivered late reads there as a long
-    ``fn()``, a result the hand-off lost as a short ``fn()`` whose
-    result the caller never took."""
+    ``fn()``.  An abandoned worker whose call finally returns hands
+    ``on_note`` one ``guard_late_return`` record: a result the hand-off
+    lost reads there as a short ``fn()`` whose result the caller never
+    took."""
 
     def __init__(self, on_note: Optional[Callable[..., None]] = None):
         self._req: Optional[queue.Queue] = None
@@ -248,6 +263,7 @@ class Watchdog:
         self._token = 0
         self.abandoned = 0          # workers stranded by expiries
         self.on_note = on_note      # (kind, **stamps) -> None
+        self.worker_tid = 0         # the live worker's native thread id
         # ONE guarded call at a time: the worker handshake is a single
         # (req, res) queue pair, so two concurrent run() calls would
         # interleave tokens on one queue, and a shared expiry could
@@ -272,6 +288,11 @@ class Watchdog:
             self._res = queue.Queue()
 
             def loop(req: queue.Queue, res: queue.Queue) -> None:
+                # one int, published by the worker for the slow path's
+                # schedstat read; a stale one names a dead thread's file
+                self.worker_tid = threading.get_native_id()  # tpulint: disable=shared-state-race
+                cpu0, vcsw0, ivcsw0 = thread_usage()
+                t_read = time.perf_counter()
                 while True:
                     token, fn, call = req.get()
                     if fn is None:    # poison pill: worker was abandoned
@@ -282,7 +303,15 @@ class Watchdog:
                     except BaseException as e:  # tpulint: disable=silent-except — shipped across the queue and re-raised in the caller
                         ok, val = False, e
                     t_ret = time.perf_counter()
-                    res.put((token, ok, val, t_taken, t_ret))
+                    usage = None
+                    if t_ret - t_taken > USAGE_LONG_CALL_S \
+                            or t_ret - t_read > USAGE_PERIOD_S:
+                        # a system call (6 us on the chip's host): for a
+                        # call that was long, else ten times a second
+                        cpu, vcsw, ivcsw = thread_usage()
+                        usage = (cpu - cpu0, vcsw - vcsw0, ivcsw - ivcsw0)
+                        cpu0, vcsw0, ivcsw0, t_read = cpu, vcsw, ivcsw, t_ret
+                    res.put((token, ok, val, t_taken, t_ret, usage))
                     if call.get("abandoned"):
                         # nobody will take this result: say when the
                         # stuck call did come back
@@ -302,13 +331,11 @@ class Watchdog:
 
     def run(self, fn: Callable, timeout_ms: Optional[float],
             site: Optional[str] = None, sid: Optional[int] = None,
-            stamps: Optional[Dict[str, float]] = None,
-            slow_note: Optional[Callable[[], Dict]] = None):
+            stamps: Optional[Dict[str, float]] = None):
         """Run ``fn()`` under ``timeout_ms``; inline when None.
-        ``site``/``sid`` name the call in the slow-call records;
-        ``stamps``, the caller's own dict, receives ``hop_us``;
-        ``slow_note`` is called only for a ``guard_slow_call`` record,
-        the moment the result is taken back, and adds its keys to it."""
+        ``site``/``sid`` name the call in a late-return record;
+        ``stamps``, the caller's own dict, receives the hand-off's
+        stamps and the worker's usage (the class docstring)."""
         if timeout_ms is None:
             return fn()
         with self._admit:
@@ -323,7 +350,7 @@ class Watchdog:
             while True:
                 remaining = deadline - time.perf_counter()
                 try:
-                    tok, ok, val, t_taken, t_ret = self._res.get(
+                    tok, ok, val, t_taken, t_ret, usage = self._res.get(
                         timeout=max(1e-4, remaining)
                         if remaining > 0 else 1e-4)
                 except queue.Empty:
@@ -343,21 +370,379 @@ class Watchdog:
                         "deadline") from None
                 if tok != token:    # stale result from an older call
                     continue
-                t_got = time.perf_counter()
                 if stamps is not None:
-                    stamps["hop_us"] = ((t_taken - t_put)
-                                        + (t_got - t_ret)) * 1e6
-                if (t_got - t_put) * 1e3 > timeout_ms / 10.0:
-                    self._note(
-                        "guard_slow_call", site=site, sid=sid, ok=ok,
-                        deadline_ms=timeout_ms, t_put_s=t_put,
-                        queued_ms=(t_taken - t_put) * 1e3,
-                        fn_ms=(t_ret - t_taken) * 1e3,
-                        taken_back_ms=(t_got - t_ret) * 1e3,
-                        **(slow_note() if slow_note is not None else {}))
+                    t_got = time.perf_counter()
+                    queued = (t_taken - t_put) * 1e6
+                    taken = (t_got - t_ret) * 1e6
+                    stamps["hop_us"] = queued + taken
+                    stamps["queued_us"] = queued
+                    stamps["fn_us"] = (t_ret - t_taken) * 1e6
+                    stamps["taken_us"] = taken
+                    if usage is not None:
+                        stamps["cpu_us"] = usage[0] * 1e6
+                        stamps["vcsw"], stamps["ivcsw"] = usage[1:]
                 if ok:
                     return val
                 raise val
+
+
+# ---- a round that ran long (docs/OBSERVABILITY.md "A slow round") ------
+SLOW_ROUND = "slow_round"
+# a round is slow where it lasts over FACTOR x the running mean round AND
+# over that mean plus MARGIN: a long prefill among decodes is neither
+SLOW_ROUND_FACTOR = 2.0
+SLOW_ROUND_MARGIN_MS = 50.0
+SLOW_ROUND_LOG_PERIOD_S = 1.0
+_ROUND_ALPHA = 0.05                 # the running means' weight of a round
+_PHASES = ("outside", "schedule", "stage", "dispatch", "wait", "readback",
+           "emit")
+# the phases in which the engine's thread has work of its own to run
+_HOST_PHASES = ("schedule", "stage", "readback", "emit")
+
+
+def slow_round_where(rec: Dict) -> Tuple[str, Optional[str]]:
+    """THE rule table: where a slow round's time went, from the numbers
+    of its record (:class:`RoundWatch` writes them; a number the record
+    lacks reads 0).  First match wins.  ``E`` is the round's excess over
+    the mean round.  The CPU readings cover ``host_window_ms``, which
+    ends with the round and began ``usual = host_window_ms - E`` of
+    ordinary running before the excess, so ``own = thread_cpu_ms -
+    thread_cpu_rate x usual`` is what the engine's thread burned in the
+    excess and ``extra = process_cpu_ms - thread_cpu_ms - other_cpu_rate
+    x host_window_ms`` what the OTHER threads burned beyond their usual
+    share of the whole window:
+
+    ====================  ==============================================
+    ``descheduled``       the host says so: throttled time rose by E/4;
+                          or run delay or CPU pressure did, and a thread
+                          of the loop was preempted in the window (or
+                          the previous reading is no older than ten such
+                          rounds)
+    ``interpreter``       collections ended in the round took E/2
+                          (``by=gc``); or ``extra`` is 0.4 E while
+                          ``own`` is under E/4: ``by=loop`` where the
+                          event loop's heartbeat was E/2 late, else
+                          ``by=thread``
+    ``descheduled``       nobody ran: ``extra`` under 0.4 E, and the
+                          event loop's heartbeat was E/2 late too (two
+                          threads lost the same time and no one burned
+                          it), or the phase below is a host phase of the
+                          engine in which ``own`` is under E/4 (a thread
+                          with work to do that did not run)
+    ``outside``           the longest phase over its mean, holding
+    ``host:<phase>``      0.4 E, is the time between two ``step`` calls
+    ``handoff``           / a host phase of the engine / the launch or
+    ``completion``        the wait: ``handoff`` where ``queued`` +
+    ``device``            ``taken_back`` hold E/2; a launch otherwise is
+                          ``host:dispatch``; a wait with the process
+                          idle is ``completion`` where the next launch's
+                          samples were ready (the runtime delivered
+                          late), ``device`` where not or none was behind
+    ``unknown``           anything else
+    ====================  ==============================================
+
+    Returns ``(where, by)``; ``by`` is None outside ``interpreter``."""
+    g = lambda k: rec.get(k) or 0.0          # noqa: E731
+    excess = g("round_ms") - g("mean_ms")
+    if excess <= 0.0:
+        return "unknown", None
+    recent = g("since_s") * 1e3 <= 10.0 * g("round_ms")
+    if g("throttled_ms_rise") >= excess / 4 or (
+            (g("ivcsw") + g("worker_ivcsw") >= 1 or recent)
+            and max(g("run_delay_ms_rise"),
+                    g("pressure_ms_rise")) >= excess / 4):
+        return "descheduled", None
+    if g("gc_ms") >= excess / 2:
+        return "interpreter", "gc"
+    window = max(g("host_window_ms"), g("round_ms"))
+    own = g("thread_cpu_ms") - g("thread_cpu_rate") * (window - excess)
+    busy = g("process_cpu_ms") - g("thread_cpu_ms") \
+        - g("other_cpu_rate") * window >= 0.4 * excess
+    if busy and own <= excess / 4:
+        return "interpreter", \
+            "loop" if g("loop_lag_ms") >= excess / 2 else "thread"
+    means = rec.get("phase_mean_ms") or {}
+    over = {p: g(p + "_ms") - (means.get(p) or 0.0) for p in _PHASES}
+    phase = max(over, key=over.get)
+    if over[phase] < 0.4 * excess:
+        phase = None
+    if not busy and (g("loop_lag_ms") >= excess / 2 or (
+            phase in _HOST_PHASES and own <= excess / 4)):
+        return "descheduled", None
+    if phase is None:
+        return "unknown", None
+    if phase == "outside":
+        return "outside", None
+    if phase == "dispatch":
+        hand = g("launch_queued_ms") + g("launch_taken_back_ms")
+        return ("handoff" if hand >= excess / 2 else "host:dispatch"), None
+    if phase != "wait":
+        return "host:" + phase, None
+    if g("queued_ms") + g("taken_back_ms") >= excess / 2:
+        return "handoff", None
+    if busy:
+        return "unknown", None
+    return ("completion" if rec.get("next_ready") else "device"), None
+
+
+class RoundWatch:
+    """The served loop's own stall record: one ``slow_round`` a round
+    that ran long, with where it went.
+
+    A round is one ``InferenceEngine.step()`` on the engine's thread,
+    from the previous ``step``'s return (where that left a launch in
+    flight; from this call's first cut where the engine had been idle)
+    to this one's.  Its cuts are the readings ``tracer.phase()`` already
+    hands the engine (:meth:`cut_dispatch`, :meth:`cut_collect`: the
+    last round's, in these slots); :meth:`end` adds the round's ONE own
+    ``perf_counter`` reading.  The host's state costs system calls
+    (``getrusage``, ``process_time``: 6 us each on the chip's host,
+    where ``perf_counter`` is 0.07), so it is read at a round's end only
+    once every ``USAGE_PERIOD_S`` and when a round IS slow: a slow
+    round's CPU and switches are the rise since the last such reading
+    (``host_window_ms``), and the running rates (CPU ms a wall ms, of
+    this thread and of the process's others) say what part of it is the
+    ordinary running before the excess.  Everything else
+    (``HostSampler``'s files, the collections' ring, the rule table) is
+    touched only once a round is slow.  No file, no lock, nothing that
+    grows.
+
+    The means are exponential (weight ``_ROUND_ALPHA``, plain means
+    until that bites); a slow round enters the round's clipped to the
+    threshold, and the rates not at all.  No round is judged before
+    ``warm`` rounds have entered, none that compiled, failed or was read
+    back outside ``step``."""
+
+    def __init__(self, warm: int, timings, note: Callable[..., None],
+                 watchdog: Optional[Watchdog] = None, metrics=None,
+                 tracer=None):
+        self.warm = max(1, int(warm))
+        self._timings = timings
+        self._note = note
+        self._watchdog = watchdog
+        self.sampler = HostSampler()
+        self.gc = watch_gc(tracer)
+        self._tid = 0               # the engine thread's native id
+        self._c_rounds = self._c_seconds = None
+        if metrics is not None:
+            self._c_rounds = metrics.counter(
+                "serving_slow_rounds_total",
+                "served rounds that lasted over twice the mean round and "
+                "over it plus 50 ms, by where the time went (where: "
+                "device|completion|descheduled|interpreter|handoff|"
+                "host:<phase>|outside|unknown)", int_valued=True)
+            self._c_seconds = metrics.counter(
+                "serving_slow_round_seconds_total",
+                "seconds the slow rounds lasted beyond the mean round "
+                "(what the loop lost to them), by where")
+        # the previous round's end, and was a launch left in flight
+        self.t_end = 0.0
+        self.ahead = False
+        self.gc_s = 0.0
+        # the event loop's worst heartbeat lateness since then, and when
+        # its next beat is due on ``time.monotonic`` (0.0: no loop);
+        # written by the loop's thread, ``InferenceEngine.note_loop_lag``
+        self.lag_ms = 0.0
+        self.beat_due = 0.0
+        # the engine thread's last reading of the host: when, its CPU
+        # and switches, the process's CPU; None until its first
+        self.t_host = 0.0
+        self._host: Optional[Tuple[float, int, int, float]] = None
+        self.reset()
+
+    def reset(self) -> None:
+        """Forget the means (``reset_metrics()``: the timed region's
+        rounds are not the warm-up's) and take the sampler's baseline."""
+        self.n = self.n_rates = 0
+        self.mean_ms = 0.0
+        self.thread_rate = self.other_rate = 0.0
+        self.limit_us = float("inf")    # a wait this long is a slow round
+        self._host = None
+        self._clear()
+        self.void = True                # the round under way is cut
+        self.sampler.read()
+
+    def _clear(self) -> None:
+        self.c0 = 0.0               # this round's first cut; 0.0: none
+        self.void = False
+        self.sid = 0
+        self.sched_ms = self.stage_ms = self.disp_ms = 0.0
+        self.wait_ms = self.read_ms = 0.0
+        self.t_read = 0.0           # the readback's end
+        self.launch: Optional[Dict[str, float]] = None
+        self.wait: Optional[Dict[str, float]] = None
+        self.next_ready: Optional[bool] = None
+        self.rec: Optional[Dict] = None     # judged at the wait's end
+
+    # ---- the engine's cuts (no clock is read here) ---------------------
+    def cut_dispatch(self, t0: float, t1: float, t2: float, t3: float,
+                     cold: bool, stamps: Dict[str, float]) -> None:
+        if not self.c0:
+            self.c0 = t0
+        self.sched_ms = (t1 - t0) * 1e3
+        self.stage_ms = (t2 - t1) * 1e3
+        self.disp_ms = (t3 - t2) * 1e3
+        self.launch = stamps
+        if cold:
+            self.void = True
+
+    def cut_collect(self, sid: int, t0: float, t1: float, t2: float,
+                    cold: bool, stamps: Dict[str, float]) -> None:
+        if not self.c0:
+            self.c0 = t0
+        self.sid = sid
+        self.wait_ms = (t1 - t0) * 1e3
+        self.read_ms = (t2 - t1) * 1e3
+        self.t_read = t2
+        self.wait = stamps
+        if cold:
+            self.void = True
+
+    # ---- the round's end ----------------------------------------------
+    def end(self, ahead: bool) -> None:
+        """``step`` returns: close the round, judge it, roll the slots.
+        ``ahead``: a launch is left in flight, so the driver comes
+        straight back and the time until it does is the next round's."""
+        t = time.perf_counter()
+        c0 = self.c0
+        if c0 and not self.void:
+            start = self.t_end if self.ahead else c0
+            dur = (t - start) * 1e3
+            mean = self.mean_ms
+            # the threshold the rounds so far left: inf before ``warm``
+            limit = self.limit_us * 1e-3
+            if dur > limit or self.rec is not None:
+                self._slow(t, start, dur)
+                dur = min(dur, limit)
+            n = self.n + 1
+            self.n = n
+            mean += (dur - mean) * max(_ROUND_ALPHA, 1.0 / n)
+            self.mean_ms = mean
+            if n >= self.warm:
+                self.limit_us = 1e3 * max(SLOW_ROUND_FACTOR * mean,
+                                          mean + SLOW_ROUND_MARGIN_MS)
+        if t - self.t_host >= USAGE_PERIOD_S:
+            self._read_host(t, rates=True)
+        self.t_end, self.ahead = t, ahead
+        self.gc_s = self.gc.total_s
+        self.lag_ms = 0.0
+        self._clear()
+
+    def _read_host(self, t: float, rates: bool = False
+                   ) -> Tuple[float, float, int, int, float]:
+        """Read this thread's usage and the process's CPU (three system
+        calls), keep them as the next reading's baseline, and return the
+        rise since the previous one: ``(seconds, thread CPU s, voluntary,
+        involuntary switches, process CPU s)``.  ``rates``: an ordinary
+        stretch, which enters the running rates."""
+        cpu, vcsw, ivcsw = thread_usage()
+        proc = time.process_time()
+        prev, dt = self._host, t - self.t_host
+        self._host, self.t_host = (cpu, vcsw, ivcsw, proc), t
+        if prev is None:
+            return 0.0, 0.0, 0, 0, 0.0
+        rise = (dt, cpu - prev[0], vcsw - prev[1], ivcsw - prev[2],
+                proc - prev[3])
+        if rates and dt > 0.0:
+            self.n_rates += 1
+            a = max(_ROUND_ALPHA, 1.0 / self.n_rates)
+            self.thread_rate += (rise[1] / dt - self.thread_rate) * a
+            self.other_rate += ((rise[4] - rise[1]) / dt
+                                - self.other_rate) * a
+        return rise
+
+    # ---- the slow path -------------------------------------------------
+    def judge_wait(self, sid: int, stamps: Dict[str, float],
+                   next_ready: Optional[bool]) -> str:
+        """A guarded wait that alone outlasts the threshold
+        (``limit_us``): the verdict is reached HERE, from the stamps just
+        taken back, while the wait's phase is still open for the caller
+        to mark with it; :meth:`end` completes the record."""
+        t = time.perf_counter()
+        start = self.t_end if self.ahead else (self.c0 or t)
+        self.sid, self.next_ready = sid, next_ready
+        wait_ms = (stamps["queued_us"] + stamps["fn_us"]
+                   + stamps["taken_us"]) / 1e3
+        self.wait, self.wait_ms = stamps, wait_ms
+        if not self.c0:
+            self.c0 = t - wait_ms / 1e3
+        self.rec = self._measure(t, start, (t - start) * 1e3)
+        return self.rec["where"]
+
+    def _measure(self, t: float, start: float, dur: float) -> Dict:
+        """The record of a round that lasted ``dur`` ms up to ``t``."""
+        if not self._tid:
+            self._tid = threading.get_native_id()
+        tm = self._timings
+        steps = max(float(tm["steps"]), 1.0)
+        means = {p: round(float(tm[k]) / steps, 3) for p, k in (
+            ("schedule", "schedule_ms"), ("stage", "stage_ms"),
+            ("dispatch", "device_ms"), ("wait", "wait_ms"),
+            ("readback", "readback_ms"))}
+        outside = (self.c0 - self.t_end) * 1e3 \
+            if self.ahead and self.c0 else 0.0
+        rec: Dict = {
+            "sid": self.sid, "round_ms": dur, "mean_ms": self.mean_ms,
+            "t_s": t, "outside_ms": outside,
+            "schedule_ms": self.sched_ms, "stage_ms": self.stage_ms,
+            "dispatch_ms": self.disp_ms, "wait_ms": self.wait_ms,
+            "readback_ms": self.read_ms,
+            "emit_ms": (t - self.t_read) * 1e3 if self.t_read else 0.0,
+            "phase_mean_ms": means}
+        for stamps, pre in ((self.launch, "launch_"), (self.wait, "")):
+            if stamps:
+                rec[pre + "queued_ms"] = stamps["queued_us"] / 1e3
+                rec[pre + "fn_ms"] = stamps["fn_us"] / 1e3
+                rec[pre + "taken_back_ms"] = stamps["taken_us"] / 1e3
+        if self.next_ready is not None:
+            rec["next_ready"] = self.next_ready
+        dt, cpu, vcsw, ivcsw, proc = self._read_host(t)
+        lag = self.lag_ms
+        if self.beat_due:
+            # a beat that was due and has not run yet is late by now: a
+            # loop that stood still with this thread reports only after
+            lag = max(lag, (time.monotonic() - self.beat_due) * 1e3)
+        worker = [s for s in (self.launch, self.wait)
+                  if s and "cpu_us" in s]
+        rec.update(
+            host_window_ms=dt * 1e3, thread_cpu_ms=cpu * 1e3,
+            process_cpu_ms=proc * 1e3, vcsw=vcsw, ivcsw=ivcsw,
+            thread_cpu_rate=self.thread_rate,
+            other_cpu_rate=self.other_rate,
+            worker_cpu_ms=sum(s["cpu_us"] for s in worker) / 1e3,
+            worker_vcsw=sum(s["vcsw"] for s in worker),
+            worker_ivcsw=sum(s["ivcsw"] for s in worker),
+            gc_ms=(self.gc.total_s - self.gc_s) * 1e3, loop_lag_ms=lag)
+        gcs = self.gc.ended_in(start, t)
+        if gcs:
+            rec["gc"] = gcs[-8:]
+        wd = self._watchdog
+        rec.update(self.sampler.read(
+            (self._tid, wd.worker_tid if wd is not None else 0)))
+        where, by = slow_round_where(rec)
+        rec["where"] = where
+        if by is not None:
+            rec["by"] = by
+        return rec
+
+    def _slow(self, t: float, start: float, dur: float) -> None:
+        rec = self.rec
+        if rec is None:
+            rec = self._measure(t, start, dur)
+        else:
+            # judged at the wait's end: the rest of the round is added
+            rec.update(round_ms=dur, t_s=t, wait_ms=self.wait_ms,
+                       readback_ms=self.read_ms,
+                       emit_ms=(t - self.t_read) * 1e3
+                       if self.t_read else 0.0)
+        where = rec["where"]
+        lost = (dur - rec["mean_ms"]) / 1e3
+        if self._c_rounds is not None:
+            self._c_rounds.inc(where=where)
+            self._c_seconds.inc(lost, where=where)
+        self._note(SLOW_ROUND, **{
+            k: round(v, 3) if isinstance(v, float) else v
+            for k, v in rec.items()})
 
 
 class FailurePolicy:
@@ -366,29 +751,46 @@ class FailurePolicy:
     bookkeeping (strikes, probe groups, backoff — it owns the state
     those mutate); this object owns what is independent of it."""
 
-    def __init__(self, cfg: FailureConfig, timings, flight=None):
+    def __init__(self, cfg: FailureConfig, timings, flight=None,
+                 metrics=None, tracer=None):
         """``timings``: the engine's counter view — the auto deadline
         reads observed ``device_ms + wait_ms`` per step from it (the
         PR-5 metrics registry is the measurement substrate).
-        ``flight``: the engine's flight recorder; the watchdog's
-        slow-call and late-return records go there and to the log."""
+        ``flight``: the engine's flight recorder; the slow-round and
+        late-return records go there and to the log.  ``metrics`` and
+        ``tracer``: the engine's registry (the slow rounds' counters)
+        and span tracer (its ring takes the collections' spans)."""
         self.cfg = cfg
         self._timings = timings
         self._flight = flight
         self.watchdog = Watchdog(on_note=self._guard_note)
+        self.rounds = RoundWatch(cfg.watchdog_warmup_steps, timings,
+                                 self._guard_note, self.watchdog,
+                                 metrics=metrics, tracer=tracer)
+        # at most one slow_round line a second reaches the log
+        self._log_after = 0.0
+        self._log_held = 0
         # armed injections, consumed in order by guarded dispatches:
         # (kind, uid filter or None, remaining fire count)
         self._inject: List[Tuple[str, Optional[int], int]] = []
 
     def _guard_note(self, kind: str, **info) -> None:
-        """One log line and one flight-recorder breadcrumb per slow or
-        late guarded call (``Watchdog``); may run on the abandoned
-        worker's thread."""
+        """One flight-recorder breadcrumb per slow round or late guarded
+        call, and one log line: a late return always (it may run on the
+        abandoned worker's thread), a slow round at most once a second,
+        the next line counting those held back (``held=``)."""
+        if self._flight is not None:
+            self._flight.note(kind, **info)
+        if kind == SLOW_ROUND:
+            now = time.perf_counter()
+            if now < self._log_after:
+                self._log_held += 1
+                return
+            self._log_after = now + SLOW_ROUND_LOG_PERIOD_S
+            info["held"], self._log_held = self._log_held, 0
         logger.warning("%s: %s", kind, " ".join(
             f"{k}={v:.3f}" if isinstance(v, float) else f"{k}={v}"
             for k, v in info.items()))
-        if self._flight is not None:
-            self._flight.note(kind, **info)
 
     # ---- fault injection (the chaos harness seam) ---------------------
     def inject(self, kind: str, uid: Optional[int] = None,
@@ -418,14 +820,12 @@ class FailurePolicy:
     # ---- the guarded-call entry --------------------------------------
     def run(self, fn: Callable, uids=(), cold: bool = False,
             site: Optional[str] = None, sid: Optional[int] = None,
-            stamps: Optional[Dict[str, float]] = None,
-            slow_note: Optional[Callable[[], Dict]] = None):
+            stamps: Optional[Dict[str, float]] = None):
         """Run one guarded device call: consume any armed injection,
         then execute under the current watchdog deadline.  ``site``
         (``dispatch``/``collect``) and ``sid`` name the call
-        in the watchdog's slow-call records; ``stamps`` receives the
-        hand-off's ``hop_us`` and ``slow_note`` adds to a slow call's
-        record (``Watchdog.run``).  ``cold``
+        in the watchdog's late-return record; ``stamps`` receives the
+        hand-off's stamps (``Watchdog.run``).  ``cold``
         marks a call whose compiled program has never completed before
         (a compile may ride it): it runs UNGUARDED — compiles are slow
         and legitimate, and abandoning a worker mid-XLA-compile leaves
@@ -448,8 +848,7 @@ class FailurePolicy:
                 raise InjectedFault(kind, uid=None)
         return self.watchdog.run(fn,
                                  None if cold else self.deadline_ms(),
-                                 site=site, sid=sid, stamps=stamps,
-                                 slow_note=slow_note)
+                                 site=site, sid=sid, stamps=stamps)
 
     def deadline_ms(self) -> Optional[float]:
         """The current watchdog deadline: the configured value, or the

@@ -151,28 +151,35 @@ class TestWatchdog:
 
     def test_hand_off_is_stamped_in_order(self):
         """Four stamps per guarded call: put, taken by the worker, fn
-        returned, result taken back.  ``hop_us`` is the two hand-overs,
-        never the call itself; an inline call has no hop."""
+        returned, result taken back, handed over in the caller's dict
+        with the worker's usage.  ``hop_us`` is the two hand-overs,
+        never the call itself; the watchdog judges none of them (a slow
+        ROUND is the engine's to note, ``slow_round``); an inline call
+        has no hop."""
         notes = []
         wd = Watchdog(on_note=lambda kind, **info: notes.append((kind, info)))
         stamps = {}
         assert wd.run(lambda: time.sleep(0.05) or "ok", 400.0,
                       site="collect", sid=7, stamps=stamps) == "ok"
-        # 50 ms is over a tenth of the 400 ms deadline: one slow-call note
-        (kind, info), = notes
-        assert kind == "guard_slow_call"
-        assert (info["site"], info["sid"], info["ok"]) == ("collect", 7, True)
-        assert info["deadline_ms"] == 400.0 and info["t_put_s"] > 0
-        assert info["queued_ms"] >= 0.0 and info["taken_back_ms"] >= 0.0
-        assert info["fn_ms"] >= 50.0
+        # 50 ms is over a tenth of the 400 ms deadline: no note of the
+        # watchdog's own, however long the call
+        assert notes == []
+        assert sorted(stamps) == ["cpu_us", "fn_us", "hop_us", "ivcsw",
+                                  "queued_us", "taken_us", "vcsw"]
+        assert stamps["queued_us"] >= 0.0 and stamps["taken_us"] >= 0.0
+        assert stamps["fn_us"] >= 50e3
+        # the worker slept through fn(): no CPU to speak of, one sleep
+        assert stamps["cpu_us"] < 25e3 and stamps["vcsw"] >= 1
         # the hop is the two hand-overs, not the 50 ms of fn()
-        assert (info["queued_ms"] + info["taken_back_ms"]) * 1e3 \
+        assert stamps["queued_us"] + stamps["taken_us"] \
             == pytest.approx(stamps["hop_us"], abs=1.0)
-        assert info["queued_ms"] + info["fn_ms"] + info["taken_back_ms"] \
-            < 400.0
-        # a fast call under a long deadline says nothing
+        assert stamps["hop_us"] + stamps["fn_us"] < 400e3
+        assert wd.worker_tid > 0
+        # the record a slow round takes these into is named slow_round
+        from deepspeed_tpu.inference.failures import SLOW_ROUND
+        assert SLOW_ROUND == "slow_round"
         assert wd.run(lambda: 1, 60_000.0, stamps=stamps) == 1
-        assert len(notes) == 1 and stamps["hop_us"] >= 0.0
+        assert notes == [] and stamps["hop_us"] >= 0.0
         inline = {}
         assert wd.run(lambda: 2, None, stamps=inline) == 2
         assert inline == {}

@@ -1,0 +1,410 @@
+"""A served round that runs long says where it went: the ``slow_round``
+record (inference/failures.py ``RoundWatch``, ``slow_round_where``;
+telemetry/host.py; docs/OBSERVABILITY.md "A slow round").
+
+Host-only: no model, no ``jit``.  The real runs drive a real
+``Watchdog`` worker; what a loaded test machine would add (pressure,
+run delay: the sampler's files) is taken out of them, and tested by the
+rule table's own cases."""
+
+import asyncio
+import gc
+import threading
+import time
+from types import SimpleNamespace
+
+import pytest
+
+from deepspeed_tpu.inference import failures
+from deepspeed_tpu.inference.failures import (SLOW_ROUND, FailureConfig,
+                                              FailurePolicy,
+                                              slow_round_where)
+from deepspeed_tpu.telemetry import FlightRecorder, MetricsRegistry, SpanTracer
+from deepspeed_tpu.telemetry import host
+
+TIMING_KEYS = ("steps", "schedule_ms", "stage_ms", "device_ms", "wait_ms",
+               "readback_ms")
+
+
+# --------------------------------------------------------------------------
+# the rule table: a case a verdict
+# --------------------------------------------------------------------------
+
+def record(**over):
+    """A 200 ms round among rounds of 20 whose wait held the excess, the
+    process idle, nothing read from the host's files; the host's CPU
+    readings cover the round and 60 ms of ordinary running before it, in
+    which this thread burns 0.2 of a core and the others 0.25."""
+    rec = {"round_ms": 200.0, "mean_ms": 20.0, "outside_ms": 0.5,
+           "schedule_ms": 2.0, "stage_ms": 1.0, "dispatch_ms": 1.5,
+           "wait_ms": 193.0, "readback_ms": 0.2, "emit_ms": 0.8,
+           "phase_mean_ms": {"schedule": 2.0, "stage": 1.0, "dispatch": 1.5,
+                             "wait": 14.0, "readback": 0.2},
+           "queued_ms": 0.05, "fn_ms": 192.9, "taken_back_ms": 0.05,
+           "host_window_ms": 260.0, "thread_cpu_ms": 16.0,
+           "worker_cpu_ms": 0.1, "process_cpu_ms": 36.0,
+           "thread_cpu_rate": 0.2, "other_cpu_rate": 0.25,
+           "ivcsw": 0, "worker_ivcsw": 0, "gc_ms": 0.0, "loop_lag_ms": 0.3,
+           "since_s": 30.0}
+    rec.update(over)
+    return rec
+
+
+def elsewhere(phase, **over):
+    """The same round with the excess in ``phase`` and a usual wait."""
+    return record(**{"wait_ms": 14.0, "fn_ms": 13.9,
+                     phase + "_ms": 180.0, **over})
+
+
+RULES = [
+    ("device", None, record(next_ready=False)),
+    ("device", None, record()),                      # no launch behind
+    ("completion", None, record(next_ready=True)),
+    ("descheduled", None, record(throttled_ms_rise=60.0, next_ready=True)),
+    ("descheduled", None, record(run_delay_ms_rise=50.0, worker_ivcsw=1)),
+    ("descheduled", None, record(pressure_ms_rise=90.0, ivcsw=2)),
+    # a rise since a reading half a minute old, nobody preempted: not it
+    ("device", None, record(run_delay_ms_rise=50.0, pressure_ms_rise=400.0)),
+    # ... but since a reading no older than ten such rounds it is
+    ("descheduled", None, record(pressure_ms_rise=90.0, since_s=1.5)),
+    ("interpreter", "gc", record(gc_ms=95.0, next_ready=True)),
+    ("interpreter", "gc", elsewhere("schedule", gc_ms=170.0,
+                                    thread_cpu_ms=190.0,
+                                    process_cpu_ms=215.0)),
+    ("interpreter", "loop", record(process_cpu_ms=230.0, loop_lag_ms=150.0)),
+    ("interpreter", "thread", record(process_cpu_ms=230.0)),
+    # the others as busy as in any stretch this long: the process idles
+    ("completion", None, record(process_cpu_ms=80.0, next_ready=True)),
+    # nobody ran: the event loop lost the same time and no one burned it
+    ("descheduled", None, record(loop_lag_ms=120.0, next_ready=True)),
+    ("descheduled", None, record(loop_lag_ms=120.0, next_ready=False)),
+    # ... a host phase in which the thread that had the work had no CPU
+    ("descheduled", None, elsewhere("stage")),
+    ("descheduled", None, elsewhere("emit", thread_cpu_ms=40.0,
+                                    process_cpu_ms=70.0)),
+    # ... the loop late because IT burned the time is the interpreter's
+    ("interpreter", "loop", record(loop_lag_ms=190.0, process_cpu_ms=240.0)),
+    ("handoff", None, record(queued_ms=100.0, fn_ms=92.9)),
+    ("handoff", None, record(taken_back_ms=95.0, fn_ms=97.9)),
+    ("handoff", None, elsewhere("dispatch", launch_queued_ms=120.0,
+                                launch_fn_ms=60.0)),
+    ("host:dispatch", None, elsewhere("dispatch", launch_fn_ms=178.0)),
+    # a host phase that burned its time on this thread
+    ("host:schedule", None, elsewhere("schedule", thread_cpu_ms=186.0,
+                                      process_cpu_ms=206.0)),
+    ("host:stage", None, elsewhere("stage", thread_cpu_ms=100.0,
+                                   process_cpu_ms=125.0)),
+    ("host:readback", None, elsewhere("readback", thread_cpu_ms=186.0,
+                                      process_cpu_ms=206.0)),
+    ("host:emit", None, elsewhere("emit", thread_cpu_ms=186.0,
+                                  process_cpu_ms=206.0)),
+    ("outside", None, elsewhere("outside")),
+    # no phase holds 0.4 of the excess
+    ("unknown", None, record(wait_ms=50.0, fn_ms=49.9, schedule_ms=40.0,
+                             stage_ms=40.0, dispatch_ms=35.0, emit_ms=34.0)),
+    # the wait is long, the process busy, and this thread had its share
+    ("unknown", None, record(process_cpu_ms=330.0, thread_cpu_ms=100.0)),
+    ("unknown", None, record(round_ms=19.0)),        # no excess at all
+]
+
+
+@pytest.mark.parametrize("where,by,rec", RULES,
+                         ids=[f"{i}-{r[0]}" + (f"-{r[1]}" if r[1] else "")
+                              for i, r in enumerate(RULES)])
+def test_rule_table(where, by, rec):
+    assert slow_round_where(rec) == (where, by)
+
+
+def test_every_verdict_of_the_table_has_a_case():
+    doc = slow_round_where.__doc__
+    for where in {r[0] for r in RULES}:
+        assert f"``{where.split(':')[0]}" in doc
+    assert {r[0].split(":")[0] for r in RULES} == {
+        "device", "completion", "descheduled", "interpreter", "handoff",
+        "host", "outside", "unknown"}
+
+
+# --------------------------------------------------------------------------
+# real rounds: a real Watchdog worker, the process's real clocks
+# --------------------------------------------------------------------------
+
+def policy(warm=3, tracer=None, metrics=None):
+    tm = {k: 1.0 for k in TIMING_KEYS}
+    flight = FlightRecorder()
+    pol = FailurePolicy(
+        FailureConfig(dispatch_timeout_ms=5000.0, watchdog_warmup_steps=warm),
+        tm, flight=flight, metrics=metrics, tracer=tracer)
+    # a loaded test machine is not what these runs are about
+    pol.rounds.sampler.read = lambda tids=(): {}
+    return pol, flight
+
+
+def round_of(rw, ms, sid=1, ahead=False, cold=False):
+    """One round that lasted ``ms`` (its first cut that long ago)."""
+    t = time.perf_counter()
+    rw.cut_collect(sid, t - ms / 1e3, t - 1e-4, t, cold, {})
+    rw.end(ahead)
+
+
+def warmed(pol, ms=2.0, n=6, ahead=True):
+    rw = pol.rounds
+    rw.end(False)
+    for i in range(n):
+        # the last one leaves a launch in flight (``ahead``): the next
+        # round then starts at its return, not at its own first cut
+        round_of(rw, ms, ahead=ahead and i == n - 1)
+    assert rw.mean_ms == pytest.approx(ms, rel=0.2)
+    return rw
+
+
+def slow_wait(pol, fn, next_ready):
+    """The engine's ``_collect`` around one guarded wait, then the
+    round's end; returns the round's one record."""
+    rw = pol.rounds
+    stamps = {}
+    t0 = time.perf_counter()
+    pol.run(fn, site="collect", sid=5, stamps=stamps)
+    where = None
+    if stamps["hop_us"] + stamps["fn_us"] > rw.limit_us:
+        where = rw.judge_wait(5, stamps, next_ready)
+    t1 = time.perf_counter()
+    rw.cut_collect(5, t0, t1, t1, False, stamps)
+    rw.end(True)
+    return where
+
+
+def records(flight):
+    return [e for e in flight.events() if e["kind"] == SLOW_ROUND]
+
+
+@pytest.mark.parametrize("next_ready,where", [(False, "device"),
+                                              (None, "device"),
+                                              (True, "completion")])
+def test_a_sleeping_wait_with_the_process_idle(next_ready, where):
+    reg = MetricsRegistry()
+    pol, flight = policy(metrics=reg)
+    warmed(pol)
+    assert slow_wait(pol, lambda: time.sleep(0.3), next_ready) == where
+    (rec,) = records(flight)
+    assert rec["where"] == where and rec["sid"] == 5 and "by" not in rec
+    assert rec["round_ms"] >= 300.0 and rec["mean_ms"] < 5.0
+    assert rec["fn_ms"] >= 299.0 and rec["wait_ms"] >= rec["fn_ms"]
+    assert rec["queued_ms"] + rec["taken_back_ms"] < 50.0
+    assert rec["process_cpu_ms"] < 100.0 and rec["worker_cpu_ms"] < 50.0
+    assert rec["host_window_ms"] >= rec["round_ms"] - 1.0
+    assert rec.get("next_ready") is next_ready
+    assert rec["worker_vcsw"] >= 1
+    assert rec["t_s"] <= time.perf_counter() + 1e-3      # rounded to a ms
+    snap = reg.snapshot()
+    assert reg.get("serving_slow_rounds_total").value(where=where) == 1
+    lost = reg.get("serving_slow_round_seconds_total").value(where=where)
+    assert lost == pytest.approx((rec["round_ms"] - rec["mean_ms"]) / 1e3,
+                                 abs=1e-3)
+    assert "serving_slow_rounds_total" in snap
+    assert f'serving_slow_rounds_total{{where="{where}"}} 1' \
+        in reg.prometheus_text()
+    # the slow round entered the mean clipped to the threshold
+    assert pol.rounds.mean_ms < 10.0
+
+
+def test_a_thread_spinning_under_the_interpreter_lock():
+    pol, flight = policy()
+    warmed(pol)
+    stop = threading.Event()
+
+    def spin():
+        n = 0
+        while not stop.is_set():
+            n += 1
+
+    th = threading.Thread(target=spin, daemon=True)
+    th.start()
+    try:
+        where = slow_wait(pol, lambda: time.sleep(0.3), True)
+    finally:
+        stop.set()
+        th.join()
+    (rec,) = records(flight)
+    assert where == rec["where"] == "interpreter" and rec["by"] == "thread"
+    # the process burned about a core while this thread had none
+    assert rec["process_cpu_ms"] >= 150.0 and rec["thread_cpu_ms"] < 75.0
+    assert rec["gc_ms"] < 75.0
+
+
+def test_a_collection_on_another_thread_inside_the_wait():
+    # cycles for the collector to walk, so that a collection takes time
+    junk = [[i] for i in range(200_000)]
+    for a, b in zip(junk, junk[1:]):
+        a.append(b)
+    tracer = SpanTracer(enabled=True)
+    pol, flight = policy(tracer=tracer)
+    warmed(pol)
+
+    def collect():
+        t0 = time.perf_counter()
+        while time.perf_counter() - t0 < 0.15:
+            gc.collect()
+
+    th = threading.Thread(target=collect, name="collector")
+    before = pol.rounds.gc.count
+    where = slow_wait(pol, lambda: (th.start(), th.join()), True)
+    del junk
+    (rec,) = records(flight)
+    assert where == rec["where"] == "interpreter" and rec["by"] == "gc"
+    assert rec["gc_ms"] >= 75.0 and pol.rounds.gc.count > before
+    mine = [g for g in rec["gc"] if g["thread"] == "collector"]
+    assert mine and all(g["gen"] == 2 and g["ms"] >= 1.0 for g in mine)
+    spans = [e for e in tracer.events() if e["name"] == host.GC_SPAN]
+    assert spans and spans[-1]["args"] == {"gen": 2, "thread": "collector"}
+    assert sum(e["dur_ns"] for e in spans) >= 75e6
+    # ... and none with the ring off
+    tracer.disable()
+    n = len(tracer.events())
+    gc.collect()
+    assert len(tracer.events()) == n
+
+
+def test_the_trigger():
+    """Not before the warm-up, not on a compile, not at 1.9 x the mean,
+    not at the mean plus 49 ms; just past either, once both hold."""
+    pol, flight = policy(warm=4)
+    rw = pol.rounds
+    rw.end(False)
+    for _ in range(3):
+        round_of(rw, 100.0)
+    round_of(rw, 900.0)                     # the fourth round: warm-up
+    assert not records(flight) and rw.n == 4
+    assert rw.mean_ms == pytest.approx(300.0, rel=0.01)
+    pol, flight = policy(warm=4)
+    rw = warmed(pol, ms=100.0, n=8, ahead=False)
+    round_of(rw, 900.0, cold=True)          # a round that compiled
+    assert not records(flight) and rw.n == 8
+    round_of(rw, 189.0)                     # 1.9 x the mean (and + 89 ms)
+    assert not records(flight)
+    round_of(rw, 2.05 * rw.mean_ms, sid=11)
+    (rec,) = records(flight)
+    assert rec["sid"] == 11 and rec["where"] in ("host:emit", "device",
+                                                 "unknown", "host:wait")
+    pol, flight = policy(warm=4)
+    rw = warmed(pol, ms=10.0, n=8, ahead=False)
+    round_of(rw, rw.mean_ms + 48.9)         # over 5 x the mean, + 49 ms
+    assert not records(flight)
+    round_of(rw, rw.mean_ms + 51.0)
+    assert len(records(flight)) == 1
+    # a round that failed, or was read back outside step(), is cut too
+    rw.void = True
+    round_of(rw, 500.0)
+    assert len(records(flight)) == 1
+    # the engine idle before this call: the round starts at its first cut
+    time.sleep(0.08)
+    round_of(rw, 10.0, ahead=False)
+    assert len(records(flight)) == 1
+    # reset_metrics(): the means are forgotten, nothing is judged until
+    # the warm-up's rounds have entered again
+    rw.reset()
+    assert rw.n == 0 and rw.limit_us == float("inf")
+    round_of(rw, 10.0)                      # the round under way: cut
+    for _ in range(3):
+        round_of(rw, 10.0)
+    round_of(rw, 500.0)
+    assert len(records(flight)) == 1 and rw.n == 4
+
+
+def test_the_log_takes_one_line_a_second(monkeypatch):
+    lines = []
+    monkeypatch.setattr(failures.logger, "warning",
+                        lambda fmt, *a: lines.append(fmt % a))
+    pol, flight = policy(warm=4)
+    rw = warmed(pol, ms=10.0, n=8, ahead=False)
+    for _ in range(4):
+        round_of(rw, 200.0)
+    assert len(records(flight)) == 4        # every one in the ring
+    assert len(lines) == 1 and lines[0].startswith("slow_round: ")
+    assert " held=0" in lines[0] and " where=" in lines[0]
+    pol._log_after = 0.0                    # a second later
+    round_of(rw, 200.0)
+    assert len(lines) == 2 and " held=3" in lines[1]
+    # a late return is never held back
+    pol._guard_note("guard_late_return", site="collect", sid=1)
+    assert len(lines) == 3
+
+
+def test_one_collection_callback_a_process():
+    watches = {id(host.watch_gc()) for _ in range(3)}
+    pols = [policy()[0] for _ in range(3)]
+    assert len(watches | {id(p.rounds.gc) for p in pols}) == 1
+    mine = [cb for cb in gc.callbacks if isinstance(cb, host.GcWatch)]
+    assert len(mine) == 1 and mine[0] is pols[0].rounds.gc
+    # tracers are held weakly: a dead engine's ring is let go
+    w = host.watch_gc(SpanTracer())
+    gc.collect()
+    live = SpanTracer()
+    w.add_ring(live)
+    w.add_ring(live)
+    assert [r() for r in w._rings].count(live) == 1
+    assert sum(r() is None for r in w._rings) == 0
+
+
+def test_the_sampler_reads_what_the_host_has(tmp_path, monkeypatch):
+    s = host.HostSampler()
+    first = s.read((threading.get_native_id(),))
+    assert "since_s" not in first
+    again = s.read((threading.get_native_id(),))
+    assert again["since_s"] >= 0.0
+    for k, v in again.items():
+        assert isinstance(v, float) and (not k.endswith("_rise") or v >= 0.0)
+    # cgroup v2's and v1's names for the same reading
+    v2 = tmp_path / "cpu.stat"
+    v2.write_text("usage_usec 5\nnr_throttled 3\nthrottled_usec 7000\n")
+    s._cpu_stat = str(v2)
+    assert s._cumulative(())["throttled_ms"] == 7.0
+    v2.write_text("nr_periods 9\nnr_throttled 4\nthrottled_time 9000000\n")
+    cur = s._cumulative(())
+    assert cur["throttled_ms"] == 9.0 and cur["nr_throttled"] == 4.0
+    # a file that is not there is left out
+    s._cpu_stat = str(tmp_path / "none")
+    assert "throttled_ms" not in s._cumulative(())
+
+
+def test_the_event_loops_heartbeat_reaches_the_round():
+    from deepspeed_tpu.gateway.server import Gateway
+    lags, dues = [], []
+    backend = SimpleNamespace(
+        metrics=MetricsRegistry(), tracer=None,
+        note_loop_lag=lambda lag, due: (lags.append(lag), dues.append(due)))
+
+    async def run():
+        gw = Gateway(backend)
+        loop = asyncio.get_running_loop()
+        gw._beat(loop, loop.time())
+        await asyncio.sleep(0.05)
+        time.sleep(0.12)                    # a callback that holds the loop
+        await asyncio.sleep(0.03)
+        gw._beat_handle.cancel()
+        gw._exec.shutdown(wait=False)
+        return gw
+
+    gw = asyncio.run(run())
+    assert len(lags) >= 3 and max(lags) >= 80.0 and min(lags) < 20.0
+    h = backend.metrics.get("serving_gateway_event_loop_lag_ms")
+    assert h.count() == len(lags)
+    assert h.bucket_counts()["50"] < h.count()
+    # the engine keeps the worst since its last round's end
+    pol, _ = policy()
+    eng = SimpleNamespace(_round=pol.rounds)
+    from deepspeed_tpu.inference.engine import InferenceEngine
+    for lag, due in zip(lags, dues):
+        InferenceEngine.note_loop_lag(eng, lag, due)
+    assert pol.rounds.lag_ms == max(lags)
+    assert dues == sorted(dues) and pol.rounds.beat_due == dues[-1] > 0.0
+    pol.rounds.end(False)
+    assert pol.rounds.lag_ms == 0.0
+    # a beat that was due and has not run when a round is judged counts:
+    # a loop that stood still with the engine's thread reports only after
+    rw = warmed(pol)
+    rw.beat_due = time.monotonic() - 0.2
+    t = time.perf_counter()
+    rec = rw._measure(t, t - 0.3, 300.0)
+    assert rec["loop_lag_ms"] >= 200.0 and rec["where"] == "descheduled"
+    InferenceEngine.note_loop_lag(eng, 0.0, 0.0)    # the driver stopped
+    assert rw._measure(t, t - 0.3, 300.0)["loop_lag_ms"] == 0.0
