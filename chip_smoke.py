@@ -86,6 +86,12 @@ REAL = dict(
     # the one-token state update at the two cells' shapes (serve-ssm-chat,
     # serve-kda-reason): a 2 MiB row a slot a layer
     state=dict(L=2, S=128, H=32, ssm=(128, 256, 2), kda=(128, 128)),
+    # the chunked form at the two Mamba-2 cells' shapes (serve-ssm-chat,
+    # serve-ssm-moe-rag): heads, head width, groups, state, chunk; a
+    # 512-row step that holds decode rows, a prompt's run and a short one
+    scan=dict(T=512, S=64, runs=((63, 300, 5), (400, 40, 7)), cases={
+        "falcon-h1": (32, 128, 2, 256, 128),
+        "granite-4.0-h": (128, 64, 1, 128, 256)}),
     # latent attention at the two cells' shapes (serve-mla-docqa: 64
     # heads, 47 one-token rows at contexts of 1k-10k beside a run of 464
     # rows; serve-kda-reason: 32 heads, 127 rows at 0.5k-6k, a run of 64)
@@ -114,6 +120,8 @@ TINY = dict(
                     window=32, ctx=(70, 190)),
     gemm=dict(K=256, N=512, Ms=(8, 32)),
     state=dict(L=2, S=4, H=4, ssm=(8, 128, 2), kda=(16, 128)),
+    scan=dict(T=32, S=4, runs=((3, 19, 1), (24, 6, 3)), cases={
+        "falcon-h1": (4, 128, 2, 16, 8), "granite-4.0-h": (4, 64, 1, 16, 8)}),
     latent=dict(row=(96, 16), block=8, cases={
         "docqa": dict(H=4, S=4, T=32, ctx=(9, 70), run=(21, 30), chunk=8)}),
     barrier=dict(n=256, iters=8),
@@ -504,6 +512,76 @@ def kernels_phase(sz, seed):
         check(bool((got["pallas"][1][0] == stack[0]).all()
                    and (got["pallas"][1][1, S] == stack[1, S]).all()),
               f"{name} moved another layer's rows or the trash row")
+
+    # --- the Mamba-2 chunked form over the chunks a step holds, ONE
+    # kernel in place on the stack, against XLA's ``chunk_scan`` as the
+    # serving forward composes it off the TPU
+    c = sz["scan"]
+    T, S = c["T"], c["S"]
+    for name, (H, P, G, N, Q) in c["cases"].items():
+        dims = ssm_ops.SSMDims(H * P, H, P, G, N, 4, Q)
+        table = [(start + at, min(Q, n - at), slot, at == 0, at + Q >= n)
+                 for start, n, slot in c["runs"] for at in range(0, n, Q)]
+        NC = -(-T // Q) + 4
+        chunks = jnp.asarray(table + [(0, 0, S, 1, 0)] * (NC - len(table)),
+                             jnp.int32)
+        fresh = jnp.arange(NC) == len(table) - 1     # the short run's
+        ins = (jax.random.normal(ks[0], (T, H, P), jnp.bfloat16),
+               0.3 * jax.random.normal(ks[1], (T, G, N), jnp.bfloat16),
+               0.3 * jax.random.normal(ks[2], (T, G, N), jnp.bfloat16),
+               jax.nn.softplus(jax.random.normal(ks[3], (T, H)) - 2.0),
+               -jnp.exp(0.5 * jax.random.normal(ks[4], (H,))),
+               jax.random.normal(ks[5], (H,)))
+        stack = 0.5 * jax.random.normal(ks[6], (2, S + 1, H, P, N),
+                                        jnp.bfloat16)
+        print(f"  ssm_chunk_scan {name} H{H} [{P}, {N}] chunk {Q}: "
+              f"{len(table)} of {NC} chunks hold "
+              f"{sum(n for _, n, _ in c['runs'])} of {T} rows")
+
+        def xla(stack, li, x, b, cc, dt, a, d, _dims=dims, _Q=Q):
+            start, n, slot, first, last = (chunks[:, i] for i in range(5))
+            q = jnp.arange(_Q)[None, :]
+            there = q < n[:, None]
+            rows = jnp.minimum(start[:, None] + q, T - 1)
+            init = jnp.where(fresh[:, None, None, None], 0,
+                             stack[li][slot].astype(jnp.float32))
+            y_run, left = ssm_ops.chunk_scan(
+                x[rows], b[rows], cc[rows],
+                jnp.where(there[..., None], dt[rows], 0.0), a, d,
+                first.astype(bool), init, _dims)
+            to = jnp.where((n > 0) & (last != 0), slot, S)
+            for i in range(NC):
+                stack = stack.at[li, to[i]].set(left[i].astype(stack.dtype))
+            y = jnp.zeros((T, H, P), jnp.float32).at[
+                jnp.where(there, rows, T).reshape(-1)].set(
+                y_run.reshape(-1, H, P), mode="drop")
+            return y, stack
+
+        got = {}
+        for impl, fn in (
+                ("pallas", lambda st, li, x, b, cc, *a, _dims=dims:
+                 ssm_ops.chunk_scan_in_place(
+                     st, li, jnp.concatenate(
+                         [t.reshape(T, -1) for t in (x, b, cc)], axis=1),
+                     *a, chunks, fresh, _dims)),
+                ("xla", xla)):
+            step = jax.jit(fn, donate_argnums=0)
+            got[impl] = jax.block_until_ready(step(jnp.copy(stack), 1, *ins))
+            st, t0 = jnp.copy(stack), time.perf_counter()
+            for _ in range(10):
+                _, st = step(st, 1, *ins)
+            jax.block_until_ready(st)
+            print(f"    {impl}: {ms((time.perf_counter() - t0) / 10)}")
+        # (both feed the MXU what XLA's default precision feeds it, one
+        # bfloat16 pass; a frame is not a chunk, so the roundings differ)
+        close("out", got["pallas"][0], got["xla"][0], 1e-2)
+        ends = [slot for _, _, slot in c["runs"]]
+        close("rows", got["pallas"][1][1, jnp.asarray(ends)],
+              got["xla"][1][1, jnp.asarray(ends)], 2e-2)
+        check(bool((got["pallas"][1][0] == stack[0]).all()
+                   and (got["pallas"][1][1, S] == stack[1, S]).all()),
+              f"{name}: the chunk kernel moved another layer's rows or "
+              "the trash row")
 
     # --- mixed-input GEMMs
     K, N = sz["gemm"]["K"], sz["gemm"]["N"]

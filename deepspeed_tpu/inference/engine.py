@@ -835,6 +835,22 @@ class InferenceEngine:
                          "state rows the dispatched steps advanced by one "
                          "token over the slot rows their dense update read "
                          "and wrote (absent before the first step)")
+            # the chunked form walks the chunks of the step's table that
+            # hold rows: how many, and how full they were
+            self._c_scan_chunks = reg.counter(
+                "serving_scan_chunks_total",
+                "chunks of the dispatched steps' tables that held rows of "
+                "a run of several tokens (the chunked form computes those "
+                "and no others), one layer's count", int_valued=True)
+            self._scan_tokens = self._scan_chunks = 0
+            reg.gauge_fn("serving_scan_chunk_fill",
+                         lambda: (self._scan_tokens
+                                  / (self._scan_chunks
+                                     * self._recurrent.chunk)
+                                  if self._scan_chunks else None),
+                         "tokens of the runs of several tokens over the "
+                         "rows of the chunks that held them (absent before "
+                         "the first such run)")
             reg.gauge_fn(
                 "serving_state_bytes",
                 lambda: len(self.state.seqs)
@@ -1137,8 +1153,11 @@ class InferenceEngine:
         sequences it advances by one token; ``scan_tokens``, the tokens
         of its longer runs; ``state_starts``, the runs that begin at
         position 0 (from zeros, whatever the slot held);
-        ``state_replays``, the one-token rows fed again."""
-        rows = scan = starts = replays = 0
+        ``state_replays``, the one-token rows fed again;
+        ``scan_chunks``, the chunks of the step's table that hold the
+        longer runs' rows (``build_batch`` cuts a run every ``chunk``
+        rows)."""
+        rows = scan = starts = replays = chunks = 0
         for uid, toks in sched:
             seq = self.state.seqs.get(uid)
             if seq is None or seq.seen_tokens == 0:
@@ -1149,13 +1168,18 @@ class InferenceEngine:
                 rows += 1
             else:
                 scan += len(toks)
+                chunks += -(-len(toks) // self._recurrent.chunk)
         self._c_state_updates.inc(rows, kind="decode")
         self._c_state_updates.inc(scan, kind="scan")
         self._state_rows += rows
         self._state_slots += self.icfg.max_seqs
         self._c_state_replayed.inc(replays)
+        self._c_scan_chunks.inc(chunks)
+        self._scan_tokens += scan
+        self._scan_chunks += chunks
         return {"state_rows": rows, "scan_tokens": scan,
-                "state_starts": starts, "state_replays": replays}
+                "scan_chunks": chunks, "state_starts": starts,
+                "state_replays": replays}
 
     def _attn_group_fill(self) -> Optional[float]:
         """Needed KV blocks over the blocks held by the groups the
@@ -1284,6 +1308,7 @@ class InferenceEngine:
         self._group_blocks = self._group_slots = 0
         self._row_tokens = self._row_slots = 0
         self._state_rows = self._state_slots = 0
+        self._scan_tokens = self._scan_chunks = 0
         self.requests.clear()
         self.tracer.clear()
         # the timed region's rounds are judged against their own mean

@@ -577,8 +577,12 @@ def _ssm_mixer(cfg, mp, u, rec_state, li, batch: RaggedBatch, runs, dt,
     one read and one write a row; else XLA's over the layer cut out of
     it, which reads a row twice); a longer run goes through the chunked form
     (``ssm_scan``) from the slot's state, or from zeros where it starts
-    at position 0, and leaves its last state in the slot; the
-    convolution reaches into the slot's tail (``ssm_conv``).
+    at position 0, and leaves its last state in the slot (with
+    ``kernel`` ONE Pallas kernel over the chunks the step's table holds,
+    the state read from and left in the stack in place; else XLA's
+    ``chunk_scan`` over every chunk of the table, the state rows cut out
+    and written back around it); the convolution reaches into the
+    slot's tail (``ssm_conv``).
     → (y [T, dm], rec_state)."""
     from ..ops import ssm as M
 
@@ -590,10 +594,11 @@ def _ssm_mixer(cfg, mp, u, rec_state, li, batch: RaggedBatch, runs, dt,
                                          cfg.ssm_col_scales)
     with jax.named_scope("ssm_conv"):
         tail = jax.lax.dynamic_index_in_dim(conv, li, keepdims=False)
-        x, b, c = M.split_xbc(M.conv_rows(
+        act = M.conv_rows(
             xbc, tail[:S], batch.seq_slot, runs["row_first"],
             runs["row_offset"], runs["row_fresh"], mp["conv_w"],
-            mp["conv_b"]).astype(dt), dims)
+            mp["conv_b"]).astype(dt)
+        x, b, c = M.split_xbc(act, dims)
         new_tail = M.conv_tails(
             xbc, tail[:S], runs["last"], runs["first"], runs["offset"],
             runs["fresh"], runs["has_run"])
@@ -617,43 +622,54 @@ def _ssm_mixer(cfg, mp, u, rec_state, li, batch: RaggedBatch, runs, dt,
                                                (li, 0, 0, 0, 0))
     with jax.named_scope("ssm_scan"):
         ch = runs["chunks"]
-        start, n, slot, first, lastc = (ch[:, i] for i in range(5))
-        Q = dims.chunk
-        q = jnp.arange(Q)[None, :]
-        there = q < n[:, None]                                   # [NC, Q]
-        rows = jnp.minimum(start[:, None] + q, T - 1)
-        # a chunk's first state is cut out of the stack where it lies
-        # and its last written back there, one row of 2 MiB at a time:
-        # a gather over the layer would copy the layer first
-        row = (1, 1) + ssm.shape[2:]
-        init = jnp.concatenate([jax.lax.dynamic_slice(
-            ssm, (li, slot[i], 0, 0, 0), row)[0]
-            for i in range(ch.shape[0])])
-        fresh = runs["fresh"][jnp.minimum(slot, S - 1)]
-        # about half of a chat mix's steps hold no run of several tokens:
-        # they skip the chunked form's products (the reads and writes of
-        # the 2 MiB rows around it go to the trash row and stay)
-        shape = (ch.shape[0], Q, dims.heads)
-        y_run, left = jax.lax.cond(
-            jnp.any(n > 0),
-            lambda: M.chunk_scan(
-                x[rows], b[rows], c[rows],
-                jnp.where(there[..., None], dts[rows], 0.0), a, mp["D"],
-                first.astype(bool),
-                jnp.where(fresh[:, None, None, None], 0,
-                          init.astype(jnp.float32)), dims),
-            lambda: (jnp.zeros(shape + (dims.head_dim,), jnp.float32),
-                     jnp.zeros(init.shape, jnp.float32)))
-        # a run's last chunk leaves its state in the slot; the others'
-        # (and the chunks that are not there) go to the trash row
-        to = jnp.where(lastc.astype(bool), slot, S)
-        left = left.astype(ssm.dtype)
-        for i in range(ch.shape[0]):
-            ssm = jax.lax.dynamic_update_slice(
-                ssm, left[i][None, None], (li, to[i], 0, 0, 0))
-        y = jnp.zeros((T,) + y_run.shape[2:], jnp.float32).at[
-            jnp.where(there, rows, T).reshape(-1)].set(
-            y_run.reshape((-1,) + y_run.shape[2:]), mode="drop")
+        if kernel:
+            # ONE kernel over the chunks that are there: their rows by
+            # its own DMAs out of the convolution's result as it lies, a
+            # run's state read from and left in its slot's row of the
+            # stack, no tile of Q x Q x heads in HBM
+            y, ssm = M.chunk_scan_in_place(
+                ssm, li, act, dts, a, mp["D"], ch,
+                runs["fresh"][jnp.minimum(ch[:, 2], S - 1)], dims)
+        else:
+            # (XLA's branch as it stood, operation for operation: the
+            # CPU's lowered step is hashed, tests/test_falcon_h1.py)
+            start, n, slot, first, lastc = (ch[:, i] for i in range(5))
+            Q = dims.chunk
+            q = jnp.arange(Q)[None, :]
+            there = q < n[:, None]                                   # [NC, Q]
+            rows = jnp.minimum(start[:, None] + q, T - 1)
+            # a chunk's first state is cut out of the stack where it lies
+            # and its last written back there, one row of 2 MiB at a time:
+            # a gather over the layer would copy the layer first
+            row = (1, 1) + ssm.shape[2:]
+            init = jnp.concatenate([jax.lax.dynamic_slice(
+                ssm, (li, slot[i], 0, 0, 0), row)[0]
+                for i in range(ch.shape[0])])
+            fresh = runs["fresh"][jnp.minimum(slot, S - 1)]
+            # about half of a chat mix's steps hold no run of several tokens:
+            # they skip the chunked form's products (the reads and writes of
+            # the 2 MiB rows around it go to the trash row and stay)
+            shape = (ch.shape[0], Q, dims.heads)
+            y_run, left = jax.lax.cond(
+                jnp.any(n > 0),
+                lambda: M.chunk_scan(
+                    x[rows], b[rows], c[rows],
+                    jnp.where(there[..., None], dts[rows], 0.0), a, mp["D"],
+                    first.astype(bool),
+                    jnp.where(fresh[:, None, None, None], 0,
+                              init.astype(jnp.float32)), dims),
+                lambda: (jnp.zeros(shape + (dims.head_dim,), jnp.float32),
+                         jnp.zeros(init.shape, jnp.float32)))
+            # a run's last chunk leaves its state in the slot; the others'
+            # (and the chunks that are not there) go to the trash row
+            to = jnp.where(lastc.astype(bool), slot, S)
+            left = left.astype(ssm.dtype)
+            for i in range(ch.shape[0]):
+                ssm = jax.lax.dynamic_update_slice(
+                    ssm, left[i][None, None], (li, to[i], 0, 0, 0))
+            y = jnp.zeros((T,) + y_run.shape[2:], jnp.float32).at[
+                jnp.where(there, rows, T).reshape(-1)].set(
+                y_run.reshape((-1,) + y_run.shape[2:]), mode="drop")
         y = jnp.where(runs["row_one"][:, None, None],
                       y_one[batch.seq_slot], y)
     with jax.named_scope("ssm_out"):

@@ -27,13 +27,20 @@ in front of it a depthwise causal convolution of width ``W`` over the
   device, and XLA's elsewhere;
 * :func:`chunk_scan` (scope ``ssm_scan``): the chunked form (SSD) over a
   list of chunks of ``Q`` tokens, each of one run; a chunk continues the
-  one before it or starts from a given state.
+  one before it or starts from a given state; in XLA, the decay tile of
+  every chunk an array in memory;
+* :func:`chunk_scan_in_place` (the same scope): the chunked form over
+  the chunks a step's table holds as ONE Pallas kernel: a run's rows by
+  its own DMAs, the decay tile in VMEM, the run's state read from and
+  left in its slot's row of the stack, a chunk that is not there a grid
+  step that does nothing; the serving forward takes it where it takes
+  :func:`state_update_in_place`.
 
 ``mixer_forward`` is the whole mixer over whole sequences from a zero
 state (``models/transformer.apply``); the serving forward
 (``inference/model.py``) composes the three itself around the engine's
-state pool.  The convolution and the chunked form are XLA: see PERF.md
-section 6 (PR 42, PR 46) for what the chip said.
+state pool.  The convolution is XLA: see PERF.md section 6 (PR 42,
+PR 46, PR 55) for what the chip said.
 """
 
 from __future__ import annotations
@@ -45,6 +52,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .paged_attention import _start, _wait
 
 F32 = jnp.float32
 
@@ -383,6 +392,283 @@ def chunk_scan(x, b, c, dt, a, d_skip, first, init, dims: SSMDims
     y = y + off + _grouped(d_skip.astype(F32), dims, 0)[..., None] * x32
     return (y.reshape(x.shape),
             left.reshape((NC, dims.heads) + left.shape[3:]))
+
+
+# ------------------------------------------ the chunked form, a kernel
+def slab_heads(dims: SSMDims) -> int:
+    """Heads the chunk kernel takes side by side on a slab's lanes: as
+    many as fill 128 where a head is narrower, halved until a group's
+    heads divide so (a slab's heads share their B and C)."""
+    side = 128 // dims.head_dim if 128 % dims.head_dim == 0 else 1
+    while (dims.heads // dims.groups) % side:
+        side //= 2
+    return side
+
+
+def _column(t, h):
+    """Column ``h`` (traced) of ``t [F, lanes]`` → ``[F, 1]``: the
+    lanes rolled until it is the first (a dynamic lane index is not a
+    thing a load can take)."""
+    lanes = t.shape[1]
+    return pltpu.roll(t, jax.lax.rem(lanes - h, jnp.int32(lanes)), 1)[:, :1]
+
+
+def _chunk_kernel(tab, li, fresh, a_ref, dt_hbm, xbc_hbm, stack_in,
+                  stack_out, y_hbm, dbuf, xbuf, bbuf, cbuf, sbuf, s_ref, ybuf,
+                  la_ref, lat_ref, cb_ref, at, sem, *, dims: SSMDims,
+                  side: int):
+    """Chunk ``c`` of the step's table for a block of heads: the rows of
+    its run that lie in the frames (aligned windows of ``F`` rows) it is
+    the first of the run to touch, a frame at a time, as ``chunk_scan``
+    computes a chunk whose other rows have ``dt = 0``.
+
+    tab: ``[NC * 5]`` (``RecBatch.chunks``), li: ``[1]``, fresh:
+    ``[NC]``, prefetched.  ``*_hbm``: the step's rows where XLA left
+    them: dt ``[T, lanes]`` (the heads on whole lane tiles, zeros past
+    them) and the convolution's ``[T, x | B | C]``; the
+    stack ``[L, S+1, slabs, side * P, N]`` in and (the same buffer) out;
+    y ``[T, H * P]`` float32, of which only the frames that hold a run's
+    rows are written.  A slab: ``side`` heads side by side on the lanes.
+    ``at``: SMEM, the run's first row and the frames the input and the
+    output buffers hold."""
+    jb, c = pl.program_id(0), pl.program_id(1)
+    sl, F, LW = xbuf.shape
+    H, P, G, N = dims.heads, dims.head_dim, dims.groups, dims.state
+    per_group = H // G
+    start, n, slot = tab[5 * c], tab[5 * c + 1], tab[5 * c + 2]
+    first, last = tab[5 * c + 3] != 0, tab[5 * c + 4] != 0
+    blk = pl.ds(jb * sl, sl)
+    f32 = lambda t: t.astype(F32)
+
+    def frame_of(f):
+        return pl.ds(pl.multiple_of(f * F, F), F)
+
+    def lanes_of(j):
+        """Slab ``j`` of this block of heads among a row's channels."""
+        return pl.ds(pl.multiple_of((jb * sl + j) * LW, LW), LW)
+
+    def each_slab(do, copy):
+        jax.lax.fori_loop(0, sl, lambda j, _: do(copy(j)), None)
+
+    def inputs(do, f):
+        """``do`` (start or wait) the copies of frame ``f``'s rows: dt,
+        B and C by group, x a slab at a time (the relayout is the DMA's:
+        a slab is then an index, not a lane offset)."""
+        rows = frame_of(f)
+        do(pltpu.make_async_copy(dt_hbm.at[rows], dbuf, sem.at[0]))
+        for g in range(G):
+            for k, buf in enumerate((bbuf, cbuf)):
+                do(pltpu.make_async_copy(
+                    xbc_hbm.at[rows, pl.ds(H * P + (k * G + g) * N, N)],
+                    buf.at[g], sem.at[0]))
+        each_slab(do, lambda j: pltpu.make_async_copy(
+            xbc_hbm.at[rows, lanes_of(j)], xbuf.at[j], sem.at[0]))
+
+    def flush():
+        """The output frame the buffer holds, to where it lies."""
+        for do in (_start, _wait):
+            each_slab(do, lambda j: pltpu.make_async_copy(
+                ybuf.at[j], y_hbm.at[frame_of(at[2]), lanes_of(j)],
+                sem.at[1]))
+
+    @pl.when(c == 0)
+    def _():
+        at[1] = -1
+        at[2] = -1
+
+    def piece(f, lo, hi):
+        """The run's rows ``lo .. hi`` of frame ``f`` (counted from the
+        frame's first): the state in ``s_ref`` read, advanced, left."""
+        @pl.when(at[1] != f)
+        def _():
+            inputs(_start, f)
+            inputs(_wait, f)
+            at[1] = f
+
+        @pl.when(at[2] != f)
+        def _():
+            pl.when(at[2] >= 0)(flush)
+            at[2] = f
+
+        q = jax.lax.broadcasted_iota(jnp.int32, (F, 1), 0)
+        there = (q >= lo) & (q < hi)                            # [F, 1]
+        causal = q >= jax.lax.broadcasted_iota(jnp.int32, (1, F), 1)
+        # the running log decay down the frame, as ``chunk_scan`` takes
+        # it: a product with a triangle of ones in full float32
+        la = jnp.dot(causal.astype(F32),
+                     jnp.where(there, dbuf[...], 0.0) * a_ref[...],
+                     precision=jax.lax.Precision.HIGHEST,
+                     preferred_element_type=F32)               # [F, lanes]
+        la_ref[...] = la
+        lat_ref[...] = la.T
+        for g in range(G):      # C B^T once a group: its heads share it
+            cb_ref[g] = jax.lax.dot_general(
+                cbuf[g], bbuf[g], (((1,), (1,)), ((), ())),
+                preferred_element_type=F32)
+
+        def slab(j, _):
+            h0 = (jb * sl + j) * side
+            g = jax.lax.div(h0, jnp.int32(per_group))
+            xs, s_in = f32(xbuf[j]), s_ref[j]                  # [F, LW]
+            # what the state the frame starts from adds to every row
+            off = jax.lax.dot_general(
+                f32(cbuf[g]), s_in, (((1,), (1,)), ((), ())),
+                preferred_element_type=F32)                     # [F, LW]
+            ys, ws, tots = [], [], []
+            for i in range(side):
+                col = _column(la_ref[...], h0 + i)              # [F, 1]
+                row = lat_ref[pl.ds(h0 + i, 1), :]              # [1, F]
+                # the frame's last, down a head's rows of the state
+                # (spread over the sublanes before the roll: a [1, 1]
+                # does not broadcast both ways)
+                ends = _column(jnp.broadcast_to(
+                    la_ref[F - 1:, :], (P, la_ref.shape[1])), h0 + i)
+                dtx = jnp.where(there, xs[:, i * P:(i + 1) * P]
+                                * _column(dbuf[...], h0 + i), 0.0)   # [F, P]
+                # inside the frame: row q reads row r <= q under
+                # exp(la_q - la_r), a tile that never leaves VMEM
+                decay = jnp.exp(jnp.where(causal, col - row, -jnp.inf))
+                ys.append(jnp.dot(cb_ref[g] * decay, dtx,
+                                  preferred_element_type=F32)
+                          + off[:, i * P:(i + 1) * P] * jnp.exp(col))
+                ws.append(dtx * jnp.exp(ends[:1] - col))
+                tots.append(jnp.exp(ends))
+            cat = (lambda p, axis: p[0] if side == 1
+                   else jnp.concatenate(p, axis=axis))
+            # what the frame's own rows leave at its end
+            local = jnp.dot(cat(ws, 1).T, f32(bbuf[g]),
+                            preferred_element_type=F32)         # [LW, N]
+            s_ref[j] = cat(tots, 0) * s_in + local
+            # (a row of another run, or of none, keeps what it holds)
+            ybuf[j] = jnp.where(there, cat(ys, 1), ybuf[j])
+
+        jax.lax.fori_loop(0, sl, slab, None)
+
+    @pl.when(n > 0)
+    def _():
+        @pl.when(first)
+        def _():
+            at[0] = start
+            cp = pltpu.make_async_copy(stack_in.at[li[0], slot, blk], sbuf,
+                                       sem.at[2])
+            cp.start()
+            cp.wait()
+            zero = fresh[c] != 0        # whatever the slot held
+
+            def take(j, _):
+                s_ref[j] = jnp.where(zero, 0.0, f32(sbuf[j]))
+
+            jax.lax.fori_loop(0, sl, take, None)
+
+        # the run ends where its last chunk does
+        e = jax.lax.while_loop(lambda i: tab[5 * i + 4] == 0,
+                               lambda i: i + 1, c)
+        run = (at[0], tab[5 * e] + tab[5 * e + 1])
+        f0 = jax.lax.div(start, jnp.int32(F))
+        f1 = jax.lax.div(start + n - 1, jnp.int32(F))
+
+        def frame(k, _):
+            # the chunk before this one took the frame it ended in
+            new = jax.lax.select(
+                k == 0, first | (jax.lax.rem(start, jnp.int32(F)) == 0),
+                f1 != f0)
+            f = f0 + k
+            pl.when(new)(lambda: piece(
+                f, jax.lax.max(run[0] - f * F, jnp.int32(0)),
+                jax.lax.min(run[1] - f * F, jnp.int32(F))))
+
+        jax.lax.fori_loop(0, 2, frame, None)
+
+        @pl.when(last)
+        def _():
+            def give(j, _):
+                sbuf[j] = s_ref[j].astype(sbuf.dtype)   # rounded once
+
+            jax.lax.fori_loop(0, sl, give, None)
+            cp = pltpu.make_async_copy(
+                sbuf, stack_out.at[li[0], slot, blk], sem.at[2])
+            cp.start()
+            cp.wait()
+
+    @pl.when((c == pl.num_programs(1) - 1) & (at[2] >= 0))
+    def _():
+        flush()
+
+
+def chunk_scan_in_place(stack, li, xbc, dt, a, d_skip, chunks, fresh,
+                        dims: SSMDims, hb=None):
+    """The chunked form over the chunks a step holds as ONE Pallas
+    kernel, a run's state read from and left in its slot's row of layer
+    ``li`` of ``stack [L, S+1, H, P, N]`` in place.
+
+    xbc: [T, conv_channels], the convolution's x, B and C of the step's
+    flat rows as it leaves them (``split_xbc``); dt: [T, H] (after
+    softplus); chunks: [NC, 5] (``RecBatch.chunks``: first row, rows,
+    slot, first of its run, last of its run); fresh: [NC], the chunk's
+    run starts from zeros whatever its slot holds.  A chunk of no rows
+    costs a grid step that does nothing: no DMA, no product.  The
+    mathematics are ``chunk_scan``'s term for term; no array of ``Q x Q
+    x heads`` exists outside VMEM, and XLA lays nothing out for the
+    kernel but a ``dt`` of fewer heads than a lane tile, padded to one.
+    → (y [T, H, P] float32, zeros in the rows no chunk holds; the
+    stack)."""
+    T, (H, P) = xbc.shape[0], (dims.heads, dims.head_dim)
+    G, N = dims.groups, dims.state
+    side = slab_heads(dims)
+    hb = hb or heads_per_step(stack)
+    LW, sl = side * P, hb // side
+    F = min(dims.chunk, T)
+    lanes = -(-H // 128) * 128
+    pad = -T % F    # (no rung of a served step: a frame divides those)
+    if pad:
+        xbc = jnp.pad(xbc, ((0, pad), (0, 0)))
+    # (a copy's rows are whole lane tiles: Falcon-H1's 32 heads are
+    # padded to 128, granite's 128 are as they are)
+    if pad or lanes - H:
+        dt = jnp.pad(dt, ((0, pad), (0, lanes - H)))
+    scalars = (chunks.astype(jnp.int32).reshape(-1),
+               jnp.asarray(li, jnp.int32).reshape(1),
+               fresh.astype(jnp.int32))
+    slabs = stack.reshape(stack.shape[:2] + (H // side, LW, N))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    slabs, y = pl.pallas_call(
+        functools.partial(_chunk_kernel, dims=dims, side=side),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=(H // hb, chunks.shape[0]),
+            in_specs=[pl.BlockSpec((1, lanes), lambda *_: (0, 0))]
+            + [hbm] * 3,
+            out_specs=[hbm, hbm],
+            scratch_shapes=[
+                pltpu.VMEM((F, lanes), F32),             # dt
+                pltpu.VMEM((sl, F, LW), xbc.dtype),      # x by slab
+                pltpu.VMEM((G, F, N), xbc.dtype),        # B
+                pltpu.VMEM((G, F, N), xbc.dtype),        # C
+                pltpu.VMEM((sl, LW, N), stack.dtype),    # a row, as stored
+                pltpu.VMEM((sl, LW, N), F32),            # the run's state
+                pltpu.VMEM((sl, F, LW), F32),            # y by slab
+                pltpu.VMEM((F, lanes), F32),             # la, and across
+                pltpu.VMEM((lanes, F), F32),
+                pltpu.VMEM((G, F, F), F32),              # C B^T
+                pltpu.SMEM((3,), jnp.int32),
+                pltpu.SemaphoreType.DMA((3,))],
+        ),
+        out_shape=[jax.ShapeDtypeStruct(slabs.shape, slabs.dtype),
+                   jax.ShapeDtypeStruct((T + pad, H * P), F32)],
+        input_output_aliases={len(scalars) + 3: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=jax.default_backend() != "tpu",
+        name="ssm_chunk_scan",
+    )(*scalars, jnp.pad(a.astype(F32), (0, lanes - H))[None],
+      dt.astype(F32), xbc, slabs)
+    start, n = chunks[:, 0], chunks[:, 1]
+    r = jnp.arange(T)[:, None]
+    held = ((r >= start) & (r < start + n)).any(1)[:, None, None]
+    y = y[:T].reshape(T, H, P) + d_skip.astype(F32)[:, None] \
+        * xbc[:T, :H * P].reshape(T, H, P).astype(F32)
+    return jnp.where(held, y, 0.0), slabs.reshape(stack.shape)
 
 
 def gated_norm(y, z, scale, dims: SSMDims, eps: float):

@@ -9,6 +9,7 @@ transient failure's re-queue; what the engine refuses for such a model;
 every wrong forward the reference knows; the older configurations'
 programs."""
 
+import contextlib
 import hashlib
 import json
 import os
@@ -82,6 +83,19 @@ def engine(tiny, **over):
     kw.update(over)
     return InferenceEngine(Model.from_params(cfg, params, param_axes=axes),
                            InferenceConfig(**kw))
+
+
+@contextlib.contextmanager
+def state_kernels():
+    """A step traced in here takes the recurrent mixers' Pallas kernels
+    (the one-token update's and the chunked form's) as it does on a TPU;
+    off one they run interpreted."""
+    from deepspeed_tpu.inference import model
+    real = model._ssm_mixer
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(model, "_ssm_mixer",
+                   lambda *a, kernel=False, **k: real(*a, kernel=True, **k))
+        yield
 
 
 def paged_logits(eng, seqs, n_prompt):
@@ -171,11 +185,14 @@ def seqs(tiny):
 @pytest.fixture(scope="module")
 def system_rows(tiny, seqs):
     with jax.default_matmul_precision("highest"):
-        return {impl: paged_logits(engine(tiny, attn_impl=impl), *seqs)
+        rows = {impl: paged_logits(engine(tiny, attn_impl=impl), *seqs)
                 for impl in ("xla", "pallas")}
+        with state_kernels():
+            rows["state kernels"] = paged_logits(engine(tiny), *seqs)
+    return rows
 
 
-@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("impl", ["xla", "pallas", "state kernels"])
 def test_chunked_prefill_and_decode_agree_with_the_reference(
         tiny, ref, seqs, system_rows, impl):
     cfg, params, _ = tiny
@@ -390,16 +407,20 @@ def test_stage_span_counts_and_gauges(tiny):
     # its state by one; uid 2 takes what the budget leaves
     assert (stage["state_rows"], stage["scan_tokens"],
             stage["state_starts"], stage["state_replays"]) == (1, 31, 3, 0)
+    # the chunks of the table that hold rows: 9 and 22 tokens by eights
+    assert stage["scan_chunks"] == 2 + 3
     for u, t in out.items():
         eng.put(u, [t])
     eng.step(sampling=GREEDY)
     stage = [e["args"] for e in eng.tracer.events()
              if e["name"] == "ds.serve.stage"][-1]
     assert (stage["state_rows"], stage["scan_tokens"],
-            stage["state_starts"]) == (2, 18, 0)
+            stage["state_starts"], stage["scan_chunks"]) == (2, 18, 0, 3)
     snap = eng.metrics.snapshot()
     upd = snap["serving_state_updates_total"]
     assert upd['{kind="decode"}'] == 3 and upd['{kind="scan"}'] == 49
+    assert snap["serving_scan_chunks_total"] == 8
+    assert snap["serving_scan_chunk_fill"] == 49 / (8 * 8)
     assert snap["serving_state_slots_in_use"] == 3
     assert snap["serving_state_bytes"] \
         == 3 * eng._recurrent.bytes_per_seq(4)

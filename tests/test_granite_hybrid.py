@@ -16,7 +16,7 @@ import pytest
 from deepspeed_tpu.inference import InferenceConfig, InferenceEngine
 from deepspeed_tpu.models.presets import build_config
 from deepspeed_tpu.models.transformer import Model
-from test_falcon_h1 import GREEDY, TOL, paged_logits, rel
+from test_falcon_h1 import GREEDY, TOL, paged_logits, rel, state_kernels
 from test_granite_reference import ref, ref_config, tiny  # noqa: F401
 
 
@@ -56,17 +56,19 @@ def engines(tiny):
 
 
 @pytest.fixture(scope="module")
-def system_rows(engines, seqs):
+def system_rows(tiny, engines, seqs):
     with jax.default_matmul_precision("highest"):
         out = {impl: paged_logits(eng, *seqs)
                for impl, eng in engines.items()}
+        with state_kernels():   # an engine of its own: another program
+            out["state kernels"] = paged_logits(engine(tiny), *seqs)
     for eng in engines.values():
         for u in seqs[0]:
             eng.flush(u)
     return out
 
 
-@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("impl", ["xla", "pallas", "state kernels"])
 def test_prefill_and_decode_agree_with_the_reference(tiny, ref, seqs,
                                                      system_rows, impl):
     """Prefill in one step (uid 3), cut over steps and chunks (uids 1, 2),

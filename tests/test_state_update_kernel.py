@@ -175,6 +175,29 @@ def test_the_backend_chooses_the_update_s_path(preset, scope, monkeypatch):
                          for c in calls)
 
 
+@pytest.mark.parametrize("preset", ["falcon-h1-tiny", "granite-h-tiny"])
+def test_the_backend_chooses_the_chunked_form_s_path(preset, monkeypatch):
+    """The runs of several tokens likewise: off the TPU XLA's
+    ``chunk_scan`` around the state rows cut out of the stack, no kernel
+    under ``ssm_scan``; on one, ONE kernel a place the layers are
+    written out, over the stack in place."""
+    from deepspeed_tpu.models.presets import build_config
+    from deepspeed_tpu.models.transformer import init_params
+
+    cfg = build_config(preset)
+    params, axes = init_params(cfg, jax.random.PRNGKey(3))
+    assert _update_calls(falcon_engine((cfg, params, axes)), "ssm_scan") == []
+    eng = falcon_engine((cfg, params, axes))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    calls = _update_calls(eng, "ssm_scan")
+    assert calls and all(
+        c.params["name"] == "ssm_chunk_scan"
+        and c.params["input_output_aliases"] == ((6, 0),) for c in calls)
+    # and no more of them than the one-token update's
+    assert len(calls) == len(_update_calls(
+        falcon_engine((cfg, params, axes)), "ssm_update"))
+
+
 def test_state_update_fill_gauge():
     from deepspeed_tpu.models.presets import build_config
     from deepspeed_tpu.models.transformer import init_params

@@ -343,7 +343,7 @@ def _pstep_compiled(one_chip, cfg, kv_quant, T, seqs, bs, mbs, blocks):
     kv = jax.tree.map(
         lambda a: S(a.shape, a.dtype),
         jax.eval_shape(KVCacheConfig(
-            cfg.layers_of("mla") if latent else cfg.num_layers,
+            cfg.block_layers,
             cfg.num_kv_heads, cfg.head_dim, block_size=bs,
             num_blocks=blocks, quant="int8" if kv_quant else "none",
             latent_dim=cfg.mla_dims.row if latent else 0,
@@ -360,7 +360,9 @@ def _pstep_compiled(one_chip, cfg, kv_quant, T, seqs, bs, mbs, blocks):
             state = (sd.heads, sd.key_dim, sd.value_dim)
         else:
             sd = cfg.ssm_dims
-            rows = (cfg.num_layers, seqs + 1)
+            # (a "mamba" layer holds a state and no blocks; a "hybrid"
+            # layer both, and every layer is one)
+            rows = (cfg.layers_of("mamba") or cfg.num_layers, seqs + 1)
             state = (sd.heads, sd.head_dim, sd.state)
         # (a delta-rule state is stored in float32: the engine's rule)
         kv = {"kv": kv,
@@ -453,25 +455,61 @@ def test_recurrent_serving_step_compiles_fits_and_keeps_its_pools_in_place(
     compiled, layer_bytes = _pstep_compiled(
         one_chip, cfg, False, T=512, seqs=128, bs=64, mbs=16, blocks=1536)
     text = compiled.as_text()
-    # the paged kernel's two and the state update's one
-    assert text.count("tpu_custom_call") == 3
+    # the paged kernel's two, the state update's one, the chunked form's
+    assert text.count("tpu_custom_call") == 4
     assert len(re.findall(r"%ssm_state_update[\w.]* = ", text)) == 1
+    assert len(re.findall(r"%ssm_chunk_scan[\w.]* = ", text)) == 1
     sd = cfg.ssm_dims
     state_layer = 129 * sd.heads * sd.head_dim * sd.state * 2
     assert layer_bytes == 1537 * 64 * 2 * 4 * 128 * 2
     # all the temporaries together stay under one layer's share of
     # either pool (103 MB, 34 MB of them the update kernel's vectors a
-    # slot, laid out for its tiles): the one-token update reads and
-    # writes the state rows where they lie (the kernel's stack is
-    # aliased to its result), and a chunk's first and last state are cut
-    # out and written back a 2 MiB row at a time (a gather over the
-    # layer made a 270 MB copy of it)
+    # slot, laid out for its tiles): the one-token update and the
+    # chunked form read and write the state rows where they lie (each
+    # kernel's stack is aliased to its result; XLA's chunked form cut a
+    # chunk's first state out and wrote its last back a 2 MiB row at a
+    # time, and a gather over the layer made a 270 MB copy of it)
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < min(layer_bytes, state_layer)
     moved = [m for m in _moves_of(text, state_layer)
              if "dynamic-update-slice" not in m and "fusion" not in m]
     assert moved == [], moved
     assert mem.argument_size_in_bytes > 13.0e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+def test_one_cache_a_layer_serving_step_holds_no_decay_tensor(one_chip,
+                                                              on_chip):
+    """The serving step of ``granite-4.0-h-small-d10`` as the benchmark
+    runs it (nine Mamba-2 layers of 128 heads, chunk 256, one attention
+    layer, 36 of 72 experts; 64 sequences) at its 512-row rung: the chunked
+    form is ONE kernel a place the layers are written out, and no
+    float32 array of ``chunks x 256 x 256 x 128`` elements (XLA's decay
+    and scores, 201 MB each at six chunks) or of a chunk's rows by head
+    (``[chunks, 256, 128, 64]``, three relayouts a layer) is left in the
+    program; its temporaries are printed (the parent's 512-row step:
+    243.3 MB, PERF.md section 6, PR 53)."""
+    from deepspeed_tpu.models.presets import build_config
+
+    rows = 512
+    cfg = build_config("granite-4.0-h-small", num_layers=10,
+                       experts_held=(0, 36), max_seq_len=9216)
+    compiled, _ = _pstep_compiled(
+        one_chip, cfg, False, T=rows, seqs=64, bs=64, mbs=144, blocks=9216)
+    text = compiled.as_text()
+    kernels = re.findall(r"%(ssm_chunk_scan[\w.]*) = ", text)
+    updates = re.findall(r"%(ssm_state_update[\w.]*) = ", text)
+    assert kernels and len(kernels) == len(updates)
+    sd = cfg.ssm_dims
+    chunks = -(-rows // sd.chunk) + 4
+    for dims in re.findall(r"f32\[([\d,]+)\]", text):
+        shape = [int(d) for d in dims.split(",")]
+        assert math.prod(shape) < chunks * sd.chunk * sd.heads * sd.head_dim, \
+            shape
+    mem = compiled.memory_analysis()
+    print(f"granite-4.0-h-small-d10, {rows} rows: temporaries "
+          f"{mem.temp_size_in_bytes / 1e6:.1f} MB, arguments "
+          f"{mem.argument_size_in_bytes / 1e9:.2f} GB")
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
 
 
