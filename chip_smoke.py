@@ -1457,6 +1457,119 @@ def serve_longcat_phase(sz, seed):
           "forward under the limit that would have to tell it")
 
 
+def serve_deepseek_v2_phase(sz, seed):
+    """The cell serve-mla-shared-docs' model (benchmarks/configs/
+    deepseek-v2-d5.json, at its published widths: latent attention at 128
+    heads under YaRN in a dense layer and four expert layers, 40 of 160
+    experts in two of eight device groups behind the group-limited greedy
+    router, the latent pool and its prefix cache) through the engine's
+    paged path against the benchmark's plain reference that follows the
+    engine's routing, by the cell's own comparisons
+    (benchmarks/lib/drivers/serve_latent_groups.py: a long prompt past
+    YaRN's original context, a second sequence onto its indexed blocks)
+    and under the file's own limits.  Then the same logits against every
+    wrong forward the reference knows, each of which has to FAIL a limit
+    the true forward passes."""
+    import gc
+
+    import jax.numpy as jnp
+
+    from benchmarks.lib import common
+    from benchmarks.lib import traffic as T
+    from benchmarks.lib.drivers import serve_latent_groups as D
+    from benchmarks.lib.drivers.serve_hybrid_share import routing_step
+    from benchmarks.lib.weights import make_model
+    from deepspeed_tpu.inference import InferenceConfig, InferenceEngine
+
+    _, _, config, mix = common.load_cell("serve-mla-shared-docs")
+    if sz is TINY:
+        common.apply_rehearsal(config, mix)
+    cfg = D.preset_config(config)
+    model = make_model(cfg, seed + 7, dtype=jnp.bfloat16)
+    ref = common.load_module(
+        os.path.join(common.ROOT, config["reference"]["file"]), "dsv2_ref")
+    tol = config["reference"]["tolerance"]
+    limit, short_limit = tol["followed_rel"], tol["routing_short"]
+    sample = config["reference"]["sample"]
+    k = int(sample["decode_tokens"])
+    md = cfg.mla_dims
+    print(f"  {config['name']}: d{cfg.d_model}, {cfg.num_layers} layers "
+          f"({cfg.num_dense_layers} dense), MLA {cfg.num_heads} heads over "
+          f"rows of {md.row}, query latent {md.q_rank}, softmax scale "
+          f"x{md.score_scale:.5g} ({cfg.rope_yarn}), experts "
+          f"{cfg.experts_held} of {cfg.num_experts} (groups "
+          f"{cfg.held_groups} of {cfg.moe_groups}, {cfg.moe_groups_kept} "
+          f"open by {cfg.moe_group_score}), top-{cfg.moe_top_k}, bf16, "
+          f"limit {limit} with the routing followed, {short_limit} on a "
+          "taken expert's score")
+    rng = T.rng_for(seed + 7, 9)
+    seqs = {900000 + i: rng.integers(0, cfg.vocab_size, n + k).tolist()
+            for i, n in enumerate(sample["prompt_lens"])}
+    n_prompt = {u: len(s) - k for u, s in seqs.items()}
+    sizes = mix["engine"]
+    block = int(sizes["kv_block_size"])
+    # the cell's engine over a pool ONE block larger than the long prompt
+    # and its fed tokens take: the long prompt's own blocks push out what
+    # the samples left indexed, and the second sequence, admitted onto
+    # the long prompt's indexed blocks, takes the blocks of its own
+    # tokens from indexed ones (an aliased hit while the pool evicts)
+    pool = -(-(int(sample["long_prompt"]) + k) // block) + 1
+    eng = InferenceEngine(model, InferenceConfig(
+        token_budget=int(sizes["token_budget"]),
+        max_seqs=int(sizes["max_seqs"]),
+        kv_block_size=block, num_kv_blocks=pool,
+        max_seq_len=int(sizes["max_seq_len"]),
+        **config.get("engine_options", {})))
+    budget = eng.icfg.token_budget
+    evicted = {}
+    named, prompts, true_system = D.system_side(
+        eng, routing_step(eng), config, seqs, n_prompt, seed + 7,
+        ready=lambda name, *_: evicted.update(
+            {name: eng.state.prefix_evictions}))
+    del eng
+    gc.collect()
+    before, after = evicted["chunked"], evicted["shared"]
+    print(f"    long prompt: {prompts['chunked']} tokens in steps of "
+          f"{budget}, then {k} fed: {true_system['chunked'][2]} steps; the "
+          f"second sequence onto {sample['shared_prefix_tokens']} of its "
+          f"tokens: {true_system['shared'][2]} steps; a pool of {pool} "
+          f"blocks: {before} indexed blocks taken back before the second "
+          f"sequence, {after - before} while it ran")
+    check(after > before, "the second sequence took no indexed block "
+          "back: it was not admitted into a pool that was evicting")
+
+    def line(got):
+        return ", ".join(f"{n} {v:.4g}" for n, v in got.items())
+
+    def failing(got):
+        return [n for n, v in got.items()
+                if v > (short_limit if n == "routing_shortfall" else limit)]
+
+    true = D.readings(ref, model.params, config, named, prompts, true_system,
+                      budget)
+    print("    true forward: " + line(true))
+    # every wrong forward against one sample, the long prompt and the
+    # sequence on its blocks; all are read before any is judged (the
+    # rehearsal proves the control flow on two of them)
+    few = [n for n in named if not n.endswith(("1", "2"))]
+    agree = []
+    for wrong in ref.WRONG[:None if sz is REAL else 2]:
+        got = D.readings(ref, model.params, config,
+                         {n: named[n] for n in few}, prompts,
+                         {n: true_system[n] for n in few}, budget,
+                         wrong=wrong)
+        fails = failing(got)
+        print(f"    reference with {wrong}: " + line(got)
+              + (f"  (fails {fails})" if fails else "  (PASSES)"),
+              flush=True)
+        if not fails:
+            agree.append(wrong)
+    check(not failing(true), f"the engine differs from the reference "
+          f"that follows its routing: {failing(true)} of {true}")
+    check(sz is TINY or not agree, f"{agree} agree(s) with the true "
+          "forward under the limit that would have to tell it")
+
+
 # --------------------------------------------------------------------------
 # four chips: the sharded paths and what they are compared with
 # --------------------------------------------------------------------------
@@ -1740,7 +1853,9 @@ def main(argv=None) -> int:
              lambda: serve_falcon_h1_phase(sz, args.seed)),
             ("serve-ling", lambda: serve_ling_phase(sz, args.seed)),
             ("serve-longcat", lambda: serve_longcat_phase(sz, args.seed)),
-            ("serve-granite", lambda: serve_granite_phase(sz, args.seed)))
+            ("serve-granite", lambda: serve_granite_phase(sz, args.seed)),
+            ("serve-deepseek-v2",
+             lambda: serve_deepseek_v2_phase(sz, args.seed)))
         if args.only and args.only not in dict(one_chip):
             ap.error(f"--only {args.only!r}: no such phase; have "
                      f"{[n for n, _ in one_chip]}")

@@ -545,6 +545,8 @@ class InferenceEngine:
         self._inflight_sched: Dict[int, int] = {} # uid -> uncollected steps
         self._preempting: set = set()             # release() = preemption
         self._round_preemptions = 0     # evictions of the last schedule
+        self._round_cached = 0          # prompt tokens it aliased
+        self._evictions_seen = 0        # state.prefix_evictions, last stage
         self._preempt_gen: Dict[int, List[int]] = {}  # pre-eviction tokens
         # tpulint: live-set — uid -> staged terminal status
         self._closing: Dict[int, str] = {}
@@ -1113,16 +1115,19 @@ class InferenceEngine:
         of KV blocks its short call walks in one such layer
         (``ops/paged_attention.group_steps``).  A model whose cache
         is a latent pool: ``latent_tokens``, that sum for ONE latent layer,
-        and ``latent_pairs``, the (query, cached row) pairs its causal
+        ``latent_tokens_one``, its part in the one-token runs (the
+        kernel's call that is bound by the rows' bytes), and
+        ``latent_pairs``, the (query, cached row) pairs its causal
         attention holds (``n * seen + n (n + 1) / 2`` a run of n rows)."""
         w = self._window
-        full = window = pairs = 0
+        full = window = pairs = one = 0
         short = []
         for uid, toks in sched:
             seq = self.state.seqs.get(uid)
             seen = seq.seen_tokens if seq else 0
             ctx = seen + len(toks)
             full += ctx
+            one += ctx if len(toks) == 1 else 0
             pairs += len(toks) * seen + len(toks) * (len(toks) + 1) // 2
             if w:
                 window += min(ctx, w + len(toks) - 1)
@@ -1130,7 +1135,8 @@ class InferenceEngine:
                 short.append((seen, len(toks)))
         if self.state.cfg.latent_dim:
             self._c_attn_kv.inc(full, kind="latent")
-            return {"latent_tokens": full, "latent_pairs": pairs}
+            return {"latent_tokens": full, "latent_tokens_one": one,
+                    "latent_pairs": pairs}
         args = {"kv_tokens_full": full}
         self._c_attn_kv.inc(full, kind="full")
         if w:
@@ -2258,6 +2264,7 @@ class InferenceEngine:
         preempts_left = (ocfg.max_preemptions_per_step
                          if ocfg.preemption else 0)
         self._round_preemptions = 0     # the stage span's ``preemptions``
+        self._round_cached = 0          # and its ``cached_tokens``
         # a model with recurrent layers: the runs of several tokens a
         # step may hold (its chunk table is of fixed size)
         run_cut = self.state.cfg.runs
@@ -2354,6 +2361,7 @@ class InferenceEngine:
             if cached:
                 tm["cached_tokens"] += cached
                 tm["prefix_hits"] += 1
+                self._round_cached += cached
             if prompt_len or cached:
                 # lifecycle admission — SAME statement block as the
                 # engine counters above, so per-request token sums
@@ -3498,6 +3506,15 @@ class InferenceEngine:
                 deferred_from={u: self._fb_step[u] for u, t in sched
                                if t[0] == FEEDBACK_TOKEN
                                and u in self._cont} or None))
+        if self.state.prefix_cache:
+            # the prompt rows this step's admissions were spared by
+            # aliasing indexed blocks, and the indexed blocks reclaimed
+            # for new content since the last staged step (the batch's
+            # allocations just made among them)
+            evicted = self.state.prefix_evictions
+            tr.phase_set(cached_tokens=self._round_cached,
+                         prefix_evictions=evicted - self._evictions_seen)
+            self._evictions_seen = evicted
         # device-order bracket: demote reads of just-evicted blocks must
         # enqueue before ANY write that may reuse them (COW copies,
         # restage uploads, the step itself) — stream ordering then makes
@@ -3880,10 +3897,15 @@ class InferenceEngine:
         if self._moe_metrics is not None:
             # the routing statistics rode the tokens' own readback
             rows = moe_stat_rows(self.cfg)
-            n, load, touched, *nothing = toks_np[-rows:].reshape(
+            n, load, touched, *more = toks_np[-rows:].reshape(
                 rows, -1)[:, 0]
             moe = {"moe_assignments": int(n), "moe_load": load / 1e3,
                    "moe_experts_touched": int(touched)}
+            if self.cfg.held_groups is not None:
+                # of the step's rows (a row a layer), those that opened
+                # a device group held here
+                moe["moe_groups_open_here"] = int(more.pop())
+            nothing = more
             if self.cfg.experts_held is None and not nothing:
                 self._moe_metrics[0].inc(moe["moe_assignments"])
             else:

@@ -43,9 +43,11 @@ MOE_STAT_ROWS = 3
 
 
 def moe_stat_rows(cfg: TransformerConfig) -> int:
-    """``MOE_STAT_ROWS``, and one more for a model with experts that
-    compute nothing: the assignments to them."""
-    return MOE_STAT_ROWS + bool(cfg.moe_zero_experts)
+    """``MOE_STAT_ROWS``, one more for a model with experts that compute
+    nothing (the assignments to them), and one for a share that holds
+    whole device groups (the rows that opened one of them)."""
+    return MOE_STAT_ROWS + bool(cfg.moe_zero_experts) \
+        + (cfg.held_groups is not None)
 
 _KV_QMAX = {jnp.dtype(jnp.int8): 127.0,
             jnp.dtype(jnp.float8_e4m3fn): 448.0}
@@ -908,7 +910,8 @@ def _ffn(cfg, lp, h, dt, act, comm: Optional[ServingComm] = None,
             norm_topk=cfg.moe_norm_topk, layer=layer,
             kernel=jax.default_backend() == "tpu" and not sharded,
             score=cfg.moe_score, route_scale=cfg.moe_route_scale,
-            with_ids=routing, **moe_share(cfg))
+            with_ids=routing, held_groups=cfg.held_groups,
+            **moe_share(cfg))
         stats = tuple(stats) if routing else stats[0]
         if "shared" in lp:       # the dense expert every token takes
             with jax.named_scope("moe_shared"):
@@ -1056,7 +1059,8 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
                 cfg.num_kv_heads, head_groups,
                 (_kv_parts(kv)[0].shape[-2], 1)).reshape(-1)
     elif cfg.position == "rope":
-        cos, sin = L.rope_freqs(cfg.rotary_dim, cfg.max_seq_len, cfg.rope_theta)
+        cos, sin = L.rope_freqs(cfg.rotary_dim, cfg.max_seq_len,
+                                cfg.rope_theta, cfg.rope_yarn)
 
     def layer_weights(ws):
         """One layer's weights from the scanned inputs: ``(lp, li)``,
@@ -1317,7 +1321,7 @@ def ragged_forward(cfg: TransformerConfig, params, kv, batch: RaggedBatch,
         logits = _unembed(cfg, params, embed_tab, x, batch, norm, dt, comm)
     out = (logits, new_kv)
     if with_moe_stats or with_routing:
-        if P > 1:
+        if P > 1 or cfg.mixer_stacks:
             # the scan's [periods, P, ...] and the tail's -> [layers, ...]
             stats = jax.tree.map(
                 lambda a, *tail: jnp.concatenate(

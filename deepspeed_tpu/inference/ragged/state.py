@@ -499,6 +499,8 @@ class StateManager:
         # the reverse map the eviction callback uses
         self._hash_index: Dict[bytes, int] = {}
         self._block_hash: Dict[int, bytes] = {}
+        # indexed blocks the allocator has reclaimed for new content
+        self.prefix_evictions = 0
         # copy-on-write copies queued by match_prefix: (uid, src, dst).
         # The ENGINE drains these with a device block copy before the
         # next step dispatch (the scheduler itself never touches the
@@ -590,6 +592,7 @@ class StateManager:
         h = self._block_hash.pop(block, None)
         meta = self._block_meta.pop(block, None)
         if h is not None:
+            self.prefix_evictions += 1
             self._hash_index.pop(h, None)
             if self.tier is not None and meta is not None:
                 self.tier_pending_demote.append(

@@ -14,7 +14,7 @@ metadata and XLA places the collectives.
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -126,11 +126,68 @@ def make_alibi_attention(base=None, head_offset=None,
     return attn
 
 
-def rope_freqs(head_dim: int, max_seq: int, theta: float = 10000.0):
-    inv = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
-                           / head_dim))
+class Yarn(NamedTuple):
+    """YaRN's scaling of the rotary embedding, a config's
+    ``rope_scaling`` block of ``type`` ``yarn`` (DeepSeek-V2): a model
+    trained over ``original`` positions served over ``factor`` times as
+    many.  The frequencies that turn more than ``beta_fast`` times in
+    the original context stay, those that turn fewer than ``beta_slow``
+    times are divided by ``factor``, and the ones between are blended by
+    a linear ramp over the pair's index.  ``m(t) = 0.1 t ln(factor) +
+    1``: cos and sin are multiplied by ``m(mscale) / m(mscale_all_dim)``
+    and the softmax scale of the attention by ``m(mscale_all_dim)^2``
+    (``score_scale``; a ``mscale_all_dim`` of 0 multiplies nothing)."""
+    factor: float
+    original: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    def _m(self, t: float) -> float:
+        return 0.1 * t * math.log(self.factor) + 1.0 \
+            if self.factor > 1 else 1.0
+
+    @property
+    def table_scale(self) -> float:
+        return self._m(self.mscale) / self._m(self.mscale_all_dim)
+
+    @property
+    def score_scale(self) -> float:
+        return self._m(self.mscale_all_dim) ** 2 \
+            if self.mscale_all_dim else 1.0
+
+    def ramp_ends(self, dim: int, theta: float):
+        """(low, high): the pairs' indices between which the ramp
+        rises from 0 (the pair keeps its frequency) to 1 (divided by
+        ``factor``)."""
+        def turns_at(r):        # the index of the pair that turns r times
+            return dim * math.log(self.original / (2 * math.pi * r)) \
+                / (2 * math.log(theta))
+        return (max(math.floor(turns_at(self.beta_fast)), 0),
+                min(math.ceil(turns_at(self.beta_slow)), dim - 1))
+
+    def inv_freq(self, dim: int, theta: float):
+        f = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+        low, high = self.ramp_ends(dim, theta)
+        ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                        / max(high - low, 1e-3), 0.0, 1.0)
+        return f / self.factor * ramp + f * (1.0 - ramp)
+
+
+def rope_freqs(head_dim: int, max_seq: int, theta: float = 10000.0,
+               yarn: Optional[Yarn] = None):
+    """(cos, sin) ``[max_seq, head_dim / 2]``; with ``yarn`` at its
+    blended frequencies and times its table's multiplier."""
+    if yarn is None:
+        inv = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                               / head_dim))
+    else:
+        inv = yarn.inv_freq(head_dim, theta)
     t = jnp.arange(max_seq, dtype=jnp.float32)
     ang = jnp.outer(t, inv)                    # [S, D/2]
+    if yarn is not None and yarn.table_scale != 1.0:
+        return jnp.cos(ang) * yarn.table_scale, jnp.sin(ang) * yarn.table_scale
     return jnp.cos(ang), jnp.sin(ang)
 
 

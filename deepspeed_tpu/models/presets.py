@@ -424,6 +424,54 @@ PRESETS: Dict[str, dict] = {
                           moe_select_bias=True, moe_norm_topk=False,
                           moe_route_scale=6.0, moe_dispatch="ragged",
                           attention_impl="xla"),
+    # --- DeepSeek-V2 (deepseek-ai/DeepSeek-V2 config.json, model_type
+    # deepseek_v2): latent attention with a query latent of 1536 in all
+    # 60 layers, 128 heads (128 unrotated + 64 rotated, values of 128)
+    # over a cached row of 512 + 64, no multipliers on the latents; YaRN
+    # (factor 40 over 4096 original positions, mscale = mscale_all_dim =
+    # 0.707: the table's multiplier is 1 and the softmax scale's 1.5896);
+    # layer 0 dense at 12288, then 160 softmax-routed experts of width
+    # 1536 in 8 device groups of which a token opens the 3 whose BEST
+    # score is highest (``group_limited_greedy``), 6 a token, weights 16
+    # x the scores and not renormalised, no bias, beside two shared
+    # experts held as one ungated MLP of 3072 ----------------------------
+    "deepseek-v2-tiny": dict(vocab_size=1024, num_layers=4, d_model=64,
+                             num_heads=4, head_dim=16, d_ff=160,
+                             max_seq_len=512, activation="silu",
+                             gated_mlp=True, norm="rmsnorm",
+                             position="rope", rope_theta=100.0,
+                             rope_pct=0.5,
+                             rope_yarn=(8.0, 64, 32.0, 1.0, 0.707, 0.707),
+                             tie_embeddings=False, attn_bias=False,
+                             mlp_bias=False, eps=1e-6,
+                             layer_pattern=("mla",), num_dense_layers=1,
+                             kda_chunk=16,
+                             mla_kv_rank=16, mla_nope_dim=16, mla_rope_dim=8,
+                             mla_value_dim=16, mla_q_rank=24,
+                             num_experts=16, moe_top_k=4, moe_d_ff=48,
+                             moe_shared_ff=96, moe_shared_gate=False,
+                             moe_score="softmax", moe_norm_topk=False,
+                             moe_route_scale=4.0, moe_groups=8,
+                             moe_groups_kept=3, moe_group_score="max",
+                             moe_dispatch="ragged", attention_impl="xla"),
+    "deepseek-v2": dict(vocab_size=102400, num_layers=60, d_model=5120,
+                        num_heads=128, head_dim=128, d_ff=12288,
+                        max_seq_len=163840, activation="silu",
+                        gated_mlp=True, norm="rmsnorm", position="rope",
+                        rope_theta=10000.0, rope_pct=0.5,
+                        rope_yarn=(40.0, 4096, 32.0, 1.0, 0.707, 0.707),
+                        tie_embeddings=False, attn_bias=False,
+                        mlp_bias=False, eps=1e-6,
+                        layer_pattern=("mla",), num_dense_layers=1,
+                        kda_chunk=64,
+                        mla_kv_rank=512, mla_nope_dim=128, mla_rope_dim=64,
+                        mla_value_dim=128, mla_q_rank=1536,
+                        num_experts=160, moe_top_k=6, moe_d_ff=1536,
+                        moe_shared_ff=3072, moe_shared_gate=False,
+                        moe_score="softmax", moe_norm_topk=False,
+                        moe_route_scale=16.0, moe_groups=8,
+                        moe_groups_kept=3, moe_group_score="max",
+                        moe_dispatch="ragged", attention_impl="xla"),
     # --- Granite-4.0-H (ibm-granite/granite-4.0-h-small config.json,
     # model_type granitemoehybrid): a layer holds ONE mixer, a Mamba-2
     # mixer (128 heads of 64, state 128, ONE group, chunk 256) in nine

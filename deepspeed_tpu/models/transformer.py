@@ -54,6 +54,10 @@ class TransformerConfig:
     position: str = "learned"
     rope_theta: float = 10000.0
     rope_pct: float = 1.0                     # partial rotary (phi: 0.4)
+    # YaRN over the rotated dims (``layers.Yarn``'s fields in order, as
+    # the presets write them); None: the plain table.  For "mla" layers:
+    # the softmax scale's multiplier lives in ``MLADims.scale``
+    rope_yarn: Optional[L.Yarn] = None
     # olmoe / olmo-2: RMSNorm with a learned scale over the WHOLE q and
     # the whole k projection (all heads together), before rotary
     qk_norm: bool = False
@@ -179,10 +183,14 @@ class TransformerConfig:
     # the chosen weights are multiplied by this after renormalisation
     moe_route_scale: float = 1.0
     # the experts in order form this many groups; a token chooses among
-    # the experts of the ``moe_groups_kept`` groups whose two best
-    # (biased) scores sum highest
+    # the experts of the ``moe_groups_kept`` groups that score highest.
+    # ``moe_group_score``: a group's score is the sum of its two best
+    # (biased) scores, "top2" (DeepSeek-V3's ``noaux_tc``), or its best
+    # score, "max" (DeepSeek-V2's ``group_limited_greedy``: a group is
+    # the experts of one device, and ``experts_held`` is whole groups)
     moe_groups: int = 1
     moe_groups_kept: int = 1
+    moe_group_score: str = "top2"
     # ``(first, count)``: the experts whose weights THIS model instance
     # holds, a chip's share of an expert layer spread over several.  The
     # router keeps its ``num_experts`` outputs and its ``moe_top_k`` a
@@ -254,6 +262,10 @@ class TransformerConfig:
                 assert self.mla_kv_rank and self.mla_value_dim \
                     and self.mla_rope_dim == self.rotary_dim \
                     and self.mla_gate in (None, "head")
+        if self.rope_yarn is not None:
+            self.rope_yarn = L.Yarn(*self.rope_yarn)   # its fields in order
+            assert self.position == "rope" and kinds == {"mla"}, \
+                "rope_yarn is written for latent layers alone"
         if self.experts_held is not None:
             self.experts_held = tuple(self.experts_held)
             first, count = self.experts_held
@@ -261,6 +273,16 @@ class TransformerConfig:
                 and first + count <= self.num_experts
         assert self.num_experts % self.moe_groups == 0 \
             and 1 <= self.moe_groups_kept <= self.moe_groups
+        assert self.moe_group_score in ("top2", "max")
+        if self.held_groups is not None:
+            # a group is a device's experts: a share holds whole groups
+            per = self.num_experts // self.moe_groups
+            first, count = self.experts_held
+            if first % per or count % per:
+                raise ValueError(
+                    f"experts_held={self.experts_held} splits a group of "
+                    f"{per} experts (moe_groups={self.moe_groups}, "
+                    "moe_group_score='max')")
         assert "window" not in self.layer_pattern or self.attn_window
         assert self.qk_norm_form in ("projection", "head")
         assert self.moe_score in ("softmax", "sigmoid")
@@ -355,7 +377,21 @@ class TransformerConfig:
             dims = dims._replace(
                 q_scale=math.sqrt(self.d_model / self.mla_q_rank),
                 kv_scale=math.sqrt(self.d_model / self.mla_kv_rank))
+        if self.rope_yarn is not None:
+            dims = dims._replace(score_scale=self.rope_yarn.score_scale)
         return dims
+
+    @property
+    def held_groups(self) -> Optional[Tuple[int, int]]:
+        """``(first, count)``: the expert groups a share holds where the
+        router limits a token to devices' groups ("max") and
+        ``experts_held`` says which are here; None for every other
+        model."""
+        if self.moe_groups <= 1 or self.moe_group_score != "max" \
+                or self.experts_held is None:
+            return None
+        per = self.num_experts // self.moe_groups
+        return self.experts_held[0] // per, self.experts_held[1] // per
 
     @property
     def router_outputs(self) -> int:
@@ -838,6 +874,8 @@ def moe_share(cfg) -> Dict[str, Any]:
     out = {}
     if cfg.moe_groups > 1:
         out["groups"] = (cfg.moe_groups, cfg.moe_groups_kept)
+        if cfg.moe_group_score != "top2":
+            out["group_score"] = cfg.moe_group_score
     if cfg.experts_held is not None:
         out["held"] = cfg.experts_held
     if cfg.moe_zero_experts:
@@ -1129,7 +1167,7 @@ def apply(cfg: TransformerConfig, params, input_ids, mask=None,
             cos = sin = None
         else:
             cos, sin = L.rope_freqs(cfg.rotary_dim, cfg.max_seq_len,
-                                    cfg.rope_theta)
+                                    cfg.rope_theta, cfg.rope_yarn)
 
     have_rng = rng is not None
     if (pld_theta is not None or ltd_keep is not None) and not have_rng:

@@ -1,6 +1,6 @@
 """Latent attention (DeepSeek-V2's MLA; the query in one product, or
-through a latent of its own as DeepSeek-V3's): what a served ``"mla"``
-layer needs.
+through a latent of its own as DeepSeek-V2's and V3's large models):
+what a served ``"mla"`` layer needs.
 
 A token leaves ONE vector in the cache, ``[c | k_r]``: the normed
 key-value latent ``c`` (``kv_rank`` values) and the rotated shared key
@@ -64,11 +64,24 @@ own DMAs, ``send_tile_rows``), with a body of its own:
   ``latent_attend`` casts it; only a tile's finished rows are written;
 * two heights, a property of the batch as ``SHORT``/``LONG`` are there
   (``tile_heights``): a one-token run is a tile of one row (``H`` rows
-  for the MXU: that call is bound by the rows' bytes), a longer run is
-  cut into tiles whose ``height * H`` is ``RUN_ROWS`` (16 rows at 64
-  heads, 32 at 32).  ``k`` is ``latent_group``'s: 16 blocks for a
-  one-token tile, 8 for a run's.  Both are static functions of what the
-  call can see; nothing selects them.
+  for the MXU), a longer run is cut into tiles whose ``height * H`` is
+  ``RUN_ROWS`` (32 rows at 32 heads, 16 at 64, 8 at 128, the least a
+  tile may be).  ``k`` is ``latent_group``'s: 16 blocks for a one-token
+  tile, 8 for a run's.  Both are static functions of what the call can
+  see; nothing selects them.
+* what binds each call follows from the heads.  A cached row costs the
+  folded form ``2 H (row + kv_rank)`` operations and ``2 row`` bytes as
+  counted (1,152; 1,280 as the pool stores it): 60 operations a byte at
+  32 heads, 121 at 64, 242 at 128, where a v5e's 197 TF/s over 819 GB/s
+  is 240.  So the one-token call is bound by its rows' bytes up to 64
+  heads and sits on the ridge at 128 (DeepSeek-V2's; it read 52% of the
+  larger of the two there: PERF.md section 6, PR 56), and a run's call,
+  whose tile reads a row once for ``height`` queries, is compute-bound
+  at every head count (three quarters of the peak in the folded form's
+  own operations at 64 and at 128 heads).  ``MLADims.scale`` carries
+  what a position scaling multiplies the softmax scale by (YaRN's
+  ``m(mscale_all_dim)^2``); the rotated 64 of the query and of the
+  shared key arrive rotated, at whatever frequencies the table holds.
 """
 
 from __future__ import annotations
@@ -104,6 +117,9 @@ class MLADims(NamedTuple):
     # key-value latent (1.0 multiplies nothing)
     q_scale: float = 1.0
     kv_scale: float = 1.0
+    # a multiplier on the softmax scale (YaRN's ``m(mscale_all_dim)^2``,
+    # ``models/layers.Yarn.score_scale``)
+    score_scale: float = 1.0
 
     @property
     def row(self) -> int:
@@ -112,7 +128,7 @@ class MLADims(NamedTuple):
 
     @property
     def scale(self) -> float:
-        return (self.nope_dim + self.rope_dim) ** -0.5
+        return (self.nope_dim + self.rope_dim) ** -0.5 * self.score_scale
 
 
 def rope_interleaved(x, cos, sin, positions):

@@ -213,9 +213,11 @@ def gate_init(key, d_model: int, num_experts: int):
 
 def route(logits, bias=None, *, top_k: int, score: str = "softmax",
           norm_topk: bool = True, route_scale: float = 1.0,
-          groups: Optional[Tuple[int, int]] = None):
+          groups: Optional[Tuple[int, int]] = None,
+          group_score: str = "top2"):
     """The router's choice without capacity: float32 ``logits [T, E]`` →
-    ``(weights [T, K] f32, experts [T, K] i32)``.
+    ``(weights [T, K] f32, experts [T, K] i32, open [T, n] bool)``, the
+    last the groups a row may choose among (None without ``groups``).
 
     ``score``: ``softmax`` over the experts, or the ``sigmoid`` of each
     logit.  ``bias [E]``: added to the scores for the CHOICE of the
@@ -223,18 +225,28 @@ def route(logits, bias=None, *, top_k: int, score: str = "softmax",
     weights are the unbiased scores of the chosen.  ``norm_topk``: the
     chosen weights are renormalised to sum 1; ``route_scale`` multiplies
     them after that.  ``groups``: ``(n, kept)``, a limit on expert
-    groups (DeepSeek-V3's ``noaux_tc``): the experts in order form ``n``
-    groups, a group's score is the sum of its two largest (biased)
-    scores, and the top-k is taken among the experts of the ``kept``
-    best groups."""
+    groups: the experts in order form ``n`` groups and the top-k is
+    taken among the experts of the ``kept`` best groups.  What a group
+    scores is ``group_score``, and the two rules choose different groups
+    on the same scores: ``"top2"``, the sum of its two largest (biased)
+    scores (DeepSeek-V3's ``noaux_tc``; Ling's); ``"max"``, its largest
+    score (DeepSeek-V2's ``group_limited_greedy``, where a group is the
+    experts of one device and the limit bounds the devices a token
+    reaches)."""
     scores = (jax.nn.softmax(logits, axis=-1) if score == "softmax"
               else jax.nn.sigmoid(logits))
     choice = scores if bias is None else scores + bias.astype(scores.dtype)
+    open_ = None
     if groups is not None and groups[0] > 1:
         n, kept = groups
         T, E = choice.shape
-        best2, _ = jax.lax.top_k(choice.reshape(T, n, E // n), 2)
-        _, keep = jax.lax.top_k(best2.sum(-1), kept)              # [T, kept]
+        grouped = choice.reshape(T, n, E // n)
+        if group_score == "max":
+            best = grouped.max(-1)
+        else:
+            best2, _ = jax.lax.top_k(grouped, 2)
+            best = best2.sum(-1)
+        _, keep = jax.lax.top_k(best, kept)                       # [T, kept]
         open_ = jnp.zeros((T, n), bool).at[
             jnp.arange(T)[:, None], keep].set(True)
         choice = jnp.where(jnp.repeat(open_, E // n, axis=1), choice,
@@ -248,7 +260,7 @@ def route(logits, bias=None, *, top_k: int, score: str = "softmax",
         vals = vals / jnp.maximum(vals.sum(axis=1, keepdims=True), 1e-9)
     if route_scale != 1.0:
         vals = vals * route_scale
-    return vals, ids
+    return vals, ids, open_
 
 
 def _ragged_moe(gate_p, expert_p, x, logits, *, top_k: int, activation,
@@ -256,7 +268,7 @@ def _ragged_moe(gate_p, expert_p, x, logits, *, top_k: int, activation,
                 route_scale: float = 1.0,
                 norm_topk: bool = True,
                 noise_policy: Optional[str], rng: Optional[jax.Array],
-                dt, groups=None, held=None
+                dt, groups=None, held=None, group_score: str = "top2"
                 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """DROPLESS grouped-GEMM MoE (``dispatch_mode="ragged"``): tokens
     sort by assigned expert and each projection is ONE
@@ -278,9 +290,9 @@ def _ragged_moe(gate_p, expert_p, x, logits, *, top_k: int, activation,
     gates = jax.nn.softmax(lf, axis=-1)                           # [T, E]
     # renormalised to sum 1 per token under norm_topk — same convention
     # as top_k_gating (reference top2 normalization sharded_moe.py:290)
-    vals, ids = route(lf, gate_p.get("bias"), top_k=top_k, score=score,
-                      norm_topk=norm_topk, route_scale=route_scale,
-                      groups=groups)
+    vals, ids, _ = route(lf, gate_p.get("bias"), top_k=top_k, score=score,
+                         norm_topk=norm_topk, route_scale=route_scale,
+                         groups=groups, group_score=group_score)
     me = gates.mean(axis=0)
     ce = jax.nn.one_hot(ids[:, 0], E, dtype=jnp.float32).mean(axis=0)
     aux_loss = (me * ce).sum() * E
@@ -328,7 +340,9 @@ def moe_serve(gate_p, expert_p, h, valid=None, *, top_k: int, activation,
               layer=None, score: str = "softmax",
               route_scale: float = 1.0, with_ids: bool = False,
               groups: Optional[Tuple[int, int]] = None,
-              held: Optional[Tuple[int, int]] = None, zero: int = 0):
+              held: Optional[Tuple[int, int]] = None, zero: int = 0,
+              group_score: str = "top2",
+              held_groups: Optional[Tuple[int, int]] = None):
     """The serving expert layer: DROPLESS by construction.  h: [T, d]
     rows of one serving step (any mix of sequences); ``valid``: [T] bool,
     False for the rows that pad the step's bucket (None: all real).
@@ -357,7 +371,8 @@ def moe_serve(gate_p, expert_p, h, valid=None, *, top_k: int, activation,
     the stack where it lies; a layer sliced out of it first would be
     copied whole on its way into the custom call.
 
-    ``groups``: the router's limit on expert groups (:func:`route`).
+    ``groups``, ``group_score``: the router's limit on expert groups
+    (:func:`route`).
     ``held``: ``(first, count)``, the experts whose weights ``expert_p``
     holds (``[.., count, ...]``), a chip's share of the layer.  The
     router keeps all its outputs and its ``top_k`` a row, the weights
@@ -378,7 +393,11 @@ def moe_serve(gate_p, expert_p, h, valid=None, *, top_k: int, activation,
     over the mean, experts that took a row), for the engine's counters
     (with ``held``: of the experts held; the router made ``top_k`` a
     real row); with ``zero`` a fourth, the assignments to experts that
-    compute nothing."""
+    compute nothing; with ``held_groups``, ``(first, count)`` of the
+    router's groups where a group is a device's experts and ``held`` is
+    whole groups (``TransformerConfig.held_groups``), a last: the real
+    rows that opened at least one of them, which a deployment's exchange
+    would send to this chip."""
     T, dm = h.shape
     E = expert_p["wi"].shape[-3]
     dt = h.dtype
@@ -399,9 +418,10 @@ def moe_serve(gate_p, expert_p, h, valid=None, *, top_k: int, activation,
     with jax.named_scope("moe_route"):
         logits = jnp.dot(h, gate_p["kernel"].astype(dt),
                          preferred_element_type=jnp.float32)
-        vals, ids = route(logits, gate_p.get("bias"), top_k=top_k,
-                          score=score, norm_topk=norm_topk,
-                          route_scale=route_scale, groups=groups)
+        vals, ids, open_ = route(
+            logits, gate_p.get("bias"), top_k=top_k, score=score,
+            norm_topk=norm_topk, route_scale=route_scale, groups=groups,
+            group_score=group_score)
         if valid is not None:
             ids = jnp.where(valid[:, None], ids, outputs)
         taken = ids                         # expert E: nowhere
@@ -445,7 +465,13 @@ def moe_serve(gate_p, expert_p, h, valid=None, *, top_k: int, activation,
         n = group_sizes.sum()
         stats = [n, (group_sizes.max() * (1000 * E)) // jnp.maximum(n, 1),
                  (group_sizes > 0).sum()]
-        stats = jnp.stack(stats + [nothing.sum()] if zero else stats)
+        if zero:
+            stats.append(nothing.sum())
+        if held_groups is not None:
+            first, count = held_groups
+            here = open_[:, first:first + count].any(1)
+            stats.append((here if valid is None else here & valid).sum())
+        stats = jnp.stack(stats)
     return (y, stats, taken) if with_ids else (y, stats)
 
 
@@ -456,7 +482,8 @@ def moe_ffn(gate_p, expert_p, x, *, top_k: int, capacity_factor: float,
             dispatch_mode: str = "scatter",
             norm_topk: bool = True, score: str = "softmax",
             route_scale: float = 1.0, groups=None, held=None,
-            zero: int = 0) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+            zero: int = 0, group_score: str = "top2"
+            ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """Full MoE FFN over x [B, S, d_model] (reference: MOELayer.forward
     sharded_moe.py:533).  Returns (y, metrics) with metrics carrying the
     aux load-balancing loss.
@@ -510,7 +537,7 @@ def moe_ffn(gate_p, expert_p, x, *, top_k: int, capacity_factor: float,
                            noise_policy=noise_policy, rng=rng, dt=dt,
                            norm_topk=norm_topk, score=score,
                            route_scale=route_scale, groups=groups,
-                           held=held)
+                           held=held, group_score=group_score)
     if score != "softmax" or "bias" in gate_p or route_scale != 1.0 \
             or groups is not None or held is not None:
         raise ValueError(

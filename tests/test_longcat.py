@@ -626,9 +626,8 @@ def test_benchmark_json_lists_the_cell_and_its_metrics():
         "longcat-flash-d4", "docqa-closed-48", 1)
     mine = [m for m in bench["per_layer"]
             if "serve-mla-docqa" in m.get("workloads", ())]
-    # (a later cell is appended behind it in an entry's list)
-    assert all("serve-mla-docqa" in m["workloads"][-2:]
-               and m["moves"] == "out_tokens_per_s" for m in mine)
+    # (later cells are appended behind it in an entry's list)
+    assert all(m["moves"] == "out_tokens_per_s" for m in mine)
     # BENCHMARK.json holds at most 128 per-layer metrics and held 127:
     # ONE entry is new (the whole step's roofline share); the cell joins
     # the lists of fourteen accepted entries whose readers take nothing
@@ -648,7 +647,7 @@ def test_benchmark_json_lists_the_cell_and_its_metrics():
     assert all(os.path.exists(reader_path("layer_metrics", n))
                for n in names)
     e2e = {m["name"]: m for m in bench["end_to_end"]}
-    assert "serve-mla-docqa" in e2e["out_tokens_per_s"]["workloads"][-2:]
+    assert "serve-mla-docqa" in e2e["out_tokens_per_s"]["workloads"]
     assert [w["name"] for w in bench["workloads"]].index(
         "serve-mla-docqa") == 8
     assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) < 64 * 1024

@@ -216,9 +216,9 @@ def _combine_by_rows(gate, experts, h, valid, top_k, held, zero):
     outputs = gate["kernel"].shape[-1]
     first, count = held or (0, outputs - zero)
     hf = np.asarray(h, np.float32)
-    vals, ids = M.route(jnp.dot(h, gate["kernel"].astype(h.dtype),
-                                preferred_element_type=jnp.float32),
-                        top_k=top_k, norm_topk=True)
+    vals, ids, _ = M.route(jnp.dot(h, gate["kernel"].astype(h.dtype),
+                                   preferred_element_type=jnp.float32),
+                           top_k=top_k, norm_topk=True)
     vals, ids = np.asarray(vals), np.asarray(ids)
     wi, wg, wo = (np.asarray(experts[k], np.float32)
                   for k in ("wi", "wg", "wo"))

@@ -594,6 +594,45 @@ def test_latent_shortcut_serving_step_compiles_and_fits(one_chip, on_chip,
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
 
 
+@pytest.mark.parametrize("rows", [128, 512])
+def test_latent_groups_serving_step_compiles_and_fits(one_chip, on_chip,
+                                                      rows):
+    """The whole serving step of ``deepseek-v2-d5`` as the benchmark runs
+    it, at both of its row counts (a dense layer and four expert layers of
+    latent attention at 128 heads, 40 of 160 experts in two device groups
+    beside a shared MLP, 9216 blocks of 64 latent rows in each of five
+    layers, 24 sequences a step, tables of 272 blocks): the grouped
+    kernel's three projections an expert layer and the latent attention's
+    two calls a layer (128 heads: tiles of one row and of 8) are the
+    program's only Pallas calls; the latent pool rides the layer scan and
+    is not copied whole; weights, pool and temporaries fit a 16 GB chip."""
+    import json
+
+    from benchmarks.lib.drivers.serve_latent_groups import preset_config
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root,
+                           "benchmarks/configs/deepseek-v2-d5.json")) as f:
+        cfg = preset_config(json.load(f))
+    compiled, layer_bytes = _pstep_compiled(
+        one_chip, cfg, False, T=rows, seqs=24, bs=64, mbs=272, blocks=9216)
+    text = compiled.as_text()
+    # the leading layer outside the scan and ONE body for the four
+    # expert layers: two attention calls each, three projections in the
+    # body
+    assert text.count("tpu_custom_call") == 2 + 2 + 3
+    assert len(re.findall(r"%latent_attention_h(1|8)[\w.]* = ", text)) == 4
+    assert layer_bytes == 9217 * 64 * 640 * 2
+    moved = [m for m in _moves_of(text, layer_bytes)
+             if "dynamic-update-slice" not in m and "fusion" not in m]
+    assert moved == [], moved
+    mem = compiled.memory_analysis()
+    print("deepseek-v2-d5 step:", rows, mem.argument_size_in_bytes,
+          mem.temp_size_in_bytes)
+    assert mem.argument_size_in_bytes > 14.0e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.0e9
+
+
 def test_moe_serving_step_compiles_and_fits(one_chip, on_chip):
     """The whole serving step of ``olmoe-1b-7b-d10`` as the benchmark
     runs it (10 layers, 768 blocks of 64, 512 tokens and 64 sequences a
