@@ -99,7 +99,12 @@ REAL = dict(
         "docqa": dict(H=64, S=48, T=512, ctx=(1024, 10000), run=(464, 2500),
                       chunk=64),
         "reason": dict(H=32, S=128, T=512, ctx=(512, 6000), run=(64, 2300),
-                       chunk=64)}),
+                       chunk=64)},
+        # the expanded form's call: a chunk of 512 rows behind a shared
+        # document (serve-mla-shared-docs) and behind a long prompt
+        # (serve-mla-docqa)
+        expanded={"shared-docs": dict(H=128, T=512, run=(512, 16384)),
+                  "docqa": dict(H=64, T=512, run=(512, 4096))}),
     barrier=dict(n=8192, iters=64),
 )
 TINY = dict(
@@ -123,7 +128,8 @@ TINY = dict(
     scan=dict(T=32, S=4, runs=((3, 19, 1), (24, 6, 3)), cases={
         "falcon-h1": (4, 128, 2, 16, 8), "granite-4.0-h": (4, 64, 1, 16, 8)}),
     latent=dict(row=(96, 16), block=8, cases={
-        "docqa": dict(H=4, S=4, T=32, ctx=(9, 70), run=(21, 30), chunk=8)}),
+        "docqa": dict(H=4, S=4, T=32, ctx=(9, 70), run=(21, 30), chunk=8)},
+        expanded={"docqa": dict(H=4, T=32, run=(21, 30))}),
     barrier=dict(n=256, iters=8),
 )
 
@@ -695,6 +701,79 @@ def latent_kernel_phase(sz, seed):
         print(f"    pallas, the one-token rows alone: {ms(t_d)} "
               f"({rows_mb / 819e9 * 1e3:.3f} ms at 819 GB/s)")
         close("one-token rows", dec[:S - 1], ref[:S - 1], BF16_REL)
+
+    # --- the expanded form's call beside the folded run call, one run
+    for name, g in c["expanded"].items():
+        H, T = g["H"], g["T"]
+        n_run, seen = g["run"]
+        dims = A.MLADims(heads=H, kv_rank=kv_rank, nope_dim=128,
+                         rope_dim=rope, value_dim=128)
+        width = -(-dims.row // 128) * 128
+        nb = -(-(seen + n_run) // bs)
+        rows = nb + 1
+        rng = np.random.default_rng(seed + H)
+        tables = rng.permutation(nb).astype(np.int32)[None]
+        keys = jax.random.split(jax.random.PRNGKey(seed + H), 4)
+        pool = jnp.zeros((2 * rows, bs, width), jnp.bfloat16).at[
+            ..., :dims.row].set(jax.random.normal(
+                keys[0], (2 * rows, bs, dims.row), jnp.bfloat16))
+        q_n, q_r = ((jax.random.normal(k, (T, H, d), jnp.float32)
+                     * dims.row ** -0.25).astype(jnp.bfloat16)
+                    for k, d in ((keys[1], 128), (keys[2], rope)))
+        ap = {"w_kvb": (jax.random.normal(keys[3], (kv_rank, H, 256),
+                                          jnp.float32)
+                        * kv_rank ** -0.5).astype(jnp.bfloat16)}
+        pos = np.where(np.arange(T) < n_run, seen + np.arange(T), 0)
+        valid = np.arange(T) < n_run
+        layer = (rows, rows)
+        pairs = n_run * seen + n_run * (n_run + 1) // 2
+        least = 2 * H * (pairs * (128 + rope + 128)
+                         + (seen + n_run) * kv_rank * 256)
+        print(f"  latent attention, expanded, {name} H{H}: a run of {n_run} "
+              f"behind {seen}: folded {2 * pairs * H * (width + kv_rank) / 1e9:.0f}"
+              f" G operations, expanded {least / 1e9:.0f} G "
+              f"({least / 197e12 * 1e3:.3f} ms at 197 T/s)")
+        j = jnp.asarray
+
+        def tiles_of(wide):
+            return A.latent_tiles(j(np.zeros(T, np.int32)), j(pos), j(valid),
+                                  j(tables), bs, nb, rows - 1, H, wide=wide)
+
+        def folded(pool, q_n, q_r, ap):
+            qf = A.fold_query(ap, q_n, q_r, dims)
+            return A.unfold_output(ap, A.latent_attend_tiles(
+                pool, qf, tiles_of(None), dims, layer), dims, jnp.bfloat16)
+
+        def expanded(pool, q_n, q_r, ap):
+            return A.latent_attend_expanded(
+                pool, q_n, q_r, A.w_kvb(ap, dims),
+                tiles_of((min(n_run, A.expand_from(dims)), A.WIDE)).wide,
+                jnp.zeros((T, H, 128), jnp.bfloat16), dims, layer)
+
+        Q = min(64, T)
+        NC = -(-n_run // Q)
+        crow = np.minimum(np.arange(NC * Q), T - 1).reshape(NC, Q)
+        there = (np.arange(NC * Q) < n_run).reshape(NC, Q)
+        lt = np.broadcast_to(tables + rows, (NC, nb))
+
+        def xla(pool, q_n, q_r, ap):
+            qf = A.fold_query(ap, q_n, q_r, dims)
+            run = A.latent_attend(pool, qf[j(crow)],
+                                  j(np.where(there, pos[crow], -1)), j(lt),
+                                  dims, 4)
+            return A.unfold_output(ap, run.reshape(
+                (NC * Q,) + run.shape[2:])[:n_run], dims, jnp.bfloat16)
+
+        ins = (pool, q_n, q_r, ap)
+        got, t_e = timed(jax.jit(expanded), *ins)
+        fold, t_f = timed(jax.jit(folded), *ins)
+        ref, t_x = timed(jax.jit(xla), *ins)
+        print(f"    pallas, expanded: {ms(t_e)}\n    pallas, folded (with "
+              f"its two products around it): {ms(t_f)}\n    xla: {ms(t_x)}")
+        close("expanded", got[:n_run], ref, BF16_REL)
+        close("folded", fold[:n_run], ref, BF16_REL)
+        check(not np.asarray(got[n_run:], np.float32).any(),
+              "the expanded call wrote rows of no tile")
 
 
 # --------------------------------------------------------------------------

@@ -910,17 +910,21 @@ class InferenceEngine:
         # XLA formulation count nothing.  A model with a latent layer is
         # cut at that kernel's two heights (ops/mla ``tile_heights``: a
         # one-token run is a tile of ONE row, a longer run tiles of a
-        # thousand MXU rows), under labels of their own
+        # thousand MXU rows), under labels of their own, and its runs of
+        # at least ``expand_from`` rows apart: the expanded form's tiles
         self._tile_heights = (SHORT, LONG)
         self._tile_labels = ("short", "long")
+        self._tile_wide = None
         if self.state.cfg.latent_dim:
-            from ..ops.mla import tile_heights
+            from ..ops.mla import tile_heights, wide_cut
             self._tile_heights = tile_heights(self.cfg.mla_dims.heads)
-            self._tile_labels = ("one", "run")
+            self._tile_labels = ("one", "run", "expanded")
+            self._tile_wide = wide_cut(self.cfg.mla_dims)
         self._c_attn_tiles = reg.counter(
             "serving_attn_tiles_total",
             "query tiles of the attention kernel over the dispatched "
-            "steps (height: short|long; a latent model's: one|run)",
+            "steps (height: short|long; a latent model's: "
+            "one|run|expanded)",
             int_valued=True)
         self._c_attn_long_rows = reg.counter(
             "serving_attn_long_tile_tokens_total",
@@ -1118,9 +1122,14 @@ class InferenceEngine:
         ``latent_tokens_one``, its part in the one-token runs (the
         kernel's call that is bound by the rows' bytes), and
         ``latent_pairs``, the (query, cached row) pairs its causal
-        attention holds (``n * seen + n (n + 1) / 2`` a run of n rows)."""
+        attention holds (``n * seen + n (n + 1) / 2`` a run of n rows);
+        where the kernel serves, of the runs that take its expanded form
+        (``ops/mla.expand_from`` rows or more) ``latent_pairs_expanded``,
+        their pairs, and ``latent_rows_expanded``, the cached rows they
+        meet (what ONE layer expands, a head)."""
         w = self._window
-        full = window = pairs = one = 0
+        full = window = pairs = one = wide_pairs = wide_rows = 0
+        wide_from = self._tile_wide[0] if pallas and self._tile_wide else None
         short = []
         for uid, toks in sched:
             seq = self.state.seqs.get(uid)
@@ -1128,15 +1137,23 @@ class InferenceEngine:
             ctx = seen + len(toks)
             full += ctx
             one += ctx if len(toks) == 1 else 0
-            pairs += len(toks) * seen + len(toks) * (len(toks) + 1) // 2
+            run_pairs = len(toks) * seen + len(toks) * (len(toks) + 1) // 2
+            pairs += run_pairs
+            if wide_from and len(toks) >= wide_from:
+                wide_pairs += run_pairs
+                wide_rows += ctx
             if w:
                 window += min(ctx, w + len(toks) - 1)
             if 0 < len(toks) <= SHORT:
                 short.append((seen, len(toks)))
         if self.state.cfg.latent_dim:
             self._c_attn_kv.inc(full, kind="latent")
-            return {"latent_tokens": full, "latent_tokens_one": one,
+            args = {"latent_tokens": full, "latent_tokens_one": one,
                     "latent_pairs": pairs}
+            if wide_from:
+                args.update(latent_pairs_expanded=wide_pairs,
+                            latent_rows_expanded=wide_rows)
+            return args
         args = {"kv_tokens_full": full}
         self._c_attn_kv.inc(full, kind="full")
         if w:
@@ -3478,9 +3495,10 @@ class InferenceEngine:
         cold = ("p", key) not in self._warm_keys
         tiles = {}
         if pallas:
-            short, long = self._tile_labels
-            n_short, n_long, rows = tile_counts(
-                [len(t) for _, t in sched], *self._tile_heights)
+            short, long, *wide = self._tile_labels
+            n_short, n_long, rows, *n_wide = tile_counts(
+                [len(t) for _, t in sched], *self._tile_heights,
+                wide=self._tile_wide)
             self._c_attn_tiles.inc(n_short, height=short)
             if n_long:
                 self._c_attn_tiles.inc(n_long, height=long)
@@ -3488,6 +3506,9 @@ class InferenceEngine:
             tiles = {f"n_tiles_{short}": n_short, f"n_tiles_{long}": n_long,
                      "tile_fill": rows / (n_long * self._tile_heights[1])
                      if n_long else 0.0}
+            if wide:
+                self._c_attn_tiles.inc(n_wide[0], height=wide[0])
+                tiles[f"n_tiles_{wide[0]}"] = n_wide[0]
         if self._recurrent is not None:
             tiles.update(self._count_state_rows(sched))
         t1 = tr.phase("ds.serve.stage", track="stage", sid=sid,

@@ -198,12 +198,15 @@ def _latent_tiles(cfg, pool, batch: RaggedBatch, block_size: int,
     ``latent_tiles``): cut once a step, outside the layer scan, as
     ``_query_tiles`` are for the paged kernel.  ``pool``: the latent
     pool ``[L, rows, bs, row]`` (a layer's last row is its trash
-    block)."""
-    from ..ops.mla import latent_tiles
+    block).  The runs long enough for the expanded form
+    (``expand_from``) make a third list."""
+    from ..ops.mla import latent_tiles, wide_cut
 
+    dims = cfg.mla_dims
     return latent_tiles(batch.seq_slot, batch.positions, batch.token_valid,
                         batch.block_tables, block_size, max_blocks_per_seq,
-                        trash=pool.shape[-3] - 1, heads=cfg.mla_dims.heads)
+                        trash=pool.shape[-3] - 1, heads=dims.heads,
+                        wide=wide_cut(dims))
 
 
 def _paged_attention_pallas(kv_layer, q, batch: RaggedBatch,
@@ -826,7 +829,9 @@ def _latent_attention(cfg, ap, h, pool, layer, batch: RaggedBatch, runs,
     again), then all the query heads over the cached rows of the row's
     sequence, ``W_kvb`` folded into the query and the output
     (``latent_attn``): by the Pallas kernel over ``tiles``
-    (``_latent_tiles`` of the step) where the caller has them, else by
+    (``_latent_tiles`` of the step; its third list's runs in the
+    expanded form, per-head keys and values built in VMEM) where the
+    caller has them, else by
     the XLA formulation, the one-token rows as one group a slot, the
     longer runs by their chunks.  ``pool``: the latent pool ``[L * rows,
     bs, row]``, which holds the layer where ``layer`` says
@@ -845,7 +850,11 @@ def _latent_attention(cfg, ap, h, pool, layer, batch: RaggedBatch, runs,
     with jax.named_scope("latent_in"):
         q_n, q_r, row = A.project(ap, h, cos, sin, batch.positions, dims,
                                   cfg.eps, mm)
-        qf = A.fold_query(ap, q_n, q_r, dims)               # [T, H, row]
+        # (where the step has runs long enough for the expanded form the
+        # folded query is made for the rows that take that form)
+        by_run = tiles is not None and tiles.wide is not None
+        if not by_run:
+            qf = A.fold_query(ap, q_n, q_r, dims)           # [T, H, row]
         if cfg.mla_gate == "head":
             gate = _mm(h, ap["wg"], dt)
     with jax.named_scope("latent_write"):
@@ -854,12 +863,19 @@ def _latent_attention(cfg, ap, h, pool, layer, batch: RaggedBatch, runs,
         blk = jnp.where(batch.token_valid, blk + base, base + nrows - 1)
         pool = A.latent_write(pool, row, blk, batch.positions % block_size)
     with jax.named_scope("latent_attn"):
-        if tiles is not None:
-            o = A.latent_attend_tiles(pool, qf, tiles, dims, layer)
+        if by_run:
+            # each run by the call its length takes: the runs long enough
+            # to pay for ``c W_kvb`` expanded (no third list at a row
+            # count that holds no such run)
+            o = A.latent_attend_runs(ap, pool, q_n, q_r, tiles, dims, layer,
+                                     dt)                    # [T, H, V]
         else:
-            o = _latent_attend_xla(cfg, qf, pool, layer, batch, runs,
-                                   max_blocks_per_seq)
-        o = A.unfold_output(ap, o, dims, dt)                # [T, H, V]
+            if tiles is not None:
+                o = A.latent_attend_tiles(pool, qf, tiles, dims, layer)
+            else:
+                o = _latent_attend_xla(cfg, qf, pool, layer, batch, runs,
+                                       max_blocks_per_seq)
+            o = A.unfold_output(ap, o, dims, dt)            # [T, H, V]
     with jax.named_scope("latent_out"):
         if cfg.mla_gate == "head":
             o = o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(

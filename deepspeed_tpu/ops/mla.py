@@ -25,7 +25,9 @@ which is attention of all the query heads over one shared key of
   softmax.  The serving forward calls it twice: the step's one-token
   rows, one group a slot, and the chunks of its longer runs;
 * :func:`latent_attend_tiles` (the same scope), the Pallas kernel
-  (``attn_impl="pallas"``: what a TPU runs), below;
+  (``attn_impl="pallas"``: what a TPU runs), below, and
+  :func:`latent_attend_expanded`, its third call, for the runs long
+  enough to pay for ``c W_kvb`` (further below);
 * :func:`attention_forward`: the layer over whole sequences with keys
   and values expanded (``models/transformer.apply``).
 
@@ -82,6 +84,93 @@ own DMAs, ``send_tile_rows``), with a body of its own:
   what a position scaling multiplies the softmax scale by (YaRN's
   ``m(mscale_all_dim)^2``); the rotated 64 of the query and of the
   shared key arrive rotated, at whatever frequencies the table holds.
+
+**The third call: the expanded form** (``latent_attend_expanded``,
+kernel ``latent_attention_h512``; PERF.md section 6, PR 58, which
+holds the chip's readings quoted below).  The folded
+form is right for a run that meets a cached row once or a few times and
+wrong for a run of hundreds of rows: as executed it costs every head
+``2 (640 + 512)`` operations a (query row, cached row) pair where
+per-head keys of ``nope + rope`` (128 + 64, met as 256 lanes) and values
+of 128 cost ``2 (256 + 128)``, a third, and the expansion ``[k_n | v] =
+c W_kvb`` of a cached row (``2 x 512 x 256`` a head) is paid ONCE for
+all the run's rows.  The same mathematics, reassociated (``(q_n W_kb^T)
+. c = q_n . (c W_kb)``), nothing lowered: products in the pool's type
+with float32 accumulation, float32 scores and statistics, ``p`` in the
+pool's type, the shared rotated 64 read from the cached row as it lies.
+
+* which run takes it is the code's choice from the run's length and
+  ``MLADims`` alone (``expand_from``): by operations the two forms
+  cross at ``kv_rank (nope + value) / (row + kv_rank - nope - rope -
+  value)`` = 171 rows whatever the head count; measured on the chip at
+  128 heads behind 16,384 cached rows the crossing lies at about 200
+  rows (folded 6.1 ms against 6.8 at 176 rows, 7.0 against 6.8 at 208,
+  7.5 against 6.6 at 224) and at 64 heads behind 4,096 at about 224,
+  because the expansion runs at 82% of the MXU's peak and the pairs'
+  products at 64% where the folded form's run at 76%, and because XLA
+  lays the step's queries out head-major around the call (0.15 ms a
+  layer whatever the run).  ``EXPAND_MARGIN`` = 1.3 puts the threshold
+  at 224 rows; a run one row under it takes the folded run call, whose
+  code is what it was.  ``query_tiles(wide=...)`` cuts such runs into a
+  third list for this caller only;
+* a tile is the rows of one run inside a window of ``WIDE`` = 512 rows
+  of the batch, at ``wide_heads`` = 16 heads: grid ``(tiles, H / 16)``.
+  The expansion is paid once a (tile, cached row, head), never once a
+  row-tile: at the folded call's tile of 8 rows it would cost 21 times
+  what it saves.  The step's queries arrive head-major ``[H, T, 256]``
+  (one transposing pass by XLA; a tile's window is a BlockSpec block at
+  any offset of the run in it), the result leaves as ``[tiles, H, 512,
+  value]`` and XLA selects each row's from its tile: the heads' VALUES,
+  no ``unfold_output`` for these rows.  Every head group walks the
+  tile's blocks again (the same tables, ``each_group_block``,
+  ``fetch_ahead``, two VMEM buffers, one DMA a block from the stacked
+  pool): the cached rows are read ``H / 16`` times a run, 173 MB a
+  layer at 16.9k rows, behind the products;
+* per group of ``WIDE_KEYS`` = 1,024 cached rows and per head: ``[k_n |
+  v] = c W_kvb_h`` (the head's ``[512, 256]`` of the matrix, brought by
+  a DMA of its own when the grid step opens) into VMEM as the pool's
+  type, then the run's rows ``WIDE_ROWS`` = 256 at a time (128 at either
+  end of the run where it covers no more of a row-tile; a row-tile
+  wholly above the group's keys is skipped) under the online softmax,
+  ``PAIR`` = 2 heads a trip of the loop so that one head's softmax lies
+  under the other's products.  Measured at 128 heads, 512 rows behind
+  8,192: 5.3 ms against the folded call's 8.7 (with its two products
+  around it); groups of 512 rows 6.0, of 256 rows 9.7; one head a trip
+  7.1, four 5.5 (and twice the kernel's compile time); row-tiles of 128
+  7.9, of 512 5.1 (but 9.1 against 8.6 for a run of 288 rows).  Leaving
+  the mask off below the diagonal or folding the scale into the query
+  moved nothing: what binds is the chain product - softmax - product of
+  a row-tile, not the vector unit's count;
+* VMEM at 16 heads and 512 rows: the queries' block 4 MB twice, the
+  result's 2 MB twice, ``W_kvb``'s slice 4 MB, the accumulator 4 MB, (m,
+  l) 4 MB each as Mosaic pads a ``[., 1]`` column, two groups of rows
+  2.6 MB, two heads' keys and values 1.5 MB, two score tiles 2 MB:
+  inside the 64 MiB the call scopes.  Expanded rows never go to HBM;
+* at a row count with a third list the step's rows go through
+  ``latent_attend_runs``, which makes the folded form's own products
+  (``fold_query``, the padded query, the zeroed result,
+  ``unfold_output``) for the rows that take that form: the one-token
+  rows gathered, the run call under a ``cond`` on its list's count;
+* the kernels' bodies and index maps use the primitives where jnp
+  would keep a body (``jax.lax.div`` and ``rem``, not ``//`` and ``%``;
+  ``jax.lax.select``, not ``jnp.where``): jnp keeps a jitted function's
+  traced body (``where``, and the divisions through it) as its FIRST
+  caller in the process left it, locations and all, and a kernel's
+  serialised module travels in the step program, so in the program's key
+  in the compile cache.  An index map that wrote ``row[t] // wide``
+  carried ten frames of a shallow caller (the engine's step function,
+  the thread that compiled it) into the expanded call's module, the key
+  then depended on which thread traced first, and a warm set-up of
+  ``serve-mla-docqa`` compiled four programs again (PERF.md section 6,
+  PR 58).  The folded calls are reached by two paths since
+  (``latent_attend_tiles`` at a row count without a third list,
+  ``latent_attend_runs`` with one), traced side by side on an engine's
+  threads: their kernel spells its mask and its four scalar divisions
+  with the primitives too, the same arithmetic
+  (``tests/test_tpu_compile.py`` lowers a step in two processes of
+  their own, in either order of its row counts, and holds the kernels'
+  locations to this package's files and their modules to the same
+  bytes).
 """
 
 from __future__ import annotations
@@ -95,13 +184,21 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .paged_attention import (GROUP_MAX, GROUP_SCORE_BYTES, GROUP_VMEM_BYTES,
-                              LONG, NEG_INF, QueryTiles, TileList, _start,
+                              LONG, NEG_INF, QueryTiles, TileList, _start, _wait,
                               _use_interpret, each_group_block, fetch_ahead,
                               query_tiles, send_tile_rows)
 
 F32 = jnp.float32
 # MXU rows (query rows x heads) of a tile of a run of several tokens
 RUN_ROWS = 1024
+# the expanded form's tile (module docstring: each was measured): a
+# window's rows of the batch, the heads of a tile, the rows that meet a
+# group's keys at a time, the cached rows a group holds (expanded whole),
+# the heads a trip of the loop
+WIDE, WIDE_HEADS, WIDE_ROWS, WIDE_KEYS, PAIR = 512, 16, 256, 1024, 2
+# what the expanded form's break-even in rows is multiplied by
+EXPAND_MARGIN = 1.3
+NEVER = 1 << 30
 
 
 class MLADims(NamedTuple):
@@ -227,19 +324,55 @@ def latent_group(rows: int, width: int, block_size: int, dtype,
     return k
 
 
+def expand_from(dims: MLADims) -> int:
+    """Rows from which a run attends in the expanded form.  A (query
+    row, cached row) pair costs a head ``2 (row + kv_rank)`` operations
+    folded and ``2 (nope + rope + value)`` expanded, and a cached row
+    costs a head ``2 kv_rank (nope + value)`` to expand, once for all
+    the run's rows: the two forms cross at ``kv_rank (nope + value) /
+    (row + kv_rank - nope - rope - value)`` rows, 171 at DeepSeek-V2's
+    sizes whatever the head count, times ``EXPAND_MARGIN`` for what the
+    count of operations leaves out (module docstring), rounded up to
+    whole vectors of 8 rows.  Sizes at which the expanded pair costs no
+    less (a latent no larger than a head's key and value): ``NEVER``,
+    more rows than a step holds."""
+    per_pair = (dims.row + dims.kv_rank
+                - dims.nope_dim - dims.rope_dim - dims.value_dim)
+    if per_pair <= 0:
+        return NEVER
+    rows = dims.kv_rank * (dims.nope_dim + dims.value_dim) / per_pair
+    return -(-int(rows * EXPAND_MARGIN + 0.5) // 8) * 8
+
+
+def wide_cut(dims: MLADims) -> Tuple[int, int]:
+    """``query_tiles``' ``wide`` for this layer: the runs of at least
+    ``expand_from(dims)`` rows, in windows of ``WIDE`` rows (what the
+    step's tiles are cut with and what the host counts with)."""
+    return expand_from(dims), WIDE
+
+
+def wide_heads(dims: MLADims) -> int:
+    """Heads a tile of the expanded form holds: the largest divisor of
+    the heads that is at most ``WIDE_HEADS``."""
+    return max(g for g in range(1, WIDE_HEADS + 1) if dims.heads % g == 0)
+
+
 def latent_tiles(seq_slot, positions, token_valid, block_tables,
                  block_size: int, max_blocks_per_seq: int, trash: int,
-                 heads: int, heights: Tuple[int, int] = None) -> QueryTiles:
+                 heads: int, heights: Tuple[int, int] = None,
+                 wide: Tuple[int, int] = None) -> QueryTiles:
     """A step's runs cut into the kernel's tiles (``query_tiles`` at
     ``tile_heights(heads)``: ``short`` holds the one-token runs,
     ``long`` the tiles of the longer ones), once a step and outside the
     layer scan.  ``trash``: the pool row of a layer's trash block.
     ``heights``: other heights than the kernel's own, for a test of
-    small tiles."""
+    small tiles.  ``wide``: ``wide_cut(dims)`` where the
+    caller has the expanded form's call for the runs of at least so many
+    rows (``latent_attend_expanded``), which then leave ``long``."""
     short, long = heights or tile_heights(heads)
     return query_tiles(seq_slot, positions, token_valid, block_tables,
                        block_size, max_blocks_per_seq, trash,
-                       short=short, long=long)
+                       short=short, long=long, wide=wide)
 
 
 def _tile_kernel(tab_ref, row_ref, pos_ref, len_ref, base_ref, q_ref,
@@ -252,8 +385,9 @@ def _tile_kernel(tab_ref, row_ref, pos_ref, len_ref, base_ref, q_ref,
     keys = group * block_size
 
     def last_block(tile):
-        return (pos_ref[tile] + jnp.maximum(len_ref[tile], 1)
-                - 1) // block_size
+        return jax.lax.div(
+            pos_ref[tile] + jax.lax.max(len_ref[tile], jnp.int32(1)) - 1,
+            jnp.int32(block_size))
 
     def each_block(do, tile, g, slot):
         """``do`` (start or wait) the DMA of every block of group ``g``
@@ -272,7 +406,7 @@ def _tile_kernel(tab_ref, row_ref, pos_ref, len_ref, base_ref, q_ref,
         each_group_block(do, tab_ref, tile, g * group, last, group, copies)
 
     pos0 = pos_ref[t]
-    groups = last_block(t) // group + 1
+    groups = jax.lax.div(last_block(t), jnp.int32(group)) + 1
 
     @pl.when(t == 0)
     def _():
@@ -290,10 +424,11 @@ def _tile_kernel(tab_ref, row_ref, pos_ref, len_ref, base_ref, q_ref,
     l_ref[...] = jnp.zeros_like(l_ref)
     # row = token * heads + head
     q = q_ref[...].reshape(R, q_ref.shape[-1])
-    qpos = pos0 + jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0) // heads
+    qpos = pos0 + jax.lax.div(
+        jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0), jnp.int32(heads))
 
     def attend(g, _):
-        slot = (par + g) % 2
+        slot = jax.lax.rem(par + g, 2)
         fetch_ahead(each_block, t, nt, g, groups, slot)
         ctx = buf_ref[slot]                                  # [keys, width]
         s = jax.lax.dot_general(
@@ -302,7 +437,7 @@ def _tile_kernel(tab_ref, row_ref, pos_ref, len_ref, base_ref, q_ref,
         cols = g * keys + jax.lax.broadcasted_iota(jnp.int32, (R, keys), 1)
         # this also masks whole the blocks of the group that lie past
         # the tile's last position and were not read
-        s = jnp.where(cols <= qpos, s, NEG_INF)
+        s = jax.lax.select(cols <= qpos, s, jnp.full_like(s, NEG_INF))
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -318,7 +453,7 @@ def _tile_kernel(tab_ref, row_ref, pos_ref, len_ref, base_ref, q_ref,
         return 0
 
     jax.lax.fori_loop(0, groups, attend, 0)
-    par_ref[0] = (par + groups) % 2
+    par_ref[0] = jax.lax.rem(par + groups, 2)
 
     def fill(ob):
         ob[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
@@ -392,6 +527,297 @@ def latent_attend_tiles(pool, q, tiles: QueryTiles, dims: MLADims,
     for tl, height in ((tiles.long, run), (tiles.short, one)):
         out = _attend_tiles(tl, pool, qp, out, base, height, dims)
     return out
+
+
+def _wide_kernel(tab_ref, row_ref, pos_ref, len_ref, base_ref, q_ref, w_ref,
+                 pool_ref, o_ref, w_buf, buf_ref, key_ref, val_ref, acc_ref,
+                 m_ref, l_ref, par_ref, sem_w, sem_in, *, block_size: int,
+                 scale: float, kv_rank: int, nope: int, group: int,
+                 rows: int):
+    """A tile of the expanded form: the rows of one run inside a window
+    of ``WIDE`` rows of the batch, at ``G`` heads (grid ``(tiles, H /
+    G)``).  Per group of cached blocks and head: ``[k_n | v] = c
+    W_kvb_h`` in VMEM, once for all the tile's rows, then the rows
+    ``rows`` at a time (half of that at either end of the run, where it
+    covers no more) under an online softmax."""
+    t, hg = pl.program_id(0), pl.program_id(1)
+    n_hg = pl.num_programs(1)
+    step, steps = t * n_hg + hg, pl.num_programs(0) * n_hg
+    G, per_head = w_buf.shape[0], w_buf.shape[-1]
+    P = key_ref.shape[0]
+    wide = q_ref.shape[1]
+    keys = group * block_size
+    half = rows // 2
+    bs = jnp.int32(block_size)
+
+    def last_block(tile):
+        return jax.lax.div(
+            pos_ref[tile] + jax.lax.max(len_ref[tile], jnp.int32(1)) - 1, bs)
+
+    def fetch(do, step, g, slot):
+        """``do`` the DMAs of group ``g`` of the tile of grid step
+        ``step`` into buffer ``slot`` (``_tile_kernel``'s walk; every
+        head group of a tile walks the tile's blocks again)."""
+        tile = jax.lax.div(step, n_hg)
+
+        def copies(i, block):
+            return (pltpu.make_async_copy(
+                pool_ref.at[block + base_ref[0]],
+                buf_ref.at[slot, pl.ds(pl.multiple_of(i * block_size,
+                                                      block_size),
+                                       block_size)],
+                sem_in.at[slot]),)
+
+        each_group_block(do, tab_ref, tile, g * group, last_block(tile),
+                         group, copies)
+
+    def each_head_slice(do):
+        """``do`` the DMA of every head's ``[kv_rank, nope + value]`` of
+        ``W_kvb`` from where the matrix lies."""
+        def one(h, _):
+            at = pl.multiple_of((hg * G + h) * per_head, per_head)
+            do(pltpu.make_async_copy(w_ref.at[:, pl.ds(at, per_head)],
+                                     w_buf.at[h], sem_w.at[0]))
+
+        jax.lax.fori_loop(0, G, one, None)
+
+    @pl.when(step == 0)
+    def _():
+        buf_ref[...] = jnp.zeros_like(buf_ref)
+        par_ref[0] = 0
+        fetch(_start, 0, 0, 0)
+
+    each_head_slice(_start)
+    # the run's rows in the window, and the halves of a row-tile that
+    # hold them; ``pos0``: the position of the window's row 0, were the
+    # run to reach back to it
+    lead = jax.lax.rem(row_ref[t], jnp.int32(wide))
+    pos0, length = pos_ref[t] - lead, len_ref[t]
+    h_lo = jax.lax.div(lead, jnp.int32(half))
+    h_hi = jax.lax.div(lead + length + (half - 1), jnp.int32(half))
+    r_lo, r_hi = jax.lax.div(h_lo + 1, 2), jax.lax.div(h_hi, 2)
+    groups = jax.lax.div(last_block(t), jnp.int32(group)) + 1
+    par = par_ref[0]
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    each_head_slice(_wait)
+
+    def attend(g, _):
+        slot = jax.lax.rem(par + g, 2)
+        fetch_ahead(fetch, step, steps, g, groups, slot)
+        # the shared rotated key (and the zeros behind it) as it lies
+        for i in range(P):
+            key_ref[i, :, nope:] = buf_ref[slot, :, kv_rank:]
+        # window rows from which a row sees a key of this group
+        seen_from = jax.lax.max(g * keys - pos0, 0)
+
+        def heads(j, _):
+            # ``P`` heads a trip: independent chains, so that one's
+            # softmax lies under another's products
+            for i in range(P):
+                kv = jax.lax.dot_general(
+                    buf_ref[slot, :, :kv_rank], w_buf[j * P + i],
+                    dimension_numbers=(((1,), (0,)), ((), ())),
+                    preferred_element_type=F32)            # [keys, n + v]
+                key_ref[i, :, :nope] = kv[:, :nope].astype(key_ref.dtype)
+                val_ref[i] = kv[:, nope:].astype(val_ref.dtype)
+
+            def tile_rows(r, _, size):
+                """The window's rows ``r * size`` and the ``size``
+                behind it."""
+                at = pl.ds(pl.multiple_of(r * size, size), size)
+                qpos = pos0 + r * size + jax.lax.broadcasted_iota(
+                    jnp.int32, (size, 1), 0)
+                cols = g * keys + jax.lax.broadcasted_iota(
+                    jnp.int32, (size, keys), 1)
+                # this also masks whole the blocks of the group that lie
+                # past the tile's last position and were not read, and
+                # every key for a row before the run's first
+                keep = cols <= qpos
+                for i in range(P):
+                    h = j * P + i
+                    s = jax.lax.dot_general(
+                        q_ref[h, at, :], key_ref[i],
+                        dimension_numbers=(((1,), (1,)), ((), ())),
+                        preferred_element_type=F32) * scale  # [size, keys]
+                    s = jax.lax.select(keep, s, jnp.full_like(s, NEG_INF))
+                    m_prev = m_ref[h, at, :]
+                    m_new = jnp.maximum(m_prev,
+                                        s.max(axis=1, keepdims=True))
+                    p = jnp.exp(s - m_new)
+                    corr = jnp.exp(m_prev - m_new)
+                    m_ref[h, at, :] = m_new
+                    l_ref[h, at, :] = l_ref[h, at, :] * corr \
+                        + p.sum(axis=1, keepdims=True)
+                    pv = jax.lax.dot_general(
+                        p.astype(val_ref.dtype), val_ref[i],
+                        dimension_numbers=(((1,), (0,)), ((), ())),
+                        preferred_element_type=F32)         # [size, value]
+                    acc_ref[h, at, :] = acc_ref[h, at, :] * corr + pv
+
+            # a half row-tile where the run starts in a row-tile's
+            # second half, the whole ones, a half where it ends in a
+            # first half (its last rows: they see every group)
+            @pl.when((jax.lax.rem(h_lo, 2) == 1)
+                     & (seen_from < (h_lo + 1) * half))
+            def _():
+                tile_rows(h_lo, None, half)
+
+            jax.lax.fori_loop(
+                jax.lax.max(r_lo, jax.lax.div(seen_from, jnp.int32(rows))),
+                r_hi, functools.partial(tile_rows, size=rows), None)
+
+            @pl.when(jax.lax.rem(h_hi, 2) == 1)
+            def _():
+                tile_rows(h_hi - 1, None, half)
+
+        jax.lax.fori_loop(0, G // P, heads, None)
+        return 0
+
+    jax.lax.fori_loop(0, groups, attend, 0)
+    par_ref[0] = jax.lax.rem(par + groups, 2)
+
+    def finish(i, _):
+        h = jax.lax.div(i, h_hi - h_lo)
+        at = pl.ds(pl.multiple_of(
+            (h_lo + jax.lax.rem(i, h_hi - h_lo)) * half, half), half)
+        o_ref[h, at, :] = (acc_ref[h, at, :] / jnp.maximum(
+            l_ref[h, at, :], 1e-30)).astype(o_ref.dtype)
+
+    jax.lax.fori_loop(0, G * (h_hi - h_lo), finish, None)
+
+
+def latent_attend_expanded(pool, q_n, q_r, w, tiles: TileList, o,
+                           dims: MLADims, layer=None):
+    """The runs of ``tiles`` (the step's third list: ``latent_tiles``
+    with ``wide``) over their sequences' cached rows in the EXPANDED
+    form, by the kernel: per-head keys ``[c W_kb_h | k_r]`` and values
+    ``c W_vb_h`` built in VMEM a group of cached blocks at a time.
+
+    pool, ``layer``: as ``latent_attend_tiles``'; q_n: [T, H, nope], q_r:
+    [T, H, rope] rotated (the stored type); w: ``W_kvb`` as
+    ``w_kvb`` gives it; o: [T, H, value_dim], the other rows' heads'
+    values → o with the rows of ``tiles`` replaced."""
+    T, H, _ = q_n.shape
+    wide, rows = WIDE, WIDE_ROWS
+    n = tiles.row.shape[0]
+    bs, W = pool.shape[1:]
+    G = wide_heads(dims)
+    P = PAIR if G % PAIR == 0 else 1
+    per_head = dims.nope_dim + dims.value_dim
+    base = 0 if layer is None else layer[0]
+    group = 1
+    while 2 * group * bs <= min(WIDE_KEYS, tiles.tables.shape[1] * bs):
+        group *= 2
+    # the step's queries head-major in whole windows, as they meet
+    # ``[k_n | k_r | the row's zeros]``: a tile reads its window
+    windows = -(-T // wide)
+    q = jnp.concatenate([q_n, q_r], axis=-1).transpose(1, 0, 2)
+    q = jnp.pad(q, ((0, 0), (0, windows * wide - T),
+                    (0, dims.nope_dim + W - dims.kv_rank - q.shape[-1])))
+    prefetch = [tiles.tables, tiles.row, tiles.pos, tiles.length,
+                jnp.reshape(base, (1,)).astype(jnp.int32)]
+    out = pl.pallas_call(
+        functools.partial(_wide_kernel, block_size=bs, scale=dims.scale,
+                          kv_rank=dims.kv_rank, nope=dims.nope_dim,
+                          group=group, rows=rows),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(tiles.count, H // G),
+            in_specs=[
+                # (the primitive: ``//`` would bring the body of jnp's
+                # floor division as whoever traced it first in this
+                # process left it, with THAT call's stack in its
+                # locations, into the kernel's serialised module and so
+                # into the step program's key in the compile cache)
+                pl.BlockSpec((G, wide, q.shape[-1]),
+                             lambda t, hg, tables, row, *_:
+                             (hg, jax.lax.div(row[t], jnp.int32(wide)), 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((None, G, wide, dims.value_dim),
+                                   lambda t, hg, *_: (t, hg, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((G, dims.kv_rank, per_head), q.dtype),
+                pltpu.VMEM((2, group * bs, W), pool.dtype),  # two groups
+                pltpu.VMEM((P, group * bs, q.shape[-1]), pool.dtype),
+                pltpu.VMEM((P, group * bs, dims.value_dim), pool.dtype),
+                pltpu.VMEM((G, wide, dims.value_dim), F32),
+                pltpu.VMEM((G, wide, 1), F32),
+                pltpu.VMEM((G, wide, 1), F32),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.SemaphoreType.DMA((1,)),
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((n, H, wide, dims.value_dim),
+                                       o.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=_use_interpret(),
+        name=f"latent_attention_h{wide}",
+    )(*prefetch, q, w.reshape(dims.kv_rank, H * per_head).astype(q.dtype),
+      pool)
+    # a tile's rows lie in its window as in the batch
+    i = jnp.arange(T)
+    for k in range(n):
+        held = ((i >= tiles.row[k]) & (i < tiles.row[k] + tiles.length[k])
+                & (k < tiles.count))
+        rows_k = jnp.tile(out[k].transpose(1, 0, 2), (windows, 1, 1))[:T]
+        o = jnp.where(held[:, None, None], rows_k, o)
+    return o
+
+
+def latent_attend_runs(ap, pool, q_n, q_r, tiles: QueryTiles, dims: MLADims,
+                       layer, dtype, heights: Tuple[int, int] = None):
+    """A step's rows where its tiles have a third list, each run by the
+    call its length takes → the heads' values [T, H, value_dim] in
+    ``dtype``, zero in the rows of no tile.  The folded form's products
+    (``fold_query``, the padded query, the zeroed result,
+    ``unfold_output``: 0.5 ms a layer at 512 rows and 128 heads, all of
+    it HBM traffic) are made for the rows that take that form, not for
+    the step's:
+
+    * the one-token runs' rows are gathered (a row a tile, at most one a
+      slot), folded, attended by the one-token call, unfolded and
+      scattered back;
+    * the runs under ``expand_from`` rows take the folded run call over
+      the step's rows as ``latent_attend_tiles`` makes it, under a
+      ``cond`` on the list's count: a step whose chunk is long enough to
+      expand has none, and a step of one-token rows has none either;
+    * the third list's runs take ``latent_attend_expanded``.
+
+    ap: the layer's parameters (``w_kvb``); pool, ``layer``, ``heights``:
+    as ``latent_attend_tiles``'; q_n, q_r: as
+    ``latent_attend_expanded``'."""
+    T, H, _ = q_n.shape
+    one, run = heights or tile_heights(H)
+    base = 0 if layer is None else layer[0]
+    width = pool.shape[-1]
+
+    def attend(tl, q_n, q_r, height):
+        """``tl``'s tiles over these rows by the folded call →
+        [rows, H, value_dim]."""
+        qf = jnp.pad(fold_query(ap, q_n, q_r, dims),
+                     ((0, height), (0, 0), (0, width - dims.row)))
+        out = jnp.zeros(q_n.shape[:2] + (dims.kv_rank,), q_n.dtype)
+        return unfold_output(
+            ap, _attend_tiles(tl, pool, qf, out, base, height, dims), dims,
+            dtype)
+
+    o = jax.lax.cond(
+        tiles.long.count > 0, lambda: attend(tiles.long, q_n, q_r, run),
+        lambda: jnp.zeros((T, H, dims.value_dim), dtype))
+    short = tiles.short
+    at = jnp.arange(short.row.shape[0], dtype=jnp.int32)
+    mine = attend(short._replace(row=at), q_n[short.row], q_r[short.row],
+                  one)
+    o = o.at[jnp.where(at < short.count, short.row, T)].set(mine,
+                                                             mode="drop")
+    return latent_attend_expanded(pool, q_n, q_r, w_kvb(ap, dims),
+                                  tiles.wide, o, dims, layer)
 
 
 def _normed(x, scale, eps: float, times: float):

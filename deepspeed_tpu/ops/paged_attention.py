@@ -146,16 +146,36 @@ class TileList(NamedTuple):
 class QueryTiles(NamedTuple):
     short: TileList
     long: TileList
+    # the runs of at least ``wide[0]`` rows, where a caller asked for
+    # them apart (the latent kernel's expanded form, ``ops/mla.py``)
+    wide: TileList = None
 
 
 def tile_counts(run_lengths: Sequence[int], short: int = SHORT,
-                long: int = LONG) -> Tuple[int, int, int]:
+                long: int = LONG, wide: Tuple[int, int] = None) -> Tuple:
     """(short tiles, long tiles, real rows in the long tiles) of a step
     whose runs have these lengths — the host's count of what
-    ``query_tiles`` builds on the device at the same two heights."""
+    ``query_tiles`` builds on the device at the same two heights; with
+    ``wide`` (``query_tiles``') a fourth count, the wide tiles, whose
+    runs the long ones then leave out.  A wide run is cut where the
+    BATCH's rows pass a multiple of the height, so this count alone
+    takes ``run_lengths`` to be in the batch's order, one run behind
+    another from row 0: what ``RaggedState.build_batch`` does with the
+    schedule it is given (a running ``cursor`` over ``requests``), and
+    what ``tests/test_latent_expanded.py`` holds against the lists cut
+    on the device."""
     n_short = sum(1 for n in run_lengths if 0 < n <= short)
-    long_runs = [n for n in run_lengths if n > short]
-    return n_short, sum(-(-n // long) for n in long_runs), sum(long_runs)
+    long_runs = [n for n in run_lengths
+                 if n > short and not (wide and n >= wide[0])]
+    counts = (n_short, sum(-(-n // long) for n in long_runs), sum(long_runs))
+    if wide:
+        at = n_wide = 0
+        for n in run_lengths:
+            if n >= wide[0]:
+                n_wide += (at + n - 1) // wide[1] - at // wide[1] + 1
+            at += n
+        counts += (n_wide,)
+    return counts
 
 
 def _pad(n: int, to: int) -> int:
@@ -250,7 +270,7 @@ def window_blocks(pos, length, window: int, block_size: int):
 def query_tiles(seq_slot, positions, token_valid, block_tables,
                 block_size: int, max_blocks_per_seq: int,
                 trash: int, short: int = SHORT,
-                long: int = LONG) -> QueryTiles:
+                long: int = LONG, wide: Tuple[int, int] = None) -> QueryTiles:
     """Cut a ragged batch into query tiles, on the device, once a step.
 
     seq_slot/positions: [T] i32, token_valid: [T] bool,
@@ -261,7 +281,13 @@ def query_tiles(seq_slot, positions, token_valid, block_tables,
     bounds the lists: ``max_seqs`` short tiles, ``T // long`` full long
     tiles and one partial one a long run.
     ``short``, ``long``: the two heights (this kernel's; the latent
-    kernel of ``ops/mla.py`` cuts the same runs at its own)."""
+    kernel of ``ops/mla.py`` cuts the same runs at its own).
+    ``wide``: ``(from, height)``, for a caller with a third call: the
+    runs of at least ``from`` rows leave the long list for a third one,
+    cut where the BATCH's rows pass a multiple of ``height`` (a tile
+    lies inside one window of ``height`` rows of the batch, at any
+    offset: the caller reads whole windows); at most a tile a such run
+    and one more a boundary, none where no run can be that long."""
     T = seq_slot.shape[0]
     max_seqs = block_tables.shape[0]
     i = jnp.arange(T, dtype=jnp.int32)
@@ -277,6 +303,12 @@ def query_tiles(seq_slot, positions, token_valid, block_tables,
     run_len = end - start + 1
     off = i - start
     is_short = run_len <= short
+    is_long = ~is_short
+    n_wide = T // wide[0] if wide else 0
+    if n_wide:
+        n_wide += -(-T // wide[1]) - 1
+        is_wide = run_len >= wide[0]
+        is_long &= ~is_wide
 
     def collect(flag, length, bound):
         rows = jnp.flatnonzero(flag, size=bound, fill_value=0).astype(
@@ -290,9 +322,12 @@ def query_tiles(seq_slot, positions, token_valid, block_tables,
 
     return QueryTiles(
         collect(first & is_short, run_len, min(T, max_seqs)),
-        collect(valid & ~is_short & (off % long == 0),
+        collect(valid & is_long & (off % long == 0),
                 jnp.minimum(run_len - off, long),
-                max(1, T // long + min(max_seqs, T // (short + 1)))))
+                max(1, T // long + min(max_seqs, T // (short + 1)))),
+        collect(valid & is_wide & (first | (i % wide[1] == 0)),
+                jnp.minimum(run_len - off, wide[1] - i % wide[1]), n_wide)
+        if n_wide else None)
 
 
 def _start(cp):

@@ -208,23 +208,32 @@ def test_a_sleeping_wait_with_the_process_idle(next_ready, where):
 
 
 def test_a_thread_spinning_under_the_interpreter_lock():
-    pol, flight = policy()
-    warmed(pol)
-    stop = threading.Event()
-
     def spin():
         n = 0
         while not stop.is_set():
             n += 1
 
-    th = threading.Thread(target=spin, daemon=True)
-    th.start()
-    try:
-        where = slow_wait(pol, lambda: time.sleep(0.3), True)
-    finally:
-        stop.set()
-        th.join()
-    (rec,) = records(flight)
+    # (a machine with no core to spare starves the spinner too: the
+    # process then burned under 0.4 of the round, the round was not the
+    # interpreter's, and the reading says nothing of the rule.  It is
+    # taken again, a few times: tier-1 runs six workers on eight cores
+    # and read 128 ms of CPU in a round of 368 once, PERF.md section 6,
+    # PR 58)
+    for attempt in range(10):
+        time.sleep(0.3 * (attempt > 0))
+        pol, flight = policy()
+        warmed(pol)
+        stop = threading.Event()
+        th = threading.Thread(target=spin, daemon=True)
+        th.start()
+        try:
+            where = slow_wait(pol, lambda: time.sleep(0.3), True)
+        finally:
+            stop.set()
+            th.join()
+        (rec,) = records(flight)
+        if rec["process_cpu_ms"] >= max(150.0, 0.45 * rec["round_ms"]):
+            break
     assert where == rec["where"] == "interpreter" and rec["by"] == "thread"
     # the process burned about a core while this thread had none
     assert rec["process_cpu_ms"] >= 150.0 and rec["thread_cpu_ms"] < 75.0

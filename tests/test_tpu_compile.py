@@ -14,6 +14,7 @@ every test file — see the on-chip-measurement guide), all such tests
 live in this one file and compile in the test's own process.
 """
 
+import base64
 import functools
 import math
 import os
@@ -319,11 +320,39 @@ def _moves_of(text: str, floor: int):
     return found
 
 
+def _kernel_bodies(lowered):
+    """The Mosaic kernels' serialised modules as a lowered program's text
+    holds them (which escapes a quote as \\22).  A kernel's module
+    travels in the program and so in the program's key in the compile
+    cache, locations and all: the ten innermost frames of every
+    operation."""
+    return re.findall(r'body\\22: \\22([\w+/=]+)', lowered.as_text())
+
+
+def _kernel_source_files(body):
+    """The source files that the locations of a kernel's serialised
+    module name."""
+    return sorted({f.decode() for f in re.findall(
+        rb"/[\w/.\-]+\.py", base64.b64decode(body))})
+
+
 def _pstep_compiled(one_chip, cfg, kv_quant, T, seqs, bs, mbs, blocks):
     """``InferenceEngine._build_pstep``'s program for ``cfg`` compiled
     for the described chip, the cache donated → (compiled, bytes of one
     layer's share of the pool); ``compiled.jaxpr()`` traces the step
     again for a test that reads its equations."""
+    lowered, pstep, args, layer_bytes = _pstep_lowered(
+        one_chip, cfg, kv_quant, T, seqs, bs, mbs, blocks)
+    compiled = lowered.compile()
+    compiled.jaxpr = lambda: jax.make_jaxpr(pstep)(*args).jaxpr
+    return compiled, layer_bytes
+
+
+def _pstep_lowered(one_chip, cfg, kv_quant, T, seqs, bs, mbs, blocks):
+    """``_pstep_compiled``'s program lowered → (lowered, the step, its
+    arguments, bytes of one layer's share of the pool).  ``one_chip``
+    None: lowered for the platform with no chip described (the kernels'
+    modules are made by then; nothing of it can be compiled)."""
     from deepspeed_tpu.inference import SamplingParams
     from deepspeed_tpu.inference.model import (fold_projections,
                                                moe_stat_rows,
@@ -399,9 +428,10 @@ def _pstep_compiled(one_chip, cfg, kv_quant, T, seqs, bs, mbs, blocks):
     prev = seqs + (moe_stat_rows(cfg) if cfg.num_experts > 1 else 0)
     args = (params, kv, batch, S((prev,), jnp.int32),
             S(key.shape, key.dtype))
-    compiled = jax.jit(pstep, donate_argnums=(1,)).lower(*args).compile()
-    compiled.jaxpr = lambda: jax.make_jaxpr(pstep)(*args).jaxpr
-    return compiled, layer_bytes
+    traced = jax.jit(pstep, donate_argnums=(1,)).trace(*args)
+    lowered = traced.lower() if one_chip is not None \
+        else traced.lower(lowering_platforms=("tpu",))
+    return lowered, pstep, args, layer_bytes
 
 
 def _eqns(jaxpr, inside_scan=False):
@@ -537,10 +567,12 @@ def test_delta_rule_and_latent_serving_step_compiles_and_fits(one_chip,
         one_chip, cfg, False, T=512, seqs=128, bs=64, mbs=96, blocks=12288)
     text = compiled.as_text()
     # one period of six expert layers, which a scan of one trip unrolls
-    assert text.count("tpu_custom_call") == 6 * 3 + 6 + 2
+    assert text.count("tpu_custom_call") == 6 * 3 + 6 + 3
     assert len(re.findall(r"%kda_state_update[\w.]* = ", text)) == 6
-    # 32 heads: a one-token run's tile, and tiles of 32 rows
-    assert len(re.findall(r"%latent_attention_h(1|32)[\w.]* = ", text)) == 2
+    # 32 heads: a one-token run's tile, tiles of 32 rows, and the
+    # expanded form's tile of a run's 512
+    assert len(re.findall(r"%latent_attention_h(1|32|512)[\w.]* = ",
+                          text)) == 3
     # a row of 576 values in five whole vectors of 128 lanes
     assert layer_bytes == 12289 * 64 * 640 * 2
     kd = cfg.kda_dims
@@ -580,9 +612,14 @@ def test_latent_shortcut_serving_step_compiles_and_fits(one_chip, on_chip,
         one_chip, cfg, False, T=rows, seqs=48, bs=64, mbs=160, blocks=4608)
     text = compiled.as_text()
     # four expert layers under a rolled scan: one body, three
-    # projections, two sublayers of two attention calls
-    assert text.count("tpu_custom_call") == 3 + 2 * 2
+    # projections, two sublayers of two attention calls, and where the
+    # step holds a run long enough (``expand_from``: 224 rows) the
+    # expanded form's
+    calls = 2 + (rows == 512)
+    assert text.count("tpu_custom_call") == 3 + 2 * calls
     assert len(re.findall(r"%latent_attention_h(1|16)[\w.]* = ", text)) == 4
+    assert len(re.findall(r"%latent_attention_h512[\w.]* = ",
+                          text)) == 2 * (rows == 512)
     assert layer_bytes == 4609 * 64 * 640 * 2
     moved = [m for m in _moves_of(text, layer_bytes)
              if "dynamic-update-slice" not in m and "fusion" not in m]
@@ -592,6 +629,84 @@ def test_latent_shortcut_serving_step_compiles_and_fits(one_chip, on_chip,
           mem.temp_size_in_bytes)
     assert mem.argument_size_in_bytes > 12.0e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+def _docqa_kernels(order):
+    """``serve-mla-docqa``'s step lowered at the row counts of ``order``,
+    one behind another → {rows: [(sha256 of a kernel's serialised
+    module, the files its locations name), a kernel]}.  For a process of
+    its own: what it reads depends on what the process traced before."""
+    import hashlib
+    import json
+
+    from benchmarks.lib.drivers.serve_latent_share import preset_config
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root,
+                           "benchmarks/configs/longcat-flash-d4.json")) as f:
+        cfg = preset_config(json.load(f))
+    return {rows: [(hashlib.sha256(body.encode()).hexdigest(),
+                    _kernel_source_files(body))
+                   for body in _kernel_bodies(_pstep_lowered(
+                       None, cfg, False, T=rows, seqs=48, bs=64, mbs=160,
+                       blocks=4608)[0])] for rows in order}
+
+
+# a process that lowers the step at the row counts of its arguments, in
+# their order; ``--shallow``: after tracing jnp's jitted helpers for a
+# scalar from its own top level, as a first caller of a few frames would
+_DOCQA_KERNELS = """
+import json, sys
+sys.path[:0] = [{root!r}, {tests!r}]
+import jax, jax.numpy as jnp
+jax.default_backend = lambda: "tpu"     # the kernels' compiled path
+if "--shallow" in sys.argv:
+    jax.make_jaxpr(lambda a: (a // 512, a % 2, jnp.where(a > 0, a, 0),
+                              jnp.maximum(a, 1)))(jnp.int32(3))
+import test_tpu_compile
+print(json.dumps(test_tpu_compile._docqa_kernels(
+    [int(a) for a in sys.argv[1:] if a.isdigit()])))
+"""
+
+
+def test_latent_step_programs_do_not_depend_on_who_traced_first():
+    """``serve-mla-docqa``'s step at both of its row counts (the three
+    latent cells share the kernels' code; this one's set-up showed it),
+    lowered in either order, each order in a process of its own (what a
+    kernel's module holds must not depend on what a worker ran before
+    this test either): the kernels' serialised modules are the same,
+    byte for byte, and the latent kernels name this package's files
+    alone.  jnp keeps a jitted function's traced body (``where``, and
+    ``//`` and ``%`` through it) as its first caller left it, locations
+    and all; the two row counts reach the folded calls by different
+    paths (``latent_attend_tiles``, ``latent_attend_runs``) and an
+    engine traces them on threads side by side, so a kernel that held
+    such a body would give its program another key in the compile cache
+    from one run to the next, and a warm set-up would compile it again
+    (PERF.md section 6, PR 58)."""
+    import json
+    import subprocess
+    import sys
+
+    tests = os.path.dirname(os.path.abspath(__file__))
+    code = _DOCQA_KERNELS.format(root=os.path.dirname(tests), tests=tests)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    procs = [subprocess.Popen([sys.executable, "-c", code, *order],
+                              stdout=subprocess.PIPE, stderr=subprocess.PIPE,
+                              text=True, env=env)
+             for order in (("128", "512"), ("--shallow", "512", "128"))]
+    outs = [p.communicate(timeout=600) for p in procs]
+    for p, (_, err) in zip(procs, outs):
+        assert p.returncode == 0, err[-2000:]
+    one, other = (json.loads(out.strip().splitlines()[-1])
+                  for out, _ in outs)
+    assert (len(one["128"]), len(one["512"])) == (3 + 2 * 2, 3 + 2 * 3)
+    assert one == other
+    latent = [files for kernels in one.values() for _, files in kernels
+              if any(f.endswith("/deepspeed_tpu/ops/mla.py") for f in files)]
+    assert len(latent) == 2 * 2 + 2 * 3
+    assert all("/deepspeed_tpu/" in f for files in latent for f in files), \
+        latent
 
 
 @pytest.mark.parametrize("rows", [128, 512])
@@ -620,8 +735,12 @@ def test_latent_groups_serving_step_compiles_and_fits(one_chip, on_chip,
     # the leading layer outside the scan and ONE body for the four
     # expert layers: two attention calls each, three projections in the
     # body
-    assert text.count("tpu_custom_call") == 2 + 2 + 3
+    # (and at 512 rows the expanded form's call for a run long enough)
+    calls = 2 + (rows == 512)
+    assert text.count("tpu_custom_call") == calls + calls + 3
     assert len(re.findall(r"%latent_attention_h(1|8)[\w.]* = ", text)) == 4
+    assert len(re.findall(r"%latent_attention_h512[\w.]* = ",
+                          text)) == 2 * (rows == 512)
     assert layer_bytes == 9217 * 64 * 640 * 2
     moved = [m for m in _moves_of(text, layer_bytes)
              if "dynamic-update-slice" not in m and "fusion" not in m]
